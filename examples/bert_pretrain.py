@@ -16,7 +16,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from hetu_tpu.utils.platform import bootstrap_example
 
-bootstrap_example(8)  # virtual devices for bare CPU runs + platform forcing
+bootstrap_example(8)  # virtual CPU devices for bare runs + compile cache
 
 import jax
 import numpy as np

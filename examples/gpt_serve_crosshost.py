@@ -11,6 +11,11 @@ re-prefills from the original prompt and (greedy decode) produces the
 EXACT tokens the dead process would have.
 
     python examples/gpt_serve_crosshost.py --requests 8 --max-tokens 24
+
+The controller (this process) never touches a JAX backend.  With
+``JAX_PLATFORMS=cpu`` the members run on CPU; on a TPU host each member is
+pinned to its own chip, so two members need two chips — on one chip the
+pool refuses before spawning anything.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from hetu_tpu.utils.platform import bootstrap_example
 
-bootstrap_example(8)
+bootstrap_example(8)  # virtual CPU devices for bare runs + compile cache
 
 PROMPTS = [
     "two processes, one van",
@@ -48,6 +53,7 @@ def main():
     from hetu_tpu.serve.crosshost import CrossProcessServingPool
 
     workdir = args.workdir or tempfile.mkdtemp(prefix="crosshost_")
+    Path(workdir).mkdir(parents=True, exist_ok=True)
     model = {"vocab_size": 256, "hidden_size": 96, "num_layers": 2,
              "num_heads": 4, "ffn_size": 192, "max_position": 96,
              "num_slots": 4, "max_len": 80, "min_bucket": 8, "seed": 0}
@@ -55,8 +61,9 @@ def main():
         2, workdir=workdir, model=model, lease_s=0.4,
         suspect_grace_s=0.4, request_timeout_s=180.0)
     print(f"pool up: 2 member PROCESSES "
-          f"(pids {[p.pid for p in pool.procs]}), van on "
-          f"127.0.0.1:{pool.port}")
+          f"(pids {[p.pid for p in pool.procs]}), "
+          f"{'one TPU chip each' if pool.members_on_chips else 'on CPU'}, "
+          f"van on 127.0.0.1:{pool.port}")
 
     results = {}
     errors = []

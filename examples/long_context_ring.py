@@ -25,14 +25,9 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-import os
+from hetu_tpu.utils.platform import bootstrap_example
 
-if "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
-from hetu_tpu.utils.platform import apply_env_platform
-
-apply_env_platform()
+bootstrap_example(8)  # virtual CPU devices for bare runs + compile cache
 
 import jax
 import jax.numpy as jnp

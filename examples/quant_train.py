@@ -21,9 +21,9 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-from hetu_tpu.utils.platform import apply_env_platform
+from hetu_tpu.utils.platform import bootstrap_example
 
-apply_env_platform()
+bootstrap_example(8)  # virtual CPU devices for bare runs + compile cache
 
 import numpy as np
 

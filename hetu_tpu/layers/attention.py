@@ -25,8 +25,8 @@ class MultiHeadAttention(Module):
                  attention_impl: str = "xla"):
         """attention_impl: 'xla' (compiler-fused composition) or 'flash'
         (Pallas kernel, hetu_tpu/ops/pallas_kernels) — flash requires seq
-        divisible by its block size and no explicit mask (masked calls warn
-        and fall back to xla)."""
+        divisible by its block size and takes no explicit mask (a masked
+        call raises: the kernel covers the causal/unmasked cases only)."""
         assert attention_impl in ("xla", "flash"), attention_impl
         assert hidden_size % num_heads == 0
         self.hidden_size = hidden_size
@@ -53,20 +53,17 @@ class MultiHeadAttention(Module):
         """x: [batch, seq, hidden]; mask broadcastable to [B,H,S,S] (1=keep)."""
         p = variables["params"]
         b, s, h = x.shape
-        x = x.astype(self.dtype)
-        qkv = ops.linear(x, p["qkv_weight"].astype(self.dtype),
-                         p["qkv_bias"].astype(self.dtype))  # [B,S,3H]
-        qkv = qkv.reshape(b, s, 3, self.num_heads, self.head_dim)
-        q, k, v = (jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3))  # [B,Hd,S,D]
         if self.attention_impl == "flash" and mask is not None:
-            import warnings
-            warnings.warn(
-                "attention_impl='flash' ignores explicit masks; falling "
-                "back to the xla path for this call (flash covers the "
-                "causal/unmasked cases)", stacklevel=2)
+            raise ValueError(
+                "attention_impl='flash' takes no explicit mask (the kernel "
+                "covers the causal and unmasked cases); build the layer "
+                "with attention_impl='xla' for masked attention")
+        x = x.astype(self.dtype)
+        q, k, v = (jnp.moveaxis(t, 1, 2)
+                   for t in self._qkv(p, x))  # [B,nh,S,hd]
         if mask is None and self.causal:
             out = self._causal_core(q, k, v)  # shared with prefill_step
-        elif self.attention_impl == "flash" and mask is None:
+        elif self.attention_impl == "flash":
             from hetu_tpu.ops.pallas_kernels import flash_attention
             out = flash_attention(q, k, v, causal=False)
         elif self.causal and mask is not None:
@@ -98,12 +95,17 @@ class MultiHeadAttention(Module):
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
 
     def _qkv(self, p, x):
-        """Fused projection split into q/k/v in cache layout [B,S,nh,hd]."""
+        """Fused projection split into q/k/v in cache layout [B,S,nh,hd].
+
+        The 3H columns are HEAD-major ([nh, 3, hd], Megatron's layout, as
+        in models/gpt_sharded.py): a column split over tp then lands whole
+        heads on each device and attention needs no gather of the heads.
+        """
         b, s, _ = x.shape
         qkv = ops.linear(x, p["qkv_weight"].astype(self.dtype),
                          p["qkv_bias"].astype(self.dtype))
-        qkv = qkv.reshape(b, s, 3, self.num_heads, self.head_dim)
-        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        qkv = qkv.reshape(b, s, self.num_heads, 3, self.head_dim)
+        return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
 
     def _out(self, p, out, b, s):
         out = jnp.moveaxis(out, 1, 2).reshape(b, s, self.hidden_size)

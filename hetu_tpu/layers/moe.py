@@ -285,10 +285,9 @@ class MoELayer(Module):
             {"params": p["gate"], "state": {}}, gi, train=train, rng=rng,
             **gate_kw)
 
-        # under SPMD (mesh given) the gathers must stay XLA ops — the
-        # partitioner can shard a gather but not a pallas_call; the Pallas
-        # kernels serve the single-device hot path (interpret=None auto)
-        kern = {"interpret": True} if self.mesh is not None else {}
+        # with a mesh, the XLA gather IS the sharded path: GSPMD splits a
+        # gather over 'ep' and refuses to partition a pallas_call
+        kern = {"kernel": False} if self.mesh is not None else {}
         if self.dispatch_impl == "gather":
             slot_token, token_slot, n_dropped = make_slot_routing(
                 gates, idx, E, capacity)

@@ -34,8 +34,8 @@ class BertConfig:
     type_vocab_size: int = 2
     dropout_rate: float = 0.1
     dtype: object = jnp.float32
-    attention_impl: str = "xla"  # 'flash' = Pallas kernel (TPU); only
-    # applies when no attention_mask is passed (masked calls warn + use xla)
+    attention_impl: str = "xla"  # 'flash' = Pallas kernel (TPU); takes no
+    # attention_mask (a masked call raises — use 'xla' for padded batches)
 
 
 class BertModel(Module):

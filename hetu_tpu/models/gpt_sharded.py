@@ -30,13 +30,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from hetu_tpu import init as initializers

@@ -90,16 +90,16 @@ def make_slot_routing(gates, expert_idx, num_experts: int, capacity: int):
 
 
 def gather_dispatch(tokens, slot_token, num_experts: int, capacity: int,
-                    *, interpret=None):
+                    *, kernel=None):
     """tokens [T, D] → expert-major [E, C, D] by row gather (empty slots
-    zero).  Pallas routed_gather on TPU; replaces the einsum dispatch's
-    O(T·E·C·D) flops with O(E·C·D) bytes."""
+    zero).  Pallas routed_gather on TPU (``kernel``: see routed_gather);
+    replaces the einsum dispatch's O(T·E·C·D) flops with O(E·C·D) bytes."""
     from hetu_tpu.ops.pallas_kernels import routed_gather
-    rows = routed_gather(tokens, slot_token, interpret=interpret)
+    rows = routed_gather(tokens, slot_token, kernel=kernel)
     return rows.reshape(num_experts, capacity, tokens.shape[-1])
 
 
-def gather_combine(expert_out, token_slot, gates, *, interpret=None):
+def gather_combine(expert_out, token_slot, gates, *, kernel=None):
     """[E, C, D] expert outputs → [T, D] token outputs, gate-weighted;
     dropped routes contribute zero (capacity-overflow semantics of the
     reference's ReverseLayoutTransform)."""
@@ -108,7 +108,7 @@ def gather_combine(expert_out, token_slot, gates, *, interpret=None):
     T, k = token_slot.shape
     flat = expert_out.reshape(E * C, D)
     picked = routed_gather(flat, token_slot.reshape(-1),
-                           interpret=interpret)          # [T*k, D]
+                           kernel=kernel)                # [T*k, D]
     picked = picked.reshape(T, k, D)
     return jnp.sum(gates[..., None].astype(picked.dtype) * picked, axis=1)
 

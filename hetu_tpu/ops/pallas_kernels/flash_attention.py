@@ -40,9 +40,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AxisType, PartitionSpec as P
+
+from hetu_tpu.parallel.mesh import AXIS_DP, AXIS_TP
+from hetu_tpu.utils.platform import auto_interpret
 
 NEG_INF = -1e30
 
@@ -156,7 +160,6 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
 
 
 def _scratch(bq, d):
-    from jax.experimental.pallas import tpu as pltpu
     return [pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32)]
@@ -165,13 +168,8 @@ def _scratch(bq, d):
 def _params():
     """bh and the outer block axis are parallel; the innermost axis carries
     the VMEM accumulator and must run in order."""
-    from jax.experimental.pallas import tpu as pltpu
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except TypeError:  # older API name
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------- backward
@@ -271,8 +269,6 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
                interpret):
-    from jax.experimental.pallas import tpu as pltpu
-
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
     bq = _fit_block(s_q, block_q)
@@ -364,6 +360,23 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, g):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+def _mesh_partition(batch: int, heads: int):
+    """How the mesh in context (``jax.set_mesh``; Executor and the serving
+    engines enter theirs) splits a [B, H, S, D] attention operand: batch
+    over 'dp' and heads over 'tp' — the two dims every kernel program is
+    independent along — where the axis divides the dim, replicated over
+    the rest.  Returns (axes to take manual, spec); no axes when no mesh
+    is set or an enclosing shard_map already holds them all."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = frozenset(a for a, t in zip(mesh.axis_names, mesh.axis_types)
+                     if t == AxisType.Auto)
+
+    def axis(name, n):
+        return name if name in auto and n % mesh.shape[name] == 0 else None
+
+    return auto, P(axis(AXIS_DP, batch), axis(AXIS_TP, heads), None, None)
+
+
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                     block_q: int = 256, block_k: int = 256,
                     interpret=None):
@@ -373,15 +386,24 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     softmax; backward recomputes probability tiles from the saved LSE
     (FlashAttention-2) — no O(S^2) tensor in HBM either way.
 
-    interpret=None auto-selects: real kernel on TPU, interpret mode
-    elsewhere.  Block sizes auto-fit down to the sequence length (any S
+    interpret=None auto-selects: compiled kernel on TPU, interpret mode on
+    CPU.  Block sizes auto-fit down to the sequence length (any S
     divisible by a power-of-two >= 8 works; only truly odd lengths need
     upstream padding).  Causal masking is bottom-right aligned for
     S_q != S_k.
+
+    The SPMD partitioner cannot split a compiled ``pallas_call`` (JAX
+    refuses to lower one outside a fully manual region), so under a mesh
+    the call is wrapped in a ``shard_map`` over the batch and head axes
+    (:func:`_mesh_partition`): each device runs the kernel on its own shard.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    from hetu_tpu.utils.platform import auto_interpret
-    interpret = auto_interpret(interpret)
-    return _flash(q, k, v, float(scale), bool(causal), int(block_q),
-                  int(block_k), bool(interpret))
+    static = (float(scale), bool(causal), int(block_q), int(block_k),
+              auto_interpret(interpret))
+    axes, spec = _mesh_partition(q.shape[0], q.shape[1])
+    if not axes:
+        return _flash(q, k, v, *static)
+    return shard_map(lambda q, k, v: _flash(q, k, v, *static),
+                     in_specs=(spec, spec, spec), out_specs=spec,
+                     axis_names=axes, check_vma=False)(q, k, v)

@@ -26,11 +26,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 

@@ -15,6 +15,7 @@ public scaling playbooks.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -56,6 +57,14 @@ class MeshConfig:
     @property
     def num_devices(self) -> int:
         return self.dp * self.tp * self.pp * self.ep * self.sp
+
+
+def mesh_context(mesh: Optional[Mesh]):
+    """Make ``mesh`` the one in context (``jax.set_mesh``) while a jitted
+    step traces and runs, so code deep inside it that must partition itself
+    — the flash-attention kernel's shard_map — can find it.  A no-op
+    context for ``None``."""
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
 
 def make_mesh(config: Optional[MeshConfig] = None, *, devices=None,

@@ -816,6 +816,8 @@ class MPMDPipelineSupervisor:
         cfg = self.workdir / f"{tag}.json"
         cfg.write_text(spec.to_json())
         self.log_paths.append(spec.log_path)
+        # stages are numpy+van only — they stay on CPU, so a fleet on a
+        # TPU host never has N processes asking for the one-owner chips
         self.procs[stage] = spawn_module(
             self.workdir, tag, "hetu_tpu.parallel.mpmd_elastic",
             [str(cfg)], extra_env={"JAX_PLATFORMS": "cpu"},

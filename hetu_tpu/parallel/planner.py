@@ -142,6 +142,60 @@ def audit(fn, *args, static_argnums=(), donate_argnums=()) -> PlanAudit:
     return result
 
 
+_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*.*?\s"
+    r"(?P<op>[a-z][a-z\-]*)\((?P<args>[^)]*)\)")
+_MATMUL_RE = re.compile(r"\s(?:convolution|dot)\(")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s.*\{\s*$")
+
+
+def gathers_feeding(hlo_text: str, target: str = "tpu_custom_call") -> list:
+    """All-gathers whose result reaches an operand of a ``target`` custom
+    call in compiled HLO text, returned as instruction lines.
+
+    The question a sharded kernel call must answer: did the partitioner
+    gather the batch or the heads back onto every device in front of it?
+    The walk goes up the def-use chain from each call's operands inside its
+    computation, through layout ops and matmul-free fusions, and stops at
+    the producing matmul, at parameters and at control flow — what lies
+    beyond the projection that made q/k/v is not "in front of" the call.
+    """
+    comps: Dict[str, Dict[str, tuple]] = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        header = _COMPUTATION_RE.match(line)  # unindented; instructions are
+        if header:
+            cur = comps.setdefault(header.group(1), {})
+        elif cur is not None and (m := _INSTR_RE.match(line)):
+            cur[m.group("name")] = (
+                m.group("op"), re.findall(r"%([\w.\-]+)", m.group("args")),
+                line.strip())
+    has_matmul = {name: any(_MATMUL_RE.search(i[2]) for i in body.values())
+                  for name, body in comps.items()}
+
+    found = []
+    for body in comps.values():
+        calls = [i for i in body.values()
+                 if i[0] == "custom-call" and f'"{target}"' in i[2]]
+        todo = [o for _, ops, _ in calls for o in ops]
+        seen = set()
+        while todo:
+            name = todo.pop()
+            if name in seen or name not in body:
+                continue
+            seen.add(name)
+            op, ops, line = body[name]
+            if op.startswith("all-gather"):
+                found.append(line)
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            if op in ("convolution", "dot", "parameter", "while", "call",
+                      "conditional", "custom-call") or (
+                    called and has_matmul.get(called.group(1))):
+                continue
+            todo.extend(ops)
+    return found
+
+
 def verify_spec_transition(mesh, shape, src, dst, dtype=None):
     """Assert XLA realizes a src→dst ShardSpec transition with the collective
     the NodeStatus algebra predicts (spec.predict_collective).

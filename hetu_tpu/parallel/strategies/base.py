@@ -35,6 +35,11 @@ def _fit(spec: P, leaf, mesh: Mesh) -> NamedSharding:
         for a in axes:
             k *= mesh.shape[a]
         dims.append(entry if leaf.shape[i] % k == 0 else None)
+    # canonical form, as jit writes its outputs' specs: no trailing Nones.
+    # P('tp', None) and P('tp') shard alike but key jit's cache apart, so a
+    # state placed with the long form compiles its step twice.
+    while dims and dims[-1] is None:
+        dims.pop()
     return NamedSharding(mesh, P(*dims))
 
 

@@ -1,15 +1,25 @@
 """ctypes binding + on-demand build of the C++ PS core.
 
 Reference analog: python/hetu/_base.py loading _LIB/libps.so via ctypes and
-ps-lite/src/python_binding.cc (151 LoC C API).  We compile csrc/hetu_ps.cpp
-with g++ on first use (no cmake needed for one TU) into
-hetu_tpu/ps/_build/libhetu_ps.so.
+ps-lite/src/python_binding.cc (151 LoC C API).  We compile csrc/*.cpp with
+g++ on first use (no cmake needed for four TUs) into
+hetu_tpu/ps/_build/libhetu_ps.<hash of the sources>.so.
+
+The library's name carries a hash of the source files' CONTENT, so a
+library is only ever loaded if it was built from exactly the sources on
+disk (a copied checkout may carry a ``_build/`` from other sources and any
+mtimes), and it is written under a temporary name and renamed into place,
+so several processes starting on a fresh copy cannot tear each other's
+output.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import os
 import subprocess
+import tempfile
 import threading
 from pathlib import Path
 
@@ -17,23 +27,40 @@ _HERE = Path(__file__).resolve().parent
 _CSRC = _HERE.parent.parent / "csrc"
 _SRCS = [_CSRC / "hetu_ps.cpp", _CSRC / "hetu_ps_van.cpp",
          _CSRC / "hetu_ps_group.cpp", _CSRC / "hetu_ps_rcache.cpp"]
-_HDRS = [_CSRC / "hetu_ps_dtype.h"]  # staleness only (not passed to g++)
+_HDRS = [_CSRC / "hetu_ps_dtype.h"]  # hashed, not passed to g++
 _BUILD = _HERE / "_build"
-_SO = _BUILD / "libhetu_ps.so"
+_CXX = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lock = threading.Lock()
 _lib = None
 _err = None
 
 
-def _build() -> None:
+def _so_path() -> Path:
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    for src in _SRCS + _HDRS:
+        h.update(src.name.encode())
+        h.update(src.read_bytes())
+    return _BUILD / f"libhetu_ps.{h.hexdigest()[:16]}.so"
+
+
+def _build() -> Path:
+    so = _so_path()
+    if so.exists():
+        return so
     _BUILD.mkdir(parents=True, exist_ok=True)
-    newest = max(src.stat().st_mtime for src in _SRCS + _HDRS)
-    if _SO.exists() and _SO.stat().st_mtime >= newest:
-        return
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           *[str(s) for s in _SRCS], "-o", str(_SO)]
-    subprocess.run(cmd, check=True, capture_output=True, text=True)
+    fd, tmp = tempfile.mkstemp(dir=_BUILD, prefix=so.name + ".", suffix=".tmp")
+    os.close(fd)
+    try:
+        r = subprocess.run([*_CXX, *[str(s) for s in _SRCS], "-o", tmp],
+                           capture_output=True, text=True)
+        if r.returncode:
+            raise RuntimeError(f"g++ failed building {so.name}:\n{r.stderr}")
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return so
 
 
 def _load():
@@ -42,9 +69,8 @@ def _load():
         if _lib is not None or _err is not None:
             return _lib
         try:
-            _build()
-            lib = ctypes.CDLL(str(_SO))
-        except Exception as e:  # pragma: no cover
+            lib = ctypes.CDLL(str(_build()))
+        except (OSError, RuntimeError) as e:  # no g++, failed build, bad .so
             _err = e
             return None
         c = ctypes
@@ -274,4 +300,7 @@ lib = _Lazy()
 
 
 def available() -> bool:
+    """Whether the native library built and loaded.  For tests that skip
+    without a toolchain; code that NEEDS the library just uses ``lib`` and
+    gets the build error raised."""
     return _load() is not None

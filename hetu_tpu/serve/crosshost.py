@@ -86,6 +86,7 @@ from hetu_tpu.serve import migrate as _migrate
 from hetu_tpu.serve.metrics import ServeMetrics
 from hetu_tpu.serve.pool import _MIG_SEQ
 from hetu_tpu.telemetry import trace
+from hetu_tpu.utils import platform as _platform
 
 # controller-allocated control channels ('CHCT'); migration transfers get
 # their own base ('MIG3'), disjoint from serve/pool.py's in-process base
@@ -865,6 +866,7 @@ def member_main(config_path: str) -> int:
     import faulthandler
     import signal as _signal
     faulthandler.register(_signal.SIGUSR1)  # live-stack dump to stderr
+    _platform.enable_compile_cache()
     spec = MemberSpec.from_json(open(config_path).read())
     harness = MemberHarness(spec)
     print("READY", spec.slot, flush=True)
@@ -947,6 +949,24 @@ class CrossProcessServingPool:
         from hetu_tpu.ps import van
         if n_members < 1:
             raise ValueError("a serving pool needs at least one member")
+        # One process per chip.  Members run on CPU when their
+        # environment says so (member_env={"JAX_PLATFORMS": "cpu"}, or
+        # the same inherited from this process); otherwise each member
+        # is a TPU process and the pool hands member i chip i of this
+        # host, and nothing else — refusing, before anything is spawned,
+        # more members than there are chips.
+        self._member_env = dict(member_env) if member_env else None
+        self.members_on_chips = (self._member_env or {}).get(
+            "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+        ).strip().lower() != "cpu"
+        if self.members_on_chips:
+            chips = _platform.local_tpu_chips()
+            if n_members > chips:
+                raise ValueError(
+                    f"{n_members} members need one TPU chip each and this "
+                    f"host has {chips}: a chip belongs to one process at "
+                    f"a time (pass member_env={{'JAX_PLATFORMS': 'cpu'}} "
+                    f"for CPU members)")
         migrate_codec = _migrate.check_codec(migrate_codec)
         self._van = van
         self._own_van = own_van
@@ -1008,9 +1028,6 @@ class CrossProcessServingPool:
             if ledger_table is not None else _mb.fresh_table_id()
         self._ledger_rows = int(ledger_rows)
         self._spawn_timeout_s = float(spawn_timeout_s)
-        # e.g. {"JAX_PLATFORMS": "cpu"} — a bench on an accelerator box
-        # keeps member processes off the chip the controller holds
-        self._member_env = dict(member_env) if member_env else None
         self.metrics = metrics if metrics is not None else ServeMetrics()
         self._lock = threading.RLock()
         self._poll_lock = threading.Lock()
@@ -1380,9 +1397,17 @@ class CrossProcessServingPool:
         from pathlib import Path
         cfg = Path(self.workdir) / f"member_{slot}_{cid}.json"
         cfg.write_text(spec.to_json())
+        env = self._member_env
+        if self.members_on_chips:
+            if _platform.backend_initialized():
+                raise RuntimeError(
+                    "this controller process holds a JAX backend, and with "
+                    "it the chips its members need: keep the controller "
+                    "off jax (or force members onto CPU with member_env)")
+            env = {**(env or {}), **_platform.chip_env(slot)}
         proc = spawn_module(self.workdir, f"member_{slot}_{cid}",
                             "hetu_tpu.serve.crosshost", [str(cfg)],
-                            extra_env=self._member_env,
+                            extra_env=env,
                             timeout_s=self._spawn_timeout_s)
         self.procs[slot] = proc
         ch = self._ctrl_chan(

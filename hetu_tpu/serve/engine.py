@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from hetu_tpu.parallel.mesh import AXIS_TP
+from hetu_tpu.parallel.mesh import AXIS_TP, mesh_context
 from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
     KVCache, KVCacheSpec, PagedKVCache, pow2_ceil,
@@ -178,7 +178,7 @@ class ServeEngine:
             self.metrics.inc("prefill_compiles")
             trace.instant("serve.recompile",
                           {"kind": "prefill", "bucket": s})
-        with trace.span("serve.prefill") as sp:
+        with trace.span("serve.prefill") as sp, mesh_context(self.mesh):
             sp.set("slot", int(slot))
             sp.set("tokens", n)
             sp.set("bucket", s)

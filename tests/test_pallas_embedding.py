@@ -1,5 +1,5 @@
 """Pallas embedding gather / scatter-add / top-k gating kernels vs the XLA
-oracles (interpret mode on CPU; compiled path needs a real chip).
+oracles (interpret mode on CPU; chip_smoke.py runs them compiled).
 
 Reference kernels replaced: src/ops/EmbeddingLookUp.cu (+ its scatter-add
 gradient) and src/ops/TopKIdx.cu — SURVEY §2.2 row 28's named Pallas gaps.
@@ -62,26 +62,26 @@ def test_scatter_is_gather_transpose():
 def test_topk_gating_matches_lax(k):
     rng = np.random.default_rng(3)
     logits = jnp.asarray(rng.standard_normal((512, 16)), jnp.float32)
-    gates, idx = topk_gating(logits, k, interpret="kernel")
+    gates, idx = topk_gating(logits, k, kernel=True)
     want_g, want_i = ops.top_k_idx_gate(logits, k)
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_i))
     np.testing.assert_allclose(np.asarray(gates), np.asarray(want_g),
                                rtol=1e-5)
-    # the large-T XLA fallback (interpret=True) must agree with the kernel
-    xg, xi = topk_gating(logits, k, interpret=True)
+    # the XLA form (kernel=False) must agree with the kernel
+    xg, xi = topk_gating(logits, k, kernel=False)
     np.testing.assert_array_equal(np.asarray(xi), np.asarray(idx))
     np.testing.assert_allclose(np.asarray(xg), np.asarray(gates), rtol=1e-5)
 
 
 def test_topk_gating_ties_resolve_low_index():
     logits = jnp.asarray([[1.0, 5.0, 5.0, 0.0]], jnp.float32)
-    _, idx = topk_gating(logits, 2, block_tokens=1, interpret="kernel")
+    _, idx = topk_gating(logits, 2, block_tokens=1, kernel=True)
     assert idx.tolist() == [[1, 2]]
 
 
 def test_topk_rejects_indivisible_block():
     with pytest.raises(ValueError, match="divisible"):
-        topk_gating(jnp.zeros((10, 8)), 2, block_tokens=4, interpret=True)
+        topk_gating(jnp.zeros((10, 8)), 2, block_tokens=4, kernel=False)
 
 
 def test_topk_gating_grad_matches_lax():
@@ -91,7 +91,7 @@ def test_topk_gating_grad_matches_lax():
     g_out = jnp.asarray(rng.standard_normal((32, 3)), jnp.float32)
 
     def f_pallas(x):
-        gates, _ = topk_gating(x, 3, interpret="kernel")
+        gates, _ = topk_gating(x, 3, kernel=True)
         return jnp.sum(gates * g_out)
 
     def f_lax(x):
@@ -103,25 +103,52 @@ def test_topk_gating_grad_matches_lax():
                                rtol=1e-5, atol=1e-7)
 
 
-def test_routed_gather_vjp_and_invalid_ids():
+@pytest.mark.parametrize("kernel", [True, False])
+def test_routed_gather_vjp_and_invalid_ids(kernel):
     """routed_gather: fwd zero-rows for -1/oob, bwd scatter-adds dups and
-    drops invalid — matches a dense one-hot oracle."""
+    drops invalid — matches a dense one-hot oracle, through the Pallas
+    kernels (interpret mode here) and through the XLA form alike."""
     rng = np.random.default_rng(8)
     table = jnp.asarray(rng.standard_normal((16, 8)), jnp.float32)
     ids = jnp.asarray([3, 3, -1, 15, 0, 99, 7, 3], jnp.int32)
     from hetu_tpu.ops.pallas_kernels import routed_gather
 
-    out = routed_gather(table, ids, interpret=True)
+    out = routed_gather(table, ids, kernel=kernel)
     valid = (np.asarray(ids) >= 0) & (np.asarray(ids) < 16)
     want = np.where(valid[:, None],
                     np.asarray(table)[np.clip(np.asarray(ids), 0, 15)], 0)
     np.testing.assert_allclose(np.asarray(out), want, rtol=1e-6)
 
     g = jnp.asarray(rng.standard_normal((8, 8)), jnp.float32)
-    dt = jax.grad(lambda t: jnp.sum(routed_gather(t, ids, interpret=True)
+    dt = jax.grad(lambda t: jnp.sum(routed_gather(t, ids, kernel=kernel)
                                     * g))(table)
     want_dt = np.zeros((16, 8), np.float32)
     for i, r in enumerate(np.asarray(ids)):
         if 0 <= r < 16:
             want_dt[r] += np.asarray(g)[i]
     np.testing.assert_allclose(np.asarray(dt), want_dt, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_row_group_kernels_off_the_tile_grid(dtype):
+    """Row counts, id counts and ids that do not line up with the 8/16-row
+    tile groups the kernels move: a table whose last group is partial, an
+    id count that is not a multiple of the group, negative and out-of-range
+    ids (which sort to both ends of the scatter's sentinel runs)."""
+    rng = np.random.default_rng(11)
+    V, D, N = 37, 24, 21
+    table = jnp.asarray(rng.standard_normal((V, D)), dtype)
+    ids = jnp.asarray(rng.integers(-3, V + 3, N), jnp.int32).at[0].set(V - 1)
+    valid = np.asarray((ids >= 0) & (ids < V))
+    safe = np.clip(np.asarray(ids), 0, V - 1)
+    got = embedding_gather(table, ids, interpret=True)
+    want = np.where(valid[:, None], np.asarray(table, np.float32)[safe], 0)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+
+    grads = jnp.asarray(rng.standard_normal((N, D)), dtype)
+    got = embedding_scatter_add(grads, ids, V, interpret=True)
+    want = np.zeros((V, D), np.float32)
+    np.add.at(want, safe[valid], np.asarray(grads, np.float32)[valid])
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, rtol=tol,
+                               atol=tol)

@@ -299,7 +299,7 @@ class TestQuantizedPsum:
         mesh = _dp_mesh()
 
         @partial(coll.shard_map, mesh=mesh, in_specs=P("dp"),
-                 out_specs=P(), check_rep=False)
+                 out_specs=P(), check_vma=False)
         def f(x):
             return coll.quantized_psum(x, "dp", **kw)
 
@@ -339,7 +339,7 @@ class TestQuantizedPsum:
         x = np.ones((len(jax.devices()), 8), np.float32)
 
         @partial(coll.shard_map, mesh=mesh, in_specs=P("dp"),
-                 out_specs=P(), check_rep=False)
+                 out_specs=P(), check_vma=False)
         def f(x):
             return coll.quantized_pmean(x, "dp", wire="int8")
 

@@ -207,7 +207,8 @@ def test_eos_evicts_and_frees_slot(gpt):
     sched = ContinuousBatchingScheduler(engine)
     req = Request(prompt=prompt, max_tokens=10, eos_id=eos)
     out = sched.run([req])
-    assert out[req.rid] == ref[:4]          # stopped AT the eos token
+    # stopped AT the eos token's FIRST occurrence (greedy streams repeat)
+    assert out[req.rid] == ref[:ref.index(eos) + 1]
     assert engine.cache.num_free == 1       # slot reclaimed
 
 
