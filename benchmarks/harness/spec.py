@@ -1,0 +1,78 @@
+"""Reading the benchmark's data files: manifest, configurations, traffic
+mixes and per-layer metrics, each found by the name ``BENCHMARK.json``
+gives it.  Adding a cell, a configuration, a mix or a metric is adding a
+file and an entry; nothing here names one."""
+
+from __future__ import annotations
+
+import json
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+BENCH = ROOT / "benchmarks"
+
+
+def _read(path: Path) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def _merge(base: dict, over: dict) -> dict:
+    out = dict(base)
+    for k, v in over.items():
+        out[k] = _merge(out[k], v) if isinstance(v, dict) and isinstance(
+            out.get(k), dict) else v
+    return out
+
+
+def manifest() -> dict:
+    return _read(ROOT / "BENCHMARK.json")
+
+
+def cell(man: dict, name: str) -> dict:
+    for w in man["workloads"]:
+        if w["name"] == name:
+            return w
+    raise KeyError(f"no workload {name!r} in BENCHMARK.json; it has "
+                   f"{[w['name'] for w in man['workloads']]}")
+
+
+def config(man: dict, name: str, *, rehearse: bool = False) -> dict:
+    entry = next(c for c in man["configs"] if c["name"] == name)
+    cfg = _read(ROOT / entry["file"])
+    if rehearse:
+        # tiny widths for the CPU rehearsal; never a measurement
+        cfg = _merge(cfg, cfg["rehearse"])
+    cfg["name"] = name
+    return cfg
+
+
+def traffic(name: str, *, rehearse: bool = False) -> dict:
+    tr = _read(BENCH / "traffic" / f"{name}.json")
+    if rehearse:
+        tr = _merge(tr, tr.get("rehearse", {}))
+    tr["name"] = name
+    return tr
+
+
+def metrics_of(entries, cell_name: str) -> list:
+    """The entries of ``end_to_end`` or ``per_layer`` a cell reports."""
+    return [m for m in entries
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def layer_metric_file(name: str) -> dict:
+    """How a per-layer metric is read: ``{"reader": ..., "params": {...}}``
+    from ``benchmarks/layer_metrics/<name>.json``.  ``BENCHMARK.json`` alone
+    says what the metric is (layer, unit, moves, cells).  A name with
+    suffixes (``decode_step_ms.batch``) that has no file of its own is read
+    as its stem is (``decode_step_ms.json``), so the same quantity in
+    another family of cells needs an entry and no file."""
+    parts = name.split(".")
+    for n in range(len(parts), 0, -1):
+        path = BENCH / "layer_metrics" / (".".join(parts[:n]) + ".json")
+        if path.is_file():
+            return _read(path)
+    raise FileNotFoundError(
+        f"no file for per-layer metric {name!r} under "
+        f"{BENCH / 'layer_metrics'}")
