@@ -178,10 +178,9 @@ class ServeEngine:
             self.metrics.inc("prefill_compiles")
             trace.instant("serve.recompile",
                           {"kind": "prefill", "bucket": s})
-        with trace.span("serve.prefill") as sp, mesh_context(self.mesh):
-            sp.set("slot", int(slot))
-            sp.set("tokens", n)
-            sp.set("bucket", s)
+        with trace.span("serve.prefill", {"slot": int(slot), "tokens": n,
+                                          "bucket": s}), \
+                mesh_context(self.mesh):
             ids = np.zeros((1, s), np.int32)
             ids[0, :n] = prompt
             k, v, first = self._prefill_fn(
@@ -211,24 +210,25 @@ class ServeEngine:
             self._decode_fn = self._build_decode()
             self.metrics.inc("decode_compiles")
             trace.instant("serve.recompile", {"kind": "decode"})
-        with trace.span("serve.decode") as sp:
-            if trace.enabled():  # the reduction is attr-only: skip when off
-                sp.set("active", int(self.active.sum()))
-            k, v, nxt = self._decode_fn(
-                self.params, self.cache.k, self.cache.v,
-                jnp.asarray(self.last_tokens),
-                jnp.asarray(self.cache.lengths))
-            # host fetch = the sync point; keep it inside the span (see
-            # prefill)
-            nxt = np.asarray(nxt)
-        self.cache.update(k, v)
-        out = {}
-        for slot in np.nonzero(self.active)[0]:
-            self.cache.lengths[slot] += 1
-            self.last_tokens[slot] = nxt[slot]
-            out[int(slot)] = int(nxt[slot])
-        self.metrics.inc("decode_steps")
-        self.metrics.observe_decode(len(out))
+        # the paged engine's seams (there ``prep`` has work to do; here
+        # the operands are the engine's own arrays)
+        with trace.span("serve.decode"):
+            with trace.span("serve.decode.launch"):
+                k, v, nxt = self._decode_fn(
+                    self.params, self.cache.k, self.cache.v,
+                    jnp.asarray(self.last_tokens),
+                    jnp.asarray(self.cache.lengths))
+            with trace.span("serve.decode.fetch"):
+                nxt = np.asarray(nxt)  # the host blocked on the device
+            with trace.span("serve.decode.post"):
+                self.cache.update(k, v)
+                out = {}
+                for slot in np.nonzero(self.active)[0]:
+                    self.cache.lengths[slot] += 1
+                    self.last_tokens[slot] = nxt[slot]
+                    out[int(slot)] = int(nxt[slot])
+                self.metrics.inc("decode_steps")
+                self.metrics.observe_decode(len(out))
         return out
 
     # ---- live-slot migration ----
@@ -601,65 +601,74 @@ class PagedServeEngine:
         cur = self._cursors.get(slot)
         if cur is None:
             raise ValueError(f"slot {slot} has no prefill in progress")
-        if not cur.matched:
-            self._match_on_first_chunk(slot, cur)
-        start = cur.pos
-        end = min(cur.n, (start // self.prefill_chunk + 1)
-                  * self.prefill_chunk)
-        size = end - start
-        s = self.chunk_bucket_for(size)
-        ps = self.cache.page_size
-        n_table = self.cache.pages_per_slot
-        # boundary path: the PADDED window [start, start+s) runs past
-        # the slot's own page view — use the extended-view executable
-        # family so nothing clamps (see _build_chunk)
-        if start + s > n_table * ps:
-            n_table += -(-self.prefill_chunk // ps)
-            if self._chunk_fn_ext is None:
-                self._chunk_fn_ext = self._build_chunk(n_table)
-            chunk_fn = self._chunk_fn_ext
-        else:
-            if self._chunk_fn is None:
-                self._chunk_fn = self._build_chunk(n_table)
-            chunk_fn = self._chunk_fn
-        if (s, n_table) not in self._seen_chunk_buckets:
-            self._seen_chunk_buckets.add((s, n_table))
-            self.metrics.inc("prefill_compiles")
-            trace.instant("serve.recompile",
-                          {"kind": "prefill_chunk", "bucket": s})
-        cow0 = self.cache.cow_copies
-        wp, wo = self.cache.prepare_write(slot, start, size)
-        wp, wo = self.cache.padded_write_map(wp, wo, s)
-        aux = np.zeros(3 * s + n_table + 2, np.int32)
-        aux[:size] = cur.prompt[start:end]
-        aux[s:2 * s] = wp
-        aux[2 * s:3 * s] = wo
-        t = self.cache.tables[slot]
-        aux[3 * s:3 * s + len(t)] = t
-        aux[3 * s + n_table] = start
-        aux[3 * s + n_table + 1] = size - 1
-        with trace.span("serve.prefill_chunk") as sp:
-            sp.set("slot", int(slot))
-            sp.set("start", int(start))
-            sp.set("tokens", int(size))
-            sp.set("bucket", int(s))
-            k, v, tok = chunk_fn(
-                self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
-            tok = int(tok)  # sync point inside the span (see ServeEngine)
-        self.cache.update(k, v)
-        self.cache.lengths[slot] = end
-        cur.pos = end
-        self.metrics.inc("prefill_tokens", size)
-        self.metrics.inc("prefill_chunks")
-        if self.cache.cow_copies > cow0:
-            self.metrics.inc("cow_copies", self.cache.cow_copies - cow0)
-        if not cur.done:
-            return None
-        del self._cursors[slot]
-        self.cache.register_prefix(slot, cur.prompt)
-        self.last_tokens[slot] = tok
-        self.active[slot] = True
-        return tok
+        # one span for the whole chunk, tiled by its four seams: prep is
+        # the host's work before the program can be called (prefix match
+        # on the first chunk, pages, operands), launch hands the operands
+        # to the device and calls it, fetch is the host blocked on the
+        # device, post the books (and the prefix index on the last chunk).
+        # Inline, not a helper: see decode()
+        with trace.span("serve.prefill_chunk", {"slot": int(slot)}):
+            with trace.span("serve.prefill_chunk.prep"):
+                if not cur.matched:
+                    self._match_on_first_chunk(slot, cur)
+                start = cur.pos
+                end = min(cur.n, (start // self.prefill_chunk + 1)
+                          * self.prefill_chunk)
+                size = end - start
+                s = self.chunk_bucket_for(size)
+                ps = self.cache.page_size
+                n_table = self.cache.pages_per_slot
+                # boundary path: the PADDED window [start, start+s) runs past
+                # the slot's own page view — use the extended-view executable
+                # family so nothing clamps (see _build_chunk)
+                if start + s > n_table * ps:
+                    n_table += -(-self.prefill_chunk // ps)
+                    if self._chunk_fn_ext is None:
+                        self._chunk_fn_ext = self._build_chunk(n_table)
+                    chunk_fn = self._chunk_fn_ext
+                else:
+                    if self._chunk_fn is None:
+                        self._chunk_fn = self._build_chunk(n_table)
+                    chunk_fn = self._chunk_fn
+                if (s, n_table) not in self._seen_chunk_buckets:
+                    self._seen_chunk_buckets.add((s, n_table))
+                    self.metrics.inc("prefill_compiles")
+                    trace.instant("serve.recompile",
+                                  {"kind": "prefill_chunk", "bucket": s})
+                cow0 = self.cache.cow_copies
+                wp, wo = self.cache.prepare_write(slot, start, size)
+                wp, wo = self.cache.padded_write_map(wp, wo, s)
+                aux = np.zeros(3 * s + n_table + 2, np.int32)
+                aux[:size] = cur.prompt[start:end]
+                aux[s:2 * s] = wp
+                aux[2 * s:3 * s] = wo
+                t = self.cache.tables[slot]
+                aux[3 * s:3 * s + len(t)] = t
+                aux[3 * s + n_table] = start
+                aux[3 * s + n_table + 1] = size - 1
+            with trace.span("serve.prefill_chunk.launch",
+                            {"start": int(start), "tokens": int(size),
+                             "bucket": int(s)}):
+                k, v, tok = chunk_fn(
+                    self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
+            with trace.span("serve.prefill_chunk.fetch"):
+                tok = int(tok)  # the host blocked on the device
+            with trace.span("serve.prefill_chunk.post"):
+                self.cache.update(k, v)
+                self.cache.lengths[slot] = end
+                cur.pos = end
+                self.metrics.inc("prefill_tokens", size)
+                self.metrics.inc("prefill_chunks")
+                if self.cache.cow_copies > cow0:
+                    self.metrics.inc("cow_copies",
+                                     self.cache.cow_copies - cow0)
+                if not cur.done:
+                    return None
+                del self._cursors[slot]
+                self.cache.register_prefix(slot, cur.prompt)
+                self.last_tokens[slot] = tok
+                self.active[slot] = True
+                return tok
 
     def prefill(self, slot: int, prompt_ids) -> int:
         """Whole-prompt prefill (the slot-engine-compatible surface):
@@ -686,66 +695,79 @@ class PagedServeEngine:
         act = np.nonzero(self.active)[0]
         if len(act) == 0:
             return {}
-        if (self.cache.lengths[act] >= self.cache.max_len).any():
-            raise RuntimeError(
-                "an active slot is at max_len; the scheduler must evict "
-                "before decoding further")
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode()
-        cow0 = self.cache.cow_copies
-        bb = pow2_ceil(len(act), self.cache.num_slots)
-        sl = np.zeros(bb, np.int32)
-        sl[:len(act)] = act
-        # grow/COW the write target of every active slot BEFORE the step
-        wp = np.zeros(bb, np.int32)
-        wo = np.zeros(bb, np.int32)
-        for i, slot in enumerate(act):
-            p, o = self.cache.prepare_write(
-                int(slot), int(self.cache.lengths[slot]), 1)
-            wp[i], wo[i] = p[0], o[0]
-        # page bucket over ACTIVE slots only (after prepare_write grew
-        # them): an inactive mid-chunked-prefill long prompt must not
-        # inflate every interleaved decode's gather to its table width —
-        # that would re-create exactly the long-arrival latency spike
-        # the chunk interleave exists to remove
-        n_pg = pow2_ceil(
-            max(len(self.cache.tables[int(s)]) for s in act),
-            self.cache.pages_per_slot)
-        if (bb, n_pg) not in self._seen_page_buckets:
-            self._seen_page_buckets.add((bb, n_pg))
-            self.metrics.inc("decode_compiles")
-            trace.instant("serve.recompile",
-                          {"kind": "decode", "pages": int(n_pg),
-                           "batch": int(bb)})
-        aux = np.zeros((bb, n_pg + 4), np.int32)
-        for i, slot in enumerate(sl):
-            t = self.cache.tables[slot][:n_pg]
-            aux[i, :len(t)] = t
-        aux[:, n_pg] = self.cache.lengths[sl]
-        aux[:, n_pg + 1] = self.last_tokens[sl]
-        aux[:, n_pg + 2] = wp
-        aux[:, n_pg + 3] = wo
-        with trace.span("serve.decode") as sp:
-            if trace.enabled():
-                sp.set("active", int(len(act)))
-                sp.set("pages", int(n_pg))
-            k, v, nxt = self._decode_fn(
-                self.params, self.cache.k, self.cache.v,
-                jnp.asarray(aux))
-            nxt = np.asarray(nxt)  # host fetch = sync point, in the span
-        self.cache.update(k, v)
-        out = {}
-        for i, slot in enumerate(act):
-            self.cache.lengths[slot] += 1
-            self.last_tokens[slot] = nxt[i]
-            out[int(slot)] = int(nxt[i])
-        if self.cache.cow_copies > cow0:
-            self.metrics.inc("cow_copies", self.cache.cow_copies - cow0)
-        self.metrics.inc("decode_steps")
-        self.metrics.observe_decode(len(out))
-        self.metrics.set_gauge("pages_in_use", self.cache.pages_in_use)
-        self.metrics.set_gauge("prefix_entries", self.cache.prefix_entries)
-        return out
+        # one span for the whole round, tiled by its four seams: prep builds
+        # the step's operands on the host, launch hands them to the device
+        # and calls the program, fetch is the host blocked on the device,
+        # post the books.  Inline on purpose: with the round in a helper
+        # method the first call of each of the 28 decode programs took
+        # 0.16 s longer on the v5e (warm-up 11.6 s -> 16.2 s; PERF.md, PR 25)
+        with trace.span("serve.decode", {"active": len(act)}):
+            with trace.span("serve.decode.prep"):
+                if (self.cache.lengths[act] >= self.cache.max_len).any():
+                    raise RuntimeError(
+                        "an active slot is at max_len; the scheduler must "
+                        "evict before decoding further")
+                if self._decode_fn is None:
+                    self._decode_fn = self._build_decode()
+                cow0 = self.cache.cow_copies
+                bb = pow2_ceil(len(act), self.cache.num_slots)
+                sl = np.zeros(bb, np.int32)
+                sl[:len(act)] = act
+                # grow/COW the write target of every active slot BEFORE
+                # the step
+                wp = np.zeros(bb, np.int32)
+                wo = np.zeros(bb, np.int32)
+                for i, slot in enumerate(act):
+                    p, o = self.cache.prepare_write(
+                        int(slot), int(self.cache.lengths[slot]), 1)
+                    wp[i], wo[i] = p[0], o[0]
+                # page bucket over ACTIVE slots only (after prepare_write
+                # grew them): an inactive mid-chunked-prefill long prompt
+                # must not inflate every interleaved decode's gather to its
+                # table width — that would re-create exactly the
+                # long-arrival latency spike the chunk interleave exists to
+                # remove
+                n_pg = pow2_ceil(
+                    max(len(self.cache.tables[int(s)]) for s in act),
+                    self.cache.pages_per_slot)
+                if (bb, n_pg) not in self._seen_page_buckets:
+                    self._seen_page_buckets.add((bb, n_pg))
+                    self.metrics.inc("decode_compiles")
+                    trace.instant("serve.recompile",
+                                  {"kind": "decode", "pages": int(n_pg),
+                                   "batch": int(bb)})
+                aux = np.zeros((bb, n_pg + 4), np.int32)
+                for i, slot in enumerate(sl):
+                    t = self.cache.tables[slot][:n_pg]
+                    aux[i, :len(t)] = t
+                aux[:, n_pg] = self.cache.lengths[sl]
+                aux[:, n_pg + 1] = self.last_tokens[sl]
+                aux[:, n_pg + 2] = wp
+                aux[:, n_pg + 3] = wo
+            with trace.span("serve.decode.launch",
+                            {"pages": int(n_pg), "batch": int(bb)}):
+                k, v, nxt = self._decode_fn(
+                    self.params, self.cache.k, self.cache.v,
+                    jnp.asarray(aux))
+            with trace.span("serve.decode.fetch"):
+                nxt = np.asarray(nxt)  # the host blocked on the device
+            with trace.span("serve.decode.post"):
+                self.cache.update(k, v)
+                out = {}
+                for i, slot in enumerate(act):
+                    self.cache.lengths[slot] += 1
+                    self.last_tokens[slot] = nxt[i]
+                    out[int(slot)] = int(nxt[i])
+                if self.cache.cow_copies > cow0:
+                    self.metrics.inc("cow_copies",
+                                     self.cache.cow_copies - cow0)
+                self.metrics.inc("decode_steps")
+                self.metrics.observe_decode(len(out))
+                self.metrics.set_gauge("pages_in_use",
+                                       self.cache.pages_in_use)
+                self.metrics.set_gauge("prefix_entries",
+                                       self.cache.prefix_entries)
+            return out
 
     # ---- live-slot migration (same contract as ServeEngine) ----
     def export_slots(self, slot_ids) -> list:

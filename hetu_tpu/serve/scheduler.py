@@ -192,6 +192,7 @@ class ContinuousBatchingScheduler:
         self._lock = threading.Lock()
         self._queue = deque()
         self._running = {}   # slot -> Request
+        self._steps = 0      # ordinal of the next step (its span's id)
         self._accepting = True
         self._reject_status = "shutdown"  # status for post-drain submits
 
@@ -747,9 +748,13 @@ class ContinuousBatchingScheduler:
         (decode is one fused call over every slot: there is no
         per-request attribution)."""
         completed = []
-        with self._lock, trace.span("serve.step") as sp:
-            progressed, admit_exc = self._admit(completed)
-            pf_progressed, pf_exc = self._advance_prefills(completed)
+        with self._lock, trace.span("serve.step",
+                                    {"step": self._steps}) as sp:
+            self._steps += 1
+            with trace.span("serve.admit"):
+                progressed, admit_exc = self._admit(completed)
+            with trace.span("serve.advance_prefills"):
+                pf_progressed, pf_exc = self._advance_prefills(completed)
             progressed = progressed or pf_progressed
             admit_exc = admit_exc or pf_exc
             toks = None
@@ -775,14 +780,15 @@ class ContinuousBatchingScheduler:
                 break
             if toks is not None:
                 progressed = True
-                now = time.monotonic()
-                for slot, req in list(self._running.items()):
-                    req.tokens.append(toks[slot])
-                    if self._should_evict(req, now):
-                        del self._running[slot]
-                        self.engine.release(slot)
-                        self._finish(req, req.status or "ok")
-                        completed.append(req)
+                with trace.span("serve.evict"):
+                    now = time.monotonic()
+                    for slot, req in list(self._running.items()):
+                        req.tokens.append(toks[slot])
+                        if self._should_evict(req, now):
+                            del self._running[slot]
+                            self.engine.release(slot)
+                            self._finish(req, req.status or "ok")
+                            completed.append(req)
             self.metrics.set_gauge("queue_depth", len(self._queue))
             self.metrics.set_gauge("slot_occupancy",
                                    self.engine.cache.occupancy)

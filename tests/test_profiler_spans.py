@@ -1,0 +1,373 @@
+"""The program's spans in the profiler's trace (ISSUE 25).
+
+``telemetry.trace.span`` opens a ``jax.profiler.TraceAnnotation`` named
+``hetu:<span>`` beside whatever the JSONL tracer does, so a profiler session
+is the only switch: while one runs the spans land in the xplane, nested as
+the calls nest; while none runs the program computes the same tokens and
+loss as with the spans taken out, and lowers to the same programs.
+
+One session is recorded per module (a tiny ``PagedServeEngine`` behind the
+scheduler, then three ``Executor.run('train')`` steps) and read back with
+``jax.profiler.ProfileData``; every test that starts a profiler runs under a
+time limit of its own.
+"""
+
+import signal
+from contextlib import contextmanager
+
+import jax
+import numpy as np
+import pytest
+
+from hetu_tpu import optim
+from hetu_tpu.models.gpt import GPTConfig, GPTModel
+from hetu_tpu.serve import (
+    ContinuousBatchingScheduler, PagedServeEngine, Request,
+)
+from hetu_tpu.telemetry import trace
+from hetu_tpu.train.executor import Executor
+
+pytestmark = pytest.mark.telemetry
+
+PROFILER_LIMIT_S = 120
+PREFIX = trace.PROFILER_PREFIX
+SEAMS = ("prep", "launch", "fetch", "post")
+PROMPT_LENS = (5, 20, 33)       # one, two and three chunks of 16
+TRAIN_STEPS = 3
+
+
+@contextmanager
+def time_limit(seconds: int):
+    """SIGALRM after ``seconds``: a profiler that hangs fails its own test
+    and not the run's limit."""
+    def on_alarm(signum, frame):
+        raise TimeoutError(f"profiler test over its {seconds} s limit")
+
+    old = signal.signal(signal.SIGALRM, on_alarm)
+    signal.alarm(seconds)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+
+
+@contextmanager
+def profiled(log_dir):
+    """A profiler session writing under ``log_dir``, under the limit."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0    # the spans, not every Python call
+    with time_limit(PROFILER_LIMIT_S), \
+            jax.profiler.trace(str(log_dir), profiler_options=opts):
+        yield
+
+
+def hetu_threads(log_dir) -> list:
+    """Per host thread that opened any: its ``hetu:`` events as (name, start,
+    end, ids), parents before their children."""
+    from jax.profiler import ProfileData
+
+    path = sorted(log_dir.glob("plugins/profile/*/*.xplane.pb"))[-1]
+    threads = []
+    with time_limit(PROFILER_LIMIT_S):
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                evs = [(e.name.split("#")[0][len(PREFIX):],
+                        float(e.start_ns),
+                        float(e.start_ns) + float(e.duration_ns),
+                        dict(e.stats))
+                       for e in line.events if e.name.startswith(PREFIX)]
+                if evs:
+                    threads.append(sorted(evs, key=lambda e: (e[1], -e[2])))
+    return threads
+
+
+def _model():
+    m = GPTModel(GPTConfig(
+        vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
+        ffn_size=128, max_position=64, dropout_rate=0.0))
+    return m, m.init(jax.random.PRNGKey(0))
+
+
+def _serving(model, variables):
+    # no prefix sharing: the same three prompts run again and again, and
+    # each time every chunk has to run as it did the first time
+    eng = PagedServeEngine(model, variables, num_slots=4, max_len=64,
+                           page_size=8, prefill_chunk=16,
+                           prefix_sharing=False)
+    return eng, ContinuousBatchingScheduler(eng)
+
+
+def _serve(sched) -> list:
+    """The same three requests every time; their tokens."""
+    g = np.random.default_rng(7)
+    reqs = [Request(prompt=[int(t) for t in g.integers(0, 97, n)],
+                    max_tokens=6) for n in PROMPT_LENS]
+    for r in reqs:
+        sched.submit(r)
+    for _ in range(200):
+        if not sched.has_work():
+            break
+        sched.step()
+    assert all(r.status == "ok" for r in reqs)
+    return [list(r.tokens) for r in reqs]
+
+
+def _training(model):
+    ex = Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-3), seed=0)
+    g = np.random.default_rng(3)
+    return ex, (g.integers(0, 97, (2, 32)).astype(np.int32),)
+
+
+def _train(ex, variables, batch):
+    """Three steps from the same fresh state; (losses, state after)."""
+    state = ex.init_state(variables)
+    losses = []
+    for _ in range(TRAIN_STEPS):
+        state, metrics = ex.run("train", state, batch)
+        losses.append(float(metrics["loss"]))
+    return losses, state
+
+
+def _lowered(eng, ex, state, batch) -> dict:
+    """Text of the decode, chunk and train programs as they lower now."""
+    aux_d = jax.ShapeDtypeStruct((2, 4 + 4), np.int32)
+    n_table = eng.cache.pages_per_slot
+    aux_c = jax.ShapeDtypeStruct((3 * 16 + n_table + 2,), np.int32)
+    args = (eng.params, eng.cache.k, eng.cache.v)
+    return {
+        "decode": eng._build_decode().lower(*args, aux_d).as_text(),
+        "chunk": eng._build_chunk(n_table).lower(*args, aux_c).as_text(),
+        "train": ex.lower("train", state, batch).as_text(),
+    }
+
+
+class _Off:
+    """``trace.span`` and ``trace.instant`` taken out: the uninstrumented
+    reference."""
+
+    def __enter__(self):
+        self.kept = trace.span, trace.instant
+        trace.span = lambda *a, **k: trace.NULL_SPAN
+        trace.instant = lambda *a, **k: None
+
+    def __exit__(self, *exc):
+        trace.span, trace.instant = self.kept
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """One profiler session over the serving steps and the train steps,
+    with what the same code gave before it, and without its spans."""
+    model, variables = _model()
+    out = {}
+    with _Off():
+        eng, sched = _serving(model, variables)
+        ex, batch = _training(model)
+        out["tokens_bare"] = _serve(sched)
+        out["loss_bare"], state = _train(ex, variables, batch)
+        out["programs_bare"] = eng.compiled_executables()
+        out["lowered_bare"] = _lowered(eng, ex, state, batch)
+
+    eng, sched = _serving(model, variables)
+    ex, batch = _training(model)
+    out["tokens_no_session"] = _serve(sched)     # also the warm-up
+    out["loss_no_session"], state = _train(ex, variables, batch)
+    out["programs_no_session"] = eng.compiled_executables()
+    out["lowered_no_session"] = _lowered(eng, ex, state, batch)
+
+    log = tmp_path_factory.mktemp("xplane")
+    with profiled(log):
+        out["tokens_session"] = _serve(sched)
+        out["loss_session"], state = _train(ex, variables, batch)
+        out["lowered_session"] = _lowered(eng, ex, state, batch)
+    out["programs_session"] = eng.compiled_executables()
+    threads = hetu_threads(log)
+    assert len(threads) == 1, "one thread did the work"
+    out["events"] = threads[0]
+    return out
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+def _children(events, parent):
+    """Events strictly inside ``parent`` and not ``parent`` itself, in
+    order of start."""
+    return [e for e in events
+            if e is not parent and e[1] >= parent[1] and e[2] <= parent[2]]
+
+
+# ------------------------------------------------------- the spans exist
+
+@pytest.mark.parametrize("name", [
+    "serve.step", "serve.admit", "serve.advance_prefills", "serve.evict",
+    "serve.decode", *(f"serve.decode.{s}" for s in SEAMS),
+    "serve.prefill_chunk", *(f"serve.prefill_chunk.{s}" for s in SEAMS),
+    "train.host_to_device", "train.step.train",
+])
+def test_span_is_in_the_xplane(recorded, name):
+    assert _named(recorded["events"], name), name
+
+
+def test_a_compile_inside_a_session_is_a_zero_length_annotation(tmp_path):
+    """``serve.recompile`` and ``train.compile`` are instants: with a
+    session running they are written too, with their ids."""
+    model, variables = _model()
+    eng, sched = _serving(model, variables)
+    ex, batch = _training(model)
+    with profiled(tmp_path):
+        _serve(sched)
+        _train(ex, variables, batch)
+    found = {}
+    for name, a, b, ids in hetu_threads(tmp_path)[0]:
+        found.setdefault(name, []).append((b - a, ids))
+    assert found["train.compile"][0][1] == {"subexecutor": "train"}
+    kinds = {st["kind"] for _, st in found["serve.recompile"]}
+    assert kinds == {"prefill_chunk", "decode"}
+    longest_span = max(d for d, _ in found["serve.step"])
+    assert all(d < longest_span / 10 for d, _ in found["serve.recompile"])
+
+
+# ------------------------------------------------------------- nesting
+
+@pytest.mark.parametrize("parent", ["serve.decode", "serve.prefill_chunk"])
+def test_four_seams_tile_their_parent(recorded, parent):
+    """prep, launch, fetch, post: inside the parent, in that order, not
+    overlapping, and together within 5% of it."""
+    events = recorded["events"]
+    parents = _named(events, parent)
+    assert len(parents) >= 5
+    shares = []
+    for p in parents:
+        kids = _children(events, p)
+        assert [k[0] for k in kids if k[0].count(".") == 2] == \
+            [f"{parent}.{s}" for s in SEAMS]
+        seams = [k for k in kids if k[0].startswith(parent + ".")]
+        for a, b in zip(seams, seams[1:]):
+            assert a[2] <= b[1]
+        shares.append(sum(k[2] - k[1] for k in seams) / (p[2] - p[1]))
+    # the median: a thread descheduled between two seams on a busy test
+    # machine is not the program's gap
+    assert max(shares) <= 1.0 and np.median(shares) >= 0.95
+
+
+def test_scheduler_step_holds_its_phases_in_order(recorded):
+    events = recorded["events"]
+    steps = _named(events, "serve.step")
+    assert len(steps) >= 6
+    engine_calls = 0
+    for st in steps:
+        kids = _children(events, st)
+        top = [k[0] for k in kids
+               if not any(o is not k and o[1] <= k[1] and k[2] <= o[2]
+                          for o in kids)]
+        assert top[:2] == ["serve.admit", "serve.advance_prefills"]
+        assert set(top[2:]) <= {"serve.decode", "serve.evict"}
+        if "serve.decode" in top:
+            assert top[2:] == ["serve.decode", "serve.evict"]
+        engine_calls += sum(k[0] in ("serve.decode", "serve.prefill_chunk")
+                            for k in kids)
+        # a chunk runs inside advance_prefills
+        adv = next(k for k in kids if k[0] == "serve.advance_prefills")
+        for c in (k for k in kids if k[0] == "serve.prefill_chunk"):
+            assert adv[1] <= c[1] and c[2] <= adv[2]
+    every = _named(events, "serve.decode") + \
+        _named(events, "serve.prefill_chunk")
+    assert engine_calls == len(every)   # none outside a step
+
+
+def test_train_spans_follow_each_other(recorded):
+    events = recorded["events"]
+    h2d = _named(events, "train.host_to_device")
+    step = _named(events, "train.step.train")
+    assert len(h2d) == len(step) == TRAIN_STEPS
+    for a, b in zip(h2d, step):
+        assert a[2] <= b[1]
+
+
+# ----------------------------------------------------------------- ids
+
+@pytest.mark.parametrize("name,keys", [
+    ("serve.step", {"step"}),
+    ("serve.decode", {"active"}),
+    ("serve.decode.launch", {"pages", "batch"}),
+    ("serve.prefill_chunk", {"slot"}),
+    ("serve.prefill_chunk.launch", {"start", "tokens", "bucket"}),
+])
+def test_ids_decode_from_the_event(recorded, name, keys):
+    evs = _named(recorded["events"], name)
+    assert evs
+    for e in evs:
+        assert set(e[3]) == keys
+        assert all(isinstance(v, int) for v in e[3].values())
+
+
+def test_ids_say_what_ran(recorded):
+    events = recorded["events"]
+    ordinals = [e[3]["step"] for e in _named(events, "serve.step")]
+    assert ordinals == list(range(ordinals[0], ordinals[0] + len(ordinals)))
+    chunks = _named(events, "serve.prefill_chunk.launch")
+    assert sorted(c[3]["tokens"] for c in chunks) == \
+        sorted([5, 16, 4, 16, 16, 1])    # 5, 20 and 33 in chunks of 16
+    assert all(c[3]["bucket"] == 16 for c in chunks)
+    assert max(e[3]["active"] for e in _named(events, "serve.decode")) == 2
+
+
+# ------------------------------------------- the device side is untouched
+
+@pytest.mark.parametrize("what", ["tokens", "loss", "programs"])
+@pytest.mark.parametrize("state", ["no_session", "session"])
+def test_same_results_as_the_uninstrumented_run(recorded, what, state):
+    assert recorded[f"{what}_{state}"] == recorded[f"{what}_bare"]
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "train"])
+def test_lowered_program_is_the_same_text(recorded, program):
+    bare = recorded["lowered_bare"][program]
+    assert "hetu:" not in bare and len(bare) > 1000
+    assert recorded["lowered_no_session"][program] == bare
+    assert recorded["lowered_session"][program] == bare
+
+
+# ------------------------------------------------------- the two sinks
+
+def test_span_without_a_tracer_is_an_inert_annotation():
+    """With jax loaded and no tracer installed a span is the profiler's
+    annotation and nothing else; ``set`` is swallowed."""
+    assert not trace.enabled()
+    sp = trace.span("anything", {"step": 1})
+    assert isinstance(sp, jax.profiler.TraceAnnotation)
+    with sp as s:
+        assert s.set("k", "v") is s
+    assert trace.instant("nothing", {"kind": "x"}) is None
+
+
+def test_both_sinks_at_once(tmp_path):
+    """The JSONL tracer keeps its record (late ``set`` included) while a
+    session writes the same span into the xplane."""
+    t = trace.enable()
+    try:
+        with profiled(tmp_path):
+            with trace.span("both.sinks", {"step": 4}) as sp:
+                sp.set("late", 1)
+    finally:
+        trace.disable()
+    ev = next(e for e in t.events if e["name"] == "both.sinks")
+    assert ev["args"] == {"step": 4, "late": 1}
+    assert [(name, ids) for name, _, _, ids in hetu_threads(tmp_path)[0]] \
+        == [("both.sinks", {"step": 4})]
+
+
+def test_a_process_without_jax_opens_no_annotation(monkeypatch):
+    """The lookup reads ``sys.modules``; without jax there the off path is
+    the singleton it was."""
+    import sys
+
+    monkeypatch.setattr(trace, "_annotation", False)
+    monkeypatch.setitem(sys.modules, "jax", None)
+    assert trace.span("x") is trace.NULL_SPAN
+    assert trace._annotation is None      # looked up once
+    monkeypatch.setitem(sys.modules, "jax", jax)
+    assert trace.span("y") is trace.NULL_SPAN
