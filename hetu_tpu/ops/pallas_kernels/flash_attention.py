@@ -2,23 +2,49 @@
 
 The reference has no fused attention (its MHA composes batch_matmul +
 softmax ops, layers/attention.py); on TPU the fusion matters because the
-[S, S] score matrix otherwise round-trips HBM.  The forward streams K/V
-blocks through VMEM — grid = (batch*heads, q_blocks, k_blocks) with the k
-dimension innermost, online-softmax state held in VMEM scratch across the
-k iterations — so VMEM usage is O(block_q * D + block_k * D) regardless of
-sequence length.  It also emits the log-sum-exp rows (LSE), which the
-backward uses to recompute probabilities tile-by-tile.
+[S, S] score matrix otherwise round-trips HBM.
 
-Backward is the standard FlashAttention-2 two-kernel scheme:
+One algorithm — online softmax over tiles, forward, and the FlashAttention-2
+two-kernel backward — and two ways of feeding it, chosen from the operand
+shapes (:func:`_resident`), never by a caller:
+
+  * **resident** (a row's operands fit VMEM; every shape up to S of a few
+    thousand): one program owns a whole ROW of tiles.  Forward and dQ run on
+    grid (batch*heads, q_blocks): the program holds its Q block and the whole
+    K and V of its (batch, head), and walks the K/V blocks 0 .. last live one
+    in a ``lax.fori_loop`` whose bound comes from the block's position, so a
+    tile above the causal diagonal is never fetched nor stepped over.  dK/dV
+    runs on grid (batch*heads, k_blocks) with Q, dO, LSE and delta resident,
+    walking the Q blocks from the first live one.  The softmax state and
+    dq^T are loop values; dk and dv accumulate in 2-D VMEM scratch.
+  * **streamed** (S of tens of thousands): grid (batch*heads, q_blocks,
+    k_blocks) with the walked axis innermost, the same tile bodies, the state
+    in 2-D VMEM scratch across the steps, and index maps clamped to the last
+    (first) live block so that a dead step re-names the block already in VMEM
+    and fetches nothing.  VMEM use is O(block_q * D + block_k * D) whatever S.
+
+A tile is held TRANSPOSED, [block_k, block_q]: keys down the sublanes,
+queries along the lanes.  The row statistics of the softmax (m, l, and the
+LSE and delta the backward reads) are then lane-dense [1, block_q] rows, the
+max and the sum over keys are elementwise across vregs instead of cross-lane
+reductions, acc^T [D, block_q] fills whole vregs at D=64, and neither
+backward kernel transposes a probability tile (dv += p^T dO and dk += ds^T q
+are plain matmuls of the transposed tiles).  The only transposes left are of
+the [D, block_q] results, once per Q block.
+
+Either way the walk is split at the diagonal: tiles wholly below it run a
+body with no iota, compare or select; only the tiles the diagonal crosses
+build a mask.  ``scale`` is folded into the Q block once where that is exact
+(a power of two, as 1/8 for D=64) and stays on the f32 scores otherwise.
+Dots run in the input dtype with f32 accumulation; softmax statistics are
+f32.  The forward also emits the log-sum-exp rows (LSE, [batch*heads,
+q_blocks, 1, block_q]), from which the backward recomputes probabilities
+tile by tile:
 
   * delta = rowsum(dO * O)                       (one cheap XLA reduction)
-  * dK/dV kernel: grid (bh, k_blocks, q_blocks), accumulating
-        p   = exp(q k^T * scale - lse)
-        dv += p^T dO
-        ds  = p * (dO v^T - delta) * scale
-        dk += ds^T q
-    in VMEM f32 scratch across the q iterations;
-  * dQ kernel: grid (bh, q_blocks, k_blocks), accumulating dq += ds k.
+  * dK/dV kernel:  p = exp(q k^T * scale - lse);  dv += p^T dO;
+        ds = p * (dO v^T - delta) * scale;  dk += ds^T q
+  * dQ kernel:     dq += ds k.
 
 No O(S^2) tensor ever touches HBM in either direction — this beats the
 reference's training memory profile (its attention materializes scores for
@@ -37,6 +63,7 @@ Interpret mode runs the same kernels on CPU for correctness tests.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -46,67 +73,297 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import AxisType, PartitionSpec as P
 
 from hetu_tpu.parallel.mesh import AXIS_DP, AXIS_TP
+from hetu_tpu.telemetry import trace
 from hetu_tpu.utils.platform import auto_interpret
 
 NEG_INF = -1e30
 
+# What one row's resident operands may take of VMEM, as the pipeline holds
+# them (two buffers each, padded to whole (8, 128) tiles).  With the
+# per-step blocks and a few [block_k, block_q] f32 temporaries on top this
+# stays well under the 16 MiB a v5e kernel is lent by default.
+_RESIDENT_VMEM_BYTES = 6 * 2 ** 20
 
-# ---------------------------------------------------------------- forward
+_NT = (((1,), (1,)), ((), ()))   # a b^T
+_NN = (((1,), (0,)), ((), ()))   # a b
+_TN = (((0,), (0,)), ((), ()))   # a^T b
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                      l_ref, *, block_q: int, block_k: int, scale: float,
-                      causal: bool, causal_offset: int):
-    """Program (bh, qi, ki): one [block_q, block_k] tile of the attention.
 
-    q_ref [block_q, D]; k_ref/v_ref [block_k, D]; o_ref [block_q, D];
-    lse_ref [block_q]; acc/m/l: VMEM scratch carrying online-softmax state
-    across ki.
-    """
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------------------------ the tile walk
+
+class _Walk:
+    """Static facts of one call that every kernel body shares: tile sizes,
+    block counts, the causal offset, and how ``scale`` is applied."""
+
+    def __init__(self, *, s_q, s_k, block_q, block_k, scale, causal):
+        self.bq, self.bk = block_q, block_k
+        self.n_q, self.n_k = s_q // block_q, s_k // block_k
+        self.causal = causal
+        self.offset = s_k - s_q
+        self.scale = scale
+        # q * 2**n is exact in any float dtype: fold the scale into the Q
+        # block once instead of multiplying every f32 score tile
+        self.fold = math.frexp(scale)[0] == 0.5
+        # a row that sees no key at all exists only when s_q > s_k
+        self.guard = causal and self.offset < 0
+
+    def q_block(self, q):
+        return q * jnp.asarray(self.scale, q.dtype) if self.fold else q
+
+    def scores(self, q, k):
+        """The transposed score tile k q^T * scale, [block_k, block_q]."""
+        s = _dot(k, q, _NT)
+        return s if self.fold else s * self.scale
+
+    def ds(self, p, dp, delta):
+        """d(scores) less the folded scale (the caller's accumulator, or its
+        pre-scaled Q, carries it then)."""
+        ds = p * (dp - delta)
+        return ds if self.fold else ds * self.scale
+
+    def mask(self, qi, ki):
+        """[block_k, block_q]: key ki*bk + r is visible to query qi*bq + c."""
+        rel = lax.broadcasted_iota(jnp.int32, (self.bk, self.bq), 1) - \
+            lax.broadcasted_iota(jnp.int32, (self.bk, self.bq), 0)
+        return rel >= ki * self.bk - qi * self.bq - self.offset
+
+    # A walk is a few (lo, hi, masked) spans of block indices, in the order
+    # they are visited; blocks outside every span are dead (above the
+    # diagonal) and are neither fetched nor stepped over.
+
+    def k_spans(self, qi):
+        """The K blocks Q block qi walks: those wholly below the diagonal,
+        unmasked, then the ones it crosses."""
+        if not self.causal:
+            return ((0, self.n_k, False),)
+        q_first = qi * self.bq + self.offset     # last key row 0 sees
+        q_last = q_first + self.bq - 1           # last key any row sees
+        n_live = jnp.clip((q_last + self.bk) // self.bk, 0, self.n_k)
+        n_full = jnp.clip((q_first + 1) // self.bk, 0, n_live)
+        return (0, n_full, False), (n_full, n_live, True)
+
+    def q_spans(self, ki):
+        """The Q blocks K block ki walks: from the first live one, those the
+        diagonal crosses, then the ones wholly below it, unmasked."""
+        if not self.causal:
+            return ((0, self.n_q, False),)
+        k_first = ki * self.bk - self.offset
+        k_last = k_first + self.bk - 1
+        q_start = jnp.clip(k_first // self.bq, 0, self.n_q)
+        q_full = jnp.clip((k_last + self.bq - 1) // self.bq, q_start,
+                          self.n_q)
+        return (q_start, q_full, True), (q_full, self.n_q, False)
+
+
+def _walk(spans, tile, carry=None):
+    """A resident walk: ``carry = tile(i, masked, carry)`` over every span,
+    as loops inside the program."""
+    for lo, hi, masked in spans:
+        carry = lax.fori_loop(
+            lo, hi, lambda i, c, masked=masked: tile(i, masked, c), carry)
+    return carry
+
+
+def _step(spans, i, tile):
+    """Grid step i of a streamed walk: ``tile(masked)`` if a span holds i."""
+    for lo, hi, masked in spans:
+        pl.when((i >= lo) & (i < hi))(functools.partial(tile, masked))
+
+
+def _rows(ref, i, block, n):
+    """Block i of a resident [S, D] operand (the whole of it when it is one
+    block: a static read, which also keeps sub-tile lengths legal)."""
+    if n == 1:
+        return ref[:]
+    return ref[pl.ds(pl.multiple_of(i * block, block), block), :]
+
+
+def _fwd_tile(w, q, k, v, m, l, acc, mask):
+    s = w.scores(q, k)
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if mask is not None and w.guard:
+        p = jnp.where(mask, p, 0.0)     # a query with m_new still NEG_INF
+    corr = jnp.exp(m - m_new)
+    l = l * corr + jnp.sum(p, axis=0, keepdims=True)
+    acc = acc * corr + _dot(v, p.astype(v.dtype), _TN)
+    return m_new, l, acc
+
+
+def _fwd_init(w, d):
+    """m, l [1, block_q]; acc^T [D, block_q]."""
+    return (jnp.full((1, w.bq), NEG_INF, jnp.float32),
+            jnp.zeros((1, w.bq), jnp.float32),
+            jnp.zeros((d, w.bq), jnp.float32))
+
+
+def _fwd_finish(o_ref, lse_ref, m, l, acc):
+    l = jnp.maximum(l, 1e-20)
+    o_ref[:] = (acc / l).T.astype(o_ref.dtype)
+    lse_ref[:] = m + jnp.log(l)
+
+
+def _p_tile(w, q, k, lse, mask):
+    """One probability tile p^T = exp(k q^T * scale - lse); a masked entry
+    reads 0 whatever its query's lse (NEG_INF for one that saw no key)."""
+    p = jnp.exp(w.scores(q, k) - lse)
+    return p if mask is None else jnp.where(mask, p, 0.0)
+
+
+def _dq_tile(w, q, k, v, do, lse, delta, mask):
+    """dq^T [D, block_q] of one tile, less the folded scale."""
+    p = _p_tile(w, q, k, lse, mask)
+    ds = w.ds(p, _dot(v, do, _NT), delta)
+    return _dot(k, ds.astype(k.dtype), _TN)
+
+
+def _dq_finish(w, dq_ref, dq):
+    dq_ref[:] = (dq * w.scale if w.fold else dq).T.astype(dq_ref.dtype)
+
+
+def _dkdv_tile(w, q, k, v, do, lse, delta, mask):
+    """(dk, dv) [block_k, D] of one tile; q is the (pre-scaled) Q block."""
+    p = _p_tile(w, q, k, lse, mask)
+    dv = _dot(p.astype(do.dtype), do, _NN)
+    ds = w.ds(p, _dot(v, do, _NT), delta)
+    return _dot(ds.astype(q.dtype), q, _NN), dv
+
+
+# ------------------------------------------------------- resident kernels
+
+def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, w):
+    """Program (bh, qi): Q block qi against the whole K/V of its row.
+    q_ref/o_ref [block_q, D]; k_ref/v_ref [S_k, D]; lse_ref [1, block_q]."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
+    q = w.q_block(q_ref[:])
+
+    def tile(ki, masked, state):
+        return _fwd_tile(w, q, _rows(k_ref, ki, w.bk, w.n_k),
+                         _rows(v_ref, ki, w.bk, w.n_k), *state,
+                         w.mask(qi, ki) if masked else None)
+
+    _fwd_finish(o_ref, lse_ref,
+                *_walk(w.k_spans(qi), tile, _fwd_init(w, q.shape[-1])))
+
+
+def _dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dq_ref, *, w):
+    """Program (bh, qi): dq of Q block qi over the K/V blocks of its row;
+    lse_ref/delta_ref [1, block_q]."""
+    qi = pl.program_id(1)
+    q, do = w.q_block(q_ref[:]), do_ref[:]
+    lse, delta = lse_ref[:], delta_ref[:]
+
+    def tile(ki, masked, dq):
+        return dq + _dq_tile(w, q, _rows(k_ref, ki, w.bk, w.n_k),
+                             _rows(v_ref, ki, w.bk, w.n_k), do, lse, delta,
+                             w.mask(qi, ki) if masked else None)
+
+    _dq_finish(w, dq_ref, _walk(w.k_spans(qi), tile,
+                                jnp.zeros(q.shape[::-1], jnp.float32)))
+
+
+def _dkdv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, w):
+    """Program (bh, ki): dk/dv of K block ki over the Q blocks of its row.
+    k_ref/v_ref [block_k, D]; q_ref/do_ref [S_q, D]; lse_ref/delta_ref
+    [q_blocks, 1, block_q].  The two [block_k, D] accumulators are scratch:
+    as loop values they cost 13% of the kernel on the chip."""
+    ki = pl.program_id(1)
+    k, v = k_ref[:], v_ref[:]
+    dk_acc[:] = jnp.zeros_like(dk_acc)
+    dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def tile(qi, masked, _):
+        dk, dv = _dkdv_tile(
+            w, w.q_block(_rows(q_ref, qi, w.bq, w.n_q)), k, v,
+            _rows(do_ref, qi, w.bq, w.n_q), lse_ref[qi], delta_ref[qi],
+            w.mask(qi, ki) if masked else None)
+        dk_acc[:] += dk
+        dv_acc[:] += dv
+
+    _walk(w.q_spans(ki), tile)
+    dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+    dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# ------------------------------------------------------- streamed kernels
+
+def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                         acc_ref, *, w):
+    """Program (bh, qi, ki): one tile; m/l [1, block_q] and acc^T
+    [D, block_q] carry the online-softmax state across ki in scratch."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        m_ref[:], l_ref[:], acc_ref[:] = _fwd_init(w, acc_ref.shape[0])
 
-    q_last = (qi + 1) * block_q - 1 + causal_offset  # last visible k pos
-    k_first = ki * block_k
-    live = (not causal) or (k_first <= q_last)
+    def tile(masked):
+        m_ref[:], l_ref[:], acc_ref[:] = _fwd_tile(
+            w, w.q_block(q_ref[:]), k_ref[:], v_ref[:], m_ref[:], l_ref[:],
+            acc_ref[:], w.mask(qi, ki) if masked else None)
 
-    @pl.when(live)
+    _step(w.k_spans(qi), ki, tile)
+
+    @pl.when(ki == w.n_k - 1)
     def _():
-        # dots stay in the input dtype (bf16 hits the fast MXU path) with
-        # f32 accumulation; scale is applied to the f32 scores
-        scores = lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + causal_offset + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + \
-                lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
-        p = jnp.exp(scores - m_new[:, None])
-        if causal:
-            p = jnp.where(scores <= NEG_INF / 2, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_prev * corr + jnp.sum(p, axis=-1)
-        m_ref[:] = m_new
-        acc_ref[:] = acc_ref[:] * corr[:, None] + lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _fwd_finish(o_ref, lse_ref, m_ref[:], l_ref[:], acc_ref[:])
 
-    @pl.when(ki == n_k - 1)
+
+def _dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dq_ref, dq_acc, *, w):
+    """Program (bh, qi, ki): accumulate dq^T of one Q block over K blocks."""
+    qi, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
     def _():
-        l = jnp.maximum(l_ref[:], 1e-20)
-        o_ref[:] = (acc_ref[:] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[:] = (m_ref[:] + jnp.log(l))[:, None]
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
+    def tile(masked):
+        dq_acc[:] += _dq_tile(w, w.q_block(q_ref[:]), k_ref[:], v_ref[:],
+                              do_ref[:], lse_ref[:], delta_ref[:],
+                              w.mask(qi, ki) if masked else None)
+
+    _step(w.k_spans(qi), ki, tile)
+
+    @pl.when(ki == w.n_k - 1)
+    def _():
+        _dq_finish(w, dq_ref, dq_acc[:])
+
+
+def _dkdv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, w):
+    """Program (bh, ki, qi): accumulate dk/dv of one K block over Q blocks."""
+    ki, qi = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def tile(masked):
+        dk, dv = _dkdv_tile(w, w.q_block(q_ref[:]), k_ref[:], v_ref[:],
+                            do_ref[:], lse_ref[:], delta_ref[:],
+                            w.mask(qi, ki) if masked else None)
+        dk_acc[:] += dk
+        dv_acc[:] += dv
+
+    _step(w.q_spans(ki), qi, tile)
+
+    @pl.when(qi == w.n_q - 1)
+    def _():
+        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+# ------------------------------------------------------------ pallas_calls
 
 def _fit_block(s: int, want: int) -> int:
     """Largest block <= want dividing s: s itself when s <= want, else the
@@ -121,158 +378,110 @@ def _fit_block(s: int, want: int) -> int:
     return b
 
 
+def _vmem_bytes(rows: int, cols: int, dtype) -> int:
+    """Bytes a [rows, cols] operand takes in VMEM: whole (8, 128) tiles of
+    32-bit words (16 rows a tile for bf16)."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * item
+
+
+def _resident(*operands) -> bool:
+    """Whether one row's walked operands ([rows, cols] of dtype each) stay
+    in VMEM for the whole row, double-buffered by the pipeline; beyond the
+    budget the walk is streamed block by block."""
+    return 2 * sum(_vmem_bytes(*o) for o in operands) <= _RESIDENT_VMEM_BYTES
+
+
+def _params(resident: bool):
+    """bh and the block axis a program owns are parallel.  A streamed walk
+    adds the walked axis innermost; it carries the VMEM accumulators and
+    must run in order.  (A resident walk is a loop inside the program.)"""
+    return pltpu.CompilerParams(dimension_semantics=(
+        ("parallel", "parallel") if resident
+        else ("parallel", "parallel", "arbitrary")))
+
+
+def _plan(kernel: str, resident: bool, w: _Walk, s_q, s_k, d):
+    """Which feeding a kernel got is fixed when the program is traced: one
+    instant per pallas_call built says so in a JSONL trace or the xplane of
+    a profiled compile."""
+    trace.instant("flash.plan", {
+        "kernel": kernel, "resident": int(resident), "block_q": w.bq,
+        "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d})
+
+
+def _specs(resident, w, s_q, s_k, d, *, own_q: bool):
+    """BlockSpecs (Q-side [.., D], Q-side statistics rows, K-side [.., D]).
+
+    ``own_q``: the program owns a Q block and walks K blocks (forward, dQ);
+    else it owns a K block and walks Q blocks (dK/dV).  Resident: the walked
+    side is the whole row.  Streamed: the walked side follows the innermost
+    grid axis, clamped into the live range so dead steps fetch nothing.  The
+    statistics (lse, delta) are [bh, q_blocks, 1, block_q]."""
+    if resident:
+        def own(bh, i):
+            return bh, i
+        def row(bh, i):
+            return bh, 0
+        q_at, k_at = (own, row) if own_q else (row, own)
+        q_rows, k_rows = (w.bq, s_k) if own_q else (s_q, w.bk)
+        stat_blocks = None if own_q else w.n_q
+    else:
+        if own_q:
+            def q_at(bh, qi, ki):
+                return bh, qi
+            def k_at(bh, qi, ki):
+                last = jnp.maximum(w.k_spans(qi)[-1][1] - 1, 0)
+                return bh, jnp.minimum(ki, last)
+        else:
+            def k_at(bh, ki, qi):
+                return bh, ki
+            def q_at(bh, ki, qi):
+                first = jnp.minimum(w.q_spans(ki)[0][0], w.n_q - 1)
+                return bh, jnp.maximum(qi, first)
+        q_rows, k_rows, stat_blocks = w.bq, w.bk, None
+    return (pl.BlockSpec((None, q_rows, d), lambda *g: (*q_at(*g), 0)),
+            pl.BlockSpec((None, stat_blocks, 1, w.bq),
+                         lambda *g: (*q_at(*g), 0, 0)),
+            pl.BlockSpec((None, k_rows, d), lambda *g: (*k_at(*g), 0)))
+
+
 def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    bq = _fit_block(s_q, block_q)
-    bk = _fit_block(s_k, block_k)
-
-    qf = q.reshape(b * h, s_q, d)
-    kf = k.reshape(b * h, s_k, d)
-    vf = v.reshape(b * h, s_k, d)
-
-    kernel = functools.partial(
-        _flash_fwd_kernel, block_q=bq, block_k=bk, scale=scale,
-        causal=causal, causal_offset=s_k - s_q)
+    w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
+              block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
+    resident = _resident((s_k, d, k.dtype), (s_k, d, v.dtype))
+    _plan("fwd", resident, w, s_q, s_k, d)
+    q_spec, lse_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=True)
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, s_q // bq, s_k // bk),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((None, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((None, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            # TPU blocks need the trailing dims (8,128)-aligned or full; a
-            # trailing singleton keeps the row vector legal: block (bq, 1)
-            pl.BlockSpec((None, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),
-        ],
+        functools.partial(_fwd_resident_kernel if resident
+                          else _fwd_streamed_kernel, w=w),
+        grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
+        in_specs=[q_spec, k_spec, k_spec],
+        out_specs=[q_spec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s_q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b * h, w.n_q, 1, w.bq), jnp.float32),
         ],
-        scratch_shapes=_scratch(bq, d),
-        compiler_params=_params(),
+        scratch_shapes=[] if resident else [
+            pltpu.VMEM((1, w.bq), jnp.float32),
+            pltpu.VMEM((1, w.bq), jnp.float32),
+            pltpu.VMEM((d, w.bq), jnp.float32)],
+        compiler_params=_params(resident),
         interpret=interpret,
-    )(qf, kf, vf)
+    )(q.reshape(b * h, s_q, d), k.reshape(b * h, s_k, d),
+      v.reshape(b * h, s_k, d))
     return out.reshape(b, h, s_q, d), lse
-
-
-def _scratch(bq, d):
-    return [pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32)]
-
-
-def _params():
-    """bh and the outer block axis are parallel; the innermost axis carries
-    the VMEM accumulator and must run in order."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
-# ---------------------------------------------------------------- backward
-
-def _recompute_p(q_ref, k_ref, lse_ref, qi, ki, *, block_q, block_k, scale,
-                 causal, causal_offset):
-    """Recompute one probability tile p = exp(q k^T * scale - lse)."""
-    scores = lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32) * scale
-    if causal:
-        q_pos = qi * block_q + causal_offset + \
-            lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = ki * block_k + \
-            lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        scores = jnp.where(q_pos >= k_pos, scores, NEG_INF)
-    p = jnp.exp(scores - lse_ref[:])  # lse block is [bq, 1]
-    if causal:
-        # guard fully-masked rows: lse there is ~NEG_INF and the subtraction
-        # above would overflow exp
-        p = jnp.where(scores <= NEG_INF / 2, 0.0, p)
-    return p, scores
-
-
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                           block_k: int, scale: float, causal: bool,
-                           causal_offset: int):
-    """Program (bh, ki, qi): accumulate dk/dv for one k block over q blocks."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    n_q = pl.num_programs(2)
-
-    @pl.when(qi == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q_last = (qi + 1) * block_q - 1 + causal_offset
-    k_first = ki * block_k
-    live = (not causal) or (k_first <= q_last)
-
-    @pl.when(live)
-    def _():
-        p, _ = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, block_q=block_q,
-                            block_k=block_k, scale=scale, causal=causal,
-                            causal_offset=causal_offset)
-        pc = p.astype(do_ref.dtype)
-        # dv += p^T dO
-        dv_acc[:] += lax.dot_general(pc, do_ref[:], (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        # dp = dO v^T ; ds = p * (dp - delta) * scale
-        dp = lax.dot_general(do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[:]) * scale).astype(q_ref.dtype)
-        # dk += ds^T q
-        dk_acc[:] += lax.dot_general(ds, q_ref[:], (((0,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-
-    @pl.when(qi == n_q - 1)
-    def _():
-        dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_acc, *, block_q: int, block_k: int,
-                         scale: float, causal: bool, causal_offset: int):
-    """Program (bh, qi, ki): accumulate dq for one q block over k blocks."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_k = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    q_last = (qi + 1) * block_q - 1 + causal_offset
-    k_first = ki * block_k
-    live = (not causal) or (k_first <= q_last)
-
-    @pl.when(live)
-    def _():
-        p, _ = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, block_q=block_q,
-                            block_k=block_k, scale=scale, causal=causal,
-                            causal_offset=causal_offset)
-        dp = lax.dot_general(do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[:]) * scale).astype(k_ref.dtype)
-        # dq += ds k
-        dq_acc[:] += lax.dot_general(ds, k_ref[:], (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-
-    @pl.when(ki == n_k - 1)
-    def _():
-        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
                interpret):
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    bq = _fit_block(s_q, block_q)
-    bk = _fit_block(s_k, block_k)
+    w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
+              block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
 
     qf = q.reshape(b * h, s_q, d)
     kf = k.reshape(b * h, s_k, d)
@@ -281,53 +490,44 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
     # delta = rowsum(dO * O): one fused elementwise+reduce, O(S*D) traffic
     delta = jnp.sum(dof.astype(jnp.float32)
                     * out.reshape(b * h, s_q, d).astype(jnp.float32),
-                    axis=-1, keepdims=True)
+                    axis=-1).reshape(lse.shape)
 
-    common = dict(block_q=bq, block_k=bk, scale=scale, causal=causal,
-                  causal_offset=s_k - s_q)
-
-    # dK/dV kernel: grid (bh, ki, qi) — q blocks innermost
+    # dK/dV: a program owns a K block and walks the Q side
+    resident = _resident((s_q, d, q.dtype), (s_q, d, g.dtype),
+                         (8 * w.n_q, w.bq, jnp.float32),
+                         (8 * w.n_q, w.bq, jnp.float32))
+    _plan("dkdv", resident, w, s_q, s_k, d)
+    q_spec, stat_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=False)
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, **common),
-        grid=(b * h, s_k // bk, s_q // bq),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda bh, ki, qi: (bh, qi, 0)),  # q
-            pl.BlockSpec((None, bk, d), lambda bh, ki, qi: (bh, ki, 0)),  # k
-            pl.BlockSpec((None, bk, d), lambda bh, ki, qi: (bh, ki, 0)),  # v
-            pl.BlockSpec((None, bq, d), lambda bh, ki, qi: (bh, qi, 0)),  # dO
-            pl.BlockSpec((None, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # lse
-            pl.BlockSpec((None, bq, 1), lambda bh, ki, qi: (bh, qi, 0)),  # delta
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((None, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
-        ],
+        functools.partial(_dkdv_resident_kernel if resident
+                          else _dkdv_streamed_kernel, w=w),
+        grid=(b * h, w.n_k) if resident else (b * h, w.n_k, w.n_q),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_k, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, s_k, d), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_params(),
+        scratch_shapes=[pltpu.VMEM((w.bk, d), jnp.float32),
+                        pltpu.VMEM((w.bk, d), jnp.float32)],
+        compiler_params=_params(resident),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
-    # dQ kernel: grid (bh, qi, ki) — k blocks innermost
+    # dQ: a program owns a Q block and walks the K side
+    resident = _resident((s_k, d, k.dtype), (s_k, d, v.dtype))
+    _plan("dq", resident, w, s_q, s_k, d)
+    q_spec, stat_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=True)
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(b * h, s_q // bq, s_k // bk),
-        in_specs=[
-            pl.BlockSpec((None, bq, d), lambda bh, qi, ki: (bh, qi, 0)),  # q
-            pl.BlockSpec((None, bk, d), lambda bh, qi, ki: (bh, ki, 0)),  # k
-            pl.BlockSpec((None, bk, d), lambda bh, qi, ki: (bh, ki, 0)),  # v
-            pl.BlockSpec((None, bq, d), lambda bh, qi, ki: (bh, qi, 0)),  # dO
-            pl.BlockSpec((None, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),  # lse
-            pl.BlockSpec((None, bq, 1), lambda bh, qi, ki: (bh, qi, 0)),  # delta
-        ],
-        out_specs=pl.BlockSpec((None, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
+        functools.partial(_dq_resident_kernel if resident
+                          else _dq_streamed_kernel, w=w),
+        grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_params(),
+        scratch_shapes=[] if resident else [
+            pltpu.VMEM((d, w.bq), jnp.float32)],
+        compiler_params=_params(resident),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
@@ -378,13 +578,16 @@ def _mesh_partition(batch: int, heads: int):
 
 
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
-                    block_q: int = 256, block_k: int = 256,
+                    block_q: int = 512, block_k: int = 512,
                     interpret=None):
     """Fused attention: q,k,v [B, H, S, D] → [B, H, S_q, D].
 
-    Fully fused in both directions: forward streams K/V blocks with online
+    Fully fused in both directions: forward walks K/V blocks with online
     softmax; backward recomputes probability tiles from the saved LSE
-    (FlashAttention-2) — no O(S^2) tensor in HBM either way.
+    (FlashAttention-2) — no O(S^2) tensor in HBM either way.  Whether a
+    row's K/V (Q, dO for dK/dV) stays in VMEM or is streamed block by block
+    follows from the shapes (module docstring); ``block_q``/``block_k`` are
+    the tile size either way.
 
     interpret=None auto-selects: compiled kernel on TPU, interpret mode on
     CPU.  Block sizes auto-fit down to the sequence length (any S

@@ -114,3 +114,140 @@ def test_flash_fully_masked_rows_zero():
     np.testing.assert_array_equal(np.asarray(dq[:, :, :64]), 0.0)
     assert np.all(np.isfinite(np.asarray(dk)))
     assert np.all(np.isfinite(np.asarray(dv)))
+
+
+# ---- both feedings, every shape class, against the XLA oracle ----
+
+_MOD = "hetu_tpu.ops.pallas_kernels.flash_attention"
+
+
+@pytest.fixture
+def feeding(request, monkeypatch):
+    """'resident': a row's operands stay in VMEM (what these small shapes
+    get).  'streamed': the budget is taken away, so the same call walks the
+    blocks on the grid — steered here, the op has no switch for it."""
+    import sys
+    if request.param == "streamed":
+        monkeypatch.setattr(sys.modules[_MOD], "_RESIDENT_VMEM_BYTES", 0)
+    return request.param
+
+
+def _plans(monkeypatch):
+    """The flash.plan instants of the calls built from here on."""
+    import sys
+    seen = []
+    monkeypatch.setattr(
+        sys.modules[_MOD].trace, "instant",
+        lambda name, attrs=None, cat="hetu": seen.append((name, attrs)))
+    return seen
+
+
+def _oracle_case(s_q, s_k, d, dtype, causal, block, seed):
+    """(flash out + grads, oracle out + grads, first visible query row)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, g = (jax.random.normal(kk, (1, 2, s_q, d)).astype(dtype)
+            for kk in (ks[0], ks[3]))
+    k, v = (jax.random.normal(kk, (1, 2, s_k, d)).astype(dtype)
+            for kk in ks[1:3])
+    blocks = {} if block is None else {"block_q": block, "block_k": block}
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, **blocks), q, k, v)
+    # rows that see no key (s_q > s_k, causal) are 0 in the kernel and a
+    # garbage average in the oracle: keep them out of the oracle's gradients
+    lo = max(0, s_q - s_k) if causal else 0
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    ref, ref_vjp = jax.vjp(causal_attention if causal else attention, *f32)
+    want = ref_vjp(g.astype(jnp.float32).at[:, :, :lo].set(0.0))
+    return (out, *vjp(g)), (ref, *want), lo
+
+
+_SHAPES = {"sq_lt_sk": (64, 128), "sq_eq_sk": (128, 128),
+           "sq_gt_sk": (128, 64)}
+
+
+@pytest.mark.parametrize("block", [32, 64, None],
+                         ids=["b32", "b64", "bdefault"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_flash_matches_oracle_causal(feeding, dtype, d, shape, block,
+                                     monkeypatch):
+    """Forward and all three gradients, causal, against ops.causal_attention:
+    both feedings, D 64 (scale folded into Q) and 128 (scale on the scores),
+    f32 and bf16, s_q <, ==, > s_k, tiles of 32 / 64 and the default (one
+    tile a row: S == block, the diagonal tile alone)."""
+    plans = _plans(monkeypatch)
+    s_q, s_k = _SHAPES[shape]
+    got, want, lo = _oracle_case(s_q, s_k, d, dtype, True, block,
+                                 seed=s_q + d)
+    assert [a["kernel"] for _, a in plans] == ["fwd", "dkdv", "dq"]
+    assert all(a["resident"] == (feeding == "resident") for _, a in plans)
+    tol = dict(rtol=1e-3, atol=2e-4) if dtype == jnp.float32 \
+        else dict(rtol=5e-2, atol=5e-2)
+    out, dq, dk, dv = (np.asarray(x, np.float32) for x in got)
+    ref, rq, rk, rv = (np.asarray(x) for x in want)
+    assert got[0].dtype == dtype
+    # all-masked rows read zero, in the output and in dq
+    np.testing.assert_array_equal(out[:, :, :lo], 0.0)
+    np.testing.assert_array_equal(dq[:, :, :lo], 0.0)
+    np.testing.assert_allclose(out[:, :, lo:], ref[:, :, lo:], **tol)
+    np.testing.assert_allclose(dq[:, :, lo:], rq[:, :, lo:], **tol)
+    np.testing.assert_allclose(dk, rk, **tol)
+    np.testing.assert_allclose(dv, rv, **tol)
+
+
+@pytest.mark.parametrize("block", [32, None], ids=["b32", "bdefault"])
+@pytest.mark.parametrize("shape", list(_SHAPES))
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_flash_matches_oracle_unmasked(feeding, shape, block):
+    """Non-causal (BERT-shaped) calls take the unmasked body for every
+    tile, cross-length included."""
+    s_q, s_k = _SHAPES[shape]
+    got, want, _ = _oracle_case(s_q, s_k, 64, jnp.float32, False, block,
+                                seed=5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("s_q,s_k,streamed", [
+    (128, 4096, {"fwd": True, "dkdv": False, "dq": True}),
+    (4096, 128, {"fwd": False, "dkdv": True, "dq": False}),
+    (128, 128, {"fwd": False, "dkdv": False, "dq": False})],
+    ids=["long_kv", "long_q", "short"])
+def test_flash_feeding_follows_the_shape(s_q, s_k, streamed, monkeypatch):
+    """The residency choice is a function of the operand shapes — a row of
+    4096 x 128 f32 is over the VMEM budget, so the kernels that would hold
+    it stream it — and is visible as the flash.plan instant; the streamed
+    kernels agree with the oracle at such a shape too."""
+    plans = _plans(monkeypatch)
+    got, want, lo = _oracle_case(s_q, s_k, 128, jnp.float32, True, None,
+                                 seed=11)
+    assert {a["kernel"]: not a["resident"] for _, a in plans} == streamed
+    assert all(name == "flash.plan" and a["block_q"] == min(s_q, 512)
+               and a["block_k"] == min(s_k, 512)
+               and (a["s_q"], a["s_k"], a["d"]) == (s_q, s_k, 128)
+               for name, a in plans)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a)[:, :, lo:],
+                                   np.asarray(b)[:, :, lo:], rtol=1e-3,
+                                   atol=2e-4)
+
+
+def test_flash_scale_not_a_power_of_two_stays_on_the_scores():
+    """An explicit scale that cannot be folded into Q exactly is applied to
+    the f32 scores, forward and backward."""
+    q, k, v = qkv(S=64, seed=6)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+    flash = loss(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, scale=0.3, block_q=32, block_k=32))
+    ref = loss(lambda q, k, v: causal_attention(q * 0.3 * 8.0, k, v))
+    for a, b in zip(jax.grad(flash, argnums=(0, 1, 2))(q, k, v),
+                    jax.grad(ref, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
+                                   atol=2e-4)

@@ -7,6 +7,8 @@ topology, which is where Mosaic itself refuses (a one-row DMA out of a tiled
 table, a dynamic sublane index on a packed dtype).
 """
 
+import json
+import re
 import sys
 from pathlib import Path
 
@@ -24,6 +26,10 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
 )
 
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+# the flash calls of the two training cells of BENCHMARK.json: gpt2-small at
+# 64 x 1024 on one chip, and gpt2-large's per-chip shard under dp=2 x tp=2
+BENCH_FLASH_SHAPES = ((64, 12, 1024, 64), (8, 10, 1024, 64))
 
 
 @pytest.fixture(autouse=True)
@@ -48,6 +54,9 @@ def _cases():
         for dt in (bf16, f32):
             yield (f"flash fwd+bwd {dt.__name__} q{qs} kv{ks}", _flash_vjp,
                    [(qs, dt), (ks, dt), (ks, dt), (qs, dt)], 3)
+    for shape in BENCH_FLASH_SHAPES:
+        yield (f"flash fwd+bwd bf16 benchmark {shape}", _flash_vjp,
+               [(shape, bf16)] * 4, 3)
     for dt in (f32, bf16):
         yield (f"embedding_gather {dt.__name__}", embedding_gather,
                [((rows, width), dt), ((n,), i32)], 1)
@@ -75,6 +84,34 @@ def test_kernel_lowers_for_tpu(name, fn, args, n_calls):
     text = jax.jit(fn).trace(*abstract).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") >= n_calls
+
+
+def test_flash_calls_read_as_the_benchmarks_reader_expects():
+    """``benchmarks/layer_metrics/flash_roofline.json`` tells the kernels
+    apart by the HLO result of their custom calls: the forward is the ONE
+    call returning (bf16, f32), the backward the TWO returning (bf16, bf16)
+    (dK, dV) and a single bf16 (dQ), and backward passes are counted by the
+    latter.  A kernel PR that changes a signature (a fused backward
+    returning three arrays, a Pallas delta with an f32 result) would make
+    ``flash_roofline`` count the wrong work without failing anything else.
+    HLO text reads as the trace's event names do, less the leading '%'."""
+    params = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "layer_metrics" / "flash_roofline.json"
+                         ).read_text())["params"]
+    abstract = [jax.ShapeDtypeStruct(BENCH_FLASH_SHAPES[0], bf16)] * 4
+    text = jax.jit(_flash_vjp).trace(*abstract).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = ["%" + line.strip().removeprefix("ROOT ").removeprefix("%")
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    hits = {name: [c.split(" = ")[1].split(" custom-call")[0]
+                   for c in calls if re.search(rx, c)]
+            for name, rx in params.items()}
+    shape = "bf16[768,1024,64]{2,1,0}"
+    assert len(hits["fwd"]) == 1 and hits["fwd"][0].startswith(
+        f"({shape}, f32["), hits
+    assert sorted(hits["bwd"]) == sorted([f"({shape}, {shape})", shape]), hits
+    assert hits["bwd_count"] == [shape], hits
 
 
 def test_gpt2_small_train_step_lowers_with_the_flash_calls():
