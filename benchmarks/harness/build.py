@@ -1,12 +1,12 @@
-"""Building the system under test from a configuration file: the model, its
-weights from ``--seed``, the trainer and the serving stack.  Only the
-program's public entry points are used: ``GPTModel``, ``Executor``,
+"""Building the system under test from a configuration file: its weights
+from ``--seed``, the mesh and strategy, the trainer and the serving stack.
+The model itself comes from the configuration's adapter
+(``spec.adapter(config).make_model``); nothing here names an architecture.
+Only the program's public entry points are used: ``Executor``,
 ``PagedServeEngine``, ``ContinuousBatchingScheduler``, ``make_mesh`` and the
 strategy presets."""
 
 from __future__ import annotations
-
-from benchmarks.harness import flops
 
 
 def key_for(seed: int, stream: int = 0):
@@ -15,25 +15,6 @@ def key_for(seed: int, stream: int = 0):
 
     return jax.random.fold_in(
         jax.random.PRNGKey(int(seed) % 2147483647), stream)
-
-
-def make_model(config: dict, section: str):
-    import jax.numpy as jnp
-
-    from hetu_tpu.models.gpt import GPTConfig, GPTModel
-
-    w = flops.widths(config)
-    sec = config[section]
-    kw = {}
-    if section == "train":
-        kw = {"fused_ce": bool(sec["fused_ce"]), "remat": bool(sec["remat"])}
-    return GPTModel(GPTConfig(
-        vocab_size=int(config["assumed"]["embedding_rows"]),
-        hidden_size=w["hidden"], num_layers=w["layers"],
-        num_heads=w["heads"], ffn_size=w["ffn"],
-        max_position=w["positions"], dropout_rate=0.0,
-        dtype=getattr(jnp, config["compute_dtype"]),
-        attention_impl=sec["attention_impl"], **kw))
 
 
 def mesh_and_strategy(config: dict, chips: int):

@@ -1,44 +1,23 @@
 """The comparison that decides ``correct``: the system under test against
-``benchmarks/reference/gpt2.py`` at the run's own widths and weights, during
-once the measured window is over and the chip's memory has been read.
+the configuration's plain reference at the run's own widths and weights,
+once the measured window is over and the chip's memory has been read.  The
+architecture's adapter (``spec.adapter(config)``) runs both sides and states
+the four tolerances with their reasons; this file only says what is
+compared with what.
 
 Logits are compared, never tokens: the weights are random, so the largest
 logit changes on rounding (PR 21 found two XLA programs of the same weights
 0.007 logits apart at a near tie).
 
-Tolerances.  The configurations compute in bfloat16 (8 bits of mantissa,
-relative rounding 2**-8 = 0.0039 per operation) over float32 weights; the
-reference is float32 at the highest matmul precision.  Measured on the v5e
-at the published widths (builder's chip runs, PR 23, some sixty runs over
-four cells): ``logit_err`` 0.0056-0.0060 of the reference's logit range for
-gpt2-small and 0.0075-0.0078 for gpt2-large, ``token_gap`` 0-0.002,
-``loss_rel`` 3e-6-3e-5, ``grad_norm_rel`` 0.9e-3-1.6e-3.  Each bound below is
-three to six times the worst of these.  A program that computed in 8-bit
-floats or integers where bfloat16 is stated rounds sixteen times coarser
-(2**-4 per operation) and lands an order of magnitude outside every one;
-a wrong page, position or mask moves logits by their whole range
-(``tests/test_reference.py`` shows both, at tiny widths).
-
 ``logit_err`` holds the model's dense forward; the engine, its page tables
-and its decode program return tokens only and are held by ``token_gap``:
-under the best of 50257 near-Gaussian logits lie on average 0.4 other
-candidates within 1% of the range and six to eight within 5%, so the bound
-is 1% (five times the worst measured) and not the 5% it first was, under
-which a coarser cache or decode program could have passed.
+and its decode program return tokens only and are held by ``token_gap``.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.harness import build
-from benchmarks.reference import gpt2 as reference
-
-LOGIT_TOL = 0.025      # max |system - reference| over the reference's range
-TOKEN_GAP_TOL = 0.01   # by the reference's logits the engine's token may
-#                        trail the best by the two candidates' own errors
-LOSS_REL_TOL = 2e-4
-GRAD_NORM_REL_TOL = 6e-3
+from benchmarks.harness import build, spec
 
 SERVE_PROMPT_LENS = (24, 100, 200, 333)   # one, two, four and six chunks
 SERVE_DECODED = 8
@@ -46,6 +25,24 @@ SERVE_DECODED = 8
 
 def _range(a) -> float:
     return float(np.max(a) - np.min(a))
+
+
+def global_norm(tree):
+    import jax
+    import jax.numpy as jnp
+
+    return jnp.sqrt(sum(jnp.sum(jnp.square(a.astype(jnp.float32)))
+                        for a in jax.tree_util.tree_leaves(tree)))
+
+
+def _verdict(arch, config: dict, compared: dict, **rest) -> dict:
+    """``ok`` when every number compared is within the limit the adapter
+    states for it; the limits go into the verdict, so that a run prints each
+    number beside its limit."""
+    stated = arch.tolerances(config)
+    limits = {k: stated[k]["limit"] for k in compared}
+    ok = all(compared[k] <= limits[k] for k in compared)
+    return {"ok": bool(ok), **compared, **rest, "limits": limits}
 
 
 def serving(model, variables, engine, scheduler, config: dict,
@@ -57,20 +54,17 @@ def serving(model, variables, engine, scheduler, config: dict,
     Two comparisons, both on logits.  (1) The model's own forward in the
     configured precision against the reference at every position.  (2) Each
     token the engine emitted must be, by the REFERENCE's logits at that
-    position, within TOKEN_GAP_TOL (of the logit range) of the best token:
-    the engine returns tokens only, and this holds them to the reference
-    without asking two roundings of a near tie to agree."""
-    import jax
-    import jax.numpy as jnp
-
+    position, within the ``token_gap`` tolerance (of the logit range) of the
+    best token: the engine returns tokens only, and this holds them to the
+    reference without asking two roundings of a near tie to agree."""
     from hetu_tpu.serve import Request
 
-    heads = int(config["n_head"])
-    vocab = int(config["vocab_size"])
+    arch = spec.adapter(config)
+    low, high = arch.id_range(config)
     max_prompt = int(config["serve"]["max_len"]) - SERVE_DECODED - 2
     lens = [min(n, max_prompt) for n in SERVE_PROMPT_LENS]
     rng = np.random.default_rng([int(seed), 7])
-    prompts = [rng.integers(0, vocab, n).astype(np.int32).tolist()
+    prompts = [rng.integers(low, high, n).astype(np.int32).tolist()
                for n in lens]
     reqs = [Request(prompt=p, max_tokens=SERVE_DECODED + 1) for p in prompts]
     scheduler.run(reqs)
@@ -80,17 +74,14 @@ def serving(model, variables, engine, scheduler, config: dict,
         return {"ok": False, "why": f"check requests ended {bad}"}
 
     width = -(-(max(lens) + SERVE_DECODED + 1) // 128) * 128
-    width = min(width, int(config["n_positions"]))
+    width = min(width, arch.positions(config))
     ids = np.zeros((len(reqs), width), np.int32)
     for i, r in enumerate(reqs):
         seq = list(r.prompt) + list(r.tokens)
         ids[i, :len(seq)] = seq
     params = variables["params"]
-    ref = np.asarray(jax.jit(
-        lambda p, x: reference.logits(p, x, heads))(params, ids))
-    sysl = np.asarray(jax.jit(
-        lambda p, x: model.apply({"params": p, "state": {}}, x)[0])(
-            params, jnp.asarray(ids)).astype(jnp.float32))
+    ref = arch.reference_logits(params, ids, config)
+    sysl = arch.system_logits(model, params, ids)
     logit_err, token_gap = 0.0, 0.0
     for i, r in enumerate(reqs):
         n = len(r.prompt)
@@ -102,9 +93,9 @@ def serving(model, variables, engine, scheduler, config: dict,
             row = ref[i, n - 1 + j]
             token_gap = max(token_gap,
                             float(np.max(row) - row[tok]) / span)
-    ok = logit_err <= LOGIT_TOL and token_gap <= TOKEN_GAP_TOL
-    return {"ok": bool(ok), "logit_err": logit_err, "token_gap": token_gap,
-            "prompts": lens, "decoded": SERVE_DECODED}
+    return _verdict(arch, config, {"logit_err": logit_err,
+                                   "token_gap": token_gap},
+                    prompts=lens, decoded=SERVE_DECODED)
 
 
 def training(model, ref_params, sys_params, config: dict, ids,
@@ -117,17 +108,16 @@ def training(model, ref_params, sys_params, config: dict, ids,
 
     from hetu_tpu.parallel.mesh import mesh_context
 
-    heads = int(config["n_head"])
+    arch = spec.adapter(config)
     loss_fn = model.lm_loss_fn()
 
     def system(p, x):
         value, grads = jax.value_and_grad(
             lambda q: loss_fn(q, {}, (x,), build.key_for(0, 1), True)[0])(p)
-        return value, reference.global_norm(grads)
+        return value, global_norm(grads)
 
-    ref_loss, ref_norm = (float(v) for v in jax.jit(
-        lambda p, x: reference.loss_and_grad_norm(p, x, heads))(
-            ref_params, np.asarray(ids)))
+    ref_loss, ref_norm = arch.reference_loss_and_grad_norm(
+        ref_params, np.asarray(ids), config)
     x = ids
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -137,7 +127,7 @@ def training(model, ref_params, sys_params, config: dict, ids,
             sys_params, x))
     loss_rel = abs(sys_loss - ref_loss) / abs(ref_loss)
     norm_rel = abs(sys_norm - ref_norm) / abs(ref_norm)
-    ok = loss_rel <= LOSS_REL_TOL and norm_rel <= GRAD_NORM_REL_TOL
-    return {"ok": bool(ok), "loss_rel": loss_rel, "grad_norm_rel": norm_rel,
-            "ref_loss": ref_loss, "sys_loss": sys_loss,
-            "ref_grad_norm": ref_norm, "sys_grad_norm": sys_norm}
+    return _verdict(arch, config, {"loss_rel": loss_rel,
+                                   "grad_norm_rel": norm_rel},
+                    ref_loss=ref_loss, sys_loss=sys_loss,
+                    ref_grad_norm=ref_norm, sys_grad_norm=sys_norm)

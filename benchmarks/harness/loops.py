@@ -106,13 +106,15 @@ def train_steps(ctx) -> Run:
 
     cfg, tr, rec = ctx.config, ctx.traffic, ctx.rec
     batch, seq = int(tr["batch"]), int(tr["seq"])
-    model = build.make_model(cfg, "train")
+    arch = spec.adapter(cfg)
+    model = arch.make_model(cfg, "train")
     mesh, strategy = build.mesh_and_strategy(cfg, ctx.chips)
     variables = build.init_variables(model, ctx.seed, mesh=mesh,
                                      strategy=strategy)
     rng = np.random.default_rng(int(ctx.seed))
-    batches = [rng.integers(0, int(cfg["vocab_size"]), (batch, seq))
-               .astype(np.int32) for _ in range(int(tr["distinct_batches"]))]
+    low, high = arch.id_range(cfg)
+    batches = [rng.integers(low, high, (batch, seq)).astype(np.int32)
+               for _ in range(int(tr["distinct_batches"]))]
 
     ex = build.make_executor(model, cfg, mesh=mesh, strategy=strategy)
     state = ex.init_state(variables, rng_key=build.key_for(ctx.seed, 1))
@@ -211,7 +213,8 @@ class Serving:
         self.ctx, self.rec = ctx, ctx.rec
         cfg = ctx.config
         t0 = time.monotonic()
-        self.model = build.make_model(cfg, "serve")
+        self.arch = spec.adapter(cfg)
+        self.model = self.arch.make_model(cfg, "serve")
         self.variables = build.init_variables(self.model, ctx.seed)
         self.engine, self.scheduler = build.make_serving(
             self.model, self.variables, cfg)
@@ -275,10 +278,10 @@ class Serving:
         cache = eng.cache
         ps, slots = cache.page_size, cache.num_slots
         rng = np.random.default_rng(0)
-        vocab = int(self.ctx.config["vocab_size"])
+        low, high = self.arch.id_range(self.ctx.config)
 
         def prompt(n):   # unshared, or the prefix index would skip chunks
-            return rng.integers(0, vocab, n).astype(np.int32).tolist()
+            return rng.integers(low, high, n).astype(np.int32).tolist()
 
         t0 = time.monotonic()
         for b in eng.chunk_buckets:
@@ -393,7 +396,7 @@ def backlog(ctx) -> Run:
     sv.warm(schedule.reach(tr))
     lengths = schedule.backlog_lengths(tr)
     prompts = schedule.token_ids(ctx.seed, [p for p, _ in lengths],
-                                 int(ctx.config["vocab_size"]))
+                                 sv.arch.id_range(ctx.config))
     depth = int(tr["queue_depth_slots"]) * sv.engine.cache.num_slots
     nxt = {"i": 0}
 
@@ -490,7 +493,7 @@ def open_loop(ctx, *, rate_rps: float = None, serving: Serving = None,
     plan = schedule.open_loop_schedule(
         {**tr, "warmup_s": lead}, ctx.seconds, rate_rps=rate_rps)
     prompts = schedule.token_ids(ctx.seed, [a.prompt_len for a in plan],
-                                 int(ctx.config["vocab_size"]))
+                                 sv.arch.id_range(ctx.config))
     win0, win1 = lead, lead + ctx.seconds
     deadline = win1 + float(tr["drain_timeout_s"])
     trace_at = float(tr["warmup_s"]) if ctx.trace else None

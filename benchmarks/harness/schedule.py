@@ -90,11 +90,13 @@ def backlog_lengths(traffic: dict) -> list:
     return permuted_lengths(traffic, int(traffic["pool_requests"]), 0)
 
 
-def token_ids(seed: int, lengths, vocab_size: int) -> list:
-    """One list of ids per prompt length, uniform over the real vocabulary,
-    from ``--seed``: the only thing of a schedule that a seed changes."""
+def token_ids(seed: int, lengths, id_range) -> list:
+    """One list of ids per prompt length, uniform over ``id_range`` (low,
+    high: the ids the architecture's adapter says traffic draws from), from
+    ``--seed``: the only thing of a schedule that a seed changes."""
     rng = np.random.default_rng(int(seed))
-    return [rng.integers(0, vocab_size, int(n)).astype(np.int32).tolist()
+    low, high = id_range
+    return [rng.integers(low, high, int(n)).astype(np.int32).tolist()
             for n in lengths]
 
 

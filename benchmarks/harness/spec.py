@@ -1,10 +1,15 @@
 """Reading the benchmark's data files: manifest, configurations, traffic
 mixes and per-layer metrics, each found by the name ``BENCHMARK.json``
-gives it.  Adding a cell, a configuration, a mix or a metric is adding a
-file and an entry; nothing here names one."""
+gives it, and the two modules a configuration file names: its
+architecture's adapter (``"adapter"``, a dotted module path) and its plain
+reference (``"reference"``, a file).  Adding a cell, a configuration, a mix,
+a metric or an architecture is adding files and an entry; nothing here, in
+the rest of ``harness/``, in ``readers/`` or in ``run.py`` names one, or
+reads a key that only one architecture's configuration has."""
 
 from __future__ import annotations
 
+import importlib
 import json
 from pathlib import Path
 
@@ -45,6 +50,19 @@ def config(man: dict, name: str, *, rehearse: bool = False) -> dict:
         cfg = _merge(cfg, cfg["rehearse"])
     cfg["name"] = name
     return cfg
+
+
+def adapter(config: dict):
+    """The adapter of the configuration's architecture: the one module that
+    knows the architecture's keys.  What it has to offer is listed in
+    ``benchmarks/arch/__init__.py``."""
+    return importlib.import_module(config["adapter"])
+
+
+def reference(config: dict):
+    """The configuration's plain reference, by the file it names."""
+    return importlib.import_module(
+        ".".join(Path(config["reference"]).with_suffix("").parts))
 
 
 def traffic(name: str, *, rehearse: bool = False) -> dict:

@@ -4,7 +4,7 @@ a decode step has to read (every matmul weight once in the compute type, and
 the cache of every token live in an active slot) or to do its operations,
 whichever is larger, over the time the chip was busy during that call."""
 
-from benchmarks.harness import flops
+from benchmarks.harness import spec
 
 
 def read(ctx, *, span: str):
@@ -17,11 +17,12 @@ def read(ctx, *, span: str):
     n = min(len(busy), len(active))
     if not n or not sum(busy[:n]):
         return None
+    arch = spec.adapter(ctx.config)
     least = 0.0
     for i in range(n):
         least += max(
-            flops.decode_step_bytes(ctx.config, cached[i])
+            arch.decode_step_bytes(ctx.config, cached[i])
             / ctx.peaks["hbm_bytes_per_s"],
-            flops.decode_step_flops(ctx.config, active[i], cached[i])
+            arch.decode_step_flops(ctx.config, active[i], cached[i])
             / ctx.peaks["bf16_flops"])
     return 100.0 * least / sum(busy[:n])

@@ -169,6 +169,16 @@ def main(argv=None) -> int:
               "phases_s": run.extra.get("setup", {}),
               "counts": {k: v for k, v in run.values.items()
                          if isinstance(v, (int, float))}}
+    # each number compared beside its limit: a run's last lines on standard
+    # error, which is what the driver's record keeps of a run that failed
+    for name, limit in run.check.get("limits", {}).items():
+        print(f"check {name} = {run.check.get(name)} (limit {limit})",
+              file=sys.stderr)
+    print(f"check compiles_in_window = {run.compiles_in_window} (limit 0), "
+          f"engine_new_executables = "
+          f"{run.values.get('engine_new_executables', 0)} (limit 0), "
+          f"ok = {run.check.get('ok')}: {run.check.get('why', '')}",
+          file=sys.stderr, flush=True)
     if args.rehearse:
         d = jax.devices()[0]
         print(json.dumps({

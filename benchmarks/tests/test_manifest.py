@@ -1,12 +1,15 @@
 """BENCHMARK.json against the data files it names: a cell, a configuration,
-a traffic mix or a per-layer metric is added by files and entries alone, so
-the entries and the files have to agree."""
+a traffic mix, a per-layer metric or an architecture is added by files and
+entries alone, so the entries and the files have to agree."""
 
 import importlib
+import inspect
 import json
+from pathlib import Path
 
 import pytest
 
+from benchmarks import arch
 from benchmarks.harness import loops, spec
 
 
@@ -24,6 +27,49 @@ def test_every_cell_has_its_files(man):
             c for c in man["configs"]
             if c["name"] == cell["config"])["reduced"]
         assert (spec.ROOT / cfg["reference"]).is_file()
+
+
+def test_every_configuration_names_an_adapter_that_offers_everything(man):
+    """The adapter imports by the name the configuration gives, offers every
+    name the harness asks by, states the four tolerances each with its
+    reason, and uses the reference file the configuration names."""
+    for c in man["configs"]:
+        cfg = spec.config(man, c["name"])
+        adapter = spec.adapter(cfg)
+        assert adapter.__name__ == cfg["adapter"]
+        for name in arch.OFFERS:
+            assert callable(getattr(adapter, name, None)), (c["name"], name)
+        tol = adapter.tolerances(cfg)
+        assert set(tol) == {"logit_err", "token_gap", "loss_rel",
+                            "grad_norm_rel"}
+        for name, t in tol.items():
+            assert t["limit"] > 0 and len(t["why"]) > 10, (c["name"], name)
+        assert Path(adapter.reference(cfg).__file__) \
+            == spec.ROOT / cfg["reference"]
+        low, high = adapter.id_range(cfg)
+        assert 0 <= low < high and adapter.positions(cfg) > 0
+        assert adapter.total_params(cfg) > 0
+
+
+def test_gpt2s_tolerances_are_the_ones_it_was_measured_with(man):
+    tol = spec.adapter(spec.config(man, "gpt2-large")).tolerances({})
+    assert {k: t["limit"] for k, t in tol.items()} == {
+        "logit_err": 0.025, "token_gap": 0.01, "loss_rel": 2e-4,
+        "grad_norm_rel": 6e-3}
+
+
+def test_an_adapter_offers_what_the_harness_asks_and_with_those_arguments():
+    """The table in ``benchmarks/arch/__init__.py`` against GPT-2's
+    adapter: the same names, and as many positional arguments as the table's
+    signature says."""
+    from benchmarks.arch import gpt2
+
+    for name, said in arch.OFFERS.items():
+        want = said[1:said.index(")")].split(", ")
+        have = [p.name for p in inspect.signature(
+            getattr(gpt2, name)).parameters.values()
+            if p.default is inspect.Parameter.empty]
+        assert have == want, name
 
 
 def test_every_per_layer_metric_can_be_read(man):

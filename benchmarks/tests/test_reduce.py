@@ -2,13 +2,15 @@
 (``data/tiny_v5e.xplane.pb``, by ``tools/record_tiny_trace.py``: four calls
 of a three-layer scanned program, each inside ``bench:inner.call``, each
 followed by 2 ms of host sleep inside ``bench:inner.host``) and on traces
-made by hand; and the operation and byte functions against hand-worked
-values for both configurations."""
+made by hand; and the operation and byte functions (the kernels' in
+``harness/flops.py``, GPT-2's in its adapter) against hand-worked values for
+both configurations."""
 
 from pathlib import Path
 
 import pytest
 
+from benchmarks.arch import gpt2
 from benchmarks.harness import flops, reduce, spec
 from benchmarks.harness.reduce import Event, Line, Plane
 
@@ -153,25 +155,27 @@ def test_short_name():
 @pytest.fixture(scope="module")
 def configs():
     man = spec.manifest()
-    return {n: spec.config(man, n) for n in ("gpt2-small", "gpt2-large")}
+    out = {n: spec.config(man, n) for n in ("gpt2-small", "gpt2-large")}
+    assert all(spec.adapter(c) is gpt2 for c in out.values())
+    return out
 
 
 def test_parameter_counts(configs):
     """Worked by hand from the published widths; the totals are the models'
     well-known sizes."""
     small, large = configs["gpt2-small"], configs["gpt2-large"]
-    assert flops.block_params(small) == 7_087_872
-    assert flops.total_params(small) == 124_439_808
-    assert flops.matmul_params(small) == 85_054_464 + 50_257 * 768
-    assert flops.block_params(large) == 19_677_440
-    assert flops.total_params(large) == 774_030_080
-    assert flops.matmul_params(large) == 708_387_840 + 50_257 * 1280
+    assert gpt2.block_params(small) == 7_087_872
+    assert gpt2.total_params(small) == 124_439_808
+    assert gpt2.matmul_params(small) == 85_054_464 + 50_257 * 768
+    assert gpt2.block_params(large) == 19_677_440
+    assert gpt2.total_params(large) == 774_030_080
+    assert gpt2.matmul_params(large) == 708_387_840 + 50_257 * 1280
 
 
 def test_train_flops_per_token(configs):
-    assert flops.train_flops_per_token(configs["gpt2-small"], 1024) \
+    assert gpt2.train_flops_per_token(configs["gpt2-small"], 1024) \
         == 6 * 123_651_840 + 6 * 12 * 768 * 1024 == 798_534_144
-    assert flops.train_flops_per_token(configs["gpt2-large"], 1024) \
+    assert gpt2.train_flops_per_token(configs["gpt2-large"], 1024) \
         == 6 * 772_716_800 + 6 * 36 * 1280 * 1024 == 4_919_416_320
 
 
@@ -180,7 +184,16 @@ def test_flash_and_decode_counts(configs):
     assert f == {"fwd": 103_079_215_104.0, "bwd": 257_698_037_760.0}
     b = flops.flash_call_bytes(64, 12, 1024, 64)
     assert b == {"fwd": 402_653_184.0, "bwd": 805_306_368.0}
-    assert flops.decode_step_bytes(configs["gpt2-small"], 1000) \
+    assert gpt2.decode_step_bytes(configs["gpt2-small"], 1000) \
         == 2 * (123_651_840 + 2 * 12 * 768 * 1000) == 284_167_680
-    assert flops.decode_step_flops(configs["gpt2-large"], 8, 2000) \
+    assert gpt2.decode_step_flops(configs["gpt2-large"], 8, 2000) \
         == 2 * 772_716_800 * 8 + 4 * 36 * 1280 * 2000
+
+
+def test_attention_call_shape_divides_batch_and_heads_over_the_mesh(configs):
+    values = {"batch": 16, "seq": 1024, "mesh": {"dp": 2, "tp": 2}}
+    assert gpt2.attention_call_shape(configs["gpt2-large"], values) \
+        == (8, 10, 1024, 64)
+    assert gpt2.attention_call_shape(
+        configs["gpt2-small"], {"batch": 64, "seq": 1024, "mesh": {}}) \
+        == (64, 12, 1024, 64)

@@ -1,6 +1,8 @@
-"""``reference/gpt2.py`` against the program at tiny widths on the CPU:
-``GPTModel`` on logits, loss and gradients, and prefill + decode through
-``PagedServeEngine`` against the reference's full forward.
+"""``reference/gpt2.py`` against the program at tiny widths on the CPU,
+through GPT-2's adapter (``benchmarks/arch/gpt2.py``), which is how the
+harness reaches both: ``GPTModel`` on logits, loss and gradients, and
+prefill + decode through ``PagedServeEngine`` against the reference's full
+forward.
 
 Tolerances: both sides compute in float32 here, so they differ only by the
 order of operations: 1e-4 of the logits' range, 1e-5 relative on the loss,
@@ -13,18 +15,28 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import check, spec
-from benchmarks.reference import gpt2 as reference
-from hetu_tpu.models.gpt import GPTConfig, GPTModel
+from benchmarks.arch import gpt2 as adapter
+from benchmarks.harness import build, check, spec
 
 HEADS = 4
+# the keys of a GPT-2 configuration, at tiny widths and in float32
+CONFIG = {"n_embd": 64, "n_head": HEADS, "n_layer": 3, "n_positions": 128,
+          "n_inner": 128, "vocab_size": 500,
+          "assumed": {"embedding_rows": 512}, "compute_dtype": "float32",
+          "adapter": "benchmarks.arch.gpt2",
+          "reference": "benchmarks/reference/gpt2.py",
+          "train": {"attention_impl": "xla", "fused_ce": False,
+                    "remat": False},
+          "serve": {"attention_impl": "xla", "num_slots": 4, "max_len": 128,
+                    "page_size": 16, "prefill_chunk": 32}}
+reference = adapter.reference(CONFIG)
+TOL = {k: t["limit"] for k, t in adapter.tolerances(CONFIG).items()}
 
 
-def _model(**kw):
-    return GPTModel(GPTConfig(
-        vocab_size=512, hidden_size=64, num_layers=3, num_heads=HEADS,
-        ffn_size=128, max_position=128, dropout_rate=0.0,
-        dtype=jnp.float32, **kw))
+def _model(dtype="float32", **train):
+    config = {**CONFIG, "compute_dtype": dtype,
+              "train": {**CONFIG["train"], **train}}
+    return adapter.make_model(config, "train" if train else "serve")
 
 
 @pytest.fixture(scope="module")
@@ -44,8 +56,9 @@ def setup():
 
 def test_logits_match_the_model(setup):
     model, variables, ids = setup
-    ref = np.asarray(reference.logits(variables["params"], ids, HEADS))
-    got = np.asarray(model.apply(variables, jnp.asarray(ids))[0])
+    ref = adapter.reference_logits(variables["params"], ids, CONFIG)
+    got = adapter.system_logits(model, variables["params"], ids)
+    assert ref.dtype == got.dtype == np.float32
     assert ref.shape == got.shape == (3, 48, 512)
     assert np.max(np.abs(ref - got)) <= 1e-4 * (ref.max() - ref.min())
 
@@ -71,11 +84,12 @@ def test_loss_and_gradients_match_the_model(setup, attention_impl, fused_ce,
         assert np.max(np.abs(a - b)) <= 1e-4 * np.max(np.abs(a)) + 1e-9, \
             jax.tree_util.keystr(path)
     # the remat form the chip check uses changes no number
-    loss2, norm2 = reference.loss_and_grad_norm(variables["params"], ids,
-                                                HEADS)
-    assert float(loss2) == pytest.approx(float(ref_loss), rel=1e-6)
-    assert float(norm2) == pytest.approx(
-        float(reference.global_norm(ref)), rel=1e-5)
+    loss2, norm2 = adapter.reference_loss_and_grad_norm(
+        variables["params"], ids, CONFIG)
+    assert loss2 == pytest.approx(float(ref_loss), rel=1e-6)
+    assert norm2 == pytest.approx(float(reference.global_norm(ref)),
+                                  rel=1e-5)
+    assert norm2 == pytest.approx(float(check.global_norm(got)), rel=1e-4)
 
 
 def test_prefill_and_decode_through_the_paged_engine(setup):
@@ -83,16 +97,12 @@ def test_prefill_and_decode_through_the_paged_engine(setup):
     chunked prefill (several chunks) and eight decoded tokens through the
     page tables, four requests in flight, against the reference's full
     forward."""
-    from hetu_tpu.serve import ContinuousBatchingScheduler, PagedServeEngine
-
     model, variables, _ = setup
-    engine = PagedServeEngine(model, variables, num_slots=4, max_len=128,
-                              page_size=16, prefill_chunk=32)
-    config = {"n_head": HEADS, "vocab_size": 500, "n_positions": 128,
-              "serve": {"max_len": 128}}
-    verdict = check.serving(model, variables, engine,
-                            ContinuousBatchingScheduler(engine), config, 7)
+    engine, scheduler = build.make_serving(model, variables, CONFIG)
+    verdict = check.serving(model, variables, engine, scheduler, CONFIG, 7)
     assert verdict["ok"], verdict
+    assert verdict["limits"] == {k: TOL[k] for k in ("logit_err",
+                                                     "token_gap")}
     assert verdict["prompts"][0] == 24 and max(verdict["prompts"]) > 64
     # float32 on both sides: far inside the bf16 tolerances of the chip run
     assert verdict["logit_err"] < 1e-4
@@ -102,21 +112,15 @@ def test_prefill_and_decode_through_the_paged_engine(setup):
 def test_a_wrong_cache_read_fails_the_check(setup, monkeypatch):
     """The serving check has teeth: an engine whose decode reads one page
     too few of its cache emits tokens the reference ranks far from best."""
-    from hetu_tpu.serve import ContinuousBatchingScheduler, PagedServeEngine
-
     model, variables, _ = setup
-    engine = PagedServeEngine(model, variables, num_slots=4, max_len=128,
-                              page_size=16, prefill_chunk=32)
+    engine, scheduler = build.make_serving(model, variables, CONFIG)
     inner = model.decode_with_cache
     monkeypatch.setattr(
         model, "decode_with_cache",
         lambda v, ids, k, vv, lengths: inner(
             v, ids, k, vv, jnp.maximum(lengths - 16, 0)))
-    config = {"n_head": HEADS, "vocab_size": 500, "n_positions": 128,
-              "serve": {"max_len": 128}}
-    verdict = check.serving(model, variables, engine,
-                            ContinuousBatchingScheduler(engine), config, 7)
-    assert not verdict["ok"] and verdict["token_gap"] > check.TOKEN_GAP_TOL
+    verdict = check.serving(model, variables, engine, scheduler, CONFIG, 7)
+    assert not verdict["ok"] and verdict["token_gap"] > TOL["token_gap"]
 
 
 def test_bf16_where_f32_is_stated_fails_at_f32_tolerance(setup):
@@ -124,15 +128,11 @@ def test_bf16_where_f32_is_stated_fails_at_f32_tolerance(setup):
     float32 tolerance of this file by two orders of magnitude, and sits
     inside the chip check's bfloat16 tolerance."""
     _, variables, ids = setup
-    low = GPTModel(GPTConfig(
-        vocab_size=512, hidden_size=64, num_layers=3, num_heads=HEADS,
-        ffn_size=128, max_position=128, dropout_rate=0.0,
-        dtype=jnp.bfloat16))
-    ref = np.asarray(reference.logits(variables["params"], ids, HEADS))
-    got = np.asarray(low.apply(variables, jnp.asarray(ids))[0]
-                     .astype(jnp.float32))
+    low = _model("bfloat16")
+    ref = adapter.reference_logits(variables["params"], ids, CONFIG)
+    got = adapter.system_logits(low, variables["params"], ids)
     err = np.max(np.abs(ref - got)) / (ref.max() - ref.min())
-    assert 1e-3 < err < check.LOGIT_TOL
+    assert 1e-3 < err < TOL["logit_err"]
 
 
 def test_rehearsal_widths_are_never_the_published_ones():

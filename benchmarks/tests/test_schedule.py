@@ -74,11 +74,11 @@ def test_the_window_does_not_depend_on_a_traced_lead(chat):
 def test_seed_changes_token_ids_only(chat):
     plan = schedule.open_loop_schedule(chat, 10)
     lens = [a.prompt_len for a in plan]
-    a = schedule.token_ids(1, lens, 50257)
-    b = schedule.token_ids(2**31 + 12345, lens, 50257)
+    a = schedule.token_ids(1, lens, (0, 50257))
+    b = schedule.token_ids(2**31 + 12345, lens, (0, 50257))
     assert [len(x) for x in a] == [len(x) for x in b] == lens
     assert a != b
-    assert a == schedule.token_ids(1, lens, 50257)
+    assert a == schedule.token_ids(1, lens, (0, 50257))
     assert all(0 <= t < 50257 for x in b for t in x)
 
 

@@ -71,7 +71,7 @@ def train_cell(cell, cfg, tr, topo) -> dict:
     from hetu_tpu.train.executor import TrainState
 
     chips = int(cell["chips"])
-    model = build.make_model(cfg, "train")
+    model = spec.adapter(cfg).make_model(cfg, "train")
     axes = cfg["train"]["mesh"][str(chips)]
     key = jax.random.PRNGKey(0)
     shapes = jax.eval_shape(model.init, key)
@@ -115,7 +115,7 @@ def serve_cell(cell, cfg, tr, topo) -> dict:
     from hetu_tpu.serve import PagedServeEngine
 
     one = SingleDeviceSharding(topo.devices[0])
-    model = build.make_model(cfg, "serve")
+    model = spec.adapter(cfg).make_model(cfg, "serve")
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     s = cfg["serve"]
     # a one-page pool: the engine is built only for its step builders
