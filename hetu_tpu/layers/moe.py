@@ -15,6 +15,16 @@ dispatched tokens constrained to P('ep', ...), and XLA's SPMD partitioner
 materializes the all_to_all exactly where the reference called alltoall_op
 (gpu_ops/AllToAll.py).  Gates produce (combine_weights [T,k],
 expert_idx [T,k], aux_loss).
+
+Which routing is which.  :class:`MoELayer` and its gates are the CAPACITY
+routing: every expert has a static number of slots
+(``capacity_factor``), is padded to it, and tokens past it are DROPPED; the
+gate routes over exactly the experts the layer holds.  It is what
+``models/moe_transformer.py`` trains with, and it is not served.
+:class:`HeldExpertLayer` at the end of this file is the HELD-EXPERT routing
+of the served model (``models/longcat_flash.py``): a router as wide as
+published over experts of which this chip holds a share, identity experts
+behind them, no capacity, no drops.
 """
 
 from __future__ import annotations
@@ -28,9 +38,9 @@ from hetu_tpu import init as initializers
 from hetu_tpu import ops
 from hetu_tpu.layers.base import Module
 from hetu_tpu.ops.moe_ops import (
-    balance_assignment, gather_combine, gather_dispatch, layout_transform,
-    make_dispatch_combine, make_slot_routing, reverse_layout_transform,
-    top_k_idx_gate,
+    balance_assignment, gather_combine, gather_dispatch, held_expert_ffn,
+    layout_transform, make_dispatch_combine, make_slot_routing,
+    reverse_layout_transform, route_biased_top_k, top_k_idx_gate,
 )
 
 
@@ -312,3 +322,69 @@ class MoELayer(Module):
                        n_dropped.astype(jnp.float32) / (T * k_choices)}
             return (out, aux, metrics), {}
         return (out, aux), {}
+
+
+# what HeldExpertLayer counts, in the order of its counts vector: (token,
+# choice) pairs routed to held experts, to identity experts, to experts
+# other chips hold; held experts chosen at least once
+MOE_STATS = ("moe_held", "moe_zero", "moe_absent", "moe_hit")
+
+
+class HeldExpertLayer:
+    """One chip's share of an expert layer, without capacity and without
+    drops.  The router is as wide as published, ``n_routed + n_zero``:
+    indices below ``n_routed`` are SwiGLU experts, of which this chip holds
+    ``held = (first, count)``; the ``n_zero`` behind them are identity
+    experts, which every chip computes for its own tokens.  For tokens ``u``::
+
+        s = softmax(float32(u) @ float32(router))          # all experts
+        chosen: the k largest of s + router_bias            # bias: choice only
+        i held:      + scaling * s_i * W_down_i(silu(W_gate_i u) * W_up_i u)
+        i identity:  + scaling * s_i * u
+        i absent:    nothing (that chip adds it; nothing stands in for it)
+
+    The weights are not renormalised over the chosen k.  The identity
+    experts are one weighted sum of ``u``, never a matmul; the held experts'
+    work follows the pairs routed to them (``ops.moe_ops.held_expert_ffn``).
+    Parameters (one layer's): ``router`` [H, n_routed + n_zero] float32,
+    ``router_bias`` [n_routed + n_zero] float32, ``gate``/``up`` [count, H,
+    F], ``down`` [count, F, H]."""
+
+    def __init__(self, *, n_routed: int, n_zero: int, k: int, scaling: float,
+                 held: tuple, block_rows: int = 128, dtype=jnp.bfloat16):
+        self.n_routed, self.n_zero, self.k = n_routed, n_zero, k
+        self.scaling = float(scaling)
+        self.first, self.count = held
+        self.block_rows = block_rows
+        self.dtype = dtype
+
+    def route(self, p, tokens):
+        """tokens [T, H] -> (weights [T, k] float32, scaling included,
+        idx [T, k])."""
+        with jax.named_scope("hetu.moe.route"):
+            scores = jax.nn.softmax(
+                jnp.dot(tokens.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST), axis=-1)
+            w, idx = route_biased_top_k(scores, p["router_bias"], self.k)
+            return w * self.scaling, idx
+
+    def apply(self, p, u, *, static_trip: bool = False, layer=None):
+        """u [..., H] -> (m [..., H] in u's dtype, counts [4] int32 in
+        ``MOE_STATS`` order).  With ``layer`` given, ``gate``/``up``/``down``
+        are stacked over layers and this is layer ``layer`` of them."""
+        tokens = u.reshape(-1, u.shape[-1])
+        w, idx = self.route(p, tokens)
+        zero = idx >= self.n_routed
+        with jax.named_scope("hetu.moe.zero"):
+            out = jnp.sum(jnp.where(zero, w, 0.0), -1, keepdims=True) \
+                * tokens.astype(jnp.float32)
+        with jax.named_scope("hetu.moe.experts"):
+            routed, per_expert = held_expert_ffn(
+                tokens.astype(self.dtype), w, idx, p["gate"], p["up"],
+                p["down"], first=self.first, block_rows=self.block_rows,
+                static_trip=static_trip, layer=layer)
+        n_held = per_expert.sum()
+        n_zero = zero.sum().astype(jnp.int32)
+        stats = jnp.stack([n_held, n_zero, idx.size - n_held - n_zero,
+                           (per_expert > 0).sum().astype(jnp.int32)])
+        return (out + routed).astype(u.dtype).reshape(u.shape), stats

@@ -3,6 +3,12 @@
 Reference: examples/moe (HetuMoE scripts, top-1/top-2 gating over 8-16 GPUs)
 — here the experts shard over the 'ep' mesh axis and XLA inserts the A2A pair
 (BASELINE.json config #5 workload).
+
+Routing: the CAPACITY path (``layers/moe.py`` ``MoELayer``: static slots per
+expert, padded, overflow dropped).  This model trains; it has no cache entry
+points and is not served.  The served expert model is
+``models/longcat_flash.py``, whose ``HeldExpertLayer`` routes without
+capacity and without drops.
 """
 
 from __future__ import annotations

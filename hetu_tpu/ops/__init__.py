@@ -26,7 +26,7 @@ from hetu_tpu.ops.norm import (
     batch_norm, layer_norm, instance_norm2d, rms_norm,
 )
 from hetu_tpu.ops.rope import (
-    apply_rope, apply_rope_at, rope_tables,
+    apply_rope, apply_rope_at, apply_rope_interleaved, rope_tables,
 )
 from hetu_tpu.ops.activations import (
     relu, leaky_relu, gelu, sigmoid, tanh, softmax, log_softmax, silu,

@@ -9,6 +9,15 @@ kernels we build one-hot *dispatch* and *combine* tensors so the whole
 token->expert permutation is two einsums — dense MXU work that XLA overlaps
 with the expert all_to_all.  Capacity is static (required by XLA); overflow
 tokens are dropped exactly like the reference's capacity_factor path.
+
+Two routings live here, and they are not interchangeable.  Everything down to
+:func:`balance_assignment` is the CAPACITY routing that ``layers/moe.py``'s
+``MoELayer`` trains with: each expert gets a static number of slots, is
+padded to it, and drops what overflows.  :func:`route_biased_top_k` and
+:func:`held_expert_ffn` at the end are the HELD-EXPERT routing a served
+model uses (``models/longcat_flash.py``): a router as wide as published
+over experts of which this chip holds a contiguous share, no capacity, no
+drops, and work that follows the rows routed here.
 """
 
 from __future__ import annotations
@@ -143,3 +152,88 @@ def balance_assignment(scores, *, iters: int = 20):
 
     lp = lax.fori_loop(0, iters, body, logp)
     return jnp.argmax(lp, axis=-1)
+
+
+# ---------------------------------------------------------------------------
+# held-expert routing: no capacity, no drops (the served path)
+# ---------------------------------------------------------------------------
+
+def route_biased_top_k(scores, bias, k: int):
+    """Choose each token's ``k`` experts by ``scores + bias`` and weigh them
+    by ``scores`` alone (the correction bias steers the choice and never the
+    weight).  scores [T, E] float32, bias [E] -> (weights [T, k] float32,
+    idx [T, k] int32).  No renormalisation over the chosen ``k``."""
+    _, idx = lax.top_k(scores + bias, k)
+    return jnp.take_along_axis(scores, idx, axis=-1), idx.astype(jnp.int32)
+
+
+def held_expert_blocks(tokens: int, k: int, count: int,
+                       block_rows: int) -> int:
+    """The most row blocks :func:`held_expert_ffn` can need: a token's
+    ``k`` choices are distinct, so at most ``min(k, count)`` of them land on
+    held experts, and each held expert's last block may be part empty."""
+    return -(-tokens * min(k, count) // block_rows) + count
+
+
+def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
+                    block_rows: int = 128, static_trip: bool = False,
+                    layer=None):
+    """SwiGLU experts over the (token, choice) pairs that land on the
+    experts held here; what absent experts would add is left out.
+
+    x [T, H]; weights/idx [T, k] from the router over ALL experts;
+    w_gate/w_up [E, H, F], w_down [E, F, H]: the ``E`` held experts, global
+    indices ``first .. first + E - 1``; with ``layer`` given they are
+    stacked over layers, [L, E, ...], and this is layer ``layer`` (inside a
+    scan over layers a block then reads its expert straight from the stacked
+    leaf; sliced a layer at a time first, the slice is copied every step).
+    Returns (out [T, H] float32, counts [E] int32 pairs per held expert).
+
+    No capacity and no drops: the pairs are sorted by held expert and walked
+    in blocks of ``block_rows`` rows, ``ceil(count_e / block_rows)`` blocks
+    for expert ``e``, so every pair is computed at any imbalance and the
+    work follows the rows routed here, not ``T * k``.  An expert nobody chose costs
+    nothing: its weights are not read.  Nothing is bounded by a buffer of
+    rows: a block gathers its rows from ``x`` and adds its result into
+    ``out``; only the sorted pair indices are held, ``T * k + block_rows``
+    integers.  The trip count is read from the counts; under
+    ``static_trip`` (reverse-mode differentiation needs a static one) it is
+    :func:`held_expert_blocks` and the blocks past the last are empty."""
+    T, k = idx.shape
+    E = w_gate.shape[0 if layer is None else 1]
+    R = int(block_rows)
+
+    def of(w, e):
+        return (w[e] if layer is None else w[layer, e]).astype(dt)
+
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)                       # absent: sorted last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    blocks = -(-counts // R)                              # per expert
+    block_end = jnp.cumsum(blocks)                        # [E]
+    row_start = jnp.cumsum(counts) - counts               # [E]
+    # pad so that a block's slice never clamps
+    order = jnp.concatenate([order, jnp.zeros((R,), jnp.int32)])
+    pair_w = weights.reshape(-1)
+    dt = x.dtype
+
+    def body(b, out):
+        e = jnp.minimum(jnp.searchsorted(block_end, b, side="right"), E - 1)
+        within = b - (block_end[e] - blocks[e])           # block of expert e
+        start = row_start[e] + within * R
+        pairs = lax.dynamic_slice_in_dim(order, start, R)
+        live = (start + jnp.arange(R)) < row_start[e] + counts[e]
+        live = live & (b < block_end[E - 1])
+        rows = x[pairs // k]                              # [R, H]
+        g = jnp.dot(rows, of(w_gate, e))
+        u = jnp.dot(rows, of(w_up, e))
+        y = jnp.dot(jax.nn.silu(g) * u, of(w_down, e),
+                    preferred_element_type=jnp.float32)
+        y = jnp.where(live[:, None], y * pair_w[pairs][:, None], 0.0)
+        return out.at[pairs // k].add(y)
+
+    out = jnp.zeros(x.shape, jnp.float32)
+    trips = held_expert_blocks(T, k, E, R) if static_trip else block_end[E - 1]
+    return lax.fori_loop(0, trips, body, out), counts

@@ -8,6 +8,8 @@ pairs with two fused multiplies — no gather, no complex dtype.
 
 Convention: HALF-ROTATION layout (the HF/Llama one) — the head dim is
 split [x1 | x2] and rotated as (x1*cos - x2*sin, x2*cos + x1*sin).
+:func:`apply_rope_interleaved` is the other published layout: neighbouring
+pairs (x[2i], x[2i+1]) rotate together (latent-attention models).
 """
 
 from __future__ import annotations
@@ -63,3 +65,14 @@ def apply_rope_at(x, cos, sin, positions):
     x1, x2 = x[..., :d2], x[..., d2:]
     return jnp.concatenate(
         [x1 * c - x2 * sn, x2 * c + x1 * sn], axis=-1)
+
+
+def apply_rope_interleaved(x, cos, sin):
+    """Rotate ``x [..., D]`` pairwise: (x[2i], x[2i+1]) by angle ``i`` of its
+    position.  cos/sin ``[..., D/2]`` are already gathered at each token's
+    position and broadcast against ``x``'s leading dims; float32 inside,
+    result in ``x``'s dtype."""
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], x.shape[-1] // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -395,6 +395,28 @@ class PagedServeEngine:
         self._decode_fn = None     # one jit, specialized per page bucket
         self._seen_chunk_buckets = set()
         self._seen_page_buckets = set()
+        # names of the counts a model's cache entry points return beside
+        # their logits, in order (an expert model: pairs routed to held,
+        # identity and absent experts, held experts hit); most have none
+        self._stat_names = tuple(getattr(model, "step_stats", ()))
+        k_row, v_row = spec.row_shapes()
+        trace.instant("serve.cache_spec", {
+            "k_width": int(np.prod(k_row)), "v_width": int(np.prod(v_row)),
+            "cache_layers": int(spec.num_layers),
+            "bytes_per_token": int(spec.bytes_per_token)})
+
+    def _count(self, stats):
+        """The counts a step returned beside its tokens, added to the
+        metrics under the model's names for them and returned as ids for
+        the step's ``post`` span (a span's ids are fixed when it opens, and
+        these arrive with the tokens); None from a model that counts
+        nothing."""
+        if not stats:
+            return None
+        ids = dict(zip(self._stat_names, np.asarray(stats[0]).tolist()))
+        for name, n in ids.items():
+            self.metrics.inc(name, n)
+        return ids
 
     # ---- compile accounting ----
     def compiled_executables(self) -> int:
@@ -446,7 +468,7 @@ class PagedServeEngine:
         cache = self.cache
         ps = cache.page_size
         L = cache.spec.num_layers
-        H, D = cache.spec.num_kv_heads, cache.spec.head_dim
+        k_row, v_row = cache.spec.row_shapes()
 
         def fn(params, k_pool, v_pool, aux):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
@@ -459,9 +481,11 @@ class PagedServeEngine:
             table = aux[3 * sc:3 * sc + n_table]
             start = aux[3 * sc + n_table]
             last = aux[3 * sc + n_table + 1]
-            k_seq = k_pool[:, table].reshape(L, 1, n_table * ps, H, D)
-            v_seq = v_pool[:, table].reshape(L, 1, n_table * ps, H, D)
-            logits, k_seq, v_seq = model.prefill_chunk_with_cache(
+            k_seq = k_pool[:, table].reshape((L, 1, n_table * ps) + k_row)
+            v_seq = v_pool[:, table].reshape((L, 1, n_table * ps) + v_row)
+            # a model may return a fourth value, its per-call counts
+            # (``model.step_stats`` names them); most return none
+            logits, k_seq, v_seq, *stats = model.prefill_chunk_with_cache(
                 {"params": params, "state": {}}, ids, k_seq, v_seq,
                 start, last_index=last)
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
@@ -473,7 +497,7 @@ class PagedServeEngine:
             # positions land in their pages, pad positions in scratch 0
             k_pool = k_pool.at[:, wpage, woff].set(rows_k)
             v_pool = v_pool.at[:, wpage, woff].set(rows_v)
-            return k_pool, v_pool, tok
+            return k_pool, v_pool, tok, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
@@ -482,7 +506,7 @@ class PagedServeEngine:
         cache = self.cache
         ps = cache.page_size
         L = cache.spec.num_layers
-        H, D = cache.spec.num_kv_heads, cache.spec.head_dim
+        k_row, v_row = cache.spec.row_shapes()
 
         def fn(params, k_pool, v_pool, aux):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
@@ -497,9 +521,9 @@ class PagedServeEngine:
             tokens = aux[:, n_pg + 1]
             wpage = aux[:, n_pg + 2]
             woff = aux[:, n_pg + 3]
-            k_seq = k_pool[:, tables].reshape(L, b, n_pg * ps, H, D)
-            v_seq = v_pool[:, tables].reshape(L, b, n_pg * ps, H, D)
-            logits, k_seq, v_seq = model.decode_with_cache(
+            k_seq = k_pool[:, tables].reshape((L, b, n_pg * ps) + k_row)
+            v_seq = v_pool[:, tables].reshape((L, b, n_pg * ps) + v_row)
+            logits, k_seq, v_seq, *stats = model.decode_with_cache(
                 {"params": params, "state": {}}, tokens, k_seq, v_seq,
                 lengths)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
@@ -516,7 +540,7 @@ class PagedServeEngine:
                 in_axes=(1, 0), out_axes=1)(v_seq, lengths)
             k_pool = k_pool.at[:, wpage, woff].set(tok_k)
             v_pool = v_pool.at[:, wpage, woff].set(tok_v)
-            return k_pool, v_pool, nxt
+            return k_pool, v_pool, nxt, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
@@ -649,11 +673,12 @@ class PagedServeEngine:
             with trace.span("serve.prefill_chunk.launch",
                             {"start": int(start), "tokens": int(size),
                              "bucket": int(s)}):
-                k, v, tok = chunk_fn(
+                k, v, tok, stats = chunk_fn(
                     self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
             with trace.span("serve.prefill_chunk.fetch"):
                 tok = int(tok)  # the host blocked on the device
-            with trace.span("serve.prefill_chunk.post"):
+                counts = self._count(stats)
+            with trace.span("serve.prefill_chunk.post", counts):
                 self.cache.update(k, v)
                 self.cache.lengths[slot] = end
                 cur.pos = end
@@ -746,12 +771,13 @@ class PagedServeEngine:
                 aux[:, n_pg + 3] = wo
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb)}):
-                k, v, nxt = self._decode_fn(
+                k, v, nxt, stats = self._decode_fn(
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(aux))
             with trace.span("serve.decode.fetch"):
                 nxt = np.asarray(nxt)  # the host blocked on the device
-            with trace.span("serve.decode.post"):
+                counts = self._count(stats)
+            with trace.span("serve.decode.post", counts):
                 self.cache.update(k, v)
                 out = {}
                 for i, slot in enumerate(act):

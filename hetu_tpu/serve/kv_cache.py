@@ -36,6 +36,7 @@ the per-slot host-side lengths.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
@@ -43,18 +44,46 @@ import numpy as np
 
 @dataclass(frozen=True)
 class KVCacheSpec:
-    """Per-layer cache geometry, derived from a model config."""
+    """Per-layer cache geometry, derived from a model config.
+
+    The two pools need not be of one width: ``head_dim`` is the K pool's,
+    ``v_head_dim`` the V pool's (None: the same).  A latent-attention model
+    keeps its normalised latent in the K pool and its rotated shared key in
+    the V pool, one "head" each.  ``num_layers`` counts CACHE layers, which
+    a model with two attention blocks a layer has twice as many of."""
 
     num_layers: int
     num_kv_heads: int
     head_dim: int
     dtype: object = jnp.float32
+    v_head_dim: Optional[int] = None
+
+    @property
+    def v_dim(self) -> int:
+        """Width of the V pool's rows."""
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    def row_shapes(self) -> tuple:
+        """((heads, K width), (heads, V width)) of one token's rows."""
+        return ((self.num_kv_heads, self.head_dim),
+                (self.num_kv_heads, self.v_dim))
+
+    @property
+    def bytes_per_token(self) -> int:
+        """Bytes one cached token takes over all cache layers."""
+        return (self.num_layers * self.num_kv_heads
+                * (self.head_dim + self.v_dim)
+                * np.dtype(self.dtype).itemsize)
 
     @staticmethod
     def from_model(model) -> "KVCacheSpec":
-        """Read the geometry off a GPTModel/LlamaModel config: models with
-        ``num_kv_heads`` are GQA (cache the un-repeated heads); the rest
-        cache all ``num_heads``."""
+        """A model that states its own cache (``kv_cache_spec()``) is
+        asked; otherwise the geometry is read off a GPTModel/LlamaModel
+        config: models with ``num_kv_heads`` are GQA (cache the un-repeated
+        heads); the rest cache all ``num_heads``."""
+        own = getattr(model, "kv_cache_spec", None)
+        if own is not None:
+            return own()
         c = model.c
         nkv = getattr(c, "num_kv_heads", None) or c.num_heads
         return KVCacheSpec(
@@ -102,10 +131,11 @@ class KVCache:
         self.spec = spec
         self.num_slots = int(num_slots)
         self.max_len = int(max_len)
-        shape = (spec.num_layers, num_slots, max_len, spec.num_kv_heads,
-                 spec.head_dim)
-        self.k = jnp.zeros(shape, spec.dtype)
-        self.v = jnp.zeros(shape, spec.dtype)
+        k_row, v_row = spec.row_shapes()
+        self.k = jnp.zeros((spec.num_layers, num_slots, max_len) + k_row,
+                           spec.dtype)
+        self.v = jnp.zeros((spec.num_layers, num_slots, max_len) + v_row,
+                           spec.dtype)
         if sharding is not None:
             import jax
             self.k = jax.device_put(self.k, sharding)
@@ -194,9 +224,9 @@ class KVCache:
                 raise ValueError(
                     f"slot snapshot of {s.length} tokens does not leave "
                     f"room to decode within max_len {self.max_len}")
-            want = (spec.num_layers, s.length, spec.num_kv_heads,
-                    spec.head_dim)
-            for name, arr in (("k", s.k), ("v", s.v)):
+            for name, arr, row in (("k", s.k, spec.row_shapes()[0]),
+                                   ("v", s.v, spec.row_shapes()[1])):
+                want = (spec.num_layers, s.length) + row
                 if tuple(arr.shape) != want:
                     raise ValueError(
                         f"{name} geometry mismatch: snapshot "
@@ -232,10 +262,9 @@ class KVCache:
                 while pad < s.length:
                     pad *= 2
                 pad = min(pad, self.max_len)
-                pad_shape = (spec.num_layers, 1, pad, spec.num_kv_heads,
-                             spec.head_dim)
-                k_rows = np.zeros(pad_shape, dt)
-                v_rows = np.zeros(pad_shape, dt)
+                k_row, v_row = spec.row_shapes()
+                k_rows = np.zeros((spec.num_layers, 1, pad) + k_row, dt)
+                v_rows = np.zeros((spec.num_layers, 1, pad) + v_row, dt)
                 k_rows[:, 0, :s.length] = s.k
                 v_rows[:, 0, :s.length] = s.v
                 self.k, self.v = self._import_fn(
@@ -342,10 +371,10 @@ class PagedKVCache:
         self.num_pages = int(num_pages)
         if self.num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is scratch)")
-        shape = (spec.num_layers, self.num_pages, self.page_size,
-                 spec.num_kv_heads, spec.head_dim)
-        self.k = jnp.zeros(shape, spec.dtype)
-        self.v = jnp.zeros(shape, spec.dtype)
+        k_row, v_row = spec.row_shapes()
+        lead = (spec.num_layers, self.num_pages, self.page_size)
+        self.k = jnp.zeros(lead + k_row, spec.dtype)
+        self.v = jnp.zeros(lead + v_row, spec.dtype)
         if sharding is not None:
             import jax
             self.k = jax.device_put(self.k, sharding)
@@ -693,9 +722,9 @@ class PagedKVCache:
                 raise ValueError(
                     f"slot snapshot of {s.length} tokens does not leave "
                     f"room to decode within max_len {self.max_len}")
-            want = (spec.num_layers, s.length, spec.num_kv_heads,
-                    spec.head_dim)
-            for name, arr in (("k", s.k), ("v", s.v)):
+            for name, arr, row in (("k", s.k, spec.row_shapes()[0]),
+                                   ("v", s.v, spec.row_shapes()[1])):
+                want = (spec.num_layers, s.length) + row
                 if tuple(arr.shape) != want:
                     raise ValueError(
                         f"{name} geometry mismatch: snapshot "
@@ -738,11 +767,11 @@ class PagedKVCache:
                 pages = np.zeros(pad, np.int32)  # surplus -> scratch 0
                 pages[:n_pg] = table
                 L = spec.num_layers
-                shape = (L, pad, ps, spec.num_kv_heads, spec.head_dim)
-                k_pg = np.zeros(shape, dt)
-                v_pg = np.zeros(shape, dt)
-                k_pg.reshape(L, pad * ps, *shape[3:])[:, :s.length] = s.k
-                v_pg.reshape(L, pad * ps, *shape[3:])[:, :s.length] = s.v
+                k_row, v_row = spec.row_shapes()
+                k_pg = np.zeros((L, pad, ps) + k_row, dt)
+                v_pg = np.zeros((L, pad, ps) + v_row, dt)
+                k_pg.reshape(L, pad * ps, *k_row)[:, :s.length] = s.k
+                v_pg.reshape(L, pad * ps, *v_row)[:, :s.length] = s.v
                 self.k, self.v = self._import_fn(
                     self.k, self.v, jnp.asarray(k_pg), jnp.asarray(v_pg),
                     jnp.asarray(pages))

@@ -222,7 +222,7 @@ def estimate_payload_bytes(engine) -> int:
     cache = engine.cache
     spec = cache.spec
     itemsize = _np_dtype(str(np.dtype(spec.dtype))).itemsize
-    per_tok = 2 * spec.num_kv_heads * spec.head_dim * itemsize
+    per_tok = spec.num_kv_heads * (spec.head_dim + spec.v_dim) * itemsize
     live_tokens = int(np.sum(cache.lengths))
     return live_tokens * spec.num_layers * per_tok
 
@@ -368,6 +368,7 @@ def pack(spec, snapshots, records=(), *, codec: str = "none") -> bytes:
         "spec": {"num_layers": int(spec.num_layers),
                  "num_kv_heads": int(spec.num_kv_heads),
                  "head_dim": int(spec.head_dim),
+                 "v_head_dim": int(spec.v_dim),
                  "dtype": dt.name},
         "slots": slots_meta,
         "records": list(records),
@@ -416,6 +417,7 @@ def unpack(payload: bytes):
     L = int(spec_d["num_layers"])
     H = int(spec_d["num_kv_heads"])
     D = int(spec_d["head_dim"])
+    Dv = int(spec_d.get("v_head_dim", D))  # older payloads: one width
     snaps = []
     pos = 0
     bodyv = memoryview(body)
@@ -427,7 +429,7 @@ def unpack(payload: bytes):
         # any frombuffer touches the body — a corrupt meta fails loudly,
         # never reshapes garbage
         nk = _encoded_tokens(kb, codec, dt, L, H, D)
-        nv = _encoded_tokens(vb, codec, dt, L, H, D)
+        nv = _encoded_tokens(vb, codec, dt, L, H, Dv)
         if nk < 0 or nv < 0:
             raise MigrationError(
                 f"slot {m['slot']}: K/V bytes do not factor into the "
@@ -436,7 +438,7 @@ def unpack(payload: bytes):
             k = _decode_kv(bodyv[pos:pos + kb], codec, dt, (L, nk, H, D),
                            int(m["slot"]), "k")
             v = _decode_kv(bodyv[pos + kb:pos + kb + vb], codec, dt,
-                           (L, nv, H, D), int(m["slot"]), "v")
+                           (L, nv, H, Dv), int(m["slot"]), "v")
         except ValueError as e:
             raise MigrationError(
                 f"slot {m['slot']}: K/V bytes do not factor into the "
@@ -459,8 +461,10 @@ def check_spec(spec, spec_dict: dict) -> None:
     mine = {"num_layers": int(spec.num_layers),
             "num_kv_heads": int(spec.num_kv_heads),
             "head_dim": int(spec.head_dim),
+            "v_head_dim": int(spec.v_dim),
             "dtype": np.dtype(spec.dtype).name}
     theirs = {k: spec_dict.get(k) for k in mine}
+    theirs["v_head_dim"] = spec_dict.get("v_head_dim", theirs["head_dim"])
     if mine != theirs:
         raise MigrationError(
             f"KV cache geometry mismatch: payload {theirs} vs local "
