@@ -143,7 +143,10 @@ class GPTModel(Module):
         input_ids: [B, S_c] at absolute positions ``start .. start+S_c-1``
         (right-padded within the chunk bucket; pad positions produce junk
         K/V that decode masks/overwrites).  k_cache/v_cache:
-        [L, B, T, nh, hd] with positions ``< start`` already written.
+        [L, B, T, nh, hd] with positions ``< start`` already written (or
+        whatever ``ops.read_cache_layer`` reads such a layer from: the
+        paged engine hands its pools with their page tables and write map),
+        carried through the layer scan (``ops.scan_cached_layers``).
         Returns (logits [B, V] at chunk-relative ``last_index``
         (default S_c - 1), new_k, new_v).  With start == 0 and one chunk
         covering the prompt, the numerics match
@@ -163,15 +166,10 @@ class GPTModel(Module):
                        mode="clip")
         h = (h + pos[None]).astype(c.dtype)
         starts = jnp.full((b,), start, jnp.int32)
-
-        def layer(carry, xs):
-            p_l, k_l, v_l = xs
-            out, k_l, v_l = self.block.prefill_chunk_step(
-                {"params": p_l, "state": {}}, carry, k_l, v_l, starts)
-            return out, (k_l, v_l)
-
-        h, (k_cache, v_cache) = jax.lax.scan(
-            layer, h, (p["blocks"], k_cache, v_cache))
+        h, k_cache, v_cache = ops.scan_cached_layers(
+            lambda p_l, h, k_l, v_l: self.block.prefill_chunk_step(
+                {"params": p_l, "state": {}}, h, k_l, v_l, starts),
+            p["blocks"], h, k_cache, v_cache, starts, s)
         h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
         idx = s - 1 if last_index is None else last_index
         h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
@@ -183,22 +181,18 @@ class GPTModel(Module):
         """One decode step for a batch of cached sequences.
 
         input_ids: [B] int32 newest token per sequence; k_cache/v_cache:
-        [L, B, T, nh, hd]; lengths: [B] int32 tokens already cached (the
-        new token's position).  Returns (logits [B, V], new_k, new_v).
+        [L, B, T, nh, hd] (or as :meth:`prefill_chunk_with_cache` takes
+        them); lengths: [B] int32 tokens already cached (the new token's
+        position).  Returns (logits [B, V], new_k, new_v).
         """
         p = variables["params"]
         c = self.c
         h = ops.embedding_lookup(p["tok_emb"], input_ids[:, None])
         h = (h + p["pos_emb"][lengths][:, None]).astype(c.dtype)
-
-        def layer(carry, xs):
-            p_l, k_l, v_l = xs
-            out, k_l, v_l = self.block.decode_step(
-                {"params": p_l, "state": {}}, carry, k_l, v_l, lengths)
-            return out, (k_l, v_l)
-
-        h, (k_cache, v_cache) = jax.lax.scan(
-            layer, h, (p["blocks"], k_cache, v_cache))
+        h, k_cache, v_cache = ops.scan_cached_layers(
+            lambda p_l, h, k_l, v_l: self.block.decode_step(
+                {"params": p_l, "state": {}}, h, k_l, v_l, lengths),
+            p["blocks"], h, k_cache, v_cache, lengths, 1)
         h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
         logits = ops.linear(h[:, 0], p["tok_emb"].T.astype(c.dtype))
         return logits, k_cache, v_cache

@@ -297,16 +297,10 @@ class LlamaModel(Module):
         # full tables, gathered per token at its absolute position
         cos, sin = self._tables(c.max_position)
         starts = jnp.full((b,), start, jnp.int32)
-
-        def layer(carry, xs):
-            p_l, k_l, v_l = xs
-            out, k_l, v_l = self.block.prefill_chunk_step(
-                {"params": p_l, "state": {}}, carry, k_l, v_l, starts,
-                cos, sin)
-            return out, (k_l, v_l)
-
-        h, (k_cache, v_cache) = jax.lax.scan(
-            layer, h, (p["blocks"], k_cache, v_cache))
+        h, k_cache, v_cache = ops.scan_cached_layers(
+            lambda p_l, h, k_l, v_l: self.block.prefill_chunk_step(
+                {"params": p_l, "state": {}}, h, k_l, v_l, starts, cos, sin),
+            p["blocks"], h, k_cache, v_cache, starts, s)
         h = ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
         idx = s - 1 if last_index is None else last_index
         h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
@@ -323,16 +317,10 @@ class LlamaModel(Module):
             p["tok_emb"], input_ids[:, None]).astype(c.dtype)
         # full tables, gathered per sequence at its own position
         cos, sin = self._tables(c.max_position)
-
-        def layer(carry, xs):
-            p_l, k_l, v_l = xs
-            out, k_l, v_l = self.block.decode_step(
-                {"params": p_l, "state": {}}, carry, k_l, v_l, lengths,
-                cos, sin)
-            return out, (k_l, v_l)
-
-        h, (k_cache, v_cache) = jax.lax.scan(
-            layer, h, (p["blocks"], k_cache, v_cache))
+        h, k_cache, v_cache = ops.scan_cached_layers(
+            lambda p_l, h, k_l, v_l: self.block.decode_step(
+                {"params": p_l, "state": {}}, h, k_l, v_l, lengths, cos, sin),
+            p["blocks"], h, k_cache, v_cache, lengths, 1)
         h = ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
         logits = ops.linear(h[:, 0], p["lm_head"].T.astype(c.dtype))
         return logits, k_cache, v_cache

@@ -394,20 +394,25 @@ class LongcatFlashModel(Module):
 
     # ---- serving (hetu_tpu/serve): latent-cache prefill / decode ----
     # k_cache [2L, B, T, 1, kv_rank] holds the latents, v_cache [2L, B, T, 1,
-    # rope] the rotated shared keys; cache layer 2l + i is attention block i
-    # of double layer l.  Both entry points return a fourth value, the
+    # rope] the rotated shared keys (or whatever ``ops.read_cache_layer``
+    # reads such layers from: the paged engine's pools with their page
+    # tables and write map); cache layer 2l + i is attention block i of
+    # double layer l.  Both entry points return a fourth value, the
     # expert layers' counts (``step_stats`` names them) summed over the
     # layers.
 
-    def _cached(self, p, input_ids, k_cache, v_cache, pos, write, attend_over):
+    def _cached(self, p, input_ids, k_cache, v_cache, pos, attend_over):
         """Both cache entry points: the double layers scanned with the two
-        caches CARRIED, so that a block's new rows are written into them in
-        place (as scan inputs and outputs they would be held twice).
-        ``write(cache, layer, rows)`` puts the block's new rows [B, S, w]
-        into cache layer ``layer``; ``attend_over(pa, q_n, q_r, c_all,
-        r_all)`` is the phase's attention over one cache layer."""
+        caches CARRIED (as scan inputs and outputs they would be held twice
+        and rewritten whole).  An attention block reads its own cache layer
+        of each (``ops.read_cache_layer``: of the paged engine's pools, that
+        layer's pages and no more), writes its new rows [B, S, w] into those
+        views from each sequence's first position ``pos[:, 0]`` on, attends
+        over them (``attend_over(pa, q_n, q_r, c_all, r_all)``, the phase's
+        form) and puts the new rows back (``ops.write_cache_layer``)."""
         h = self._embed(p, input_ids)
         cos, sin = self.attn.rope_at(pos)
+        at, s = pos[:, 0], pos.shape[1]
 
         layers = p["layers"]
 
@@ -418,13 +423,17 @@ class LongcatFlashModel(Module):
             def attend(i, x):
                 pa = self._block(layers["attn"], l, i)
                 q_n, q_r, c, k_r = self.attn.project(pa, x, cos, sin)
-                at = 2 * l + i
-                caches[0] = write(caches[0], at, c)
-                caches[1] = write(caches[1], at, k_r.astype(v_all.dtype))
-                c_all = jax.lax.dynamic_index_in_dim(caches[0], at, 0, False)
-                r_all = jax.lax.dynamic_index_in_dim(caches[1], at, 0, False)
-                return attend_over(pa, q_n, q_r, c_all[:, :, 0],
-                                   r_all[:, :, 0])
+                cl = 2 * l + i
+                # the views without their one-head axis: written to with it,
+                # a view's (1, width) minor pair is tiled two rows deep and
+                # copied whole before the attention can read it
+                c_all, r_all = ops.cache_update(
+                    ops.read_cache_layer(caches[0], cl)[:, :, 0],
+                    ops.read_cache_layer(caches[1], cl)[:, :, 0],
+                    c, k_r.astype(self.c.dtype), at)
+                caches[0] = ops.write_cache_layer(caches[0], cl, c_all, at, s)
+                caches[1] = ops.write_cache_layer(caches[1], cl, r_all, at, s)
+                return attend_over(pa, q_n, q_r, c_all, r_all)
 
             out, stats = self._double_layer(layers, l, h, attend,
                                             static_trip=False)
@@ -443,16 +452,12 @@ class LongcatFlashModel(Module):
         b, s = input_ids.shape
         pos = start + jnp.broadcast_to(jnp.arange(s)[None], (b, s))
 
-        def write(cache, layer, rows):
-            return jax.lax.dynamic_update_slice(
-                cache, rows[None, :, :, None], (layer, 0, start, 0, 0))
-
         def attend_over(pa, q_n, q_r, c_all, r_all):
             with jax.named_scope("hetu.mla.prefill"):
                 return self.attn.expanded(pa, q_n, q_r, c_all, r_all, pos)
 
         h, k_cache, v_cache, stats = self._cached(
-            p, input_ids, k_cache, v_cache, pos, write, attend_over)
+            p, input_ids, k_cache, v_cache, pos, attend_over)
         idx = s - 1 if last_index is None else last_index
         h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
         return self._head(p, h), k_cache, v_cache, stats
@@ -462,10 +467,6 @@ class LongcatFlashModel(Module):
         """One decode step; input_ids [B], lengths [B] tokens cached.
         Returns (logits [B, V], new_k, new_v, counts)."""
         p = variables["params"]
-        rows_of = jnp.arange(input_ids.shape[0])
-
-        def write(cache, layer, rows):
-            return cache.at[layer, rows_of, lengths, 0].set(rows[:, 0])
 
         def attend_over(pa, q_n, q_r, c_all, r_all):
             with jax.named_scope("hetu.mla.decode"):
@@ -473,7 +474,7 @@ class LongcatFlashModel(Module):
 
         h, k_cache, v_cache, stats = self._cached(
             p, input_ids[:, None], k_cache, v_cache, lengths[:, None],
-            write, attend_over)
+            attend_over)
         return self._head(p, h[:, 0]), k_cache, v_cache, stats
 
     # ---- training (test size; no cut of the published model trains on

@@ -43,13 +43,67 @@ def causal_attention(q, k, v, *, scale=None):
 def cache_update(k_cache, v_cache, k_new, v_new, lengths):
     """Write one new token's K/V into each sequence's cache slot.
 
-    k_cache/v_cache: [B, T, kv_heads, D]; k_new/v_new: [B, 1, kv_heads, D];
+    k_cache/v_cache: [B, T, kv_heads, D]; k_new/v_new: [B, 1, kv_heads, D]
+    (or both without the heads axis: ``[B, T, D]`` and ``[B, 1, D]``);
     lengths: [B] int32 — tokens already cached per sequence, i.e. the index
     the new token lands at.  Returns the updated caches.
     """
     write = jax.vmap(
-        lambda c, n, i: jax.lax.dynamic_update_slice(c, n, (i, 0, 0)))
+        lambda c, n, i: jax.lax.dynamic_update_slice(
+            c, n, (i,) + (0,) * (c.ndim - 1)))
     return write(k_cache, k_new, lengths), write(v_cache, v_new, lengths)
+
+
+def read_cache_layer(cache, layer):
+    """Layer ``layer`` of an all-layer cache, as the dense ``[B, T, kv_heads,
+    D]`` the attention steps below work on.  A cache that is held some other
+    way reads its own layer (``cache.read(layer)``: the paged engine's
+    ``serve.kv_cache.PagedLayers`` gathers that layer's pages, and only
+    them); a plain ``[L, B, T, kv_heads, D]`` array is indexed."""
+    read = getattr(cache, "read", None)
+    if read is not None:
+        return read(layer)
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+
+
+def write_cache_layer(cache, layer, view, at, n: int):
+    """Put back what a layer's step wrote into its ``view`` (from
+    :func:`read_cache_layer`, or a reshape of it that keeps ``[B, T]`` in
+    front): the ``n`` rows from position ``at[b]`` on, ``at`` [B] int32.  A
+    cache that writes its own rows (``cache.write(layer, rows)``) is given
+    just those rows; a plain array takes the whole view back as its layer.
+    Returns the cache."""
+    write = getattr(cache, "write", None)
+    if write is not None:
+        rows = jax.vmap(
+            lambda v, i: jax.lax.dynamic_slice_in_dim(v, i, n, 0))(view, at)
+        return write(layer, rows)
+    return jax.lax.dynamic_update_index_in_dim(
+        cache, view.reshape(cache.shape[1:]), layer, 0)
+
+
+def scan_cached_layers(step, blocks, h, k_cache, v_cache, at, n: int):
+    """A cache entry point's layer scan with the two all-layer caches
+    CARRIED: layer ``l`` reads its own layer of each
+    (:func:`read_cache_layer`), runs ``step(p_l, h, k_l, v_l) -> (h, k_l,
+    v_l)`` (a block's decode or chunk step, which writes its new rows into
+    the views it is handed) and puts back the ``n`` rows a sequence that it
+    wrote from position ``at[b]`` on (:func:`write_cache_layer`).  As scan
+    inputs and outputs the caches would be held twice and rewritten whole.
+    ``blocks`` are the layers' stacked parameters.  Returns (h, k_cache,
+    v_cache)."""
+    def layer(carry, xs):
+        h, k_all, v_all = carry
+        p_l, l = xs
+        h, k_l, v_l = step(p_l, h, read_cache_layer(k_all, l),
+                           read_cache_layer(v_all, l))
+        return (h, write_cache_layer(k_all, l, k_l, at, n),
+                write_cache_layer(v_all, l, v_l, at, n)), None
+
+    n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    carry, _ = jax.lax.scan(layer, (h, k_cache, v_cache),
+                            (blocks, jnp.arange(n_layers)))
+    return carry
 
 
 def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):

@@ -40,7 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from hetu_tpu.parallel.mesh import AXIS_TP, mesh_context
 from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
-    KVCache, KVCacheSpec, PagedKVCache, pow2_ceil,
+    KVCache, KVCacheSpec, PagedKVCache, PagedLayers, pow2_ceil,
 )
 from hetu_tpu.serve.metrics import ServeMetrics
 from hetu_tpu.telemetry import trace
@@ -310,8 +310,10 @@ def _place_params_and_cache_spec(model, variables, mesh, spec):
     if mesh is not None:
         tp = mesh.shape.get(AXIS_TP, 1)
         params = _DecodeTP().place(params, mesh)
+        # axis 3 holds the kv heads in both caches: ``[L, slots, T, heads,
+        # hd]`` and the paged pool's flat rows ``[L, pages, ps, heads * hd]``
         axes = (None, None, None,
-                AXIS_TP if spec.num_kv_heads % tp == 0 else None, None)
+                AXIS_TP if spec.num_kv_heads % tp == 0 else None)
         cache_sharding = NamedSharding(mesh, P(*axes))
     return params, cache_sharding
 
@@ -337,6 +339,15 @@ class PagedServeEngine:
     """ServeEngine over a :class:`PagedKVCache`: paged gather/scatter
     decode, chunked prefill, prefix sharing with copy-on-write.
 
+    Both jitted programs hand the model the pools themselves, with the
+    call's page tables and write map (:class:`PagedLayers`), in the places
+    of its ``k_cache`` / ``v_cache`` arguments.  The model carries them
+    through its layer scan: each layer gathers its own pages into a view
+    ``[b, n_pg * page_size, *row]``, runs its attention step on that view
+    and scatters the step's new rows into the carried, donated pool.  No
+    view of every layer is built and the pool is never copied (how that was
+    checked: ``kv_cache.py``'s docstring).
+
     Drop-in for :class:`ServeEngine` everywhere the scheduler/pool/
     migration stack touches an engine (same prefill/decode/export/adopt/
     release surface, same ``cache.lengths``/``max_len``/``num_free``
@@ -345,12 +356,12 @@ class PagedServeEngine:
     :meth:`begin_prefill`, :meth:`prefill_step`.
 
     Compilation discipline: chunked prefill compiles one executable per
-    power-of-two CHUNK bucket (the page table always gathers the full
-    per-slot table, so chunk width is the only specializing shape);
-    decode compiles one executable per power-of-two PAGE-COUNT bucket —
-    short sequences gather (and write back) a fraction of ``max_len``
-    instead of every slot's worst case, which is where paged decode's
-    per-step byte traffic win comes from.  Both are asserted via
+    power-of-two CHUNK bucket (a layer always gathers the slot's full page
+    table, so chunk width is the only specializing shape); decode compiles
+    one executable per power-of-two PAGE-COUNT bucket — short sequences
+    gather a fraction of ``max_len`` a layer instead of every slot's worst
+    case, which is where paged decode's per-step byte traffic win comes
+    from.  Both are asserted via
     :meth:`compiled_executables` like the slot engine.
     """
 
@@ -400,10 +411,15 @@ class PagedServeEngine:
         # identity and absent experts, held experts hit); most have none
         self._stat_names = tuple(getattr(model, "step_stats", ()))
         k_row, v_row = spec.row_shapes()
+        # bytes ONE cache layer's view of one page holds, K and V together:
+        # what a call's ``view_bytes`` id multiplies by its pages
+        self._page_view_bytes = self.cache.page_size * (
+            spec.bytes_per_token // spec.num_layers)
         trace.instant("serve.cache_spec", {
             "k_width": int(np.prod(k_row)), "v_width": int(np.prod(v_row)),
             "cache_layers": int(spec.num_layers),
-            "bytes_per_token": int(spec.bytes_per_token)})
+            "bytes_per_token": int(spec.bytes_per_token),
+            "pool_bytes": int(self.cache.k.nbytes + self.cache.v.nbytes)})
 
     def _count(self, stats):
         """The counts a step returned beside its tokens, added to the
@@ -452,23 +468,23 @@ class PagedServeEngine:
 
     # ---- jitted step builders ----
     def _build_chunk(self, n_table: int):
-        """One chunk executable family over a gathered view of
-        ``n_table`` pages.  TWO families exist: the hot path gathers
-        exactly ``pages_per_slot`` pages, and a BOUNDARY path
-        (:attr:`_chunk_fn_ext`) extends the view by one max-chunk of
-        scratch columns — a padded final chunk near max_len writes (and
-        re-extracts) rows at ``start + bucket``, which can run past
-        ``pages_per_slot * ps``, and without the extension
+        """One chunk executable family over views of ``n_table`` pages.
+        Each layer of the model's scan gathers ITS pages of the one slot
+        into a view, writes the chunk's rows into that view and attends
+        over it, and scatters the same rows into the carried pool through
+        the host's write map (:class:`PagedLayers`).  TWO families exist:
+        the hot path's views are exactly ``pages_per_slot`` pages wide, and
+        a BOUNDARY path (:attr:`_chunk_fn_ext`) extends every layer's view
+        by one max-chunk of scratch columns — a padded final chunk near
+        max_len writes (and re-extracts) rows at ``start + bucket``, which
+        can run past ``pages_per_slot * ps``, and without the extension
         dynamic_update_slice/dynamic_slice would CLAMP the start and
         silently smear pad junk over real history (wrong tokens on
         exactly the near-full-context shared-prompt resubmit).  Keeping
         the extension off the hot path keeps the common chunk's gather
         at its minimum width."""
         model = self.model
-        cache = self.cache
-        ps = cache.page_size
-        L = cache.spec.num_layers
-        k_row, v_row = cache.spec.row_shapes()
+        k_row, v_row = self.cache.spec.row_shapes()
 
         def fn(params, k_pool, v_pool, aux):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
@@ -476,37 +492,28 @@ class PagedServeEngine:
             # start | last) into one device_put, like the decode step
             sc = (aux.shape[0] - n_table - 2) // 3
             ids = aux[:sc][None]
-            wpage = aux[sc:2 * sc]
-            woff = aux[2 * sc:3 * sc]
-            table = aux[3 * sc:3 * sc + n_table]
+            # per-token write map: real positions land in their pages,
+            # pad positions in scratch 0
+            wpage = aux[sc:2 * sc][None]
+            woff = aux[2 * sc:3 * sc][None]
+            table = aux[3 * sc:3 * sc + n_table][None]
             start = aux[3 * sc + n_table]
             last = aux[3 * sc + n_table + 1]
-            k_seq = k_pool[:, table].reshape((L, 1, n_table * ps) + k_row)
-            v_seq = v_pool[:, table].reshape((L, 1, n_table * ps) + v_row)
             # a model may return a fourth value, its per-call counts
             # (``model.step_stats`` names them); most return none
-            logits, k_seq, v_seq, *stats = model.prefill_chunk_with_cache(
-                {"params": params, "state": {}}, ids, k_seq, v_seq,
+            logits, k, v, *stats = model.prefill_chunk_with_cache(
+                {"params": params, "state": {}}, ids,
+                PagedLayers(k_pool, table, wpage, woff, k_row),
+                PagedLayers(v_pool, table, wpage, woff, v_row),
                 start, last_index=last)
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
-            rows_k = jax.lax.dynamic_slice_in_dim(k_seq[:, 0], start, sc,
-                                                  axis=1)
-            rows_v = jax.lax.dynamic_slice_in_dim(v_seq[:, 0], start, sc,
-                                                  axis=1)
-            # per-token scatter through the host-built write map: real
-            # positions land in their pages, pad positions in scratch 0
-            k_pool = k_pool.at[:, wpage, woff].set(rows_k)
-            v_pool = v_pool.at[:, wpage, woff].set(rows_v)
-            return k_pool, v_pool, tok, tuple(stats)
+            return k.pool, v.pool, tok, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
     def _build_decode(self):
         model = self.model
-        cache = self.cache
-        ps = cache.page_size
-        L = cache.spec.num_layers
-        k_row, v_row = cache.spec.row_shapes()
+        k_row, v_row = self.cache.spec.row_shapes()
 
         def fn(params, k_pool, v_pool, aux):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
@@ -514,33 +521,22 @@ class PagedServeEngine:
             # offset) into ONE device_put — five small uploads per step
             # cost more wall time than the decode math at serving batch
             # sizes
-            b = aux.shape[0]
             n_pg = aux.shape[1] - 4
             tables = aux[:, :n_pg]
             lengths = aux[:, n_pg]
             tokens = aux[:, n_pg + 1]
-            wpage = aux[:, n_pg + 2]
-            woff = aux[:, n_pg + 3]
-            k_seq = k_pool[:, tables].reshape((L, b, n_pg * ps) + k_row)
-            v_seq = v_pool[:, tables].reshape((L, b, n_pg * ps) + v_row)
-            logits, k_seq, v_seq, *stats = model.decode_with_cache(
-                {"params": params, "state": {}}, tokens, k_seq, v_seq,
-                lengths)
+            wpage = aux[:, n_pg + 2:n_pg + 3]
+            woff = aux[:, n_pg + 3:]
+            # a layer gathers its pages of the B sequences and scatters B
+            # new rows (:class:`PagedLayers`): a decode step moves one
+            # layer's view at a time and O(B) rows into the pool, never a
+            # view of every layer and never the pool
+            logits, k, v, *stats = model.decode_with_cache(
+                {"params": params, "state": {}}, tokens,
+                PagedLayers(k_pool, tables, wpage, woff, k_row),
+                PagedLayers(v_pool, tables, wpage, woff, v_row), lengths)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            # only the newly written token row goes back to the pool —
-            # a decode step moves O(B) token rows, never the gathered
-            # sequence view
-            tok_k = jax.vmap(
-                lambda kb, i: jax.lax.dynamic_index_in_dim(
-                    kb, i, axis=1, keepdims=False),
-                in_axes=(1, 0), out_axes=1)(k_seq, lengths)
-            tok_v = jax.vmap(
-                lambda vb, i: jax.lax.dynamic_index_in_dim(
-                    vb, i, axis=1, keepdims=False),
-                in_axes=(1, 0), out_axes=1)(v_seq, lengths)
-            k_pool = k_pool.at[:, wpage, woff].set(tok_k)
-            v_pool = v_pool.at[:, wpage, woff].set(tok_v)
-            return k_pool, v_pool, nxt, tuple(stats)
+            return k.pool, v.pool, nxt, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
@@ -672,7 +668,8 @@ class PagedServeEngine:
                 aux[3 * s + n_table + 1] = size - 1
             with trace.span("serve.prefill_chunk.launch",
                             {"start": int(start), "tokens": int(size),
-                             "bucket": int(s)}):
+                             "bucket": int(s),
+                             "view_bytes": n_table * self._page_view_bytes}):
                 k, v, tok, stats = chunk_fn(
                     self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
             with trace.span("serve.prefill_chunk.fetch"):
@@ -770,7 +767,9 @@ class PagedServeEngine:
                 aux[:, n_pg + 2] = wp
                 aux[:, n_pg + 3] = wo
             with trace.span("serve.decode.launch",
-                            {"pages": int(n_pg), "batch": int(bb)}):
+                            {"pages": int(n_pg), "batch": int(bb),
+                             "view_bytes": int(bb * n_pg)
+                             * self._page_view_bytes}):
                 k, v, nxt, stats = self._decode_fn(
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(aux))

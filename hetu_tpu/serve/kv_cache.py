@@ -8,7 +8,7 @@ Two allocators share one spec/snapshot vocabulary:
   ``max_len`` tokens of HBM whether it uses them or not (internal
   fragmentation), and identical prompts cache identical K/V twice.
 * :class:`PagedKVCache` — fixed-size PAGES (``page_size`` tokens) in a
-  device-resident pool ``[L, num_pages, page_size, kv_heads,
+  device-resident pool ``[L, num_pages, page_size, kv_heads *
   head_dim]``, per-request page tables, refcounted PREFIX SHARING
   (hash-of-token-prefix → shared read-only pages, so identical system
   prompts across a pool's traffic dedup to one physical copy) with
@@ -28,16 +28,29 @@ GQA-aware: the cache stores the model's ``num_kv_heads`` heads un-repeated
 ``GPTConfig`` (kv_heads == num_heads) and ``LlamaConfig``
 (``num_kv_heads <= num_heads``).
 
-The arrays are functionally updated inside the engine's jitted steps
-(donated, so XLA updates in place); this class owns the slot lifecycle and
+The arrays are functionally updated inside the engine's jitted steps and
+donated to them.  Donation alone did not make the update in place: through
+PR 28 the two paged programs gathered every layer's pages into one view
+before the model ran and scattered the new rows over a full leading axis
+afterwards, and the TPU compiler turned both into relayouts of the whole
+pool, seven copies of it a decode round (PERF.md, ledger of PR 28).  Since
+PR 29 the pool travels through the model's layer scan as a carry
+(:class:`PagedLayers`): a layer gathers its own pages and scatters its own
+new rows, and the compiled programs hold nothing of the pool's size but the
+pool (checked in the HLO compiled for a described v5e,
+``benchmarks/tools/compile_v5e_serve.py --hlo``, in the jaxpr by
+``tests/paged_programs.py``, and on the chip by the ledger's
+``breakdown.device_ops``).  The allocator classes own the slot lifecycle and
 the per-slot host-side lengths.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -137,7 +150,6 @@ class KVCache:
         self.v = jnp.zeros((spec.num_layers, num_slots, max_len) + v_row,
                            spec.dtype)
         if sharding is not None:
-            import jax
             self.k = jax.device_put(self.k, sharding)
             self.v = jax.device_put(self.v, sharding)
         self.lengths = np.zeros(num_slots, np.int32)
@@ -237,8 +249,6 @@ class KVCache:
                         f"{name} dtype mismatch: snapshot "
                         f"{np.dtype(arr.dtype).name} vs cache {dt.name}")
         if self._import_fn is None:
-            import jax
-
             def write(k, v, k_rows, v_rows, slot):
                 # rows padded to a power-of-two bucket: executables stay
                 # bounded (one per bucket, like the engine's prefill)
@@ -299,6 +309,52 @@ def pow2_ceil(n: int, cap: int) -> int:
     return max(min(b, cap), 1)
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=("pool", "tables", "wpage", "woff"),
+         meta_fields=("row",))
+@dataclass(frozen=True)
+class PagedLayers:
+    """One pool of a paged cache as a jitted step hands it to the model's
+    cache entry points, in the place of a dense ``[L, B, T, *row]`` cache:
+    the pool itself with the step's page tables and write map, ALL the page
+    arithmetic of the two paged programs.
+
+    ``pool`` ``[L, num_pages, page_size, width]`` (rows flat, as
+    :class:`PagedKVCache` holds them); ``tables`` ``[B, n_pg]`` int32, each
+    sequence's pages in order (scratch-padded); ``wpage`` / ``woff``
+    ``[B, S]`` int32, where the host's write map puts each of the step's
+    ``S`` new rows a sequence (pad rows: scratch page 0); ``row`` the shape
+    ``(heads, head width)`` the model sees a row in (static).
+
+    The model carries the value through its layer scan
+    (``ops.scan_cached_layers``); a layer reads its own pages and writes its
+    own new rows, so nothing of the pool's size, and no view of every
+    layer, is ever made: the pool is a loop carry of a donated argument and
+    the row scatter updates it in place."""
+
+    pool: jax.Array
+    tables: jax.Array
+    wpage: jax.Array
+    woff: jax.Array
+    row: tuple
+
+    def read(self, layer):
+        """Cache layer ``layer`` of every sequence, ``[B, n_pg * page_size,
+        *row]``: one gather of that layer's pages by the tables, on the
+        pool's two major axes."""
+        pages = self.pool[layer, self.tables]      # [B, n_pg, ps, width]
+        b, n_pg, ps = pages.shape[:3]
+        return pages.reshape((b, n_pg * ps) + tuple(self.row))
+
+    def write(self, layer, rows):
+        """The step's new rows ``[B, S, *row]`` (or flat, ``[B, S,
+        width]``) of cache layer ``layer``, scattered through the write
+        map."""
+        rows = rows.reshape(rows.shape[:2] + self.pool.shape[3:])
+        return replace(
+            self, pool=self.pool.at[layer, self.wpage, self.woff].set(rows))
+
+
 class PagePoolExhausted(RuntimeError):
     """The page pool has no free page and nothing reclaimable.
 
@@ -326,8 +382,14 @@ class _PrefixEntry:
 class PagedKVCache:
     """Paged K/V pool + per-slot page tables + refcounted prefix sharing.
 
-    ``k``/``v``: ``[L, num_pages, page_size, kv_heads, head_dim]`` jax
-    arrays, replaced wholesale by the engine after each jitted step.
+    ``k``/``v``: ``[L, num_pages, page_size, kv_heads * head_dim]`` jax
+    arrays, replaced wholesale by the engine after each jitted step.  A
+    token's row is held FLAT: with a ``[kv_heads, head_dim]`` minor pair of
+    (20, 64) the TPU's own layout of the array put the PAGE axis in the
+    lanes (least padding), and every gather or scatter by page then cost a
+    relayout of the whole pool, before and after the layer loop; 1280 flat
+    is ten whole lane tiles, the pool keeps the order it is declared in and
+    a page is one contiguous block (compiles for a described v5e, PR 29).
     Page 0 is a reserved SCRATCH page: jitted steps run over every slot
     with fixed shapes, and inactive slots' (masked, garbage) writes need
     a harmless landing zone — page 0 is never allocated to a request.
@@ -373,10 +435,9 @@ class PagedKVCache:
             raise ValueError("need >= 2 pages (page 0 is scratch)")
         k_row, v_row = spec.row_shapes()
         lead = (spec.num_layers, self.num_pages, self.page_size)
-        self.k = jnp.zeros(lead + k_row, spec.dtype)
-        self.v = jnp.zeros(lead + v_row, spec.dtype)
+        self.k = jnp.zeros(lead + (int(np.prod(k_row)),), spec.dtype)
+        self.v = jnp.zeros(lead + (int(np.prod(v_row)),), spec.dtype)
         if sharding is not None:
-            import jax
             self.k = jax.device_put(self.k, sharding)
             self.v = jax.device_put(self.v, sharding)
         self.lengths = np.zeros(self.num_slots, np.int32)
@@ -508,8 +569,6 @@ class PagedKVCache:
         src = self.tables[slot][idx]
         dst = self._alloc_page(slot)
         if self._copy_fn is None:
-            import jax
-
             def copy(k, v, src, dst):
                 k_page = jax.lax.dynamic_slice_in_dim(k, src, 1, axis=1)
                 v_page = jax.lax.dynamic_slice_in_dim(v, src, 1, axis=1)
@@ -565,18 +624,6 @@ class PagedKVCache:
         wp[:n] = pages
         wo[:n] = offs
         return wp, wo
-
-    def table_array(self, n_pages: int):
-        """Page tables as one ``[num_slots, n_pages]`` int32 array,
-        scratch-padded — the gather operand of the jitted decode."""
-        out = np.zeros((self.num_slots, n_pages), np.int32)
-        for s, table in enumerate(self.tables):
-            t = table[:n_pages]
-            out[s, :len(t)] = t
-        return out
-
-    def max_table_pages(self) -> int:
-        return max((len(t) for t in self.tables), default=0)
 
     # ---- prefix sharing ----
     @staticmethod
@@ -695,10 +742,11 @@ class PagedKVCache:
             pages = np.asarray(self.tables[slot][:self.pages_for_tokens(n)],
                                np.int32)
             L = self.spec.num_layers
-            k_pg = np.asarray(self.k[:, pages])  # [L, P, ps, H, D]
+            k_row, v_row = self.spec.row_shapes()
+            k_pg = np.asarray(self.k[:, pages])  # [L, P, ps, H * D]
             v_pg = np.asarray(self.v[:, pages])
-            k_rows = k_pg.reshape(L, len(pages) * ps, *k_pg.shape[3:])[:, :n]
-            v_rows = v_pg.reshape(L, len(pages) * ps, *v_pg.shape[3:])[:, :n]
+            k_rows = k_pg.reshape(L, len(pages) * ps, *k_row)[:, :n]
+            v_rows = v_pg.reshape(L, len(pages) * ps, *v_row)[:, :n]
             snaps.append(KVSlotSnapshot(
                 slot=slot, length=n, k=np.ascontiguousarray(k_rows),
                 v=np.ascontiguousarray(v_rows)))
@@ -744,8 +792,6 @@ class PagedKVCache:
                 f"{self.available_pages()} available "
                 f"(free + reclaimable - reserved)")
         if self._import_fn is None:
-            import jax
-
             def write(k, v, k_pages, v_pages, pages):
                 k = k.at[:, pages].set(k_pages)
                 v = v.at[:, pages].set(v_pages)
@@ -773,7 +819,9 @@ class PagedKVCache:
                 k_pg.reshape(L, pad * ps, *k_row)[:, :s.length] = s.k
                 v_pg.reshape(L, pad * ps, *v_row)[:, :s.length] = s.v
                 self.k, self.v = self._import_fn(
-                    self.k, self.v, jnp.asarray(k_pg), jnp.asarray(v_pg),
+                    self.k, self.v,
+                    jnp.asarray(k_pg.reshape(L, pad, ps, -1)),
+                    jnp.asarray(v_pg.reshape(L, pad, ps, -1)),
                     jnp.asarray(pages))
                 self.tables[slot] = table
                 self.lengths[slot] = s.length

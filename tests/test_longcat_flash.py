@@ -30,6 +30,9 @@ from hetu_tpu.serve import (  # noqa: E402
     ContinuousBatchingScheduler, KVCacheSpec, PagedServeEngine, Request,
 )
 from hetu_tpu.serve import migrate  # noqa: E402
+from paged_programs import (  # noqa: E402
+    engine_greedy, oversized, pad_writes,
+)
 
 F32_TOL = 2e-4      # both sides float32: the order of operations only
 VOCAB = 96
@@ -319,6 +322,70 @@ def test_the_cache_spec_has_two_widths_and_its_own_layer_count():
         vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
         num_kv_heads=2, ffn_size=64, max_position=32)))
     assert llama == KVCacheSpec(2, 2, 8, jnp.float32)
+
+
+# ---------------- (g2) one cache layer's pages at a time (ISSUE 29)
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "chunk_ext"])
+def test_no_program_holds_a_pool_or_a_view_of_every_layer(program):
+    """As ``test_paged_kv.py``'s for GPT and Llama: nothing as large as the
+    latent pool or as ``L x b x T x row`` but the carried pool itself."""
+    model, v = make(kv_lora_rank=32, max_position=512)
+    engine = PagedServeEngine(model, v, num_slots=2, max_len=512,
+                              page_size=8, prefill_chunk=16)
+    floor, found = oversized(engine, program, batch=2, chunk=16)
+    assert floor >= 4 * 512 * 32 and found == []
+
+
+def dense_greedy(model, v, prompt, n, max_len) -> list:
+    """Greedy tokens through the same two entry points over DENSE caches
+    ``[2L, 1, T, 1, w]``, the whole prompt one chunk: what a slot cache
+    would hold (this model has no slot-cache engine to ask)."""
+    spec = model.kv_cache_spec()
+    k_row, v_row = spec.row_shapes()
+    k = jnp.zeros((spec.num_layers, 1, max_len) + k_row, spec.dtype)
+    vv = jnp.zeros((spec.num_layers, 1, max_len) + v_row, spec.dtype)
+    logits, k, vv, _ = jax.jit(model.prefill_chunk_with_cache)(
+        v, jnp.asarray([prompt], jnp.int32), k, vv, jnp.int32(0))
+    toks = [int(jnp.argmax(logits[0]))]
+    step = jax.jit(model.decode_with_cache)
+    for i in range(n - 1):
+        logits, k, vv, _ = step(
+            v, jnp.asarray(toks[-1:], jnp.int32), k, vv,
+            jnp.asarray([len(prompt) + i], jnp.int32))
+        toks.append(int(jnp.argmax(logits[0])))
+    return toks
+
+
+@pytest.mark.parametrize("case", ["boundary", "cow", "tp2"])
+def test_paged_tokens_equal_the_dense_caches(case):
+    """Float32, token for token: a prompt whose padded final chunk runs
+    past the slot's pages (the boundary program), two requests sharing a
+    prefix with a copy-on-write page, and a ``tp=2`` mesh."""
+    model, v = make()
+    # max_len 60 = 15 pages of 4: the chunk [48, 57) pads to 16 -> 64 > 60
+    prompt = ids_of(57 if case == "boundary" else 21, seed=29).tolist()
+    n = 3 if case == "boundary" else 8
+    want = dense_greedy(model, v, prompt, n, 60)
+    engine = PagedServeEngine(
+        model, v, num_slots=2, max_len=60, page_size=4, prefill_chunk=16,
+        mesh=ht.make_mesh(tp=2) if case == "tp2" else None)
+    assert engine_greedy(engine, prompt, n) == want
+    if case == "boundary":
+        assert engine._chunk_fn_ext is not None
+    if case == "cow":
+        assert engine_greedy(engine, prompt, n) == want
+        assert engine.cache.cow_copies >= 1
+        assert engine.cache.prefix_hit_tokens == len(prompt) - 1
+
+
+def test_pad_positions_are_written_to_scratch_only():
+    model, v = make()
+    engine = PagedServeEngine(model, v, num_slots=4, max_len=64, page_size=8,
+                              prefill_chunk=16, prefix_sharing=False)
+    prompts = [ids_of(n, seed=n).tolist() for n in (5, 19, 9)]
+    assert pad_writes(engine, prompts) == {
+        "stray": [], "missed": [], "scratch_written": True}
 
 
 # ------------------------------------------------- (h) a slot migrates

@@ -21,6 +21,8 @@ from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, PagedServeEngine, Request, ServeEngine,
 )
+from paged_programs import engine_greedy as _engine_greedy
+from paged_programs import oversized, pad_writes
 
 pytestmark = pytest.mark.paged
 
@@ -49,13 +51,6 @@ def llama():
     return _llama_gqa()
 
 
-def _engine_greedy(engine, prompt, n):
-    slot = engine.alloc_slot()
-    toks = [engine.prefill(slot, prompt)]
-    for _ in range(n - 1):
-        toks.append(engine.decode()[slot])
-    engine.release(slot)
-    return toks
 
 
 # ---- paged-vs-slot token parity (greedy decode) ----
@@ -521,3 +516,83 @@ def test_llama_full_dedup_near_max_len(llama):
                              page_size=8)
     assert _engine_greedy(paged, prompt, 4) == want
     assert _engine_greedy(paged, prompt, 4) == want  # full-dedup resubmit
+
+
+# ---- one layer's pages at a time (ISSUE 29) ----
+
+def _wide(kind):
+    """The two models with four layers and 256 positions: a view of every
+    layer then outweighs one layer's (GQA-repeated) and any weight."""
+    if kind == "gpt":
+        m = GPTModel(GPTConfig(
+            vocab_size=97, hidden_size=64, num_layers=4, num_heads=4,
+            ffn_size=128, max_position=256, dropout_rate=0.0))
+    else:
+        m = LlamaModel(LlamaConfig(
+            vocab_size=97, hidden_size=64, num_layers=4, num_heads=4,
+            num_kv_heads=2, ffn_size=96, max_position=256))
+    return m, m.init(jax.random.PRNGKey(2))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "chunk_ext"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_no_program_holds_a_pool_or_a_view_of_every_layer(kind, program):
+    """The jaxpr of each paged program: nothing as large as the K pool or
+    as ``L x b x T x row`` is made, other than the carried pool the row
+    scatter writes into."""
+    model, variables = _wide(kind)
+    engine = PagedServeEngine(model, variables, num_slots=2, max_len=256,
+                              page_size=8, prefill_chunk=16)
+    floor, found = oversized(engine, program, batch=2, chunk=16)
+    assert floor >= 4 * 256 * 32 and found == []
+
+
+def _first_token(model, variables, prompt):
+    logits, _, _ = model.prefill_with_cache(
+        variables, np.asarray([prompt], np.int32),
+        last_index=len(prompt) - 1)
+    return int(np.argmax(np.asarray(logits[0])))
+
+
+@pytest.mark.parametrize("case", ["boundary", "cow", "tp2"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_paged_tokens_equal_the_slot_engines(kind, case, gpt, llama):
+    """Float32, token for token, against the slot-cache engine and
+    ``prefill_with_cache``: a prompt whose padded final chunk runs past
+    the slot's pages (the boundary program), two requests sharing a prefix
+    with a copy-on-write page, and a ``tp=2`` mesh."""
+    model, variables = gpt if kind == "gpt" else llama
+    g = np.random.default_rng(29)
+    # max_len 60 = 15 pages of 4: the chunk [48, 57) pads to 16 -> 64 > 60
+    prompt = [int(t) for t in g.integers(0, 97, 57 if case == "boundary"
+                                         else 21)]
+    n = 3 if case == "boundary" else 8
+    want = _engine_greedy(ServeEngine(model, variables, num_slots=2,
+                                      max_len=60), prompt, n)
+    assert want[0] == _first_token(model, variables, prompt)
+    paged = PagedServeEngine(
+        model, variables, num_slots=2, max_len=60, page_size=4,
+        prefill_chunk=16, mesh=ht.make_mesh(tp=2) if case == "tp2" else None)
+    assert _engine_greedy(paged, prompt, n) == want
+    if case == "boundary":
+        assert paged._chunk_fn_ext is not None
+    if case == "cow":
+        # the same prompt again: all but its last token adopted, that one
+        # recomputed into the shared tail page, which is copied first
+        assert _engine_greedy(paged, prompt, n) == want
+        assert paged.cache.cow_copies >= 1
+        assert paged.cache.prefix_hit_tokens == len(prompt) - 1
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_pad_positions_are_written_to_scratch_only(kind, gpt, llama):
+    """Chunks padded to their bucket and a decode round with a pad row:
+    every row the programs wrote is a live position's or scratch (0, 0)."""
+    model, variables = gpt if kind == "gpt" else llama
+    engine = PagedServeEngine(model, variables, num_slots=4, max_len=64,
+                              page_size=8, prefill_chunk=16,
+                              prefix_sharing=False)
+    g = np.random.default_rng(5)
+    prompts = [[int(t) for t in g.integers(0, 97, n)] for n in (5, 19, 9)]
+    assert pad_writes(engine, prompts) == {
+        "stray": [], "missed": [], "scratch_written": True}

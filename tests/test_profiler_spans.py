@@ -292,9 +292,10 @@ def test_train_spans_follow_each_other(recorded):
 @pytest.mark.parametrize("name,keys", [
     ("serve.step", {"step"}),
     ("serve.decode", {"active"}),
-    ("serve.decode.launch", {"pages", "batch"}),
+    ("serve.decode.launch", {"pages", "batch", "view_bytes"}),
     ("serve.prefill_chunk", {"slot"}),
-    ("serve.prefill_chunk.launch", {"start", "tokens", "bucket"}),
+    ("serve.prefill_chunk.launch", {"start", "tokens", "bucket",
+                                    "view_bytes"}),
 ])
 def test_ids_decode_from_the_event(recorded, name, keys):
     evs = _named(recorded["events"], name)
@@ -313,6 +314,28 @@ def test_ids_say_what_ran(recorded):
         sorted([5, 16, 4, 16, 16, 1])    # 5, 20 and 33 in chunks of 16
     assert all(c[3]["bucket"] == 16 for c in chunks)
     assert max(e[3]["active"] for e in _named(events, "serve.decode")) == 2
+
+
+def test_cache_ids_say_what_a_call_holds(tmp_path):
+    """``serve.cache_spec`` carries the pools' bytes, and each launch the
+    bytes ONE cache layer's gathered view holds in that call (K and V):
+    far under the pools, and under what a view of every layer would be."""
+    model, variables = _model()
+    with profiled(tmp_path):
+        eng, sched = _serving(model, variables)
+        _serve(sched)
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    pools = eng.cache.k.nbytes + eng.cache.v.nbytes
+    assert spec[3]["pool_bytes"] == pools == 2 * 2 * 33 * 8 * 64 * 4
+    row = spec[3]["bytes_per_token"] // spec[3]["cache_layers"]  # K + V
+    for e in _named(events, "serve.decode.launch"):
+        ids = e[3]
+        assert ids["view_bytes"] == ids["batch"] * ids["pages"] * 8 * row
+    chunks = _named(events, "serve.prefill_chunk.launch")
+    assert {c[3]["view_bytes"] for c in chunks} == {8 * 8 * row}
+    assert all(e[3]["view_bytes"] * spec[3]["cache_layers"] < pools
+               for e in events if "view_bytes" in e[3])
 
 
 # ------------------------------------------- the device side is untouched
