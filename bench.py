@@ -12,7 +12,7 @@ lax.fori_loop at two iteration counts and the slope
 (T_big - T_small) / (n_big - n_small) cancels the constant dispatch and
 fetch overhead.  The loop returns a scalar so the fetch is O(1).
 
-The device sub-commands (gpt, gpt_sweep, resnet, ctr, moe, serve, paged)
+The device sub-commands (gpt, gpt_sweep, resnet, ctr, moe)
 measure a TPU: on any other backend they exit nonzero without printing a
 metric, unless HETU_BENCH_SMOKE is set (tiny shapes, a check that the code
 path runs — its numbers mean nothing).  Every result names the device it
@@ -46,7 +46,7 @@ from hetu_tpu.profiler.cost_model import ChipSpec, detect_chip
 from hetu_tpu.utils.platform import device_stamp, enable_compile_cache
 
 # sub-commands that measure the accelerator (the rest time host-side planes)
-_DEVICE_CMDS = ("gpt", "gpt_sweep", "resnet", "ctr", "moe", "serve", "paged")
+_DEVICE_CMDS = ("gpt", "gpt_sweep", "resnet", "ctr", "moe")
 
 
 def _chip() -> ChipSpec:
@@ -500,237 +500,6 @@ def bench_moe():
     })
 
 
-def bench_serve():
-    """Serving decode throughput (tokens/s) through the KV-cache engine,
-    one chip; A/B on the same engine (same compiled executables):
-    continuous batching vs static batch-at-once waves.
-
-    Workload: requests with varied prompt lengths and generation budgets,
-    so slots free at different times — exactly where iteration-level
-    admission beats draining a wave before admitting the next.
-    """
-    import os
-
-    from hetu_tpu import models
-    from hetu_tpu.serve import (
-        ContinuousBatchingScheduler, Request, ServeEngine,
-    )
-
-    V, H, L, NH, SLOTS, MAXLEN, NREQ = 50304, 768, 12, 12, 8, 512, 32
-    if os.environ.get("HETU_BENCH_SMOKE"):  # CI/CPU smoke: same code path
-        V, H, L, NH, SLOTS, MAXLEN, NREQ = 512, 64, 2, 4, 4, 64, 12
-    cfg = models.GPTConfig(
-        vocab_size=V, hidden_size=H, num_layers=L, num_heads=NH,
-        ffn_size=4 * H, max_position=MAXLEN, dropout_rate=0.0,
-        dtype=jnp.bfloat16)
-    model = models.GPTModel(cfg)
-    variables = model.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(model, variables, num_slots=SLOTS, max_len=MAXLEN)
-
-    def make_requests():
-        g = np.random.default_rng(0)
-        return [Request(
-            prompt=[int(t) for t in g.integers(0, V,
-                                               int(g.integers(4, MAXLEN // 4)))],
-            max_tokens=int(g.integers(4, MAXLEN // 2)))
-            for _ in range(NREQ)]
-
-    def run_continuous():
-        rs = make_requests()
-        t0 = time.perf_counter()
-        ContinuousBatchingScheduler(engine).run(rs)
-        return sum(len(r.tokens) for r in rs), time.perf_counter() - t0
-
-    def run_static_waves():
-        # batch-at-once: each wave exactly fills the slots and drains
-        # COMPLETELY before the next is admitted
-        rs = make_requests()
-        t0 = time.perf_counter()
-        for i in range(0, len(rs), SLOTS):
-            ContinuousBatchingScheduler(engine).run(rs[i:i + SLOTS])
-        return sum(len(r.tokens) for r in rs), time.perf_counter() - t0
-
-    run_continuous()      # warm every bucket + the decode executable
-    tok_c, dt_c = run_continuous()
-    tok_s, dt_s = run_static_waves()
-    tps = tok_c / dt_c
-    base_tps = tok_s / dt_s
-    _emit({
-        "metric": "gpt_serve_decode_tokens_per_sec_1chip",
-        "value": round(tps, 1),
-        "unit": "generated_tokens_per_sec",
-        "vs_baseline": round(tps / base_tps, 3),
-        "extra": {"requests": NREQ, "slots": SLOTS, "max_len": MAXLEN,
-                  "executables": engine.compiled_executables(),
-                  "continuous_s": round(dt_c, 4),
-                  "ab": {"optimized": "continuous_batching",
-                         "baseline": "static_batch_at_once_same_engine",
-                         "baseline_tokens_per_s": round(base_tps, 1),
-                         "baseline_s": round(dt_s, 4)}},
-    })
-
-
-def bench_paged():
-    """Paged KV cache (prefix sharing + chunked prefill) vs the slot
-    engine at MATCHED HBM budget — the ISSUE 13 acceptance A/B.
-
-    A/B 1 (throughput, shared-prefix workload): both engines get the
-    same K/V token capacity (slot: ``SLOTS x MAXLEN``; paged: the same
-    token count as a page pool).  Requests share one system prompt with
-    short unique suffixes — the pool's realistic traffic shape.  The
-    slot engine admits at most SLOTS sequences and caches the shared
-    prefix once PER SLOT; the paged engine dedups the prefix to one
-    physical copy and allocates only live pages, so far more sequences
-    decode concurrently in the same memory → higher sustained decode
-    tokens/sec.
-
-    A/B 2 (p99 decode latency under a long-prompt arrival): while short
-    requests decode, a MAXLEN-scale prompt arrives.  The slot engine
-    prefills it monolithically inside one scheduler step (every
-    in-flight decode stalls behind it); the paged engine interleaves
-    page-aligned chunks with decode rounds, so the worst step latency
-    stays bounded at ~one chunk.
-
-    Also reports the prefix-dedup bytes saved (hit tokens x per-token
-    K/V bytes) and the prefix hit rate.
-    """
-    import os
-
-    from hetu_tpu import models
-    from hetu_tpu.serve import (
-        ContinuousBatchingScheduler, PagedServeEngine, Request,
-        ServeEngine,
-    )
-
-    V, H, L, NH, SLOTS, MAXLEN, NREQ, PAGE = (
-        50304, 768, 12, 12, 8, 512, 64, 64)
-    if os.environ.get("HETU_BENCH_SMOKE"):  # CI/CPU smoke: same code path
-        V, H, L, NH, SLOTS, MAXLEN, NREQ, PAGE = (
-            512, 64, 2, 4, 4, 128, 32, 16)
-    cfg = models.GPTConfig(
-        vocab_size=V, hidden_size=H, num_layers=L, num_heads=NH,
-        ffn_size=4 * H, max_position=MAXLEN, dropout_rate=0.0,
-        dtype=jnp.bfloat16)
-    model = models.GPTModel(cfg)
-    variables = model.init(jax.random.PRNGKey(0))
-    g = np.random.default_rng(0)
-    # system-prompt-heavy traffic (the dedup-relevant shape): 3/4 of the
-    # context is a shared prefix, short unique question, short answer
-    prefix = [int(t) for t in g.integers(0, V, 3 * MAXLEN // 4)]
-    gen = MAXLEN // 32
-
-    def shared_requests():
-        rng = np.random.default_rng(1)
-        return [Request(prompt=prefix + [int(t) for t in
-                                         rng.integers(0, V, 8)],
-                        max_tokens=gen) for _ in range(NREQ)]
-
-    # matched HBM budget: same cached-token capacity on both arms
-    budget_tokens = SLOTS * MAXLEN
-
-    def slot_engine():
-        return ServeEngine(model, variables, num_slots=SLOTS,
-                           max_len=MAXLEN)
-
-    def paged_engine():
-        return PagedServeEngine(
-            model, variables, num_slots=2 * SLOTS, max_len=MAXLEN,
-            page_size=PAGE, num_pages=1 + budget_tokens // PAGE)
-
-    def throughput(make):
-        engine = make()
-        sch = ContinuousBatchingScheduler(engine,
-                                          prefill_chunks_per_step=2)
-        # warm TWICE: the first pass compiles cold-index buckets, the
-        # second mirrors the timed pass's admission pattern (the prefix
-        # index is populated by then, which changes bucket traffic)
-        sch.run(shared_requests())
-        sch.run(shared_requests())
-        best = 0.0
-        for _ in range(3):  # best-of-3: the region is ~100ms, box noise
-            rs = shared_requests()  # is not
-            t0 = time.perf_counter()
-            sch.run(rs)
-            dt = time.perf_counter() - t0
-            best = max(best, sum(len(r.tokens) for r in rs) / dt)
-        return best, engine
-
-    tps_slot, _ = throughput(slot_engine)
-    tps_paged, pe = throughput(paged_engine)
-    snap = pe.metrics.snapshot()
-    spec = pe.cache.spec
-    per_tok = (2 * spec.num_layers * spec.num_kv_heads * spec.head_dim
-               * np.dtype(jnp.bfloat16).itemsize)
-    dedup_bytes = int(snap.get("prefix_hit_tokens", 0)) * per_tok
-
-    def p99_under_arrival(make, warm_steps=4):
-        """Max/p99 per-step latency of an engine decoding short
-        requests while one MAXLEN-scale prompt arrives.  The identical
-        workload runs once UNMEASURED first so every executable (chunk
-        buckets, page/batch buckets, the long prefill bucket) is warm —
-        the timed pass isolates the scheduling policy, not XLA."""
-        engine = make()
-        sch = ContinuousBatchingScheduler(engine,
-                                          prefill_chunks_per_step=2)
-
-        def workload(seed, timed):
-            rng = np.random.default_rng(seed)
-            short = [Request(
-                prompt=[int(t) for t in rng.integers(0, V, 12)],
-                max_tokens=MAXLEN // 2) for _ in range(3)]
-            for r in short:
-                sch.submit(r)
-            for _ in range(warm_steps):
-                sch.step()
-            long_req = Request(
-                prompt=[int(t) for t in
-                        rng.integers(0, V, MAXLEN - gen - 2)],
-                max_tokens=4)
-            sch.submit(long_req)
-            lats = []
-            while sch.has_work():
-                t0 = time.perf_counter()
-                sch.step()
-                lats.append(time.perf_counter() - t0)
-            return lats
-
-        workload(2, timed=False)  # warm every bucket the timed pass hits
-        p99s, maxes = [], []
-        for _ in range(3):  # median-of-3 against box noise
-            lats = sorted(workload(2, timed=True))
-            p99s.append(lats[min(int(0.99 * len(lats)), len(lats) - 1)])
-            maxes.append(lats[-1])
-        return sorted(p99s)[1], sorted(maxes)[1]
-
-    p99_slot, max_slot = p99_under_arrival(slot_engine)
-    p99_paged, max_paged = p99_under_arrival(paged_engine)
-
-    speedup = tps_paged / max(tps_slot, 1e-9)
-    _emit({
-        "metric": "serve_paged_vs_slot_decode_throughput_x",
-        "value": round(speedup, 3),
-        "unit": "x_decode_tokens_per_sec_matched_hbm_shared_prefix",
-        "extra": {
-            "paged_tokens_per_s": round(tps_paged, 1),
-            "slot_tokens_per_s": round(tps_slot, 1),
-            "budget_tokens": budget_tokens,
-            "page_size": PAGE,
-            "requests": NREQ,
-            "prefix_hit_rate": round(snap.get("prefix_hit_rate", 0.0), 3),
-            "prefix_dedup_bytes_saved": dedup_bytes,
-            "cow_copies": int(snap.get("cow_copies", 0)),
-            "long_prompt_arrival": {
-                "p99_step_s_slot_monolithic": round(p99_slot, 4),
-                "p99_step_s_paged_chunked": round(p99_paged, 4),
-                "max_step_s_slot_monolithic": round(max_slot, 4),
-                "max_step_s_paged_chunked": round(max_paged, 4),
-                "p99_flatness_x": round(p99_slot / max(p99_paged, 1e-9),
-                                        3),
-            },
-        },
-    })
-
-
 def bench_migrate():
     """Live KV-slot migration vs re-prefill: the failover-cost crossover.
 
@@ -738,7 +507,7 @@ def bench_migrate():
     engine is handed to a peer two ways — (a) MIGRATED: export the live
     slot, chunked CRC wire over a real van blob channel, import + adopt
     (zero prefill on the peer); (b) RE-PREFILLED: the PR 3 failover path
-    (prompt + emitted tokens re-forwarded through the bucketed prefill).
+    (prompt + emitted tokens re-forwarded through the chunked prefill).
     Migration moves O(ctx · layers · kv_heads · head_dim) bytes;
     re-prefill recomputes a forward pass over ctx tokens — the crossover
     context is where keeping live KV beats recomputing it, the number an
@@ -761,7 +530,7 @@ def bench_migrate():
 
     from hetu_tpu import models
     from hetu_tpu.ps import van
-    from hetu_tpu.serve import ServeEngine
+    from hetu_tpu.serve import PagedServeEngine
     from hetu_tpu.serve import migrate as mg
 
     smoke = bool(os.environ.get("HETU_BENCH_SMOKE"))
@@ -790,8 +559,8 @@ def bench_migrate():
         dtype=DTYPE)
     model = models.GPTModel(cfg)
     variables = model.init(jax.random.PRNGKey(0))
-    src = ServeEngine(model, variables, num_slots=2, max_len=MAXLEN)
-    dst = ServeEngine(model, variables, num_slots=2, max_len=MAXLEN)
+    src = PagedServeEngine(model, variables, num_slots=2, max_len=MAXLEN)
+    dst = PagedServeEngine(model, variables, num_slots=2, max_len=MAXLEN)
     port = van.serve(0)
     g = np.random.default_rng(0)
 
@@ -1527,15 +1296,15 @@ def bench_quant():
 
     # --- (2) KV migration payload: none / bf16 / int8 ------------------
     from hetu_tpu import models
-    from hetu_tpu.serve import ServeEngine
+    from hetu_tpu.serve import PagedServeEngine
     from hetu_tpu.serve import migrate as mg
 
     cfg = models.GPTConfig(
         vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
         ffn_size=256, max_position=max(2 * CTX, 128), dropout_rate=0.0)
     model = models.GPTModel(cfg)
-    eng = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
-                      num_slots=1, max_len=max(2 * CTX, 128))
+    eng = PagedServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                           num_slots=1, max_len=max(2 * CTX, 128))
     slot = eng.alloc_slot()
     eng.prefill(slot, [int(t) for t in
                        np.random.default_rng(0).integers(0, 512, CTX)])
@@ -1684,7 +1453,7 @@ def bench_crosshost():
     from hetu_tpu.resilience.faults import (
         FaultEvent, FaultInjector, FaultSchedule,
     )
-    from hetu_tpu.serve import ServeEngine, ServingPool
+    from hetu_tpu.serve import PagedServeEngine, ServingPool
     from hetu_tpu.serve.crosshost import CrossProcessServingPool
     from hetu_tpu.serve.scheduler import Request
     from hetu_tpu.telemetry import timeline, trace
@@ -1709,8 +1478,8 @@ def bench_crosshost():
     variables = model.init(jax.random.PRNGKey(0))
 
     def factory():
-        return ServeEngine(model, variables, num_slots=N_REQ,
-                           max_len=MAXLEN, min_bucket=8)
+        return PagedServeEngine(model, variables, num_slots=N_REQ,
+                                max_len=MAXLEN, min_bucket=8)
 
     inproc_s = []
     pool = ServingPool({"a": factory, "b": factory}, start_poll=False)
@@ -2643,7 +2412,7 @@ def bench_autoscale():
     model_spec = {"vocab_size": 97, "hidden_size": 64, "num_layers": 2,
                   "num_heads": 4, "ffn_size": 128, "max_position": 64,
                   "num_slots": 4, "max_len": 48, "min_bucket": 8,
-                  "seed": 0, "engine": "paged", "page_size": 8}
+                  "seed": 0, "page_size": 8}
     slo_classes = {
         "gold": {"priority": 2, "weight": 4.0, "ttft_slo_s": GOLD_SLO},
         "bronze": {"priority": 0, "weight": 1.0, "ttft_slo_s": None},
@@ -2810,8 +2579,7 @@ def bench_autoscale():
         "extra": {
             "spike": {"peak_x": 10.0, "duration_s": DUR,
                       "base_qps": QPS, "seed": 0},
-            "fleet": {"min_members": MINM, "max_members": MAXM,
-                      "engine": "paged"},
+            "fleet": {"min_members": MINM, "max_members": MAXM},
             "on": on, "off": off,
             "gold_ttft_slo_s": GOLD_SLO,
         },
@@ -3167,8 +2935,7 @@ def main():
                  f" — set HETU_BENCH_SMOKE=1 for a tiny-shape code-path run")
     {"gpt": bench_gpt,
      "resnet": bench_resnet, "ctr": bench_ctr, "moe": bench_moe,
-     "gpt_sweep": bench_gpt_sweep, "serve": bench_serve,
-     "paged": bench_paged,
+     "gpt_sweep": bench_gpt_sweep,
      "ctr_serve": bench_ctr_serve,
      "migrate": bench_migrate,
      "quant": bench_quant,

@@ -1,7 +1,7 @@
 """GPT serving: KV-cache decode + continuous batching over the van.
 
 The full serving path end to end — byte-level prompts go over the blob
-channel to an InferenceServer whose engine decodes through the slot KV
+channel to an InferenceServer whose engine decodes through the paged KV
 cache, with concurrent clients exercising the continuous-batching
 scheduler:
 
@@ -28,7 +28,7 @@ import hetu_tpu as ht
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, InferenceClient, InferenceServer,
-    ServeEngine,
+    PagedServeEngine,
 )
 from hetu_tpu.utils.logger import MetricLogger
 
@@ -59,12 +59,13 @@ def main():
         max_position=args.max_len, dropout_rate=0.0))
     variables = model.init(jax.random.PRNGKey(0))
     mesh = ht.make_mesh(tp=args.tp) if args.tp > 1 else None
-    engine = ServeEngine(model, variables, num_slots=args.slots,
-                         max_len=args.max_len, mesh=mesh)
+    engine = PagedServeEngine(model, variables, num_slots=args.slots,
+                              max_len=args.max_len, mesh=mesh)
     server = InferenceServer(ContinuousBatchingScheduler(engine),
                              max_clients=args.clients)
     print(f"serving on 127.0.0.1:{server.port} "
-          f"(slots={args.slots}, buckets={engine.buckets}, tp={args.tp})")
+          f"(slots={args.slots}, chunk buckets={engine.chunk_buckets}, "
+          f"tp={args.tp})")
 
     results = {}
     errors = []

@@ -29,7 +29,7 @@ bootstrap_example(8)  # virtual CPU devices for bare runs + compile cache
 import jax
 
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
-from hetu_tpu.serve import ServeEngine, ServingPool
+from hetu_tpu.serve import PagedServeEngine, ServingPool
 
 PROMPTS = [
     "the tpu mesh hums",
@@ -58,8 +58,8 @@ def main():
     variables = model.init(jax.random.PRNGKey(0))
 
     def factory():
-        return ServeEngine(model, variables, num_slots=args.slots,
-                           max_len=args.max_len)
+        return PagedServeEngine(model, variables, num_slots=args.slots,
+                                max_len=args.max_len)
 
     pool = ServingPool({"alpha": factory, "beta": factory},
                        health_poll_s=0.05, max_loop_errors=2)

@@ -62,7 +62,7 @@ class MultiHeadAttention(Module):
         q, k, v = (jnp.moveaxis(t, 1, 2)
                    for t in self._qkv(p, x))  # [B,nh,S,hd]
         if mask is None and self.causal:
-            out = self._causal_core(q, k, v)  # shared with prefill_step
+            out = self._causal_core(q, k, v)
         elif self.attention_impl == "flash":
             from hetu_tpu.ops.pallas_kernels import flash_attention
             out = flash_attention(q, k, v, causal=False)
@@ -83,10 +83,8 @@ class MultiHeadAttention(Module):
         return y, {}
 
     def _causal_core(self, q, k, v):
-        """The unmasked causal attention core, honoring attention_impl —
-        ONE body shared by :meth:`apply` and :meth:`prefill_step` so
-        serving cannot numerically drift from training (incl. the flash
-        kernel path)."""
+        """The unmasked causal attention core, honoring attention_impl
+        (incl. the flash kernel path)."""
         if self.attention_impl == "flash":
             from hetu_tpu.ops.pallas_kernels import flash_attention
             return flash_attention(q, k, v, causal=True)
@@ -113,33 +111,17 @@ class MultiHeadAttention(Module):
                           p["out_weight"].astype(self.dtype),
                           p["out_bias"].astype(self.dtype))
 
-    def prefill_step(self, variables, x):
-        """Causal prefill that also returns the chunk's K/V for a cache.
-
-        x: [B, S, H] → (y [B, S, H], k [B, S, nh, hd], v [B, S, nh, hd]).
-        Inference-only (no dropout); numerics match
-        ``apply(causal=True, train=False)`` token for token.
-        """
-        if not self.causal:
-            raise NotImplementedError("KV-cache decode is causal-LM only")
-        p = variables["params"]
-        b, s, _ = x.shape
-        x = x.astype(self.dtype)
-        q, k, v = self._qkv(p, x)
-        out = self._causal_core(*(jnp.moveaxis(t, 1, 2)
-                                  for t in (q, k, v)))
-        return self._out(p, out, b, s), k, v
-
     def prefill_chunk_step(self, variables, x, k_cache, v_cache, starts):
-        """Chunked prefill against a cache (the paged engine's prefill).
+        """Chunked prefill against a cache (the serving engine's prefill).
 
         x: [B, S_c, H] — a chunk whose token ``i`` sits at absolute
         position ``starts[b] + i``; k_cache/v_cache: [B, T, nh, hd]
         already holding the tokens before the chunk (a shared prefix,
         earlier chunks).  Writes the chunk's K/V at ``starts`` and
         attends over history + the chunk's causal triangle.  Returns
-        (y [B, S_c, H], new_k_cache, new_v_cache).  With starts == 0 and
-        S_c == T the numerics match :meth:`prefill_step` token-for-token.
+        (y [B, S_c, H], new_k_cache, new_v_cache).  Inference-only (no
+        dropout); with starts == 0 the tokens match
+        ``apply(causal=True, train=False)``.
         """
         if not self.causal:
             raise NotImplementedError("KV-cache decode is causal-LM only")
@@ -153,7 +135,7 @@ class MultiHeadAttention(Module):
         return self._out(p, out, b, s), k_cache, v_cache
 
     def decode_step(self, variables, x, k_cache, v_cache, lengths):
-        """One-token decode against a slot cache.
+        """One-token decode against a cache.
 
         x: [B, 1, H]; k_cache/v_cache: [B, T, nh, hd]; lengths: [B] int32 =
         tokens already cached (the new token's K/V is written at that

@@ -90,16 +90,6 @@ class TransformerBlock(Module):
                                                           "ln2", x))
         return x + self._mod(p, self.ffn_out, "ffn_out", self.activation(h))
 
-    def prefill_step(self, variables, x):
-        """x [B,S,H] → (out [B,S,H], k [B,S,nh,hd], v [B,S,nh,hd])."""
-        if not self.pre_norm:
-            raise NotImplementedError("KV-cache decode needs pre-LN blocks")
-        p = variables["params"]
-        a, k, v = self.attn.prefill_step(
-            {"params": p["attn"], "state": {}},
-            self._mod(p, self.ln1, "ln1", x))
-        return self._mlp(p, x + a), k, v
-
     def prefill_chunk_step(self, variables, x, k_cache, v_cache, starts):
         """Chunked prefill: x [B,S_c,H] at absolute positions
         ``starts[b] + i``, caches [B,T,nh,hd] holding everything before

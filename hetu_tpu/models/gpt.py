@@ -105,35 +105,6 @@ class GPTModel(Module):
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
 
-    def prefill_with_cache(self, variables, input_ids, *, last_index=None):
-        """Full-prompt forward that also returns every layer's K/V.
-
-        input_ids: [B, S] (right-padded to the serving bucket; pad
-        positions produce junk K/V that decode masks/overwrites).
-        Returns (logits, k [L, B, S, nh, hd], v [L, B, S, nh, hd]) where
-        logits is [B, S, V] — or [B, V] when ``last_index`` (the last real
-        prompt position) is given, so serving skips the [S, V] head matmul
-        for the S-1 positions whose logits it would throw away.
-        """
-        p = variables["params"]
-        c = self.c
-        b, s = input_ids.shape
-        h = ops.embedding_lookup(p["tok_emb"], input_ids)
-        h = (h + p["pos_emb"][None, :s]).astype(c.dtype)
-
-        def layer(carry, p_l):
-            out, k, v = self.block.prefill_step(
-                {"params": p_l, "state": {}}, carry)
-            return out, (k, v)
-
-        h, (ks, vs) = jax.lax.scan(layer, h, p["blocks"])
-        h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
-        if last_index is not None:
-            h = jax.lax.dynamic_index_in_dim(h, last_index, axis=1,
-                                             keepdims=False)  # [B, H]
-        logits = ops.linear(h, p["tok_emb"].T.astype(c.dtype))
-        return logits, ks, vs
-
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None):
         """Chunked prefill: forward ONE chunk of the prompt against a
@@ -149,8 +120,7 @@ class GPTModel(Module):
         carried through the layer scan (``ops.scan_cached_layers``).
         Returns (logits [B, V] at chunk-relative ``last_index``
         (default S_c - 1), new_k, new_v).  With start == 0 and one chunk
-        covering the prompt, the numerics match
-        :meth:`prefill_with_cache` token-for-token.
+        covering the prompt, the tokens match :meth:`apply`'s.
         """
         p = variables["params"]
         c = self.c

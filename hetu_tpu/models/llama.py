@@ -92,11 +92,7 @@ class LlamaBlock(Module):
                                     jnp.float32),
         }, "state": {}}
 
-    def _attention_with_kv(self, p, x, cos, sin):
-        """Shared causal-attention body; also returns the chunk's rotated
-        un-repeated K/V in cache layout [B, S, nkv, hd] so training
-        (:meth:`apply`) and serving prefill stay ONE code path — the
-        decode-parity guarantee rides on them never drifting."""
+    def _attention(self, p, x, cos, sin):
         c = self.c
         b, s, h = x.shape
         nh, nkv = c.num_heads, c.num_kv_heads
@@ -104,23 +100,18 @@ class LlamaBlock(Module):
         q, k, v = (jnp.moveaxis(t, 1, 2) for t in (q, k, v))  # [B,h,S,D]
         q = ops.apply_rope(q, cos, sin)
         k = ops.apply_rope(k, cos, sin)
-        kr, vr = k, v
         if nkv != nh:  # GQA: each kv head serves num_heads/nkv query heads
             rep = nh // nkv
-            kr = jnp.repeat(k, rep, axis=1)
-            vr = jnp.repeat(v, rep, axis=1)
+            k = jnp.repeat(k, rep, axis=1)
+            v = jnp.repeat(v, rep, axis=1)
         if c.attention_impl == "flash":
             from hetu_tpu.ops.pallas_kernels import flash_attention
-            out = flash_attention(q, kr, vr, causal=True)
+            out = flash_attention(q, k, v, causal=True)
         else:
-            out = ops.causal_attention(q, kr, vr)
+            out = ops.causal_attention(q, k, v)
         out = jnp.moveaxis(out, 1, 2).reshape(b, s, h)
-        a = ops.linear(out.astype(c.dtype),
-                       p["out_weight"].astype(c.dtype))
-        return a, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2)
-
-    def _attention(self, p, x, cos, sin):
-        return self._attention_with_kv(p, x, cos, sin)[0]
+        return ops.linear(out.astype(c.dtype),
+                          p["out_weight"].astype(c.dtype))
 
     def apply(self, variables, x, cos, sin):
         p = variables["params"]
@@ -154,16 +145,6 @@ class LlamaBlock(Module):
         k = qkv[..., nh * hd:(nh + nkv) * hd].reshape(b, s, nkv, hd)
         v = qkv[..., (nh + nkv) * hd:].reshape(b, s, nkv, hd)
         return q, k, v
-
-    def prefill_step(self, variables, x, cos, sin):
-        """cos/sin: [S, hd/2] chunk tables (prefill starts at position 0).
-        x [B,S,H] → (out [B,S,H], k [B,S,nkv,hd] rotated, v [B,S,nkv,hd]).
-        """
-        p = variables["params"]
-        a, k, v = self._attention_with_kv(
-            p["attn"], ops.rms_norm(x, p["rms1_scale"], eps=self.c.rms_eps),
-            cos, sin)
-        return self._mlp(p, x + a), k, v
 
     def prefill_chunk_step(self, variables, x, k_cache, v_cache, starts,
                            cos, sin):
@@ -257,31 +238,6 @@ class LlamaModel(Module):
         return logits, {}
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
-
-    def prefill_with_cache(self, variables, input_ids, *, last_index=None):
-        """Full-prompt forward returning per-layer rotated K/V.
-
-        input_ids: [B, S] → (logits, k [L, B, S, nkv, hd],
-        v [L, B, S, nkv, hd]); logits is [B, S, V], or [B, V] when
-        ``last_index`` names the last real prompt position (serving skips
-        the head matmul for the padded tail)."""
-        p = variables["params"]
-        c = self.c
-        h = ops.embedding_lookup(p["tok_emb"], input_ids).astype(c.dtype)
-        cos, sin = self._tables(input_ids.shape[1])
-
-        def layer(carry, p_l):
-            out, k, v = self.block.prefill_step(
-                {"params": p_l, "state": {}}, carry, cos, sin)
-            return out, (k, v)
-
-        h, (ks, vs) = jax.lax.scan(layer, h, p["blocks"])
-        h = ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
-        if last_index is not None:
-            h = jax.lax.dynamic_index_in_dim(h, last_index, axis=1,
-                                             keepdims=False)  # [B, H]
-        logits = ops.linear(h, p["lm_head"].T.astype(c.dtype))
-        return logits, ks, vs
 
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None):

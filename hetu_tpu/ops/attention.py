@@ -35,7 +35,7 @@ def causal_attention(q, k, v, *, scale=None):
     return attention(q, k, v, mask=mask, scale=scale)
 
 
-# ---- serving decode: attention over a preallocated slot cache ----
+# ---- serving decode: attention over a preallocated cache ----
 # (hetu_tpu/serve) — the cache is TIME-major ([B, T, kv_heads, D]) because
 # every write is a per-sequence update at one time index; attention
 # transposes to head-major internally.
@@ -57,9 +57,12 @@ def cache_update(k_cache, v_cache, k_new, v_new, lengths):
 def read_cache_layer(cache, layer):
     """Layer ``layer`` of an all-layer cache, as the dense ``[B, T, kv_heads,
     D]`` the attention steps below work on.  A cache that is held some other
-    way reads its own layer (``cache.read(layer)``: the paged engine's
+    way reads its own layer (``cache.read(layer)``: the serving engine's
     ``serve.kv_cache.PagedLayers`` gathers that layer's pages, and only
-    them); a plain ``[L, B, T, kv_heads, D]`` array is indexed."""
+    them); a plain ``[L, B, T, kv_heads, D]`` array is indexed.  No engine
+    holds plain arrays: they are the tests' oracle for a cached but unpaged
+    run (``tests/paged_programs.py`` ``dense_greedy``) and what
+    ``benchmarks/tools/compile_v5e.py`` hands the entry points."""
     read = getattr(cache, "read", None)
     if read is not None:
         return read(layer)
@@ -71,8 +74,9 @@ def write_cache_layer(cache, layer, view, at, n: int):
     :func:`read_cache_layer`, or a reshape of it that keeps ``[B, T]`` in
     front): the ``n`` rows from position ``at[b]`` on, ``at`` [B] int32.  A
     cache that writes its own rows (``cache.write(layer, rows)``) is given
-    just those rows; a plain array takes the whole view back as its layer.
-    Returns the cache."""
+    just those rows; a plain array (:func:`read_cache_layer` says who
+    passes one) takes the whole view back as its layer.  Returns the
+    cache."""
     write = getattr(cache, "write", None)
     if write is not None:
         rows = jax.vmap(
@@ -121,7 +125,8 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):
     or stale from a previous page occupant) are masked out.
 
     With ``starts == 0`` and S_c == T this reduces to causal attention —
-    the property the paged-vs-slot token-parity tests ride on.
+    the property the engine's token parity with the training forward
+    rides on.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -145,7 +150,7 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, scale=None):
-    """Single-token attention against a slot cache (GQA-aware).
+    """Single-token attention against a cache (GQA-aware).
 
     q: [B, heads, 1, D] — the newest token's query, already positioned at
     index ``lengths[b]`` in its sequence (so its K/V must have been written
@@ -153,7 +158,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None):
     with kv_heads dividing heads (kv_heads < heads = GQA; repeats serve
     each kv head to heads/kv_heads query heads).  lengths: [B] int32 index
     of the newest token; positions > lengths[b] (unwritten or stale from a
-    previous slot occupant) are masked out.
+    previous occupant) are masked out.
     """
     if q.shape[-2] != 1:
         raise ValueError(

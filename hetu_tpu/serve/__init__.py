@@ -3,19 +3,17 @@
 The serving layer the ROADMAP's "heavy traffic" north star needs on top
 of the training-only models:
 
-  * :mod:`kv_cache` — GQA-aware K/V caches: the slot allocator
-    (:class:`KVCache`) and the PAGED allocator (:class:`PagedKVCache`)
-    with refcounted prefix sharing + copy-on-write, so finished
-    sequences release memory to queued requests and identical system
-    prompts dedup to one physical copy;
-  * :mod:`engine` — bucketed jit-compiled prefill + fixed-shape
-    single-token decode (bounded executable count) over the existing
-    GPT/Llama forwards, optionally tp-sharded over a mesh; the paged
-    variant (:class:`PagedServeEngine`) adds page-table gather/scatter
-    steps and page-aligned chunked prefill;
+  * :mod:`kv_cache` — the GQA-aware paged K/V cache
+    (:class:`PagedKVCache`) with refcounted prefix sharing +
+    copy-on-write, so finished sequences release memory to queued
+    requests and identical system prompts dedup to one physical copy;
+  * :mod:`engine` — :class:`PagedServeEngine`: bucketed jit-compiled
+    page-aligned chunked prefill + single-token decode with page-table
+    gather/scatter steps (bounded executable count) over the models'
+    cache entry points, optionally tp-sharded over a mesh;
   * :mod:`scheduler` — continuous batching: admit into free slots every
-    decode step, evict on EOS/max_tokens/deadline, token-budget (slot)
-    or page-budget (paged) backpressure, chunked-prefill interleave;
+    decode step, evict on EOS/max_tokens/deadline, page-budget
+    backpressure, chunked-prefill interleave;
   * :mod:`server` — blob-channel front-end over the van transport with
     per-request timeouts, idempotent resubmission dedup, and graceful
     shutdown;
@@ -38,9 +36,9 @@ examples/ctr_serve.py for the end-to-end paths.
 """
 
 from hetu_tpu.serve.crosshost import CrossProcessServingPool
-from hetu_tpu.serve.engine import PagedServeEngine, ServeEngine
+from hetu_tpu.serve.engine import PagedServeEngine
 from hetu_tpu.serve.kv_cache import (
-    KVCache, KVCacheSpec, KVSlotSnapshot, PagedKVCache,
+    KVCacheSpec, KVSlotSnapshot, PagedKVCache,
 )
 from hetu_tpu.serve.metrics import ServeMetrics
 from hetu_tpu.serve.migrate import MigrationError
@@ -55,8 +53,7 @@ from hetu_tpu.serve.server import (
 )
 
 __all__ = [
-    "ServeEngine", "PagedServeEngine", "KVCache", "PagedKVCache",
-    "KVCacheSpec", "KVSlotSnapshot",
+    "PagedServeEngine", "PagedKVCache", "KVCacheSpec", "KVSlotSnapshot",
     "ServeMetrics", "MigrationError", "ServingPool",
     "CrossProcessServingPool",
     "ContinuousBatchingScheduler", "Request",

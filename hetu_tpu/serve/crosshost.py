@@ -210,15 +210,12 @@ def build_engine(model_spec: dict):
     in-test reference engines: same spec → same weights everywhere, the
     property that makes cross-process failover token-exact.
 
-    ``{"engine": "paged"}`` in the spec builds a
-    :class:`~hetu_tpu.serve.engine.PagedServeEngine` (page size via
-    ``"page_size"``, pool size via ``"num_pages"``) instead of the slot
-    engine — same weights, same wire; migration between the two is the
-    cross-allocator path serve/migrate.py already supports."""
+    The engine is a :class:`~hetu_tpu.serve.engine.PagedServeEngine`
+    (page size via ``"page_size"``, pool size via ``"num_pages"``)."""
     import jax
 
     from hetu_tpu.models.gpt import GPTConfig, GPTModel
-    from hetu_tpu.serve.engine import PagedServeEngine, ServeEngine
+    from hetu_tpu.serve.engine import PagedServeEngine
     spec = {**DEFAULT_MODEL, **(model_spec or {})}
     cfg = GPTConfig(
         vocab_size=int(spec["vocab_size"]),
@@ -229,17 +226,13 @@ def build_engine(model_spec: dict):
         max_position=int(spec["max_position"]), dropout_rate=0.0)
     model = GPTModel(cfg)
     variables = model.init(jax.random.PRNGKey(int(spec["seed"])))
-    if spec.get("engine") == "paged":
-        num_pages = spec.get("num_pages")
-        return model, variables, PagedServeEngine(
-            model, variables, num_slots=int(spec["num_slots"]),
-            max_len=int(spec["max_len"]),
-            page_size=int(spec.get("page_size", 8)),
-            num_pages=None if num_pages is None else int(num_pages),
-            min_bucket=int(spec["min_bucket"]))
-    return model, variables, ServeEngine(
+    num_pages = spec.get("num_pages")
+    return model, variables, PagedServeEngine(
         model, variables, num_slots=int(spec["num_slots"]),
-        max_len=int(spec["max_len"]), min_bucket=int(spec["min_bucket"]))
+        max_len=int(spec["max_len"]),
+        page_size=int(spec.get("page_size", 8)),
+        num_pages=None if num_pages is None else int(num_pages),
+        min_bucket=int(spec["min_bucket"]))
 
 
 # ---------------------------------------------------------------------------

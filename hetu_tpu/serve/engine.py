@@ -1,24 +1,27 @@
-"""Bucketed prefill + single-token decode over the model forwards.
+"""Chunked prefill + single-token decode over the model forwards.
 
-Two engines share one serving surface: :class:`ServeEngine` over the
-slot cache, and :class:`PagedServeEngine` over the paged pool (page
-tables, prefix sharing, chunked prefill — see kv_cache.py).
+One engine, :class:`PagedServeEngine`, over the paged pool (page tables,
+prefix sharing, chunked prefill — see kv_cache.py).
+
+What a served model implements: ``prefill_chunk_with_cache`` and
+``decode_with_cache`` (both take the cache layers in the places of their
+``k_cache`` / ``v_cache`` arguments and return them beside the logits),
+and either ``kv_cache_spec()`` or the config fields
+:meth:`KVCacheSpec.from_model` reads.  ``models/gpt.py``,
+``models/llama.py`` and ``models/longcat_flash.py`` do.
 
 Compilation discipline is the whole point of this module: serving traffic
 has arbitrary prompt lengths, and a naive jit would compile one executable
-per distinct length.  Instead prompts are right-padded to power-of-two
-BUCKETS (plus the cache's max_len as the last bucket), so the engine
-compiles at most ``len(buckets)`` prefill executables + 1 decode
-executable for the whole life of the server — asserted in
-tests/test_serve.py via :meth:`compiled_executables`.  (The paged
-engine's analog: pow2 chunk buckets for prefill, pow2 active-batch x
-page-count buckets for decode — tests/test_paged_kv.py.)
+per distinct length.  Instead a prompt is prefilled in page-aligned chunks
+right-padded to power-of-two chunk BUCKETS, and a decode step runs over a
+power-of-two bucket of active slots by a power-of-two bucket of pages, so
+the engine compiles at most :attr:`PagedServeEngine.max_executables`
+programs for the whole life of the server — asserted in
+tests/test_paged_kv.py via :meth:`PagedServeEngine.compiled_executables`.
 
 Prefill runs one request at a time (batch 1, bounded compile count);
-decode steps ALL cache slots at once with fixed shapes (``[num_slots]``
-tokens/lengths), so continuous batching admissions never change the
-decode executable.  Free slots ride along masked — wasted FLOPs on an
-idle slot are cheaper than a recompile.
+decode steps every ACTIVE slot at once, so continuous batching admissions
+change the decode executable only when they cross a bucket.
 
 Tensor parallelism: pass ``mesh`` and the engine places the parameters
 with the Megatron split points (qkv/ffn-in column, out/ffn-down row — the
@@ -37,10 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from hetu_tpu.parallel.mesh import AXIS_TP, mesh_context
+from hetu_tpu.parallel.mesh import AXIS_TP
 from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
-    KVCache, KVCacheSpec, PagedKVCache, PagedLayers, pow2_ceil,
+    KVCacheSpec, PagedKVCache, PagedLayers, pow2_ceil,
 )
 from hetu_tpu.serve.metrics import ServeMetrics
 from hetu_tpu.telemetry import trace
@@ -65,253 +68,16 @@ def _pow2_buckets(lo: int, hi: int) -> tuple:
     return tuple(out)
 
 
-class ServeEngine:
-    """Owns params + KV cache + the jitted prefill/decode executables.
-
-    model: GPTModel or LlamaModel (anything with ``prefill_with_cache`` /
-    ``decode_with_cache``).  num_slots bounds concurrent sequences;
-    max_len bounds tokens per sequence (prompt + generation), defaulting
-    to the model's max_position.
-    """
-
-    def __init__(self, model, variables, *, num_slots: int = 8,
-                 max_len: Optional[int] = None, mesh=None,
-                 min_bucket: int = 16,
-                 metrics: Optional[ServeMetrics] = None):
-        self.model = model
-        self.metrics = metrics or ServeMetrics()
-        c = model.c
-        max_len = int(max_len or c.max_position)
-        if max_len > c.max_position:
-            raise ValueError(f"max_len {max_len} exceeds the model's "
-                             f"max_position {c.max_position}")
-        spec = KVCacheSpec.from_model(model)
-        self.buckets = _pow2_buckets(min(min_bucket, max_len), max_len)
-
-        self.mesh = mesh
-        # kv-head sharded cache when GQA heads divide tp, else
-        # replicated (graceful, same policy as Strategy._fit)
-        self.params, cache_sharding = _place_params_and_cache_spec(
-            model, variables, mesh, spec)
-        self.cache = KVCache(spec, num_slots, max_len,
-                             sharding=cache_sharding)
-
-        # newest token per slot (decode feeds all slots every step)
-        self.last_tokens = np.zeros(num_slots, np.int32)
-        self.active = np.zeros(num_slots, bool)
-
-        # ONE jitted prefill: jax.jit's shape cache specializes it per
-        # bucket width, so bucket_for() alone bounds the executable count
-        self._prefill_fn = None
-        self._decode_fn = None
-        self._seen_buckets = set()
-
-    # ---- compile accounting ----
-    def compiled_executables(self) -> int:
-        """Executables actually compiled so far (the recompile budget the
-        tests assert): sum of jit-cache sizes across the step fns."""
-        return sum(fn._cache_size()
-                   for fn in (self._prefill_fn, self._decode_fn)
-                   if fn is not None)
-
-    @property
-    def max_executables(self) -> int:
-        """Hard ceiling: one per bucket + one decode."""
-        return len(self.buckets) + 1
-
-    def bucket_for(self, n: int) -> int:
-        for b in self.buckets:
-            if n <= b:
-                return b
-        raise ValueError(f"prompt of {n} tokens exceeds max_len "
-                         f"{self.cache.max_len}")
-
-    # ---- jitted step builders ----
-    def _build_prefill(self):
-        model = self.model
-
-        def fn(params, k_cache, v_cache, ids, slot, true_len):
-            # last_index: only the final real position's logits are
-            # computed — the padded tail's head matmul is skipped
-            logits, k, v = model.prefill_with_cache(
-                {"params": params, "state": {}}, ids,
-                last_index=true_len - 1)
-            # k: [L, 1, S, nkv, hd] — batch dim 1 IS the slot slice, so it
-            # writes into [L, slots, T, nkv, hd] at (0, slot, 0) directly
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, slot, 0, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, slot, 0, 0, 0))
-            first = jnp.argmax(logits[0], -1).astype(jnp.int32)
-            return k_cache, v_cache, first
-
-        return jax.jit(fn, donate_argnums=(1, 2))
-
-    def _build_decode(self):
-        model = self.model
-
-        def fn(params, k_cache, v_cache, tokens, lengths):
-            logits, k_cache, v_cache = model.decode_with_cache(
-                {"params": params, "state": {}}, tokens, k_cache, v_cache,
-                lengths)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return k_cache, v_cache, nxt
-
-        return jax.jit(fn, donate_argnums=(1, 2))
-
-    # ---- serving steps ----
-    def prefill(self, slot: int, prompt_ids) -> int:
-        """Run the prompt through the bucketed prefill into ``slot``;
-        returns the first generated (greedy) token."""
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        n = prompt.shape[0]
-        if n < 1:
-            raise ValueError("empty prompt")
-        if n >= self.cache.max_len:
-            raise ValueError(f"prompt of {n} tokens leaves no room to "
-                             f"generate within max_len {self.cache.max_len}")
-        s = self.bucket_for(n)
-        if self._prefill_fn is None:
-            self._prefill_fn = self._build_prefill()
-        if s not in self._seen_buckets:
-            self._seen_buckets.add(s)
-            self.metrics.inc("prefill_compiles")
-            trace.instant("serve.recompile",
-                          {"kind": "prefill", "bucket": s})
-        with trace.span("serve.prefill", {"slot": int(slot), "tokens": n,
-                                          "bucket": s}), \
-                mesh_context(self.mesh):
-            ids = np.zeros((1, s), np.int32)
-            ids[0, :n] = prompt
-            k, v, first = self._prefill_fn(
-                self.params, self.cache.k, self.cache.v,
-                jnp.asarray(ids), jnp.int32(slot), jnp.int32(n))
-            # the value fetch is the sync point: inside the span, so the
-            # span covers device execution, not just the async dispatch
-            first = int(first)
-        self.cache.update(k, v)
-        self.cache.lengths[slot] = n
-        self.last_tokens[slot] = first
-        self.active[slot] = True
-        self.metrics.inc("prefill_tokens", n)
-        return first
-
-    def decode(self) -> dict:
-        """One decode step over every slot; returns {slot: token} for the
-        active ones.  Inactive slots compute masked garbage (cheaper than
-        a shape change) and are ignored."""
-        if not self.active.any():
-            return {}
-        if (self.cache.lengths[self.active] >= self.cache.max_len).any():
-            raise RuntimeError(
-                "an active slot is at max_len; the scheduler must evict "
-                "before decoding further")
-        if self._decode_fn is None:
-            self._decode_fn = self._build_decode()
-            self.metrics.inc("decode_compiles")
-            trace.instant("serve.recompile", {"kind": "decode"})
-        # the paged engine's seams (there ``prep`` has work to do; here
-        # the operands are the engine's own arrays)
-        with trace.span("serve.decode"):
-            with trace.span("serve.decode.launch"):
-                k, v, nxt = self._decode_fn(
-                    self.params, self.cache.k, self.cache.v,
-                    jnp.asarray(self.last_tokens),
-                    jnp.asarray(self.cache.lengths))
-            with trace.span("serve.decode.fetch"):
-                nxt = np.asarray(nxt)  # the host blocked on the device
-            with trace.span("serve.decode.post"):
-                self.cache.update(k, v)
-                out = {}
-                for slot in np.nonzero(self.active)[0]:
-                    self.cache.lengths[slot] += 1
-                    self.last_tokens[slot] = nxt[slot]
-                    out[int(slot)] = int(nxt[slot])
-                self.metrics.inc("decode_steps")
-                self.metrics.observe_decode(len(out))
-        return out
-
-    # ---- live-slot migration ----
-    def export_slots(self, slot_ids) -> list:
-        """Snapshot mid-decode slots for hand-off to a peer engine: the
-        cache's truncated K/V rows plus this engine's per-slot decode
-        state (the last emitted token, which is NOT in the cache yet) in
-        ``meta`` — everything a peer needs to continue decoding
-        token-for-token with zero prefill.
-
-        Exported slots are SUSPENDED (allocated but excluded from
-        :meth:`decode`) until the caller either releases them (the
-        migration committed) or :meth:`resume_slots` them (rollback).
-        The wire transfer runs outside any lock, and a decode step
-        admitted in that window would otherwise silently advance the
-        exported slots past their requests' recorded tokens — tokens a
-        rollback could never recover."""
-        for slot in slot_ids:
-            if not self.active[int(slot)]:
-                raise ValueError(f"slot {int(slot)} is not mid-decode; "
-                                 f"nothing to migrate")
-        snaps = self.cache.export_slots(slot_ids)
-        for s in snaps:
-            s.meta["last_token"] = int(self.last_tokens[s.slot])
-        for slot in slot_ids:  # suspend LAST: any failure above leaves
-            self.active[int(slot)] = False  # every slot still decoding
-        return snaps
-
-    def resume_slots(self, slot_ids) -> None:
-        """Re-activate slots suspended by :meth:`export_slots` — the
-        rollback half of a failed migration: the source engine resumes
-        decoding them exactly where they stopped (``last_tokens`` was
-        kept through the suspension)."""
-        slots = [int(s) for s in slot_ids]
-        for slot in slots:  # validate-first: resume is all-or-nothing
-            if self.cache.lengths[slot] < 1:
-                raise ValueError(f"slot {slot} has no cached tokens to "
-                                 f"resume")
-        for slot in slots:
-            self.active[slot] = True
-
-    def adopt_slots(self, snapshots) -> dict:
-        """Adopt peer-exported slots; returns ``{source_slot: slot}``.
-        The next :meth:`decode` continues each adopted sequence exactly
-        where the source left off — no prefill step runs (the
-        ``serve.prefill`` span/metric stays flat, the zero-re-prefill
-        contract tests assert)."""
-        snaps = list(snapshots)
-        for s in snaps:
-            if "last_token" not in s.meta:
-                raise ValueError(
-                    f"slot snapshot {s.slot} has no last_token meta — "
-                    f"exported from a cache, not an engine?")
-        slot_map = self.cache.import_slots(snaps)
-        for s in snaps:
-            slot = slot_map[s.slot]
-            self.last_tokens[slot] = int(s.meta["last_token"])
-            self.active[slot] = True
-        self.metrics.inc("slots_adopted", len(slot_map))
-        return slot_map
-
-    # ---- slot lifecycle (delegates; engine keeps its masks in sync) ----
-    def alloc_slot(self) -> int:
-        slot = self.cache.alloc()
-        self.active[slot] = False
-        return slot
-
-    def release(self, slot: int) -> None:
-        self.active[slot] = False
-        self.last_tokens[slot] = 0
-        self.cache.free(slot)
-
-
 def _place_params_and_cache_spec(model, variables, mesh, spec):
-    """The tp placement both engines share: Megatron split points on the
-    params, kv-head-sharded cache when GQA heads divide tp."""
+    """The engine's tp placement: Megatron split points on the params,
+    kv-head-sharded cache when GQA heads divide tp."""
     params = variables["params"] if "params" in variables else variables
     cache_sharding = None
     if mesh is not None:
         tp = mesh.shape.get(AXIS_TP, 1)
         params = _DecodeTP().place(params, mesh)
-        # axis 3 holds the kv heads in both caches: ``[L, slots, T, heads,
-        # hd]`` and the paged pool's flat rows ``[L, pages, ps, heads * hd]``
+        # axis 3 holds the kv heads of the pool's flat rows
+        # ``[L, pages, ps, heads * hd]``
         axes = (None, None, None,
                 AXIS_TP if spec.num_kv_heads % tp == 0 else None)
         cache_sharding = NamedSharding(mesh, P(*axes))
@@ -336,8 +102,13 @@ class _PrefillCursor:
 
 
 class PagedServeEngine:
-    """ServeEngine over a :class:`PagedKVCache`: paged gather/scatter
-    decode, chunked prefill, prefix sharing with copy-on-write.
+    """Owns params + a :class:`PagedKVCache` + the jitted chunk/decode
+    executables: paged gather/scatter decode, chunked prefill, prefix
+    sharing with copy-on-write.
+
+    model: anything with the cache entry points the module docstring names.
+    num_slots bounds concurrent sequences; max_len bounds tokens per
+    sequence (prompt + generation), defaulting to the model's max_position.
 
     Both jitted programs hand the model the pools themselves, with the
     call's page tables and write map (:class:`PagedLayers`), in the places
@@ -348,12 +119,12 @@ class PagedServeEngine:
     view of every layer is built and the pool is never copied (how that was
     checked: ``kv_cache.py``'s docstring).
 
-    Drop-in for :class:`ServeEngine` everywhere the scheduler/pool/
-    migration stack touches an engine (same prefill/decode/export/adopt/
-    release surface, same ``cache.lengths``/``max_len``/``num_free``
-    geometry) plus the paged additions the scheduler's page-budget
-    admission and chunked-prefill interleave use: :meth:`admission_ok`,
-    :meth:`begin_prefill`, :meth:`prefill_step`.
+    The scheduler/pool/migration stack drives it through
+    :meth:`admission_ok`, :meth:`begin_prefill`, :meth:`prefill_step`
+    (page-budget admission, chunked prefill interleaved with decode),
+    :meth:`decode`, the export/resume/adopt/reindex verbs of a live-slot
+    migration, :meth:`alloc_slot`/:meth:`release`, and the cache's
+    ``lengths``/``max_len``/``num_free`` geometry.
 
     Compilation discipline: chunked prefill compiles one executable per
     power-of-two CHUNK bucket (a layer always gathers the slot's full page
@@ -361,8 +132,7 @@ class PagedServeEngine:
     one executable per power-of-two PAGE-COUNT bucket — short sequences
     gather a fraction of ``max_len`` a layer instead of every slot's worst
     case, which is where paged decode's per-step byte traffic win comes
-    from.  Both are asserted via
-    :meth:`compiled_executables` like the slot engine.
+    from.  Both are asserted via :meth:`compiled_executables`.
     """
 
     def __init__(self, model, variables, *, num_slots: int = 8,
@@ -555,7 +325,7 @@ class PagedServeEngine:
         """True when the page pool can hold this request's worst case
         alongside every outstanding reservation.  Prefix-shared pages
         are credited — the dedup is what lets a pool of identical system
-        prompts admit far past the slot cache's capacity.
+        prompts admit far past one private copy a request.
 
         The uncredited check runs first: when the worst case fits
         anyway (the common uncontended admission), no prefix probe runs
@@ -693,7 +463,7 @@ class PagedServeEngine:
                 return tok
 
     def prefill(self, slot: int, prompt_ids) -> int:
-        """Whole-prompt prefill (the slot-engine-compatible surface):
+        """Whole-prompt prefill, for callers with nothing to interleave:
         begin + advance every chunk in one call."""
         self.begin_prefill(slot, prompt_ids)
         while True:
@@ -706,12 +476,9 @@ class PagedServeEngine:
         """One decode step over the ACTIVE slots (paged gather/scatter);
         returns {slot: token} for them.
 
-        Unlike the slot engine (which steps every slot, active or not —
-        its cache rows exist anyway), the paged decode gathers only a
-        power-of-two BUCKET of active slots: per-step work scales with
-        live traffic, not the engine's concurrency ceiling, which is
-        what lets a paged engine carry 4x the slots of a slot engine at
-        the same per-step cost.  Pad rows in the bucket duplicate a real
+        The step gathers only a power-of-two BUCKET of active slots:
+        per-step work scales with live traffic, not the engine's
+        concurrency ceiling.  Pad rows in the bucket duplicate a real
         slot's table (harmless gather) but their write map points at the
         scratch page, so they can never corrupt the pool."""
         act = np.nonzero(self.active)[0]
@@ -794,8 +561,21 @@ class PagedServeEngine:
                                        self.cache.prefix_entries)
             return out
 
-    # ---- live-slot migration (same contract as ServeEngine) ----
+    # ---- live-slot migration ----
     def export_slots(self, slot_ids) -> list:
+        """Snapshot mid-decode slots for hand-off to a peer engine: the
+        cache's truncated K/V rows plus this engine's per-slot decode
+        state (the last emitted token, which is NOT in the cache yet) in
+        ``meta`` — everything a peer needs to continue decoding
+        token-for-token with zero prefill.
+
+        Exported slots are SUSPENDED (allocated but excluded from
+        :meth:`decode`) until the caller either releases them (the
+        migration committed) or :meth:`resume_slots` them (rollback).
+        The wire transfer runs outside any lock, and a decode step
+        admitted in that window would otherwise silently advance the
+        exported slots past their requests' recorded tokens — tokens a
+        rollback could never recover."""
         for slot in slot_ids:
             if not self.active[int(slot)]:
                 raise ValueError(f"slot {int(slot)} is not mid-decode; "
@@ -808,6 +588,10 @@ class PagedServeEngine:
         return snaps
 
     def resume_slots(self, slot_ids) -> None:
+        """Re-activate slots suspended by :meth:`export_slots` — the
+        rollback half of a failed migration: the source engine resumes
+        decoding them exactly where they stopped (``last_tokens`` was
+        kept through the suspension)."""
         slots = [int(s) for s in slot_ids]
         for slot in slots:
             if self.cache.lengths[slot] < 1:
@@ -817,6 +601,11 @@ class PagedServeEngine:
             self.active[slot] = True
 
     def adopt_slots(self, snapshots) -> dict:
+        """Adopt peer-exported slots; returns ``{source_slot: slot}``.
+        The next :meth:`decode` continues each adopted sequence exactly
+        where the source left off — no prefill step runs (the
+        ``serve.prefill_chunk`` span/metric stays flat, the
+        zero-re-prefill contract tests assert)."""
         snaps = list(snapshots)
         for s in snaps:
             if "last_token" not in s.meta:

@@ -1,24 +1,17 @@
-"""KV caches for decoder-LM serving: slot-granular and paged.
+"""The paged KV cache for decoder-LM serving.
 
-Two allocators share one spec/snapshot vocabulary:
+:class:`PagedKVCache` holds fixed-size PAGES (``page_size`` tokens) in a
+device-resident pool ``[L, num_pages, page_size, kv_heads * head_dim]``,
+per-request page tables, refcounted PREFIX SHARING (hash-of-token-prefix
+→ shared read-only pages, so identical system prompts across a pool's
+traffic dedup to one physical copy) with copy-on-write on the first
+divergent write, and an LRU prefix index whose pages are reclaimed under
+pressure.  The vLLM/Gemma-on-TPU serving memory model (PAPERS.md, arXiv
+2605.25645), under the jitted-step discipline of engine.py.  A sequence
+holds the pages its tokens fill, not ``max_len`` of them.
 
-* :class:`KVCache` — the original whole-sequence slot allocator: ONE
-  pair of arrays ``[L, num_slots, max_len, kv_heads, head_dim]``, a
-  free list of slots.  Simple, but every admitted sequence reserves
-  ``max_len`` tokens of HBM whether it uses them or not (internal
-  fragmentation), and identical prompts cache identical K/V twice.
-* :class:`PagedKVCache` — fixed-size PAGES (``page_size`` tokens) in a
-  device-resident pool ``[L, num_pages, page_size, kv_heads *
-  head_dim]``, per-request page tables, refcounted PREFIX SHARING
-  (hash-of-token-prefix → shared read-only pages, so identical system
-  prompts across a pool's traffic dedup to one physical copy) with
-  copy-on-write on the first divergent write, and an LRU prefix index
-  whose pages are reclaimed under pressure.  The vLLM/Gemma-on-TPU
-  serving memory model (PAPERS.md, arXiv 2605.25645), grafted onto the
-  same jitted-step engine discipline.
-
-Both hand whole slots to admitted requests and reclaim on eviction —
-finished sequences release their memory to queued requests immediately
+It hands a slot to each admitted request and reclaims it on eviction —
+finished sequences release their pages to queued requests immediately
 (continuous batching, scheduler.py) instead of waiting for a static
 batch to drain.
 
@@ -40,8 +33,8 @@ new rows, and the compiled programs hold nothing of the pool's size but the
 pool (checked in the HLO compiled for a described v5e,
 ``benchmarks/tools/compile_v5e_serve.py --hlo``, in the jaxpr by
 ``tests/paged_programs.py``, and on the chip by the ledger's
-``breakdown.device_ops``).  The allocator classes own the slot lifecycle and
-the per-slot host-side lengths.
+``breakdown.device_ops``).  The allocator owns the slot lifecycle and the
+per-slot host-side lengths.
 """
 
 from __future__ import annotations
@@ -126,173 +119,6 @@ class KVSlotSnapshot:
     @property
     def nbytes(self) -> int:
         return self.k.nbytes + self.v.nbytes
-
-
-class KVCache:
-    """Slot-allocated K/V arrays + free list.
-
-    ``k``/``v``: ``[L, num_slots, max_len, kv_heads, head_dim]`` jax
-    arrays, replaced wholesale by the engine after each jitted step.
-    ``lengths``: host-side int32 per slot — tokens currently cached.
-    """
-
-    def __init__(self, spec: KVCacheSpec, num_slots: int, max_len: int, *,
-                 sharding=None):
-        if num_slots < 1 or max_len < 2:
-            raise ValueError(f"need >=1 slot and max_len >= 2, got "
-                             f"{num_slots}/{max_len}")
-        self.spec = spec
-        self.num_slots = int(num_slots)
-        self.max_len = int(max_len)
-        k_row, v_row = spec.row_shapes()
-        self.k = jnp.zeros((spec.num_layers, num_slots, max_len) + k_row,
-                           spec.dtype)
-        self.v = jnp.zeros((spec.num_layers, num_slots, max_len) + v_row,
-                           spec.dtype)
-        if sharding is not None:
-            self.k = jax.device_put(self.k, sharding)
-            self.v = jax.device_put(self.v, sharding)
-        self.lengths = np.zeros(num_slots, np.int32)
-        # LIFO keeps hot slots hot (their pages are the ones most recently
-        # touched by a jitted step)
-        self._free = list(range(num_slots - 1, -1, -1))
-        self._import_fn = None  # lazily jitted slot writer (import_slots)
-
-    # ---- slot lifecycle ----
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def occupancy(self) -> float:
-        return 1.0 - len(self._free) / self.num_slots
-
-    def alloc(self) -> int:
-        """Claim a free slot (length reset); raises if none are free —
-        callers gate admission on ``num_free`` (scheduler backpressure)."""
-        if not self._free:
-            raise RuntimeError("KV cache has no free slots")
-        slot = self._free.pop()
-        self.lengths[slot] = 0
-        return slot
-
-    def free(self, slot: int) -> None:
-        """Release a slot back to the pool.  The K/V bytes are NOT zeroed —
-        decode masks positions beyond ``lengths`` and prefill overwrites
-        from position 0, so stale rows are unreachable."""
-        if slot in self._free:
-            raise ValueError(f"slot {slot} double-freed")
-        if not 0 <= slot < self.num_slots:
-            raise ValueError(f"slot {slot} out of range")
-        self.lengths[slot] = 0
-        self._free.append(slot)
-
-    def update(self, k, v) -> None:
-        """Swap in the arrays a jitted step returned."""
-        self.k, self.v = k, v
-
-    # ---- live-slot migration (serve/migrate.py rides on these) ----
-    def export_slots(self, slot_ids) -> list:
-        """Snapshot occupied slots for migration to a peer cache.
-
-        Each snapshot's K/V rows are truncated to the slot's live
-        ``lengths[slot]`` and fetched to the host — the slot itself stays
-        allocated and untouched, so a failed transfer rolls back to the
-        source simply by NOT freeing it.
-        """
-        snaps = []
-        for slot in slot_ids:
-            slot = int(slot)
-            if not 0 <= slot < self.num_slots:
-                raise ValueError(f"slot {slot} out of range")
-            if slot in self._free:
-                raise ValueError(f"slot {slot} is free; nothing to export")
-            n = int(self.lengths[slot])
-            if n < 1:
-                raise ValueError(f"slot {slot} has no cached tokens")
-            snaps.append(KVSlotSnapshot(
-                slot=slot, length=n,
-                k=np.asarray(self.k[:, slot, :n]),
-                v=np.asarray(self.v[:, slot, :n])))
-        return snaps
-
-    def import_slots(self, snapshots) -> dict:
-        """Adopt peer-exported snapshots; returns ``{source_slot: slot}``.
-
-        Validates EVERY snapshot against this cache's geometry before
-        allocating anything — a mismatched migration errors loudly and
-        adopts nothing (no partially-imported slots), which is what lets
-        the sender keep serving after a failed hand-off.
-        """
-        snaps = list(snapshots)
-        if len(snaps) > self.num_free:
-            raise RuntimeError(
-                f"cannot adopt {len(snaps)} slots: only {self.num_free} "
-                f"free")
-        spec = self.spec
-        dt = np.dtype(spec.dtype)
-        for s in snaps:
-            if s.length < 1 or s.length >= self.max_len:
-                raise ValueError(
-                    f"slot snapshot of {s.length} tokens does not leave "
-                    f"room to decode within max_len {self.max_len}")
-            for name, arr, row in (("k", s.k, spec.row_shapes()[0]),
-                                   ("v", s.v, spec.row_shapes()[1])):
-                want = (spec.num_layers, s.length) + row
-                if tuple(arr.shape) != want:
-                    raise ValueError(
-                        f"{name} geometry mismatch: snapshot "
-                        f"{tuple(arr.shape)} vs cache spec {want} "
-                        f"(layers/kv_heads/head_dim must match exactly)")
-                if np.dtype(arr.dtype) != dt:
-                    raise ValueError(
-                        f"{name} dtype mismatch: snapshot "
-                        f"{np.dtype(arr.dtype).name} vs cache {dt.name}")
-        if self._import_fn is None:
-            def write(k, v, k_rows, v_rows, slot):
-                # rows padded to a power-of-two bucket: executables stay
-                # bounded (one per bucket, like the engine's prefill)
-                # while donation lets XLA update the cache in place — a
-                # slot adoption moves <= 2x its live bytes, never a
-                # whole-cache copy and never a full max_len row
-                k = jax.lax.dynamic_update_slice(k, k_rows,
-                                                 (0, slot, 0, 0, 0))
-                v = jax.lax.dynamic_update_slice(v, v_rows,
-                                                 (0, slot, 0, 0, 0))
-                return k, v
-
-            self._import_fn = jax.jit(write, donate_argnums=(0, 1))
-        slot_map: dict = {}
-        allocated: list = []
-        try:
-            for s in snaps:
-                slot = self.alloc()
-                allocated.append(slot)
-                pad = 1
-                while pad < s.length:
-                    pad *= 2
-                pad = min(pad, self.max_len)
-                k_row, v_row = spec.row_shapes()
-                k_rows = np.zeros((spec.num_layers, 1, pad) + k_row, dt)
-                v_rows = np.zeros((spec.num_layers, 1, pad) + v_row, dt)
-                k_rows[:, 0, :s.length] = s.k
-                v_rows[:, 0, :s.length] = s.v
-                self.k, self.v = self._import_fn(
-                    self.k, self.v, jnp.asarray(k_rows),
-                    jnp.asarray(v_rows), jnp.int32(slot))
-                self.lengths[slot] = s.length
-                slot_map[s.slot] = slot
-        except Exception:
-            for slot in allocated:
-                self.free(slot)
-            raise
-        return slot_map
-
-    @property
-    def active_tokens(self) -> int:
-        """Tokens currently cached across occupied slots (the scheduler's
-        token-budget currency)."""
-        return int(self.lengths.sum())
 
 
 # ---------------------------------------------------------------------------
@@ -427,8 +253,8 @@ class PagedKVCache:
         self.page_size = int(page_size)
         self.pages_per_slot = -(-self.max_len // self.page_size)  # ceil
         if num_pages is None:
-            # parity default: same token capacity as the slot cache,
-            # plus the scratch page
+            # default: every slot can reach max_len at once, plus the
+            # scratch page
             num_pages = 1 + self.num_slots * self.pages_per_slot
         self.num_pages = int(num_pages)
         if self.num_pages < 2:
@@ -445,7 +271,7 @@ class PagedKVCache:
         self.ref_table = np.zeros(self.num_pages, np.int32)
         self.ref_index = np.zeros(self.num_pages, np.int32)
         self._free_slots = list(range(self.num_slots - 1, -1, -1))
-        # LIFO like the slot cache: recently-touched pages stay hot.
+        # LIFO: recently-touched pages stay hot.
         # Page 0 excluded — the scratch page is never allocated.
         self._free_pages = list(range(self.num_pages - 1, 0, -1))
         self._reserve = np.zeros(self.num_slots, np.int32)
@@ -465,7 +291,7 @@ class PagedKVCache:
 
     @property
     def num_free(self) -> int:
-        """Free REQUEST slots (admission gate, same name as KVCache)."""
+        """Free REQUEST slots (admission gate)."""
         return len(self._free_slots)
 
     @property
@@ -487,10 +313,6 @@ class PagedKVCache:
     @property
     def occupancy(self) -> float:
         return self.pages_in_use / max(self.num_pages - 1, 1)
-
-    @property
-    def active_tokens(self) -> int:
-        return int(self.lengths.sum())
 
     # ---- slot lifecycle ----
     def alloc(self) -> int:
@@ -721,9 +543,9 @@ class PagedKVCache:
 
     # ---- live-slot migration (serve/migrate.py rides on these) ----
     def export_slots(self, slot_ids) -> list:
-        """Snapshot occupied slots as CONTIGUOUS truncated K/V rows —
-        the same :class:`KVSlotSnapshot` wire form as the slot cache
-        (codec-compatible), assembled by gathering each slot's LIVE
+        """Snapshot occupied slots as CONTIGUOUS truncated K/V rows
+        (:class:`KVSlotSnapshot`, the wire form migrate.py's codecs
+        pack), assembled by gathering each slot's LIVE
         pages only: sharing means a page can back many slots, but a
         migration payload ships each slot's logical tokens (the adopter
         rebuilds page tables locally; re-dedup on import is the
@@ -807,7 +629,7 @@ class PagedKVCache:
                 allocated.append(slot)
                 n_pg = self.pages_for_tokens(s.length)
                 # pow2 page-count bucket keeps the import executable
-                # count bounded, like the slot cache's import
+                # count bounded
                 pad = pow2_ceil(n_pg, self.pages_per_slot)
                 table = [self._alloc_page(slot) for _ in range(n_pg)]
                 pages = np.zeros(pad, np.int32)  # surplus -> scratch 0

@@ -39,13 +39,11 @@ compare across processes), requeue count.  Decoding is greedy argmax
 today, so there is no sampler/RNG state to carry; a sampling engine
 extends the record here.
 
-Paged engines (serve/kv_cache.py:PagedKVCache) speak this wire
-unchanged: a paged export assembles each slot's LIVE pages into the
-same contiguous truncated-rows snapshot (page ids are process-local
-and meaningless on the wire — the adopter rebuilds page tables as it
-imports), so payload size scales with live tokens either way, every
-codec applies, and slot↔paged CROSS-ALLOCATOR drains work — the
-rolling-upgrade path from a slot-engine fleet to a paged one.  A paged
+The wire holds no page ids: an export (serve/kv_cache.py:PagedKVCache)
+assembles each slot's LIVE pages into one contiguous truncated-rows
+snapshot (page ids are process-local and meaningless on the wire — the
+adopter rebuilds page tables as it imports), so payload size scales with
+live tokens and every codec applies.  The
 adopter also RE-DEDUPS each imported slot back into its prefix index
 (scheduler ``adopt_inflight`` → engine ``reindex_prefix``: page-boundary
 hashes of the request's token stream registered against the imported

@@ -59,7 +59,7 @@ class EngineKilled(RuntimeError):
 
 
 class _GuardedEngine:
-    """Kill-switch proxy over a ServeEngine.
+    """Kill-switch proxy over a PagedServeEngine.
 
     ``kill()`` makes every subsequent engine VERB raise — the in-process
     analog of SIGKILLing a member's accelerator process: unannounced and
@@ -94,9 +94,21 @@ class _GuardedEngine:
         self._check()
         self.inner.release(slot)
 
-    def prefill(self, slot, prompt):
+    def admission_pages(self, prompt_len, max_tokens):
         self._check()
-        return self.inner.prefill(slot, prompt)
+        return self.inner.admission_pages(prompt_len, max_tokens)
+
+    def admission_ok(self, prompt, max_tokens):
+        self._check()
+        return self.inner.admission_ok(prompt, max_tokens)
+
+    def begin_prefill(self, slot, prompt, *, max_tokens=0):
+        self._check()
+        self.inner.begin_prefill(slot, prompt, max_tokens=max_tokens)
+
+    def prefill_step(self, slot):
+        self._check()
+        return self.inner.prefill_step(slot)
 
     def decode(self):
         self._check()
@@ -113,6 +125,10 @@ class _GuardedEngine:
     def resume_slots(self, slot_ids):
         self._check()
         self.inner.resume_slots(slot_ids)
+
+    def reindex_prefix(self, slot, tokens):
+        self._check()
+        self.inner.reindex_prefix(slot, tokens)
 
 
 class PoolMember:
@@ -149,7 +165,7 @@ class ServingPool:
     """Router + supervisor over N serving members.
 
     ``engine_factories``: ``{name: factory}`` (or a list; names become
-    ``m0..mN``) where each factory builds a fresh ``ServeEngine`` — the
+    ``m0..mN``) where each factory builds a fresh ``PagedServeEngine`` — the
     same factory revives a member after death.  The pool starts one van
     server for the whole process (``own_van=False`` + ``port`` attaches
     to an existing one) — members share it for migration transfers.
@@ -161,8 +177,8 @@ class ServingPool:
     """
 
     def __init__(self, engine_factories, *, port: int = 0,
-                 own_van: bool = True, token_budget: Optional[int] = None,
-                 max_requeues: int = 5, max_loop_errors: int = 2,
+                 own_van: bool = True, max_requeues: int = 5,
+                 max_loop_errors: int = 2,
                  failover_grace_s: float = 30.0,
                  health_poll_s: float = 0.05,
                  request_timeout_s: float = 60.0,
@@ -197,7 +213,6 @@ class ServingPool:
             self.port = port
         self.metrics = metrics if metrics is not None else ServeMetrics()
         self.request_timeout_s = float(request_timeout_s)
-        self._token_budget = token_budget
         self._max_requeues = int(max_requeues)
         self._max_loop_errors = int(max_loop_errors)
         self._failover_grace_s = float(failover_grace_s)
@@ -237,8 +252,7 @@ class ServingPool:
             return self._member_factory(self, name, factory)
         engine = _GuardedEngine(factory())
         sched = ContinuousBatchingScheduler(
-            engine, token_budget=self._token_budget,
-            max_requeues=self._max_requeues,
+            engine, max_requeues=self._max_requeues,
             shed=self._shed, shed_headroom=self._shed_headroom)
         srv = InferenceServer(
             sched, port=self.port, own_van=False, max_clients=0,
@@ -638,7 +652,7 @@ class ServingPool:
     @staticmethod
     def _close_engine(m: PoolMember) -> None:
         """Best-effort engine close where the engine kind has one (the
-        LLM ServeEngine does not; a CTR engine closes its serving
+        LLM engine does not; a CTR engine closes its serving
         caches, recording any still-open degrade span)."""
         close = getattr(m.scheduler.engine, "close", None)
         if close is None:

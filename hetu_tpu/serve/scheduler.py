@@ -1,14 +1,15 @@
-"""Continuous-batching scheduler over a ServeEngine.
+"""Continuous-batching scheduler over a PagedServeEngine.
 
 Static batch-at-once serving wastes every slot that finishes early;
 continuous batching admits new requests into freed slots at EVERY decode
 step (the Orca/vLLM iteration-level scheduling idea): each ``step()``
 first admits queued requests while (a) a cache slot is free and (b) the
-token budget holds the working set — prompt + one generated token must
-fit alongside the tokens already cached (backpressure, so a burst of
-long prompts queues instead of thrashing the cache) — then runs ONE
-decode step for every active slot and evicts sequences that hit EOS,
-their ``max_tokens``, the cache's ``max_len``, or their deadline.
+engine's page ledger holds the request's worst case alongside every
+outstanding reservation (backpressure, so a burst of long prompts queues
+instead of thrashing the cache), advances the admitted prompts' chunked
+prefills, then runs ONE decode step for every active slot and evicts
+sequences that hit EOS, their ``max_tokens``, the cache's ``max_len``,
+or their deadline.
 
 Thread-safe: the server's listener threads ``submit()``/``cancel()``
 concurrently with the engine loop calling ``step()``.
@@ -138,8 +139,7 @@ class Request:
 
 
 class ContinuousBatchingScheduler:
-    def __init__(self, engine, *, token_budget: Optional[int] = None,
-                 metrics=None, max_requeues: int = 3,
+    def __init__(self, engine, *, metrics=None, max_requeues: int = 3,
                  shed: bool = False, shed_headroom: float = 1.0,
                  prefill_chunks_per_step: int = 1,
                  slo_classes: Optional[dict] = None):
@@ -149,15 +149,7 @@ class ContinuousBatchingScheduler:
         # (re)admission keeps killing engines must eventually fail instead
         # of poisoning every restarted incarnation
         self.max_requeues = int(max_requeues)
-        cache = engine.cache
-        # default budget: the cache itself (backpressure only kicks in
-        # when admission would overrun physical capacity anyway).  For a
-        # PAGED engine the token budget is vestigial: admission gates on
-        # the engine's page ledger instead (admission_ok), which credits
-        # prefix-shared pages and nets out outstanding reservations.
-        self.token_budget = int(token_budget or
-                                cache.num_slots * cache.max_len)
-        # chunked-prefill interleave (paged engines): per step, at most
+        # chunked-prefill interleave: per step, at most
         # this many prefill chunks advance before the decode round, so a
         # 4k-context arrival adds ONE bounded chunk of latency per step
         # to in-flight decodes instead of a whole-prompt stall
@@ -635,7 +627,7 @@ class ContinuousBatchingScheduler:
                         if req in self._queue:
                             self._queue.remove(req)
                 raise
-            if snapshots and hasattr(self.engine, "reindex_prefix"):
+            if snapshots:
                 # re-dedup the imported pages into THIS engine's prefix
                 # index: the scheduler is the one party that knows each
                 # adopted slot's token stream (prompt + emitted tokens;
@@ -752,10 +744,9 @@ class ContinuousBatchingScheduler:
                                     {"step": self._steps}) as sp:
             self._steps += 1
             with trace.span("serve.admit"):
-                progressed, admit_exc = self._admit(completed)
+                admit_exc = self._admit(completed)
             with trace.span("serve.advance_prefills"):
-                pf_progressed, pf_exc = self._advance_prefills(completed)
-            progressed = progressed or pf_progressed
+                progressed, pf_exc = self._advance_prefills(completed)
             admit_exc = admit_exc or pf_exc
             toks = None
             while self._running:
@@ -804,13 +795,14 @@ class ContinuousBatchingScheduler:
 
     # ---- internals (called under the lock) ----
     def _admit(self, completed: list):
-        """Admit queued requests into free slots.  Returns ``(progressed,
-        admit_exc)``: whether any prefill succeeded, and the last
-        admission exception (step() re-raises it only on zero progress)."""
-        progressed = False
+        """Admit queued requests into free slots: each reserves its
+        pages and parks a prefill cursor (:meth:`_advance_prefills` runs
+        the chunks).  Returns the last admission exception (step()
+        re-raises it only on zero progress)."""
         admit_exc = None
+        cache = self.engine.cache
         now = time.monotonic()
-        while self._queue and self.engine.cache.num_free:
+        while self._queue and cache.num_free:
             # SLO pick: rotate the chosen request to the head, then the
             # rest of the loop (and its popleft/appendleft failure
             # handling) runs unchanged against index 0.  FIFO when
@@ -828,34 +820,28 @@ class ContinuousBatchingScheduler:
                 completed.append(req)
                 continue
             n = len(req.prompt)
-            if n == 0 or n + 1 > self.engine.cache.max_len \
-                    or n + 1 > self.token_budget:
-                # empty prompts, prompts too long for a slot, and prompts
-                # whose working set could NEVER fit the budget must fail
-                # the REQUEST — the alternatives are an exception in the
-                # engine loop thread or a queue head wedged forever
-                self._queue.popleft()
-                self._finish(req, "overflow")
-                completed.append(req)
-                continue
-            paged = hasattr(self.engine, "begin_prefill")
             # a requeued/preempted request's emitted tokens were FOLDED
             # into its prompt — its worst case is the remaining budget,
             # not max_tokens, or a fold near the page-pool ceiling
             # inflates the reservation past what the pool can EVER grant
             # and wedges the queue head forever
             remaining = max(int(req.max_tokens) - len(req.tokens), 1)
-            if paged:
-                # page-budget backpressure: the engine's ledger knows
-                # what the request's worst case costs AFTER prefix
-                # sharing and what outstanding reservations still claim
-                if not self.engine.admission_ok(req.prompt, remaining):
-                    break
-            elif self.engine.cache.active_tokens + n + 1 > \
-                    self.token_budget:
-                # token-budget backpressure: the working set after
-                # admission (fits eventually — running sequences will
-                # finish and free it)
+            if n == 0 or n + 1 > cache.max_len or \
+                    self.engine.admission_pages(n, remaining) \
+                    > cache.num_pages - 1:
+                # empty prompts, prompts too long for a slot, and requests
+                # whose worst case could NEVER fit the page pool must fail
+                # the REQUEST — the alternatives are an exception in the
+                # engine loop thread or a queue head wedged forever
+                self._queue.popleft()
+                self._finish(req, "overflow")
+                completed.append(req)
+                continue
+            # page-budget backpressure: the engine's ledger knows what
+            # the request's worst case costs AFTER prefix sharing and
+            # what outstanding reservations still claim (fits eventually
+            # — running sequences will finish and free it)
+            if not self.engine.admission_ok(req.prompt, remaining):
                 break
             self._queue.popleft()
             self._charge_wfq_locked(req)
@@ -876,31 +862,16 @@ class ContinuousBatchingScheduler:
                 # first admission only: the queue-wait number a requeue
                 # must not rewrite (same rule as first_token_at)
                 req.admitted_at = time.monotonic()
-            if paged:
-                # chunked-prefill interleave: admission only ADOPTS the
-                # shared prefix, reserves pages, and parks a cursor —
-                # the chunks themselves advance one per step
-                # (_advance_prefills), interleaved with decode rounds
-                try:
-                    self.engine.begin_prefill(slot, req.prompt,
-                                              max_tokens=remaining)
-                except Exception as e:
-                    admit_exc = e
-                    if not self._requeue_locked(req, self.max_requeues,
-                                                tail=True):
-                        completed.append(req)
-                    try:
-                        self.engine.release(slot)
-                    except Exception:
-                        pass
-                    continue
-                self._prefilling[slot] = req
-                continue
+            # chunked-prefill interleave: admission only reserves pages
+            # and parks a cursor — the chunks themselves (the prefix match
+            # rides on the first) advance one per step
+            # (_advance_prefills), interleaved with decode rounds
             try:
-                first = self.engine.prefill(slot, req.prompt)
+                self.engine.begin_prefill(slot, req.prompt,
+                                          max_tokens=remaining)
             except Exception as e:
-                # a prefill blow-up must not orphan the request: at this
-                # point it is in NEITHER the queue NOR _running, so the
+                # a blow-up here must not orphan the request: at this
+                # point it is in NEITHER the queue NOR _prefilling, so the
                 # failover requeue could never find it — the client would
                 # hang out its full timeout undiagnosed.  Requeue it at
                 # the TAIL (other requests get served first; past its
@@ -918,33 +889,18 @@ class ContinuousBatchingScheduler:
                 except Exception:
                     pass  # engine already broken; the loop records that
                 continue
-            progressed = True
-            req.tokens.append(first)
-            now_t = time.monotonic()
-            if req.first_token_at is None:
-                # only the FIRST admission observes TTFT: a failover
-                # re-prefill must not double-count the histogram or
-                # overwrite the client-visible ttft_s
-                req.first_token_at = now_t
-                self.metrics.observe_ttft(req.ttft_s,
-                                          tenant=req.tenant)
-            self._running[slot] = req
-            if self._should_evict(req, now_t):
-                del self._running[slot]
-                self.engine.release(slot)
-                self._finish(req, req.status or "ok")
-                completed.append(req)
-        return progressed, admit_exc
+            self._prefilling[slot] = req
+        return admit_exc
 
     def _advance_prefills(self, completed: list):
-        """Advance chunked prefills (paged engines), at most
+        """Advance chunked prefills, at most
         ``prefill_chunks_per_step`` chunks per step — the interleave
         policy that keeps a long-prompt arrival from spiking in-flight
         decode latency.  A prefill whose final chunk completes emits its
         first token and the request joins ``_running`` for the decode
-        round below.  Returns ``(progressed, exc)`` like :meth:`_admit`
-        (chunk failures are charged to the request; step() re-raises
-        only on zero overall progress)."""
+        round below.  Returns ``(progressed, exc)``: whether any chunk
+        ran, and the last chunk exception (chunk failures are charged to
+        the request; step() re-raises only on zero overall progress)."""
         if not self._prefilling:
             return False, None
         progressed = False
@@ -971,7 +927,7 @@ class ContinuousBatchingScheduler:
             try:
                 tok = self.engine.prefill_step(slot)
             except Exception as e:
-                # same containment as a monolithic prefill blow-up: the
+                # same containment as a begin_prefill blow-up: the
                 # request goes back to the TAIL (or fails past its
                 # requeue cap), the slot frees, everyone else continues
                 exc = e
@@ -989,6 +945,9 @@ class ContinuousBatchingScheduler:
             req.tokens.append(tok)
             now_t = time.monotonic()
             if req.first_token_at is None:
+                # only the FIRST admission observes TTFT: a failover
+                # re-prefill must not double-count the histogram or
+                # overwrite the client-visible ttft_s
                 req.first_token_at = now_t
                 self.metrics.observe_ttft(req.ttft_s,
                                           tenant=req.tenant)

@@ -236,7 +236,7 @@ class InferenceServer:
         # request in flight per channel pair, so remembering the LAST
         # request id per listener is sufficient — a timed-out client
         # that re-puts the same id gets the original request's result,
-        # never a second generation (or a second token-budget charge)
+        # never a second generation (or a second page reservation)
         dedup: dict = {}
         try:
             while not self._stop.is_set():

@@ -1,9 +1,17 @@
 """What the paged engine's two programs hold and where they write: the
 checks ``test_paged_kv.py`` (GPT, Llama) and ``test_longcat_flash.py`` share
-(ISSUE 29).  A helper module, no tests of its own."""
+(ISSUE 29), and the two token oracles every serving test holds the engine
+to, both independent of any engine, and the hold that lets a pool test
+catch a member mid-decode.  A helper module, no tests of its own."""
+
+import threading
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+
+from hetu_tpu.serve import KVCacheSpec
 
 # the primitives a pool may leave whole: the in-place row scatter, and the
 # loop and call boundaries the carried pool passes through
@@ -65,6 +73,40 @@ def oversized(engine, name: str, *, batch: int, chunk: int):
                    and not (s in pools and p in CARRIERS)]
 
 
+def ref_greedy(model, variables, prompt, n: int) -> list:
+    """``n`` greedy tokens by a full re-forward of the training ``apply``
+    each step: no cache at all."""
+    ids = list(prompt)
+    out = []
+    for _ in range(n):
+        logits, _ = model.apply(variables, jnp.asarray([ids], jnp.int32))
+        tok = int(jnp.argmax(logits[0, -1]))
+        out.append(tok)
+        ids.append(tok)
+    return out
+
+
+def dense_greedy(model, variables, prompt, n: int, max_len: int) -> list:
+    """``n`` greedy tokens through the model's two cache entry points over
+    DENSE caches ``[L, 1, max_len, *row]``, the whole prompt one chunk: a
+    cached run with no pages, tables or write maps in it."""
+    spec = KVCacheSpec.from_model(model)
+    k_row, v_row = spec.row_shapes()
+    k = jnp.zeros((spec.num_layers, 1, max_len) + k_row, spec.dtype)
+    v = jnp.zeros((spec.num_layers, 1, max_len) + v_row, spec.dtype)
+    # a model may return a fourth value, its per-call counts
+    logits, k, v, *_ = jax.jit(model.prefill_chunk_with_cache)(
+        variables, jnp.asarray([prompt], jnp.int32), k, v, jnp.int32(0))
+    toks = [int(jnp.argmax(logits[0]))]
+    step = jax.jit(model.decode_with_cache)
+    for i in range(n - 1):
+        logits, k, v, *_ = step(
+            variables, jnp.asarray(toks[-1:], jnp.int32), k, v,
+            jnp.asarray([len(prompt) + i], jnp.int32))
+        toks.append(int(jnp.argmax(logits[0])))
+    return toks
+
+
 def engine_greedy(engine, prompt, n: int) -> list:
     """``n`` greedy tokens of one request through an engine's own steps."""
     slot = engine.alloc_slot()
@@ -105,3 +147,37 @@ def pad_writes(engine, prompts, sentinel: float = 7.0) -> dict:
             missed |= live - written
     return {"stray": sorted(stray), "missed": sorted(missed - {(0, 0)}),
             "scratch_written": (0, 0) not in missed}
+
+
+def submit_and_hold_mid_decode(member, requests, steps: int = 3) -> None:
+    """Submit ``requests`` to ``member`` and let its engine loop take exactly
+    ``steps`` scheduler steps, then hold it BETWEEN steps (outside the
+    scheduler's lock) until its server stops.  Three steps admit two
+    requests, prefill one prompt chunk each and decode a round or two: every
+    request is then mid-decode, whatever a step costs on this machine, and
+    stays so while the test drains the member.  (Catching a 12-token request
+    mid-decode by polling raced the loop for the scheduler's lock.)"""
+    permits = threading.Semaphore(0)
+    stop = member.server._stop
+    real_step = member.scheduler.step
+    taken = []
+
+    def step():
+        while not permits.acquire(timeout=0.02):
+            if stop.is_set():  # drained and closing: nothing left to hold
+                break
+        try:
+            return real_step()
+        finally:
+            taken.append(1)
+
+    member.scheduler.step = step
+    for r in requests:
+        member.scheduler.submit(r)
+    for _ in range(steps):
+        permits.release()
+    deadline = time.monotonic() + 60
+    while len(taken) < steps:
+        assert time.monotonic() < deadline, "the engine loop never stepped"
+        time.sleep(0.005)
+    assert all(r.tokens and not r.done.is_set() for r in requests)

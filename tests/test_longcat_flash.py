@@ -31,7 +31,7 @@ from hetu_tpu.serve import (  # noqa: E402
 )
 from hetu_tpu.serve import migrate  # noqa: E402
 from paged_programs import (  # noqa: E402
-    engine_greedy, oversized, pad_writes,
+    dense_greedy, engine_greedy, oversized, pad_writes,
 )
 
 F32_TOL = 2e-4      # both sides float32: the order of operations only
@@ -335,26 +335,6 @@ def test_no_program_holds_a_pool_or_a_view_of_every_layer(program):
                               page_size=8, prefill_chunk=16)
     floor, found = oversized(engine, program, batch=2, chunk=16)
     assert floor >= 4 * 512 * 32 and found == []
-
-
-def dense_greedy(model, v, prompt, n, max_len) -> list:
-    """Greedy tokens through the same two entry points over DENSE caches
-    ``[2L, 1, T, 1, w]``, the whole prompt one chunk: what a slot cache
-    would hold (this model has no slot-cache engine to ask)."""
-    spec = model.kv_cache_spec()
-    k_row, v_row = spec.row_shapes()
-    k = jnp.zeros((spec.num_layers, 1, max_len) + k_row, spec.dtype)
-    vv = jnp.zeros((spec.num_layers, 1, max_len) + v_row, spec.dtype)
-    logits, k, vv, _ = jax.jit(model.prefill_chunk_with_cache)(
-        v, jnp.asarray([prompt], jnp.int32), k, vv, jnp.int32(0))
-    toks = [int(jnp.argmax(logits[0]))]
-    step = jax.jit(model.decode_with_cache)
-    for i in range(n - 1):
-        logits, k, vv, _ = step(
-            v, jnp.asarray(toks[-1:], jnp.int32), k, vv,
-            jnp.asarray([len(prompt) + i], jnp.int32))
-        toks.append(int(jnp.argmax(logits[0])))
-    return toks
 
 
 @pytest.mark.parametrize("case", ["boundary", "cow", "tp2"])

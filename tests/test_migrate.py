@@ -6,7 +6,7 @@ source-side rollback on a failed transfer.
 The contract under test (ISSUE 5 acceptance): a request migrated
 mid-decode produces argmax tokens identical to the same request never
 migrated, and the receiving engine performs zero prefill steps for
-migrated slots (the ``serve.prefill`` metric stays flat).
+migrated slots (the ``prefill_tokens`` metric stays flat).
 """
 
 import threading
@@ -20,9 +20,10 @@ import pytest
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
-    ContinuousBatchingScheduler, MigrationError, Request, ServeEngine,
+    ContinuousBatchingScheduler, MigrationError, PagedServeEngine, Request,
 )
 from hetu_tpu.serve import migrate as mg
+from paged_programs import ref_greedy as _ref_greedy
 
 pytestmark = pytest.mark.migrate
 
@@ -43,22 +44,12 @@ def llama():
     return m, m.init(jax.random.PRNGKey(1))
 
 
-def _ref_greedy(model, variables, prompt, n):
-    ids = list(prompt)
-    out = []
-    for _ in range(n):
-        logits, _ = model.apply(variables, jnp.asarray([ids], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        ids.append(tok)
-    return out
-
-
 def _engine(model, variables, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_len", 48)
     kw.setdefault("min_bucket", 8)
-    return ServeEngine(model, variables, **kw)
+    kw.setdefault("page_size", 8)
+    return PagedServeEngine(model, variables, **kw)
 
 
 def _migrate_mid_decode(model, variables, prompt, n_total, n_before,
@@ -93,7 +84,7 @@ def test_gpt_migrated_decode_parity_zero_prefill(gpt, n_before):
     prompt = [3, 14, 15, 9, 2, 6]
     toks, dst = _migrate_mid_decode(model, variables, prompt, 10, n_before)
     assert toks == _ref_greedy(model, variables, prompt, 10)
-    # the receiving engine NEVER prefilled: serve.prefill metrics flat
+    # the receiving engine NEVER prefilled: its prefill metrics stay flat
     assert dst.metrics.count("prefill_tokens") == 0
     assert dst.metrics.count("prefill_compiles") == 0
 
@@ -554,6 +545,55 @@ def test_export_rollback_releases_done_in_transit_slot(gpt):
         sched.export_inflight_with_slots()
     assert eng.cache.num_free == 1  # doomed's slot freed, not leaked
     assert list(sched._running.values()) == [live]  # live re-attached
+
+
+@pytest.mark.parametrize("verb,args", [
+    ("admission_pages", (3, 4)), ("admission_ok", ([1, 2, 3], 4)),
+    ("begin_prefill", (0, [1, 2, 3])), ("prefill_step", (0,)),
+    ("reindex_prefix", (0, [1, 2, 3]))])
+def test_killed_guard_raises_from_the_scheduling_verbs(gpt, verb, args):
+    """The guard forwards every verb the scheduler calls, so a pool member
+    is scheduled as a bare engine is (page-budget admission, chunked
+    prefill, re-dedup after an adoption) — and each of them dies with the
+    engine."""
+    from hetu_tpu.serve.pool import EngineKilled, _GuardedEngine
+    model, variables = gpt
+    eng = _GuardedEngine(_engine(model, variables))
+    assert eng.alloc_slot() == 0
+    if verb == "prefill_step":
+        eng.begin_prefill(0, [1, 2, 3])
+    getattr(eng, verb)(*args)  # alive: reaches the engine
+    eng.kill()
+    with pytest.raises(EngineKilled):
+        getattr(eng, verb)(*args)
+
+
+def test_guarded_members_interleave_chunks_and_reindex_adoptions(gpt):
+    """Two pool-style members (the scheduler over the pool's guard): a long
+    prompt prefills a chunk a step, not whole inside the admission, and a
+    slot adopted from the peer is re-deduped into the adopter's prefix
+    index — what a bare engine gets, through the guard."""
+    from hetu_tpu.serve.pool import _GuardedEngine
+    model, variables = gpt
+    s1, s2 = (ContinuousBatchingScheduler(_GuardedEngine(
+        _engine(model, variables, prefill_chunk=8))) for _ in range(2))
+    prompt = list(range(1, 21))  # three chunks of 8
+    req = Request(prompt=prompt, max_tokens=8)
+    s1.submit(req)
+    s1.step()
+    assert s1._prefilling and not req.tokens  # one chunk in, two to go
+    for _ in range(4):
+        s1.step()
+    assert 0 < len(req.tokens) < 8  # mid-decode
+    assert len(mg.migrate_inflight(s1, s2)) == 1
+    assert s2.engine.metrics.count("prefix_reindexed") >= 2  # two full pages
+    s2.run([])
+    assert req.status == "ok"
+    assert req.tokens == _ref_greedy(model, variables, prompt, 8)
+    assert s2.engine.metrics.count("prefill_tokens") == 0
+
+
+def test_dead_wire_mid_migration_rolls_back_to_the_source(gpt):
     """A dead wire mid-migration re-adopts requests AND slots at the
     source — migration either completes or the source keeps serving."""
     model, variables = gpt

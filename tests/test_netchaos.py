@@ -37,6 +37,8 @@ pytestmark = pytest.mark.netchaos
 # ---------------------------------------------------------------------------
 
 class _Cache:
+    num_pages = 1 << 20  # never what holds an admission back
+
     def __init__(self, num_slots, max_len=64):
         self.num_slots, self.max_len = num_slots, max_len
         self.lengths = np.zeros(num_slots, np.int32)
@@ -45,10 +47,6 @@ class _Cache:
     @property
     def num_free(self):
         return len(self.free)
-
-    @property
-    def active_tokens(self):
-        return int(self.lengths.sum())
 
     @property
     def occupancy(self):
@@ -63,17 +61,28 @@ class SlowEngine:
         self.cache = _Cache(num_slots)
         self.step_s = step_s
         self.metrics = ServeMetrics()
+        self._prompts = {}  # slot -> prompt length, begun and not prefilled
 
     def alloc_slot(self):
         return self.cache.free.pop()
 
     def release(self, slot):
         self.cache.lengths[slot] = 0
+        self._prompts.pop(slot, None)
         if slot not in self.cache.free:
             self.cache.free.append(slot)
 
-    def prefill(self, slot, prompt):
-        self.cache.lengths[slot] = len(prompt) + 1
+    def admission_pages(self, prompt_len, max_tokens):
+        return 1
+
+    def admission_ok(self, prompt, max_tokens):
+        return True
+
+    def begin_prefill(self, slot, prompt, *, max_tokens=0):
+        self._prompts[slot] = len(prompt)
+
+    def prefill_step(self, slot):
+        self.cache.lengths[slot] = self._prompts.pop(slot) + 1
         time.sleep(self.step_s)
         return 1
 

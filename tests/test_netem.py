@@ -498,16 +498,17 @@ def test_pool_drain_codec_auto_end_to_end():
     import jax
 
     from hetu_tpu.models.gpt import GPTConfig, GPTModel
-    from hetu_tpu.serve import ServeEngine, ServingPool
+    from hetu_tpu.serve import PagedServeEngine, ServingPool
     from hetu_tpu.serve.scheduler import Request
+    from paged_programs import submit_and_hold_mid_decode
     model = GPTModel(GPTConfig(
         vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
         ffn_size=128, max_position=64, dropout_rate=0.0))
     variables = model.init(jax.random.PRNGKey(0))
 
     def factory():
-        return ServeEngine(model, variables, num_slots=4, max_len=48,
-                           min_bucket=8)
+        return PagedServeEngine(model, variables, num_slots=4, max_len=48,
+                                page_size=8, min_bucket=8)
 
     pool = ServingPool({"a": factory, "b": factory},
                        migrate_codec="auto", start_poll=False)
@@ -517,12 +518,7 @@ def test_pool_drain_codec_auto_end_to_end():
                         timeout_s=60.0),
                 Request(prompt=[2, 7, 1, 8], max_tokens=12,
                         timeout_s=60.0)]
-        for r in reqs:
-            pool.members["a"].scheduler.submit(r)
-        deadline = time.monotonic() + 30
-        while not all(r.tokens for r in reqs):
-            assert time.monotonic() < deadline
-            time.sleep(0.01)
+        submit_and_hold_mid_decode(pool.members["a"], reqs)
         # an emulated slow link: auto must pick the compressed codec,
         # and the drain still completes token-exact on the peer
         em.set_link(ne.LinkPolicy(rate_mbps=0.001), direction="ingress")
@@ -542,14 +538,14 @@ def test_resolve_codec_prefers_netem_visible_rate():
     import jax
 
     from hetu_tpu.models.gpt import GPTConfig, GPTModel
-    from hetu_tpu.serve.engine import ServeEngine
+    from hetu_tpu.serve.engine import PagedServeEngine
     from hetu_tpu.serve.migrate import resolve_codec
     cfg = GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
                     num_heads=2, ffn_size=64, max_position=32,
                     dropout_rate=0.0)
     model = GPTModel(cfg)
-    engine = ServeEngine(model, model.init(jax.random.PRNGKey(0)),
-                         num_slots=2, max_len=32)
+    engine = PagedServeEngine(model, model.init(jax.random.PRNGKey(0)),
+                              num_slots=2, max_len=32)
     slot = engine.alloc_slot()
     engine.prefill(slot, [1, 2, 3, 4, 5, 6, 7, 8])
     em = ne.NetEm(seed=0).install()

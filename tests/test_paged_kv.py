@@ -1,14 +1,13 @@
 """Paged KV cache + prefix sharing + chunked prefill (ISSUE 13).
 
-The contract under test: greedy decode through the PAGED engine is
-TOKEN-FOR-TOKEN identical to the slot engine (which is itself
-token-exact against the training forward, tests/test_serve.py) — for
-GPT, for GQA-Llama, under a tp mesh, across chunked prefills of any
-chunk split, and through live migration (paged→paged and the
-cross-allocator slot→paged drain) — while prefix sharing dedups
-identical prefixes to one physical copy with copy-on-write isolation
-and exact refcount release, and the whole engine compiles a BOUNDED
-number of executables.
+The contract under test: greedy decode through the paged engine is
+TOKEN-FOR-TOKEN identical to the training forward (prompt by prompt in
+tests/test_serve.py) and to the models' cache entry points over dense
+caches — for GPT, for GQA-Llama, under a tp mesh, across chunked
+prefills of any chunk split, and through live migration — while prefix
+sharing dedups identical prefixes to one physical copy with
+copy-on-write isolation and exact refcount release, and the whole
+engine compiles a BOUNDED number of executables.
 """
 
 import jax
@@ -19,10 +18,10 @@ import hetu_tpu as ht
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
-    ContinuousBatchingScheduler, PagedServeEngine, Request, ServeEngine,
+    ContinuousBatchingScheduler, PagedServeEngine, Request,
 )
 from paged_programs import engine_greedy as _engine_greedy
-from paged_programs import oversized, pad_writes
+from paged_programs import dense_greedy, oversized, pad_writes, ref_greedy
 
 pytestmark = pytest.mark.paged
 
@@ -52,32 +51,7 @@ def llama():
 
 
 
-
-# ---- paged-vs-slot token parity (greedy decode) ----
-
-@pytest.mark.parametrize("prompt_len", [1, 5, 9, 17, 33])
-def test_gpt_paged_vs_slot_parity(gpt, prompt_len):
-    model, variables = gpt
-    g = np.random.default_rng(prompt_len)
-    prompt = [int(t) for t in g.integers(0, 97, prompt_len)]
-    slot = ServeEngine(model, variables, num_slots=2, max_len=64)
-    paged = PagedServeEngine(model, variables, num_slots=2, max_len=64,
-                             page_size=8)
-    assert _engine_greedy(slot, prompt, 12) == \
-        _engine_greedy(paged, prompt, 12)
-
-
-@pytest.mark.parametrize("prompt_len", [1, 7, 19])
-def test_llama_gqa_paged_vs_slot_parity(llama, prompt_len):
-    model, variables = llama
-    g = np.random.default_rng(100 + prompt_len)
-    prompt = [int(t) for t in g.integers(0, 97, prompt_len)]
-    slot = ServeEngine(model, variables, num_slots=2, max_len=64)
-    paged = PagedServeEngine(model, variables, num_slots=2, max_len=64,
-                             page_size=8)
-    assert _engine_greedy(slot, prompt, 10) == \
-        _engine_greedy(paged, prompt, 10)
-
+# ---- chunked prefill (prompt-by-prompt parity: tests/test_serve.py) ----
 
 def test_parity_independent_of_chunk_split(gpt):
     """The same prompt prefilled in one chunk vs many page-aligned
@@ -93,38 +67,23 @@ def test_parity_independent_of_chunk_split(gpt):
     assert _engine_greedy(one, prompt, 10) == _engine_greedy(many, prompt, 10)
 
 
-def test_tp_sharded_paged_matches_slot(llama):
-    model, variables = llama
-    prompt = [3, 14, 15, 9, 2, 6]
-    plain = ServeEngine(model, variables, num_slots=2, max_len=32,
-                        min_bucket=8)
-    mesh = ht.make_mesh(tp=2)  # nkv=2 → kv-head-sharded page pool
-    paged = PagedServeEngine(model, variables, num_slots=2, max_len=32,
-                             page_size=8, mesh=mesh)
-    assert _engine_greedy(plain, prompt, 8) == _engine_greedy(paged, prompt, 8)
-
-
 # ---- prefix sharing ----
 
 def test_shared_prefix_divergent_suffixes_token_exact(llama):
     """System-prompt traffic: one shared prefix, divergent suffixes.
     The paged engine dedups the prefix (hits counted) and every request
-    still decodes token-for-token like the unshared slot engine."""
+    still decodes token-for-token like the unshared full forward."""
     model, variables = llama
     g = np.random.default_rng(3)
     prefix = [int(t) for t in g.integers(0, 97, 17)]
     suffixes = [[int(t) for t in g.integers(0, 97, k)] for k in (5, 9, 3)]
 
-    def run(engine):
-        sch = ContinuousBatchingScheduler(engine)
-        reqs = [Request(prompt=prefix + s, max_tokens=8) for s in suffixes]
-        sch.run(reqs)
-        return [r.tokens for r in reqs]
-
-    want = run(ServeEngine(model, variables, num_slots=4, max_len=64))
     paged = PagedServeEngine(model, variables, num_slots=4, max_len=64,
                              page_size=8)
-    assert run(paged) == want
+    reqs = [Request(prompt=prefix + s, max_tokens=8) for s in suffixes]
+    ContinuousBatchingScheduler(paged).run(reqs)
+    assert [r.tokens for r in reqs] == _oracle(
+        model, variables, [prefix + s for s in suffixes], 8)
     snap = paged.metrics.snapshot()
     # the 2nd and 3rd requests share the prefix's full pages (17 tokens
     # → two 8-token pages each)
@@ -141,8 +100,7 @@ def test_identical_prompts_full_dedup_and_cow(gpt):
     model, variables = gpt
     g = np.random.default_rng(5)
     prompt = [int(t) for t in g.integers(0, 97, 21)]
-    want = _engine_greedy(ServeEngine(model, variables, num_slots=1,
-                                      max_len=64), prompt, 8)
+    want = ref_greedy(model, variables, prompt, 8)
     paged = PagedServeEngine(model, variables, num_slots=2, max_len=64,
                              page_size=8)
     sch = ContinuousBatchingScheduler(paged)
@@ -288,17 +246,14 @@ def test_chunked_prefill_interleaves_with_decode(gpt):
 # ---- migration: live pages only, codec-compatible ----
 
 def _oracle(model, variables, prompts, n):
-    out = []
-    for p in prompts:
-        e = ServeEngine(model, variables, num_slots=1, max_len=64)
-        out.append(_engine_greedy(e, p, n))
-    return out
+    return [ref_greedy(model, variables, p, n) for p in prompts]
 
 
 @pytest.mark.migrate
-def test_paged_to_paged_migration_token_parity(gpt):
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_paged_to_paged_migration_token_parity(kind, gpt, llama):
     from hetu_tpu.serve import migrate as mg
-    model, variables = gpt
+    model, variables = gpt if kind == "gpt" else llama
     g = np.random.default_rng(7)
     prompts = [[int(t) for t in g.integers(0, 97, k)] for k in (11, 23, 6)]
     want = _oracle(model, variables, prompts, 10)
@@ -319,33 +274,6 @@ def test_paged_to_paged_migration_token_parity(gpt):
     assert [r.tokens for r in reqs] == want
     # zero re-prefill on the adopter: adopted mid-decode slots continue
     assert dst.engine.metrics.count("slots_adopted") >= 1
-
-
-@pytest.mark.migrate
-def test_slot_to_paged_cross_allocator_migration(gpt):
-    """The paged cache speaks the same snapshot wire form as the slot
-    cache: a slot engine's live export adopts into a paged engine (the
-    rolling-upgrade drain) with token parity preserved."""
-    from hetu_tpu.serve import migrate as mg
-    model, variables = gpt
-    g = np.random.default_rng(8)
-    prompts = [[int(t) for t in g.integers(0, 97, k)] for k in (9, 17)]
-    want = _oracle(model, variables, prompts, 10)
-    src = ContinuousBatchingScheduler(ServeEngine(
-        model, variables, num_slots=2, max_len=64))
-    dst = ContinuousBatchingScheduler(PagedServeEngine(
-        model, variables, num_slots=4, max_len=64, page_size=8))
-    reqs = [Request(prompt=list(p), max_tokens=10) for p in prompts]
-    for r in reqs:
-        src.submit(r)
-    for _ in range(4):
-        src.step()
-    mg.migrate_inflight(src, dst)
-    for _ in range(80):
-        if not dst.has_work():
-            break
-        dst.step()
-    assert [r.tokens for r in reqs] == want
 
 
 @pytest.mark.migrate
@@ -437,8 +365,7 @@ def test_full_dedup_near_max_len_no_clamp_corruption(gpt):
     # max_len 64, page 8: prompt 58 → full-hit resubmit runs one chunk
     # at start=57 padded to bucket 16 → 73 > 64 without the extension
     prompt = [int(t) for t in g.integers(0, 97, 58)]
-    want = _engine_greedy(ServeEngine(model, variables, num_slots=1,
-                                      max_len=64), prompt, 4)
+    want = dense_greedy(model, variables, prompt, 4, 64)
     paged = PagedServeEngine(model, variables, num_slots=2, max_len=64,
                              page_size=8)
     first = _engine_greedy(paged, prompt, 4)
@@ -510,8 +437,7 @@ def test_llama_full_dedup_near_max_len(llama):
     model, variables = llama
     g = np.random.default_rng(37)
     prompt = [int(t) for t in g.integers(0, 97, 58)]
-    want = _engine_greedy(ServeEngine(model, variables, num_slots=1,
-                                      max_len=64), prompt, 4)
+    want = dense_greedy(model, variables, prompt, 4, 64)
     paged = PagedServeEngine(model, variables, num_slots=2, max_len=64,
                              page_size=8)
     assert _engine_greedy(paged, prompt, 4) == want
@@ -547,29 +473,22 @@ def test_no_program_holds_a_pool_or_a_view_of_every_layer(kind, program):
     assert floor >= 4 * 256 * 32 and found == []
 
 
-def _first_token(model, variables, prompt):
-    logits, _, _ = model.prefill_with_cache(
-        variables, np.asarray([prompt], np.int32),
-        last_index=len(prompt) - 1)
-    return int(np.argmax(np.asarray(logits[0])))
-
-
 @pytest.mark.parametrize("case", ["boundary", "cow", "tp2"])
 @pytest.mark.parametrize("kind", ["gpt", "llama"])
-def test_paged_tokens_equal_the_slot_engines(kind, case, gpt, llama):
-    """Float32, token for token, against the slot-cache engine and
-    ``prefill_with_cache``: a prompt whose padded final chunk runs past
-    the slot's pages (the boundary program), two requests sharing a prefix
-    with a copy-on-write page, and a ``tp=2`` mesh."""
+def test_paged_tokens_equal_the_dense_caches(kind, case, gpt, llama):
+    """Float32, token for token, against the same two entry points over
+    dense caches (and, for the first token, the full forward): a prompt
+    whose padded final chunk runs past the slot's pages (the boundary
+    program), two requests sharing a prefix with a copy-on-write page, and
+    a ``tp=2`` mesh."""
     model, variables = gpt if kind == "gpt" else llama
     g = np.random.default_rng(29)
     # max_len 60 = 15 pages of 4: the chunk [48, 57) pads to 16 -> 64 > 60
     prompt = [int(t) for t in g.integers(0, 97, 57 if case == "boundary"
                                          else 21)]
     n = 3 if case == "boundary" else 8
-    want = _engine_greedy(ServeEngine(model, variables, num_slots=2,
-                                      max_len=60), prompt, n)
-    assert want[0] == _first_token(model, variables, prompt)
+    want = dense_greedy(model, variables, prompt, n, 60)
+    assert want[:1] == ref_greedy(model, variables, prompt, 1)
     paged = PagedServeEngine(
         model, variables, num_slots=2, max_len=60, page_size=4,
         prefill_chunk=16, mesh=ht.make_mesh(tp=2) if case == "tp2" else None)

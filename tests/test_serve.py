@@ -6,11 +6,11 @@ serving engine is TOKEN-FOR-TOKEN identical to re-running the full
 sequence through the training forward and taking argmax — for GPT, for
 Llama (incl. GQA), and under a tp mesh — while a serving run over many
 requests of varied prompt lengths compiles a BOUNDED number of
-executables (power-of-two prompt buckets + one decode step).
+executables (power-of-two chunk buckets, and power-of-two batch by page
+buckets of the decode step).
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,8 +18,10 @@ import hetu_tpu as ht
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
-    ContinuousBatchingScheduler, Request, ServeEngine, ServeMetrics,
+    ContinuousBatchingScheduler, PagedServeEngine, Request, ServeMetrics,
 )
+from paged_programs import engine_greedy as _engine_greedy
+from paged_programs import ref_greedy as _ref_greedy
 
 
 def _gpt():
@@ -46,50 +48,44 @@ def llama():
     return _llama_gqa()
 
 
-def _ref_greedy(model, variables, prompt, n):
-    """Greedy decode by full re-forward each step (the parity oracle)."""
-    ids = list(prompt)
-    out = []
-    for _ in range(n):
-        logits, _ = model.apply(variables, jnp.asarray([ids], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        ids.append(tok)
-    return out
-
-
-def _engine_greedy(engine, prompt, n):
-    slot = engine.alloc_slot()
-    toks = [engine.prefill(slot, prompt)]
-    for _ in range(n - 1):
-        toks.append(engine.decode()[slot])
-    engine.release(slot)
-    return toks
+def _engine(model, variables, **kw):
+    """Pages of 8: a 16-position engine then has two pages a slot."""
+    kw.setdefault("page_size", 8)
+    kw.setdefault("min_bucket", 8)
+    return PagedServeEngine(model, variables, **kw)
 
 
 # ---- decode parity ----
 
-@pytest.mark.parametrize("prompt_len", [1, 5, 9, 17])
-def test_gpt_decode_parity(gpt, prompt_len):
+# (max_len, tokens) of the two geometries these cases came from: this file's
+# and test_paged_kv.py's, which held the same engine to a second engine
+_SHORT, _LONG = (40, 10), (64, 12)
+
+
+@pytest.mark.parametrize("geometry,prompt_len", [
+    (_SHORT, 1), (_SHORT, 5), (_SHORT, 9), (_SHORT, 17),
+    (_LONG, 1), (_LONG, 5), (_LONG, 9), (_LONG, 17), (_LONG, 33)])
+def test_gpt_decode_parity(gpt, geometry, prompt_len):
     model, variables = gpt
+    max_len, n = geometry
     g = np.random.default_rng(prompt_len)
     prompt = [int(t) for t in g.integers(0, 97, prompt_len)]
-    engine = ServeEngine(model, variables, num_slots=2, max_len=40,
-                         min_bucket=8)
-    assert _engine_greedy(engine, prompt, 10) == \
-        _ref_greedy(model, variables, prompt, 10)
+    engine = _engine(model, variables, num_slots=2, max_len=max_len)
+    assert _engine_greedy(engine, prompt, n) == \
+        _ref_greedy(model, variables, prompt, n)
 
 
-@pytest.mark.parametrize("prompt_len", [3, 11])
-def test_llama_gqa_decode_parity(llama, prompt_len):
+@pytest.mark.parametrize("geometry,prompt_len", [
+    (_SHORT, 3), (_SHORT, 11), (_LONG, 1), (_LONG, 7), (_LONG, 19)])
+def test_llama_gqa_decode_parity(llama, geometry, prompt_len):
     model, variables = llama
     assert model.c.num_kv_heads < model.c.num_heads  # really GQA
-    g = np.random.default_rng(prompt_len)
+    max_len, n = geometry
+    g = np.random.default_rng(100 + prompt_len)
     prompt = [int(t) for t in g.integers(0, 97, prompt_len)]
-    engine = ServeEngine(model, variables, num_slots=2, max_len=40,
-                         min_bucket=8)
-    assert _engine_greedy(engine, prompt, 10) == \
-        _ref_greedy(model, variables, prompt, 10)
+    engine = _engine(model, variables, num_slots=2, max_len=max_len)
+    assert _engine_greedy(engine, prompt, n) == \
+        _ref_greedy(model, variables, prompt, n)
 
 
 def test_llama_mha_decode_parity():
@@ -98,20 +94,21 @@ def test_llama_mha_decode_parity():
         vocab_size=53, hidden_size=32, num_layers=2, num_heads=4,
         ffn_size=64, max_position=32))
     v = m.init(jax.random.PRNGKey(2))
-    engine = ServeEngine(m, v, num_slots=1, max_len=24, min_bucket=8)
+    engine = _engine(m, v, num_slots=1, max_len=24)
     prompt = [5, 1, 9]
     assert _engine_greedy(engine, prompt, 8) == _ref_greedy(m, v, prompt, 8)
 
 
 def test_parity_independent_of_bucket_padding(gpt):
-    """The same prompt through two different buckets (forced by engine
-    min_bucket) must generate identical tokens — pad K/V never leaks."""
+    """The same prompt through two different chunk buckets (forced by
+    engine min_bucket) must generate identical tokens — pad K/V never
+    leaks."""
     model, variables = gpt
     prompt = [3, 14, 15, 9, 2]
-    small = ServeEngine(model, variables, num_slots=1, max_len=40,
-                        min_bucket=8)    # bucket 8
-    big = ServeEngine(model, variables, num_slots=1, max_len=40,
-                      min_bucket=32)     # bucket 32
+    small = _engine(model, variables, num_slots=1, max_len=40)  # bucket 8
+    big = _engine(model, variables, num_slots=1, max_len=40,
+                  min_bucket=32)                                # bucket 32
+    assert small.chunk_bucket_for(5) == 8 and big.chunk_bucket_for(5) == 32
     assert _engine_greedy(small, prompt, 8) == _engine_greedy(big, prompt, 8)
 
 
@@ -120,12 +117,10 @@ def test_parity_independent_of_bucket_padding(gpt):
 def test_tp_sharded_decode_matches_unsharded(llama):
     model, variables = llama
     prompt = [3, 14, 15, 9, 2, 6]
-    plain = ServeEngine(model, variables, num_slots=2, max_len=32,
-                        min_bucket=8)
-    mesh = ht.make_mesh(tp=2)  # nkv=2 → kv-head-sharded cache
-    tp = ServeEngine(model, variables, num_slots=2, max_len=32,
-                     min_bucket=8, mesh=mesh)
-    assert _engine_greedy(plain, prompt, 8) == _engine_greedy(tp, prompt, 8)
+    mesh = ht.make_mesh(tp=2)  # nkv=2 → kv-head-sharded page pool
+    tp = _engine(model, variables, num_slots=2, max_len=32, mesh=mesh)
+    assert _engine_greedy(tp, prompt, 8) == \
+        _ref_greedy(model, variables, prompt, 8)
 
 
 def test_tp8_graceful_when_kv_heads_do_not_divide(llama):
@@ -133,11 +128,10 @@ def test_tp8_graceful_when_kv_heads_do_not_divide(llama):
     weight splits degrade per-dim (Strategy._fit); numerics unchanged."""
     model, variables = llama
     prompt = [7, 3, 1]
-    plain = ServeEngine(model, variables, num_slots=1, max_len=24,
-                        min_bucket=8)
-    tp = ServeEngine(model, variables, num_slots=1, max_len=24,
-                     min_bucket=8, mesh=ht.make_mesh(tp=8))
-    assert _engine_greedy(plain, prompt, 6) == _engine_greedy(tp, prompt, 6)
+    tp = _engine(model, variables, num_slots=1, max_len=24,
+                 mesh=ht.make_mesh(tp=8))
+    assert _engine_greedy(tp, prompt, 6) == \
+        _ref_greedy(model, variables, prompt, 6)
 
 
 # ---- bounded compilation under real traffic ----
@@ -145,10 +139,10 @@ def test_tp8_graceful_when_kv_heads_do_not_divide(llama):
 def test_bounded_executables_serving_32_varied_requests(gpt):
     """>= 32 requests of varied prompt lengths through the
     continuous-batching scheduler compile at most one executable per
-    prompt bucket plus one decode step."""
+    chunk bucket plus one per decode bucket, and a second wave stays
+    inside the same ceiling."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=4, max_len=48,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=4, max_len=48)
     g = np.random.default_rng(7)
     reqs = [Request(prompt=[int(t) for t in g.integers(0, 97,
                                                        int(g.integers(1, 40)))],
@@ -158,16 +152,13 @@ def test_bounded_executables_serving_32_varied_requests(gpt):
     out = sched.run(reqs)
     assert len(out) == 32
     assert all(r.status == "ok" for r in reqs)
-    # buckets (8,16,32,48) + 1 decode = 5; every bucket was hit
     assert engine.compiled_executables() <= engine.max_executables
     assert engine.metrics.count("decode_steps") > 0
-    # a second wave of traffic must not compile anything new
-    before = engine.compiled_executables()
     reqs2 = [Request(prompt=[int(t) for t in g.integers(0, 97,
                                                         int(g.integers(1, 40)))],
                      max_tokens=2) for _ in range(8)]
     sched.run(reqs2)
-    assert engine.compiled_executables() == before
+    assert engine.compiled_executables() <= engine.max_executables
 
 
 # ---- continuous batching semantics ----
@@ -176,8 +167,7 @@ def test_admission_into_freed_slots_midstream(gpt):
     """More requests than slots: later requests must start while earlier
     ones are still decoding (continuous batching, not batch-at-once)."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=2, max_len=32,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=2, max_len=32)
     sched = ContinuousBatchingScheduler(engine)
     short_a = Request(prompt=[1], max_tokens=2)
     long_b = Request(prompt=[11, 12], max_tokens=14)
@@ -199,8 +189,7 @@ def test_admission_into_freed_slots_midstream(gpt):
 
 def test_eos_evicts_and_frees_slot(gpt):
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=1, max_len=32,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=1, max_len=32)
     prompt = [3, 14, 15]
     ref = _ref_greedy(model, variables, prompt, 10)
     eos = ref[3]
@@ -212,13 +201,14 @@ def test_eos_evicts_and_frees_slot(gpt):
     assert engine.cache.num_free == 1       # slot reclaimed
 
 
-def test_token_budget_backpressure(gpt):
-    """With a budget that fits one working set, concurrency collapses to
-    sequential admission even though slots are free."""
+def test_page_budget_of_one_working_set_serializes_admission(gpt):
+    """With a page pool that fits one request's worst case, concurrency
+    collapses to sequential admission even though slots are free."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=4, max_len=32,
-                         min_bucket=8)
-    sched = ContinuousBatchingScheduler(engine, token_budget=16)
+    # 8 + 3 + 1 tokens = 2 pages + 1 of copy-on-write headroom, of 3
+    engine = _engine(model, variables, num_slots=4, max_len=32,
+                     num_pages=4, prefix_sharing=False)
+    sched = ContinuousBatchingScheduler(engine)
     reqs = [Request(prompt=[1, 2, 3, 4, 5, 6, 7, 8], max_tokens=3)
             for _ in range(3)]
     for r in reqs:
@@ -231,17 +221,19 @@ def test_token_budget_backpressure(gpt):
         if all(r.done.is_set() for r in reqs):
             break
     assert all(r.status == "ok" for r in reqs)
-    assert max_occupied == 1, "budget of one working set must serialize"
+    assert max_occupied == 1, "a pool of one working set must serialize"
 
 
-def test_prompt_exceeding_token_budget_rejected_not_wedged(gpt):
-    """A prompt that could NEVER fit the budget must fail as overflow —
-    not deadlock the queue head while the engine loop hot-spins."""
+def test_request_the_page_pool_can_never_hold_rejected_not_wedged(gpt):
+    """A request whose worst case could NEVER fit the page pool must fail
+    as overflow — not deadlock the queue head while the engine loop
+    hot-spins."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=2, max_len=32,
-                         min_bucket=8)
-    sched = ContinuousBatchingScheduler(engine, token_budget=8)
-    too_big = Request(prompt=list(range(1, 11)), max_tokens=4)  # 10+1 > 8
+    engine = _engine(model, variables, num_slots=2, max_len=32,
+                     num_pages=4)
+    sched = ContinuousBatchingScheduler(engine)
+    # 10 + 9 + 1 tokens = 3 pages + 1 of headroom > the pool's 3
+    too_big = Request(prompt=list(range(1, 11)), max_tokens=9)
     fits = Request(prompt=[1, 2, 3], max_tokens=2)
     sched.submit(too_big)
     sched.submit(fits)
@@ -251,22 +243,21 @@ def test_prompt_exceeding_token_budget_rejected_not_wedged(gpt):
             break
     assert too_big.status == "overflow" and too_big.tokens == []
     assert fits.status == "ok"          # the queue kept moving behind it
+    assert engine.cache.num_free == 2
 
 
 def test_submit_after_shutdown_drain_fails_fast(gpt):
     """A listener racing close() must get an immediate 'shutdown'
     completion, not a request parked forever with no engine loop."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=1, max_len=16,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=1, max_len=16)
     sched = ContinuousBatchingScheduler(engine)
     sched.drain("shutdown", stop_accepting=True)
     late = sched.submit(Request(prompt=[1, 2], max_tokens=4))
     assert late.done.is_set() and late.status == "shutdown"
     # an ERROR drain keeps accepting (the loop recovers per-request)
     sched2 = ContinuousBatchingScheduler(
-        ServeEngine(model, variables, num_slots=1, max_len=16,
-                    min_bucket=8))
+        _engine(model, variables, num_slots=1, max_len=16))
     sched2.drain("error")
     req = sched2.submit(Request(prompt=[1, 2], max_tokens=2))
     sched2.run([])
@@ -275,8 +266,7 @@ def test_submit_after_shutdown_drain_fails_fast(gpt):
 
 def test_prompt_overflow_rejected(gpt):
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=1, max_len=16,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=1, max_len=16)
     sched = ContinuousBatchingScheduler(engine)
     req = Request(prompt=list(range(1, 20)), max_tokens=4)
     sched.run([req])
@@ -287,8 +277,8 @@ def test_generation_capped_by_cache_capacity(gpt):
     """A request whose max_tokens exceeds the slot's remaining room ends
     cleanly at capacity instead of writing past max_len."""
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=1, max_len=16,
-                         min_bucket=8)
+    # two pages of the sequence and one of copy-on-write headroom
+    engine = _engine(model, variables, num_slots=1, max_len=16, num_pages=4)
     sched = ContinuousBatchingScheduler(engine)
     req = Request(prompt=list(range(1, 12)), max_tokens=50)
     out = sched.run([req])
@@ -299,8 +289,7 @@ def test_generation_capped_by_cache_capacity(gpt):
 
 def test_expired_request_times_out_in_queue(gpt):
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=1, max_len=16,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=1, max_len=16)
     sched = ContinuousBatchingScheduler(engine)
     req = Request(prompt=[1, 2], max_tokens=4, timeout_s=0.0)
     sched.submit(req)
@@ -317,8 +306,8 @@ def test_metrics_report_through_metric_logger(gpt, tmp_path):
 
     model, variables = gpt
     metrics = ServeMetrics()
-    engine = ServeEngine(model, variables, num_slots=2, max_len=32,
-                         min_bucket=8, metrics=metrics)
+    engine = _engine(model, variables, num_slots=2, max_len=32,
+                     metrics=metrics)
     sched = ContinuousBatchingScheduler(engine)
     sched.run([Request(prompt=[1, 2, 3], max_tokens=4),
                Request(prompt=[4, 5], max_tokens=3)])

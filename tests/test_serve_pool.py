@@ -9,7 +9,6 @@ import threading
 import time
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 from hetu_tpu.ps import available
@@ -19,8 +18,10 @@ if not available():  # pragma: no cover
 
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.resilience.faults import FaultInjector, FaultSchedule
-from hetu_tpu.serve import ServeEngine, ServingPool
+from hetu_tpu.serve import PagedServeEngine, ServingPool
 from hetu_tpu.telemetry import timeline, trace
+from paged_programs import ref_greedy as _ref_greedy
+from paged_programs import submit_and_hold_mid_decode
 
 pytestmark = pytest.mark.migrate
 
@@ -33,21 +34,10 @@ def gpt():
     return m, m.init(jax.random.PRNGKey(0))
 
 
-def _ref_greedy(model, variables, prompt, n):
-    ids = list(prompt)
-    out = []
-    for _ in range(n):
-        logits, _ = model.apply(variables, jnp.asarray([ids], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        ids.append(tok)
-    return out
-
-
 def _factory(model, variables):
     def make():
-        return ServeEngine(model, variables, num_slots=4, max_len=48,
-                           min_bucket=8)
+        return PagedServeEngine(model, variables, num_slots=4, max_len=48,
+                                page_size=8, min_bucket=8)
     return make
 
 
@@ -111,25 +101,20 @@ def test_no_member_available_fails_fast(gpt):
 def test_planned_drain_migrates_zero_prefill(gpt):
     """Drain a member mid-decode: its requests finish on the peer with
     token parity and the PEER never prefills the migrated slots (the
-    ``serve.prefill`` metric stays flat)."""
+    ``prefill_tokens`` metric stays flat)."""
     model, variables = gpt
     f = _factory(model, variables)
     pool = ServingPool({"a": f, "b": f}, start_poll=False)
     prompts = [[1, 2, 3], [9, 8, 7, 6]]
     try:
         a, b = pool.members["a"], pool.members["b"]
-        reqs = []
         from hetu_tpu.serve import Request
-        for p in prompts:  # route straight to 'a' so the drain has work
-            r = Request(prompt=p, max_tokens=12, timeout_s=90.0)
-            a.scheduler.submit(r)
-            reqs.append(r)
-        deadline = time.monotonic() + 30
-        while not all(r.tokens for r in reqs):
-            assert time.monotonic() < deadline, "decode never started"
-            time.sleep(0.01)
+        # route straight to 'a' so the drain has work
+        reqs = [Request(prompt=p, max_tokens=12, timeout_s=90.0)
+                for p in prompts]
+        submit_and_hold_mid_decode(a, reqs)
         slot_map = pool.drain_member("a")
-        assert len(slot_map) >= 1
+        assert len(slot_map) == len(reqs)
         assert a.server._stop.is_set()  # migrate-then-exit
         for r in reqs:
             assert r.done.wait(60)
@@ -164,20 +149,13 @@ def test_drain_codec_override_per_drain(gpt):
     try:
         with pytest.raises(ValueError, match="codec"):
             pool.drain_member("a", codec="zstd")
-        a = pool.members["a"]
-        reqs = []
-        for p in ([1, 2, 3], [9, 8, 7, 6]):
-            r = Request(prompt=p, max_tokens=12, timeout_s=90.0)
-            a.scheduler.submit(r)
-            reqs.append(r)
-        deadline = time.monotonic() + 30
-        while not all(r.tokens for r in reqs):
-            assert time.monotonic() < deadline, "decode never started"
-            time.sleep(0.01)
+        reqs = [Request(prompt=p, max_tokens=12, timeout_s=90.0)
+                for p in ([1, 2, 3], [9, 8, 7, 6])]
+        submit_and_hold_mid_decode(pool.members["a"], reqs)
         logical0 = counter("serve.migrate.bytes_logical")
         wire0 = counter("serve.migrate.bytes_wire")
         slot_map = pool.drain_member("a", codec="bf16")
-        assert len(slot_map) >= 1
+        assert len(slot_map) == len(reqs)
         # the pool-level default is untouched by the per-drain override
         assert pool.migrate_codec == "none"
         logical = counter("serve.migrate.bytes_logical") - logical0
@@ -377,12 +355,8 @@ def test_two_pools_sharing_one_van_draw_distinct_migration_channels(gpt):
         reqs = []
         for pool in (pool_a, pool_b):
             r = Request(prompt=[1, 2, 3], max_tokens=30, timeout_s=90.0)
-            pool.members["a"].scheduler.submit(r)
+            submit_and_hold_mid_decode(pool.members["a"], [r])
             reqs.append(r)
-        deadline = time.monotonic() + 30
-        while not all(r.tokens for r in reqs):
-            assert time.monotonic() < deadline, "decode never started"
-            time.sleep(0.01)
         # drain CONCURRENTLY — the interleaving where same-id transfers
         # would cross-consume each other's chunks
         maps = {}

@@ -17,14 +17,13 @@ from hetu_tpu.ps import available
 if not available():  # pragma: no cover
     pytest.skip("native PS lib unavailable", allow_module_level=True)
 
-import jax.numpy as jnp
-
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.ps import van
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, InferenceClient, InferenceServer,
-    Request, ServeEngine, request_channel, response_channel,
+    PagedServeEngine, Request, request_channel, response_channel,
 )
+from paged_programs import ref_greedy as _ref_greedy
 
 
 @pytest.fixture(scope="module")
@@ -38,8 +37,7 @@ def gpt():
 @pytest.fixture
 def server(gpt):
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=4, max_len=48,
-                         min_bucket=8)
+    engine = _engine(model, variables, num_slots=4)
     sched = ContinuousBatchingScheduler(engine)
     srv = InferenceServer(sched, max_clients=3, request_timeout_s=60.0,
                           poll_s=0.1)
@@ -47,15 +45,9 @@ def server(gpt):
     srv.close()
 
 
-def _ref_greedy(model, variables, prompt, n):
-    ids = list(prompt)
-    out = []
-    for _ in range(n):
-        logits, _ = model.apply(variables, jnp.asarray([ids], jnp.int32))
-        tok = int(jnp.argmax(logits[0, -1]))
-        out.append(tok)
-        ids.append(tok)
-    return out
+def _engine(model, variables, *, num_slots=2, max_len=48):
+    return PagedServeEngine(model, variables, num_slots=num_slots,
+                            max_len=max_len, page_size=8, min_bucket=8)
 
 
 def test_generate_end_to_end_matches_reference(server):
@@ -116,8 +108,7 @@ def test_per_request_timeout_returns_timeout_status(server):
 
 def test_graceful_shutdown_drains_and_stops_van(gpt):
     model, variables = gpt
-    engine = ServeEngine(model, variables, num_slots=2, max_len=32,
-                         min_bucket=8)
+    engine = _engine(model, variables, max_len=32)
     sched = ContinuousBatchingScheduler(engine)
     srv = InferenceServer(sched, max_clients=1, poll_s=0.05)
     client = InferenceClient("127.0.0.1", srv.port, 0)
@@ -171,14 +162,15 @@ def test_malformed_request_gets_error_response(server):
 
 
 class _BoomEngine:
-    """Engine double whose prefill always blows up — the 'unexpected
-    engine-loop exception' case the server must survive visibly."""
+    """Engine double that admits and then always blows up — the
+    'unexpected engine-loop exception' case the server must survive
+    visibly."""
 
     class _Cache:
         num_slots = 2
         max_len = 16
         num_free = 2
-        active_tokens = 0
+        num_pages = 5
         occupancy = 0.0
         lengths = [0, 0]
 
@@ -193,7 +185,16 @@ class _BoomEngine:
     def release(self, slot):
         pass
 
-    def prefill(self, slot, prompt):
+    def admission_pages(self, prompt_len, max_tokens):
+        return 1
+
+    def admission_ok(self, prompt, max_tokens):
+        return True
+
+    def begin_prefill(self, slot, prompt, *, max_tokens=0):
+        raise RuntimeError("boom: engine exploded mid-step")
+
+    def prefill_step(self, slot):
         raise RuntimeError("boom: engine exploded mid-step")
 
     def decode(self):
@@ -239,7 +240,7 @@ def test_dead_engine_fails_requests_and_reports_unhealthy():
 
 
 class _FlakyEngine:
-    """Proxy over a real ServeEngine that starts raising on command — the
+    """Proxy over a real engine that starts raising on command — the
     'engine crashed mid-decode' case the failover path must survive."""
 
     def __init__(self, inner):
@@ -259,17 +260,11 @@ class _FlakyEngine:
         if self.dead:
             raise RuntimeError("flaky: engine crashed")
 
-    def alloc_slot(self):
+    def __getattr__(self, verb):
+        """Every other verb the scheduler calls (admission, the two
+        prefill verbs, slot alloc and release): dead once ``dead``."""
         self._check()
-        return self.inner.alloc_slot()
-
-    def release(self, slot):
-        self._check()
-        self.inner.release(slot)
-
-    def prefill(self, slot, prompt):
-        self._check()
-        return self.inner.prefill(slot, prompt)
+        return getattr(self.inner, verb)
 
     def decode(self):
         self._check()
@@ -284,8 +279,7 @@ def test_engine_crash_restart_loses_zero_requests(gpt):
     token-for-token greedy answer (re-prefill from prompt + tokens
     emitted so far), and `healthy` recovers."""
     model, variables = gpt
-    flaky = _FlakyEngine(ServeEngine(model, variables, num_slots=2,
-                                     max_len=48, min_bucket=8))
+    flaky = _FlakyEngine(_engine(model, variables))
     sched = ContinuousBatchingScheduler(flaky)
     srv = InferenceServer(sched, max_clients=3, poll_s=0.05,
                           request_timeout_s=120.0, max_loop_errors=2,
@@ -318,8 +312,7 @@ def test_engine_crash_restart_loses_zero_requests(gpt):
             time.sleep(0.02)
         assert not srv.healthy
         # restart inside the grace window: a FRESH engine adopts the queue
-        srv.restart_engine(ServeEngine(model, variables, num_slots=2,
-                                       max_len=48, min_bucket=8))
+        srv.restart_engine(_engine(model, variables))
         assert srv.healthy
         for t in ts:
             t.join(120)
@@ -337,43 +330,39 @@ def test_engine_crash_restart_loses_zero_requests(gpt):
 
 
 class _SelectivePoisonEngine:
-    """Proxy over a real ServeEngine whose prefill raises for ONE magic
-    prompt — the 'poisoned request' that must fail alone, not kill the
-    server."""
+    """Proxy over a real engine whose ``verb`` (``begin_prefill``, at
+    admission, or ``prefill_step``, at the prompt's first chunk) raises for
+    ONE magic prompt — the 'poisoned request' that must fail alone, not
+    kill the server."""
 
-    def __init__(self, inner):
+    def __init__(self, inner, verb):
         self.inner = inner
+        self.verb = verb
+        self.poisoned = set()  # slots holding the magic prompt
 
-    @property
-    def cache(self):
-        return self.inner.cache
+    def __getattr__(self, name):
+        return getattr(self.inner, name)
 
-    @property
-    def metrics(self):
-        return self.inner.metrics
-
-    def alloc_slot(self):
-        return self.inner.alloc_slot()
-
-    def release(self, slot):
-        self.inner.release(slot)
-
-    def prefill(self, slot, prompt):
+    def begin_prefill(self, slot, prompt, *, max_tokens=0):
+        self.poisoned.discard(slot)
         if int(np.asarray(prompt).reshape(-1)[0]) == 66:
-            raise RuntimeError("poisoned prompt")
-        return self.inner.prefill(slot, prompt)
+            if self.verb == "begin_prefill":
+                raise RuntimeError("poisoned prompt")
+            self.poisoned.add(slot)
+        self.inner.begin_prefill(slot, prompt, max_tokens=max_tokens)
 
-    def decode(self):
-        return self.inner.decode()
+    def prefill_step(self, slot):
+        if slot in self.poisoned:
+            raise RuntimeError("poisoned prompt")
+        return self.inner.prefill_step(slot)
 
 
 def test_poisoned_request_fails_alone_server_stays_healthy(gpt):
-    """A request whose prefill deterministically raises is charged to the
+    """A request whose admission deterministically raises is charged to the
     REQUEST (status 'error' after its requeue cap) while the engine keeps
     serving everyone else: no engine-loop strikes, `healthy` stays True."""
     model, variables = gpt
-    eng = _SelectivePoisonEngine(ServeEngine(model, variables, num_slots=2,
-                                             max_len=48, min_bucket=8))
+    eng = _SelectivePoisonEngine(_engine(model, variables), "begin_prefill")
     sched = ContinuousBatchingScheduler(eng)
     srv = InferenceServer(sched, max_clients=2, poll_s=0.05,
                           request_timeout_s=60.0, max_loop_errors=3)
@@ -392,6 +381,29 @@ def test_poisoned_request_fails_alone_server_stays_healthy(gpt):
         good.close()
         bad.close()
         srv.close()
+
+
+def test_poisoned_chunk_is_charged_to_the_request_while_others_decode(gpt):
+    """A request whose prefill CHUNK deterministically raises, one attempt
+    a step: while another request decodes, every step makes progress, so
+    none of the attempts raises out of ``step()`` (no engine-loop strike),
+    the poisoned request ends 'error' at its requeue cap and the other one
+    is served token for token."""
+    model, variables = gpt
+    eng = _SelectivePoisonEngine(_engine(model, variables), "prefill_step")
+    sched = ContinuousBatchingScheduler(eng, max_requeues=3)
+    good = sched.submit(Request(prompt=[5, 6, 7], max_tokens=20))
+    while not good.tokens:
+        sched.step()
+    bad = sched.submit(Request(prompt=[66, 2, 3], max_tokens=6))
+    for _ in range(8):
+        sched.step()                     # raises on an engine-loop strike
+    assert bad.status == "error" and bad.requeues == 4  # past the cap
+    assert not good.done.is_set()        # it was decoding all the while
+    sched.run([])
+    assert good.status == "ok"
+    assert good.tokens == _ref_greedy(model, variables, [5, 6, 7], 20)
+    assert eng.cache.num_free == 2
 
 
 def test_close_mid_grace_cannot_flip_state_after_shutdown():
@@ -468,7 +480,7 @@ def test_duplicate_submit_same_id_dedups(server):
         ref = _ref_greedy(model, variables, [1, 2, 3], 5)
         assert r1["status"] == "ok" and r1["tokens"] == ref
         assert r2["status"] == "ok" and r2["tokens"] == ref
-        # ONE generation, ONE token-budget charge
+        # ONE generation, ONE page reservation
         assert srv.metrics.count("requests_submitted") - before == 1
         assert srv.metrics.count("requests_deduped") == 1
         # a DIFFERENT id (or a restarted client's new nonce) is fresh

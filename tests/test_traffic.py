@@ -16,12 +16,13 @@ import pytest
 
 from hetu_tpu.models.gpt import GPTConfig, GPTModel
 from hetu_tpu.serve import (
-    ContinuousBatchingScheduler, PagedServeEngine, Request, ServeEngine,
+    ContinuousBatchingScheduler, PagedServeEngine, Request,
 )
 from hetu_tpu.traffic import (
     AutoscalePolicy, Autoscaler, TenantSpec, TraceSpec, diurnal_multiplier,
     dumps_trace, load_trace, replay, save_trace, synthesize,
 )
+from paged_programs import ref_greedy
 
 pytestmark = pytest.mark.traffic
 
@@ -299,16 +300,7 @@ def test_shed_projection_counts_only_same_or_higher_tier(gpt):
 # ---------------------------------------------------------------------------
 
 def _oracle(model, variables, prompts, n):
-    out = []
-    for p in prompts:
-        e = ServeEngine(model, variables, num_slots=1, max_len=64)
-        slot = e.alloc_slot()
-        toks = [e.prefill(slot, p)]
-        for _ in range(n - 1):
-            toks.append(e.decode()[slot])
-        e.release(slot)
-        out.append(toks)
-    return out
+    return [ref_greedy(model, variables, p, n) for p in prompts]
 
 
 @pytest.mark.migrate
