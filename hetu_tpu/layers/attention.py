@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import Module, held_as
 
 
 class MultiHeadAttention(Module):
@@ -91,6 +91,11 @@ class MultiHeadAttention(Module):
         return ops.causal_attention(q, k, v)
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
+
+    def serving_params(self, params):
+        # mirrors _qkv and _out: all four leaves are read as
+        # astype(self.dtype) and as nothing else
+        return held_as(params, self.dtype)
 
     def _qkv(self, p, x):
         """Fused projection split into q/k/v in cache layout [B,S,nh,hd].

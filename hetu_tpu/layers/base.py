@@ -29,6 +29,21 @@ def child_rng(rng, i: int):
     return None if rng is None else jax.random.fold_in(rng, i)
 
 
+def held_as(tree, dtype):
+    """Every leaf of ``tree`` in ``dtype``: the leaf itself where it already
+    is (no copy, no new buffer), else cast, once.  A shape standing for its
+    array (``jax.eval_shape``'s trees, from which an engine is built only to
+    be compiled) is re-typed."""
+    def one(leaf):
+        if leaf.dtype == dtype:
+            return leaf
+        if isinstance(leaf, jax.ShapeDtypeStruct):
+            return leaf.update(dtype=dtype)
+        return leaf.astype(dtype)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
 class Module:
     """Base module; subclasses override init/apply."""
 
@@ -37,6 +52,17 @@ class Module:
 
     def apply(self, variables, *args, train: bool = False, rng=None):
         raise NotImplementedError
+
+    def serving_params(self, params):
+        """``params`` as this module's forward READS them, for a server
+        that holds the weights and never updates them: a leaf the forward
+        reads only as a whole-leaf ``astype(compute dtype)`` comes back in
+        that dtype (the ``astype`` at the use site is then a no-op), every
+        other leaf as given.  Stacked leaves (a leading layer axis) are
+        taken as they are.  The default is the tree as given: right for a
+        module that reads its leaves as stored (norms, embeddings), and
+        never wrong."""
+        return params
 
     # convenience: module(variables, x) == module.apply(...)
     def __call__(self, variables, *args, **kwargs):

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import Module, held_as
 
 
 class Linear(Module):
@@ -26,9 +26,10 @@ class Linear(Module):
         self.dtype = dtype
 
     def init(self, key):
-        # params are stored f32 (master weights); self.dtype is the COMPUTE
+        # params are made f32 (master weights); self.dtype is the COMPUTE
         # dtype applied at use time, so bf16 training keeps full-precision
-        # optimizer updates
+        # optimizer updates.  A server may hand the layer its weights
+        # already cast (serving_params): the cast at use is then a no-op
         kw, kb = jax.random.split(key)
         params = {"weight": self.weight_init(
             kw, (self.in_features, self.out_features), jnp.float32)}
@@ -48,6 +49,10 @@ class Linear(Module):
         if self.activation is not None:
             y = self.activation(y)
         return y, {}
+
+    def serving_params(self, params):
+        # mirrors apply: weight and bias are both read as astype(self.dtype)
+        return held_as(params, self.dtype)
 
 
 class Conv2d(Module):

@@ -81,6 +81,10 @@ class TransformerBlock(Module):
     # Pre-LN causal blocks only — the decoder-LM configuration GPT uses;
     # the post-LN (BERT) layout is an encoder and has no decode loop.
 
+    def serving_params(self, params):
+        return {name: getattr(self, name).serving_params(p)
+                for name, p in params.items()}
+
     def _mod(self, p, m, name, h, **kw):
         out, _ = m.apply({"params": p[name], "state": {}}, h, **kw)
         return out

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import Module, held_as
 from hetu_tpu.layers.transformer import TransformerBlock
 
 
@@ -100,10 +100,28 @@ class GPTModel(Module):
         h = self.hidden_states(variables, input_ids, train=train, rng=rng)
         # tied LM head in the compute dtype: an f32 matmul would skip the
         # MXU bf16 path; CE upcasts to f32 for the reduction
-        logits = ops.linear(h, p["tok_emb"].T.astype(c.dtype))
+        logits = ops.linear(h, self._head_weight(p))
         return logits, {}
 
+    def _head_weight(self, p):
+        """The tied head ``[H, V]`` in the compute dtype: from the copy a
+        server holds for it (:meth:`serving_params`) when there is one."""
+        return p.get("lm_head", p["tok_emb"]).T.astype(self.c.dtype)
+
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
+
+    def serving_params(self, params):
+        """The blocks' matmul leaves in the compute dtype (each layer's
+        own ``serving_params``; the stacked leaves cast whole).  The
+        embeddings and norms stay as given: the lookup adds ``tok_emb`` and
+        ``pos_emb`` rows in their own dtype and only then rounds.  The tied
+        ``tok_emb`` is read a second way, by the head in the compute dtype,
+        so a tree whose ``tok_emb`` is not in it gains that copy as
+        ``lm_head``."""
+        held = dict(params, blocks=self.block.serving_params(params["blocks"]))
+        if params["tok_emb"].dtype != self.c.dtype:
+            held["lm_head"] = held_as(params["tok_emb"], self.c.dtype)
+        return held
 
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None):
@@ -143,7 +161,7 @@ class GPTModel(Module):
         h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
         idx = s - 1 if last_index is None else last_index
         h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
-        logits = ops.linear(h, p["tok_emb"].T.astype(c.dtype))
+        logits = ops.linear(h, self._head_weight(p))
         return logits, k_cache, v_cache
 
     def decode_with_cache(self, variables, input_ids, k_cache, v_cache,
@@ -164,7 +182,7 @@ class GPTModel(Module):
                 {"params": p_l, "state": {}}, h, k_l, v_l, lengths),
             p["blocks"], h, k_cache, v_cache, lengths, 1)
         h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
-        logits = ops.linear(h[:, 0], p["tok_emb"].T.astype(c.dtype))
+        logits = ops.linear(h[:, 0], self._head_weight(p))
         return logits, k_cache, v_cache
 
     def lm_loss_fn(self):

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import Module, held_as
 
 
 @dataclass
@@ -130,6 +130,14 @@ class LlamaBlock(Module):
         down = ops.linear(ops.silu(gate) * up,
                           p["ffn_down"].astype(c.dtype))
         return x + down
+
+    def serving_params(self, params):
+        # mirrors _qkv, the two steps' out projection and _mlp: every
+        # matmul leaf is read as astype(c.dtype) and as nothing else; the
+        # two norm scales are read as they are
+        matmuls = ("attn", "ffn_gate", "ffn_up", "ffn_down")
+        return dict(params, **held_as({k: params[k] for k in matmuls},
+                                      self.c.dtype))
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
     # The cache stores ROTATED k (RoPE applied at write time, the standard
@@ -238,6 +246,14 @@ class LlamaModel(Module):
         return logits, {}
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
+
+    def serving_params(self, params):
+        """The blocks' matmul leaves and the untied head in the compute
+        dtype; ``tok_emb`` (looked up, then rounded) and the norm scales as
+        given."""
+        return dict(params,
+                    blocks=self.block.serving_params(params["blocks"]),
+                    lm_head=held_as(params["lm_head"], self.c.dtype))
 
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None):

@@ -8,7 +8,15 @@ What a served model implements: ``prefill_chunk_with_cache`` and
 ``k_cache`` / ``v_cache`` arguments and return them beside the logits),
 and either ``kv_cache_spec()`` or the config fields
 :meth:`KVCacheSpec.from_model` reads.  ``models/gpt.py``,
-``models/llama.py`` and ``models/longcat_flash.py`` do.
+``models/llama.py`` and ``models/longcat_flash.py`` do.  Optionally
+``serving_params(params)``: the parameters as those two entry points READ
+them (a leaf they read only as ``astype(compute dtype)`` in that dtype, a
+leaf they read in two dtypes held in both, the rest as given).  The engine
+calls it once, at build, and holds what it returns, so a weight is cast
+once and not in every decode round and every prefill chunk; a model
+without it, or whose leaves are already in the dtype they are read in, is
+served from the very arrays it was given.  The caller's ``variables`` are
+not kept: a caller that drops them after the build gets their bytes back.
 
 Compilation discipline is the whole point of this module: serving traffic
 has arbitrary prompt lengths, and a naive jit would compile one executable
@@ -70,8 +78,12 @@ def _pow2_buckets(lo: int, hi: int) -> tuple:
 
 def _place_params_and_cache_spec(model, variables, mesh, spec):
     """The engine's tp placement: Megatron split points on the params,
-    kv-head-sharded cache when GQA heads divide tp."""
-    params = variables["params"] if "params" in variables else variables
+    kv-head-sharded cache when GQA heads divide tp.  The placed leaves are
+    then held as the model's cache entry points read them (the module
+    docstring's ``serving_params``; a cast keeps its leaf's sharding).
+    Returns (params, cache sharding, the ids of ``serve.params_held``)."""
+    given = params = variables["params"] if "params" in variables \
+        else variables
     cache_sharding = None
     if mesh is not None:
         tp = mesh.shape.get(AXIS_TP, 1)
@@ -81,7 +93,29 @@ def _place_params_and_cache_spec(model, variables, mesh, spec):
         axes = (None, None, None,
                 AXIS_TP if spec.num_kv_heads % tp == 0 else None)
         cache_sharding = NamedSharding(mesh, P(*axes))
-    return params, cache_sharding
+    as_read = getattr(model, "serving_params", None)
+    if as_read is not None:
+        params = as_read(params)
+    return params, cache_sharding, _params_held(given, params)
+
+
+def _params_held(given, held) -> dict:
+    """What the build did to the parameters, by leaf path: the leaves it
+    was given, those it holds in another dtype than they came in (or in a
+    second one), and the bytes on each side."""
+    given, held = ({jax.tree_util.keystr(path): leaf for path, leaf
+                    in jax.tree_util.tree_leaves_with_path(tree)}
+                   for tree in (given, held))
+
+    def nbytes(leaves):
+        return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+                   for a in leaves.values())
+
+    return {
+        "leaves": len(given),
+        "retyped": sum(path not in given or given[path].dtype != leaf.dtype
+                       for path, leaf in held.items()),
+        "bytes_given": nbytes(given), "bytes_held": nbytes(held)}
 
 
 class _PrefillCursor:
@@ -104,7 +138,11 @@ class _PrefillCursor:
 class PagedServeEngine:
     """Owns params + a :class:`PagedKVCache` + the jitted chunk/decode
     executables: paged gather/scatter decode, chunked prefill, prefix
-    sharing with copy-on-write.
+    sharing with copy-on-write.  The params it owns are ``variables``'
+    leaves in the dtype the two programs read each in (the model's
+    ``serving_params``, once, here): the leaves themselves where that is
+    the dtype they came in, a cast copy where it is not.  ``variables``
+    itself is not kept.
 
     model: anything with the cache entry points the module docstring names.
     num_slots bounds concurrent sequences; max_len bounds tokens per
@@ -151,8 +189,10 @@ class PagedServeEngine:
                              f"max_position {c.max_position}")
         spec = KVCacheSpec.from_model(model)
         self.mesh = mesh
-        self.params, cache_sharding = _place_params_and_cache_spec(
+        self.params, cache_sharding, held = _place_params_and_cache_spec(
             model, variables, mesh, spec)
+        for name, n in held.items():
+            self.metrics.set_gauge(f"params_{name}", n)
         self.cache = PagedKVCache(
             spec, num_slots, max_len, page_size=page_size,
             num_pages=num_pages, sharding=cache_sharding,
@@ -190,6 +230,7 @@ class PagedServeEngine:
             "cache_layers": int(spec.num_layers),
             "bytes_per_token": int(spec.bytes_per_token),
             "pool_bytes": int(self.cache.k.nbytes + self.cache.v.nbytes)})
+        trace.instant("serve.params_held", held)
 
     def _count(self, stats):
         """The counts a step returned beside its tokens, added to the

@@ -1,6 +1,7 @@
 """What the paged engine's two programs hold and where they write: the
 checks ``test_paged_kv.py`` (GPT, Llama) and ``test_longcat_flash.py`` share
-(ISSUE 29), and the two token oracles every serving test holds the engine
+(ISSUE 29), which parameter leaves they convert and the logits they argmax
+(ISSUE 31), and the two token oracles every serving test holds the engine
 to, both independent of any engine, and the hold that lets a pool test
 catch a member mid-decode.  A helper module, no tests of its own."""
 
@@ -8,6 +9,7 @@ import threading
 import time
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 
@@ -39,12 +41,13 @@ def _results(jaxpr):
             yield from _results(sub)
 
 
-def program(engine, name: str, *, batch: int, chunk: int):
+def program(engine, name: str, *, batch: int, chunk: int, params=None):
     """The jaxpr of the ``decode`` program, of the hot ``chunk`` program or
     of the boundary one (``chunk_ext``, its views one max-chunk wider), and
-    the pages a sequence's view spans in it."""
+    the pages a sequence's view spans in it.  Over the engine's own leaves,
+    or over ``params``."""
     cache = engine.cache
-    args = (engine.params, cache.k, cache.v)
+    args = (engine.params if params is None else params, cache.k, cache.v)
     n_pg = cache.pages_per_slot
     if name == "chunk_ext":
         n_pg += -(-engine.prefill_chunk // cache.page_size)
@@ -71,6 +74,99 @@ def oversized(engine, name: str, *, batch: int, chunk: int):
     assert any(p == "scatter" and s in pools for p, s in seen), name
     return floor, [(p, s) for p, s in seen if int(np.prod(s)) >= floor
                    and not (s in pools and p in CARRIERS)]
+
+
+# the primitives that hand a leaf on as it is but for its shape, or a
+# slice of it: ``tok_emb.T``, a stacked leaf read at ``[l]``
+LAYOUT_ONLY = {"transpose", "reshape", "squeeze", "slice", "dynamic_slice"}
+# the primitives whose body takes the operands themselves, one for one
+BODIES = {"scan", "pjit", "jit", "closed_call", "core_call", "checkpoint",
+          "custom_jvp_call", "custom_vjp_call"}
+
+
+def _inner_leaves(eqn, sub, is_leaf):
+    """Which of ``sub``'s inputs are parameter leaves, given which of
+    ``eqn``'s operands are: a loop's or a call's body takes the operands
+    themselves, a scatter's or a reduction's combiner takes scalars."""
+    name = eqn.primitive.name
+    if name == "while":
+        nc, nb = eqn.params["cond_nconsts"], eqn.params["body_nconsts"]
+        carry = is_leaf[nc + nb:]
+        is_leaf = (is_leaf[:nc] + carry
+                   if sub is eqn.params["cond_jaxpr"].jaxpr
+                   else is_leaf[nc:nc + nb] + carry)
+    elif name == "cond":
+        is_leaf = is_leaf[1:]
+    elif name not in BODIES:
+        return set()
+    assert len(is_leaf) == len(sub.invars), (name, "operands not mapped")
+    return {v for v, leaf in zip(sub.invars, is_leaf) if leaf}
+
+
+def _leaf_converts(jaxpr, leaves: set):
+    for eqn in jaxpr.eqns:
+        is_leaf = [isinstance(v, jax.extend.core.Var) and v in leaves
+                   for v in eqn.invars]
+        name = eqn.primitive.name
+        if name == "convert_element_type" and is_leaf[0]:
+            aval = eqn.invars[0].aval
+            if len(aval.shape) >= 2:
+                yield (tuple(aval.shape), str(aval.dtype),
+                       str(eqn.params["new_dtype"]))
+        if name in LAYOUT_ONLY and is_leaf[0]:
+            leaves.update(eqn.outvars)
+        for sub in _sub_jaxprs(eqn.params):
+            yield from _leaf_converts(sub, _inner_leaves(eqn, sub, is_leaf))
+
+
+def param_converts(engine, name: str, *, batch: int, chunk: int,
+                   params=None) -> list:
+    """(shape, from, to) of every ``convert_element_type`` in program
+    ``name`` whose operand is a parameter leaf of two or more dimensions,
+    whole, transposed or one layer's slice of it, the bodies of loops and
+    calls included: the casts a program makes of its weights in every call.
+    Over the engine's own leaves, or over ``params``."""
+    leaves = engine.params if params is None else params
+    closed, _ = program(engine, name, batch=batch, chunk=chunk, params=leaves)
+    n = len(jax.tree_util.tree_leaves(leaves))
+    return list(_leaf_converts(closed.jaxpr, set(closed.jaxpr.invars[:n])))
+
+
+class LogitsOut:
+    """A served model that hands each cache entry point's logits on as its
+    per-call counts too, so that the engine's own two programs return them
+    beside the token they argmax."""
+
+    def __init__(self, model):
+        self.model, self.c = model, model.c
+
+    def __getattr__(self, name):
+        return getattr(self.model, name)
+
+    def prefill_chunk_with_cache(self, *args, **kw):
+        logits, k, v = self.model.prefill_chunk_with_cache(*args, **kw)
+        return logits, k, v, logits
+
+    def decode_with_cache(self, *args, **kw):
+        logits, k, v = self.model.decode_with_cache(*args, **kw)
+        return logits, k, v, logits
+
+
+def engine_logits(model, variables, prompt, n: int, *, as_given=False,
+                  **engine_kw):
+    """The logits behind every chunk of ``prompt`` and each of ``n - 1``
+    decode rounds after it, out of the engine's own programs
+    (:class:`LogitsOut`), and the engine.  ``as_given``: over the leaves the
+    engine was given, not over those it holds."""
+    from hetu_tpu.serve import PagedServeEngine
+
+    engine = PagedServeEngine(LogitsOut(model), variables, **engine_kw)
+    if as_given:
+        engine.params = variables["params"]
+    logits = []
+    engine._count = lambda stats: logits.append(np.asarray(stats[0]))
+    engine_greedy(engine, prompt, n)
+    return logits, engine
 
 
 def ref_greedy(model, variables, prompt, n: int) -> list:

@@ -31,7 +31,8 @@ from hetu_tpu.serve import (  # noqa: E402
 )
 from hetu_tpu.serve import migrate  # noqa: E402
 from paged_programs import (  # noqa: E402
-    dense_greedy, engine_greedy, oversized, pad_writes,
+    dense_greedy, engine_greedy, oversized, pad_writes, param_converts,
+    program as paged_program,
 )
 
 F32_TOL = 2e-4      # both sides float32: the order of operations only
@@ -292,6 +293,15 @@ def test_bfloat16_weights_stay_bfloat16_and_are_held_once():
     for path, a in jax.tree_util.tree_leaves_with_path(engine.params):
         want = jnp.float32 if "router" in str(path) else jnp.bfloat16
         assert a.dtype == want, path
+    # every leaf is in the type it is read in: the engine holds the very
+    # arrays it was given (ISSUE 31), and says so
+    given = jax.tree_util.tree_leaves(v["params"])
+    held = jax.tree_util.tree_leaves(engine.params)
+    assert len(held) == len(given) and all(
+        a is b for a, b in zip(held, given))
+    snap = engine.metrics.snapshot()
+    assert snap["params_retyped"] == 0
+    assert snap["params_bytes_held"] == snap["params_bytes_given"] == own
     slot = engine.alloc_slot()
     engine.prefill(slot, ids_of(20).tolist())
     engine.decode()
@@ -300,6 +310,23 @@ def test_bfloat16_weights_stay_bfloat16_and_are_held_once():
     pools = engine.cache.k.nbytes + engine.cache.v.nbytes
     live = sum(a.nbytes for a in jax.live_arrays()) - before - pools
     assert live < 1.1 * own, (live, own)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "chunk_ext"])
+def test_no_program_converts_a_bfloat16_leaf(program):
+    """Weights made in the compute type cost no cast in any call: no paged
+    program converts a parameter leaf, read whole or at ``[l, i]``, and each
+    is the program it would be over the leaves as given (ISSUE 31)."""
+    c = tiny(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model = LongcatFlashModel(c)
+    v = jax.jit(model.init)(jax.random.PRNGKey(0))
+    engine = PagedServeEngine(model, v, num_slots=2, max_len=64, page_size=8,
+                              prefill_chunk=16)
+    assert param_converts(engine, program, batch=2, chunk=16) == []
+    held, _ = paged_program(engine, program, batch=2, chunk=16)
+    given, _ = paged_program(engine, program, batch=2, chunk=16,
+                             params=v["params"])
+    assert str(held) == str(given)
 
 
 def test_the_cache_spec_has_two_widths_and_its_own_layer_count():

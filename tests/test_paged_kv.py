@@ -11,6 +11,7 @@ engine compiles a BOUNDED number of executables.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -20,8 +21,12 @@ from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, PagedServeEngine, Request,
 )
+from hetu_tpu.telemetry import trace
 from paged_programs import engine_greedy as _engine_greedy
-from paged_programs import dense_greedy, oversized, pad_writes, ref_greedy
+from paged_programs import (
+    dense_greedy, engine_logits, oversized, pad_writes, param_converts,
+    ref_greedy,
+)
 
 pytestmark = pytest.mark.paged
 
@@ -515,3 +520,167 @@ def test_pad_positions_are_written_to_scratch_only(kind, gpt, llama):
     prompts = [[int(t) for t in g.integers(0, 97, n)] for n in (5, 19, 9)]
     assert pad_writes(engine, prompts) == {
         "stray": [], "missed": [], "scratch_written": True}
+
+
+# ---- each weight held in the dtype its programs read it in (ISSUE 31) ----
+
+def _bf16(kind, dtype=jnp.bfloat16):
+    """The two models computing in ``dtype`` over the float32 leaves their
+    ``init`` yields."""
+    if kind == "gpt":
+        m = GPTModel(GPTConfig(
+            vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
+            ffn_size=128, max_position=64, dropout_rate=0.0, dtype=dtype))
+    else:
+        m = LlamaModel(LlamaConfig(
+            vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
+            num_kv_heads=2, ffn_size=96, max_position=64, dtype=dtype))
+    v = m.init(jax.random.PRNGKey(3))
+    assert all(a.dtype == jnp.float32 for a in jax.tree_util.tree_leaves(v))
+    return m, v
+
+
+def _leaves(tree) -> dict:
+    return {jax.tree_util.keystr(path): a for path, a
+            in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+# the leaves a program reads in float32: norms, positions, the lookup's table
+READ_IN_FLOAT32 = ("ln", "rms", "pos_emb", "tok_emb")
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_engine_holds_each_leaf_in_the_dtype_it_is_read_in(kind):
+    """bfloat16 compute over float32 leaves: the engine's matmul leaves
+    (and the biases cast beside them) are bfloat16, its norm, position and
+    lookup leaves the very float32 arrays it was given, the tied head a
+    second, bfloat16 leaf; the build instant and the metrics count them."""
+    model, variables = _bf16(kind)
+    given = _leaves(variables["params"])
+    tracer = trace.enable()
+    try:
+        engine = PagedServeEngine(model, variables, num_slots=2, max_len=64,
+                                  page_size=8, prefill_chunk=16)
+    finally:
+        trace.disable()
+    held = _leaves(engine.params)
+    retyped = 0
+    for path_, a in held.items():
+        if any(k in path_ for k in READ_IN_FLOAT32):
+            assert a is given[path_], path_
+        else:
+            assert a.dtype == jnp.bfloat16, path_
+            retyped += 1
+    # GPT-2's tied table is read both ways and held both ways
+    assert set(held) - set(given) == ({"['lm_head']"} if kind == "gpt"
+                                      else set())
+    assert np.array_equal(
+        held["['lm_head']"],
+        given["['tok_emb']" if kind == "gpt" else "['lm_head']"]
+        .astype(jnp.bfloat16))
+    want = {"leaves": len(given), "retyped": retyped,
+            "bytes_given": sum(a.nbytes for a in given.values()),
+            "bytes_held": sum(a.nbytes for a in held.values())}
+    assert retyped == (9 if kind == "gpt" else 6)
+    assert want["bytes_held"] < 0.7 * want["bytes_given"]
+    snap = engine.metrics.snapshot()
+    assert {k: snap[f"params_{k}"] for k in want} == want
+    (instant,) = [e for e in tracer.events
+                  if e["name"] == "serve.params_held"]
+    assert instant["args"] == want
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "chunk_ext"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_no_program_converts_a_parameter_leaf(kind, program):
+    """The jaxpr of each paged program over the engine's leaves holds no
+    ``convert_element_type`` of a parameter leaf of two or more dimensions,
+    the scan's body included; over the float32 leaves the engine was given
+    the same walk finds one for every matmul weight."""
+    model, variables = _bf16(kind)
+    engine = PagedServeEngine(model, variables, num_slots=2, max_len=64,
+                              page_size=8, prefill_chunk=16)
+    assert param_converts(engine, program, batch=2, chunk=16) == []
+    before = param_converts(engine, program, batch=2, chunk=16,
+                            params=variables["params"])
+    assert len(before) == (5 if kind == "gpt" else 6)
+    assert {(a, b) for _, a, b in before} == {("float32", "bfloat16")}
+
+
+@pytest.mark.parametrize("case", ["hot", "boundary"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_logits_over_held_leaves_equal_those_over_given_leaves(kind, case):
+    """Rounding a weight once at build and once a call are the same
+    mathematics: every chunk's and every decode round's logits, out of the
+    engine's own programs, are equal bit for bit over the leaves the engine
+    holds and over the float32 leaves it was given."""
+    model, variables = _bf16(kind)
+    g = np.random.default_rng(31)
+    # max_len 60 = 15 pages of 4: the chunk [48, 57) pads to 16 -> 64 > 60
+    prompt = [int(t) for t in g.integers(0, 97, 57 if case == "boundary"
+                                         else 21)]
+    kw = dict(num_slots=2, max_len=60, page_size=4, prefill_chunk=16)
+    held, engine = engine_logits(model, variables, prompt, 4, **kw)
+    given, _ = engine_logits(model, variables, prompt, 4, as_given=True, **kw)
+    assert (engine._chunk_fn_ext is not None) == (case == "boundary")
+    assert len(held) == len(given) == -(-len(prompt) // 16) + 3
+    for a, b in zip(held, given):
+        assert a.shape[-1] == 97 and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_float32_compute_is_served_from_the_arrays_given(kind):
+    """Every leaf is already in the dtype it is read in: the engine holds
+    the very arrays it was given, no copy, no second head, nothing counted
+    as re-typed."""
+    model, variables = _bf16(kind, jnp.float32)
+    engine = PagedServeEngine(model, variables, num_slots=2, max_len=64,
+                              page_size=8, prefill_chunk=16)
+    given, held = _leaves(variables["params"]), _leaves(engine.params)
+    assert set(held) == set(given)
+    assert all(held[p] is given[p] for p in given)
+    snap = engine.metrics.snapshot()
+    assert snap["params_retyped"] == 0
+    assert snap["params_bytes_held"] == snap["params_bytes_given"]
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_an_engine_built_from_shapes_holds_the_same_types(kind):
+    """``jax.eval_shape``'s tree in place of the arrays (an engine built
+    only for its programs to be compiled, ``benchmarks/tools``): the same
+    leaves in the same types, counted the same."""
+    model, variables = _bf16(kind)
+    kw = dict(num_slots=2, max_len=64, page_size=8, prefill_chunk=16)
+    real = PagedServeEngine(model, variables, **kw)
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(3))
+    described = PagedServeEngine(model, shapes, **kw)
+    assert {p: (a.shape, a.dtype) for p, a in _leaves(real.params).items()} \
+        == {p: (a.shape, a.dtype)
+            for p, a in _leaves(described.params).items()}
+    want, got = real.metrics.snapshot(), described.metrics.snapshot()
+    assert all(got[k] == want[k] for k in want if k.startswith("params_"))
+
+
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_retyped_leaves_keep_the_megatron_placement(kind):
+    """Under a ``tp=2`` mesh a re-typed leaf has the sharding its float32
+    leaf was placed with, and the tokens are those of the leaves as given."""
+    model, variables = _bf16(kind)
+    mesh = ht.make_mesh(tp=2)
+    kw = dict(num_slots=2, max_len=64, page_size=8, prefill_chunk=16,
+              mesh=mesh)
+    engine = PagedServeEngine(model, variables, **kw)
+    from hetu_tpu.serve.engine import _DecodeTP
+    want = _leaves(_DecodeTP().shardings(variables["params"], mesh))
+    split = 0
+    for path, a in _leaves(engine.params).items():
+        if path in want:
+            assert a.sharding.is_equivalent_to(want[path], a.ndim), path
+            split += not a.sharding.is_fully_replicated
+    assert split >= 4
+    g = np.random.default_rng(7)
+    prompt = [int(t) for t in g.integers(0, 97, 21)]
+    as_given = PagedServeEngine(model, variables, **kw)
+    as_given.params = _DecodeTP().place(variables["params"], mesh)
+    assert _engine_greedy(engine, prompt, 6) == \
+        _engine_greedy(as_given, prompt, 6)

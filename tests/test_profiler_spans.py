@@ -316,6 +316,24 @@ def test_ids_say_what_ran(recorded):
     assert max(e[3]["active"] for e in _named(events, "serve.decode")) == 2
 
 
+def test_params_held_says_what_the_build_did_to_the_weights(tmp_path):
+    """``serve.params_held`` is one instant at build, beside
+    ``serve.cache_spec``: the leaves given, those held in another dtype, and
+    the bytes on each side (float32 compute here: nothing re-typed)."""
+    model, variables = _model()
+    with profiled(tmp_path):
+        eng, _ = _serving(model, variables)
+    events = hetu_threads(tmp_path)[0]
+    (held,) = _named(events, "serve.params_held")
+    leaves = jax.tree_util.tree_leaves(eng.params)
+    nbytes = sum(a.nbytes for a in leaves)
+    assert held[3] == {"leaves": len(leaves), "retyped": 0,
+                       "bytes_given": nbytes, "bytes_held": nbytes}
+    names = [e[0] for e in events]
+    assert names.index("serve.params_held") \
+        == names.index("serve.cache_spec") + 1
+
+
 def test_cache_ids_say_what_a_call_holds(tmp_path):
     """``serve.cache_spec`` carries the pools' bytes, and each launch the
     bytes ONE cache layer's gathered view holds in that call (K and V):
