@@ -337,47 +337,84 @@ class HeldExpertLayer:
     ``held = (first, count)``; the ``n_zero`` behind them are identity
     experts, which every chip computes for its own tokens.  For tokens ``u``::
 
-        s = softmax(float32(u) @ float32(router))          # all experts
+        s = score(float32(u) @ float32(router))            # all experts
         chosen: the k largest of s + router_bias            # bias: choice only
-        i held:      + scaling * s_i * W_down_i(silu(W_gate_i u) * W_up_i u)
-        i identity:  + scaling * s_i * u
+        w_i = scaling * s_i            (or, renormalised, over the chosen k:
+              scaling * s_i / (sum of the chosen s_j + 1e-20))
+        i held:      + w_i * W_down_i(silu(W_gate_i u) * W_up_i u)
+        i identity:  + w_i * u
         i absent:    nothing (that chip adds it; nothing stands in for it)
+        shared:      + W_down(silu(W_gate u) * W_up u)      # every token
 
-    The weights are not renormalised over the chosen k.  The identity
-    experts are one weighted sum of ``u``, never a matmul; the held experts'
-    work follows the pairs routed to them (``ops.moe_ops.held_expert_ffn``).
-    Parameters (one layer's): ``router`` [H, n_routed + n_zero] float32,
-    ``router_bias`` [n_routed + n_zero] float32, ``gate``/``up`` [count, H,
-    F], ``down`` [count, F, H]."""
+    ``scoring`` is the published rule, ``"softmax"`` over all experts or
+    ``"sigmoid"`` of each; ``renormalise`` whether the chosen weights are
+    divided by their sum (the sum keeps ALL ``k`` chosen scores, the absent
+    experts' too: the router is whole on every chip); ``shared`` whether the
+    layer has a shared expert, one more dense SwiGLU that every chip
+    computes for its own tokens and that is no part of the routing.  All
+    three are the model's, set from its configuration, not options of a
+    deployment.  The identity experts are one weighted sum of ``u``, never a
+    matmul; the held experts' work follows the pairs routed to them
+    (``ops.moe_ops.held_expert_ffn``).  Parameters (one layer's): ``router``
+    [H, n_routed + n_zero] float32, ``router_bias`` [n_routed + n_zero]
+    float32, ``gate``/``up`` [count, H, F], ``down`` [count, F, H]; with a
+    shared expert ``shared_gate``/``shared_up`` [H, F_s], ``shared_down``
+    [F_s, H]."""
 
     def __init__(self, *, n_routed: int, n_zero: int, k: int, scaling: float,
-                 held: tuple, block_rows: int = 128, dtype=jnp.bfloat16):
+                 held: tuple, block_rows: int = 128, dtype=jnp.bfloat16,
+                 scoring: str = "softmax", renormalise: bool = False,
+                 shared: bool = False):
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
         self.n_routed, self.n_zero, self.k = n_routed, n_zero, k
         self.scaling = float(scaling)
         self.first, self.count = held
         self.block_rows = block_rows
         self.dtype = dtype
+        self.scoring, self.renormalise, self.shared = \
+            scoring, bool(renormalise), bool(shared)
 
     def route(self, p, tokens):
         """tokens [T, H] -> (weights [T, k] float32, scaling included,
         idx [T, k])."""
         with jax.named_scope("hetu.moe.route"):
-            scores = jax.nn.softmax(
-                jnp.dot(tokens.astype(jnp.float32), p["router"],
-                        precision=jax.lax.Precision.HIGHEST), axis=-1)
+            logits = jnp.dot(tokens.astype(jnp.float32), p["router"],
+                             precision=jax.lax.Precision.HIGHEST)
+            scores = jax.nn.softmax(logits, axis=-1) \
+                if self.scoring == "softmax" else jax.nn.sigmoid(logits)
             w, idx = route_biased_top_k(scores, p["router_bias"], self.k)
+            if self.renormalise:
+                w = w / (w.sum(-1, keepdims=True) + 1e-20)
             return w * self.scaling, idx
+
+    def shared_expert(self, p, tokens, layer=None):
+        """The shared expert's part for tokens [T, H], float32 [T, H]."""
+        dt = self.dtype
+
+        def of(name):
+            w = p[name] if layer is None else p[name][layer]
+            return w.astype(dt)
+
+        with jax.named_scope("hetu.moe.shared"):
+            x = tokens.astype(dt)
+            h = jax.nn.silu(jnp.dot(x, of("shared_gate"))) \
+                * jnp.dot(x, of("shared_up"))
+            return jnp.dot(h, of("shared_down"),
+                           preferred_element_type=jnp.float32)
 
     def apply(self, p, u, *, static_trip: bool = False, layer=None):
         """u [..., H] -> (m [..., H] in u's dtype, counts [4] int32 in
         ``MOE_STATS`` order).  With ``layer`` given, ``gate``/``up``/``down``
-        are stacked over layers and this is layer ``layer`` of them."""
+        (and the shared expert's three) are stacked over layers and this is
+        layer ``layer`` of them."""
         tokens = u.reshape(-1, u.shape[-1])
         w, idx = self.route(p, tokens)
         zero = idx >= self.n_routed
-        with jax.named_scope("hetu.moe.zero"):
-            out = jnp.sum(jnp.where(zero, w, 0.0), -1, keepdims=True) \
-                * tokens.astype(jnp.float32)
+        if self.n_zero:
+            with jax.named_scope("hetu.moe.zero"):
+                out = jnp.sum(jnp.where(zero, w, 0.0), -1, keepdims=True) \
+                    * tokens.astype(jnp.float32)
         with jax.named_scope("hetu.moe.experts"):
             routed, per_expert = held_expert_ffn(
                 tokens.astype(self.dtype), w, idx, p["gate"], p["up"],
@@ -387,4 +424,7 @@ class HeldExpertLayer:
         n_zero = zero.sum().astype(jnp.int32)
         stats = jnp.stack([n_held, n_zero, idx.size - n_held - n_zero,
                            (per_expert > 0).sum().astype(jnp.int32)])
-        return (out + routed).astype(u.dtype).reshape(u.shape), stats
+        out = out + routed if self.n_zero else routed
+        if self.shared:
+            out = out + self.shared_expert(p, tokens, layer)
+        return out.astype(u.dtype).reshape(u.shape), stats

@@ -64,7 +64,7 @@ from hetu_tpu.ops.moe_ops import (
 )
 from hetu_tpu.ops.attention import (
     attention, cache_update, causal_attention, chunk_attention,
-    decode_attention, read_cache_layer, scan_cached_layers,
+    decode_attention, read_cache_layer, ring_update, scan_cached_layers,
     write_cache_layer,
 )
 from hetu_tpu.ops.graph_ops import (

@@ -29,9 +29,15 @@ def attention(q, k, v, *, mask=None, scale=None):
     return jnp.einsum("...qk,...kd->...qd", probs, v)
 
 
-def causal_attention(q, k, v, *, scale=None):
+def causal_attention(q, k, v, *, scale=None, window=None):
+    """``window``: a query sees the last ``window`` keys only, itself
+    included (key ``j`` for a query at ``i`` when ``0 <= i - j < window``);
+    None: every key up to itself."""
     s_q, s_k = q.shape[-2], k.shape[-2]
     mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                          k=s_k - s_q - int(window))
     return attention(q, k, v, mask=mask, scale=scale)
 
 
@@ -110,7 +116,127 @@ def scan_cached_layers(step, blocks, h, k_cache, v_cache, at, n: int):
     return carry
 
 
-def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):
+KEY_BLOCK = 1024     # keys walked at a time over a view longer than this
+
+
+def ring_update(k_ring, v_ring, k_new, v_new, starts):
+    """Write a step's new K/V rows into each sequence's RING view (a window
+    group's gathered pages: row ``r`` of the ``R`` holds the position ``p =
+    r (mod R)`` written last).  k_ring/v_ring: [B, R, kv_heads, D];
+    k_new/v_new: [B, S, kv_heads, D], row ``i`` at position ``starts[b] +
+    i``; starts: [B] int32.  ``R >= window - 1 + S``, so the rows written
+    over are behind every query's window.  Returns the updated views."""
+    r = k_ring.shape[1]
+    at = (starts[:, None] + jnp.arange(k_new.shape[1])) % r      # [B, S]
+    write = jax.vmap(lambda c, n, i: c.at[i].set(n))
+    return write(k_ring, k_new, at), write(v_ring, v_new, at)
+
+
+def _ring_positions(r: int, last):
+    """The position each row of a ring of ``r`` rows holds once position
+    ``last`` [B] has been written: the newest ``p <= last`` with ``p = row
+    (mod r)``; negative where no such position exists yet.  [B, r]."""
+    rows = jnp.arange(r)[None, :]
+    return last[:, None] - (last[:, None] - rows) % r
+
+
+def _grouped(q, n_kv: int):
+    """q [B, heads, S, D] -> [B, kv_heads, heads / kv_heads, S, D]: query
+    head ``h`` reads KV head ``h // rep``, so the view is never repeated."""
+    b, nh, s, d = q.shape
+    return q.reshape(b, n_kv, nh // n_kv, s, d)
+
+
+def _attend_seen(q, k_cache, v_cache, seen, scale):
+    """Softmax attention of q [B, heads, S, D] over a whole time-major view
+    [B, T, kv_heads, D] under the mask ``seen`` [B, S, T], heads grouped.
+    Returns [B, heads, S, D]."""
+    b, nh, s, d = q.shape
+    scores = jnp.einsum("bgrsd,btgd->bgrst", _grouped(q, k_cache.shape[2]),
+                        k_cache, preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(seen[:, None, None], scores,
+                       jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
+    out = jnp.einsum("bgrst,btgd->bgrsd", probs, v_cache)
+    return out.reshape(b, nh, s, v_cache.shape[-1])
+
+
+def _attend_one_query(q, k_rows, v_rows, g: int, seen, scale):
+    """Softmax attention of ONE query a sequence, q [B, heads, 1, D], over a
+    time-major view of ``g`` KV heads under the mask ``seen`` [B, T], heads
+    grouped, with the view read as the FLAT rows it is gathered in, k_rows /
+    v_rows [B, T, g * D]: the queries are laid out block-diagonally ([g * D,
+    heads], head ``h``'s query in the rows of the KV head it reads, zeros
+    elsewhere), so scores and weighted sum are two plain matmuls over the
+    rows and the view is neither split by head nor relaid (as ``[B, T, g,
+    D]`` the view is tiled another way than its pages, and as ``[B, g, T,
+    D]`` operands it is transposed: either is a copy of the whole view, a
+    quarter of a long decode round; my chip run, PERF.md PR 32).  The zeros
+    cost ``g`` times the multiplications, which one query a sequence can
+    afford.  Returns [B, heads, 1, D]."""
+    b, nh, _, d = q.shape
+    eye = jnp.eye(g, dtype=q.dtype)
+    q_blocks = jnp.einsum("bgrd,gh->bgdhr", q.reshape(b, g, nh // g, d),
+                          eye).reshape(b, g * d, nh)
+    scores = jnp.einsum("btk,bkh->bht", k_rows, q_blocks,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(seen[:, None, :], scores,
+                       jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_rows.dtype)
+    dv = v_rows.shape[-1] // g
+    out = jnp.einsum("bht,btk->bhk", probs, v_rows)
+    # head h's own KV head's columns of its row
+    out = jnp.einsum("bgrhd,gh->bgrd", out.reshape(b, g, nh // g, g, dv),
+                     eye.astype(out.dtype))
+    return out.reshape(b, nh, 1, dv)
+
+
+def _attend_blocks(q, k_cache, v_cache, pos, scale, block: int):
+    """Causal attention of q [B, heads, S, D] at positions ``pos`` [B, S]
+    over a time-major view [B, T, kv_heads, D] with ``T > block``: the keys
+    are walked ``block`` at a time under a running maximum and sum, heads
+    grouped, so neither the [heads, S, T] scores nor a repeated copy of the
+    view is ever whole; and the walk ends at the last key any query can see,
+    so a chunk's cost follows its history and not the width of the table it
+    was handed.  Returns [B, heads, S, D]."""
+    b, nh, s, d = q.shape
+    t, n_kv = k_cache.shape[1], k_cache.shape[2]
+    q5 = _grouped(q, n_kv)
+    rep = nh // n_kv
+    blocks = -(-t // block)
+
+    def step(j, carry):
+        m, l, acc = carry
+        # the last block is moved back to end with the view; the keys it
+        # then shares with the block before are masked out of it
+        at = jnp.minimum(j * block, t - block)
+        k_blk = jax.lax.dynamic_slice_in_dim(k_cache, at, block, 1)
+        v_blk = jax.lax.dynamic_slice_in_dim(v_cache, at, block, 1)
+        key = at + jnp.arange(block)
+        seen = ((key[None, None, :] <= pos[:, :, None])
+                & (key >= j * block)[None, None, :])[:, None, None]
+        scores = jnp.einsum("bgrsd,btgd->bgrst", q5, k_blk,
+                            preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(seen, scores, -1e30)
+        m_new = jnp.maximum(m, scores.max(-1))
+        alpha = jnp.exp(m - m_new)
+        probs = jnp.where(seen, jnp.exp(scores - m_new[..., None]), 0.0)
+        l = l * alpha + probs.sum(-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrst,btgd->bgrsd", probs.astype(v_cache.dtype), v_blk,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    carry = (jnp.full((b, n_kv, rep, s), -1e30, jnp.float32),
+             jnp.zeros((b, n_kv, rep, s), jnp.float32),
+             jnp.zeros((b, n_kv, rep, s, v_cache.shape[-1]), jnp.float32))
+    trips = jnp.minimum(jnp.max(pos) // block + 1, blocks)
+    _, l, acc = jax.lax.fori_loop(0, trips, step, carry)
+    return (acc / l[..., None]).astype(v_cache.dtype).reshape(
+        b, nh, s, v_cache.shape[-1])
+
+
+def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
     """Multi-token chunk attention against a cache (the chunked-prefill /
     prefix-sharing core, GQA-aware).
 
@@ -127,16 +253,39 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):
     With ``starts == 0`` and S_c == T this reduces to causal attention —
     the property the engine's token parity with the training forward
     rides on.
+
+    kv_heads < heads (GQA): the query heads are grouped by the KV head they
+    read and the view is used as it is, never repeated.  A view longer than
+    ``KEY_BLOCK`` is walked in key blocks under a running softmax, as far
+    as the chunk's last position (:func:`_attend_blocks`).
+
+    ``window``: the layer sees the last ``window`` positions only, and the
+    cache is the window group's RING of ``T`` rows (:func:`ring_update`
+    wrote the chunk into it): row ``r`` holds the newest position ``p = r
+    (mod T)``, and query ``i`` sees it when ``0 <= starts[b] + i - p <
+    window``.  A window layer reads its ring, never the full table.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     nh, nkv = q.shape[1], k_cache.shape[2]
+    if window is not None or nkv != nh or k_cache.shape[1] > KEY_BLOCK:
+        t, s_c = k_cache.shape[1], q.shape[-2]
+        pos = starts[:, None] + jnp.arange(s_c)              # [B, S_c]
+        if window is not None:
+            held = _ring_positions(t, starts + s_c - 1)      # [B, T]
+            back = pos[:, :, None] - held[:, None, :]
+            seen = (held >= 0)[:, None, :] & (back >= 0) \
+                & (back < int(window))
+            return _attend_seen(q, k_cache, v_cache, seen, scale)
+        if t > KEY_BLOCK:
+            return _attend_blocks(q, k_cache, v_cache, pos, scale,
+                                  KEY_BLOCK)
+        return _attend_seen(q, k_cache, v_cache,
+                            jnp.arange(t)[None, None, :] <= pos[:, :, None],
+                            scale)
+    # as many KV heads as query heads over a short view: as it always was
     k = jnp.moveaxis(k_cache, 1, 2)  # [B, kv_heads, T, D]
     v = jnp.moveaxis(v_cache, 1, 2)
-    if nkv != nh:
-        rep = nh // nkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     t = k_cache.shape[1]
@@ -149,16 +298,26 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def decode_attention(q, k_cache, v_cache, lengths, *, scale=None):
+def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
+                     window=None, kv_heads=None):
     """Single-token attention against a cache (GQA-aware).
 
     q: [B, heads, 1, D] — the newest token's query, already positioned at
     index ``lengths[b]`` in its sequence (so its K/V must have been written
     via :func:`cache_update` first).  k_cache/v_cache: [B, T, kv_heads, D]
-    with kv_heads dividing heads (kv_heads < heads = GQA; repeats serve
-    each kv head to heads/kv_heads query heads).  lengths: [B] int32 index
-    of the newest token; positions > lengths[b] (unwritten or stale from a
-    previous occupant) are masked out.
+    with kv_heads dividing heads (kv_heads < heads = GQA: the query heads
+    are grouped by the KV head they read and the view is read as the flat
+    rows it is, never repeated nor relaid: :func:`_attend_one_query`).
+    lengths: [B] int32 index of the newest token; positions > lengths[b]
+    (unwritten or stale from a previous occupant) are masked out.
+
+    ``window``: as :func:`chunk_attention`'s — the cache is the window
+    group's ring, written by :func:`ring_update`.
+
+    ``kv_heads``: the caches are given as the FLAT rows their pages hold,
+    [B, T, kv_heads * D] (written by :func:`cache_update` / :func:`ring_update`
+    with flat new rows): what the GQA path reads anyway, and from a paged
+    view without the copy that splitting the rows by head costs.
     """
     if q.shape[-2] != 1:
         raise ValueError(
@@ -166,13 +325,18 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None):
             "(prefill goes through causal_attention over the chunk)")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    nh, nkv = q.shape[1], k_cache.shape[2]
+    nh, nkv = q.shape[1], kv_heads or k_cache.shape[2]
+    if nkv != nh or window is not None or kv_heads:
+        b, t = k_cache.shape[:2]
+        seen = jnp.arange(t)[None, :] <= lengths[:, None]   # [B, T]
+        if window is not None:
+            held = _ring_positions(t, lengths)               # [B, T]
+            seen = (held >= 0) & (lengths[:, None] - held < int(window))
+        return _attend_one_query(q, k_cache.reshape(b, t, -1),
+                                 v_cache.reshape(b, t, -1), nkv, seen, scale)
+    # as many KV heads as query heads: as it always was
     k = jnp.moveaxis(k_cache, 1, 2)  # [B, kv_heads, T, D]
     v = jnp.moveaxis(v_cache, 1, 2)
-    if nkv != nh:
-        rep = nh // nkv
-        k = jnp.repeat(k, rep, axis=1)
-        v = jnp.repeat(v, rep, axis=1)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     t = k_cache.shape[1]

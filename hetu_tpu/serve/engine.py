@@ -1,6 +1,6 @@
 """Chunked prefill + single-token decode over the model forwards.
 
-One engine, :class:`PagedServeEngine`, over the paged pool (page tables,
+One engine, :class:`PagedServeEngine`, over the paged pools (page tables,
 prefix sharing, chunked prefill — see kv_cache.py).
 
 What a served model implements: ``prefill_chunk_with_cache`` and
@@ -8,7 +8,11 @@ What a served model implements: ``prefill_chunk_with_cache`` and
 ``k_cache`` / ``v_cache`` arguments and return them beside the logits),
 and either ``kv_cache_spec()`` or the config fields
 :meth:`KVCacheSpec.from_model` reads.  ``models/gpt.py``,
-``models/llama.py`` and ``models/longcat_flash.py`` do.  Optionally
+``models/llama.py``, ``models/longcat_flash.py`` and
+``models/exaone_moe.py`` do.  A model whose spec states several GROUPS of
+cache layers (window layers beside full ones) is handed a tuple of cache
+layers in each place, one a group in the spec's order, and returns the
+same.  Optionally
 ``serving_params(params)``: the parameters as those two entry points READ
 them (a leaf they read only as ``astype(compute dtype)`` in that dtype, a
 leaf they read in two dtypes held in both, the rest as given).  The engine
@@ -118,6 +122,15 @@ def _params_held(given, held) -> dict:
         "bytes_given": nbytes(given), "bytes_held": nbytes(held)}
 
 
+def _pools(layers):
+    """The pool(s) of what a cache entry point returned in the place of its
+    cache layers: one :class:`PagedLayers`' pool, or a tuple of them, one a
+    group."""
+    if isinstance(layers, PagedLayers):
+        return layers.pool
+    return tuple(g.pool for g in layers)
+
+
 class _PrefillCursor:
     """Host-side state of one in-progress chunked prefill."""
 
@@ -150,7 +163,14 @@ class PagedServeEngine:
 
     Both jitted programs hand the model the pools themselves, with the
     call's page tables and write map (:class:`PagedLayers`), in the places
-    of its ``k_cache`` / ``v_cache`` arguments.  The model carries them
+    of its ``k_cache`` / ``v_cache`` arguments: one :class:`PagedLayers`
+    each for a cache of one group, a tuple of them (one a group, the
+    spec's order) for a model that states several.  A further group's
+    table is of a width fixed at build (a window group's ring: what a
+    chunk, or one decoded token, needs beside its window), packed behind
+    the first group's operands in the same ``aux``, so both programs stay
+    keyed by the first group's shapes alone and a model of one group
+    compiles to the programs it compiled to before groups.  The model carries them
     through its layer scan: each layer gathers its own pages into a view
     ``[b, n_pg * page_size, *row]``, runs its attention step on that view
     and scatters the step's new rows into the carried, donated pool.  No
@@ -159,9 +179,10 @@ class PagedServeEngine:
 
     The scheduler/pool/migration stack drives it through
     :meth:`admission_ok`, :meth:`begin_prefill`, :meth:`prefill_step`
-    (page-budget admission, chunked prefill interleaved with decode),
-    :meth:`decode`, the export/resume/adopt/reindex verbs of a live-slot
-    migration, :meth:`alloc_slot`/:meth:`release`, and the cache's
+    (page-budget admission a group, chunked prefill interleaved with
+    decode), :meth:`decode`, the export/resume/adopt/reindex verbs of a
+    live-slot migration (a grouped cache refuses them:
+    ``kv_cache.GroupedCacheNotPortable``), :meth:`alloc_slot`/:meth:`release`, and the cache's
     ``lengths``/``max_len``/``num_free`` geometry.
 
     Compilation discipline: chunked prefill compiles one executable per
@@ -193,17 +214,18 @@ class PagedServeEngine:
             model, variables, mesh, spec)
         for name, n in held.items():
             self.metrics.set_gauge(f"params_{name}", n)
-        self.cache = PagedKVCache(
-            spec, num_slots, max_len, page_size=page_size,
-            num_pages=num_pages, sharding=cache_sharding,
-            max_prefix_entries=max_prefix_entries if prefix_sharing else 0)
         # chunked prefill: chunk ends align to prefill_chunk boundaries
         # (a multiple of page_size), so chunks fill whole pages and the
         # prefix index's page-aligned entries match cleanly
         if prefill_chunk is None:
             prefill_chunk = max(4 * page_size, min_bucket)
-        ps = self.cache.page_size
+        ps = int(page_size)
         self.prefill_chunk = -(-int(prefill_chunk) // ps) * ps
+        self.cache = PagedKVCache(
+            spec, num_slots, max_len, page_size=page_size,
+            num_pages=num_pages, sharding=cache_sharding,
+            max_prefix_entries=max_prefix_entries if prefix_sharing else 0,
+            step_rows=self.prefill_chunk)
         self.chunk_buckets = _pow2_buckets(
             min(min_bucket, self.prefill_chunk), self.prefill_chunk)
 
@@ -220,17 +242,42 @@ class PagedServeEngine:
         # their logits, in order (an expert model: pairs routed to held,
         # identity and absent experts, held experts hit); most have none
         self._stat_names = tuple(getattr(model, "step_stats", ()))
+        self._released_seen = 0   # of cache.window_released, by _count
+        # the further groups of the model's cache (window layers beside
+        # full ones): the fixed widths of their tables in the chunk programs
+        # and in the decode program (a ring as wide as the step needs)
+        self._more = self.cache.groups[1:]
+        self._ring_chunk = tuple(g.spec.ring_pages(self.prefill_chunk, ps)
+                                 for g in self._more)
+        self._ring_decode = tuple(g.spec.ring_pages(1, ps)
+                                  for g in self._more)
+        # bytes ONE cache layer's view of one page holds, K and V together,
+        # a group: what a call's ``view_bytes`` id multiplies by its pages
+        self._page_view_bytes = tuple(
+            ps * (g.bytes_per_token // g.num_layers) for g in spec.groups)
         k_row, v_row = spec.row_shapes()
-        # bytes ONE cache layer's view of one page holds, K and V together:
-        # what a call's ``view_bytes`` id multiplies by its pages
-        self._page_view_bytes = self.cache.page_size * (
-            spec.bytes_per_token // spec.num_layers)
-        trace.instant("serve.cache_spec", {
-            "k_width": int(np.prod(k_row)), "v_width": int(np.prod(v_row)),
-            "cache_layers": int(spec.num_layers),
-            "bytes_per_token": int(spec.bytes_per_token),
-            "pool_bytes": int(self.cache.k.nbytes + self.cache.v.nbytes)})
+        ids = {"k_width": int(np.prod(k_row)), "v_width": int(np.prod(v_row)),
+               "cache_layers": int(spec.num_layers),
+               "bytes_per_token": int(spec.bytes_per_token),
+               "pool_bytes": int(self.cache.k.nbytes + self.cache.v.nbytes)}
+        for i, g in enumerate(self._more, 1):
+            k_row, v_row = g.spec.row_shapes()
+            ids.update({
+                f"g{i}_k_width": int(np.prod(k_row)),
+                f"g{i}_v_width": int(np.prod(v_row)),
+                f"g{i}_cache_layers": int(g.spec.num_layers),
+                f"g{i}_window": int(g.window or 0),
+                f"g{i}_pool_bytes": int(g.k.nbytes + g.v.nbytes)})
+        trace.instant("serve.cache_spec", ids)
         trace.instant("serve.params_held", held)
+
+    def _pool_args(self):
+        """The pools as the two programs take them: one pair, or with
+        further groups a tuple of each."""
+        if not self._more:
+            return self.cache.k, self.cache.v
+        return (tuple(g.k for g in self.cache.groups),
+                tuple(g.v for g in self.cache.groups))
 
     def _count(self, stats):
         """The counts a step returned beside its tokens, added to the
@@ -244,6 +291,33 @@ class PagedServeEngine:
         for name, n in ids.items():
             self.metrics.inc(name, n)
         return ids
+
+    def _held(self, ids, slots, lengths):
+        """``ids`` (a step's counts, or None) with what a cache of SEVERAL
+        groups holds once ``slots`` stand at ``lengths`` (the step's effect,
+        which ``post`` is about to book): ``kv_pages_full`` /
+        ``kv_pages_window``, the pages the allocated slots' tables hold in
+        the groups that keep every position and in the window groups,
+        ``kv_pages_if_one_group``, what they would hold were every cache
+        layer to keep every position (all three in pages of one cache
+        layer, and gauges of the same names), and ``kv_window_released``,
+        the pages dropped from behind a window since the last call (a
+        counter).  A cache of one group: ``ids`` as given."""
+        if not self._more:
+            return ids
+        after = self.cache.lengths.copy()
+        after[slots] = lengths
+        full, window, one = self.cache.held_layer_pages(after)
+        released = self.cache.window_released
+        held = {"kv_pages_full": full, "kv_pages_window": window,
+                "kv_pages_if_one_group": one}
+        for name, n in held.items():
+            self.metrics.set_gauge(name, n)
+        self.metrics.inc("kv_window_released",
+                         released - self._released_seen)
+        held["kv_window_released"] = released - self._released_seen
+        self._released_seen = released
+        return {**(ids or {}), **held}
 
     # ---- compile accounting ----
     def compiled_executables(self) -> int:
@@ -296,12 +370,16 @@ class PagedServeEngine:
         at its minimum width."""
         model = self.model
         k_row, v_row = self.cache.spec.row_shapes()
+        more = tuple(zip((g.spec.row_shapes() for g in self._more),
+                         self._ring_chunk))
 
         def fn(params, k_pool, v_pool, aux):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
             # operands (ids | write pages | write offsets | page table |
-            # start | last) into one device_put, like the decode step
-            sc = (aux.shape[0] - n_table - 2) // 3
+            # start | last) into one device_put, like the decode step; a
+            # further group appends its own (write pages | ring table)
+            rings = sum(ring for _, ring in more)
+            sc = (aux.shape[0] - n_table - 2 - rings) // (3 + len(more))
             ids = aux[:sc][None]
             # per-token write map: real positions land in their pages,
             # pad positions in scratch 0
@@ -310,57 +388,98 @@ class PagedServeEngine:
             table = aux[3 * sc:3 * sc + n_table][None]
             start = aux[3 * sc + n_table]
             last = aux[3 * sc + n_table + 1]
+            # the pools: one pair, or with further groups a tuple of each
+            k = PagedLayers(k_pool[0] if more else k_pool, table, wpage,
+                            woff, k_row)
+            v = PagedLayers(v_pool[0] if more else v_pool, table, wpage,
+                            woff, v_row)
+            if more:
+                k, v, at = [k], [v], 3 * sc + n_table + 2
+                for i, ((k_r, v_r), ring) in enumerate(more, 1):
+                    wpage = aux[at:at + sc][None]
+                    table = aux[at + sc:at + sc + ring][None]
+                    k.append(PagedLayers(k_pool[i], table, wpage, woff, k_r))
+                    v.append(PagedLayers(v_pool[i], table, wpage, woff, v_r))
+                    at += sc + ring
+                k, v = tuple(k), tuple(v)
             # a model may return a fourth value, its per-call counts
             # (``model.step_stats`` names them); most return none
             logits, k, v, *stats = model.prefill_chunk_with_cache(
-                {"params": params, "state": {}}, ids,
-                PagedLayers(k_pool, table, wpage, woff, k_row),
-                PagedLayers(v_pool, table, wpage, woff, v_row),
+                {"params": params, "state": {}}, ids, k, v,
                 start, last_index=last)
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
-            return k.pool, v.pool, tok, tuple(stats)
+            return _pools(k), _pools(v), tok, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
     def _build_decode(self):
         model = self.model
         k_row, v_row = self.cache.spec.row_shapes()
+        more = tuple(zip((g.spec.row_shapes() for g in self._more),
+                         self._ring_decode))
 
         def fn(params, k_pool, v_pool, aux):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
             # the step (page table | length | token | write page | write
             # offset) into ONE device_put — five small uploads per step
             # cost more wall time than the decode math at serving batch
-            # sizes
-            n_pg = aux.shape[1] - 4
+            # sizes; a further group appends its own (ring table | write
+            # page), of a width fixed at build, so the program stays keyed
+            # by the first group's page bucket and the batch bucket
+            n_pg = aux.shape[1] - 4 - sum(ring + 1 for _, ring in more)
             tables = aux[:, :n_pg]
             lengths = aux[:, n_pg]
             tokens = aux[:, n_pg + 1]
             wpage = aux[:, n_pg + 2:n_pg + 3]
-            woff = aux[:, n_pg + 3:]
+            woff = aux[:, n_pg + 3:n_pg + 4]
             # a layer gathers its pages of the B sequences and scatters B
             # new rows (:class:`PagedLayers`): a decode step moves one
             # layer's view at a time and O(B) rows into the pool, never a
             # view of every layer and never the pool
+            k = PagedLayers(k_pool[0] if more else k_pool, tables, wpage,
+                            woff, k_row)
+            v = PagedLayers(v_pool[0] if more else v_pool, tables, wpage,
+                            woff, v_row)
+            if more:
+                k, v, at = [k], [v], n_pg + 4
+                for i, ((k_r, v_r), ring) in enumerate(more, 1):
+                    tables = aux[:, at:at + ring]
+                    wpage = aux[:, at + ring:at + ring + 1]
+                    k.append(PagedLayers(k_pool[i], tables, wpage, woff, k_r))
+                    v.append(PagedLayers(v_pool[i], tables, wpage, woff, v_r))
+                    at += ring + 1
+                k, v = tuple(k), tuple(v)
             logits, k, v, *stats = model.decode_with_cache(
-                {"params": params, "state": {}}, tokens,
-                PagedLayers(k_pool, tables, wpage, woff, k_row),
-                PagedLayers(v_pool, tables, wpage, woff, v_row), lengths)
+                {"params": params, "state": {}}, tokens, k, v, lengths)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return k.pool, v.pool, nxt, tuple(stats)
+            return _pools(k), _pools(v), nxt, tuple(stats)
 
         return jax.jit(fn, donate_argnums=(1, 2))
 
     # ---- admission (the scheduler's page-budget backpressure) ----
     def admission_pages(self, prompt_len: int, max_tokens: int,
-                        shared_tokens: int = 0) -> int:
-        """Worst-case pages an admission can touch: prompt + generation
-        (capped at max_len) minus already-shared pages, plus one page of
-        copy-on-write headroom."""
+                        shared_tokens: int = 0, group: int = 0) -> int:
+        """Worst-case pages of ``group`` an admission can touch: prompt +
+        generation (capped at max_len) minus already-shared pages, plus one
+        page of copy-on-write headroom.  Of a window group: what it holds at
+        once, at most its ring, plus the same one page."""
         total = min(int(prompt_len) + int(max_tokens) + 1,
                     self.cache.max_len)
-        return max(self.cache.pages_for_tokens(total)
+        pages = self.cache.pages_for_tokens(total)
+        if group:
+            return min(pages, self._ring_chunk[group - 1]) + 1
+        return max(pages
                    - self.cache.pages_for_tokens(int(shared_tokens)), 0) + 1
+
+    def _admission_by_group(self, prompt_len: int, max_tokens: int,
+                            shared_tokens: int = 0) -> tuple:
+        return tuple(self.admission_pages(prompt_len, max_tokens,
+                                          shared_tokens, g)
+                     for g in range(len(self.cache.groups)))
+
+    def _fits(self, need: tuple) -> bool:
+        return all(n <= self.cache.available_pages(g)
+                   for g, n in enumerate(need))
 
     def admission_ok(self, prompt, max_tokens: int) -> bool:
         """True when the page pool can hold this request's worst case
@@ -375,14 +494,13 @@ class PagedServeEngine:
         unless the shared credit is what decides.  When the probe does
         run it is LRU-neutral (``touch=False``): a request must not pin
         index entries it never adopted."""
-        avail = self.cache.available_pages()
-        if self.admission_pages(len(prompt), max_tokens, 0) <= avail:
+        if self._fits(self._admission_by_group(len(prompt), max_tokens)):
             return True
         n_shared, _ = self.cache.match_prefix(prompt, touch=False)
         if not n_shared:
             return False
-        return self.admission_pages(len(prompt), max_tokens,
-                                    n_shared) <= avail
+        return self._fits(self._admission_by_group(len(prompt), max_tokens,
+                                                   n_shared))
 
     # ---- chunked prefill ----
     def begin_prefill(self, slot: int, prompt_ids, *,
@@ -406,18 +524,18 @@ class PagedServeEngine:
         if n >= self.cache.max_len:
             raise ValueError(f"prompt of {n} tokens leaves no room to "
                              f"generate within max_len {self.cache.max_len}")
-        self.cache.reserve(slot, self.admission_pages(n, max_tokens, 0))
+        self.cache.reserve(slot, self._admission_by_group(n, max_tokens))
         self._cursors[slot] = _PrefillCursor(prompt, max_tokens)
         self.active[slot] = False
 
     def _match_on_first_chunk(self, slot: int, cur: _PrefillCursor) -> None:
         cur.matched = True
         n_shared, pages = self.cache.match_prefix(cur.prompt)
-        if n_shared and not self.cache.tables[slot]:
+        if n_shared and not any(g.tables[slot] for g in self.cache.groups):
             self.cache.adopt_prefix(slot, n_shared, pages)
             cur.pos = n_shared
             # shrink the admission's reservation by the shared credit
-            self.cache.reserve(slot, self.admission_pages(
+            self.cache.reserve(slot, self._admission_by_group(
                 cur.n, cur.max_tokens, n_shared))
             self.metrics.inc("prefix_hits")
             self.metrics.inc("prefix_hit_tokens", n_shared)
@@ -432,6 +550,8 @@ class PagedServeEngine:
         cur = self._cursors.get(slot)
         if cur is None:
             raise ValueError(f"slot {slot} has no prefill in progress")
+        if self._more:
+            return self._prefill_step_grouped(slot, cur)
         # one span for the whole chunk, tiled by its four seams: prep is
         # the host's work before the program can be called (prefix match
         # on the first chunk, pages, operands), launch hands the operands
@@ -467,7 +587,7 @@ class PagedServeEngine:
                     trace.instant("serve.recompile",
                                   {"kind": "prefill_chunk", "bucket": s})
                 cow0 = self.cache.cow_copies
-                wp, wo = self.cache.prepare_write(slot, start, size)
+                (wp,), wo = self.cache.prepare_write(slot, start, size)
                 wp, wo = self.cache.padded_write_map(wp, wo, s)
                 aux = np.zeros(3 * s + n_table + 2, np.int32)
                 aux[:size] = cur.prompt[start:end]
@@ -480,12 +600,93 @@ class PagedServeEngine:
             with trace.span("serve.prefill_chunk.launch",
                             {"start": int(start), "tokens": int(size),
                              "bucket": int(s),
-                             "view_bytes": n_table * self._page_view_bytes}):
+                             "view_bytes": n_table * self._page_view_bytes[0]}):
                 k, v, tok, stats = chunk_fn(
                     self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
             with trace.span("serve.prefill_chunk.fetch"):
                 tok = int(tok)  # the host blocked on the device
                 counts = self._count(stats)
+            with trace.span("serve.prefill_chunk.post", counts):
+                self.cache.update(k, v)
+                self.cache.lengths[slot] = end
+                cur.pos = end
+                self.metrics.inc("prefill_tokens", size)
+                self.metrics.inc("prefill_chunks")
+                if self.cache.cow_copies > cow0:
+                    self.metrics.inc("cow_copies",
+                                     self.cache.cow_copies - cow0)
+                if not cur.done:
+                    return None
+                del self._cursors[slot]
+                self.cache.register_prefix(slot, cur.prompt)
+                self.last_tokens[slot] = tok
+                self.active[slot] = True
+                return tok
+
+    def _prefill_step_grouped(self, slot: int, cur) -> Optional[int]:
+        """:meth:`prefill_step` over a cache of several groups: the same
+        four seams, with each further group's write pages and ring table
+        packed behind the first group's operands, the pools handed over as
+        tuples, and the groups' page counts on the ``post`` span."""
+        with trace.span("serve.prefill_chunk", {"slot": int(slot)}):
+            with trace.span("serve.prefill_chunk.prep"):
+                if not cur.matched:
+                    self._match_on_first_chunk(slot, cur)
+                start = cur.pos
+                end = min(cur.n, (start // self.prefill_chunk + 1)
+                          * self.prefill_chunk)
+                size = end - start
+                s = self.chunk_bucket_for(size)
+                ps = self.cache.page_size
+                n_table = self.cache.pages_per_slot
+                # boundary path: the PADDED window [start, start+s) runs past
+                # the slot's own page view — use the extended-view executable
+                # family so nothing clamps (see _build_chunk)
+                if start + s > n_table * ps:
+                    n_table += -(-self.prefill_chunk // ps)
+                    if self._chunk_fn_ext is None:
+                        self._chunk_fn_ext = self._build_chunk(n_table)
+                    chunk_fn = self._chunk_fn_ext
+                else:
+                    if self._chunk_fn is None:
+                        self._chunk_fn = self._build_chunk(n_table)
+                    chunk_fn = self._chunk_fn
+                if (s, n_table) not in self._seen_chunk_buckets:
+                    self._seen_chunk_buckets.add((s, n_table))
+                    self.metrics.inc("prefill_compiles")
+                    trace.instant("serve.recompile",
+                                  {"kind": "prefill_chunk", "bucket": s})
+                cow0 = self.cache.cow_copies
+                pages, wo = self.cache.prepare_write(slot, start, size)
+                wp, wo = self.cache.padded_write_map(pages[0], wo, s)
+                aux = np.zeros(3 * s + n_table + 2 + sum(
+                    s + ring for ring in self._ring_chunk), np.int32)
+                aux[:size] = cur.prompt[start:end]
+                aux[s:2 * s] = wp
+                aux[2 * s:3 * s] = wo
+                t = self.cache.tables[slot]
+                aux[3 * s:3 * s + len(t)] = t
+                aux[3 * s + n_table] = start
+                aux[3 * s + n_table + 1] = size - 1
+                at = 3 * s + n_table + 2
+                for g, wp, ring in zip(self._more, pages[1:],
+                                       self._ring_chunk):
+                    aux[at:at + size] = wp
+                    aux[at + s:at + s + ring] = g.device_table(slot, ring)
+                    at += s + ring
+                k_pool, v_pool = self._pool_args()
+                launch = {"start": int(start), "tokens": int(size),
+                          "bucket": int(s),
+                          "view_bytes": n_table * self._page_view_bytes[0]}
+                for i, ring in enumerate(self._ring_chunk, 1):
+                    launch[f"g{i}_view_bytes"] = \
+                        ring * self._page_view_bytes[i]
+            with trace.span("serve.prefill_chunk.launch", launch):
+                k, v, tok, stats = chunk_fn(
+                    self.params, k_pool, v_pool, jnp.asarray(aux))
+            with trace.span("serve.prefill_chunk.fetch"):
+                tok = int(tok)  # the host blocked on the device
+                counts = self._held(self._count(stats), slot, end)
             with trace.span("serve.prefill_chunk.post", counts):
                 self.cache.update(k, v)
                 self.cache.lengths[slot] = end
@@ -525,6 +726,17 @@ class PagedServeEngine:
         act = np.nonzero(self.active)[0]
         if len(act) == 0:
             return {}
+        if self._more:
+            return self._decode_grouped(act)
+        # a cache of one group takes the round below, kept line for line as
+        # it was before groups.  Folded into ONE round with the groups'
+        # additions under ``if self._more:`` (the same programs, the same
+        # cache keys, every one a cache hit), the first call of every
+        # program was slower on the v5e: warm-up 8.84 -> 12.9-13.2 s in
+        # gpt2-large's cell, 36.6 -> 53.0-53.5 s in LongCat's.  No one
+        # statement carries it: this round with the pools in two locals
+        # 10.25 s, with the launch ids made before the span 9.22 s, the
+        # fold without those locals 12.7 s (PERF.md, PR 32; ROADMAP S11)
         # one span for the whole round, tiled by its four seams: prep builds
         # the step's operands on the host, launch hands them to the device
         # and calls the program, fetch is the host blocked on the device,
@@ -550,7 +762,7 @@ class PagedServeEngine:
                 for i, slot in enumerate(act):
                     p, o = self.cache.prepare_write(
                         int(slot), int(self.cache.lengths[slot]), 1)
-                    wp[i], wo[i] = p[0], o[0]
+                    wp[i], wo[i] = p[0][0], o[0]
                 # page bucket over ACTIVE slots only (after prepare_write
                 # grew them): an inactive mid-chunked-prefill long prompt
                 # must not inflate every interleaved decode's gather to its
@@ -577,13 +789,102 @@ class PagedServeEngine:
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb),
                              "view_bytes": int(bb * n_pg)
-                             * self._page_view_bytes}):
+                             * self._page_view_bytes[0]}):
                 k, v, nxt, stats = self._decode_fn(
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(aux))
             with trace.span("serve.decode.fetch"):
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = self._count(stats)
+            with trace.span("serve.decode.post", counts):
+                self.cache.update(k, v)
+                out = {}
+                for i, slot in enumerate(act):
+                    self.cache.lengths[slot] += 1
+                    self.last_tokens[slot] = nxt[i]
+                    out[int(slot)] = int(nxt[i])
+                if self.cache.cow_copies > cow0:
+                    self.metrics.inc("cow_copies",
+                                     self.cache.cow_copies - cow0)
+                self.metrics.inc("decode_steps")
+                self.metrics.observe_decode(len(out))
+                self.metrics.set_gauge("pages_in_use",
+                                       self.cache.pages_in_use)
+                self.metrics.set_gauge("prefix_entries",
+                                       self.cache.prefix_entries)
+            return out
+
+    def _decode_grouped(self, act) -> dict:
+        """:meth:`decode` over a cache of several groups, ``act`` the active
+        slots: the same four seams, with each further group's ring table
+        and write page packed behind the first group's operands, the pools
+        handed over as tuples, and the groups' page counts on the ``post``
+        span."""
+        with trace.span("serve.decode", {"active": len(act)}):
+            with trace.span("serve.decode.prep"):
+                if (self.cache.lengths[act] >= self.cache.max_len).any():
+                    raise RuntimeError(
+                        "an active slot is at max_len; the scheduler must "
+                        "evict before decoding further")
+                if self._decode_fn is None:
+                    self._decode_fn = self._build_decode()
+                cow0 = self.cache.cow_copies
+                bb = pow2_ceil(len(act), self.cache.num_slots)
+                sl = np.zeros(bb, np.int32)
+                sl[:len(act)] = act
+                # grow/COW the write target of every active slot BEFORE
+                # the step
+                wp = np.zeros((len(self.cache.groups), bb), np.int32)
+                wo = np.zeros(bb, np.int32)
+                for i, slot in enumerate(act):
+                    p, o = self.cache.prepare_write(
+                        int(slot), int(self.cache.lengths[slot]), 1)
+                    wp[:, i], wo[i] = [g[0] for g in p], o[0]
+                # page bucket over ACTIVE slots only (after prepare_write
+                # grew them): an inactive mid-chunked-prefill long prompt
+                # must not inflate every interleaved decode's gather to its
+                # table width — that would re-create exactly the
+                # long-arrival latency spike the chunk interleave exists to
+                # remove
+                n_pg = pow2_ceil(
+                    max(len(self.cache.tables[int(s)]) for s in act),
+                    self.cache.pages_per_slot)
+                if (bb, n_pg) not in self._seen_page_buckets:
+                    self._seen_page_buckets.add((bb, n_pg))
+                    self.metrics.inc("decode_compiles")
+                    trace.instant("serve.recompile",
+                                  {"kind": "decode", "pages": int(n_pg),
+                                   "batch": int(bb)})
+                aux = np.zeros((bb, n_pg + 4 + sum(
+                    ring + 1 for ring in self._ring_decode)), np.int32)
+                for i, slot in enumerate(sl):
+                    t = self.cache.tables[slot][:n_pg]
+                    aux[i, :len(t)] = t
+                aux[:, n_pg] = self.cache.lengths[sl]
+                aux[:, n_pg + 1] = self.last_tokens[sl]
+                aux[:, n_pg + 2] = wp[0]
+                aux[:, n_pg + 3] = wo
+                at = n_pg + 4
+                for g, wp_g, ring in zip(self._more, wp[1:],
+                                         self._ring_decode):
+                    for i, slot in enumerate(act):   # pad rows: scratch
+                        aux[i, at:at + ring] = g.device_table(int(slot), ring)
+                    aux[:, at + ring] = wp_g
+                    at += ring + 1
+                k_pool, v_pool = self._pool_args()
+                launch = {"pages": int(n_pg), "batch": int(bb),
+                          "view_bytes": int(bb * n_pg)
+                          * self._page_view_bytes[0]}
+                for i, ring in enumerate(self._ring_decode, 1):
+                    launch[f"g{i}_view_bytes"] = \
+                        int(bb * ring) * self._page_view_bytes[i]
+            with trace.span("serve.decode.launch", launch):
+                k, v, nxt, stats = self._decode_fn(
+                    self.params, k_pool, v_pool, jnp.asarray(aux))
+            with trace.span("serve.decode.fetch"):
+                nxt = np.asarray(nxt)  # the host blocked on the device
+                counts = self._held(self._count(stats), act,
+                                     self.cache.lengths[act] + 1)
             with trace.span("serve.decode.post", counts):
                 self.cache.update(k, v)
                 out = {}

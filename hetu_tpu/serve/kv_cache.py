@@ -1,8 +1,11 @@
 """The paged KV cache for decoder-LM serving.
 
-:class:`PagedKVCache` holds fixed-size PAGES (``page_size`` tokens) in a
-device-resident pool ``[L, num_pages, page_size, kv_heads * head_dim]``,
-per-request page tables, refcounted PREFIX SHARING (hash-of-token-prefix
+:class:`PagedKVCache` holds fixed-size PAGES (``page_size`` tokens) in
+device-resident pools ``[L, num_pages, page_size, kv_heads * head_dim]``, one
+pair for each GROUP of cache layers the model's :class:`KVCacheSpec` states
+(most models have one group; a model with window layers beside full ones has
+two, and its window group keeps only the pages a future query can still
+see), per-request page tables a group, refcounted PREFIX SHARING (hash-of-token-prefix
 → shared read-only pages, so identical system prompts across a pool's
 traffic dedup to one physical copy) with copy-on-write on the first
 divergent write, and an LRU prefix index whose pages are reclaimed under
@@ -17,7 +20,7 @@ batch to drain.
 
 GQA-aware: the cache stores the model's ``num_kv_heads`` heads un-repeated
 (half or a quarter of the MHA footprint for typical GQA configs);
-``ops.decode_attention`` repeats them at read time.  Works for both
+``ops.decode_attention`` groups the query heads by the KV head they read.  Works for both
 ``GPTConfig`` (kv_heads == num_heads) and ``LlamaConfig``
 (``num_kv_heads <= num_heads``).
 
@@ -56,13 +59,32 @@ class KVCacheSpec:
     ``v_head_dim`` the V pool's (None: the same).  A latent-attention model
     keeps its normalised latent in the K pool and its rotated shared key in
     the V pool, one "head" each.  ``num_layers`` counts CACHE layers, which
-    a model with two attention blocks a layer has twice as many of."""
+    a model with two attention blocks a layer has twice as many of.
+
+    **Groups.**  A model whose cache layers are not all of one kind states
+    the further kinds under ``also``, each a spec of its own (its cache
+    layers, its row shapes, its ``window``): :attr:`groups` is this spec
+    followed by them, and the cache holds a pool pair and a page table a
+    slot for each.  ``window``: the layers of the group see the last
+    ``window`` positions only (a query at ``i`` the keys ``j`` with ``0 <=
+    i - j < window``), so the cache keeps the pages a future query can still
+    see and drops the rest as a slot advances; None: every position.  The
+    first group is the one whose tables grow with the sequence: the
+    engine's page buckets and ``pages_per_slot`` are its."""
 
     num_layers: int
     num_kv_heads: int
     head_dim: int
     dtype: object = jnp.float32
     v_head_dim: Optional[int] = None
+    window: Optional[int] = None
+    also: tuple = ()
+
+    @property
+    def groups(self) -> tuple:
+        """Every group's own spec, this one's first."""
+        return (replace(self, also=()),) + tuple(self.also) \
+            if self.also else (self,)
 
     @property
     def v_dim(self) -> int:
@@ -76,10 +98,20 @@ class KVCacheSpec:
 
     @property
     def bytes_per_token(self) -> int:
-        """Bytes one cached token takes over all cache layers."""
+        """Bytes one cached token takes over this group's cache layers."""
         return (self.num_layers * self.num_kv_heads
                 * (self.head_dim + self.v_dim)
                 * np.dtype(self.dtype).itemsize)
+
+    def ring_pages(self, rows: int, page_size: int) -> Optional[int]:
+        """Pages a slot of a window group holds at most while a step writes
+        ``rows`` new rows: the window behind the first of them, the rows
+        themselves, and one page more because neither end need fall on a
+        page's edge.  The fixed width of the group's table in a program;
+        None for a group that keeps every position."""
+        if self.window is None:
+            return None
+        return -(-(self.window - 1 + int(rows)) // page_size) + 1
 
     @staticmethod
     def from_model(model) -> "KVCacheSpec":
@@ -193,23 +225,148 @@ class PagePoolExhausted(RuntimeError):
     loop."""
 
 
+class GroupedCacheNotPortable(RuntimeError):
+    """A cache of more than one group was asked to export or import live
+    slots.  The wire form (:class:`KVSlotSnapshot`, ``serve/migrate.py``) is
+    one ``[layers, length, heads, width]`` pair a slot; a window group's
+    slot holds the rows of its last pages only, under another layer count,
+    and no peer could adopt them through that form.  Such a cache's requests
+    move by re-prefill, not by their rows:
+    ``ContinuousBatchingScheduler.export_inflight_with_slots`` catches this
+    error and hands the requests over folded (``slot=None``, no snapshot),
+    so a drain or a planned migration completes at a prefill a request."""
+
+
 @dataclass
 class _PrefixEntry:
     """One cached token-prefix: ``pages`` hold the K/V of the first
-    ``n_tokens`` tokens whose sha256 is ``key``.  Entries hold an INDEX
-    reference on each page (``ref_index``); pages referenced only by the
-    index are reclaimable under pressure (LRU eviction)."""
+    ``n_tokens`` tokens whose sha256 is ``key``: for each group ``(first,
+    pages)``, the pages of logical indices ``first, first + 1, ...`` (a
+    group that keeps every position: all of them from 0; a window group:
+    those a continuation from ``n_tokens - 1`` on can still see).  Entries
+    hold an INDEX reference on each page (``ref_index``); pages referenced
+    only by the index are reclaimable under pressure (LRU eviction)."""
 
     key: bytes
     pages: tuple
     n_tokens: int
 
 
-class PagedKVCache:
-    """Paged K/V pool + per-slot page tables + refcounted prefix sharing.
+class _PageGroup:
+    """One group of cache layers in a :class:`PagedKVCache`: its pool pair,
+    its pages' two refcounts and free list, and each slot's page table and
+    reservation.  ``tables[slot]`` are the slot's pages of logical indices
+    ``base[slot], base[slot] + 1, ...`` (logical page ``i`` holds positions
+    ``[i * page_size, (i + 1) * page_size)``): ``base`` stays 0 in a group
+    that keeps every position and advances in a window group as pages fall
+    behind the window."""
 
-    ``k``/``v``: ``[L, num_pages, page_size, kv_heads * head_dim]`` jax
-    arrays, replaced wholesale by the engine after each jitted step.  A
+    def __init__(self, spec: KVCacheSpec, num_slots: int, num_pages: int,
+                 page_size: int, sharding):
+        if num_pages < 2:
+            raise ValueError("need >= 2 pages (page 0 is scratch)")
+        self.spec = spec
+        self.window = spec.window
+        self.num_pages = int(num_pages)
+        k_row, v_row = spec.row_shapes()
+        lead = (spec.num_layers, self.num_pages, page_size)
+        self.k = jnp.zeros(lead + (int(np.prod(k_row)),), spec.dtype)
+        self.v = jnp.zeros(lead + (int(np.prod(v_row)),), spec.dtype)
+        if sharding is not None:
+            self.k = jax.device_put(self.k, sharding)
+            self.v = jax.device_put(self.v, sharding)
+        self.tables: list = [[] for _ in range(num_slots)]
+        self.base = np.zeros(num_slots, np.int64)
+        self.ref_table = np.zeros(self.num_pages, np.int32)
+        self.ref_index = np.zeros(self.num_pages, np.int32)
+        # LIFO: recently-touched pages stay hot.
+        # Page 0 excluded — the scratch page is never allocated.
+        self.free_pages = list(range(self.num_pages - 1, 0, -1))
+        self.reserve = np.zeros(num_slots, np.int32)
+        # a window group's claim is of pages held AT ONCE: a page dropped
+        # from behind the window gives its claim back, up to this many
+        self.reserve_cap = np.zeros(num_slots, np.int32)
+        self.released = 0        # pages dropped from behind a window
+        self.copy_fn = None      # lazily jitted page copy (COW)
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.num_pages - 1 - len(self.free_pages)
+
+    @property
+    def reclaimable_pages(self) -> int:
+        return int(np.sum((self.ref_table == 0) & (self.ref_index > 0)))
+
+    def available_pages(self) -> int:
+        return (len(self.free_pages) + self.reclaimable_pages
+                - int(self.reserve.sum()))
+
+    def unref_table(self, page: int) -> None:
+        self.ref_table[page] -= 1
+        if self.ref_table[page] < 0:
+            raise AssertionError(f"page {page} table-ref underflow")
+        if self.ref_table[page] == 0 and self.ref_index[page] == 0:
+            self.free_pages.append(page)
+
+    def unref_index(self, page: int) -> None:
+        self.ref_index[page] -= 1
+        if self.ref_table[page] == 0 and self.ref_index[page] == 0:
+            self.free_pages.append(page)
+
+    def clear_slot(self, slot: int) -> None:
+        for page in self.tables[slot]:
+            self.unref_table(page)
+        self.tables[slot] = []
+        self.base[slot] = 0
+        self.reserve[slot] = self.reserve_cap[slot] = 0
+
+    def release_behind(self, slot: int, next_position: int,
+                       page_size: int) -> None:
+        """Drop the slot's table reference to every page that lies wholly
+        behind the window of every query from ``next_position`` on (its
+        last position ``<= next_position - window``): freed, unless the
+        prefix index holds it."""
+        keep_from = max(next_position - self.window + 1, 0) // page_size
+        table = self.tables[slot]
+        n = min(max(keep_from - int(self.base[slot]), 0), len(table))
+        for page in table[:n]:
+            self.unref_table(page)
+        del table[:n]
+        self.base[slot] = keep_from if not table else self.base[slot] + n
+        self.released += n
+        self.reserve[slot] = min(self.reserve[slot] + n,
+                                 self.reserve_cap[slot])
+
+    def device_table(self, slot: int, width: int) -> np.ndarray:
+        """The slot's table as a program takes it, ``width`` columns
+        (scratch-padded).  A group that keeps every position: its first
+        ``width`` pages in order.  A window group: a RING, logical page
+        ``i`` in column ``i % width``, so that the gathered view's row ``r``
+        holds the position ``p = r (mod width * page_size)`` that was
+        written last."""
+        out = np.zeros(width, np.int32)
+        table = self.tables[slot]
+        if self.window is None:
+            t = table[:width]
+            out[:len(t)] = t
+            return out
+        if len(table) > width:
+            raise AssertionError(
+                f"slot {slot} holds {len(table)} window pages, the "
+                f"program's ring has {width}")
+        for i, page in enumerate(table):
+            out[(int(self.base[slot]) + i) % width] = page
+        return out
+
+
+class PagedKVCache:
+    """Paged K/V pools + per-slot page tables + refcounted prefix sharing,
+    for each GROUP of cache layers the spec states (``spec.groups``; most
+    models have one).
+
+    A group's ``k``/``v``: ``[L, num_pages, page_size, kv_heads *
+    head_dim]`` jax arrays over that group's cache layers, replaced
+    wholesale by the engine after each jitted step.  A
     token's row is held FLAT: with a ``[kv_heads, head_dim]`` minor pair of
     (20, 64) the TPU's own layout of the array put the PAGE axis in the
     lanes (least padding), and every gather or scatter by page then cost a
@@ -220,6 +377,18 @@ class PagedKVCache:
     with fixed shapes, and inactive slots' (masked, garbage) writes need
     a harmless landing zone — page 0 is never allocated to a request.
 
+    ``k``, ``v``, ``tables``, ``num_pages``, ``pages_per_slot``,
+    ``ref_table``, ``ref_index`` and ``_reserve`` are the FIRST group's: the
+    one whose tables grow with the sequence, and the only one of a model
+    with one kind of cache layer, for which this class is what it was
+    before groups.  A WINDOW group (``spec.window``) keeps, for each slot,
+    the pages that a query at the slot's next position or later can still
+    see, and the pages of the step being written; :meth:`prepare_write`
+    drops the table's reference to the rest as the slot advances
+    (``window_released``).  Its table in a program is a ring of fixed
+    width (:meth:`KVCacheSpec.ring_pages`), so the programs' shapes follow
+    the first group's page count alone.
+
     Ownership model: each page carries two refcounts — ``ref_table``
     (how many slot page-tables reference it) and ``ref_index`` (how many
     prefix-index entries do).  A page is WRITABLE by a slot only when it
@@ -229,19 +398,24 @@ class PagedKVCache:
     and a forked request can never corrupt its sibling's (or the
     cache's) prefix.  A page returns to the free list when BOTH counts
     reach zero; eviction of LRU index entries under allocation pressure
-    is what turns "referenced only by the index" into free pages.
+    is what turns "referenced only by the index" into free pages.  A
+    prefix entry holds pages of EVERY group (of a window group those of
+    the prefix's last ``window`` positions), and is made only where every
+    group still holds them, so a hit always finds what the continuation
+    reads.
 
     Reservations: :meth:`reserve`/``reserved_remaining`` implement the
     scheduler's page-budget backpressure — an admission reserves the
-    worst-case pages its request can touch (prompt + generation + one
-    COW), and :meth:`available_pages` nets free + reclaimable pages
+    worst-case pages its request can touch in each group (prompt +
+    generation + one COW; of a window group the ring + one COW), and
+    :meth:`available_pages` nets a group's free + reclaimable pages
     against outstanding reservations so admissions cannot oversubscribe
-    the pool out from under running decodes.
+    a pool out from under running decodes.
     """
 
     def __init__(self, spec: KVCacheSpec, num_slots: int, max_len: int, *,
                  page_size: int = 16, num_pages=None, sharding=None,
-                 max_prefix_entries: int = 256):
+                 max_prefix_entries: int = 256, step_rows: int = 1):
         if num_slots < 1 or max_len < 2:
             raise ValueError(f"need >=1 slot and max_len >= 2, got "
                              f"{num_slots}/{max_len}")
@@ -256,25 +430,20 @@ class PagedKVCache:
             # default: every slot can reach max_len at once, plus the
             # scratch page
             num_pages = 1 + self.num_slots * self.pages_per_slot
-        self.num_pages = int(num_pages)
-        if self.num_pages < 2:
-            raise ValueError("need >= 2 pages (page 0 is scratch)")
-        k_row, v_row = spec.row_shapes()
-        lead = (spec.num_layers, self.num_pages, self.page_size)
-        self.k = jnp.zeros(lead + (int(np.prod(k_row)),), spec.dtype)
-        self.v = jnp.zeros(lead + (int(np.prod(v_row)),), spec.dtype)
-        if sharding is not None:
-            self.k = jax.device_put(self.k, sharding)
-            self.v = jax.device_put(self.v, sharding)
+        # ``step_rows``: the most rows one step writes a slot (the engine's
+        # prefill chunk), which sizes a window group's ring and pool: every
+        # slot its ring and one copy-on-write, plus the scratch page
+        self.groups = []
+        for g in spec.groups:
+            ring = g.ring_pages(step_rows, self.page_size)
+            self.groups.append(_PageGroup(
+                g, self.num_slots,
+                int(num_pages) if ring is None
+                else 1 + self.num_slots * (min(ring, self.pages_per_slot)
+                                           + 1),
+                self.page_size, sharding))
         self.lengths = np.zeros(self.num_slots, np.int32)
-        self.tables: list = [[] for _ in range(self.num_slots)]
-        self.ref_table = np.zeros(self.num_pages, np.int32)
-        self.ref_index = np.zeros(self.num_pages, np.int32)
         self._free_slots = list(range(self.num_slots - 1, -1, -1))
-        # LIFO: recently-touched pages stay hot.
-        # Page 0 excluded — the scratch page is never allocated.
-        self._free_pages = list(range(self.num_pages - 1, 0, -1))
-        self._reserve = np.zeros(self.num_slots, np.int32)
         self.max_prefix_entries = int(max_prefix_entries)
         from collections import OrderedDict
         self._prefix: "OrderedDict[bytes, _PrefixEntry]" = OrderedDict()
@@ -282,8 +451,16 @@ class PagedKVCache:
         self.cow_copies = 0
         self.prefix_hit_tokens = 0
         self.prefix_evictions = 0
-        self._copy_fn = None     # lazily jitted page copy (COW)
         self._import_fn = None   # lazily jitted page writer (import_slots)
+
+    # ---- the first group's books, under the names they had ----
+    k = property(lambda self: self.groups[0].k)
+    v = property(lambda self: self.groups[0].v)
+    tables = property(lambda self: self.groups[0].tables)
+    num_pages = property(lambda self: self.groups[0].num_pages)
+    ref_table = property(lambda self: self.groups[0].ref_table)
+    ref_index = property(lambda self: self.groups[0].ref_index)
+    _reserve = property(lambda self: self.groups[0].reserve)
 
     # ---- geometry helpers ----
     def pages_for_tokens(self, n: int) -> int:
@@ -296,23 +473,50 @@ class PagedKVCache:
 
     @property
     def pages_in_use(self) -> int:
-        return self.num_pages - 1 - len(self._free_pages)
+        return sum(g.pages_in_use for g in self.groups)
 
     @property
     def reclaimable_pages(self) -> int:
         """Pages held only by the prefix index — allocatable after an
         LRU eviction, so admission counts them as available."""
-        return int(np.sum((self.ref_table == 0) & (self.ref_index > 0)))
+        return sum(g.reclaimable_pages for g in self.groups)
 
-    def available_pages(self) -> int:
-        """Pages an admission may still claim: free + reclaimable, net
-        of every running slot's outstanding reservation."""
-        return (len(self._free_pages) + self.reclaimable_pages
-                - int(self._reserve.sum()))
+    def available_pages(self, group: int = 0) -> int:
+        """Pages of ``group`` an admission may still claim: free +
+        reclaimable, net of every running slot's outstanding
+        reservation."""
+        return self.groups[group].available_pages()
 
     @property
     def occupancy(self) -> float:
-        return self.pages_in_use / max(self.num_pages - 1, 1)
+        return self.pages_in_use / max(
+            sum(g.num_pages - 1 for g in self.groups), 1)
+
+    @property
+    def window_released(self) -> int:
+        """Pages dropped from behind a window so far, all window groups."""
+        return sum(g.released for g in self.groups)
+
+    def held_layer_pages(self, lengths=None) -> tuple:
+        """What the allocated slots' tables hold, in pages of ONE cache
+        layer: (in the groups that keep every position, in the window
+        groups, what the same slots would hold at ``lengths`` (default:
+        their own) were every cache layer of every group to keep every
+        position)."""
+        if lengths is None:
+            lengths = self.lengths
+        live = [s for s in range(self.num_slots)
+                if s not in self._free_slots]
+        full = window = 0
+        for g in self.groups:
+            n = sum(len(g.tables[s]) for s in live) * g.spec.num_layers
+            if g.window is None:
+                full += n
+            else:
+                window += n
+        layers = sum(g.spec.num_layers for g in self.groups)
+        return full, window, layers * sum(
+            self.pages_for_tokens(lengths[s]) for s in live)
 
     # ---- slot lifecycle ----
     def alloc(self) -> int:
@@ -320,8 +524,8 @@ class PagedKVCache:
             raise RuntimeError("paged KV cache has no free slots")
         slot = self._free_slots.pop()
         self.lengths[slot] = 0
-        self.tables[slot] = []
-        self._reserve[slot] = 0
+        for g in self.groups:
+            g.clear_slot(slot)      # a freed slot's books are empty already
         return slot
 
     def free(self, slot: int) -> None:
@@ -329,30 +533,29 @@ class PagedKVCache:
             raise ValueError(f"slot {slot} out of range")
         if slot in self._free_slots:
             raise ValueError(f"slot {slot} double-freed")
-        for page in self.tables[slot]:
-            self._unref_table(page)
-        self.tables[slot] = []
+        for g in self.groups:
+            g.clear_slot(slot)
         self.lengths[slot] = 0
-        self._reserve[slot] = 0
         self._free_slots.append(slot)
 
-    def reserve(self, slot: int, n_pages: int) -> None:
-        """Record the admission's worst-case page claim for ``slot``;
-        every page the slot later allocates draws it down."""
-        self._reserve[slot] = max(int(n_pages), 0)
+    def reserve(self, slot: int, n_pages) -> None:
+        """Record the admission's worst-case page claim for ``slot``, one
+        number a group (a bare number: the first group's); every page the
+        slot later allocates draws it down."""
+        if isinstance(n_pages, (int, np.integer)):
+            n_pages = (n_pages,)
+        for g, n in zip(self.groups, n_pages):
+            g.reserve[slot] = g.reserve_cap[slot] = max(int(n), 0)
 
     def update(self, k, v) -> None:
-        """Swap in the pool arrays a jitted step returned."""
-        self.k, self.v = k, v
+        """Swap in the pool arrays a jitted step returned: one pair, or a
+        sequence of each in the groups' order."""
+        if not isinstance(k, (tuple, list)):
+            k, v = (k,), (v,)
+        for g, k_g, v_g in zip(self.groups, k, v):
+            g.k, g.v = k_g, v_g
 
     # ---- page lifecycle (internal) ----
-    def _unref_table(self, page: int) -> None:
-        self.ref_table[page] -= 1
-        if self.ref_table[page] < 0:
-            raise AssertionError(f"page {page} table-ref underflow")
-        if self.ref_table[page] == 0 and self.ref_index[page] == 0:
-            self._free_pages.append(page)
-
     def _evict_one_entry(self) -> bool:
         """Drop the least-recently-used prefix entry; True if any entry
         was evicted (its index refs released — pages with no table refs
@@ -360,37 +563,38 @@ class PagedKVCache:
         if not self._prefix:
             return False
         _, entry = self._prefix.popitem(last=False)
-        for page in entry.pages:
-            self.ref_index[page] -= 1
-            if self.ref_table[page] == 0 and self.ref_index[page] == 0:
-                self._free_pages.append(page)
+        for g, (_, pages) in zip(self.groups, entry.pages):
+            for page in pages:
+                g.unref_index(page)
         self.prefix_evictions += 1
         return True
 
-    def _alloc_page(self, slot: int) -> int:
-        """Claim a free page for ``slot`` (evicting LRU prefix entries
-        under pressure), charging its reservation."""
-        while not self._free_pages:
+    def _alloc_page(self, slot: int, group: int = 0) -> int:
+        """Claim a free page of ``group`` for ``slot`` (evicting LRU prefix
+        entries under pressure), charging its reservation."""
+        g = self.groups[group]
+        while not g.free_pages:
             if not self._evict_one_entry():
                 raise PagePoolExhausted(
                     "KV page pool exhausted: no free pages and nothing "
                     "reclaimable — an unreserved (adopted) slot decoded "
                     "past the pool, or the scheduler's page budget "
                     "under-reserved")
-        page = self._free_pages.pop()
-        self.ref_table[page] = 1
-        self.ref_index[page] = 0
-        if self._reserve[slot] > 0:
-            self._reserve[slot] -= 1
+        page = g.free_pages.pop()
+        g.ref_table[page] = 1
+        g.ref_index[page] = 0
+        if g.reserve[slot] > 0:
+            g.reserve[slot] -= 1
         return page
 
-    def _cow(self, slot: int, idx: int) -> int:
+    def _cow(self, slot: int, idx: int, group: int = 0) -> int:
         """Copy-on-write: replace ``tables[slot][idx]`` (shared) with a
         private copy; the page bytes move on device (donated, in place
         in the pool)."""
-        src = self.tables[slot][idx]
-        dst = self._alloc_page(slot)
-        if self._copy_fn is None:
+        g = self.groups[group]
+        src = g.tables[slot][idx]
+        dst = self._alloc_page(slot, group)
+        if g.copy_fn is None:
             def copy(k, v, src, dst):
                 k_page = jax.lax.dynamic_slice_in_dim(k, src, 1, axis=1)
                 v_page = jax.lax.dynamic_slice_in_dim(v, src, 1, axis=1)
@@ -400,46 +604,53 @@ class PagedKVCache:
                                                         axis=1)
                 return k, v
 
-            self._copy_fn = jax.jit(copy, donate_argnums=(0, 1))
-        self.k, self.v = self._copy_fn(self.k, self.v, jnp.int32(src),
-                                       jnp.int32(dst))
-        self.tables[slot][idx] = dst
-        self._unref_table(src)
+            g.copy_fn = jax.jit(copy, donate_argnums=(0, 1))
+        g.k, g.v = g.copy_fn(g.k, g.v, jnp.int32(src), jnp.int32(dst))
+        g.tables[slot][idx] = dst
+        g.unref_table(src)
         self.cow_copies += 1
         return dst
 
     def prepare_write(self, slot: int, start: int, n: int):
-        """Make positions ``[start, start + n)`` of ``slot`` writable:
-        append fresh pages as the range grows the table, COW any shared
-        page the range touches.  Returns ``(write_page, write_off)``
-        int32 arrays of length ``n`` mapping each position to its
-        physical (page, offset) — the scatter map the jitted steps take.
-        """
+        """Make positions ``[start, start + n)`` of ``slot`` writable in
+        every group: append fresh pages as the range grows the table, COW
+        any shared page the range touches; a window group first drops the
+        pages that fell behind the window of position ``start``.  Returns
+        ``(write_pages, write_off)``: for each group an int32 array of
+        length ``n`` mapping each position to its physical page, and the
+        offsets in the page, which all groups share — the scatter maps the
+        jitted steps take."""
         ps = self.page_size
         if start + n > self.max_len:
             raise ValueError(f"write [{start}, {start + n}) overruns "
                              f"max_len {self.max_len}")
-        table = self.tables[slot]
-        pages = np.empty(n, np.int32)
-        offs = np.empty(n, np.int32)
-        for i in range(n):
-            pos = start + i
-            pi = pos // ps
-            if pi == len(table):
-                table.append(self._alloc_page(slot))
-            elif pi > len(table):
-                raise AssertionError(
-                    f"write at {pos} skips pages (table has {len(table)})")
-            page = table[pi]
-            if self.ref_table[page] + self.ref_index[page] > 1:
-                page = self._cow(slot, pi)
-            pages[i] = page
-            offs[i] = pos % ps
-        return pages, offs
+        pos = start + np.arange(n)
+        offs = (pos % ps).astype(np.int32)
+        by_group = []
+        for gi, g in enumerate(self.groups):
+            if g.window is not None:
+                g.release_behind(slot, start, ps)
+            table = g.tables[slot]
+            pages = np.empty(n, np.int32)
+            for pi in range(start // ps, (start + n - 1) // ps + 1):
+                idx = pi - int(g.base[slot])
+                if idx == len(table):
+                    table.append(self._alloc_page(slot, gi))
+                elif not 0 <= idx < len(table):
+                    raise AssertionError(
+                        f"write at page {pi} skips pages (table holds "
+                        f"{int(g.base[slot])}..+{len(table)})")
+                page = table[idx]
+                if g.ref_table[page] + g.ref_index[page] > 1:
+                    page = self._cow(slot, idx, gi)
+                pages[max(pi * ps - start, 0):(pi + 1) * ps - start] = page
+            by_group.append(pages)
+        return by_group, offs
 
     def padded_write_map(self, pages, offs, total: int):
-        """Extend a :meth:`prepare_write` map to a padded chunk bucket:
-        pad positions scatter into the scratch page (0, 0)."""
+        """Extend a :meth:`prepare_write` map (one group's pages, the
+        offsets) to a padded chunk bucket: pad positions scatter into the
+        scratch page (0, 0)."""
         n = len(pages)
         wp = np.zeros(total, np.int32)
         wo = np.zeros(total, np.int32)
@@ -467,7 +678,8 @@ class PagedKVCache:
         return out
 
     def match_prefix(self, tokens, *, touch: bool = True):
-        """Longest cached prefix of ``tokens``: ``(n_shared, pages)``.
+        """Longest cached prefix of ``tokens``: ``(n_shared, pages)``,
+        ``pages`` what :meth:`adopt_prefix` takes (the entry's, a group).
 
         Tries the exact-prompt entry first (full dedup — identical
         prompts share even the partial tail page), then page-aligned
@@ -496,16 +708,35 @@ class PagedKVCache:
         return 0, []
 
     def adopt_prefix(self, slot: int, n_shared: int, pages) -> None:
-        """Attach a matched prefix to ``slot``: its table starts as the
-        shared pages (read-only — any write COWs), with ``n_shared``
-        tokens already valid."""
-        if self.tables[slot]:
+        """Attach a matched prefix to ``slot``: each group's table starts
+        as the shared pages (read-only — any write COWs), with
+        ``n_shared`` tokens already valid."""
+        if any(g.tables[slot] for g in self.groups):
             raise ValueError(f"slot {slot} already has pages")
-        self.tables[slot] = list(pages)
-        for page in pages:
-            self.ref_table[page] += 1
+        for g, (first, shared) in zip(self.groups, pages):
+            g.tables[slot] = list(shared)
+            g.base[slot] = first
+            for page in shared:
+                g.ref_table[page] += 1
         self.lengths[slot] = int(n_shared)
         self.prefix_hit_tokens += int(n_shared)
+
+    def _entry_pages(self, slot: int, n_tok: int):
+        """The pages an entry for the first ``n_tok`` tokens of ``slot``
+        holds, ``(first, pages)`` a group, or None where a window group no
+        longer holds what a continuation from ``n_tok - 1`` on would read
+        (a query there sees back to ``n_tok - window``)."""
+        out = []
+        last = self.pages_for_tokens(n_tok)          # pages [0, last)
+        for g in self.groups:
+            first = 0 if g.window is None \
+                else max(n_tok - g.window, 0) // self.page_size
+            lo = first - int(g.base[slot])
+            if lo < 0:
+                return None
+            out.append((first, tuple(
+                g.tables[slot][lo:last - int(g.base[slot])])))
+        return tuple(out)
 
     def register_prefix(self, slot: int, tokens, *,
                         aligned_only: bool = False) -> None:
@@ -522,18 +753,20 @@ class PagedKVCache:
         the very next token and leave a stale never-matching entry."""
         if not self.max_prefix_entries:
             return
-        table = self.tables[slot]
         for n_tok, digest in self._digests(tokens, self.page_size).items():
             if aligned_only and n_tok % self.page_size:
                 continue
             if digest in self._prefix:
                 self._prefix.move_to_end(digest)
                 continue
-            pages = tuple(table[:self.pages_for_tokens(n_tok)])
+            pages = self._entry_pages(slot, n_tok)
+            if pages is None:
+                continue
             self._prefix[digest] = _PrefixEntry(
                 key=digest, pages=pages, n_tokens=int(n_tok))
-            for page in pages:
-                self.ref_index[page] += 1
+            for g, (_, held) in zip(self.groups, pages):
+                for page in held:
+                    g.ref_index[page] += 1
             while len(self._prefix) > self.max_prefix_entries:
                 self._evict_one_entry()
 
@@ -542,6 +775,14 @@ class PagedKVCache:
         return len(self._prefix)
 
     # ---- live-slot migration (serve/migrate.py rides on these) ----
+    def _one_group(self, verb: str) -> None:
+        if len(self.groups) > 1:
+            raise GroupedCacheNotPortable(
+                f"cannot {verb} slots of a cache of {len(self.groups)} "
+                f"groups: a window group holds a slot's last pages only, "
+                f"which the one-pair snapshot cannot carry; requeue the "
+                f"requests instead")
+
     def export_slots(self, slot_ids) -> list:
         """Snapshot occupied slots as CONTIGUOUS truncated K/V rows
         (:class:`KVSlotSnapshot`, the wire form migrate.py's codecs
@@ -549,7 +790,9 @@ class PagedKVCache:
         pages only: sharing means a page can back many slots, but a
         migration payload ships each slot's logical tokens (the adopter
         rebuilds page tables locally; re-dedup on import is the
-        adopter's prefix index's job)."""
+        adopter's prefix index's job).  A cache of several groups
+        refuses (:class:`GroupedCacheNotPortable`)."""
+        self._one_group("export")
         snaps = []
         ps = self.page_size
         for slot in slot_ids:
@@ -579,6 +822,7 @@ class PagedKVCache:
         ``{source_slot: slot}``.  Validates EVERYTHING (geometry, dtype,
         slot and page headroom) before allocating anything — a
         mismatched migration errors loudly and adopts nothing."""
+        self._one_group("import")
         snaps = list(snapshots)
         if len(snaps) > self.num_free:
             raise RuntimeError(
@@ -621,6 +865,7 @@ class PagedKVCache:
 
             self._import_fn = jax.jit(write, donate_argnums=(0, 1))
         ps = self.page_size
+        g = self.groups[0]
         slot_map: dict = {}
         allocated: list = []
         try:
@@ -640,12 +885,12 @@ class PagedKVCache:
                 v_pg = np.zeros((L, pad, ps) + v_row, dt)
                 k_pg.reshape(L, pad * ps, *k_row)[:, :s.length] = s.k
                 v_pg.reshape(L, pad * ps, *v_row)[:, :s.length] = s.v
-                self.k, self.v = self._import_fn(
-                    self.k, self.v,
+                g.k, g.v = self._import_fn(
+                    g.k, g.v,
                     jnp.asarray(k_pg.reshape(L, pad, ps, -1)),
                     jnp.asarray(v_pg.reshape(L, pad, ps, -1)),
                     jnp.asarray(pages))
-                self.tables[slot] = table
+                g.tables[slot] = table
                 self.lengths[slot] = s.length
                 slot_map[s.slot] = slot
         except Exception:

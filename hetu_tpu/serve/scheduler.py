@@ -24,7 +24,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional
 
-from hetu_tpu.serve.kv_cache import PagePoolExhausted
+from hetu_tpu.serve.kv_cache import (GroupedCacheNotPortable,
+                                      PagePoolExhausted)
 from hetu_tpu.telemetry import trace
 
 _ids = itertools.count(1)
@@ -448,12 +449,34 @@ class ContinuousBatchingScheduler:
         the scheduler lock — no decode step can run between the requests
         leaving ``_running`` and their K/V rows being captured, so the
         snapshot and each request's token list always agree.  Returns
-        ``(pairs, snapshots)``."""
+        ``(pairs, snapshots)``.
+
+        An engine whose cache cannot put its slots' rows on the wire (a
+        cache of several groups: ``kv_cache.GroupedCacheNotPortable``)
+        exports as ``fold=True`` does, under the same lock hold: every
+        running request folds its tokens into its prompt, frees its slot
+        and leaves with ``slot=None`` and no snapshot, so the peer
+        re-prefills it.  A drain or a planned migration of such an engine
+        completes; it costs a prefill a request."""
         with self._lock:
             pairs = self._export_locked(fold=False)
             slots = [slot for _, slot in pairs if slot is not None]
             try:
                 snaps = self.engine.export_slots(slots) if slots else []
+            except GroupedCacheNotPortable:
+                # refused before anything was suspended: the running
+                # requests go back to their slots for the length of this
+                # lock hold, and leave again folded
+                for req, slot in pairs:
+                    if slot is not None and not req.done.is_set():
+                        req.slot, req.state = slot, "running"
+                        self._running[slot] = req
+                    elif slot is not None:
+                        self._release_slot_locked(slot)
+                queued = [(req, None) for req, slot in pairs
+                          if slot is None]
+                self.metrics.inc("exports_folded")
+                return self._export_locked(fold=True) + queued, []
             except Exception:
                 # the engine died mid-export: put everything straight
                 # back (same lock hold) — the requests must never end up

@@ -684,3 +684,202 @@ def test_retyped_leaves_keep_the_megatron_placement(kind):
     as_given.params = _DecodeTP().place(variables["params"], mesh)
     assert _engine_greedy(engine, prompt, 6) == \
         _engine_greedy(as_given, prompt, 6)
+
+
+# ---- groups of cache layers: window layers beside full ones (ISSUE 32) ----
+
+def _grouped_cache(*, window=8, slots=3, max_len=64, page=4, step_rows=8,
+                   prefix=256, layers=(1, 2)):
+    from hetu_tpu.serve.kv_cache import KVCacheSpec, PagedKVCache
+    spec = KVCacheSpec(
+        num_layers=layers[0], num_kv_heads=2, head_dim=4, dtype=jnp.float32,
+        also=(KVCacheSpec(num_layers=layers[1], num_kv_heads=2, head_dim=4,
+                          dtype=jnp.float32, window=window),))
+    return PagedKVCache(spec, slots, max_len, page_size=page,
+                        max_prefix_entries=prefix, step_rows=step_rows)
+
+
+def _write(cache, slot, start, n):
+    """What an engine step does to the books: the write map, then the
+    length."""
+    pages, offs = cache.prepare_write(slot, start, n)
+    cache.lengths[slot] = start + n
+    return pages, offs
+
+
+def test_a_window_group_drops_the_pages_behind_the_window():
+    """Window 8 over pages of 4, chunks of 8: the full group's table grows
+    with the sequence, the window group's holds the pages a query at the
+    next position or later can still see, and a dropped page is free
+    again."""
+    cache = _grouped_cache()
+    full, win = cache.groups
+    assert (full.window, win.window) == (None, 8)
+    assert cache.spec.groups[1].ring_pages(8, 4) == 5     # 7 + 8 rows, + 1
+    assert cache.spec.groups[1].ring_pages(1, 4) == 3
+    assert win.num_pages == 1 + 3 * (5 + 1)
+    slot = cache.alloc()
+    for start in range(0, 40, 8):
+        (wp_full, wp_win), offs = _write(cache, slot, start, 8)
+        assert list(offs) == [0, 1, 2, 3] * 2
+        assert len(full.tables[slot]) == (start + 8) // 4
+        # positions > start - 8 are kept while the chunk is written
+        first = max(start - 8 + 1, 0) // 4
+        assert win.base[slot] == first
+        assert len(win.tables[slot]) == (start + 8) // 4 - first <= 5
+        assert list(wp_win[:4]) == [win.tables[slot][start // 4 - first]] * 4
+        assert list(wp_full[4:]) == [full.tables[slot][start // 4 + 1]] * 4
+    assert cache.window_released == win.released == 6
+    assert win.pages_in_use == 4 and full.pages_in_use == 10
+    # decoding on: a page is dropped each time the window leaves it
+    for pos in range(40, 52):
+        _write(cache, slot, pos, 1)
+    assert win.base[slot] == (51 - 8 + 1) // 4 and len(win.tables[slot]) == 2
+    assert cache.held_layer_pages() == (13 * 1, 2 * 2, 13 * 3)
+    cache.free(slot)
+    assert cache.pages_in_use == 0 and not np.any(win.ref_table)
+
+
+def test_a_window_groups_table_is_a_ring_in_the_program():
+    cache = _grouped_cache()
+    win = cache.groups[1]
+    slot = cache.alloc()
+    _write(cache, slot, 0, 8)
+    _write(cache, slot, 8, 8)
+    _write(cache, slot, 16, 8)          # holds logical pages 2..5
+    assert win.base[slot] == 2 and len(win.tables[slot]) == 4
+    ring = win.device_table(slot, 5)
+    for i, page in enumerate(win.tables[slot]):
+        assert ring[(2 + i) % 5] == page
+    assert ring[1] == 0                 # the one column not held: scratch
+    with pytest.raises(AssertionError, match="ring"):
+        win.device_table(slot, 3)
+    full = cache.groups[0]
+    assert list(full.device_table(slot, 8)[:6]) == full.tables[slot]
+
+
+def test_a_prefix_entry_needs_every_groups_pages():
+    """A hit is taken only where every group holds what the continuation
+    will read: entries are made for the prefixes whose window pages the
+    slot still holds, and a match of one hands back pages of both
+    groups."""
+    cache = _grouped_cache()
+    full, win = cache.groups
+    tokens = list(range(100, 130))           # 30 tokens: 7 pages and a tail
+    slot = cache.alloc()
+    for start in (0, 8, 16, 24):
+        _write(cache, slot, start, min(8, 30 - start))
+    cache.register_prefix(slot, tokens)
+    # the window group holds pages 4.. (positions > 24 - 8): entries exist
+    # for the prefixes whose last 8 positions lie in them
+    held = {e.n_tokens for e in cache._prefix.values()}
+    assert held == {24, 28, 30}
+    n, pages = cache.match_prefix(tokens + [7, 8])     # a longer prompt
+    assert n == 28 and [len(p) for _, p in pages] == [7, 2]
+    n, pages = cache.match_prefix(tokens)              # the same prompt
+    assert n == 29                                     # one token prefills
+    (f0, f_pages), (w0, w_pages) = pages
+    assert (f0, w0) == (0, (30 - 8) // 4) and len(f_pages) == 8
+    assert list(w_pages) == win.tables[slot][w0 - int(win.base[slot]):]
+    # a prompt that shares only 20 tokens finds no entry: the pages a
+    # continuation from there would read are gone
+    assert cache.match_prefix(tokens[:20] + [1, 2, 3]) == (0, [])
+    other = cache.alloc()
+    cache.adopt_prefix(other, n, pages)
+    assert win.base[other] == w0 and cache.lengths[other] == 29
+    # the adopter writes on: a shared page is copied first, in both groups
+    cow0 = cache.cow_copies
+    _write(cache, other, 29, 3)
+    assert cache.cow_copies == cow0 + 2
+    assert win.tables[other][-1] != win.tables[slot][-1]
+    for s in (slot, other):
+        cache.free(s)
+    assert cache.pages_in_use == cache.reclaimable_pages > 0
+    while cache._evict_one_entry():
+        pass
+    assert cache.pages_in_use == 0
+    assert not np.any(win.ref_index) and not np.any(full.ref_index)
+
+
+def test_a_grouped_slot_freed_twice_raises():
+    cache = _grouped_cache()
+    slot = cache.alloc()
+    _write(cache, slot, 0, 8)
+    cache.free(slot)
+    with pytest.raises(ValueError, match="double-freed"):
+        cache.free(slot)
+    assert cache.pages_in_use == 0
+
+
+def test_two_full_groups_leave_the_first_groups_books_as_one_groups():
+    """A cache of one group is the cache it was before groups, and a second
+    group changes nothing of the first's books: the same writes, prefix
+    entries and frees give the same tables, refcounts, free list and
+    reservations."""
+    from hetu_tpu.serve.kv_cache import KVCacheSpec, PagedKVCache
+    one = KVCacheSpec(num_layers=2, num_kv_heads=2, head_dim=4,
+                      dtype=jnp.float32)
+    two = KVCacheSpec(num_layers=2, num_kv_heads=2, head_dim=4,
+                      dtype=jnp.float32, also=(one,))
+    assert one.groups == (one,) and two.groups == (one, one)
+    caches = [PagedKVCache(s, 3, 64, page_size=4) for s in (one, two)]
+    tokens = list(range(50, 71))
+    for cache in caches:
+        a = cache.alloc()
+        cache.reserve(a, 9)
+        _write(cache, a, 0, 16)
+        _write(cache, a, 16, 5)
+        cache.register_prefix(a, tokens)
+        b = cache.alloc()
+        n, pages = cache.match_prefix(tokens + [1])
+        cache.adopt_prefix(b, n, pages)
+        _write(cache, b, n, 3)
+        cache.free(a)
+    first, second = caches
+    assert first.k is first.groups[0].k and len(first.groups) == 1
+    assert first.tables == second.tables
+    assert first.groups[0].free_pages == second.groups[0].free_pages
+    for name in ("ref_table", "ref_index", "_reserve", "lengths"):
+        np.testing.assert_array_equal(getattr(first, name),
+                                      getattr(second, name))
+    assert first.available_pages() == second.available_pages(0) \
+        == second.available_pages(1)
+    assert first.cow_copies * 2 == second.cow_copies
+    np.testing.assert_array_equal(np.asarray(first.k),
+                                  np.asarray(second.groups[1].k))
+
+
+def test_admission_is_by_group():
+    """A request is admitted when EVERY group can hold its worst case: the
+    window group's claim is its ring plus one page whatever the prompt's
+    length, and a window pool with nothing left refuses what the full
+    group could hold."""
+    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
+    model = ExaoneMoeModel(ExaoneMoeConfig(
+        vocab_size=97, hidden_size=32, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_size=48, expert_ffn_size=16,
+        n_routed_experts=8, moe_topk=2, held=(0, 4), window=8,
+        max_position=128, dtype=jnp.float32, param_dtype=jnp.float32))
+    e = PagedServeEngine(model, jax.jit(model.init)(jax.random.PRNGKey(0)),
+                         num_slots=3, max_len=128, page_size=4,
+                         prefill_chunk=8, min_bucket=4)
+    full, win = e.cache.groups
+    assert e.admission_pages(100, 20) == 31 + 1          # the first group's
+    assert e.admission_pages(100, 20, group=1) == 5 + 1  # ring + one COW
+    assert e.admission_pages(6, 1, group=1) == 2 + 1     # never past its need
+    prompt = list(range(1, 41))
+    assert e.admission_ok(prompt, 8)
+    slot = e.alloc_slot()
+    e.begin_prefill(slot, prompt, max_tokens=8)
+    assert (full.reserve[slot], win.reserve[slot]) == (14, 6)
+    while e.prefill_step(slot) is None:
+        pass
+    # the ring's claim is of pages held at once: dropped pages give it back
+    assert win.reserve[slot] + len(win.tables[slot]) <= 6
+    assert e.metrics.count("kv_window_released") == win.released > 0
+    # nothing left in the window pool: refused, though the full group fits
+    other = e.alloc_slot()
+    e.cache.reserve(other, (0, win.available_pages()))
+    assert full.available_pages() > 40 and not e.admission_ok(prompt, 8)
+    e.release(other)
+    assert e.admission_ok(prompt, 8)

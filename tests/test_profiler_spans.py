@@ -412,3 +412,59 @@ def test_a_process_without_jax_opens_no_annotation(monkeypatch):
     assert trace._annotation is None      # looked up once
     monkeypatch.setitem(sys.modules, "jax", jax)
     assert trace.span("y") is trace.NULL_SPAN
+
+
+# ------------------------------------------ a cache of two groups (ISSUE 32)
+
+def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
+    """A model whose cache has a window group beside the full one: the
+    ``post`` span of every decode round and prefill chunk carries what the
+    tables hold (``kv_pages_full``, ``kv_pages_window``), what one group
+    would hold (``kv_pages_if_one_group``) and the pages dropped from
+    behind the window since the last call, beside the expert layer's
+    counts; ``serve.cache_spec`` lists the second group; each launch says
+    what a layer of each group gathers."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
+
+    model = ExaoneMoeModel(ExaoneMoeConfig(
+        vocab_size=97, hidden_size=32, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_size=48, expert_ffn_size=16,
+        n_routed_experts=8, moe_topk=2, held=(0, 4), window=8,
+        max_position=128, dtype=jnp.float32, param_dtype=jnp.float32))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=128,
+                               page_size=4, prefill_chunk=8, min_bucket=4,
+                               prefix_sharing=False)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    full, win = eng.cache.groups
+    row = 2 * 2 * 8 * 4                       # K + V of one token, a layer
+    assert spec[3]["cache_layers"] == 1 and spec[3]["g1_cache_layers"] == 4
+    assert spec[3]["g1_window"] == 8
+    assert spec[3]["pool_bytes"] == full.k.nbytes + full.v.nbytes
+    assert spec[3]["g1_pool_bytes"] == win.k.nbytes + win.v.nbytes \
+        == 4 * (1 + 4 * 6) * 4 * row
+    posts = _named(events, "serve.decode.post") \
+        + _named(events, "serve.prefill_chunk.post")
+    keys = {"moe_held", "moe_zero", "moe_absent", "moe_hit", "kv_pages_full",
+            "kv_pages_window", "kv_pages_if_one_group", "kv_window_released"}
+    assert posts and all(set(e[3]) == keys for e in posts)
+    assert sum(e[3]["kv_window_released"] for e in posts) \
+        == eng.metrics.count("kv_window_released") == win.released > 0
+    for e in posts:
+        ids = e[3]
+        assert ids["kv_pages_full"] * 5 == ids["kv_pages_if_one_group"]
+        assert ids["kv_pages_window"] <= 4 * 5 * 4   # 4 layers, ring, slots
+    # at 33 + 6 tokens the grouped cache holds well under one group's pages
+    last = max(posts, key=lambda e: e[1])[3]
+    assert last["kv_pages_full"] + last["kv_pages_window"] \
+        < 0.8 * last["kv_pages_if_one_group"]
+    for e in _named(events, "serve.decode.launch"):
+        assert e[3]["view_bytes"] == e[3]["batch"] * e[3]["pages"] * 4 * row
+        assert e[3]["g1_view_bytes"] == e[3]["batch"] * 3 * 4 * row
+    for e in _named(events, "serve.prefill_chunk.launch"):
+        assert e[3]["g1_view_bytes"] == 5 * 4 * row
