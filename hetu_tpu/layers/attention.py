@@ -139,12 +139,14 @@ class MultiHeadAttention(Module):
                                   starts)
         return self._out(p, out, b, s), k_cache, v_cache
 
-    def decode_step(self, variables, x, k_cache, v_cache, lengths):
-        """One-token decode against a cache.
+    def decode_step(self, variables, x, k_cache, v_cache, layer, lengths):
+        """One-token decode against cache layer ``layer`` of the two
+        ALL-LAYER caches (``ops.decode_layer_attention`` says what they may
+        be: a paged cache on a TPU is attended where its pages lie).
 
-        x: [B, 1, H]; k_cache/v_cache: [B, T, nh, hd]; lengths: [B] int32 =
-        tokens already cached (the new token's K/V is written at that
-        index).  Returns (y [B, 1, H], new_k_cache, new_v_cache).
+        x: [B, 1, H]; lengths: [B] int32 = tokens already cached (the new
+        token's K/V is written at that index).  Returns (y [B, 1, H],
+        new_k_cache, new_v_cache).
         """
         if not self.causal:
             raise NotImplementedError("KV-cache decode is causal-LM only")
@@ -152,7 +154,6 @@ class MultiHeadAttention(Module):
         b = x.shape[0]
         x = x.astype(self.dtype)
         q, k, v = self._qkv(p, x)
-        k_cache, v_cache = ops.cache_update(k_cache, v_cache, k, v, lengths)
-        out = ops.decode_attention(jnp.moveaxis(q, 1, 2), k_cache, v_cache,
-                                   lengths)
+        out, k_cache, v_cache = ops.decode_layer_attention(
+            jnp.moveaxis(q, 1, 2), k, v, k_cache, v_cache, layer, lengths)
         return self._out(p, out, b, 1), k_cache, v_cache

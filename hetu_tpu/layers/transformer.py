@@ -106,12 +106,14 @@ class TransformerBlock(Module):
             self._mod(p, self.ln1, "ln1", x), k_cache, v_cache, starts)
         return self._mlp(p, x + a), k_cache, v_cache
 
-    def decode_step(self, variables, x, k_cache, v_cache, lengths):
-        """x [B,1,H], caches [B,T,nh,hd] → (out, new_k_cache, new_v_cache)."""
+    def decode_step(self, variables, x, k_cache, v_cache, layer, lengths):
+        """x [B,1,H], cache layer ``layer`` of the two all-layer caches →
+        (out, new_k_cache, new_v_cache)."""
         if not self.pre_norm:
             raise NotImplementedError("KV-cache decode needs pre-LN blocks")
         p = variables["params"]
         a, k_cache, v_cache = self.attn.decode_step(
             {"params": p["attn"], "state": {}},
-            self._mod(p, self.ln1, "ln1", x), k_cache, v_cache, lengths)
+            self._mod(p, self.ln1, "ln1", x), k_cache, v_cache, layer,
+            lengths)
         return self._mlp(p, x + a), k_cache, v_cache

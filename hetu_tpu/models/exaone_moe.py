@@ -344,7 +344,8 @@ class ExaoneMoeModel(Module):
     # fourth value, the expert layers' counts (``step_stats`` names them)
     # summed over the layers.
 
-    def _cached(self, p, input_ids, k_cache, v_cache, pos, attention):
+    def _cached(self, p, input_ids, k_cache, v_cache, pos, attention,
+                one_query: bool = False):
         """Both cache entry points: a layer reads its own cache layer of its
         group (of the engine's pools, that layer's pages and no more),
         writes its new rows [B, S, kv_heads, D] into the views from each
@@ -352,7 +353,10 @@ class ExaoneMoeModel(Module):
         over them (``attention(q, k_view, v_view, window)``, the phase's
         step) and puts the new rows into the pool.  Views and new rows are
         kept FLAT, [B, T, kv_heads * D], as the pages hold them: split by
-        head a view is tiled another way and copied whole."""
+        head a view is tiled another way and copied whole.  ``one_query``
+        (a decode round): a FULL layer makes no view, its step is
+        ``ops.decode_layer_attention`` over its group's cache where it
+        lies; a window layer's ring is read as above."""
         h = self._embed(p, input_ids)
         cos, sin = self.rope_at(pos)
         at = pos[:, 0]
@@ -366,6 +370,11 @@ class ExaoneMoeModel(Module):
             b, s = k.shape[:2]
             with jax.named_scope(
                     "hetu.attn.window" if window else "hetu.attn.full"):
+                if one_query and window is None:
+                    o, k_cache[g], v_cache[g] = ops.decode_layer_attention(
+                        q, k, v, k_cache[g], v_cache[g], cl, at,
+                        scale=self.scale)
+                    return self._out(pa, l, o)
                 update = ops.ring_update if window else ops.cache_update
                 k_view, v_view = k_cache[g].read(cl), v_cache[g].read(cl)
                 t = k_view.shape[1]
@@ -420,7 +429,7 @@ class ExaoneMoeModel(Module):
 
         h, k_cache, v_cache, stats = self._cached(
             p, input_ids[:, None], k_cache, v_cache, lengths[:, None],
-            attention)
+            attention, one_query=True)
         return self._head(p, h[:, 0]), k_cache, v_cache, stats
 
     # ---- training (test size; no cut of the published model trains on

@@ -177,10 +177,10 @@ class GPTModel(Module):
         c = self.c
         h = ops.embedding_lookup(p["tok_emb"], input_ids[:, None])
         h = (h + p["pos_emb"][lengths][:, None]).astype(c.dtype)
-        h, k_cache, v_cache = ops.scan_cached_layers(
-            lambda p_l, h, k_l, v_l: self.block.decode_step(
-                {"params": p_l, "state": {}}, h, k_l, v_l, lengths),
-            p["blocks"], h, k_cache, v_cache, lengths, 1)
+        h, k_cache, v_cache = ops.scan_layers_over_caches(
+            lambda p_l, h, k, v, l: self.block.decode_step(
+                {"params": p_l, "state": {}}, h, k, v, l, lengths),
+            p["blocks"], h, k_cache, v_cache)
         h = ops.layer_norm(h, p["ln_f_scale"], p["ln_f_bias"])
         logits = ops.linear(h[:, 0], self._head_weight(p))
         return logits, k_cache, v_cache

@@ -141,8 +141,8 @@ class LlamaBlock(Module):
 
     # ---- serving (hetu_tpu/serve): KV-cache prefill / decode ----
     # The cache stores ROTATED k (RoPE applied at write time, the standard
-    # serving layout) and the nkv un-repeated GQA heads; decode_attention
-    # repeats at read time.
+    # serving layout) and the nkv un-repeated GQA heads; the query heads
+    # are grouped at read time.
 
     def _qkv(self, pa, x):
         c = self.c
@@ -175,11 +175,12 @@ class LlamaBlock(Module):
                        p["attn"]["out_weight"].astype(c.dtype))
         return self._mlp(p, x + a), k_cache, v_cache
 
-    def decode_step(self, variables, x, k_cache, v_cache, lengths,
+    def decode_step(self, variables, x, k_cache, v_cache, layer, lengths,
                     cos, sin):
         """One-token decode; cos/sin are FULL tables [T_max, hd/2] gathered
-        at each sequence's position.  x [B,1,H]; caches [B,T,nkv,hd];
-        lengths [B] = tokens already cached.  Returns (out, new_k, new_v).
+        at each sequence's position.  x [B,1,H]; cache layer ``layer`` of
+        the two all-layer caches (``ops.decode_layer_attention``); lengths
+        [B] = tokens already cached.  Returns (out, new_k, new_v).
         """
         p = variables["params"]
         c = self.c
@@ -188,9 +189,8 @@ class LlamaBlock(Module):
         q, k, v = self._qkv(p["attn"], hn)
         q = ops.apply_rope_at(jnp.moveaxis(q, 1, 2), cos, sin, lengths)
         k = ops.apply_rope_at(jnp.moveaxis(k, 1, 2), cos, sin, lengths)
-        k_cache, v_cache = ops.cache_update(
-            k_cache, v_cache, jnp.moveaxis(k, 1, 2), v, lengths)
-        out = ops.decode_attention(q, k_cache, v_cache, lengths)
+        out, k_cache, v_cache = ops.decode_layer_attention(
+            q, jnp.moveaxis(k, 1, 2), v, k_cache, v_cache, layer, lengths)
         out = jnp.moveaxis(out, 1, 2).reshape(b, 1, c.hidden_size)
         a = ops.linear(out.astype(c.dtype),
                        p["attn"]["out_weight"].astype(c.dtype))
@@ -289,10 +289,10 @@ class LlamaModel(Module):
             p["tok_emb"], input_ids[:, None]).astype(c.dtype)
         # full tables, gathered per sequence at its own position
         cos, sin = self._tables(c.max_position)
-        h, k_cache, v_cache = ops.scan_cached_layers(
-            lambda p_l, h, k_l, v_l: self.block.decode_step(
-                {"params": p_l, "state": {}}, h, k_l, v_l, lengths, cos, sin),
-            p["blocks"], h, k_cache, v_cache, lengths, 1)
+        h, k_cache, v_cache = ops.scan_layers_over_caches(
+            lambda p_l, h, k, v, l: self.block.decode_step(
+                {"params": p_l, "state": {}}, h, k, v, l, lengths, cos, sin),
+            p["blocks"], h, k_cache, v_cache)
         h = ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
         logits = ops.linear(h[:, 0], p["lm_head"].T.astype(c.dtype))
         return logits, k_cache, v_cache

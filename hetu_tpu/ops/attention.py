@@ -13,6 +13,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from hetu_tpu.ops.pallas_kernels import paged_attention
+from hetu_tpu.utils.platform import (
+    default_backend_is_tpu as _default_backend_is_tpu,
+)
+
 
 def attention(q, k, v, *, mask=None, scale=None):
     """q,k,v: [..., heads, seq, head_dim] (or [B,H,S,D]).
@@ -102,13 +107,25 @@ def scan_cached_layers(step, blocks, h, k_cache, v_cache, at, n: int):
     inputs and outputs the caches would be held twice and rewritten whole.
     ``blocks`` are the layers' stacked parameters.  Returns (h, k_cache,
     v_cache)."""
-    def layer(carry, xs):
-        h, k_all, v_all = carry
-        p_l, l = xs
+    def on_views(p_l, h, k_all, v_all, l):
         h, k_l, v_l = step(p_l, h, read_cache_layer(k_all, l),
                            read_cache_layer(v_all, l))
         return (h, write_cache_layer(k_all, l, k_l, at, n),
-                write_cache_layer(v_all, l, v_l, at, n)), None
+                write_cache_layer(v_all, l, v_l, at, n))
+
+    return scan_layers_over_caches(on_views, blocks, h, k_cache, v_cache)
+
+
+def scan_layers_over_caches(step, blocks, h, k_cache, v_cache):
+    """The layer scan of every cache entry point: the two all-layer caches
+    are CARRIED, and layer ``l`` is handed them whole with its index,
+    ``step(p_l, h, k_cache, v_cache, l) -> (h, k_cache, v_cache)``.  A step
+    that reads its layer's views goes through :func:`scan_cached_layers`; a
+    decode entry point whose attention is the one-query step
+    (:func:`decode_layer_attention`) calls this, and no view of the layer is
+    made for it.  Returns (h, k_cache, v_cache)."""
+    def layer(carry, xs):
+        return step(xs[0], *carry, xs[1]), None
 
     n_layers = jax.tree_util.tree_leaves(blocks)[0].shape[0]
     carry, _ = jax.lax.scan(layer, (h, k_cache, v_cache),
@@ -300,24 +317,26 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
 
 def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
                      window=None, kv_heads=None):
-    """Single-token attention against a cache (GQA-aware).
+    """Single-token attention against one cache layer's VIEW (GQA-aware).
 
     q: [B, heads, 1, D] — the newest token's query, already positioned at
     index ``lengths[b]`` in its sequence (so its K/V must have been written
     via :func:`cache_update` first).  k_cache/v_cache: [B, T, kv_heads, D]
-    with kv_heads dividing heads (kv_heads < heads = GQA: the query heads
+    with kv_heads dividing heads.  Whatever the grouping, the query heads
     are grouped by the KV head they read and the view is read as the flat
-    rows it is, never repeated nor relaid: :func:`_attend_one_query`).
+    rows it is, never repeated nor relaid (:func:`_attend_one_query`).
     lengths: [B] int32 index of the newest token; positions > lengths[b]
     (unwritten or stale from a previous occupant) are masked out.
 
     ``window``: as :func:`chunk_attention`'s — the cache is the window
-    group's ring, written by :func:`ring_update`.
+    group's ring, written by :func:`ring_update`.  A ring is never walked
+    by the paged kernel (:func:`decode_layer_attention`): a
+    ``paged_attn.plan`` instant says so when the program is traced.
 
     ``kv_heads``: the caches are given as the FLAT rows their pages hold,
     [B, T, kv_heads * D] (written by :func:`cache_update` / :func:`ring_update`
-    with flat new rows): what the GQA path reads anyway, and from a paged
-    view without the copy that splitting the rows by head costs.
+    with flat new rows): what is read anyway, and from a paged view without
+    the copy that splitting the rows by head costs.
     """
     if q.shape[-2] != 1:
         raise ValueError(
@@ -325,23 +344,56 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None,
             "(prefill goes through causal_attention over the chunk)")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    nh, nkv = q.shape[1], kv_heads or k_cache.shape[2]
-    if nkv != nh or window is not None or kv_heads:
-        b, t = k_cache.shape[:2]
-        seen = jnp.arange(t)[None, :] <= lengths[:, None]   # [B, T]
-        if window is not None:
-            held = _ring_positions(t, lengths)               # [B, T]
-            seen = (held >= 0) & (lengths[:, None] - held < int(window))
-        return _attend_one_query(q, k_cache.reshape(b, t, -1),
-                                 v_cache.reshape(b, t, -1), nkv, seen, scale)
-    # as many KV heads as query heads: as it always was
-    k = jnp.moveaxis(k_cache, 1, 2)  # [B, kv_heads, T, D]
-    v = jnp.moveaxis(v_cache, 1, 2)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
-    t = k_cache.shape[1]
-    valid = jnp.arange(t)[None, :] <= lengths[:, None]      # [B, T]
-    scores = jnp.where(valid[:, None, None, :], scores,
-                       jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+    nkv = kv_heads or k_cache.shape[2]
+    b, t = k_cache.shape[:2]
+    seen = jnp.arange(t)[None, :] <= lengths[:, None]       # [B, T]
+    if window is not None:
+        paged_attention.plan(q, nkv, kernel=False, why="window", rows=t)
+        held = _ring_positions(t, lengths)                   # [B, T]
+        seen = (held >= 0) & (lengths[:, None] - held < int(window))
+    return _attend_one_query(q, k_cache.reshape(b, t, -1),
+                             v_cache.reshape(b, t, -1), nkv, seen, scale)
+
+
+def decode_layer_attention(q, k_new, v_new, k_cache, v_cache, layer,
+                           lengths, *, scale=None):
+    """The one-query step of a decode round over cache layer ``layer`` (one
+    that keeps every position) of the two all-layer caches: each sequence's
+    new K/V row is written at position ``lengths[b]`` and its query attends
+    over every position up to it.
+
+    q: [B, heads, 1, D]; k_new / v_new: [B, 1, kv_heads, D]; k_cache /
+    v_cache: the all-layer caches as a cache entry point is handed them;
+    lengths: [B] int32 tokens already cached.  Returns (out [B, heads, 1,
+    Dv], k_cache, v_cache).
+
+    **The rule is on what is observed here.**  A paged cache (one with a
+    one-query step of its own, ``k_cache.attend``: the serving engine's
+    ``serve.kv_cache.PagedLayers``) on a TPU backend: the new rows go into
+    the pool through the write map, and ONE Pallas kernel walks each
+    sequence's page table in the pool where it lies, as far as the sequence
+    is long (``pallas_kernels.paged_attention``); no view of the layer is
+    gathered.  A paged cache on another backend, and a plain ``[L, B, T,
+    kv_heads, D]`` array (:func:`read_cache_layer` says who passes one),
+    take the XLA composition over that layer's flat rows
+    (:func:`decode_attention`); a ``paged_attn.plan`` instant says which
+    and why when the program is traced."""
+    b, _, g, _ = k_new.shape
+    why = ("dense_cache" if not hasattr(k_cache, "attend")
+           else "sharded" if k_cache.sharded
+           else "" if _default_backend_is_tpu() else "backend")
+    if not why:
+        k_cache = k_cache.write(layer, k_new)
+        v_cache = v_cache.write(layer, v_new)
+        out = k_cache.attend(v_cache, layer, q, lengths, scale=scale)
+        return out, k_cache, v_cache
+    k_l = read_cache_layer(k_cache, layer)
+    t = k_l.shape[1]
+    paged_attention.plan(q, g, kernel=False, rows=t, why=why)
+    k_l, v_l = cache_update(
+        k_l.reshape(b, t, -1), read_cache_layer(v_cache, layer).reshape(
+            b, t, -1), k_new.reshape(b, 1, -1), v_new.reshape(b, 1, -1),
+        lengths)
+    out = decode_attention(q, k_l, v_l, lengths, scale=scale, kv_heads=g)
+    return (out, write_cache_layer(k_cache, layer, k_l, lengths, 1),
+            write_cache_layer(v_cache, layer, v_l, lengths, 1))

@@ -171,11 +171,15 @@ class PagedServeEngine:
     the first group's operands in the same ``aux``, so both programs stay
     keyed by the first group's shapes alone and a model of one group
     compiles to the programs it compiled to before groups.  The model carries them
-    through its layer scan: each layer gathers its own pages into a view
-    ``[b, n_pg * page_size, *row]``, runs its attention step on that view
-    and scatters the step's new rows into the carried, donated pool.  No
-    view of every layer is built and the pool is never copied (how that was
-    checked: ``kv_cache.py``'s docstring).
+    through its layer scan: in a chunk each layer gathers its own pages
+    into a view ``[b, n_pg * page_size, *row]``, runs its attention step on
+    that view and scatters the step's new rows into the carried, donated
+    pool; in a decode round a layer scatters its new rows and attends over
+    its pages where they lie in the pool (one Pallas kernel on a TPU,
+    :meth:`PagedLayers.attend`; a window group's ring, a pool laid over a
+    tensor-parallel ``mesh``, and any layer on a CPU backend, gathers its
+    view as a chunk does).  No view of every layer is built and the pool is
+    never copied (how that was checked: ``kv_cache.py``'s docstring).
 
     The scheduler/pool/migration stack drives it through
     :meth:`admission_ok`, :meth:`begin_prefill`, :meth:`prefill_step`
@@ -417,6 +421,8 @@ class PagedServeEngine:
         k_row, v_row = self.cache.spec.row_shapes()
         more = tuple(zip((g.spec.row_shapes() for g in self._more),
                          self._ring_decode))
+        # a pool laid over a mesh says so: its one-query step keeps the view
+        sharded = self.mesh is not None
 
         def fn(params, k_pool, v_pool, aux):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
@@ -432,21 +438,25 @@ class PagedServeEngine:
             tokens = aux[:, n_pg + 1]
             wpage = aux[:, n_pg + 2:n_pg + 3]
             woff = aux[:, n_pg + 3:n_pg + 4]
-            # a layer gathers its pages of the B sequences and scatters B
-            # new rows (:class:`PagedLayers`): a decode step moves one
-            # layer's view at a time and O(B) rows into the pool, never a
+            # a layer scatters B new rows into the pool and attends over
+            # its pages of the B sequences where they lie
+            # (:meth:`PagedLayers.attend`, on a TPU; a window group's ring,
+            # a pool laid over a mesh and a CPU backend gather that layer's
+            # view): a decode step moves O(B) rows into the pool, never a
             # view of every layer and never the pool
             k = PagedLayers(k_pool[0] if more else k_pool, tables, wpage,
-                            woff, k_row)
+                            woff, k_row, sharded)
             v = PagedLayers(v_pool[0] if more else v_pool, tables, wpage,
-                            woff, v_row)
+                            woff, v_row, sharded)
             if more:
                 k, v, at = [k], [v], n_pg + 4
                 for i, ((k_r, v_r), ring) in enumerate(more, 1):
                     tables = aux[:, at:at + ring]
                     wpage = aux[:, at + ring:at + ring + 1]
-                    k.append(PagedLayers(k_pool[i], tables, wpage, woff, k_r))
-                    v.append(PagedLayers(v_pool[i], tables, wpage, woff, v_r))
+                    k.append(PagedLayers(k_pool[i], tables, wpage, woff, k_r,
+                                         sharded))
+                    v.append(PagedLayers(v_pool[i], tables, wpage, woff, v_r,
+                                         sharded))
                     at += ring + 1
                 k, v = tuple(k), tuple(v)
             logits, k, v, *stats = model.decode_with_cache(

@@ -36,7 +36,12 @@ new rows, and the compiled programs hold nothing of the pool's size but the
 pool (checked in the HLO compiled for a described v5e,
 ``benchmarks/tools/compile_v5e_serve.py --hlo``, in the jaxpr by
 ``tests/paged_programs.py``, and on the chip by the ledger's
-``breakdown.device_ops``).  The allocator owns the slot lifecycle and the
+``breakdown.device_ops``).  Since PR 33 a decode round gathers no view of a
+layer that keeps every position either: ONE Pallas kernel walks each
+sequence's page table in the pool where it lies, as far as the sequence is
+long (:meth:`PagedLayers.attend`, ``ops.decode_layer_attention``); the chunk
+programs and a window group's ring still read a layer's view
+(:meth:`PagedLayers.read`).  The allocator owns the slot lifecycle and the
 per-slot host-side lengths.
 """
 
@@ -49,6 +54,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from hetu_tpu.ops.pallas_kernels.paged_attention import paged_decode_attention
 
 
 @dataclass(frozen=True)
@@ -169,7 +176,7 @@ def pow2_ceil(n: int, cap: int) -> int:
 
 @partial(jax.tree_util.register_dataclass,
          data_fields=("pool", "tables", "wpage", "woff"),
-         meta_fields=("row",))
+         meta_fields=("row", "sharded"))
 @dataclass(frozen=True)
 class PagedLayers:
     """One pool of a paged cache as a jitted step hands it to the model's
@@ -182,19 +189,25 @@ class PagedLayers:
     sequence's pages in order (scratch-padded); ``wpage`` / ``woff``
     ``[B, S]`` int32, where the host's write map puts each of the step's
     ``S`` new rows a sequence (pad rows: scratch page 0); ``row`` the shape
-    ``(heads, head width)`` the model sees a row in (static).
+    ``(heads, head width)`` the model sees a row in (static); ``sharded``
+    whether the engine laid the pool over a mesh (static: a traced pool does
+    not say; the one-query step then keeps the view, which the partitioner
+    splits by head, and does not hand a split pool to :meth:`attend`'s
+    kernel, which it cannot split).
 
     The model carries the value through its layer scan
-    (``ops.scan_cached_layers``); a layer reads its own pages and writes its
-    own new rows, so nothing of the pool's size, and no view of every
-    layer, is ever made: the pool is a loop carry of a donated argument and
-    the row scatter updates it in place."""
+    (``ops.scan_cached_layers``, ``ops.scan_layers_over_caches``); a layer reads
+    its own pages and writes its own new rows, so nothing of the pool's
+    size, and no view of every layer, is ever made: the pool is a loop carry
+    of a donated argument, the row scatter updates it in place, and a decode
+    round's attention reads it in place (:meth:`attend`)."""
 
     pool: jax.Array
     tables: jax.Array
     wpage: jax.Array
     woff: jax.Array
     row: tuple
+    sharded: bool = False
 
     def read(self, layer):
         """Cache layer ``layer`` of every sequence, ``[B, n_pg * page_size,
@@ -211,6 +224,19 @@ class PagedLayers:
         rows = rows.reshape(rows.shape[:2] + self.pool.shape[3:])
         return replace(
             self, pool=self.pool.at[layer, self.wpage, self.woff].set(rows))
+
+    def attend(self, values, layer, q, lengths, *, scale=None):
+        """The one-query step of a decode round over cache layer ``layer``,
+        this pool the keys' and ``values`` the other pool of the pair (the
+        same tables): q ``[B, heads, 1, D]`` attends over positions ``<=
+        lengths[b]`` of each sequence, whose newest row is already written
+        (:meth:`write`).  ONE Pallas kernel walks the tables in the pools
+        where they lie, a sequence's live pages and no more
+        (``ops.pallas_kernels.paged_attention``): no view is gathered.
+        Returns ``[B, heads, 1, Dv]``."""
+        return paged_decode_attention(
+            q, self.pool, values.pool, layer, self.tables, lengths,
+            kv_heads=self.row[0], scale=scale)
 
 
 class PagePoolExhausted(RuntimeError):

@@ -24,18 +24,27 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
     embedding_gather, embedding_scatter_add, flash_attention, routed_gather,
     topk_gating,
 )
+from hetu_tpu.ops.pallas_kernels.paged_attention import (  # noqa: E402
+    paged_decode_attention,
+)
 
 bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 # the flash calls of the two training cells of BENCHMARK.json: gpt2-small at
 # 64 x 1024 on one chip, and gpt2-large's per-chip shard under dp=2 x tp=2
 BENCH_FLASH_SHAPES = ((64, 12, 1024, 64), (8, 10, 1024, 64))
+# the paged decode kernel at the top slot and page bucket of the two serving
+# cells it runs in: (slots, query heads, KV heads, head width, cache layers,
+# pages in the pool, page size, pages a slot)
+BENCH_PAGED_SHAPES = {
+    "gpt2-large.batch": (8, 20, 20, 64, 36, 385, 16, 48),
+    "k-exaone-236b-a23b.batch-mixed": (16, 64, 8, 128, 1, 4225, 128, 264)}
 
 
 @pytest.fixture(autouse=True)
 def _compiled_kernels(monkeypatch):
     """Lower the kernels as a TPU backend would: never interpret mode."""
-    for mod in ("embedding", "flash_attention"):
+    for mod in ("embedding", "flash_attention", "paged_attention"):
         m = sys.modules[f"hetu_tpu.ops.pallas_kernels.{mod}"]
         name = "_auto_interpret" if mod == "embedding" else "auto_interpret"
         monkeypatch.setattr(m, name, lambda interpret: False)
@@ -72,6 +81,15 @@ def _cases():
     yield (f"topk_gating {k} of {experts}",
            lambda x: topk_gating(x, k, kernel=True),
            [((tokens, experts), f32)], 1)
+    for cell, (b, nh, g, d, layers, pool, ps, n_pg) in \
+            BENCH_PAGED_SHAPES.items():
+        pool_of = ((layers, pool, ps, g * d), bf16)
+        yield (f"paged_decode_attention {cell}",
+               lambda q, k, v, layer, tables, lengths, g=g:
+               paged_decode_attention(q, k, v, layer, tables, lengths,
+                                      kv_heads=g),
+               [((b, nh, 1, d), bf16), pool_of, pool_of, ((), i32),
+                ((b, n_pg), i32), ((b,), i32)], 1)
 
 
 CASES = list(_cases())
@@ -155,3 +173,65 @@ def test_kernel_compiles_for_v5e(name, fn, args, n_calls):
     sh = SingleDeviceSharding(topo.devices[0])
     abstract = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in args]
     jax.jit(fn).lower(*abstract).compile()
+
+
+# ---- a pool laid over a tensor-parallel mesh: no Mosaic call to partition
+
+def _tp2_decode(monkeypatch, devices=None):
+    """The decode program of a tiny GQA engine under tp=2 as a TPU backend
+    traces it, with its arguments: on the engine's own (CPU) mesh, or
+    abstract over ``devices``.  Returns (jitted program, arguments, pool)."""
+    import hetu_tpu as ht
+    from hetu_tpu.models.llama import LlamaConfig, LlamaModel
+    from hetu_tpu.serve import PagedServeEngine
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    monkeypatch.setattr(sys.modules["hetu_tpu.ops.attention"],
+                        "_default_backend_is_tpu", lambda: True)
+    model = LlamaModel(LlamaConfig(
+        vocab_size=128, hidden_size=256, num_layers=2, num_heads=4,
+        num_kv_heads=2, ffn_size=512, max_position=64, dtype=bf16))
+    engine = PagedServeEngine(
+        model, model.init(jax.random.PRNGKey(0)), num_slots=4, max_len=64,
+        page_size=16, mesh=ht.make_mesh(tp=2))
+    cache = engine.cache
+    assert cache.k.sharding.spec[3] == "tp"
+    args = (engine.params, cache.k, cache.v,
+            jnp.zeros((4, cache.pages_per_slot + 4), i32))
+    if devices is not None:
+        mesh = ht.make_mesh(tp=2, devices=devices)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=(
+                NamedSharding(mesh, getattr(a.sharding, "spec",
+                                            PartitionSpec())))), args)
+    return engine._build_decode(), args, cache.k
+
+
+def test_a_tp_sharded_decode_program_lowers_for_tpu_without_the_kernel(
+        monkeypatch):
+    """The partitioner cannot split a Mosaic call ("cannot be automatically
+    partitioned" when the program is lowered), so a pool laid over a mesh
+    keeps the view, which it splits by KV head: the rule is on the cache,
+    not on the backend alone."""
+    fn, args, _ = _tp2_decode(monkeypatch)
+    text = fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" not in text
+
+
+@pytest.mark.slow
+def test_a_tp_sharded_decode_program_compiles_for_v5e_and_gathers_no_pool(
+        monkeypatch):
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    fn, args, pool = _tp2_decode(monkeypatch, topo.devices[:2])
+    text = fn.lower(*args).compile().as_text()
+    assert "custom_call_target=\"tpu_custom_call\"" not in text
+    # no collective brings a pool together: nothing of the whole pool's
+    # shape, nor of one layer's pages at their full width
+    whole = ",".join(map(str, pool.shape))
+    layer = ",".join(map(str, pool.shape[1:]))
+    gathers = [line for line in text.splitlines() if "all-gather" in line]
+    assert not [g for g in gathers if f"[{whole}]" in g or f"[{layer}]" in g]
+    assert f"bf16[{whole}]" not in text    # each chip holds half the width
