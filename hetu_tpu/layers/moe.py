@@ -359,7 +359,11 @@ class HeldExpertLayer:
     [H, n_routed + n_zero] float32, ``router_bias`` [n_routed + n_zero]
     float32, ``gate``/``up`` [count, H, F], ``down`` [count, F, H]; with a
     shared expert ``shared_gate``/``shared_up`` [H, F_s], ``shared_down``
-    [F_s, H]."""
+    [F_s, H].  ``router_bias`` is whatever the model hands over under that
+    name: a parameter leaf where the model serves published weights, the
+    model's STATE where it trains without an auxiliary loss and moves the
+    bias itself (``models/deepseek_v3.py``); it steers the choice only, so
+    no gradient reaches it."""
 
     def __init__(self, *, n_routed: int, n_zero: int, k: int, scaling: float,
                  held: tuple, block_rows: int = 128, dtype=jnp.bfloat16,
@@ -403,13 +407,10 @@ class HeldExpertLayer:
             return jnp.dot(h, of("shared_down"),
                            preferred_element_type=jnp.float32)
 
-    def apply(self, p, u, *, static_trip: bool = False, layer=None):
-        """u [..., H] -> (m [..., H] in u's dtype, counts [4] int32 in
-        ``MOE_STATS`` order).  With ``layer`` given, ``gate``/``up``/``down``
-        (and the shared expert's three) are stacked over layers and this is
-        layer ``layer`` of them."""
-        tokens = u.reshape(-1, u.shape[-1])
-        w, idx = self.route(p, tokens)
+    def combine(self, p, tokens, w, idx, layer=None):
+        """What the chosen experts add for tokens [T, H] under the router's
+        ``(w, idx)``: (float32 [T, H], counts [4] int32 in ``MOE_STATS``
+        order)."""
         zero = idx >= self.n_routed
         if self.n_zero:
             with jax.named_scope("hetu.moe.zero"):
@@ -419,7 +420,7 @@ class HeldExpertLayer:
             routed, per_expert = held_expert_ffn(
                 tokens.astype(self.dtype), w, idx, p["gate"], p["up"],
                 p["down"], first=self.first, block_rows=self.block_rows,
-                static_trip=static_trip, layer=layer)
+                layer=layer)
         n_held = per_expert.sum()
         n_zero = zero.sum().astype(jnp.int32)
         stats = jnp.stack([n_held, n_zero, idx.size - n_held - n_zero,
@@ -427,4 +428,16 @@ class HeldExpertLayer:
         out = out + routed if self.n_zero else routed
         if self.shared:
             out = out + self.shared_expert(p, tokens, layer)
+        return out, stats
+
+    def apply(self, p, u, *, layer=None):
+        """u [..., H] -> (m [..., H] in u's dtype, counts [4] int32 in
+        ``MOE_STATS`` order).  With ``layer`` given, ``gate``/``up``/``down``
+        (and the shared expert's three) are stacked over layers and this is
+        layer ``layer`` of them.  :meth:`route` then :meth:`combine`; a
+        model that needs the router's choices between the two (to count
+        them for the correction bias) calls the pair itself."""
+        tokens = u.reshape(-1, u.shape[-1])
+        w, idx = self.route(p, tokens)
+        out, stats = self.combine(p, tokens, w, idx, layer)
         return out.astype(u.dtype).reshape(u.shape), stats

@@ -276,7 +276,7 @@ class ExaoneMoeModel(Module):
             u = ops.linear(x, p["up"][l].astype(dt))
             return ops.linear(ops.silu(g) * u, p["down"][l].astype(dt))
 
-    def _layer(self, p, l: int, h, attend, *, static_trip: bool):
+    def _layer(self, p, l: int, h, attend):
         """Layer ``l`` over ``h`` [B, S, H], ``p`` the stacked leaves of
         every layer; ``attend(l, window, a)`` is the phase's attention
         block on the normed input ``a``, ``window`` None on a full layer.
@@ -291,7 +291,7 @@ class ExaoneMoeModel(Module):
         m, stats = self.moe.apply(
             dict(moe, router=moe["router"][e],
                  router_bias=moe["router_bias"][e]),
-            u, layer=e, static_trip=static_trip)
+            u, layer=e)
         return h + m, stats
 
     def _embed(self, p, ids):
@@ -330,7 +330,7 @@ class ExaoneMoeModel(Module):
                              o.reshape(b, c.num_heads, s, c.head_dim))
 
         for l in range(c.num_layers):
-            h, _ = self._layer(p["layers"], l, h, attend, static_trip=train)
+            h, _ = self._layer(p["layers"], l, h, attend)
         return h
 
     def apply(self, variables, input_ids, *, train: bool = False, rng=None):
@@ -388,7 +388,7 @@ class ExaoneMoeModel(Module):
 
         stats = jnp.zeros((4,), jnp.int32)
         for l in range(self.c.num_layers):
-            h, n = self._layer(p["layers"], l, h, attend, static_trip=False)
+            h, n = self._layer(p["layers"], l, h, attend)
             stats = stats + n
         return h, tuple(k_cache), tuple(v_cache), stats
 

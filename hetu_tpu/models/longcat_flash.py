@@ -331,7 +331,7 @@ class LongcatFlashModel(Module):
             u = ops.linear(x, p["up"][l, i].astype(dt))
             return ops.linear(ops.silu(g) * u, p["down"][l, i].astype(dt))
 
-    def _double_layer(self, p, l, h, attend, *, static_trip: bool):
+    def _double_layer(self, p, l, h, attend):
         """Double layer ``l`` over ``h`` [B, S, H].  ``p`` are the stacked
         leaves of EVERY layer, each read at ``[l, ...]`` where it is used:
         handed to the scan a layer at a time instead, a layer's slice of a
@@ -346,7 +346,7 @@ class LongcatFlashModel(Module):
         m, stats = self.moe.apply(
             dict(moe, router=moe["router"][l],
                  router_bias=moe["router_bias"][l]),
-            u, layer=l, static_trip=static_trip)
+            u, layer=l)
         h2 = h1 + self._ffn(p["ffn"], l, 0, u)
         h3 = h2 + attend(1, self._norm(h2, p["attn_norm"][l, 1]))
         out = h3 + self._ffn(p["ffn"], l, 1,
@@ -381,8 +381,7 @@ class LongcatFlashModel(Module):
                     q_n, q_r, c, k_r = self.attn.project(pa, x, cos, sin)
                     return self.attn.expanded(pa, q_n, q_r, c, k_r, pos,
                                               static_trip=train)
-            out, _ = self._double_layer(layers, l, h, attend,
-                                        static_trip=train)
+            out, _ = self._double_layer(layers, l, h, attend)
             return out, None
 
         h, _ = jax.lax.scan(layer, h, jnp.arange(self.c.num_layers))
@@ -435,8 +434,7 @@ class LongcatFlashModel(Module):
                 caches[1] = ops.write_cache_layer(caches[1], cl, r_all, at, s)
                 return attend_over(pa, q_n, q_r, c_all, r_all)
 
-            out, stats = self._double_layer(layers, l, h, attend,
-                                            static_trip=False)
+            out, stats = self._double_layer(layers, l, h, attend)
             return (out, caches[0], caches[1]), stats
 
         (h, k_cache, v_cache), stats = jax.lax.scan(

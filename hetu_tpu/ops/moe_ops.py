@@ -14,13 +14,18 @@ Two routings live here, and they are not interchangeable.  Everything down to
 :func:`balance_assignment` is the CAPACITY routing that ``layers/moe.py``'s
 ``MoELayer`` trains with: each expert gets a static number of slots, is
 padded to it, and drops what overflows.  :func:`route_biased_top_k` and
-:func:`held_expert_ffn` at the end are the HELD-EXPERT routing a served
-model uses (``models/longcat_flash.py``): a router as wide as published
-over experts of which this chip holds a contiguous share, no capacity, no
-drops, and work that follows the rows routed here.
+:func:`held_expert_ffn` at the end are the HELD-EXPERT routing of a model
+served or trained as one chip's share (``models/longcat_flash.py``,
+``models/exaone_moe.py``, ``models/deepseek_v3.py``): a router as wide as
+published over experts of which this chip holds a contiguous share, no
+capacity, no drops, and work that follows the rows routed here, forward and
+backward.
 """
 
 from __future__ import annotations
+
+import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -167,17 +172,160 @@ def route_biased_top_k(scores, bias, k: int):
     return jnp.take_along_axis(scores, idx, axis=-1), idx.astype(jnp.int32)
 
 
-def held_expert_blocks(tokens: int, k: int, count: int,
-                       block_rows: int) -> int:
-    """The most row blocks :func:`held_expert_ffn` can need: a token's
-    ``k`` choices are distinct, so at most ``min(k, count)`` of them land on
-    held experts, and each held expert's last block may be part empty."""
-    return -(-tokens * min(k, count) // block_rows) + count
+class _WalkPlan(NamedTuple):
+    """The walk over the (token, choice) pairs that land on the ``E`` held
+    experts, in blocks of ``R`` rows.  The forward and the backward walk
+    both read their trip count from it: ``trips``, the blocks that hold a
+    pair."""
+    order: jax.Array       # pair indices sorted by held expert, absent last,
+    #                        R zeros behind them: a block's slice never clamps
+    counts: jax.Array      # [E] pairs per held expert
+    blocks: jax.Array      # [E] blocks per held expert
+    block_end: jax.Array   # [E] their running end
+    row_start: jax.Array   # [E] each expert's first row in the sorted order
+
+    @property
+    def trips(self):
+        return self.block_end[-1]
+
+
+def _walk_plan(idx, first: int, E: int, R: int) -> _WalkPlan:
+    """The plan for pairs ``idx`` [T, k] over the ``E`` experts held from
+    ``first`` on."""
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < E)
+    key = jnp.where(held, local, E)                       # absent: sorted last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    blocks = -(-counts // R)                              # per expert
+    block_end = jnp.cumsum(blocks)                        # [E]
+    row_start = jnp.cumsum(counts) - counts               # [E]
+    order = jnp.concatenate([order, jnp.zeros((R,), jnp.int32)])
+    return _WalkPlan(order, counts, blocks, block_end, row_start)
+
+
+def _walk_block(plan, b, R: int):
+    """Block ``b`` of the walk: (its held expert, the ``R`` pair indices it
+    covers, which of them are its expert's own)."""
+    order, counts, blocks, block_end, row_start = plan
+    E = counts.shape[0]
+    e = jnp.minimum(jnp.searchsorted(block_end, b, side="right"), E - 1)
+    within = b - (block_end[e] - blocks[e])               # block of expert e
+    start = row_start[e] + within * R
+    pairs = lax.dynamic_slice_in_dim(order, start, R)
+    live = (start + jnp.arange(R)) < row_start[e] + counts[e]
+    return e, pairs, live
+
+
+def _expert_of(w, e, layer):
+    return w[e] if layer is None else w[layer, e]
+
+
+def _held_forward(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
+    k = idx.shape[1]
+    E = w_gate.shape[0 if layer is None else 1]
+    plan = _walk_plan(idx, first, E, R)
+    pair_w = weights.reshape(-1)
+    dt = x.dtype
+
+    def body(b, out):
+        e, pairs, live = _walk_block(plan, b, R)
+        rows = x[pairs // k]                              # [R, H]
+        g = jnp.dot(rows, _expert_of(w_gate, e, layer).astype(dt))
+        u = jnp.dot(rows, _expert_of(w_up, e, layer).astype(dt))
+        y = jnp.dot(jax.nn.silu(g) * u,
+                    _expert_of(w_down, e, layer).astype(dt),
+                    preferred_element_type=jnp.float32)
+        y = jnp.where(live[:, None], y * pair_w[pairs][:, None], 0.0)
+        return out.at[pairs // k].add(y)
+
+    out = lax.fori_loop(0, plan.trips, body,
+                        jnp.zeros(x.shape, jnp.float32))
+    return (out, plan.counts), plan
+
+
+def _held_backward(x, weights, idx, w_gate, w_up, w_down, layer, plan, d_out,
+                   R):
+    """The walk again, block by block over the blocks that hold a pair: a
+    block's gate and up are recomputed from its rows, ``dW`` is added into
+    its expert's gradient, ``dx`` into its rows' and the pair weights'
+    gradient into its pairs'.  An expert nobody chose keeps a zero gradient
+    and its weights are not read."""
+    k = idx.shape[1]
+    pair_w = weights.reshape(-1)
+    dt = x.dtype
+    f32 = jnp.float32
+
+    def body(b, carry):
+        dx, dpw, dwg, dwu, dwd = carry
+        e, pairs, live = _walk_block(plan, b, R)
+        tok = pairs // k
+        rows = x[tok]
+        wg = _expert_of(w_gate, e, layer).astype(dt)
+        wu = _expert_of(w_up, e, layer).astype(dt)
+        wd = _expert_of(w_down, e, layer).astype(dt)
+        g, u = jnp.dot(rows, wg), jnp.dot(rows, wu)       # as the forward's
+        a = jax.nn.silu(g) * u                            # [R, F]
+        dy = jnp.where(live[:, None], d_out[tok], 0.0)    # [R, H] float32
+        y = jnp.dot(a, wd, preferred_element_type=f32)
+        dpw = dpw.at[pairs].add(jnp.sum(y * dy, -1))
+        dy = (dy * pair_w[pairs][:, None]).astype(dt)
+        da = lax.dot_general(dy, wd, (((1,), (1,)), ((), ())),
+                             preferred_element_type=f32)  # [R, F]
+        gf, uf = g.astype(f32), u.astype(f32)
+        sig = jax.nn.sigmoid(gf)
+        dg = (da * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt)
+        du = (da * gf * sig).astype(dt)
+        tn = (((0,), (0,)), ((), ()))                     # a^T b
+        dwd = dwd.at[e].add(lax.dot_general(
+            a, dy, tn, preferred_element_type=f32))
+        dwg = dwg.at[e].add(lax.dot_general(
+            rows, dg, tn, preferred_element_type=f32))
+        dwu = dwu.at[e].add(lax.dot_general(
+            rows, du, tn, preferred_element_type=f32))
+        nt = (((1,), (1,)), ((), ()))                     # a b^T
+        drows = lax.dot_general(dg, wg, nt, preferred_element_type=f32) \
+            + lax.dot_general(du, wu, nt, preferred_element_type=f32)
+        return dx.at[tok].add(drows), dpw, dwg, dwu, dwd
+
+    def zeros(w):
+        return jnp.zeros(w.shape[-3:], f32)
+
+    dx, dpw, dwg, dwu, dwd = lax.fori_loop(
+        0, plan.trips, body,
+        (jnp.zeros(x.shape, f32), jnp.zeros(pair_w.shape, f32),
+         zeros(w_gate), zeros(w_up), zeros(w_down)))
+
+    def back(w, dw):
+        dw = dw.astype(w.dtype)
+        return dw if layer is None else jnp.zeros_like(w).at[layer].set(dw)
+
+    return (dx.astype(x.dtype), dpw.reshape(weights.shape).astype(
+        weights.dtype), None, back(w_gate, dwg), back(w_up, dwu),
+        back(w_down, dwd), None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
+    return _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
+                         first, R)[0]
+
+
+def _held_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
+    out, plan = _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
+                              first, R)
+    return out, (x, weights, idx, w_gate, w_up, w_down, layer, plan)
+
+
+def _held_vjp_bwd(first, R, res, g):
+    return _held_backward(*res, g[0], R)
+
+
+_held.defvjp(_held_vjp_fwd, _held_vjp_bwd)
 
 
 def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
-                    block_rows: int = 128, static_trip: bool = False,
-                    layer=None):
+                    block_rows: int = 128, layer=None):
     """SwiGLU experts over the (token, choice) pairs that land on the
     experts held here; what absent experts would add is left out.
 
@@ -196,44 +344,14 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     nothing: its weights are not read.  Nothing is bounded by a buffer of
     rows: a block gathers its rows from ``x`` and adds its result into
     ``out``; only the sorted pair indices are held, ``T * k + block_rows``
-    integers.  The trip count is read from the counts; under
-    ``static_trip`` (reverse-mode differentiation needs a static one) it is
-    :func:`held_expert_blocks` and the blocks past the last are empty."""
-    T, k = idx.shape
-    E = w_gate.shape[0 if layer is None else 1]
-    R = int(block_rows)
+    integers.  The trip count is read from the counts.
 
-    def of(w, e):
-        return (w[e] if layer is None else w[layer, e]).astype(dt)
-
-    local = idx.reshape(-1) - first
-    held = (local >= 0) & (local < E)
-    key = jnp.where(held, local, E)                       # absent: sorted last
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    counts = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
-    blocks = -(-counts // R)                              # per expert
-    block_end = jnp.cumsum(blocks)                        # [E]
-    row_start = jnp.cumsum(counts) - counts               # [E]
-    # pad so that a block's slice never clamps
-    order = jnp.concatenate([order, jnp.zeros((R,), jnp.int32)])
-    pair_w = weights.reshape(-1)
-    dt = x.dtype
-
-    def body(b, out):
-        e = jnp.minimum(jnp.searchsorted(block_end, b, side="right"), E - 1)
-        within = b - (block_end[e] - blocks[e])           # block of expert e
-        start = row_start[e] + within * R
-        pairs = lax.dynamic_slice_in_dim(order, start, R)
-        live = (start + jnp.arange(R)) < row_start[e] + counts[e]
-        live = live & (b < block_end[E - 1])
-        rows = x[pairs // k]                              # [R, H]
-        g = jnp.dot(rows, of(w_gate, e))
-        u = jnp.dot(rows, of(w_up, e))
-        y = jnp.dot(jax.nn.silu(g) * u, of(w_down, e),
-                    preferred_element_type=jnp.float32)
-        y = jnp.where(live[:, None], y * pair_w[pairs][:, None], 0.0)
-        return out.at[pairs // k].add(y)
-
-    out = jnp.zeros(x.shape, jnp.float32)
-    trips = held_expert_blocks(T, k, E, R) if static_trip else block_end[E - 1]
-    return lax.fori_loop(0, trips, body, out), counts
+    Reverse mode walks the same blocks (a ``custom_vjp``: a loop whose trip
+    count is read from data has no transpose of its own): the backward's
+    cost follows the load as the forward's does, and no block's
+    intermediate is kept between the two.  Gradients go to ``x``, to the
+    pair ``weights`` (and through them to the router) and to the three
+    weight leaves, accumulated in float32 and rounded once to the leaf's
+    type; given ``layer``, a leaf's gradient is zero outside that layer."""
+    return _held(x, weights, idx, w_gate, w_up, w_down, layer, int(first),
+                 int(block_rows))

@@ -50,6 +50,11 @@ No O(S^2) tensor ever touches HBM in either direction — this beats the
 reference's training memory profile (its attention materializes scores for
 the backward), and it is what makes S >= 8k practical on one chip.
 
+Q and K share one width ``d_qk`` and V, O and dO another, ``d_v`` (latent
+attention trains at 192 | 128; everywhere else the two are equal): a score
+tile contracts over ``d_qk``, acc^T and dv are ``d_v`` wide, dq and dk
+``d_qk``.  Nothing is padded to the wider of the two.
+
 Causal masking is BOTTOM-RIGHT aligned (query i attends to keys
 <= i + (S_k - S_q)), matching ops.causal_attention, so cross-length
 (prefix/KV-cache) calls agree with the oracle in both directions — except
@@ -197,7 +202,7 @@ def _fwd_tile(w, q, k, v, m, l, acc, mask):
 
 
 def _fwd_init(w, d):
-    """m, l [1, block_q]; acc^T [D, block_q]."""
+    """m, l [1, block_q]; acc^T [D_v, block_q]."""
     return (jnp.full((1, w.bq), NEG_INF, jnp.float32),
             jnp.zeros((1, w.bq), jnp.float32),
             jnp.zeros((d, w.bq), jnp.float32))
@@ -217,7 +222,7 @@ def _p_tile(w, q, k, lse, mask):
 
 
 def _dq_tile(w, q, k, v, do, lse, delta, mask):
-    """dq^T [D, block_q] of one tile, less the folded scale."""
+    """dq^T [D_qk, block_q] of one tile, less the folded scale."""
     p = _p_tile(w, q, k, lse, mask)
     ds = w.ds(p, _dot(v, do, _NT), delta)
     return _dot(k, ds.astype(k.dtype), _TN)
@@ -228,7 +233,8 @@ def _dq_finish(w, dq_ref, dq):
 
 
 def _dkdv_tile(w, q, k, v, do, lse, delta, mask):
-    """(dk, dv) [block_k, D] of one tile; q is the (pre-scaled) Q block."""
+    """(dk [block_k, D_qk], dv [block_k, D_v]) of one tile; q is the
+    (pre-scaled) Q block."""
     p = _p_tile(w, q, k, lse, mask)
     dv = _dot(p.astype(do.dtype), do, _NN)
     ds = w.ds(p, _dot(v, do, _NT), delta)
@@ -239,7 +245,8 @@ def _dkdv_tile(w, q, k, v, do, lse, delta, mask):
 
 def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, w):
     """Program (bh, qi): Q block qi against the whole K/V of its row.
-    q_ref/o_ref [block_q, D]; k_ref/v_ref [S_k, D]; lse_ref [1, block_q]."""
+    q_ref [block_q, D_qk], o_ref [block_q, D_v]; k_ref [S_k, D_qk], v_ref
+    [S_k, D_v]; lse_ref [1, block_q]."""
     qi = pl.program_id(1)
     q = w.q_block(q_ref[:])
 
@@ -249,7 +256,7 @@ def _fwd_resident_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, w):
                          w.mask(qi, ki) if masked else None)
 
     _fwd_finish(o_ref, lse_ref,
-                *_walk(w.k_spans(qi), tile, _fwd_init(w, q.shape[-1])))
+                *_walk(w.k_spans(qi), tile, _fwd_init(w, v_ref.shape[-1])))
 
 
 def _dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -402,17 +409,18 @@ def _params(resident: bool):
         else ("parallel", "parallel", "arbitrary")))
 
 
-def _plan(kernel: str, resident: bool, w: _Walk, s_q, s_k, d):
+def _plan(kernel: str, resident: bool, w: _Walk, s_q, s_k, d, d_v):
     """Which feeding a kernel got is fixed when the program is traced: one
     instant per pallas_call built says so in a JSONL trace or the xplane of
     a profiled compile."""
     trace.instant("flash.plan", {
         "kernel": kernel, "resident": int(resident), "block_q": w.bq,
-        "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d})
+        "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d, "d_v": d_v})
 
 
-def _specs(resident, w, s_q, s_k, d, *, own_q: bool):
-    """BlockSpecs (Q-side [.., D], Q-side statistics rows, K-side [.., D]).
+def _specs(resident, w, s_q, s_k, *, own_q: bool):
+    """(Q-side spec of a width, Q-side statistics rows' spec, K-side spec
+    of a width): Q and K are ``d_qk`` wide, V, O and dO ``d_v``.
 
     ``own_q``: the program owns a Q block and walks K blocks (forward, dQ);
     else it owns a K block and walks Q blocks (dK/dV).  Resident: the walked
@@ -441,89 +449,93 @@ def _specs(resident, w, s_q, s_k, d, *, own_q: bool):
                 first = jnp.minimum(w.q_spans(ki)[0][0], w.n_q - 1)
                 return bh, jnp.maximum(qi, first)
         q_rows, k_rows, stat_blocks = w.bq, w.bk, None
-    return (pl.BlockSpec((None, q_rows, d), lambda *g: (*q_at(*g), 0)),
+    return (lambda d: pl.BlockSpec((None, q_rows, d),
+                                   lambda *g: (*q_at(*g), 0)),
             pl.BlockSpec((None, stat_blocks, 1, w.bq),
                          lambda *g: (*q_at(*g), 0, 0)),
-            pl.BlockSpec((None, k_rows, d), lambda *g: (*k_at(*g), 0)))
+            lambda d: pl.BlockSpec((None, k_rows, d),
+                                   lambda *g: (*k_at(*g), 0)))
 
 
 def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
               block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
-    resident = _resident((s_k, d, k.dtype), (s_k, d, v.dtype))
-    _plan("fwd", resident, w, s_q, s_k, d)
-    q_spec, lse_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=True)
+    resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
+    _plan("fwd", resident, w, s_q, s_k, d, d_v)
+    q_side, lse_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_resident_kernel if resident
                           else _fwd_streamed_kernel, w=w),
         grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
-        in_specs=[q_spec, k_spec, k_spec],
-        out_specs=[q_spec, lse_spec],
+        in_specs=[q_side(d), k_side(d), k_side(d_v)],
+        out_specs=[q_side(d_v), lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s_q, d_v), q.dtype),
             jax.ShapeDtypeStruct((b * h, w.n_q, 1, w.bq), jnp.float32),
         ],
         scratch_shapes=[] if resident else [
             pltpu.VMEM((1, w.bq), jnp.float32),
             pltpu.VMEM((1, w.bq), jnp.float32),
-            pltpu.VMEM((d, w.bq), jnp.float32)],
+            pltpu.VMEM((d_v, w.bq), jnp.float32)],
         compiler_params=_params(resident),
         interpret=interpret,
     )(q.reshape(b * h, s_q, d), k.reshape(b * h, s_k, d),
-      v.reshape(b * h, s_k, d))
-    return out.reshape(b, h, s_q, d), lse
+      v.reshape(b * h, s_k, d_v))
+    return out.reshape(b, h, s_q, d_v), lse
 
 
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
                interpret):
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
               block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
 
     qf = q.reshape(b * h, s_q, d)
     kf = k.reshape(b * h, s_k, d)
-    vf = v.reshape(b * h, s_k, d)
-    dof = g.reshape(b * h, s_q, d)
+    vf = v.reshape(b * h, s_k, d_v)
+    dof = g.reshape(b * h, s_q, d_v)
     # delta = rowsum(dO * O): one fused elementwise+reduce, O(S*D) traffic
     delta = jnp.sum(dof.astype(jnp.float32)
-                    * out.reshape(b * h, s_q, d).astype(jnp.float32),
+                    * out.reshape(b * h, s_q, d_v).astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
 
     # dK/dV: a program owns a K block and walks the Q side
-    resident = _resident((s_q, d, q.dtype), (s_q, d, g.dtype),
+    resident = _resident((s_q, d, q.dtype), (s_q, d_v, g.dtype),
                          (8 * w.n_q, w.bq, jnp.float32),
                          (8 * w.n_q, w.bq, jnp.float32))
-    _plan("dkdv", resident, w, s_q, s_k, d)
-    q_spec, stat_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=False)
+    _plan("dkdv", resident, w, s_q, s_k, d, d_v)
+    q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=False)
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_resident_kernel if resident
                           else _dkdv_streamed_kernel, w=w),
         grid=(b * h, w.n_k) if resident else (b * h, w.n_k, w.n_q),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
-        out_specs=[k_spec, k_spec],
+        in_specs=[q_side(d), k_side(d), k_side(d_v), q_side(d_v), stat_spec,
+                  stat_spec],
+        out_specs=[k_side(d), k_side(d_v)],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, s_k, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, s_k, d_v), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((w.bk, d), jnp.float32),
-                        pltpu.VMEM((w.bk, d), jnp.float32)],
+                        pltpu.VMEM((w.bk, d_v), jnp.float32)],
         compiler_params=_params(resident),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
     # dQ: a program owns a Q block and walks the K side
-    resident = _resident((s_k, d, k.dtype), (s_k, d, v.dtype))
-    _plan("dq", resident, w, s_q, s_k, d)
-    q_spec, stat_spec, k_spec = _specs(resident, w, s_q, s_k, d, own_q=True)
+    resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
+    _plan("dq", resident, w, s_q, s_k, d, d_v)
+    q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
     dq = pl.pallas_call(
         functools.partial(_dq_resident_kernel if resident
                           else _dq_streamed_kernel, w=w),
         grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, stat_spec, stat_spec],
-        out_specs=q_spec,
+        in_specs=[q_side(d), k_side(d), k_side(d_v), q_side(d_v), stat_spec,
+                  stat_spec],
+        out_specs=q_side(d),
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[] if resident else [
             pltpu.VMEM((d, w.bq), jnp.float32)],
@@ -532,7 +544,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
     )(qf, kf, vf, dof, lse, delta)
 
     return (dq.reshape(b, h, s_q, d), dk.reshape(b, h, s_k, d),
-            dv.reshape(b, h, s_k, d))
+            dv.reshape(b, h, s_k, d_v))
 
 
 # ---------------------------------------------------------------- public op
@@ -580,7 +592,8 @@ def _mesh_partition(batch: int, heads: int):
 def flash_attention(q, k, v, *, causal: bool = False, scale=None,
                     block_q: int = 512, block_k: int = 512,
                     interpret=None):
-    """Fused attention: q,k,v [B, H, S, D] → [B, H, S_q, D].
+    """Fused attention: q, k [B, H, S, D_qk], v [B, H, S_k, D_v] →
+    [B, H, S_q, D_v] (the two widths may differ).
 
     Fully fused in both directions: forward walks K/V blocks with online
     softmax; backward recomputes probability tiles from the saved LSE

@@ -80,6 +80,13 @@ class Executor:
     loss_fn(params, model_state, batch, rng, train) ->
         (loss, (metrics_dict, new_model_state))
 
+    A metric that is itself a dict of scalars is a GROUP of per-step ids
+    (an expert model's counts under ``"moe"``): besides being returned with
+    the rest, each train step's groups go onto a ``train.<group>`` instant
+    (ids ``step`` and the group's own) when a later ``run`` finds them
+    finished.  ``run`` never waits for them: a group still being computed
+    stays queued.
+
     Usage:
         ex = Executor(loss_fn, optimizer, mesh=mesh)
         state = ex.init_state(variables)
@@ -146,6 +153,10 @@ class Executor:
         # set_grad_scale, which retraces)
         self.grad_scale = 1.0
         self._compiled: Dict[str, Callable] = {}
+        # (step number, {group: {id: scalar array}}) of train steps issued
+        # whose groups have not been put on their instants yet
+        self._groups: list = []
+        self._steps_issued = 0
 
     # ---- elastic resharding support (resilience/elastic.py) ----
     def set_mesh(self, mesh: Optional[Mesh]) -> None:
@@ -360,6 +371,8 @@ class Executor:
             self._compiled[name] = self._compile(name)
         if self._quant_sync() and name in ("train", "train_guarded"):
             self._record_grad_sync_bytes(state)
+        if self._groups:
+            self._emit_finished_groups()
         with trace.span("train.host_to_device"):
             batch = _device_batch(batch, self.mesh, self.dp_axis)
         sname = _STEP_SPAN.get(name)
@@ -373,7 +386,26 @@ class Executor:
                 # phase fetches a value next.  Only a TRACED run pays this
                 # barrier — tracing off keeps the async pipeline.
                 jax.block_until_ready(out)
-            return out
+        if name in ("train", "train_guarded"):
+            self._steps_issued += 1
+            groups = {k: v for k, v in out[1].items() if isinstance(v, dict)}
+            if groups:
+                self._groups.append((self._steps_issued, groups))
+        return out
+
+    def _emit_finished_groups(self) -> None:
+        """Put the queued steps' metric groups on their instants, oldest
+        first, as far as they are finished; the first one still being
+        computed ends the pass (no wait: ``is_ready`` only asks)."""
+        while self._groups:
+            step, groups = self._groups[0]
+            leaves = jax.tree_util.tree_leaves(groups)
+            if not all(a.is_ready() for a in leaves):
+                return
+            self._groups.pop(0)
+            for group, ids in jax.device_get(groups).items():
+                trace.instant("train." + group, {
+                    "step": step, **{k: v.item() for k, v in ids.items()}})
 
     def lower(self, name: str, state: TrainState, batch):
         """The named subexecutor lowered for ``(state, batch)`` — a

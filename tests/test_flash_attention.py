@@ -251,3 +251,54 @@ def test_flash_scale_not_a_power_of_two_stays_on_the_scores():
                     jax.grad(ref, argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3,
                                    atol=2e-4)
+
+
+# ---- two widths: Q, K of one, V, O, dO of another (latent attention) ----
+
+@pytest.mark.parametrize("shape", [(128, 128), (64, 192), (192, 64)],
+                         ids=["square", "sq_lt_sk", "sq_gt_sk"])
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_flash_two_widths_match_the_oracle(feeding, shape, monkeypatch):
+    """Forward, dQ and dK/dV with Q, K 48 wide and V, O, dO 32 wide, against
+    ``ops.causal_attention``, resident and streamed, ``S_q != S_k`` either
+    way round; every plan carries both widths."""
+    s_q, s_k = shape
+    plans = _plans(monkeypatch)
+    ks = jax.random.split(jax.random.PRNGKey(21), 4)
+    q = jax.random.normal(ks[0], (1, 2, s_q, 48))
+    k = jax.random.normal(ks[1], (1, 2, s_k, 48))
+    v = jax.random.normal(ks[2], (1, 2, s_k, 32))
+    g = jax.random.normal(ks[3], (1, 2, s_q, 32))
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=32, block_k=32), q, k, v)
+    lo = max(0, s_q - s_k)            # rows that see no key: 0 in the kernel
+    ref, ref_vjp = jax.vjp(causal_attention, q, k, v)
+    assert out.shape == (1, 2, s_q, 32)
+    for a, b in zip((out, *vjp(g)),
+                    (ref, *ref_vjp(g.at[:, :, :lo].set(0.0)))):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a)[:, :, lo:],
+                                   np.asarray(b)[:, :, lo:], rtol=1e-3,
+                                   atol=2e-4)
+    assert {a["kernel"] for _, a in plans} == {"fwd", "dkdv", "dq"}
+    assert all((a["d"], a["d_v"]) == (48, 32)
+               and a["resident"] == (feeding == "resident")
+               for _, a in plans)
+
+
+def test_flash_plan_carries_d_v_when_the_widths_agree(monkeypatch):
+    plans = _plans(monkeypatch)
+    q, k, v = qkv(S=64, seed=7)
+    flash_attention(q, k, v, causal=True)
+    assert plans and all(a["d"] == a["d_v"] == q.shape[-1] for _, a in plans)
+
+
+def test_flash_residency_counts_each_width(monkeypatch):
+    """One row of K at 192 and V at 128 (bfloat16, 8192 keys) is over the
+    budget where two operands of 64 at 1024 keys are not: the walk is chosen
+    from both widths."""
+    import sys
+    mod = sys.modules[_MOD]
+    assert not mod._resident((8192, 192, jnp.bfloat16),
+                             (8192, 128, jnp.bfloat16))
+    assert mod._resident((1024, 64, jnp.bfloat16), (1024, 64, jnp.bfloat16))

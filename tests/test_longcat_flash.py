@@ -25,7 +25,6 @@ from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer  # noqa: E402
 from hetu_tpu.models.longcat_flash import (  # noqa: E402
     LatentAttention, LongcatFlashConfig, LongcatFlashModel,
 )
-from hetu_tpu.ops.moe_ops import held_expert_blocks  # noqa: E402
 from hetu_tpu.serve import (  # noqa: E402
     ContinuousBatchingScheduler, KVCacheSpec, PagedServeEngine, Request,
 )
@@ -231,8 +230,10 @@ def test_the_shares_of_the_expert_layer_add_up(shares):
 
 # ----------------------------------------------------------- (e) no drops
 
-@pytest.mark.parametrize("static_trip", [False, True])
-def test_every_token_on_one_held_expert_is_still_computed(static_trip):
+@pytest.mark.parametrize("differentiated", [False, True])
+def test_every_token_on_one_held_expert_is_still_computed(differentiated):
+    """Forward, and under reverse mode (the walk's own backward): all 37
+    rows of the crowded expert are computed, ten blocks of four rows."""
     c = tiny()
     p = expert_layer_params(c)
     first, count = c.held
@@ -242,16 +243,21 @@ def test_every_token_on_one_held_expert_is_still_computed(static_trip):
     tokens = 37                          # ten blocks of four rows, one short
     u = jnp.asarray(np.random.default_rng(2).normal(size=(1, tokens, 32)),
                     jnp.float32)
-    out, stats = jax.jit(
-        lambda q, x: layer.apply(q, x, static_trip=static_trip))(mine, u)
+    if differentiated:
+        (_, (out, stats)), du = jax.jit(jax.value_and_grad(
+            lambda x, q: (lambda o, s: (jnp.sum(o), (o, s)))(
+                *layer.apply(q, x)), has_aux=True))(u, mine)
+        want_du = jax.grad(lambda x: jnp.sum(
+            ref.expert_layer(mine, x, dims_of(c))))(u)
+        np.testing.assert_allclose(du, want_du, rtol=1e-4, atol=1e-5)
+    else:
+        out, stats = jax.jit(lambda q, x: layer.apply(q, x))(mine, u)
     want = ref.expert_layer(mine, u, dims_of(c))
     np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
     _, idx = ref.expert_choice(p["router"], p["router_bias"], u, dims_of(c))
     assert int(np.sum(np.asarray(idx) == crowded)) == tokens
     assert int(stats[0]) >= tokens and int(stats[3]) >= 1
     assert sum(int(s) for s in stats[:3]) == tokens * c.moe_topk
-    assert held_expert_blocks(tokens, c.moe_topk, count,
-                              c.expert_block_rows) == 28 + 4
 
 
 # ---------------------------------------------- (f) loss and gradients

@@ -240,3 +240,105 @@ def test_topk_gate_pallas_impl_matches_xla():
     np.testing.assert_array_equal(np.asarray(ia), np.asarray(ib))
     np.testing.assert_allclose(np.asarray(ga), np.asarray(gb), rtol=1e-5)
     np.testing.assert_allclose(float(aa), float(ab), rtol=1e-5)
+
+
+# ---- the held-expert walk: forward and backward follow the load ----
+
+def _held_case(routing: str, T=40, k=3, H=16, F=12, E_all=12, first=4, E=4,
+               seed=0):
+    """Tokens, pair weights, choices and the held experts' weights; ``even``
+    spreads the choices, ``skewed`` sends every token's first choice to one
+    held expert, ``empty`` leaves two held experts unchosen."""
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, size=(T, k)), jnp.float32)
+    if routing == "even":
+        idx = np.stack([rng.permutation(E_all)[:k] for _ in range(T)])
+    elif routing == "skewed":
+        idx = np.stack([np.concatenate([[first + 1], rng.permutation(
+            [e for e in range(E_all) if e != first + 1])[:k - 1]])
+            for _ in range(T)])
+    else:
+        pool = [e for e in range(E_all) if e not in (first, first + 3)]
+        idx = np.stack([rng.permutation(pool)[:k] for _ in range(T)])
+    ws = [jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+          for s in ((E, H, F), (E, H, F), (E, F, H))]
+    return x, w, jnp.asarray(idx, jnp.int32), ws
+
+
+def _dense_composition(x, w, idx, wg, wu, wd, first):
+    """Every held expert over every token, weighted by the token's weight on
+    it (zero where it was not chosen)."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(wg.shape[0]):
+        we = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1, keepdims=True)
+        out = out + we * ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e])
+    return out
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed", "empty"])
+def test_held_walk_gradients_equal_the_dense_compositions(routing):
+    from hetu_tpu.ops.moe_ops import held_expert_ffn
+
+    x, w, idx, (wg, wu, wd) = _held_case(routing)
+    probe = jnp.asarray(np.random.default_rng(9).normal(size=x.shape),
+                        jnp.float32)
+
+    def walk(x, w, wg, wu, wd):
+        out, _ = held_expert_ffn(x, w, idx, wg, wu, wd, first=4,
+                                 block_rows=8)
+        return jnp.sum(out * probe)
+
+    def dense(x, w, wg, wu, wd):
+        return jnp.sum(_dense_composition(x, w, idx, wg, wu, wd, 4) * probe)
+
+    got = jax.jit(jax.grad(walk, argnums=(0, 1, 2, 3, 4)))(x, w, wg, wu, wd)
+    want = jax.grad(dense, argnums=(0, 1, 2, 3, 4))(x, w, wg, wu, wd)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    if routing == "empty":      # nobody chose them: zero, weights unread
+        for a in got[2:]:
+            assert not np.any(np.asarray(a[0])) \
+                and not np.any(np.asarray(a[3]))
+    # stacked leaves read in place: the gradient is the layer's, zero elsewhere
+    stacked = [jnp.stack([jnp.zeros_like(a), a]) for a in (wg, wu, wd)]
+    got2 = jax.grad(lambda *ws: jnp.sum(held_expert_ffn(
+        x, w, idx, *ws, first=4, block_rows=8, layer=1)[0] * probe),
+        argnums=(0, 1, 2))(*stacked)
+    for a, b in zip(got2, want[2:]):
+        np.testing.assert_allclose(a[1], b, rtol=1e-4, atol=1e-5)
+        assert not np.any(np.asarray(a[0]))
+
+
+@pytest.mark.parametrize("routing", ["even", "skewed", "empty"])
+def test_held_walk_trips_forward_and_backward_follow_the_counts(
+        routing, monkeypatch):
+    """Both walks run ``sum(ceil(count_e / R))`` trips: counted by a host
+    callback put round the block lookup both loop bodies share."""
+    from hetu_tpu.ops import moe_ops
+
+    x, w, idx, (wg, wu, wd) = _held_case(routing, seed=3)
+    trips = []
+    block = moe_ops._walk_block
+
+    def counted(plan, b, R):
+        jax.debug.callback(lambda: trips.append(1))
+        return block(plan, b, R)
+
+    monkeypatch.setattr(moe_ops, "_walk_block", counted)
+    (_, counts), pull = jax.vjp(
+        lambda x, wg: moe_ops.held_expert_ffn(x, w, idx, wg, wu, wd, first=4,
+                                              block_rows=8), x, wg)
+    jax.effects_barrier()
+    want = int(np.sum(-(-np.asarray(counts) // 8)))
+    local = np.asarray(idx) - 4
+    assert np.array_equal(counts, np.bincount(
+        local[(local >= 0) & (local < 4)], minlength=4))
+    forward = len(trips)
+    assert forward == want
+    pull((jnp.ones(x.shape, jnp.float32), np.zeros(
+        counts.shape, jax.dtypes.float0)))
+    jax.effects_barrier()
+    assert len(trips) - forward == want
+    # far fewer than the worst case a static trip count would walk
+    assert want < -(-x.shape[0] * 3 // 8) + 4

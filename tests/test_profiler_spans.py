@@ -468,3 +468,83 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
         assert e[3]["g1_view_bytes"] == e[3]["batch"] * 3 * 4 * row
     for e in _named(events, "serve.prefill_chunk.launch"):
         assert e[3]["g1_view_bytes"] == 5 * 4 * row
+
+
+# ------------------------------------------ a trained expert model's counts
+
+def _expert_model():
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.deepseek_v3 import DeepseekV3Config, DeepseekV3Model
+
+    model = DeepseekV3Model(DeepseekV3Config(
+        vocab_size=97, hidden_size=32, num_layers=3, num_heads=2,
+        kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, ffn_size=48, expert_ffn_size=16, n_routed_experts=8,
+        moe_topk=2, held=(2, 4), expert_block_rows=8, ce_row_chunk=32,
+        max_position=64, dtype=jnp.float32))
+    batch = (np.random.default_rng(0).integers(0, 97, (2, 32)).astype(
+        np.int32),)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(0)), batch
+
+
+def test_train_moe_instant_carries_each_steps_counts(tmp_path):
+    """``train.moe``: one instant a finished step, written by a LATER
+    ``run`` (the last step's is never written: nothing runs after it), with
+    the step's number and the expert layers' counts as ids."""
+    from hetu_tpu.models.deepseek_v3 import MOE_STEP_IDS
+
+    model, variables, batch = _expert_model()
+    ex = Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-3))
+    state = ex.init_state(variables)
+    seen = []
+    with profiled(tmp_path):
+        for _ in range(4):
+            state, metrics = ex.run("train", state, batch)
+            jax.block_until_ready(metrics)   # the test's wait, not run()'s
+            seen.append({k: np.asarray(v).item()
+                         for k, v in metrics["moe"].items()})
+    events = _named(hetu_threads(tmp_path)[0], "train.moe")
+    assert [e[3]["step"] for e in events] == [1, 2, 3]
+    for e, want in zip(events, seen):
+        ids = dict(e[3])
+        assert set(ids) == {"step", *MOE_STEP_IDS}
+        assert ids.pop("step") and ids == pytest.approx(want)
+        assert ids["moe_held"] + ids["moe_absent"] == 2 * 32 * 2 * 2
+        assert ids["moe_blocks_fwd"] == ids["moe_blocks_bwd"] \
+            >= ids["moe_held"] / 8
+        assert e[2] - e[1] < 1e6                 # an instant: no length
+    assert 0 < events[-1][3]["router_bias_absmax"] <= 0.0031
+
+
+def test_no_step_waits_for_its_counts():
+    """``run`` only ASKS whether a queued step's counts are finished: while
+    they are not, it issues the next step and keeps them queued; it never
+    blocks on them."""
+    model, variables, batch = _expert_model()
+    ex = Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-3))
+    state = ex.init_state(variables)
+    state, _ = ex.run("train", state, batch)
+    (step, groups), = ex._groups
+    assert step == 1 and set(groups) == {"moe"}
+
+    class NotYet:
+        def is_ready(self):
+            return False
+
+        def block_until_ready(self):
+            raise AssertionError("run() waited for a step's counts")
+
+        def __array__(self, *a, **kw):
+            raise AssertionError("run() fetched unfinished counts")
+
+    ex._groups[0] = (1, {"moe": {"moe_held": NotYet()}})
+    state, _ = ex.run("train", state, batch)
+    assert [s for s, _ in ex._groups] == [1, 2]
+    # a model with no groups queues nothing
+    gpt = GPTModel(GPTConfig(vocab_size=64, hidden_size=32, num_layers=1,
+                             num_heads=2, ffn_size=64, max_position=32))
+    ex2 = Executor(gpt.lm_loss_fn(), optim.AdamWOptimizer(1e-3))
+    s2 = ex2.init_state(gpt.init(jax.random.PRNGKey(0)))
+    ex2.run("train", s2, (batch[0][:, :16] % 64,))
+    assert ex2._groups == []
