@@ -46,7 +46,9 @@ later serving section would use (inherited, not exercised here).  **Split**:
 ``project`` (no query rank, and none of LongCat's ``sqrt(hidden / rank)``
 factors on ``q`` and ``c``) and the training attention (flash, heads-major).
 
-Layers run under ``lax.scan`` with per-layer remat; a layer's leaves reach
+Layers run under ``lax.scan`` with per-layer remat (``ops.remat``: a layer
+keeps its input and the flash kernel's output and LSE rows, and recomputes
+the rest, the held-expert walk included); a layer's leaves reach
 the scan body as that layer's slice, so that the held-expert walk's
 gradients are one layer's and not a stacked leaf's (``layer=`` reads in
 place, and its cotangent is as large as the whole leaf).  Master weights are
@@ -339,7 +341,7 @@ class DeepseekV3Model(Module):
             return out, tuple(counts)
 
         if c.remat:
-            dense, sparse = jax.checkpoint(dense), jax.checkpoint(sparse)
+            dense, sparse = ops.remat(dense), ops.remat(sparse)
         h, _ = jax.lax.scan(dense, h, p["dense"])
         h, (chosen, stats, blocks) = jax.lax.scan(
             sparse, h, (p["sparse"], bias))

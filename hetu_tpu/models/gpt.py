@@ -33,8 +33,10 @@ class GPTConfig:
     remat: bool = False  # recompute each layer in backward: O(L*S*H) residuals
     # instead of O(L*S^2) attention scores — the jax.checkpoint analog of the
     # reference's recompute/checkpoint knobs (Galvatron's ckpt flag)
-    remat_policy: str = "full"  # 'full' = save only layer inputs;
-    # 'dots' = also save matmul outputs (recompute elementwise only)
+    remat_policy: str = "full"  # 'full' = save the layer's input and, where
+    # attention runs the flash kernel, its output and LSE rows (ops.remat:
+    # the backward never runs the forward kernel again); 'dots' = also save
+    # matmul outputs (recompute elementwise only)
     fused_ce: bool = True  # lm_loss via ops.lm_head_cross_entropy: head
     # matmul fused into a chunked exact-LSE CE so [B*S, V] f32 logits never
     # materialize (the unfused path is the reference's
@@ -85,9 +87,7 @@ class GPTModel(Module):
             return out, None
 
         if c.remat:
-            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                      if c.remat_policy == "dots" else None)
-            layer = jax.checkpoint(layer, policy=policy)
+            layer = ops.remat(layer, c.remat_policy)
         keys = (jax.random.split(rng, c.num_layers) if rng is not None
                 else jnp.zeros((c.num_layers, 2), jnp.uint32))
         h, _ = jax.lax.scan(layer, h, (p["blocks"], keys))

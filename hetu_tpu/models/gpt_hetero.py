@@ -105,7 +105,7 @@ class HeteroGPT(GPTModel):
             if self.layer_remat is not None and self.layer_remat[i]:
                 # execute the plan's per-layer ckpt flag: activations of
                 # this layer are rematerialized in backward instead of held
-                block_fn = jax.checkpoint(block_fn)
+                block_fn = ops.remat(block_fn)
             h = block_fn(p[f"layer{i}"], h, lrng)
         return ops.layer_norm(h.astype(jnp.float32), p["ln_f_scale"],
                               p["ln_f_bias"])

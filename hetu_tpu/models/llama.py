@@ -235,7 +235,7 @@ class LlamaModel(Module):
             return out, None
 
         if c.remat:
-            layer = jax.checkpoint(layer)
+            layer = ops.remat(layer)
         h, _ = jax.lax.scan(layer, h, p["blocks"])
         return ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
 
@@ -371,7 +371,7 @@ class HeteroLlama(LlamaModel):
                 return self.block.apply({"params": lp, "state": {}}, hh,
                                         cos, sin)[0]
             if self.layer_remat is not None and self.layer_remat[i]:
-                block_fn = jax.checkpoint(block_fn)
+                block_fn = ops.remat(block_fn)
             h = block_fn(p[f"layer{i}"], h)
         return ops.rms_norm(h, p["rms_f_scale"], eps=c.rms_eps)
 

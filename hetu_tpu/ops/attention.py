@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu.ops.pallas_kernels import paged_attention
+from hetu_tpu.ops.pallas_kernels.flash_attention import SAVED_LSE, SAVED_OUT
 from hetu_tpu.utils.platform import (
     default_backend_is_tpu as _default_backend_is_tpu,
 )
@@ -44,6 +45,20 @@ def causal_attention(q, k, v, *, scale=None, window=None):
         mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
                           k=s_k - s_q - int(window))
     return attention(q, k, v, mask=mask, scale=scale)
+
+
+def remat(layer, policy: str = "full"):
+    """``layer`` recomputed in the backward pass (``jax.checkpoint``), less
+    the flash kernel's forward: its output and LSE rows, all its backward
+    needs beside q, k and v, are kept by name, so dK/dV and dQ run against
+    the saved pair.  ``policy`` 'full' keeps nothing else but the layer's
+    inputs; 'dots' keeps the matmul results too.  A layer whose attention
+    is an XLA composition carries no such names and keeps what it kept."""
+    keep = jax.checkpoint_policies.save_only_these_names(SAVED_OUT, SAVED_LSE)
+    if policy == "dots":
+        keep = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable, keep)
+    return jax.checkpoint(layer, policy=keep)
 
 
 # ---- serving decode: attention over a preallocated cache ----

@@ -73,6 +73,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import AxisType, PartitionSpec as P
@@ -82,6 +83,13 @@ from hetu_tpu.telemetry import trace
 from hetu_tpu.utils.platform import auto_interpret
 
 NEG_INF = -1e30
+
+# The names (``jax.ad_checkpoint.checkpoint_name``) of the forward kernel's
+# two results, which are all its backward needs beside q, k and v.  A
+# ``jax.checkpoint`` whose policy saves them (``ops.remat``) keeps both, and
+# the recomputed layer then holds no forward kernel.
+SAVED_OUT = "hetu.flash.out"
+SAVED_LSE = "hetu.flash.lse"
 
 # What one row's resident operands may take of VMEM, as the pipeline holds
 # them (two buffers each, padded to whole (8, 128) tiles).  With the
@@ -560,6 +568,10 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     out, lse = _flash_fwd(q, k, v, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k,
                           interpret=interpret)
+    # named before ``out`` leaves both ways, so that a recomputed layer
+    # reads the saved value as the primal and as the residual
+    out = checkpoint_name(out, SAVED_OUT)
+    lse = checkpoint_name(lse, SAVED_LSE)
     return out, (q, k, v, out, lse)
 
 
@@ -612,6 +624,12 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     refuses to lower one outside a fully manual region), so under a mesh
     the call is wrapped in a ``shard_map`` over the batch and head axes
     (:func:`_mesh_partition`): each device runs the kernel on its own shard.
+
+    Under differentiation the output and the LSE rows carry the names
+    ``SAVED_OUT`` and ``SAVED_LSE`` (inside the ``shard_map`` too).  A name
+    is the identity wherever no ``jax.checkpoint`` policy asks for it; a
+    layer recomputed through ``ops.remat`` keeps the two, so its backward
+    runs dK/dV and dQ and not the forward kernel a second time.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5

@@ -302,3 +302,77 @@ def test_flash_residency_counts_each_width(monkeypatch):
     assert not mod._resident((8192, 192, jnp.bfloat16),
                              (8192, 128, jnp.bfloat16))
     assert mod._resident((1024, 64, jnp.bfloat16), (1024, 64, jnp.bfloat16))
+
+
+# ---- a recomputed layer keeps the forward kernel's output and LSE rows
+
+def _remat_gpt_grads(mesh_axes, policy, named, monkeypatch, run=True):
+    """(loss, gradients, jaxpr text of the gradient) of a small flash GPT
+    with per-layer remat: through ``ops.remat`` as it is (``named``), or
+    through the plain ``jax.checkpoint`` it stands in for.  Run operation
+    by operation (``jax.disable_jit``), so that no compiler's fusion choices
+    stand between the two programs: what is compared is their arithmetic."""
+    import hetu_tpu as ht
+    from hetu_tpu import ops
+    from hetu_tpu.models.gpt import GPTConfig, GPTModel
+    from hetu_tpu.parallel.mesh import mesh_context
+    from hetu_tpu.parallel.strategies.simple import MegatronLM
+
+    if not named:
+        dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        monkeypatch.setattr(ops, "remat", lambda layer, policy="full":
+                            jax.checkpoint(layer, policy=dots
+                                           if policy == "dots" else None))
+    model = GPTModel(GPTConfig(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+        ffn_size=64, max_position=32, dropout_rate=0.0,
+        attention_impl="flash", remat=True, remat_policy=policy,
+        ce_row_chunk=32))
+    params = model.init(jax.random.PRNGKey(0))["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
+    mesh = ht.make_mesh(**mesh_axes) if mesh_axes else None
+    if mesh is not None:
+        params = jax.device_put(params, MegatronLM().shardings(params, mesh))
+    loss_fn = model.lm_loss_fn()
+
+    def grad(p):
+        return jax.value_and_grad(
+            lambda p: loss_fn(p, {}, (ids,), None, True)[0])(p)
+
+    with mesh_context(mesh):
+        text = str(jax.make_jaxpr(grad)(params))
+        if not run:
+            return None, None, text
+        with jax.disable_jit():
+            return (*grad(params), text)
+
+
+@pytest.mark.parametrize("mesh_axes,policy", [
+    (None, "full"), ({"dp": 2, "tp": 2}, "full"), (None, "dots")],
+    ids=["one-device-full", "dp2tp2-full", "one-device-dots"])
+def test_remat_keeps_the_forward_kernels_results_bit_for_bit(
+        mesh_axes, policy, monkeypatch):
+    """The saved output and LSE rows equal the recomputed ones, so loss and
+    every gradient leaf are the plain ``jax.checkpoint``'s bit for bit; what
+    differs is the gradient's program: the scanned layer holds the forward
+    kernel once, not twice (through the kernel's ``shard_map`` too), and
+    under 'dots' the matmul results are still kept beside the two names."""
+    loss, grads, text = _remat_gpt_grads(mesh_axes, policy, True,
+                                         monkeypatch)
+    want_loss, want, want_text = _remat_gpt_grads(mesh_axes, policy, False,
+                                                  monkeypatch)
+    assert np.asarray(loss).tobytes() == np.asarray(want_loss).tobytes()
+    got = jax.tree_util.tree_leaves_with_path(grads)
+    assert len(got) == len(jax.tree_util.tree_leaves(want)) > 10
+    for (path, a), b in zip(got, jax.tree_util.tree_leaves(want)):
+        assert np.abs(np.asarray(b)).max() > 0, jax.tree_util.keystr(path)
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), \
+            jax.tree_util.keystr(path)
+    # forward, dK/dV, dQ | and the recomputed forward
+    assert (text.count("pallas_call["), want_text.count("pallas_call[")) \
+        == (3, 4)
+    if policy == "dots":
+        monkeypatch.undo()
+        full_text = _remat_gpt_grads(mesh_axes, "full", True, monkeypatch,
+                                     run=False)[2]
+        assert text.count("dot_general") < full_text.count("dot_general")
