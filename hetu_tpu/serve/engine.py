@@ -247,6 +247,10 @@ class PagedServeEngine:
         # identity and absent experts, held experts hit); most have none
         self._stat_names = tuple(getattr(model, "step_stats", ()))
         self._released_seen = 0   # of cache.window_released, by _count
+        # programs launched so far, chunks and decode rounds counted
+        # together: ``seq`` on a launch span, and on the fetch span that
+        # waits for that launch
+        self._seq = 0
         # the further groups of the model's cache (window layers beside
         # full ones): the fixed widths of their tables in the chunk programs
         # and in the decode program (a ring as wide as the step needs)
@@ -256,7 +260,8 @@ class PagedServeEngine:
         self._ring_decode = tuple(g.spec.ring_pages(1, ps)
                                   for g in self._more)
         # bytes ONE cache layer's view of one page holds, K and V together,
-        # a group: what a call's ``view_bytes`` id multiplies by its pages
+        # a group: what a chunk's ``view_bytes`` id multiplies by its pages
+        # (a decode round gathers no view on a TPU and states none)
         self._page_view_bytes = tuple(
             ps * (g.bytes_per_token // g.num_layers) for g in spec.groups)
         k_row, v_row = spec.row_shapes()
@@ -377,7 +382,7 @@ class PagedServeEngine:
         more = tuple(zip((g.spec.row_shapes() for g in self._more),
                          self._ring_chunk))
 
-        def fn(params, k_pool, v_pool, aux):
+        def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
             # operands (ids | write pages | write offsets | page table |
             # start | last) into one device_put, like the decode step; a
@@ -414,7 +419,10 @@ class PagedServeEngine:
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
             return _pools(k), _pools(v), tok, tuple(stats)
 
-        return jax.jit(fn, donate_argnums=(1, 2))
+        # the function's name is the program's on the device: the profiler's
+        # ``XLA Modules`` events, compile logs and HLO dumps read
+        # ``jit_hetu_serve_prefill_chunk``
+        return jax.jit(hetu_serve_prefill_chunk, donate_argnums=(1, 2))
 
     def _build_decode(self):
         model = self.model
@@ -424,7 +432,7 @@ class PagedServeEngine:
         # a pool laid over a mesh says so: its one-query step keeps the view
         sharded = self.mesh is not None
 
-        def fn(params, k_pool, v_pool, aux):
+        def hetu_serve_decode(params, k_pool, v_pool, aux):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
             # the step (page table | length | token | write page | write
             # offset) into ONE device_put — five small uploads per step
@@ -464,7 +472,8 @@ class PagedServeEngine:
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return _pools(k), _pools(v), nxt, tuple(stats)
 
-        return jax.jit(fn, donate_argnums=(1, 2))
+        # named as the chunk program is: ``jit_hetu_serve_decode``
+        return jax.jit(hetu_serve_decode, donate_argnums=(1, 2))
 
     # ---- admission (the scheduler's page-budget backpressure) ----
     def admission_pages(self, prompt_len: int, max_tokens: int,
@@ -607,13 +616,15 @@ class PagedServeEngine:
                 aux[3 * s:3 * s + len(t)] = t
                 aux[3 * s + n_table] = start
                 aux[3 * s + n_table + 1] = size - 1
+                self._seq += 1
             with trace.span("serve.prefill_chunk.launch",
                             {"start": int(start), "tokens": int(size),
                              "bucket": int(s),
-                             "view_bytes": n_table * self._page_view_bytes[0]}):
+                             "view_bytes": n_table * self._page_view_bytes[0],
+                             "seq": self._seq}):
                 k, v, tok, stats = chunk_fn(
                     self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
-            with trace.span("serve.prefill_chunk.fetch"):
+            with trace.span("serve.prefill_chunk.fetch", {"seq": self._seq}):
                 tok = int(tok)  # the host blocked on the device
                 counts = self._count(stats)
             with trace.span("serve.prefill_chunk.post", counts):
@@ -685,16 +696,18 @@ class PagedServeEngine:
                     aux[at + s:at + s + ring] = g.device_table(slot, ring)
                     at += s + ring
                 k_pool, v_pool = self._pool_args()
+                self._seq += 1
                 launch = {"start": int(start), "tokens": int(size),
                           "bucket": int(s),
-                          "view_bytes": n_table * self._page_view_bytes[0]}
+                          "view_bytes": n_table * self._page_view_bytes[0],
+                          "seq": self._seq}
                 for i, ring in enumerate(self._ring_chunk, 1):
                     launch[f"g{i}_view_bytes"] = \
                         ring * self._page_view_bytes[i]
             with trace.span("serve.prefill_chunk.launch", launch):
                 k, v, tok, stats = chunk_fn(
                     self.params, k_pool, v_pool, jnp.asarray(aux))
-            with trace.span("serve.prefill_chunk.fetch"):
+            with trace.span("serve.prefill_chunk.fetch", {"seq": self._seq}):
                 tok = int(tok)  # the host blocked on the device
                 counts = self._held(self._count(stats), slot, end)
             with trace.span("serve.prefill_chunk.post", counts):
@@ -796,14 +809,14 @@ class PagedServeEngine:
                 aux[:, n_pg + 1] = self.last_tokens[sl]
                 aux[:, n_pg + 2] = wp
                 aux[:, n_pg + 3] = wo
+                self._seq += 1
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb),
-                             "view_bytes": int(bb * n_pg)
-                             * self._page_view_bytes[0]}):
+                             "seq": self._seq}):
                 k, v, nxt, stats = self._decode_fn(
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(aux))
-            with trace.span("serve.decode.fetch"):
+            with trace.span("serve.decode.fetch", {"seq": self._seq}):
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = self._count(stats)
             with trace.span("serve.decode.post", counts):
@@ -882,16 +895,13 @@ class PagedServeEngine:
                     aux[:, at + ring] = wp_g
                     at += ring + 1
                 k_pool, v_pool = self._pool_args()
-                launch = {"pages": int(n_pg), "batch": int(bb),
-                          "view_bytes": int(bb * n_pg)
-                          * self._page_view_bytes[0]}
-                for i, ring in enumerate(self._ring_decode, 1):
-                    launch[f"g{i}_view_bytes"] = \
-                        int(bb * ring) * self._page_view_bytes[i]
-            with trace.span("serve.decode.launch", launch):
+                self._seq += 1
+            with trace.span("serve.decode.launch",
+                            {"pages": int(n_pg), "batch": int(bb),
+                             "seq": self._seq}):
                 k, v, nxt, stats = self._decode_fn(
                     self.params, k_pool, v_pool, jnp.asarray(aux))
-            with trace.span("serve.decode.fetch"):
+            with trace.span("serve.decode.fetch", {"seq": self._seq}):
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = self._held(self._count(stats), act,
                                      self.cache.lengths[act] + 1)

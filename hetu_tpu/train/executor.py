@@ -378,7 +378,10 @@ class Executor:
         sname = _STEP_SPAN.get(name)
         if sname is None:
             sname = _STEP_SPAN.setdefault(name, "train.step." + name)
-        with trace.span(sname), mesh_context(self.mesh):
+        # ``step``: the number this launch's train step gets below, the one
+        # its ``train.<group>`` instants carry
+        with trace.span(sname, {"step": self._steps_issued + 1}), \
+                mesh_context(self.mesh):
             out = self._compiled[name](state, batch)
             if trace.enabled():
                 # jit dispatch is async: without a sync the span times the

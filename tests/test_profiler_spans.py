@@ -292,10 +292,13 @@ def test_train_spans_follow_each_other(recorded):
 @pytest.mark.parametrize("name,keys", [
     ("serve.step", {"step"}),
     ("serve.decode", {"active"}),
-    ("serve.decode.launch", {"pages", "batch", "view_bytes"}),
+    ("serve.decode.launch", {"pages", "batch", "seq"}),
+    ("serve.decode.fetch", {"seq"}),
     ("serve.prefill_chunk", {"slot"}),
     ("serve.prefill_chunk.launch", {"start", "tokens", "bucket",
-                                    "view_bytes"}),
+                                    "view_bytes", "seq"}),
+    ("serve.prefill_chunk.fetch", {"seq"}),
+    ("train.step.train", {"step"}),
 ])
 def test_ids_decode_from_the_event(recorded, name, keys):
     evs = _named(recorded["events"], name)
@@ -314,6 +317,71 @@ def test_ids_say_what_ran(recorded):
         sorted([5, 16, 4, 16, 16, 1])    # 5, 20 and 33 in chunks of 16
     assert all(c[3]["bucket"] == 16 for c in chunks)
     assert max(e[3]["active"] for e in _named(events, "serve.decode")) == 2
+
+
+# ------------------------------------------- a launch's identity (ISSUE 36)
+
+def test_seq_rises_by_one_a_launch_across_rounds_and_chunks(recorded):
+    """One counter an engine: a decode round and a prefill chunk draw from
+    the same numbers, in the order they were launched."""
+    events = recorded["events"]
+    launches = sorted(_named(events, "serve.decode.launch")
+                      + _named(events, "serve.prefill_chunk.launch"),
+                      key=lambda e: e[1])
+    seqs = [e[3]["seq"] for e in launches]
+    assert len(seqs) >= 12 and {e[0] for e in launches} == {
+        "serve.decode.launch", "serve.prefill_chunk.launch"}
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))
+    # the session was the engine's second pass over the same requests
+    assert seqs[0] == len(seqs) + 1
+
+
+@pytest.mark.parametrize("kind", ["serve.decode", "serve.prefill_chunk"])
+def test_a_fetch_carries_the_seq_of_the_launch_it_waits_for(recorded, kind):
+    events = recorded["events"]
+    calls = _named(events, kind)
+    assert calls
+    for call in calls:
+        kids = {k[0]: k for k in _children(events, call)}
+        launch, fetch = kids[kind + ".launch"], kids[kind + ".fetch"]
+        assert launch[3]["seq"] == fetch[3]["seq"]
+        assert launch[2] <= fetch[1]
+
+
+def test_train_step_span_carries_the_steps_number(recorded):
+    """``step`` counts the train steps the executor has issued, this one
+    included; the session's three follow the three before it."""
+    steps = [e[3]["step"]
+             for e in _named(recorded["events"], "train.step.train")]
+    assert steps == [TRAIN_STEPS + 1 + i for i in range(TRAIN_STEPS)]
+
+
+@pytest.mark.parametrize("program,module", [
+    ("decode", "jit_hetu_serve_decode"),
+    ("chunk", "jit_hetu_serve_prefill_chunk"),
+    ("train", "jit__train_step"),
+])
+def test_program_lowers_under_its_own_module_name(recorded, program, module):
+    """The jitted function's name is the program's on the device (the
+    profiler's ``XLA Modules`` line, compile logs, HLO dumps)."""
+    text = recorded["lowered_session"][program]
+    assert f"module @{module} " in text
+    assert "module @jit_fn" not in text
+
+
+@pytest.mark.parametrize("chunks,spans", [(0, 9), (1, 14)])
+def test_spans_a_scheduler_step(recorded, chunks, spans):
+    """A step with a decode round opens 9 spans, 14 with one chunk beside
+    it: the launch ids added none."""
+    events = recorded["events"]
+    counted = []
+    for st in _named(events, "serve.step"):
+        kids = _children(events, st)
+        names = [k[0] for k in kids]
+        if names.count("serve.decode") == 1 \
+                and names.count("serve.prefill_chunk") == chunks:
+            counted.append(1 + len(kids))
+    assert counted and set(counted) == {spans}
 
 
 def test_params_held_says_what_the_build_did_to_the_weights(tmp_path):
@@ -335,9 +403,10 @@ def test_params_held_says_what_the_build_did_to_the_weights(tmp_path):
 
 
 def test_cache_ids_say_what_a_call_holds(tmp_path):
-    """``serve.cache_spec`` carries the pools' bytes, and each launch the
-    bytes ONE cache layer's gathered view holds in that call (K and V):
-    far under the pools, and under what a view of every layer would be."""
+    """``serve.cache_spec`` carries the pools' bytes, and a chunk's launch
+    the bytes ONE cache layer's gathered view holds in that call (K and V):
+    far under the pools, and under what a view of every layer would be.  A
+    decode round gathers no view on a TPU and states none."""
     model, variables = _model()
     with profiled(tmp_path):
         eng, sched = _serving(model, variables)
@@ -347,9 +416,9 @@ def test_cache_ids_say_what_a_call_holds(tmp_path):
     pools = eng.cache.k.nbytes + eng.cache.v.nbytes
     assert spec[3]["pool_bytes"] == pools == 2 * 2 * 33 * 8 * 64 * 4
     row = spec[3]["bytes_per_token"] // spec[3]["cache_layers"]  # K + V
-    for e in _named(events, "serve.decode.launch"):
-        ids = e[3]
-        assert ids["view_bytes"] == ids["batch"] * ids["pages"] * 8 * row
+    rounds = _named(events, "serve.decode.launch")
+    assert rounds and all(set(e[3]) == {"pages", "batch", "seq"}
+                          for e in rounds)
     chunks = _named(events, "serve.prefill_chunk.launch")
     assert {c[3]["view_bytes"] for c in chunks} == {8 * 8 * row}
     assert all(e[3]["view_bytes"] * spec[3]["cache_layers"] < pools
@@ -422,8 +491,9 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
     tables hold (``kv_pages_full``, ``kv_pages_window``), what one group
     would hold (``kv_pages_if_one_group``) and the pages dropped from
     behind the window since the last call, beside the expert layer's
-    counts; ``serve.cache_spec`` lists the second group; each launch says
-    what a layer of each group gathers."""
+    counts; ``serve.cache_spec`` lists the second group; a chunk's launch
+    says what a layer of each group gathers, a decode round's states no
+    view."""
     import jax.numpy as jnp
 
     from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
@@ -463,10 +533,13 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
     last = max(posts, key=lambda e: e[1])[3]
     assert last["kv_pages_full"] + last["kv_pages_window"] \
         < 0.8 * last["kv_pages_if_one_group"]
-    for e in _named(events, "serve.decode.launch"):
-        assert e[3]["view_bytes"] == e[3]["batch"] * e[3]["pages"] * 4 * row
-        assert e[3]["g1_view_bytes"] == e[3]["batch"] * 3 * 4 * row
-    for e in _named(events, "serve.prefill_chunk.launch"):
+    rounds = _named(events, "serve.decode.launch")
+    assert rounds and all(set(e[3]) == {"pages", "batch", "seq"}
+                          for e in rounds)
+    chunks = _named(events, "serve.prefill_chunk.launch")
+    assert chunks
+    for e in chunks:
+        assert e[3]["view_bytes"] == 128 // 4 * 4 * row   # the slot's table
         assert e[3]["g1_view_bytes"] == 5 * 4 * row
 
 
@@ -504,8 +577,15 @@ def test_train_moe_instant_carries_each_steps_counts(tmp_path):
             jax.block_until_ready(metrics)   # the test's wait, not run()'s
             seen.append({k: np.asarray(v).item()
                          for k, v in metrics["moe"].items()})
-    events = _named(hetu_threads(tmp_path)[0], "train.moe")
+    every = hetu_threads(tmp_path)[0]
+    events = _named(every, "train.moe")
     assert [e[3]["step"] for e in events] == [1, 2, 3]
+    # the step's own span carries the same number, and the instant of step n
+    # is written by a later run: after span n, before span n + 2
+    spans = _named(every, "train.step.train")
+    assert [e[3] for e in spans] == [{"step": n} for n in (1, 2, 3, 4)]
+    for e, own, later in zip(events, spans, spans[1:]):
+        assert own[3]["step"] == e[3]["step"] and own[2] <= e[1] <= later[1]
     for e, want in zip(events, seen):
         ids = dict(e[3])
         assert set(ids) == {"step", *MOE_STEP_IDS}
