@@ -12,10 +12,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
 from hetu_tpu.layers.base import Module, held_as
+from hetu_tpu.ops.attention import SAVED_REDUCED
 
 
 class MultiHeadAttention(Module):
@@ -80,7 +82,9 @@ class MultiHeadAttention(Module):
         y = ops.linear(out.astype(self.dtype),
                        p["out_weight"].astype(self.dtype),
                        p["out_bias"].astype(self.dtype))
-        return y, {}
+        # row-parallel under Megatron: a layer recomputed under a 'tp' mesh
+        # keeps the summed value (ops.remat); the identity anywhere else
+        return checkpoint_name(y, SAVED_REDUCED), {}
 
     def _causal_core(self, q, k, v):
         """The unmasked causal attention core, honoring attention_impl

@@ -21,10 +21,12 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
 from hetu_tpu.layers.base import Module, held_as
+from hetu_tpu.ops.attention import SAVED_REDUCED
 
 
 @dataclass
@@ -110,8 +112,11 @@ class LlamaBlock(Module):
         else:
             out = ops.causal_attention(q, k, v)
         out = jnp.moveaxis(out, 1, 2).reshape(b, s, h)
-        return ops.linear(out.astype(c.dtype),
-                          p["out_weight"].astype(c.dtype))
+        # row-parallel under Megatron: kept by a layer recomputed under a
+        # 'tp' mesh (ops.remat); the identity anywhere else
+        return checkpoint_name(
+            ops.linear(out.astype(c.dtype), p["out_weight"].astype(c.dtype)),
+            SAVED_REDUCED)
 
     def apply(self, variables, x, cos, sin):
         p = variables["params"]

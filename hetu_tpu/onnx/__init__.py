@@ -46,6 +46,8 @@ _PRIM_TO_ONNX = {
     "gather": "Gather", "dynamic_slice": "Slice", "select_n": "Where",
     "convert_element_type": "Cast", "stop_gradient": "Identity",
     "custom_jvp_call": "Identity", "integer_pow": "Pow", "squeeze": "Squeeze",
+    # jax.ad_checkpoint.checkpoint_name: a label for a remat policy
+    "name": "Identity",
     "argmax": "ArgMax", "iota": "Range", "clamp": "Clip",
 }
 
@@ -165,6 +167,7 @@ _HANDLER_PARAMS = {
     "integer_pow": {"y"},
     "squeeze": {"dimensions"},
     "concatenate": {"dimension"},
+    "name": {"name"},
 }
 
 _IMPORT_HANDLERS = {
@@ -190,6 +193,7 @@ _IMPORT_HANDLERS = {
     "convert_element_type": lambda at: (
         lambda x: x.astype(at["new_dtype"])),
     "stop_gradient": lambda at: (lambda x: jax.lax.stop_gradient(x)),
+    "name": lambda at: (lambda x: x),
     "integer_pow": lambda at: (lambda x: jnp.power(x, at["y"])),
     "squeeze": lambda at: (lambda x: jnp.squeeze(
         x, axis=tuple(at["dimensions"]))),

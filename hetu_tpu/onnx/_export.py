@@ -101,6 +101,8 @@ _SIMPLE = {
     "max": "Max", "min": "Min", "pow": "Pow", "logistic": "Sigmoid",
     "erf": "Erf", "stop_gradient": "Identity", "copy": "Identity",
     "and": "And", "or": "Or", "not": "Not", "eq": "Equal",
+    # jax.ad_checkpoint.checkpoint_name (what ops.remat keeps by name)
+    "name": "Identity",
 }
 _COMPARE = {"lt": ("Less", False), "le": ("LessOrEqual", False),
             "gt": ("Greater", False), "ge": ("GreaterOrEqual", False)}

@@ -15,9 +15,16 @@ import jax.numpy as jnp
 
 from hetu_tpu.ops.pallas_kernels import paged_attention
 from hetu_tpu.ops.pallas_kernels.flash_attention import SAVED_LSE, SAVED_OUT
+from hetu_tpu.parallel.mesh import AXIS_TP
+from hetu_tpu.telemetry import trace
 from hetu_tpu.utils.platform import (
     default_backend_is_tpu as _default_backend_is_tpu,
 )
+
+# The name (``jax.ad_checkpoint.checkpoint_name``) of a row-parallel
+# attention out-projection's result: under a mesh that splits 'tp' it is a
+# sum over chips (Megatron's all-reduce), and :func:`remat` keeps it there.
+SAVED_REDUCED = "hetu.tp.reduced"
 
 
 def attention(q, k, v, *, mask=None, scale=None):
@@ -53,8 +60,23 @@ def remat(layer, policy: str = "full"):
     needs beside q, k and v, are kept by name, so dK/dV and dQ run against
     the saved pair.  ``policy`` 'full' keeps nothing else but the layer's
     inputs; 'dots' keeps the matmul results too.  A layer whose attention
-    is an XLA composition carries no such names and keeps what it kept."""
-    keep = jax.checkpoint_policies.save_only_these_names(SAVED_OUT, SAVED_LSE)
+    is an XLA composition carries no such names and keeps what it kept.
+
+    Where the mesh in context (``jax.set_mesh``) splits 'tp', the attention
+    out-projection's result (``SAVED_REDUCED``) is kept too: the backward
+    needs it (the second norm's input) and recomputing it there means the
+    matmul and its all-reduce again, a value crossing chips twice.  With no
+    mesh, or 'tp' of one, it is a local matmul and dearer to hold than to
+    redo, so the policy does not ask for it.  The mesh stands in for the
+    split, which is the strategy's (``MegatronLM.ROW``) and cannot be read
+    off a traced weight: a 'tp' mesh whose strategy leaves ``out_weight``
+    whole (``DataParallel``, a hidden size 'tp' does not divide) keeps one
+    activation a layer for a matmul alone.  One ``remat.plan`` instant a
+    call says which it was."""
+    tp = jax.sharding.get_abstract_mesh().shape.get(AXIS_TP, 1)
+    names = (SAVED_OUT, SAVED_LSE) + ((SAVED_REDUCED,) if tp > 1 else ())
+    trace.instant("remat.plan", {"tp": tp, "reduced": int(tp > 1)})
+    keep = jax.checkpoint_policies.save_only_these_names(*names)
     if policy == "dots":
         keep = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable, keep)

@@ -628,3 +628,41 @@ def test_no_step_waits_for_its_counts():
     s2 = ex2.init_state(gpt.init(jax.random.PRNGKey(0)))
     ex2.run("train", s2, (batch[0][:, :16] % 64,))
     assert ex2._groups == []
+
+
+# ------------------------------- what a recomputed layer keeps (ISSUE 38)
+
+@pytest.mark.parametrize("mesh_axes,tp", [(None, 1), ({"dp": 4}, 1),
+                                          ({"dp": 2, "tp": 2}, 2)],
+                         ids=["no-mesh", "dp4-tp1", "dp2tp2"])
+def test_remat_plan_says_whether_the_reduction_is_kept(mesh_axes, tp):
+    """One ``remat.plan`` instant per ``ops.remat`` call, when the step is
+    TRACED: ``reduced`` is 1 where the mesh in context splits 'tp' and 0
+    anywhere else.  A second run of the compiled step emits none."""
+    import hetu_tpu as ht
+    from hetu_tpu.parallel.mesh import mesh_context
+    from hetu_tpu.parallel.strategies import MegatronLM
+
+    model = GPTModel(GPTConfig(
+        vocab_size=97, hidden_size=64, num_layers=2, num_heads=4,
+        ffn_size=128, max_position=64, dropout_rate=0.0, remat=True))
+    params = model.init(jax.random.PRNGKey(0))["params"]
+    ids = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 97)
+    mesh = ht.make_mesh(**mesh_axes) if mesh_axes else None
+    if mesh is not None:
+        params = jax.device_put(params, MegatronLM().shardings(params, mesh))
+    loss_fn = model.lm_loss_fn()
+    grad = jax.jit(jax.grad(lambda p: loss_fn(p, {}, (ids,), None, True)[0]))
+
+    def plans(t):
+        return [e["args"] for e in t.events if e["name"] == "remat.plan"]
+
+    t = trace.enable()
+    try:
+        with mesh_context(mesh):
+            jax.block_until_ready(grad(params))
+            assert plans(t) == [{"reduced": int(tp > 1), "tp": tp}]
+            jax.block_until_ready(grad(params))
+            assert len(plans(t)) == 1
+    finally:
+        trace.disable()
