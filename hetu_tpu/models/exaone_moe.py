@@ -113,7 +113,55 @@ class ExaoneMoeConfig:
                              "the query heads")
 
 
-class ExaoneMoeModel(Module):
+class GroupedHeads:
+    """Grouped-query attention's projections as K-EXAONE has them, for any
+    model whose configuration ``self.c`` gives ``num_heads``,
+    ``num_kv_heads``, ``head_dim``, ``rms_eps`` and ``dtype``
+    (``models/mellum.py`` shares them): Q and K normalised per head, the
+    half-rotation layout over the whole head, the out-projection.  ``p`` is
+    the attention leaves stacked over layers, ``l`` the layer read."""
+
+    def _norm(self, x, scale):
+        return ops.rms_norm(x, scale, eps=self.c.rms_eps)
+
+    @staticmethod
+    def _rotate(x, cos, sin):
+        """Half-rotation layout over the whole head: x [B, S, heads, D],
+        cos/sin [B, S, D / 2]; float32 inside, result in x's dtype."""
+        xf = x.astype(jnp.float32)
+        d2 = x.shape[-1] // 2
+        x1, x2 = xf[..., :d2], xf[..., d2:]
+        cos, sin = cos[:, :, None], sin[:, :, None]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    def _qkv(self, p, l: int, a, cos, sin, rotate: bool):
+        """a [B, S, H] normed -> (q [B, heads, S, D], k [B, S, kv_heads, D],
+        v the same) of layer ``l``: q and k normalised per head, rotated
+        where ``rotate``.  k and v are the rows a cache holds."""
+        c, dt = self.c, self.c.dtype
+        b, s, _ = a.shape
+        q = ops.linear(a, p["q"][l].astype(dt), trans_w=True).reshape(
+            b, s, c.num_heads, c.head_dim)
+        k = ops.linear(a, p["k"][l].astype(dt), trans_w=True).reshape(
+            b, s, c.num_kv_heads, c.head_dim)
+        v = ops.linear(a, p["v"][l].astype(dt)).reshape(
+            b, s, c.num_kv_heads, c.head_dim)
+        q = self._norm(q, p["q_norm"][l])
+        k = self._norm(k, p["k_norm"][l])
+        if rotate:
+            q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
+        return jnp.moveaxis(q, 1, 2), k, v
+
+    def _out(self, p, l: int, o):
+        """o [B, heads, S, D] -> [B, S, H]."""
+        b, _, s, _ = o.shape
+        o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
+        return ops.linear(o.astype(self.c.dtype),
+                          p["o"][l].astype(self.c.dtype))
+
+
+class ExaoneMoeModel(GroupedHeads, Module):
     """``params["layers"]``: ``attn_norm``/``ffn_norm`` [L, H], ``attn`` {q
     [heads * D, H], k [kv_heads * D, H], v [H, kv_heads * D], o [heads * D,
     H], q_norm, k_norm} stacked over the L layers, ``ffn`` {gate, up,
@@ -222,9 +270,6 @@ class ExaoneMoeModel(Module):
         }, "state": {}}
 
     # ---- pieces of a layer ----
-    def _norm(self, x, scale):
-        return ops.rms_norm(x, scale, eps=self.c.rms_eps)
-
     def rope_at(self, pos):
         """cos/sin [..., head_dim / 2] float32 at absolute positions."""
         d = self.c.head_dim
@@ -232,42 +277,6 @@ class ExaoneMoeModel(Module):
             jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         ang = pos.astype(jnp.float32)[..., None] * inv
         return jnp.cos(ang), jnp.sin(ang)
-
-    @staticmethod
-    def _rotate(x, cos, sin):
-        """Half-rotation layout over the whole head: x [B, S, heads, D],
-        cos/sin [B, S, D / 2]; float32 inside, result in x's dtype."""
-        xf = x.astype(jnp.float32)
-        d2 = x.shape[-1] // 2
-        x1, x2 = xf[..., :d2], xf[..., d2:]
-        cos, sin = cos[:, :, None], sin[:, :, None]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1).astype(x.dtype)
-
-    def _qkv(self, p, l: int, a, cos, sin, rotate: bool):
-        """a [B, S, H] normed -> (q [B, heads, S, D], k [B, S, kv_heads, D],
-        v the same) of layer ``l``: q and k normalised per head, rotated on
-        a window layer.  k and v are the rows the cache holds."""
-        c, dt = self.c, self.c.dtype
-        b, s, _ = a.shape
-        q = ops.linear(a, p["q"][l].astype(dt), trans_w=True).reshape(
-            b, s, c.num_heads, c.head_dim)
-        k = ops.linear(a, p["k"][l].astype(dt), trans_w=True).reshape(
-            b, s, c.num_kv_heads, c.head_dim)
-        v = ops.linear(a, p["v"][l].astype(dt)).reshape(
-            b, s, c.num_kv_heads, c.head_dim)
-        q = self._norm(q, p["q_norm"][l])
-        k = self._norm(k, p["k_norm"][l])
-        if rotate:
-            q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
-        return jnp.moveaxis(q, 1, 2), k, v
-
-    def _out(self, p, l: int, o):
-        """o [B, heads, S, D] -> [B, S, H]."""
-        b, _, s, _ = o.shape
-        o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
-        return ops.linear(o.astype(self.c.dtype),
-                          p["o"][l].astype(self.c.dtype))
 
     def _ffn(self, p, l: int, x):
         dt = self.c.dtype

@@ -62,6 +62,29 @@ query rows whose mask hides EVERY key (only possible when s_q > s_k):
 there the kernel returns 0 output and 0 gradients, whereas the XLA
 composition softmaxes the uniform -1e30 scores into garbage averages.
 Zero is the deliberate semantics for an all-masked row.
+
+**A window** (``window=W``, causal only): a query sees itself and the
+``W - 1`` keys before it, aligned as the diagonal is (the rule of
+``ops.causal_attention``).  The walk then has a LOWER edge as well: a Q
+block's K blocks are up to three spans, the blocks the window's lower edge
+crosses (masked), the blocks wholly inside (no iota, compare or select) and
+the blocks the diagonal crosses (masked; a block both edges cross is masked
+once, with both), and a K block's Q blocks mirror it and END at the last
+query block that still sees the K block.  Blocks outside every span are
+neither fetched nor stepped over (resident) or re-name the block already in
+VMEM (streamed: the index maps clamp to the first AND the last live block).
+At S = 16,384, W = 1,024 and 512 x 512 tiles a Q block walks 3 K blocks
+where a causal one walks 16.5 on average.
+
+**Grouped heads**: K and V may come with ``heads / g`` heads.  The program of
+query head ``h`` reads K and V of head ``h // g`` through its index map, and
+the dK/dV program that owns a K block of one KV head accumulates over the
+``g`` query heads of its group before it writes (resident: the group's Q,
+dO and statistics are one block and the heads a loop; streamed: the walked
+axes are heads x Q blocks), so dK and dV leave at ``heads / g`` heads.
+Neither a repeated copy of K or V nor a ``[B, heads, S, D]`` dK or dV ever
+exists in HBM.  With ``g = 1`` and no window every call traces to the
+kernels it traced to before either existed.
 Interpret mode runs the same kernels on CPU for correctness tests.
 """
 
@@ -108,20 +131,38 @@ def _dot(a, b, dims):
 
 # ------------------------------------------------------------ the tile walk
 
+def _clip(x, lo, hi):
+    """``min(max(x, lo), hi)`` of block indices: traced in a kernel or an
+    index map, plain integers where a call's tiles are counted."""
+    if all(isinstance(a, int) for a in (x, lo, hi)):
+        return max(lo, min(x, hi))
+    return jnp.clip(x, lo, hi)
+
+
 class _Walk:
     """Static facts of one call that every kernel body shares: tile sizes,
-    block counts, the causal offset, and how ``scale`` is applied."""
+    block counts, the causal offset, the window, how many query heads read
+    one KV head, and how ``scale`` is applied."""
 
-    def __init__(self, *, s_q, s_k, block_q, block_k, scale, causal):
+    def __init__(self, *, s_q, s_k, block_q, block_k, scale, causal,
+                 window=None, group=1):
         self.bq, self.bk = block_q, block_k
         self.n_q, self.n_k = s_q // block_q, s_k // block_k
         self.causal = causal
         self.offset = s_k - s_q
+        # a window as long as the keys hides nothing the diagonal leaves
+        self.window = None if window is None or window >= s_k else window
+        self.g = group
         self.scale = scale
         # q * 2**n is exact in any float dtype: fold the scale into the Q
         # block once instead of multiplying every f32 score tile
         self.fold = math.frexp(scale)[0] == 0.5
-        # a row that sees no key at all exists only when s_q > s_k
+        # a row that sees no key at all exists only when s_q > s_k (under a
+        # window too: a query whose position is a key's sees that key).  A
+        # row may still see nothing in the FIRST tiles of its walk, the ones
+        # the window's lower edge crosses; what the forward then sums is
+        # wiped by the correction of the first tile that holds a real score
+        # (exp(-1e30 - m) is 0), and the diagonal tile always does.
         self.guard = causal and self.offset < 0
 
     def q_block(self, q):
@@ -139,37 +180,66 @@ class _Walk:
         return ds if self.fold else ds * self.scale
 
     def mask(self, qi, ki):
-        """[block_k, block_q]: key ki*bk + r is visible to query qi*bq + c."""
+        """[block_k, block_q]: key ki*bk + r is visible to query qi*bq + c:
+        not after it and, under a window, fewer than ``window`` before."""
         rel = lax.broadcasted_iota(jnp.int32, (self.bk, self.bq), 1) - \
             lax.broadcasted_iota(jnp.int32, (self.bk, self.bq), 0)
-        return rel >= ki * self.bk - qi * self.bq - self.offset
+        edge = ki * self.bk - qi * self.bq - self.offset
+        if self.window is None:
+            return rel >= edge
+        return (rel >= edge) & (rel < edge + self.window)
 
     # A walk is a few (lo, hi, masked) spans of block indices, in the order
     # they are visited; blocks outside every span are dead (above the
-    # diagonal) and are neither fetched nor stepped over.
+    # diagonal, or wholly behind the window) and are neither fetched nor
+    # stepped over.  ``qi`` / ``ki`` traced or a plain integer (``tiles``).
 
     def k_spans(self, qi):
-        """The K blocks Q block qi walks: those wholly below the diagonal,
-        unmasked, then the ones it crosses."""
+        """The K blocks Q block qi walks: those the window's lower edge
+        crosses (none without a window), those wholly visible, unmasked,
+        then the ones the diagonal crosses."""
         if not self.causal:
             return ((0, self.n_k, False),)
         q_first = qi * self.bq + self.offset     # last key row 0 sees
         q_last = q_first + self.bq - 1           # last key any row sees
-        n_live = jnp.clip((q_last + self.bk) // self.bk, 0, self.n_k)
-        n_full = jnp.clip((q_first + 1) // self.bk, 0, n_live)
-        return (0, n_full, False), (n_full, n_live, True)
+        n_live = _clip((q_last + self.bk) // self.bk, 0, self.n_k)
+        n_full = _clip((q_first + 1) // self.bk, 0, n_live)
+        if self.window is None:
+            return (0, n_full, False), (n_full, n_live, True)
+        lo_first = q_first - self.window + 1     # first key row 0 sees
+        lo_last = q_last - self.window + 1       # first key every row sees
+        k_start = _clip(lo_first // self.bk, 0, n_live)
+        k_in = _clip((lo_last + self.bk - 1) // self.bk, k_start, n_live)
+        n_full = _clip(n_full, k_in, n_live)
+        return ((k_start, k_in, True), (k_in, n_full, False),
+                (n_full, n_live, True))
 
     def q_spans(self, ki):
         """The Q blocks K block ki walks: from the first live one, those the
-        diagonal crosses, then the ones wholly below it, unmasked."""
+        diagonal crosses, then the ones wholly visible, unmasked, and under
+        a window the ones its lower edge crosses, the last that see the
+        block."""
         if not self.causal:
             return ((0, self.n_q, False),)
         k_first = ki * self.bk - self.offset
         k_last = k_first + self.bk - 1
-        q_start = jnp.clip(k_first // self.bq, 0, self.n_q)
-        q_full = jnp.clip((k_last + self.bq - 1) // self.bq, q_start,
-                          self.n_q)
-        return (q_start, q_full, True), (q_full, self.n_q, False)
+        q_start = _clip(k_first // self.bq, 0, self.n_q)
+        if self.window is None:
+            q_full = _clip((k_last + self.bq - 1) // self.bq, q_start,
+                           self.n_q)
+            return (q_start, q_full, True), (q_full, self.n_q, False)
+        q_end = _clip((k_last + self.window - 1) // self.bq + 1, q_start,
+                      self.n_q)
+        q_full = _clip((k_last + self.bq - 1) // self.bq, q_start, q_end)
+        q_in = _clip((k_first + self.window) // self.bq, q_full, q_end)
+        return ((q_start, q_full, True), (q_full, q_in, False),
+                (q_in, q_end, True))
+
+    def tiles(self) -> int:
+        """Tiles one head's walk visits, a kernel call (the forward's count;
+        dQ's is the same walk and dK/dV's the same tiles by columns)."""
+        return sum(hi - lo for qi in range(self.n_q)
+                   for lo, hi, _ in self.k_spans(qi))
 
 
 def _walk(spans, tile, carry=None):
@@ -187,9 +257,14 @@ def _step(spans, i, tile):
         pl.when((i >= lo) & (i < hi))(functools.partial(tile, masked))
 
 
-def _rows(ref, i, block, n):
+def _rows(ref, i, block, n, head=None):
     """Block i of a resident [S, D] operand (the whole of it when it is one
-    block: a static read, which also keeps sub-tile lengths legal)."""
+    block: a static read, which also keeps sub-tile lengths legal); of head
+    ``head`` where the operand holds a group's heads, [g, S, D]."""
+    if head is not None:
+        if n == 1:
+            return ref[head]
+        return ref[head, pl.ds(pl.multiple_of(i * block, block), block), :]
     if n == 1:
         return ref[:]
     return ref[pl.ds(pl.multiple_of(i * block, block), block), :]
@@ -286,24 +361,36 @@ def _dq_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dkdv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, w):
-    """Program (bh, ki): dk/dv of K block ki over the Q blocks of its row.
-    k_ref/v_ref [block_k, D]; q_ref/do_ref [S_q, D]; lse_ref/delta_ref
-    [q_blocks, 1, block_q].  The two [block_k, D] accumulators are scratch:
-    as loop values they cost 13% of the kernel on the chip."""
+    """Program (b * kv_heads, ki): dk/dv of K block ki over the Q blocks of
+    its row, of every query head that reads its KV head.  k_ref/v_ref
+    [block_k, D]; q_ref/do_ref [S_q, D]; lse_ref/delta_ref [q_blocks, 1,
+    block_q]; with ``g`` query heads a KV head each of the four has the
+    group's heads in front ([g, S_q, D], [g, q_blocks, 1, block_q]) and the
+    walk is run a head.  The two [block_k, D] accumulators are scratch: as
+    loop values they cost 13% of the kernel on the chip."""
     ki = pl.program_id(1)
     k, v = k_ref[:], v_ref[:]
     dk_acc[:] = jnp.zeros_like(dk_acc)
     dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def tile(qi, masked, _):
-        dk, dv = _dkdv_tile(
-            w, w.q_block(_rows(q_ref, qi, w.bq, w.n_q)), k, v,
-            _rows(do_ref, qi, w.bq, w.n_q), lse_ref[qi], delta_ref[qi],
-            w.mask(qi, ki) if masked else None)
-        dk_acc[:] += dk
-        dv_acc[:] += dv
+    def head(h):
+        def stat(ref, qi):
+            return ref[qi] if h is None else ref[h, qi]
 
-    _walk(w.q_spans(ki), tile)
+        def tile(qi, masked, _):
+            dk, dv = _dkdv_tile(
+                w, w.q_block(_rows(q_ref, qi, w.bq, w.n_q, h)), k, v,
+                _rows(do_ref, qi, w.bq, w.n_q, h), stat(lse_ref, qi),
+                stat(delta_ref, qi), w.mask(qi, ki) if masked else None)
+            dk_acc[:] += dk
+            dv_acc[:] += dv
+
+        _walk(w.q_spans(ki), tile)
+
+    if w.g == 1:
+        head(None)
+    else:
+        lax.fori_loop(0, w.g, lambda h, _: head(h), None)
     dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
     dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
 
@@ -355,10 +442,17 @@ def _dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dkdv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, w):
-    """Program (bh, ki, qi): accumulate dk/dv of one K block over Q blocks."""
-    ki, qi = pl.program_id(1), pl.program_id(2)
+    """Program (bh, ki, qi): accumulate dk/dv of one K block over Q blocks;
+    with ``g`` query heads a KV head, program (b * kv_heads, ki, head of the
+    group, qi): over the group's heads as well."""
+    ki, qi = pl.program_id(1), pl.program_id(2 if w.g == 1 else 3)
 
-    @pl.when(qi == 0)
+    def at(head, block):
+        """This step is Q block ``block`` of the group's head ``head``."""
+        here = qi == block
+        return here if w.g == 1 else (pl.program_id(2) == head) & here
+
+    @pl.when(at(0, 0))
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -372,7 +466,7 @@ def _dkdv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     _step(w.q_spans(ki), qi, tile)
 
-    @pl.when(qi == w.n_q - 1)
+    @pl.when(at(w.g - 1, w.n_q - 1))
     def _():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -408,22 +502,42 @@ def _resident(*operands) -> bool:
     return 2 * sum(_vmem_bytes(*o) for o in operands) <= _RESIDENT_VMEM_BYTES
 
 
-def _params(resident: bool):
+def _params(grid):
     """bh and the block axis a program owns are parallel.  A streamed walk
-    adds the walked axis innermost; it carries the VMEM accumulators and
+    adds the walked axes innermost; they carry the VMEM accumulators and
     must run in order.  (A resident walk is a loop inside the program.)"""
     return pltpu.CompilerParams(dimension_semantics=(
-        ("parallel", "parallel") if resident
-        else ("parallel", "parallel", "arbitrary")))
+        ("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2)))
 
 
-def _plan(kernel: str, resident: bool, w: _Walk, s_q, s_k, d, d_v):
+def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window):
     """Which feeding a kernel got is fixed when the program is traced: one
     instant per pallas_call built says so in a JSONL trace or the xplane of
-    a profiled compile."""
+    a profiled compile.  ``tiles_live`` is what the call's walk visits (every
+    head's), ``tiles_causal`` what it would without the window."""
+    (b, h, s_q, d), s_k = q.shape, k.shape[2]
+    plain = _Walk(s_q=s_q, s_k=s_k, block_q=w.bq, block_k=w.bk,
+                  scale=w.scale, causal=w.causal)
     trace.instant("flash.plan", {
         "kernel": kernel, "resident": int(resident), "block_q": w.bq,
-        "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d, "d_v": d_v})
+        "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d,
+        "d_v": v.shape[3], "window": int(window or 0),
+        "kv_heads": k.shape[1], "tiles_live": b * h * w.tiles(),
+        "tiles_causal": b * h * plain.tiles()})
+
+
+def _live(i, spans, n, *, ends: str = "both"):
+    """Grid index ``i`` of a streamed walk over ``n`` blocks clamped into
+    the walk's live range, so a dead step re-names a block already in
+    VMEM.  ``ends``: a walk without a window is dead at one end only (a Q
+    block's K blocks start at 0: ``"last"``; a K block's Q blocks run to
+    the end: ``"first"``), and is clamped there alone, as it always was."""
+    first = jnp.minimum(spans[0][0], n - 1)
+    if ends == "first":
+        return jnp.maximum(i, first)
+    if ends == "last":
+        return jnp.minimum(i, jnp.maximum(spans[-1][1] - 1, 0))
+    return jnp.clip(i, first, jnp.maximum(spans[-1][1] - 1, first))
 
 
 def _specs(resident, w, s_q, s_k, *, own_q: bool):
@@ -434,49 +548,78 @@ def _specs(resident, w, s_q, s_k, *, own_q: bool):
     else it owns a K block and walks Q blocks (dK/dV).  Resident: the walked
     side is the whole row.  Streamed: the walked side follows the innermost
     grid axis, clamped into the live range so dead steps fetch nothing.  The
-    statistics (lse, delta) are [bh, q_blocks, 1, block_q]."""
+    statistics (lse, delta) are [bh, q_blocks, 1, block_q].
+
+    With ``g`` query heads a KV head the leading axis of the Q side counts
+    query heads and the K side's KV heads: a program that owns a Q block
+    reads the K side at ``bh // g``; one that owns a K block of KV head
+    ``j`` holds the group's ``g`` heads of the Q side as one block
+    (resident) or walks them on a grid axis of their own (streamed)."""
+    g = w.g
+    lead = None                # the Q side's leading block dim: one head
+
+    def kv(bh):
+        return bh if g == 1 else bh // g
+
     if resident:
-        def own(bh, i):
-            return bh, i
-        def row(bh, i):
-            return bh, 0
-        q_at, k_at = (own, row) if own_q else (row, own)
-        q_rows, k_rows = (w.bq, s_k) if own_q else (s_q, w.bk)
-        stat_blocks = None if own_q else w.n_q
+        if own_q:
+            def q_at(bh, i):
+                return bh, i
+            def k_at(bh, i):
+                return kv(bh), 0
+            q_rows, k_rows, stat_blocks = w.bq, s_k, None
+        else:
+            def q_at(bh, i):
+                return bh, 0
+            def k_at(bh, i):
+                return bh, i
+            q_rows, k_rows, stat_blocks = s_q, w.bk, w.n_q
+            lead = None if g == 1 else g
     else:
+        windowed = w.window is not None
         if own_q:
             def q_at(bh, qi, ki):
                 return bh, qi
             def k_at(bh, qi, ki):
-                last = jnp.maximum(w.k_spans(qi)[-1][1] - 1, 0)
-                return bh, jnp.minimum(ki, last)
+                return kv(bh), _live(ki, w.k_spans(qi), w.n_k,
+                                     ends="both" if windowed else "last")
         else:
-            def k_at(bh, ki, qi):
+            def k_at(bh, ki, *walked):
                 return bh, ki
-            def q_at(bh, ki, qi):
-                first = jnp.minimum(w.q_spans(ki)[0][0], w.n_q - 1)
-                return bh, jnp.maximum(qi, first)
+            def q_at(bh, ki, *walked):     # walked: (head of the group,) qi
+                head = bh if g == 1 else bh * g + walked[0]
+                return head, _live(walked[-1], w.q_spans(ki), w.n_q,
+                                   ends="both" if windowed else "first")
         q_rows, k_rows, stat_blocks = w.bq, w.bk, None
-    return (lambda d: pl.BlockSpec((None, q_rows, d),
-                                   lambda *g: (*q_at(*g), 0)),
-            pl.BlockSpec((None, stat_blocks, 1, w.bq),
-                         lambda *g: (*q_at(*g), 0, 0)),
+    return (lambda d: pl.BlockSpec((lead, q_rows, d),
+                                   lambda *i: (*q_at(*i), 0)),
+            pl.BlockSpec((lead, stat_blocks, 1, w.bq),
+                         lambda *i: (*q_at(*i), 0, 0)),
             lambda d: pl.BlockSpec((None, k_rows, d),
-                                   lambda *g: (*k_at(*g), 0)))
+                                   lambda *i: (*k_at(*i), 0)))
 
 
-def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
+def _call_walk(q, k, *, scale, causal, window, block_q, block_k) -> _Walk:
+    (_, h, s_q, _), (_, h_kv, s_k, _) = q.shape, k.shape
+    return _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
+                 block_k=_fit_block(s_k, block_k), scale=scale,
+                 causal=causal, window=window, group=h // h_kv)
+
+
+def _flash_fwd(q, k, v, *, scale, causal, window, block_q, block_k,
+               interpret):
     b, h, s_q, d = q.shape
-    s_k, d_v = k.shape[2], v.shape[3]
-    w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
-              block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
+    h_kv, s_k, d_v = k.shape[1], k.shape[2], v.shape[3]
+    w = _call_walk(q, k, scale=scale, causal=causal, window=window,
+                   block_q=block_q, block_k=block_k)
     resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
-    _plan("fwd", resident, w, s_q, s_k, d, d_v)
+    _plan("fwd", resident, w, q, k, v, window)
     q_side, lse_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
+    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_resident_kernel if resident
                           else _fwd_streamed_kernel, w=w),
-        grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
+        grid=grid,
         in_specs=[q_side(d), k_side(d), k_side(d_v)],
         out_specs=[q_side(d_v), lse_spec],
         out_shape=[
@@ -487,86 +630,93 @@ def _flash_fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((1, w.bq), jnp.float32),
             pltpu.VMEM((1, w.bq), jnp.float32),
             pltpu.VMEM((d_v, w.bq), jnp.float32)],
-        compiler_params=_params(resident),
+        compiler_params=_params(grid),
         interpret=interpret,
-    )(q.reshape(b * h, s_q, d), k.reshape(b * h, s_k, d),
-      v.reshape(b * h, s_k, d_v))
+    )(q.reshape(b * h, s_q, d), k.reshape(b * h_kv, s_k, d),
+      v.reshape(b * h_kv, s_k, d_v))
     return out.reshape(b, h, s_q, d_v), lse
 
 
-def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, block_q, block_k,
-               interpret):
+def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, window, block_q,
+               block_k, interpret):
     b, h, s_q, d = q.shape
-    s_k, d_v = k.shape[2], v.shape[3]
-    w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
-              block_k=_fit_block(s_k, block_k), scale=scale, causal=causal)
+    h_kv, s_k, d_v = k.shape[1], k.shape[2], v.shape[3]
+    w = _call_walk(q, k, scale=scale, causal=causal, window=window,
+                   block_q=block_q, block_k=block_k)
 
     qf = q.reshape(b * h, s_q, d)
-    kf = k.reshape(b * h, s_k, d)
-    vf = v.reshape(b * h, s_k, d_v)
+    kf = k.reshape(b * h_kv, s_k, d)
+    vf = v.reshape(b * h_kv, s_k, d_v)
     dof = g.reshape(b * h, s_q, d_v)
     # delta = rowsum(dO * O): one fused elementwise+reduce, O(S*D) traffic
     delta = jnp.sum(dof.astype(jnp.float32)
                     * out.reshape(b * h, s_q, d_v).astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
 
-    # dK/dV: a program owns a K block and walks the Q side
-    resident = _resident((s_q, d, q.dtype), (s_q, d_v, g.dtype),
-                         (8 * w.n_q, w.bq, jnp.float32),
-                         (8 * w.n_q, w.bq, jnp.float32))
-    _plan("dkdv", resident, w, s_q, s_k, d, d_v)
+    # dK/dV: a program owns a K block of one KV head and walks the Q side,
+    # of every query head of its group
+    resident = _resident((w.g * s_q, d, q.dtype), (w.g * s_q, d_v, g.dtype),
+                         (w.g * 8 * w.n_q, w.bq, jnp.float32),
+                         (w.g * 8 * w.n_q, w.bq, jnp.float32))
+    _plan("dkdv", resident, w, q, k, v, window)
     q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=False)
+    grid = (b * h_kv, w.n_k) if resident \
+        else (b * h_kv, w.n_k, w.n_q) if w.g == 1 \
+        else (b * h_kv, w.n_k, w.g, w.n_q)
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_resident_kernel if resident
                           else _dkdv_streamed_kernel, w=w),
-        grid=(b * h, w.n_k) if resident else (b * h, w.n_k, w.n_q),
+        grid=grid,
         in_specs=[q_side(d), k_side(d), k_side(d_v), q_side(d_v), stat_spec,
                   stat_spec],
         out_specs=[k_side(d), k_side(d_v)],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_k, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, s_k, d_v), v.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, s_k, d), k.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, s_k, d_v), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((w.bk, d), jnp.float32),
                         pltpu.VMEM((w.bk, d_v), jnp.float32)],
-        compiler_params=_params(resident),
+        compiler_params=_params(grid),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
     # dQ: a program owns a Q block and walks the K side
     resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
-    _plan("dq", resident, w, s_q, s_k, d, d_v)
+    _plan("dq", resident, w, q, k, v, window)
     q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
+    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k)
     dq = pl.pallas_call(
         functools.partial(_dq_resident_kernel if resident
                           else _dq_streamed_kernel, w=w),
-        grid=(b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k),
+        grid=grid,
         in_specs=[q_side(d), k_side(d), k_side(d_v), q_side(d_v), stat_spec,
                   stat_spec],
         out_specs=q_side(d),
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
         scratch_shapes=[] if resident else [
             pltpu.VMEM((d, w.bq), jnp.float32)],
-        compiler_params=_params(resident),
+        compiler_params=_params(grid),
         interpret=interpret,
     )(qf, kf, vf, dof, lse, delta)
 
-    return (dq.reshape(b, h, s_q, d), dk.reshape(b, h, s_k, d),
-            dv.reshape(b, h, s_k, d_v))
+    return (dq.reshape(b, h, s_q, d), dk.reshape(b, h_kv, s_k, d),
+            dv.reshape(b, h_kv, s_k, d_v))
 
 
 # ---------------------------------------------------------------- public op
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _flash_fwd(q, k, v, scale=scale, causal=causal, block_q=block_q,
-                        block_k=block_k, interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, window, block_q, block_k, interpret):
+    out, _ = _flash_fwd(q, k, v, scale=scale, causal=causal, window=window,
+                        block_q=block_q, block_k=block_k,
+                        interpret=interpret)
     return out
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, scale, causal, window, block_q, block_k,
+                   interpret):
     out, lse = _flash_fwd(q, k, v, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k,
+                          window=window, block_q=block_q, block_k=block_k,
                           interpret=interpret)
     # named before ``out`` leaves both ways, so that a recomputed layer
     # reads the saved value as the primal and as the residual
@@ -575,10 +725,12 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, g):
+def _flash_vjp_bwd(scale, causal, window, block_q, block_k, interpret, res,
+                   g):
     q, k, v, out, lse = res
     return _flash_bwd(q, k, v, out, lse, g, scale=scale, causal=causal,
-                      block_q=block_q, block_k=block_k, interpret=interpret)
+                      window=window, block_q=block_q, block_k=block_k,
+                      interpret=interpret)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -601,11 +753,13 @@ def _mesh_partition(batch: int, heads: int):
     return auto, P(axis(AXIS_DP, batch), axis(AXIS_TP, heads), None, None)
 
 
-def flash_attention(q, k, v, *, causal: bool = False, scale=None,
-                    block_q: int = 512, block_k: int = 512,
+def flash_attention(q, k, v, *, causal: bool = False, window=None,
+                    scale=None, block_q: int = 512, block_k: int = 512,
                     interpret=None):
-    """Fused attention: q, k [B, H, S, D_qk], v [B, H, S_k, D_v] →
-    [B, H, S_q, D_v] (the two widths may differ).
+    """Fused attention: q [B, H, S, D_qk], k [B, H / g, S_k, D_qk], v [B,
+    H / g, S_k, D_v] → [B, H, S_q, D_v] (the two widths may differ; ``g``
+    any divisor of H: query head ``h`` reads KV head ``h // g``, and dK and
+    dV come back at ``H / g`` heads).
 
     Fully fused in both directions: forward walks K/V blocks with online
     softmax; backward recomputes probability tiles from the saved LSE
@@ -618,7 +772,9 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     CPU.  Block sizes auto-fit down to the sequence length (any S
     divisible by a power-of-two >= 8 works; only truly odd lengths need
     upstream padding).  Causal masking is bottom-right aligned for
-    S_q != S_k.
+    S_q != S_k.  ``window`` (with ``causal``): a query sees itself and the
+    ``window - 1`` keys before it, the rule of ``ops.causal_attention``;
+    blocks wholly behind the window are not walked.
 
     The SPMD partitioner cannot split a compiled ``pallas_call`` (JAX
     refuses to lower one outside a fully manual region), so under a mesh
@@ -633,9 +789,19 @@ def flash_attention(q, k, v, *, causal: bool = False, scale=None,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    static = (float(scale), bool(causal), int(block_q), int(block_k),
-              auto_interpret(interpret))
-    axes, spec = _mesh_partition(q.shape[0], q.shape[1])
+    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads over {k.shape[1]} key and "
+            f"{v.shape[1]} value heads: K and V share a head count that "
+            "divides the queries'")
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"window {window!r}: a positive number of keys, "
+                         "and only with causal=True")
+    static = (float(scale), bool(causal),
+              None if window is None else int(window), int(block_q),
+              int(block_k), auto_interpret(interpret))
+    # the KV heads are the fewer: an axis that divides them divides both
+    axes, spec = _mesh_partition(q.shape[0], k.shape[1])
     if not axes:
         return _flash(q, k, v, *static)
     return shard_map(lambda q, k, v: _flash(q, k, v, *static),

@@ -255,13 +255,15 @@ def test_flash_scale_not_a_power_of_two_stays_on_the_scores():
 
 # ---- two widths: Q, K of one, V, O, dO of another (latent attention) ----
 
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "w40"])
 @pytest.mark.parametrize("shape", [(128, 128), (64, 192), (192, 64)],
                          ids=["square", "sq_lt_sk", "sq_gt_sk"])
 @pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
-def test_flash_two_widths_match_the_oracle(feeding, shape, monkeypatch):
+def test_flash_two_widths_match_the_oracle(feeding, shape, window,
+                                           monkeypatch):
     """Forward, dQ and dK/dV with Q, K 48 wide and V, O, dO 32 wide, against
     ``ops.causal_attention``, resident and streamed, ``S_q != S_k`` either
-    way round; every plan carries both widths."""
+    way round, with and without a window; every plan carries both widths."""
     s_q, s_k = shape
     plans = _plans(monkeypatch)
     ks = jax.random.split(jax.random.PRNGKey(21), 4)
@@ -270,9 +272,11 @@ def test_flash_two_widths_match_the_oracle(feeding, shape, monkeypatch):
     v = jax.random.normal(ks[2], (1, 2, s_k, 32))
     g = jax.random.normal(ks[3], (1, 2, s_q, 32))
     out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, block_q=32, block_k=32), q, k, v)
+        q, k, v, causal=True, window=window, block_q=32, block_k=32),
+        q, k, v)
     lo = max(0, s_q - s_k)            # rows that see no key: 0 in the kernel
-    ref, ref_vjp = jax.vjp(causal_attention, q, k, v)
+    ref, ref_vjp = jax.vjp(
+        lambda q, k, v: causal_attention(q, k, v, window=window), q, k, v)
     assert out.shape == (1, 2, s_q, 32)
     for a, b in zip((out, *vjp(g)),
                     (ref, *ref_vjp(g.at[:, :, :lo].set(0.0)))):
@@ -304,14 +308,157 @@ def test_flash_residency_counts_each_width(monkeypatch):
     assert mod._resident((1024, 64, jnp.bfloat16), (1024, 64, jnp.bfloat16))
 
 
+# ---- a window beside the diagonal, and K, V at fewer heads than Q ----
+
+def _masked_case(s_q, s_k, window, *, heads=2, group=1, d=64, d_v=None,
+                 block=32, seed=0):
+    """(flash out + grads, oracle out + grads over K, V repeated to the
+    query heads, first visible query row)."""
+    d_v = d_v or d
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (1, heads, s_q, d))
+    k = jax.random.normal(ks[1], (1, heads // group, s_k, d))
+    v = jax.random.normal(ks[2], (1, heads // group, s_k, d_v))
+    g = jax.random.normal(ks[3], (1, heads, s_q, d_v))
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=block, block_k=block),
+        q, k, v)
+    lo = max(0, s_q - s_k)
+    ref, ref_vjp = jax.vjp(lambda q, k, v: causal_attention(
+        q, jnp.repeat(k, group, 1), jnp.repeat(v, group, 1), window=window),
+        q, k, v)
+    return (out, *vjp(g)), (ref, *ref_vjp(g.at[:, :, :lo].set(0.0))), lo
+
+
+def _assert_agree(got, want, lo):
+    out, dq = (np.asarray(x) for x in got[:2])
+    np.testing.assert_array_equal(out[:, :, :lo], 0.0)
+    np.testing.assert_array_equal(dq[:, :, :lo], 0.0)
+    for i, (a, b) in enumerate(zip(got, want)):
+        assert a.shape == b.shape
+        rows = slice(lo, None) if i < 2 else slice(None)
+        np.testing.assert_allclose(np.asarray(a)[:, :, rows],
+                                   np.asarray(b)[:, :, rows], rtol=1e-3,
+                                   atol=2e-4)
+
+
+# in tiles of 32: under a block, not a multiple of one, one block, the
+# keys' own length (hides nothing), longer than the keys
+_WINDOWS = {"lt_block": lambda s_k: 8, "ragged": lambda s_k: 40,
+            "one_block": lambda s_k: 32, "eq_s": lambda s_k: s_k,
+            "gt_s": lambda s_k: 2 * s_k + 3}
+
+
+@pytest.mark.parametrize("window", list(_WINDOWS))
+@pytest.mark.parametrize("shape", list(_SHAPES))
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_flash_window_matches_the_oracle(feeding, shape, window,
+                                         monkeypatch):
+    """Forward and all three gradients against
+    ``ops.causal_attention(window=)``: windows smaller than a block, not a
+    multiple of it, equal to it, equal to and larger than S, ``s_q`` <, ==,
+    > ``s_k``, resident and streamed.  The plan says what the walk visits: no
+    more than a causal walk, and as much where the window hides nothing."""
+    plans = _plans(monkeypatch)
+    s_q, s_k = _SHAPES[shape]
+    w = _WINDOWS[window](s_k)
+    got, want, lo = _masked_case(s_q, s_k, w, seed=s_q + w)
+    _assert_agree(got, want, lo)
+    assert [a["kernel"] for _, a in plans] == ["fwd", "dkdv", "dq"]
+    for _, a in plans:
+        assert a["resident"] == (feeding == "resident")
+        assert (a["window"], a["kv_heads"]) == (w, 2)
+        assert 0 < a["tiles_live"] <= a["tiles_causal"]
+        if w >= s_k:
+            assert a["tiles_live"] == a["tiles_causal"]
+        elif s_q == s_k:      # 4 x 4 tiles: the corner of 3 is behind it
+            assert a["tiles_live"] < a["tiles_causal"]
+
+
+@pytest.mark.parametrize("window", [None, 40], ids=["causal", "w40"])
+@pytest.mark.parametrize("group", [1, 4, 8])
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_flash_grouped_heads_match_repeated_kv(feeding, group, window,
+                                               monkeypatch):
+    """8 query heads over 8, 2 and 1 KV heads: value, dQ, and dK, dV AT THE
+    KV HEADS, against the oracle over K and V repeated to 8 heads (whose
+    gradient sums each group), with and without a window."""
+    plans = _plans(monkeypatch)
+    got, want, lo = _masked_case(128, 128, window, heads=8, group=group,
+                                 d=32, seed=group)
+    assert got[2].shape == got[3].shape == (1, 8 // group, 128, 32)
+    _assert_agree(got, want, lo)
+    assert all(a["kv_heads"] == 8 // group
+               and a["resident"] == (feeding == "resident")
+               for _, a in plans)
+
+
+def test_flash_refuses_heads_that_do_not_group_and_a_window_alone():
+    q, k, v = qkv(H=4, S=64)
+    with pytest.raises(ValueError, match="divides the queries"):
+        flash_attention(q, k[:, :3], v[:, :3], causal=True)
+    with pytest.raises(ValueError, match="divides the queries"):
+        flash_attention(q, k[:, :2], v[:, :1], causal=True)
+    with pytest.raises(ValueError, match="only with causal"):
+        flash_attention(q, k, v, window=16)
+    with pytest.raises(ValueError, match="positive number"):
+        flash_attention(q, k, v, causal=True, window=0)
+
+
+@pytest.mark.parametrize("case", [
+    (128, 128, None, 1), (128, 128, 40, 1), (128, 128, 8, 4),
+    (64, 128, 40, 2), (128, 64, 24, 1), (128, 128, 128, 1)],
+    ids=["causal", "w40", "w8-g4", "sq_lt_sk-w40-g2", "sq_gt_sk-w24",
+         "w_eq_s"])
+@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+def test_each_kernel_visits_exactly_the_live_tiles(feeding, case,
+                                                   monkeypatch):
+    """The tile bodies the three kernels run, counted by a host callback
+    round each: every one runs ``tiles_live`` times a call, the plan's
+    count, which is the number of (Q block, K block) pairs that hold a
+    visible score, times the query heads; a streamed walk's dead grid steps
+    run none."""
+    import sys
+    mod = sys.modules[_MOD]
+    s_q, s_k, window, group = case
+    heads, block = 4, 32
+    trips = {"fwd": 0, "dq": 0, "dkdv": 0}
+
+    def counted(name):
+        body = getattr(mod, f"_{name}_tile")
+
+        def tile(*args):
+            jax.debug.callback(
+                lambda: trips.__setitem__(name, trips[name] + 1))
+            return body(*args)
+        return tile
+
+    for name in trips:
+        monkeypatch.setattr(mod, f"_{name}_tile", counted(name))
+    plans = _plans(monkeypatch)
+    got, want, lo = _masked_case(s_q, s_k, window, heads=heads, group=group,
+                                 d=32, block=block, seed=3)
+    jax.effects_barrier()
+    _assert_agree(got, want, lo)
+    back = np.arange(s_q)[:, None] + (s_k - s_q) - np.arange(s_k)[None]
+    seen = (back >= 0) & (back < (window or s_k + s_q))
+    live = seen.reshape(s_q // block, block, s_k // block, block).any((1, 3))
+    assert {a["kernel"]: a["tiles_live"] for _, a in plans} == \
+        dict.fromkeys(trips, heads * int(live.sum()))
+    assert trips == dict.fromkeys(trips, heads * int(live.sum()))
+    assert all(a["tiles_causal"] >= a["tiles_live"] for _, a in plans)
+
+
 # ---- a recomputed layer keeps the forward kernel's output and LSE rows
 
 def _remat_gpt_grads(mesh_axes, policy, named, monkeypatch, run=True):
     """(loss, gradients, jaxpr text of the gradient) of a small flash GPT
-    with per-layer remat: through ``ops.remat`` as it is (``named``), or
-    through the plain ``jax.checkpoint`` it stands in for.  Run operation
-    by operation (``jax.disable_jit``), so that no compiler's fusion choices
-    stand between the two programs: what is compared is their arithmetic."""
+    with per-layer remat (``policy`` 'period': of a small ``mellum`` model,
+    three window layers and a full one, K and V at half the heads): through
+    ``ops.remat`` as it is (``named``), or through the plain
+    ``jax.checkpoint`` it stands in for.  Run operation by operation
+    (``jax.disable_jit``), so that no compiler's fusion choices stand
+    between the two programs: what is compared is their arithmetic."""
     import hetu_tpu as ht
     from hetu_tpu import ops
     from hetu_tpu.models.gpt import GPTConfig, GPTModel
@@ -323,11 +470,20 @@ def _remat_gpt_grads(mesh_axes, policy, named, monkeypatch, run=True):
         monkeypatch.setattr(ops, "remat", lambda layer, policy="full":
                             jax.checkpoint(layer, policy=dots
                                            if policy == "dots" else None))
-    model = GPTModel(GPTConfig(
-        vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
-        ffn_size=64, max_position=32, dropout_rate=0.0,
-        attention_impl="flash", remat=True, remat_policy=policy,
-        ce_row_chunk=32))
+    if policy == "period":
+        from hetu_tpu.models.mellum import MellumConfig, MellumModel
+        model = MellumModel(MellumConfig(
+            vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
+            num_kv_heads=2, head_dim=8, expert_ffn_size=16,
+            n_routed_experts=4, moe_topk=2, held=(1, 2), window=12,
+            max_position=32, dtype=jnp.float32, expert_block_rows=8,
+            ce_row_chunk=32, embedding_init_std=1.0))
+    else:
+        model = GPTModel(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=2, num_heads=2,
+            ffn_size=64, max_position=32, dropout_rate=0.0,
+            attention_impl="flash", remat=True, remat_policy=policy,
+            ce_row_chunk=32))
     params = model.init(jax.random.PRNGKey(0))["params"]
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
     mesh = ht.make_mesh(**mesh_axes) if mesh_axes else None
@@ -348,8 +504,10 @@ def _remat_gpt_grads(mesh_axes, policy, named, monkeypatch, run=True):
 
 
 @pytest.mark.parametrize("mesh_axes,policy", [
-    (None, "full"), ({"dp": 2, "tp": 2}, "full"), (None, "dots")],
-    ids=["one-device-full", "dp2tp2-full", "one-device-dots"])
+    (None, "full"), ({"dp": 2, "tp": 2}, "full"), (None, "dots"),
+    (None, "period")],
+    ids=["one-device-full", "dp2tp2-full", "one-device-dots",
+         "window-and-full-layers"])
 def test_remat_keeps_the_forward_kernels_results_bit_for_bit(
         mesh_axes, policy, monkeypatch):
     """The saved output and LSE rows equal the recomputed ones, so loss and
@@ -368,9 +526,11 @@ def test_remat_keeps_the_forward_kernels_results_bit_for_bit(
         assert np.abs(np.asarray(b)).max() > 0, jax.tree_util.keystr(path)
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), \
             jax.tree_util.keystr(path)
-    # forward, dK/dV, dQ | and the recomputed forward
+    # forward, dK/dV, dQ | and the recomputed forward; a layer of the
+    # unrolled period (three window layers, one full)
+    calls = 4 if policy == "period" else 1
     assert (text.count("pallas_call["), want_text.count("pallas_call[")) \
-        == (3, 4)
+        == (3 * calls, 4 * calls)
     if policy == "dots":
         monkeypatch.undo()
         full_text = _remat_gpt_grads(mesh_axes, "full", True, monkeypatch,

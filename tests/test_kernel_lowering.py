@@ -33,6 +33,13 @@ bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
 # the flash calls of the two training cells of BENCHMARK.json: gpt2-small at
 # 64 x 1024 on one chip, and gpt2-large's per-chip shard under dp=2 x tp=2
 BENCH_FLASH_SHAPES = ((64, 12, 1024, 64), (8, 10, 1024, 64))
+# the flash calls of mellum2-12b-a2.5b-instruct.train-ep4: one 16,384-token
+# sequence, 32 query heads over 4 KV heads of 128, a window of 1,024 on three
+# layers of four (streamed); and a resident call with grouped heads
+MASKED_FLASH_CASES = (
+    ("mellum window", (1, 32, 16384, 128), (1, 4, 16384, 128), 1024),
+    ("mellum full", (1, 32, 16384, 128), (1, 4, 16384, 128), None),
+    ("resident grouped", (2, 32, 512, 128), (2, 4, 512, 128), 200))
 # the paged decode kernel at the top slot and page bucket of the two serving
 # cells it runs in: (slots, query heads, KV heads, head width, cache layers,
 # pages in the pool, page size, pages a slot)
@@ -56,6 +63,14 @@ def _flash_vjp(q, k, v, g):
     return (out, *vjp(g))
 
 
+def _flash_vjp_masked(window):
+    def fn(q, k, v, g):
+        out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=window), q, k, v)
+        return (out, *vjp(g))
+    return fn
+
+
 def _cases():
     rows, width, n = FULL.emb
     tokens, experts, k = FULL.topk
@@ -66,6 +81,10 @@ def _cases():
     for shape in BENCH_FLASH_SHAPES:
         yield (f"flash fwd+bwd bf16 benchmark {shape}", _flash_vjp,
                [(shape, bf16)] * 4, 3)
+    for name, qs, ks, window in MASKED_FLASH_CASES:
+        yield (f"flash fwd+bwd bf16 {name} q{qs} kv{ks} w{window}",
+               _flash_vjp_masked(window),
+               [(qs, bf16), (ks, bf16), (ks, bf16), (qs, bf16)], 3)
     for dt in (f32, bf16):
         yield (f"embedding_gather {dt.__name__}", embedding_gather,
                [((rows, width), dt), ((n,), i32)], 1)
@@ -132,6 +151,47 @@ def test_flash_calls_read_as_the_benchmarks_reader_expects():
     assert hits["bwd_count"] == [shape], hits
 
 
+def test_grouped_window_calls_keep_k_v_dk_and_dv_at_the_kv_heads():
+    """The new cell's calls as ``flash_roofline.train-ep4`` reads them: the
+    forward still the ONE call returning (bf16, f32), the backward the TWO
+    returning (bf16, bf16) and a single bf16, now with K, V, dK and dV at
+    the 4 KV heads: every call takes K and V as ``[4, S, D]`` beside Q's
+    ``[32, S, D]`` and dK/dV leaves as two ``[4, S, D]``, so no ``[B, heads,
+    S, D]`` K, V, dK or dV exists for the kernels to read or XLA to sum.  In
+    a trace the calls are named after their ``jax.named_scope``; a lowering
+    has none, so the patterns' ``%s`` is filled with nothing."""
+    params = json.loads((Path(__file__).resolve().parent.parent / "benchmarks"
+                         / "layer_metrics" / "flash_roofline.train-ep4.json"
+                         ).read_text())["params"]
+    _, qs, ks, window = MASKED_FLASH_CASES[0]
+    abstract = [jax.ShapeDtypeStruct(s, bf16) for s in (qs, ks, ks, qs)]
+    text = jax.jit(_flash_vjp_masked(window)).trace(*abstract).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo")
+    calls = ["%" + line.strip().removeprefix("ROOT ").removeprefix("%")
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    hits = {name: [c for c in calls if re.search(rx % "", c)]
+            for name, rx in params.items()}
+    q, kv = "bf16[32,16384,128]{2,1,0}", "bf16[4,16384,128]{2,1,0}"
+
+    def result(c):
+        return c.split(" = ")[1].split(" custom-call")[0]
+
+    def operands(c):
+        return re.findall(
+            r"(?:bf16|f32)\[[\d,]+\]\{[\d,]+\}",
+            c.split("operand_layout_constraints={")[1].split(
+                ", frontend_attributes")[0])
+
+    (fwd,) = hits["fwd"]
+    assert result(fwd).startswith(f"({q}, f32[") and \
+        operands(fwd) == [q, kv, kv]
+    assert sorted(map(result, hits["bwd"])) == sorted([f"({kv}, {kv})", q])
+    assert [result(c) for c in hits["bwd_count"]] == [q]
+    for c in hits["bwd"]:
+        assert operands(c)[:4] == [q, kv, kv, q]
+
+
 def _gpt2_small():
     """(model, batch shape, scanned layer bodies)."""
     from hetu_tpu.models.gpt import GPTConfig, GPTModel
@@ -156,13 +216,27 @@ def _tiny_deepseek_v3():
     return model, (2, 256), 2
 
 
-@pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3])
+def _tiny_mellum():
+    from hetu_tpu.models.mellum import MellumConfig, MellumModel
+
+    model = MellumModel(MellumConfig(
+        vocab_size=512, hidden_size=256, num_layers=8, num_heads=4,
+        num_kv_heads=2, head_dim=128, expert_ffn_size=128,
+        n_routed_experts=8, moe_topk=2, held=(2, 2), window=128,
+        expert_block_rows=128, ce_row_chunk=256, max_position=256))
+    return model, (2, 256), 4
+
+
+@pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3,
+                                   _tiny_mellum])
 def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
     """Per-layer remat keeps the forward kernel's output and LSE rows
     (``ops.remat``), so a scanned layer body holds the forward kernel once,
     in the forward scan, and dK/dV and dQ in the backward scan: three Mosaic
     calls, not four with a recomputed forward.  The ``deepseek_v3`` model
-    scans two bodies, the dense layer's and the expert layers'."""
+    scans two bodies, the dense layer's and the expert layers'; the
+    ``mellum`` model scans a PERIOD, whose body holds three window layers
+    and a full one, and every call of it takes K and V at the KV heads."""
     import hetu_tpu as ht
     from hetu_tpu import optim
     from hetu_tpu.train.executor import TrainState
@@ -181,6 +255,15 @@ def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
     text = ex._compile("train").trace(jax.eval_shape(state), batch).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 3 * bodies
+    if build is _tiny_mellum:
+        # 2 x 4 query heads of [256, 128] over 2 x 2 KV heads: no call reads
+        # a K or V at the query heads, and dK/dV leave at the KV heads
+        q, kv = "tensor<8x256x128xbf16>", "tensor<4x256x128xbf16>"
+        sigs = re.findall(r"stablehlo.custom_call @tpu_custom_call.*? : "
+                          r"\((.*?)\) -> (.*)", text)
+        assert len(sigs) == 12
+        assert all(s[0].split(", ")[:3] == [q, kv, kv] for s in sigs)
+        assert sum(s[1].count(kv) == 2 for s in sigs) == 4
 
 
 @pytest.mark.slow

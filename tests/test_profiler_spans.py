@@ -666,3 +666,45 @@ def test_remat_plan_says_whether_the_reduction_is_kept(mesh_axes, tp):
             assert len(plans(t)) == 1
     finally:
         trace.disable()
+
+
+# --------------- what a flash call's walk visits, and at which heads (ISSUE 40)
+
+@pytest.mark.parametrize("window,kv_heads,live", [
+    (None, 4, 10), (40, 4, 9), (40, 1, 9), (8, 2, 7), (128, 4, 10)],
+    ids=["causal", "w40", "w40-one-kv-head", "w8-two-kv-heads", "w-eq-s"])
+def test_flash_plan_says_the_window_the_kv_heads_and_the_tiles(window,
+                                                               kv_heads,
+                                                               live):
+    """One ``flash.plan`` instant per kernel built, when the call is TRACED:
+    beside the ids it had, ``window`` (0 for none), ``kv_heads``, and
+    ``tiles_live`` beside ``tiles_causal``: what the walk visits and what a
+    causal walk would, a call (all heads).  S = 128 in tiles of 32: the
+    diagonal leaves 10 of 16 tiles a head; a window of 40 hides the corner
+    tile (9), one of 8 the three below the subdiagonal (7), one as long as
+    the keys nothing."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.ops.pallas_kernels import flash_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (2, 4, 128, 32))
+    k, v = (jax.random.normal(kk, (2, kv_heads, 128, 32)) for kk in ks[1:])
+    grad = jax.jit(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=window, block_q=32, block_k=32) ** 2),
+        argnums=(0, 1, 2)))
+    t = trace.enable()
+    try:
+        jax.block_until_ready(grad(q, k, v))
+        plans = [e["args"] for e in t.events if e["name"] == "flash.plan"]
+        jax.block_until_ready(grad(q, k, v))     # compiled: none per call
+        assert len([e for e in t.events if e["name"] == "flash.plan"]) == 3
+    finally:
+        trace.disable()
+    assert [p["kernel"] for p in plans] == ["fwd", "dkdv", "dq"]
+    for p in plans:
+        assert set(p) == {"kernel", "resident", "block_q", "block_k", "s_q",
+                          "s_k", "d", "d_v", "window", "kv_heads",
+                          "tiles_live", "tiles_causal"}
+        assert (p["window"], p["kv_heads"]) == (window or 0, kv_heads)
+        assert (p["tiles_live"], p["tiles_causal"]) == (8 * live, 80)
