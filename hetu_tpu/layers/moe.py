@@ -355,7 +355,17 @@ class HeldExpertLayer:
     three are the model's, set from its configuration, not options of a
     deployment.  The identity experts are one weighted sum of ``u``, never a
     matmul; the held experts' work follows the pairs routed to them
-    (``ops.moe_ops.held_expert_ffn``).  Parameters (one layer's): ``router``
+    (``ops.moe_ops.held_expert_ffn``, which takes one of two paths by the
+    static shapes it is handed, ``ops.moe_ops.held_expert_path``: a loop over
+    blocks of ``block_rows`` sorted rows where an expert gets a handful of
+    rows, as in a decode round or a prefill chunk, and sorted rows through
+    grouped matmuls with one combine where it gets a thousand, as in a
+    training step.  ``block_rows`` governs the loop path alone; the grouped
+    path's row tile is its kernels' constant and its memory is bounded by a
+    static row budget made from ``T``, ``k``, the held count and the router's
+    width, never by a capacity; it is one jitted function for every layer
+    of a program that calls it at one shape, so its kernels are lowered
+    once a program).  Parameters (one layer's): ``router``
     [H, n_routed + n_zero] float32, ``router_bias`` [n_routed + n_zero]
     float32, ``gate``/``up`` [count, H, F], ``down`` [count, F, H]; with a
     shared expert ``shared_gate``/``shared_up`` [H, F_s], ``shared_down``
@@ -420,7 +430,7 @@ class HeldExpertLayer:
             routed, per_expert = held_expert_ffn(
                 tokens.astype(self.dtype), w, idx, p["gate"], p["up"],
                 p["down"], first=self.first, block_rows=self.block_rows,
-                layer=layer)
+                layer=layer, routed=self.n_routed + self.n_zero)
         n_held = per_expert.sum()
         n_zero = zero.sum().astype(jnp.int32)
         stats = jnp.stack([n_held, n_zero, idx.size - n_held - n_zero,

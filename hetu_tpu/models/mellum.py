@@ -34,7 +34,8 @@ here (``layers/moe.py`` ``HeldExpertLayer``, softmax, renormalised, no shared
 expert, no bias); pairs that land on an absent expert are left out and the
 renormalising sum keeps all ``k`` scores.  The walk over the held experts'
 pairs follows the load forward and backward
-(``ops.moe_ops.held_expert_ffn``).  No auxiliary balance loss (the
+(``ops.moe_ops.held_expert_ffn``: at a training step's shape, sorted rows
+through grouped matmuls, ``hetu.moe.gmm``).  No auxiliary balance loss (the
 configuration gives no coefficient) and no next-token head (it gives no key
 for one).
 
@@ -71,11 +72,12 @@ from hetu_tpu import ops
 from hetu_tpu.layers.base import Module
 from hetu_tpu.layers.moe import HeldExpertLayer
 from hetu_tpu.models.exaone_moe import FULL, WINDOW, GroupedHeads
+from hetu_tpu.ops.moe_ops import held_expert_path
 
 # the scalar ids of one step's expert layers, summed over the layers; the
 # trainer puts the group on its ``train.moe`` instant
 MOE_STEP_IDS = ("moe_held", "moe_absent", "moe_hit", "moe_blocks_fwd",
-                "moe_blocks_bwd")
+                "moe_blocks_bwd", "moe_grouped")
 
 
 @dataclass
@@ -344,10 +346,16 @@ class MellumModel(GroupedHeads, Module):
             held, _, absent, hit = (counts["stats"].sum(0)[i]
                                     for i in range(4))
             blocks = counts["blocks"].sum()
-            # the backward walk reads its trip count from the same plan as
-            # the forward's (ops.moe_ops._walk_plan): the blocks that hold
-            # a pair
+            # ``blocks``: what the loop path's walks take, forward and
+            # backward (both read their trip count from
+            # ops.moe_ops._walk_plan: the blocks that hold a pair); at a
+            # shape the grouped path takes, what the loop WOULD walk.
+            # ``grouped``: the held pairs the grouped path computed, all of
+            # them or none by ops.moe_ops.held_expert_path's static rule
+            grouped = held * int(held_expert_path(
+                ids.size, c.moe_topk, c.held[1], c.hidden_size,
+                c.expert_ffn_size) == "grouped")
             group = dict(zip(MOE_STEP_IDS,
-                             (held, absent, hit, blocks, blocks)))
+                             (held, absent, hit, blocks, blocks, grouped)))
             return loss, ({"moe": group}, model_state)
         return fn

@@ -10,6 +10,8 @@ sequence-parallel axis (hetu_tpu/parallel/ring_attention.py).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -72,15 +74,29 @@ def remat(layer, policy: str = "full"):
     off a traced weight: a 'tp' mesh whose strategy leaves ``out_weight``
     whole (``DataParallel``, a hidden size 'tp' does not divide) keeps one
     activation a layer for a matmul alone.  One ``remat.plan`` instant a
-    call says which it was."""
+    call says which it was.
+
+    Every call with the same answer hands ``jax.checkpoint`` the SAME policy
+    object (:func:`_keep`): JAX splits a jitted function inside a
+    checkpointed layer into what is kept and what is recomputed once a
+    (function, policy object) pair, so the layers of one scan body, each
+    under its own ``remat``, share that split, and a Pallas kernel inside
+    such a function is lowered once for all of them
+    (``ops.moe_ops.held_expert_ffn``)."""
     tp = jax.sharding.get_abstract_mesh().shape.get(AXIS_TP, 1)
-    names = (SAVED_OUT, SAVED_LSE) + ((SAVED_REDUCED,) if tp > 1 else ())
     trace.instant("remat.plan", {"tp": tp, "reduced": int(tp > 1)})
+    return jax.checkpoint(layer, policy=_keep(tp > 1, policy == "dots"))
+
+
+@functools.cache
+def _keep(reduced: bool, dots: bool):
+    """:func:`remat`'s checkpoint policy, one object an answer."""
+    names = (SAVED_OUT, SAVED_LSE) + ((SAVED_REDUCED,) if reduced else ())
     keep = jax.checkpoint_policies.save_only_these_names(*names)
-    if policy == "dots":
+    if dots:
         keep = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable, keep)
-    return jax.checkpoint(layer, policy=keep)
+    return keep
 
 
 # ---- serving decode: attention over a preallocated cache ----

@@ -16,10 +16,13 @@ Two routings live here, and they are not interchangeable.  Everything down to
 padded to it, and drops what overflows.  :func:`route_biased_top_k` and
 :func:`held_expert_ffn` at the end are the HELD-EXPERT routing of a model
 served or trained as one chip's share (``models/longcat_flash.py``,
-``models/exaone_moe.py``, ``models/deepseek_v3.py``): a router as wide as
-published over experts of which this chip holds a contiguous share, no
-capacity, no drops, and work that follows the rows routed here, forward and
-backward.
+``models/exaone_moe.py``, ``models/deepseek_v3.py``, ``models/mellum.py``):
+a router as wide as published over experts of which this chip holds a
+contiguous share, no capacity, no drops, and work that follows the rows
+routed here, forward and backward.  It has two paths, chosen from static
+shapes by :func:`held_expert_path`: a loop over row blocks (decode rounds
+and prefill chunks) and sorted rows through grouped matmuls (training
+steps).
 """
 
 from __future__ import annotations
@@ -30,6 +33,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from hetu_tpu.ops.pallas_kernels import grouped_matmul as gm
 
 
 def top_k_idx_gate(logits, k: int):
@@ -324,34 +329,275 @@ def _held_vjp_bwd(first, R, res, g):
 _held.defvjp(_held_vjp_fwd, _held_vjp_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the grouped path: sort once, grouped matmuls, combine once
+#
+# Its forward and backward are module-level JITTED functions of arrays, the
+# Python values (``first``, the row budget) static: every walk of a program
+# at one shape is then one traced function, lowered once, its Pallas calls
+# with it (why that matters, and what it asks of ``ops.remat``:
+# :func:`held_expert_ffn`).  XLA inlines the calls.
+# ---------------------------------------------------------------------------
+
+# the most sorted rows a trip of the grouped path holds at once: its buffers
+# are [rows, H] and [rows, F], whatever T * k is
+GROUPED_ROW_BUDGET = 20480
+# the grouped path is taken from this many (token, choice) pairs an expert
+# held here, were every pair held (``T * k / E``) ...
+GROUPED_MIN_PAIRS_AN_EXPERT = 1024
+# ... where an expert's [H, F] weight is at most this many elements: the
+# grouped matmuls keep one whole in VMEM, two of them double-buffered in the
+# call that makes dx
+GROUPED_MAX_WEIGHT = 4 * 1024 * 1024
+
+
+def held_expert_path(T: int, k: int, E: int, H: int, F: int) -> str:
+    """``"grouped"`` or ``"loop"``: which walk :func:`held_expert_ffn` takes
+    for ``T`` tokens of ``k`` choices over ``E`` held experts of ``[H, F]``.
+    Static shapes alone decide (the rule and the measurement behind it are
+    stated in :func:`held_expert_ffn`)."""
+    return "grouped" if (T * k >= GROUPED_MIN_PAIRS_AN_EXPERT * E
+                         and H * F <= GROUPED_MAX_WEIGHT) else "loop"
+
+
+def grouped_row_budget(T: int, k: int, E: int, routed=None) -> int:
+    """Sorted rows a trip of the grouped path holds.  The pairs an even
+    router over ``routed`` experts sends to ``E`` of them, and an eighth
+    more (every pair, where the router's width is not given), are cut into
+    the fewest equal trips of at most :data:`GROUPED_ROW_BUDGET` rows, in
+    whole tiles.  Static; a load past it costs trips, never rows."""
+    pairs = T * k if routed is None else -(-T * k * E * 9 // (routed * 8))
+    rows = min(T * k, pairs)
+    rows = -(-rows // -(-rows // GROUPED_ROW_BUDGET))     # a trip's
+    return -(-rows // gm.TILE_ROWS) * gm.TILE_ROWS
+
+
+def _layer_leaves(leaves, layer, dt):
+    """(leaves, layer) as the grouped matmuls read them: leaves of ``dt``
+    already stay in place, stacked or not; otherwise this layer's slices,
+    cast."""
+    if all(w.dtype == dt for w in leaves):
+        return leaves, layer
+    return [(w if layer is None else w[layer]).astype(dt)
+            for w in leaves], None
+
+
+def _leaf_grad(w, dw, layer):
+    """``dw`` (one layer's, float32) as the cotangent of leaf ``w``."""
+    dw = dw.astype(w.dtype)
+    return dw if layer is None else jnp.zeros_like(w).at[layer].set(dw)
+
+
+class _GroupedPlan(NamedTuple):
+    """The pairs that land on the ``E`` held experts, sorted once."""
+    order: jax.Array       # pair indices sorted by held expert, absent last,
+    #                        a trip's rows of zeros behind them
+    counts: jax.Array      # [E] pairs per held expert
+    row_start: jax.Array   # [E] each expert's first row in the sorted order
+
+    @property
+    def held(self):
+        return self.row_start[-1] + self.counts[-1]
+
+
+def _grouped_plan(idx, first: int, E: int, B: int) -> _GroupedPlan:
+    """:func:`_walk_plan`'s sort and counts for the grouped path, the counts
+    by comparison (a scalar scatter-add of ``T * k`` ones costs a
+    millisecond a walk on the chip, PERF.md section 6, PR 41)."""
+    local = idx.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < E), local, E)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = jnp.sum(key[None] == jnp.arange(E)[:, None], axis=1,
+                     dtype=jnp.int32)
+    order = jnp.concatenate([order, jnp.zeros((B,), jnp.int32)])
+    return _GroupedPlan(order, counts, jnp.cumsum(counts) - counts)
+
+
+class _Trip(NamedTuple):
+    """One trip of the grouped path: ``B`` rows of the sorted order."""
+    pairs: jax.Array       # [B] pair indices
+    tok: jax.Array         # [B] their tokens
+    add_pair: jax.Array    # [B] the same where a row is a held pair, past
+    add_tok: jax.Array     # the last index where not: a dropped update
+    visits: gm.Visits      # the experts' rows inside the trip, as every
+    #                        grouped call of the trip reads them
+
+
+def _trip(plan: _GroupedPlan, s, B: int, T: int, k: int) -> _Trip:
+    lo = s * B
+    pairs = lax.dynamic_slice_in_dim(plan.order, lo, B)
+    live = lo + jnp.arange(B) < plan.held
+    end = plan.row_start + plan.counts
+    return _Trip(pairs, pairs // k, jnp.where(live, pairs, T * k),
+                 jnp.where(live, pairs // k, T),
+                 gm.group_visits(jnp.clip(plan.row_start - lo, 0, B),
+                                 jnp.clip(end - lo, 0, B), B))
+
+
+@functools.partial(jax.jit, static_argnames=("first", "B"))
+def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
+                     B):
+    (T, _), k, dt = x.shape, idx.shape[1], x.dtype
+    plan = _grouped_plan(idx, first, w_gate.shape[-3], B)
+    pair_w = weights.reshape(-1)
+    (wg, wu, wd), layer = _layer_leaves((w_gate, w_up, w_down), layer, dt)
+
+    def body(s, out):
+        t = _trip(plan, s, B, T, k)
+        rows = x[t.tok]                                   # [B, H], once
+        g = gm.gmm(rows, wg, t.visits, layer=layer, out_dtype=dt)
+        u = gm.gmm(rows, wu, t.visits, layer=layer, out_dtype=dt)
+        y = gm.gmm(jax.nn.silu(g) * u, wd, t.visits, layer=layer,
+                   row_scale=pair_w[t.pairs])
+        # rows past the held pairs were never written: dropped, not added
+        return out.at[t.add_tok].add(y, mode="drop")
+
+    out = lax.fori_loop(0, -(-plan.held // B), body,
+                        jnp.zeros(x.shape, jnp.float32))
+    return (out, plan.counts), plan
+
+
+@functools.partial(jax.jit, static_argnames=("B",))
+def _grouped_backward(x, weights, idx, w_gate, w_up, w_down, layer, plan,
+                      d_out, B):
+    """The trips again: a trip's gate and up are recomputed from its rows;
+    an expert's ``dW`` is summed on chip over its row tiles and written once
+    a trip, ``dx`` and the pair weights' gradient are made in sorted order
+    and added once a trip."""
+    (T, _), k, dt, f32 = x.shape, idx.shape[1], x.dtype, jnp.float32
+    pair_w = weights.reshape(-1)
+    (wg, wu, wd), at = _layer_leaves((w_gate, w_up, w_down), layer, dt)
+
+    def body(s, carry):
+        dx, dpw, dwg, dwu, dwd = carry
+        t = _trip(plan, s, B, T, k)
+        rows = x[t.tok]
+        g = gm.gmm(rows, wg, t.visits, layer=at, out_dtype=dt)   # as forward
+        u = gm.gmm(rows, wu, t.visits, layer=at, out_dtype=dt)
+        a = jax.nn.silu(g) * u                            # [B, F]
+        # y = a @ wd stays on chip: its product with dy is all that is read
+        dpw_rows, dy, da = gm.gmm_down_back(
+            a, wd, d_out[t.tok], pair_w[t.pairs], t.visits, layer=at)
+        dpw = dpw.at[t.add_pair].add(dpw_rows, mode="drop")
+        gf, uf = g.astype(f32), u.astype(f32)
+        sig = jax.nn.sigmoid(gf)
+        dg = (da * uf * sig * (1.0 + gf * (1.0 - sig))).astype(dt)
+        du = (da * gf * sig).astype(dt)
+        dwd = gm.tgmm(a, dy, t.visits, dwd)
+        dwg = gm.tgmm(rows, dg, t.visits, dwg)
+        dwu = gm.tgmm(rows, du, t.visits, dwu)
+        drows = gm.gmm(dg, wg, t.visits, layer=at, transpose_rhs=True,
+                       also=(du, wu))
+        dx = dx.at[t.add_tok].add(drows, mode="drop")
+        return dx, dpw, dwg, dwu, dwd
+
+    def zeros(w):
+        return jnp.zeros(w.shape[-3:], f32)
+
+    dx, dpw, dwg, dwu, dwd = lax.fori_loop(
+        0, -(-plan.held // B), body,
+        (jnp.zeros(x.shape, f32), jnp.zeros(pair_w.shape, f32),
+         zeros(w_gate), zeros(w_up), zeros(w_down)))
+    return (dx.astype(x.dtype), dpw.reshape(weights.shape).astype(
+        weights.dtype), None, _leaf_grad(w_gate, dwg, layer),
+        _leaf_grad(w_up, dwu, layer), _leaf_grad(w_down, dwd, layer), None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _grouped(x, weights, idx, w_gate, w_up, w_down, layer, first, B):
+    return _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer,
+                            first, B)[0]
+
+
+def _grouped_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, B):
+    out, plan = _grouped_forward(x, weights, idx, w_gate, w_up, w_down,
+                                 layer, first, B)
+    return out, (x, weights, idx, w_gate, w_up, w_down, layer, plan)
+
+
+def _grouped_vjp_bwd(first, B, res, g):
+    return _grouped_backward(*res, g[0], B)
+
+
+_grouped.defvjp(_grouped_vjp_fwd, _grouped_vjp_bwd)
+
+
 def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
-                    block_rows: int = 128, layer=None):
+                    block_rows: int = 128, layer=None, routed=None):
     """SwiGLU experts over the (token, choice) pairs that land on the
     experts held here; what absent experts would add is left out.
 
-    x [T, H]; weights/idx [T, k] from the router over ALL experts;
-    w_gate/w_up [E, H, F], w_down [E, F, H]: the ``E`` held experts, global
-    indices ``first .. first + E - 1``; with ``layer`` given they are
-    stacked over layers, [L, E, ...], and this is layer ``layer`` (inside a
-    scan over layers a block then reads its expert straight from the stacked
-    leaf; sliced a layer at a time first, the slice is copied every step).
-    Returns (out [T, H] float32, counts [E] int32 pairs per held expert).
+    x [T, H]; weights/idx [T, k] from the router over ALL experts (``routed``
+    of them, where the caller says); w_gate/w_up [E, H, F], w_down [E, F, H]:
+    the ``E`` held experts, global indices ``first .. first + E - 1``; with
+    ``layer`` given they are stacked over layers, [L, E, ...], and this is
+    layer ``layer`` (inside a scan over layers an expert's weight is then
+    read straight from the stacked leaf; sliced a layer at a time first, the
+    slice is copied every step).  Returns (out [T, H] float32, counts [E]
+    int32 pairs per held expert).
 
-    No capacity and no drops: the pairs are sorted by held expert and walked
-    in blocks of ``block_rows`` rows, ``ceil(count_e / block_rows)`` blocks
-    for expert ``e``, so every pair is computed at any imbalance and the
-    work follows the rows routed here, not ``T * k``.  An expert nobody chose costs
-    nothing: its weights are not read.  Nothing is bounded by a buffer of
-    rows: a block gathers its rows from ``x`` and adds its result into
-    ``out``; only the sorted pair indices are held, ``T * k + block_rows``
-    integers.  The trip count is read from the counts.
+    No capacity and no drops, on either of two paths: the pairs are sorted by
+    held expert once, every pair is computed at any imbalance, the work
+    follows the rows routed here and not ``T * k``, and an expert nobody
+    chose costs nothing: its weights are not read.
 
-    Reverse mode walks the same blocks (a ``custom_vjp``: a loop whose trip
+    **The loop path** walks the sorted pairs in blocks of ``block_rows``
+    rows, ``ceil(count_e / block_rows)`` blocks for expert ``e``, the trip
+    count read from the counts.  A block gathers its rows from ``x``,
+    multiplies them by its expert and adds its result into ``out``; in
+    reverse it also adds into its expert's whole float32 ``dW``.  Nothing is
+    bounded by a buffer of rows: only the sorted pair indices are held.  One
+    block an expert is the least a walk can do, which is what a decode round
+    or a prefill chunk needs (a handful of rows an expert, bound by the hit
+    experts' weight reads).
+
+    **The grouped path** brings the rows into expert order once a trip
+    (``x[order // k]``), runs gate, up and down as grouped matmuls over each
+    expert's contiguous rows (``ops/pallas_kernels/grouped_matmul.py``,
+    ``hetu.moe.gmm`` in a trace: an expert's weight read in place and kept in
+    VMEM across its row tiles, a tile two experts share masked, not padded)
+    and adds the result into ``out`` with ONE scatter-add a trip; in reverse
+    an expert's ``dW`` is summed in VMEM over its row tiles and written once,
+    the down projection's result never leaves the chip (its product with the
+    cotangent is all the pair weights' gradient needs), and ``dx`` is one
+    more scatter-add.  A trip holds :func:`grouped_row_budget` sorted rows,
+    a static number: memory is bounded by that row budget, never by a
+    capacity and never by ``T * k``; a load past it costs trips, never rows.
+    ``block_rows`` is the loop path's alone.  The path's forward and backward
+    are jitted functions, so that every walk of a program at one shape is
+    the same traced function and each grouped kernel is lowered once a
+    program, not once a layer of a scan body: ten Mosaic modules in a step
+    whose body holds four expert layers, not forty (a program's build cost,
+    which a warm compile cache does not remove; nothing the device runs
+    changes).  ``layer`` is an array index or None there, never a static
+    value, which would make a function a layer.  Under per-layer
+    ``jax.checkpoint`` the sharing holds where the layers share ONE policy
+    object, as ``ops.remat`` hands out: JAX splits a jitted call into kept
+    and recomputed parts once a (jaxpr, policy object) pair.
+
+    **The rule** (:func:`held_expert_path`; static shapes only, no option):
+    grouped where ``T * k >= 1024 * E`` and ``H * F <= 4 Mi``.  Set on the
+    chip (v5e, PERF.md section 6, PR 41): at 2304 x 896, 16 held of 64, one
+    walk forward and backward takes 4.8 | 6.1 | 10.0 | 16.2 | 30.0 ms on the
+    loop path (128-row blocks) against 4.3 | 5.1 | 6.6 | 10.0 | 16.4 ms
+    grouped at ``T`` = 512 | 1024 | 2048 | 4096 | 8192 with ``k`` = 8, so
+    the paths part from 256 pairs an expert and by a third at 1,024; forward
+    alone at 6144 x 2048 the two are within a tenth of each other up to
+    ``T`` = 4096, and an expert's weight no longer fits the kernels' VMEM.
+    The two expert training cells sit at 6,144 and 8,192 pairs an expert,
+    the serving programs at 384 and under.
+
+    Reverse mode follows the path taken (a ``custom_vjp``: a loop whose trip
     count is read from data has no transpose of its own): the backward's
-    cost follows the load as the forward's does, and no block's
-    intermediate is kept between the two.  Gradients go to ``x``, to the
-    pair ``weights`` (and through them to the router) and to the three
-    weight leaves, accumulated in float32 and rounded once to the leaf's
-    type; given ``layer``, a leaf's gradient is zero outside that layer."""
+    cost follows the load as the forward's does, and no intermediate is kept
+    between the two.  Gradients go to ``x``, to the pair ``weights`` (and
+    through them to the router) and to the three weight leaves, accumulated
+    in float32 and rounded once to the leaf's type; given ``layer``, a
+    leaf's gradient is zero outside that layer."""
+    T, k = idx.shape
+    E, H, F = w_gate.shape[-3:]
+    if held_expert_path(T, k, E, H, F) == "grouped":
+        return _grouped(x, weights, idx, w_gate, w_up, w_down, layer,
+                        int(first), grouped_row_budget(T, k, E, routed))
     return _held(x, weights, idx, w_gate, w_up, w_down, layer, int(first),
                  int(block_rows))

@@ -1,0 +1,314 @@
+"""Grouped matmuls over rows sorted by group (the held-expert walk's
+experts): :func:`gmm`, ``out[rows of g] = lhs[rows of g] @ rhs[g]``, and its
+transpose :func:`tgmm`, ``out[g] += lhs[rows of g]^T @ rhs[rows of g]``.
+
+The shape of ``jax.experimental.pallas.ops.tpu.megablox``, cut to what the
+walk needs.  Group sizes are DATA, every shape is static: the rows of group
+``g`` are ``starts[g] .. ends[g] - 1`` of the first operand, contiguous and
+in group order, anywhere inside it (a window of a longer sorted order has
+its sizes clipped to the window by the caller).  The row axis is cut into
+tiles of :data:`TILE_ROWS`; the grid's row axis is the list of VISITS, one a
+(group, tile) pair that shares a row, read from scalar-prefetched tables
+(:func:`group_visits`, made once for all the calls over the same rows), and
+its length is read from the sizes too: an empty group is never visited, its
+weights never read, and a tile that two groups share is visited once by each
+with the other's rows masked (no group is padded to a tile).  Tiles past the
+last group's rows are not visited: :func:`gmm` leaves those rows of its
+result UNWRITTEN, and the caller masks them.
+
+``gmm`` keeps a group's whole ``[K, N]`` weight in VMEM, read in place from
+the stacked ``[G, K, N]`` operand (with ``layer``: ``[L, G, K, N]``) and
+fetched once a group, the visits of one group being consecutive;
+:func:`gmm_down_back` is the down projection's backward in one such call,
+the forward's product recomputed and never written.  ``tgmm``
+keeps a group's ``[K, N]`` sum in a float32 VMEM scratch across that group's
+visits and writes it once, added to what the aliased accumulator held
+(``acc``: a group without rows is not touched and keeps its value).
+
+bf16 operands, float32 accumulation.  The calls carry the name
+``hetu.moe.gmm`` in a device trace.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from hetu_tpu.utils.platform import auto_interpret
+
+# rows a visit multiplies.  128 x 128 is the MXU; 256 rows keep a visit's
+# matmul near 4 us at the trained widths while a tile shared by two groups
+# (one a group boundary, computed twice) stays a small share of the rows
+TILE_ROWS = 256
+# a tgmm sum block, in elements: [K, N] is cut along K or N to fit
+_TGMM_BLOCK = 1152 * 1024
+_VMEM_LIMIT = 96 * 1024 * 1024
+SCOPE = "hetu.moe.gmm"
+
+
+class Visits(NamedTuple):
+    """The visits of groups ``starts[g] .. ends[g] - 1`` over some rows in
+    tiles: what every grouped call over the same sorted rows reads, made
+    once (:func:`group_visits`) and handed to each."""
+    group: jax.Array       # [V_max] the group of visit v
+    tile: jax.Array        # [V_max] its row tile
+    count: jax.Array       # how many of the V_max are real
+    starts: jax.Array      # [G] each group's first row
+    ends: jax.Array        # [G] one past its last
+
+
+def group_visits(starts, ends, rows: int) -> Visits:
+    """The visit tables of groups ``starts[g] .. ends[g] - 1`` over ``rows``
+    rows in tiles of :data:`TILE_ROWS` (of ``rows``, if fewer).
+    ``V_max = rows / tile + G - 1``."""
+    tm = _tile_rows(rows)
+    G = starts.shape[0]
+    starts, ends = starts.astype(jnp.int32), ends.astype(jnp.int32)
+    sizes = ends - starts
+    first = starts // tm
+    tiles = jnp.where(sizes > 0, (ends - 1) // tm - first + 1, 0)
+    v_end = jnp.cumsum(tiles)
+    v_max = rows // tm + G - 1
+    v = jnp.arange(v_max, dtype=jnp.int32)
+    group = jnp.minimum(jnp.searchsorted(v_end, v, side="right"),
+                        G - 1).astype(jnp.int32)
+    tile = first[group] + v - (v_end[group] - tiles[group])
+    tile = jnp.clip(tile, 0, rows // tm - 1).astype(jnp.int32)
+    return Visits(group, tile, v_end[-1].astype(jnp.int32), starts, ends)
+
+
+def _tile_rows(M):
+    tm = min(TILE_ROWS, M)
+    if M % tm:
+        raise ValueError(f"{M} rows are no multiple of the tile's {tm}")
+    return tm
+
+
+def _row_mask(starts, ends, g, t, tm):
+    rows = t * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    return (rows >= starts[g]) & (rows < ends[g])
+
+
+def _store(out, value, mask, fresh):
+    """A visit's rows of ``value`` into its tile of ``out``: the first visit
+    of a tile zeroes the rows it does not own, a later one keeps them."""
+    @pl.when(fresh)
+    def _():
+        out[...] = jnp.where(mask, value, 0).astype(out.dtype)
+
+    @pl.when(jnp.logical_not(fresh))
+    def _():
+        out[...] = jnp.where(mask, value.astype(out.dtype), out[...])
+
+
+def _visit(group, tile, starts, ends, tm):
+    """(group, row mask, whether the tile is visited for the first time) of
+    this grid step's visit."""
+    v = pl.program_id(0)
+    g, t = group[v], tile[v]
+    fresh = (v == 0) | (tile[jnp.maximum(v - 1, 0)] != t)
+    return g, _row_mask(starts, ends, g, t, tm), fresh
+
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _gmm_kernel(group, tile, starts, ends, layer, *refs, tm, transpose_rhs,
+                pairs, scaled):
+    del layer
+    out = refs[-1]
+    _, mask, fresh = _visit(group, tile, starts, ends, tm)
+    dims = _NT if transpose_rhs else _NN
+    acc = sum(lax.dot_general(refs[2 * i][...], refs[2 * i + 1][...], dims,
+                              preferred_element_type=jnp.float32)
+              for i in range(pairs))
+    if scaled:
+        acc = acc * refs[2 * pairs][...]
+    _store(out, acc, mask, fresh)
+
+
+def _weight_spec(w, layer):
+    """(block spec, the layer operand) of a stacked weight read in place:
+    group ``group[v]``'s whole [K, N] slab, of layer ``layer`` if given."""
+    stacked = layer is not None
+
+    def index(v, group, tile, starts, ends, layer):
+        return ((layer[0],) if stacked else ()) + (group[v], 0, 0)
+
+    block = ((None,) if stacked else ()) + (None,) + w.shape[-2:]
+    layer = jnp.zeros((1,), jnp.int32) if layer is None \
+        else jnp.asarray(layer, jnp.int32).reshape(1)
+    return pl.BlockSpec(block, index), layer
+
+
+def _rows_spec(tm, width):
+    return pl.BlockSpec((tm, width), lambda v, group, tile, *_: (tile[v], 0))
+
+
+def _over_visits(kernel, visits: Visits, layer_op, operands, specs, outs, *,
+                 interpret):
+    """``kernel`` run once a visit of ``visits`` over the rows of
+    ``operands[0]``; ``outs``: the (width, dtype) of each [M, width] result.
+    One result comes back bare."""
+    M = operands[0].shape[0]
+    tm = _tile_rows(M)
+    call = pl.pallas_call(
+        functools.partial(kernel, tm=tm),
+        out_shape=tuple(jax.ShapeDtypeStruct((M, n), d) for n, d in outs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            in_specs=[s if isinstance(s, pl.BlockSpec) else _rows_spec(tm, s)
+                      for s in specs],
+            out_specs=tuple(_rows_spec(tm, n) for n, _ in outs),
+            grid=(visits.count,)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=auto_interpret(interpret))
+    with jax.named_scope(SCOPE):
+        out = call(visits.group, visits.tile, visits.starts, visits.ends,
+                   layer_op, *operands)
+    return out[0] if len(outs) == 1 else out
+
+
+def _column(v):
+    return v.astype(jnp.float32).reshape(-1, 1)
+
+
+def gmm(lhs, rhs, visits: Visits, *, layer=None, transpose_rhs: bool = False,
+        out_dtype=jnp.float32, also=None, row_scale=None, interpret=None):
+    """``lhs`` [M, K] rows sorted by group (``visits`` over its ``M`` rows:
+    :func:`group_visits`), ``rhs`` [G, K, N] (or [G, N, K]
+    with ``transpose_rhs``; a leading layer axis with ``layer``) ->
+    [M, N] ``out_dtype``: row ``r`` of group ``g`` is ``lhs[r] @ rhs[g]``,
+    accumulated in float32.  ``also=(lhs2, rhs2)`` adds a second such
+    product before the result is rounded; ``row_scale`` [M] float32
+    multiplies row ``r`` by ``row_scale[r]``, in float32.  Rows of a visited
+    tile that no group owns are zero; tiles past the last group's rows are
+    unwritten."""
+    N = rhs.shape[-2] if transpose_rhs else rhs.shape[-1]
+    operands, specs = [], []
+    for a, w in ((lhs, rhs),) + ((also,) if also is not None else ()):
+        spec, layer_op = _weight_spec(w, layer)
+        operands += [a, w]
+        specs += [a.shape[1], spec]
+    pairs = len(operands) // 2
+    if row_scale is not None:
+        operands.append(_column(row_scale))
+        specs.append(1)
+    return _over_visits(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+                          pairs=pairs, scaled=row_scale is not None),
+        visits, layer_op, operands, specs, [(N, out_dtype)],
+        interpret=interpret)
+
+
+def _down_back_kernel(group, tile, starts, ends, layer, a, w, dy, scale,
+                      dot, dyw, da, *, tm):
+    del layer
+    _, mask, fresh = _visit(group, tile, starts, ends, tm)
+    d = dy[...]
+    y = lax.dot_general(a[...], w[...], _NN,
+                        preferred_element_type=jnp.float32)
+    weighted = (d * scale[...]).astype(dyw.dtype)
+    _store(dot, jnp.sum(y * d, axis=1, keepdims=True), mask, fresh)
+    _store(dyw, weighted, mask, fresh)
+    _store(da, lax.dot_general(weighted, w[...], _NT,
+                               preferred_element_type=jnp.float32),
+           mask, fresh)
+
+
+def gmm_down_back(a, w_down, dy, row_scale, visits: Visits, *, layer=None,
+                  interpret=None):
+    """The down-projection's backward over sorted rows, its result never
+    written: with ``y = a @ w_down[g]`` ([M, F] x [G, F, H], float32) and
+    ``dy`` [M, H] float32 the cotangent of ``row_scale * y``, returns
+    (``sum(y * dy, -1)`` [M] float32: the scale's gradient; ``dyw = dy *
+    row_scale`` [M, H] in ``a``'s type; ``dyw @ w_down[g]^T`` [M, F]
+    float32: ``a``'s gradient).  Rows as :func:`gmm` leaves them."""
+    F, H = a.shape[1], dy.shape[1]
+    spec, layer_op = _weight_spec(w_down, layer)
+    dot, dyw, da = _over_visits(
+        _down_back_kernel, visits, layer_op,
+        [a, w_down, dy, _column(row_scale)], [F, spec, H, 1],
+        [(1, jnp.float32), (H, a.dtype), (F, jnp.float32)],
+        interpret=interpret)
+    return dot[:, 0], dyw, da
+
+
+def _tgmm_kernel(group, tile, starts, ends, lhs, rhs, acc, out, sums, *, tm):
+    v = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    g, t = group[v], tile[v]
+
+    @pl.when((v == 0) | (group[jnp.maximum(v - 1, 0)] != g))
+    def _():
+        sums[...] = jnp.zeros_like(sums)
+
+    mask = _row_mask(starts, ends, g, t, tm)
+    a = jnp.where(mask, lhs[...], jnp.zeros_like(lhs))
+    b = jnp.where(mask, rhs[...], jnp.zeros_like(rhs))
+    sums[...] += lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+
+    @pl.when((v == last) | (group[jnp.minimum(v + 1, last)] != g))
+    def _():
+        out[...] = acc[...] + sums[...]
+
+
+def _cut(K: int, N: int, limit: int):
+    """(tk, tn): the largest [tk, tn] block of a [K, N] sum within ``limit``
+    elements, each side the whole dimension or a multiple of 128 dividing
+    it."""
+    def sides(d):
+        return [d] + [s for s in range(128, d, 128) if d % s == 0]
+
+    fits = [(tk * tn, tn, tk) for tk in sides(K) for tn in sides(N)
+            if tk * tn <= limit]
+    if not fits:
+        return min(sides(K)), min(sides(N))
+    _, tn, tk = max(fits)
+    return tk, tn
+
+
+def tgmm(lhs, rhs, visits: Visits, acc, *, interpret=None):
+    """``lhs`` [M, K], ``rhs`` [M, N], rows sorted by group; ``acc``
+    [G, K, N] float32 -> ``acc`` with ``lhs[rows of g]^T @ rhs[rows of g]``
+    added to group ``g``'s slab, each slab summed in VMEM over its group's
+    row tiles and written once.  ``acc`` is donated."""
+    M, K = lhs.shape
+    N = rhs.shape[1]
+    tm = _tile_rows(M)
+    tk, tn = _cut(K, N, _TGMM_BLOCK)
+    slab = pl.BlockSpec((None, tk, tn), lambda i, j, v, group, *_:
+                        (group[v], i, j))
+    call = pl.pallas_call(
+        functools.partial(_tgmm_kernel, tm=tm),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda i, j, v, group, tile, *_:
+                             (tile[v], i)),
+                pl.BlockSpec((tm, tn), lambda i, j, v, group, tile, *_:
+                             (tile[v], j)),
+                slab,
+            ],
+            out_specs=slab,
+            grid=(K // tk, N // tn, visits.count),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=auto_interpret(interpret))
+    with jax.named_scope(SCOPE):
+        return call(visits.group, visits.tile, visits.starts, visits.ends,
+                    lhs, rhs, acc)

@@ -24,6 +24,7 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
     embedding_gather, embedding_scatter_add, flash_attention, routed_gather,
     topk_gating,
 )
+from hetu_tpu.ops.pallas_kernels import grouped_matmul  # noqa: E402,F401
 from hetu_tpu.ops.pallas_kernels.paged_attention import (  # noqa: E402
     paged_decode_attention,
 )
@@ -43,6 +44,12 @@ MASKED_FLASH_CASES = (
 # the paged decode kernel at the top slot and page bucket of the two serving
 # cells it runs in: (slots, query heads, KV heads, head width, cache layers,
 # pages in the pool, page size, pages a slot)
+# the held-expert walk of the two expert training cells, grouped path: (tokens
+# a step, choices a token, the router's width, held experts, hidden, expert
+# FFN)
+BENCH_GROUPED_SHAPES = {
+    "kanana-2-30b-a3b-instruct-2601.train-ep8": (16384, 6, 128, 16, 2048, 768),
+    "mellum2-12b-a2.5b-instruct.train-ep4": (16384, 8, 64, 16, 2304, 896)}
 BENCH_PAGED_SHAPES = {
     "gpt2-large.batch": (8, 20, 20, 64, 36, 385, 16, 48),
     "k-exaone-236b-a23b.batch-mixed": (16, 64, 8, 128, 1, 4225, 128, 264)}
@@ -50,11 +57,19 @@ BENCH_PAGED_SHAPES = {
 
 @pytest.fixture(autouse=True)
 def _compiled_kernels(monkeypatch):
-    """Lower the kernels as a TPU backend would: never interpret mode."""
-    for mod in ("embedding", "flash_attention", "paged_attention"):
+    """Lower the kernels as a TPU backend would: never interpret mode.  The
+    grouped expert walk is a module-level jitted function
+    (``ops.moe_ops``), and JAX keeps its trace by shapes alone: the caches
+    are emptied on the way in and out, so that no test meets a walk another
+    traced in the other mode."""
+    for mod in ("embedding", "flash_attention", "paged_attention",
+                "grouped_matmul"):
         m = sys.modules[f"hetu_tpu.ops.pallas_kernels.{mod}"]
         name = "_auto_interpret" if mod == "embedding" else "auto_interpret"
         monkeypatch.setattr(m, name, lambda interpret: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _flash_vjp(q, k, v, g):
@@ -68,6 +83,18 @@ def _flash_vjp_masked(window):
         out, vjp = jax.vjp(lambda q, k, v: flash_attention(
             q, k, v, causal=True, window=window), q, k, v)
         return (out, *vjp(g))
+    return fn
+
+
+def _held_experts_vjp(routed):
+    from hetu_tpu.ops.moe_ops import held_expert_ffn
+
+    def fn(x, w, idx, wg, wu, wd, ct):
+        (out, _), vjp = jax.vjp(
+            lambda x, w, wg, wu, wd: held_expert_ffn(
+                x, w, idx, wg, wu, wd, first=0, routed=routed),
+            x, w, wg, wu, wd)
+        return (out, *vjp((ct, jnp.zeros((wg.shape[0],), i32))))
     return fn
 
 
@@ -100,6 +127,14 @@ def _cases():
     yield (f"topk_gating {k} of {experts}",
            lambda x: topk_gating(x, k, kernel=True),
            [((tokens, experts), f32)], 1)
+    for cell, (t, k, routed, e, h, f) in BENCH_GROUPED_SHAPES.items():
+        # forward: gate, up, down; backward: gate and up again, the down
+        # projection's backward in one call, three dW, one dx
+        yield (f"held experts grouped fwd+bwd {cell}",
+               _held_experts_vjp(routed),
+               [((t, h), bf16), ((t, k), f32), ((t, k), i32),
+                ((e, h, f), bf16), ((e, h, f), bf16), ((e, f, h), bf16),
+                ((t, h), f32)], 10)
     for cell, (b, nh, g, d, layers, pool, ps, n_pg) in \
             BENCH_PAGED_SHAPES.items():
         pool_of = ((layers, pool, ps, g * d), bf16)
@@ -227,21 +262,13 @@ def _tiny_mellum():
     return model, (2, 256), 4
 
 
-@pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3,
-                                   _tiny_mellum])
-def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
-    """Per-layer remat keeps the forward kernel's output and LSE rows
-    (``ops.remat``), so a scanned layer body holds the forward kernel once,
-    in the forward scan, and dK/dV and dQ in the backward scan: three Mosaic
-    calls, not four with a recomputed forward.  The ``deepseek_v3`` model
-    scans two bodies, the dense layer's and the expert layers'; the
-    ``mellum`` model scans a PERIOD, whose body holds three window layers
-    and a full one, and every call of it takes K and V at the KV heads."""
+def _step_text(model, batch_shape):
+    """The train step of ``model`` as lowered for TPU, locations included
+    (a Mosaic call's ``jax.named_scope`` is in its location only)."""
     import hetu_tpu as ht
     from hetu_tpu import optim
     from hetu_tpu.train.executor import TrainState
 
-    model, batch_shape, bodies = build()
     ex = ht.Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-4))
 
     def state():
@@ -252,8 +279,22 @@ def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
                           step=jnp.zeros((), i32))
 
     batch = (jax.ShapeDtypeStruct(batch_shape, i32),)
-    text = ex._compile("train").trace(jax.eval_shape(state), batch).lower(
-        lowering_platforms=("tpu",)).as_text()
+    return ex._compile("train").trace(jax.eval_shape(state), batch).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3,
+                                   _tiny_mellum])
+def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
+    """Per-layer remat keeps the forward kernel's output and LSE rows
+    (``ops.remat``), so a scanned layer body holds the forward kernel once,
+    in the forward scan, and dK/dV and dQ in the backward scan: three Mosaic
+    calls, not four with a recomputed forward.  The ``deepseek_v3`` model
+    scans two bodies, the dense layer's and the expert layers'; the
+    ``mellum`` model scans a PERIOD, whose body holds three window layers
+    and a full one, and every call of it takes K and V at the KV heads."""
+    model, batch_shape, bodies = build()
+    text = _step_text(model, batch_shape)
     assert text.count("tpu_custom_call") == 3 * bodies
     if build is _tiny_mellum:
         # 2 x 4 query heads of [256, 128] over 2 x 2 KV heads: no call reads
@@ -264,6 +305,112 @@ def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
         assert len(sigs) == 12
         assert all(s[0].split(", ")[:3] == [q, kv, kv] for s in sigs)
         assert sum(s[1].count(kv) == 2 for s in sigs) == 4
+
+
+def _row_scatters(text, hidden):
+    """Rows of every scatter's update into a float32 ``[T, hidden]``."""
+    return [int(m) for m in re.findall(
+        rf"stablehlo\.scatter.*?tensor<(\d+)x{hidden}xf32>\)\s*->", text,
+        flags=re.S)]
+
+
+@pytest.mark.parametrize("build", [_tiny_deepseek_v3, _tiny_mellum])
+def test_a_train_step_over_the_threshold_lowers_with_grouped_calls(build):
+    """At 1,024 tokens of 2 choices over 2 held experts the walk takes the
+    grouped path (``ops.moe_ops.held_expert_path``): its Mosaic calls carry
+    ``hetu.moe.gmm`` and no block of ``expert_block_rows`` rows is added into
+    the float32 ``[T, H]`` result; at 512 tokens the same model holds the
+    loop, a block's scatter-add inside a ``while``."""
+    from hetu_tpu.ops.moe_ops import grouped_row_budget, held_expert_path
+
+    model, _, bodies = build()
+    rows, hidden = model.c.expert_block_rows, model.c.hidden_size
+    assert held_expert_path(1024, 2, 2, hidden, 128) == "grouped"
+    text = _step_text(model, (4, 256))
+    # a walk: gate, up, down forward; gate, up, the down projection's
+    # backward, three dW and one dx backward (the recomputed forward walk's
+    # result is read by nobody, and is not traced); one walk's calls a
+    # program, however many expert layers a scanned body holds
+    assert text.count("tpu_custom_call") == 3 * bodies + 10
+    assert _grouped_calls(text) == 10
+    adds = _row_scatters(text, hidden)
+    assert grouped_row_budget(1024, 2, 2, 8) in adds and rows not in adds
+    assert held_expert_path(512, 2, 2, hidden, 128) == "loop"
+    text = _step_text(model, (2, 256))
+    assert text.count("tpu_custom_call") == 3 * bodies
+    assert "hetu.moe.gmm" not in text
+    assert rows in _row_scatters(text, hidden)
+
+
+def _grouped_calls(text, part=None):
+    """The Mosaic calls of a lowered program ``text`` (of ``part`` of it)
+    whose location carries ``hetu.moe.gmm``."""
+    scoped = set(re.findall(r'^(#loc\d+) = loc\("[^"]*hetu\.moe\.gmm', text,
+                            flags=re.M))
+    return sum(loc in scoped for loc in re.findall(
+        r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)$",
+        text if part is None else part, flags=re.M))
+
+
+@pytest.mark.parametrize("build,walks", [(_tiny_deepseek_v3, 1),
+                                         (_tiny_mellum, 4)])
+def test_a_scan_body_lowers_each_grouped_kernel_once(build, walks):
+    """What a program costs to BUILD: a ``pallas_call`` is lowered to its
+    Mosaic module in Python every time the step is traced, compile cache
+    warm or not, and ``setup_s`` pays it (PERF.md section 6, PR 42).  The
+    grouped walk is a jitted function, so the four expert layers of
+    ``mellum``'s scanned period, each under its own ``ops.remat``, call ONE
+    lowered forward and ONE lowered backward: 10 grouped Mosaic calls in
+    the step, where four separately lowered walks made 40; ``deepseek_v3``
+    scans a one-layer body and holds what one walk makes."""
+    model, _, _ = build()
+    text = _step_text(model, (4, 256))
+    assert _grouped_calls(text) == 10
+    holders = {}
+    for fn in re.split(r"\n(?=\s*func\.func )", text):
+        name = re.match(r"\s*func\.func \w+ @([\w.]+)", fn)
+        if name and _grouped_calls(text, fn):
+            holders[name.group(1)] = len(re.findall(
+                rf"call @{re.escape(name.group(1))}\(", text))
+    # the walk forward (gate, up, down) and the walk backward (the other
+    # seven), each lowered once and called once a layer of the body
+    assert sorted(n.split("_")[2] for n in holders) == ["backward",
+                                                        "forward"]
+    assert set(holders.values()) == {walks}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_a_serving_program_of_exaone_moe_lowers_with_the_loop(program):
+    """Decode rounds and prefill chunks are far under the rule's threshold:
+    the expert walk is the loop, a ``while`` whose trip count is read from
+    the counts, and the program holds no grouped call."""
+    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
+    from hetu_tpu.serve import PagedServeEngine
+
+    model = ExaoneMoeModel(ExaoneMoeConfig(
+        vocab_size=96, hidden_size=128, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
+        first_dense=1, n_routed_experts=16, moe_topk=4, held=(4, 4),
+        window=8, max_position=256, dtype=bf16, param_dtype=bf16,
+        expert_block_rows=8))
+    engine = PagedServeEngine(model, jax.jit(model.init)(
+        jax.random.PRNGKey(0)), num_slots=4, max_len=160, page_size=4,
+        prefill_chunk=8, min_bucket=4)
+    k_pool, v_pool = engine._pool_args()
+    n_pg = engine.cache.pages_per_slot
+    if program == "decode":
+        fn = engine._build_decode()
+        aux = (4, n_pg + 4 + sum(r + 1 for r in engine._ring_decode))
+    else:
+        fn = engine._build_chunk(n_pg)
+        aux = (3 * 8 + n_pg + 2 + sum(8 + r for r in engine._ring_chunk),)
+    text = fn.trace(engine.params, k_pool, v_pool,
+                    jax.ShapeDtypeStruct(aux, i32)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "hetu.moe.experts" in text and "hetu.moe.gmm" not in text
+    # the walk: a while whose bound is no constant, one a layer's scan body
+    assert re.search(r"stablehlo\.while", text)
+    assert 8 in _row_scatters(text, 128)
 
 
 @pytest.mark.slow

@@ -597,6 +597,33 @@ def test_train_moe_instant_carries_each_steps_counts(tmp_path):
     assert 0 < events[-1][3]["router_bias_absmax"] <= 0.0031
 
 
+@pytest.mark.parametrize("path", ["grouped", "loop"])
+def test_train_moe_instant_says_what_the_grouped_path_computed(
+        tmp_path, monkeypatch, path):
+    """``moe_grouped``: the step's held pairs that the grouped path computed,
+    all of them at a shape over the rule's threshold
+    (``ops.moe_ops.held_expert_path``) and none under it."""
+    from hetu_tpu.ops import moe_ops
+
+    # 64 tokens of 2 choices over 4 held experts: 32 pairs an expert
+    monkeypatch.setattr(moe_ops, "GROUPED_MIN_PAIRS_AN_EXPERT",
+                        32 if path == "grouped" else 33)
+    assert moe_ops.held_expert_path(64, 2, 4, 32, 16) == path
+    model, variables, batch = _expert_model()
+    ex = Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-3))
+    state = ex.init_state(variables)
+    with profiled(tmp_path):
+        for _ in range(3):
+            state, metrics = ex.run("train", state, batch)
+            jax.block_until_ready(metrics)
+    events = _named(hetu_threads(tmp_path)[0], "train.moe")
+    assert [e[3]["step"] for e in events] == [1, 2]
+    for e in events:
+        assert e[3]["moe_held"] > 0
+        assert e[3]["moe_grouped"] == (e[3]["moe_held"]
+                                       if path == "grouped" else 0)
+
+
 def test_no_step_waits_for_its_counts():
     """``run`` only ASKS whether a queued step's counts are finished: while
     they are not, it issues the next step and keeps them queued; it never
