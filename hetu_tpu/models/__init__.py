@@ -15,3 +15,4 @@ from hetu_tpu.models.gpt_hetero import HeteroGPT, PlanStrategy
 from hetu_tpu.models.ctr_zoo import DeepFM, DCN, CrossNet
 from hetu_tpu.models.llama import (HeteroLlama, LlamaConfig, LlamaModel,
                                    llama2_7b)
+from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel

@@ -1,0 +1,363 @@
+"""One decoder block for the expert models that are served, and its three
+calls.
+
+A layer is ``h + operator(N(h))`` then ``h + feed_forward(N(h))``, ``N``
+RMSNorm with its own weight: the operator grouped-query attention
+(:class:`GroupedHeads`: q and k normalised per head, the half-rotation layout
+where the layer is rotated, full or over a window) unless the model states
+another (:meth:`BlockDecoder._operator`); the feed-forward a dense SwiGLU on
+the ``first_dense`` leading layers and the model's
+:class:`~hetu_tpu.layers.moe.HeldExpertLayer` on the rest.  A layer runs in
+one of three calls, which :class:`LayerCall` describes: the dense forward, a
+prefill chunk over the serving engine's cache layers, a decode round over
+them.
+
+:class:`BlockDecoder` is the layer, the three calls, both cache entry points
+of ``hetu_tpu/serve`` and the loss.  A model (``models/exaone_moe.py``: window
+and full attention layers in two cache groups; ``models/lfm2_moe.py``: short
+convolutions with state layers between full attention layers in one group)
+states through the constructor its expert layer and, by layer, where a
+layer's attention leaves and cache layer lie, whether it is rotated and what
+window it has; and itself holds its configuration, its weights (``init``) and
+the cache it asks for (``kv_cache_spec``).  The head is ``lm_head`` where the
+weights have one, else tied to the embedding.
+
+The layers run as a Python loop, not a scan: layers of several kinds with
+caches of several shapes do not scan.  Parameter leaves are stacked over the
+layers that have them and read at a layer's own (static) index.
+
+``jax.named_scope``s mark the sub-layers in the jitted programs
+(``hetu.attn.window``, ``hetu.attn.full``, ``hetu.ffn.dense``; the expert
+layer's are ``layers/moe.py``'s).
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+
+from hetu_tpu import ops
+from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.moe import MOE_STATS
+
+# the names ``layer_types`` gives the two kinds of attention layer
+WINDOW, FULL = "sliding_attention", "full_attention"
+
+
+def draw_leaf(key, lead: tuple, shape: tuple, std, dtype):
+    """A normal leaf ``lead + shape`` of ``dtype``, one float32 draw at a
+    time: a slice of 2**25 numbers or more (a dense FFN leaf, the embedding)
+    in up to eight row blocks, so the float32 draw of a piece is the only
+    wide temporary, never a twin of the leaf."""
+    rows = math.gcd(shape[0], 8) if math.prod(shape) >= 2 ** 25 else 1
+    part = (shape[0] // rows,) + tuple(shape[1:])
+    out = jax.lax.map(
+        lambda kk: (jax.random.normal(kk, part, jnp.float32)
+                    * std).astype(dtype),
+        jax.random.split(key, math.prod(lead) * rows))
+    return out.reshape(lead + shape)
+
+
+@dataclass
+class LayerCall:
+    """The call a layer's operator runs in.  The dense forward: the rotary
+    table alone.  A cached call (a prefill chunk, a decode round) besides:
+    ``k`` / ``v`` the cache layers a group, lists that a layer replaces as it
+    writes; ``at`` [B] each sequence's first new position; ``attention(q,
+    k_view, v_view, window)`` the call's attention step over a layer's view;
+    ``one_query`` a decode round.  A model with state layers: ``state`` its
+    ``serve.kv_cache.SlotStates`` (None in the dense forward, which starts
+    from zeros) and ``last`` the chunk-relative index of the last REAL token
+    (None: the call's last row)."""
+
+    cos: jax.Array
+    sin: jax.Array
+    k: Optional[list] = None
+    v: Optional[list] = None
+    at: Optional[jax.Array] = None
+    attention: Optional[Callable] = None
+    one_query: bool = False
+    state: object = None
+    last: object = None
+
+
+class GroupedHeads:
+    """Grouped-query attention's projections for any model whose
+    configuration ``self.c`` gives ``num_heads``, ``num_kv_heads``,
+    ``head_dim``, ``rms_eps`` and ``dtype`` (:class:`BlockDecoder`'s models;
+    ``models/mellum.py``, which trains): Q and K normalised per head, the
+    half-rotation layout over the whole head, the out-projection.  ``p`` is
+    the attention leaves stacked over layers, ``l`` the layer read."""
+
+    def _norm(self, x, scale):
+        return ops.rms_norm(x, scale, eps=self.c.rms_eps)
+
+    @staticmethod
+    def _rotate(x, cos, sin):
+        """Half-rotation layout over the whole head: x [B, S, heads, D],
+        cos/sin [B, S, D / 2]; float32 inside, result in x's dtype."""
+        xf = x.astype(jnp.float32)
+        d2 = x.shape[-1] // 2
+        x1, x2 = xf[..., :d2], xf[..., d2:]
+        cos, sin = cos[:, :, None], sin[:, :, None]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    def _qkv(self, p, l: int, a, cos, sin, rotate: bool):
+        """a [B, S, H] normed -> (q [B, heads, S, D], k [B, S, kv_heads, D],
+        v the same) of layer ``l``: q and k normalised per head, rotated
+        where ``rotate``.  k and v are the rows a cache holds."""
+        c, dt = self.c, self.c.dtype
+        b, s, _ = a.shape
+        q = ops.linear(a, p["q"][l].astype(dt), trans_w=True).reshape(
+            b, s, c.num_heads, c.head_dim)
+        k = ops.linear(a, p["k"][l].astype(dt), trans_w=True).reshape(
+            b, s, c.num_kv_heads, c.head_dim)
+        v = ops.linear(a, p["v"][l].astype(dt)).reshape(
+            b, s, c.num_kv_heads, c.head_dim)
+        q = self._norm(q, p["q_norm"][l])
+        k = self._norm(k, p["k_norm"][l])
+        if rotate:
+            q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
+        return jnp.moveaxis(q, 1, 2), k, v
+
+    def _out(self, p, l: int, o):
+        """o [B, heads, S, D] -> [B, S, H]."""
+        b, _, s, _ = o.shape
+        o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
+        return ops.linear(o.astype(self.c.dtype),
+                          p["o"][l].astype(self.c.dtype))
+
+
+class BlockDecoder(GroupedHeads, Module):
+    """``config`` gives ``num_layers``, ``first_dense``,
+    :class:`GroupedHeads`' widths, ``rope_theta`` and ``dtype``; ``moe`` is
+    the model's expert layer.  The tables, each by layer index and holding
+    the attention layers alone: ``attn_leaf`` the layer's index in the
+    stacked attention leaves, ``cache_layer`` its (group, cache layer in the
+    group) of the serving cache, ``rotated`` the layers whose q and k are
+    rotated, ``window`` the window of those that have one.
+
+    ``params``: ``tok_emb`` [V, H], ``lm_head`` [V, H] unless the head is
+    tied, ``norm_f``, ``layers``: ``attn_norm``/``ffn_norm`` [L, H] (the
+    operator's norm whatever the operator), ``attn`` (:class:`GroupedHeads`'
+    leaves, stacked), ``ffn`` {gate, up, down} over the ``first_dense``
+    leading layers, ``moe`` (the expert layer's) over the rest."""
+
+    # what the fourth value of the two cache entry points counts, in order
+    step_stats = MOE_STATS
+
+    def __init__(self, config, moe, *, attn_leaf, cache_layer, rotated,
+                 window=None):
+        self.c = config
+        self.moe = moe
+        self.scale = config.head_dim ** -0.5
+        self.attn_leaf, self.cache_layer = attn_leaf, cache_layer
+        self.rotated = frozenset(rotated)
+        self.window = dict(window or {})
+
+    # ---- pieces of a layer ----
+    def rope_at(self, pos):
+        """cos/sin [..., head_dim / 2] float32 at absolute positions."""
+        d = self.c.head_dim
+        inv = 1.0 / self.c.rope_theta ** (
+            jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = pos.astype(jnp.float32)[..., None] * inv
+        return jnp.cos(ang), jnp.sin(ang)
+
+    def _ffn(self, p, l: int, x):
+        dt = self.c.dtype
+        with jax.named_scope("hetu.ffn.dense"):
+            g = ops.linear(x, p["gate"][l].astype(dt))
+            u = ops.linear(x, p["up"][l].astype(dt))
+            return ops.linear(ops.silu(g) * u, p["down"][l].astype(dt))
+
+    def _operator(self, p, l: int, a, call: LayerCall):
+        """Layer ``l``'s operator on its normed input ``a`` [B, S, H]:
+        attention, unless the model states another for the layer."""
+        return self._attention(p["attn"], l, a, call)
+
+    def _attention(self, pa, l: int, a, call: LayerCall):
+        """Grouped-query attention of layer ``l`` in the call ``call``, ``pa``
+        the stacked attention leaves.  The dense forward attends over the
+        call's own rows.  A cached call: the layer reads its own cache layer
+        of its group (of the engine's pools, that layer's pages and no more),
+        writes its new rows [B, S, kv_heads, D] into the views from each
+        sequence's first position on (a ring wraps), attends over them and
+        puts the new rows into the pool.  Views and new rows are kept FLAT,
+        [B, T, kv_heads * D], as the pages hold them: split by head a view
+        is tiled another way and copied whole.  A decode round's FULL layer
+        makes no view, its step is ``ops.decode_layer_attention`` over its
+        group's cache where it lies; a window layer's ring is read as
+        above."""
+        c = self.c
+        window = self.window.get(l)
+        al = self.attn_leaf[l]
+        q, k, v = self._qkv(pa, al, a, call.cos, call.sin, l in self.rotated)
+        b, s = k.shape[:2]
+        scope = "hetu.attn.window" if window else "hetu.attn.full"
+        if call.k is None:
+            with jax.named_scope(scope):
+                # heads grouped by the KV head they read: [B, kv, rep, S, D]
+                # against [B, kv, 1, S, D]
+                o = ops.causal_attention(
+                    q.reshape(b, c.num_kv_heads, c.num_heads // c.num_kv_heads,
+                              s, c.head_dim),
+                    jnp.moveaxis(k, 1, 2)[:, :, None],
+                    jnp.moveaxis(v, 1, 2)[:, :, None],
+                    scale=self.scale, window=window)
+            return self._out(pa, al, o.reshape(b, c.num_heads, s, c.head_dim))
+        g, cl = self.cache_layer[l]
+        with jax.named_scope(scope):
+            if call.one_query and window is None:
+                o, call.k[g], call.v[g] = ops.decode_layer_attention(
+                    q, k, v, call.k[g], call.v[g], cl, call.at,
+                    scale=self.scale)
+                return self._out(pa, al, o)
+            update = ops.ring_update if window else ops.cache_update
+            k_view, v_view = call.k[g].read(cl), call.v[g].read(cl)
+            t = k_view.shape[1]
+            k_view, v_view = update(
+                k_view.reshape(b, t, -1), v_view.reshape(b, t, -1),
+                k.reshape(b, s, -1), v.reshape(b, s, -1), call.at)
+            o = call.attention(q, k_view, v_view, window)
+        call.k[g] = call.k[g].write(cl, k)
+        call.v[g] = call.v[g].write(cl, v)
+        return self._out(pa, al, o)
+
+    def _layer(self, p, l: int, h, call: LayerCall):
+        """Layer ``l`` over ``h`` [B, S, H] in the call ``call``, ``p`` the
+        stacked leaves of every layer.  Returns (out, the expert layer's
+        counts [4] int32, zeros on a dense layer)."""
+        h = h + self._operator(p, l, self._norm(h, p["attn_norm"][l]), call)
+        u = self._norm(h, p["ffn_norm"][l])
+        if l < self.c.first_dense:
+            return h + self._ffn(p["ffn"], l, u), jnp.zeros((4,), jnp.int32)
+        moe, e = p["moe"], l - self.c.first_dense
+        m, stats = self.moe.apply(
+            dict(moe, router=moe["router"][e],
+                 router_bias=moe["router_bias"][e]),
+            u, layer=e)
+        return h + m, stats
+
+    def _embed(self, p, ids):
+        return ops.embedding_lookup(p["tok_emb"], ids).astype(self.c.dtype)
+
+    def _head(self, p, h):
+        """h: the stream after the last layer -> logits; the head tied to
+        the embedding where the weights have no ``lm_head``."""
+        return ops.linear(self._norm(h, p["norm_f"]),
+                          p.get("lm_head", p["tok_emb"]).T.astype(
+                              self.c.dtype))
+
+    # ---- dense forward ----
+    def hidden_states(self, variables, input_ids, *, train: bool = False,
+                      rng=None):
+        p = variables["params"]
+        c = self.c
+        b, s = input_ids.shape
+        h = self._embed(p, input_ids)
+        pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        call = LayerCall(*self.rope_at(pos))
+        for l in range(c.num_layers):
+            h, _ = self._layer(p["layers"], l, h, call)
+        return h
+
+    def apply(self, variables, input_ids, *, train: bool = False, rng=None):
+        h = self.hidden_states(variables, input_ids, train=train, rng=rng)
+        return self._head(variables["params"], h), {}
+
+    # ---- serving (hetu_tpu/serve): prefill in chunks / decode ----
+    # k_cache and v_cache are each the cache layers of the model's one group,
+    # or a tuple of them, one a group of its ``kv_cache_spec()`` in order,
+    # with ``read(layer)`` -> [B, T, kv_heads, D] and ``write(layer, rows)``;
+    # a window group's view is a ring.  Both entry points return a fourth
+    # value, the expert layers' counts (``step_stats`` names them) summed
+    # over the layers.
+
+    def _cached(self, p, input_ids, k_cache, v_cache, pos, attention,
+                one_query: bool = False, state=None, last=None):
+        """Both cache entry points: every layer in a :class:`LayerCall` over
+        the cache layers (a group's pair, or one pair bare where the model's
+        cache has one group) from each sequence's first position
+        ``pos[:, 0]`` on, ``attention`` the call's step.  Returns (the
+        stream, new_k, new_v, counts) and, given ``state``, the state
+        last."""
+        h = self._embed(p, input_ids)
+        bare = not isinstance(k_cache, (tuple, list))
+        call = LayerCall(
+            *self.rope_at(pos), k=[k_cache] if bare else list(k_cache),
+            v=[v_cache] if bare else list(v_cache), at=pos[:, 0],
+            attention=attention, one_query=one_query, state=state, last=last)
+        stats = jnp.zeros((4,), jnp.int32)
+        for l in range(self.c.num_layers):
+            h, n = self._layer(p["layers"], l, h, call)
+            stats = stats + n
+        k_cache, v_cache = (call.k[0], call.v[0]) if bare \
+            else (tuple(call.k), tuple(call.v))
+        out = (h, k_cache, v_cache, self._counts(stats))
+        return out if state is None else out + (call.state,)
+
+    def _counts(self, stats):
+        """The counts a cache entry point returns, from the expert layers'
+        sum ``stats`` [4]: here as they are (``step_stats`` names them)."""
+        return stats
+
+    def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
+                                 v_cache, start, *, last_index=None,
+                                 state=None):
+        """input_ids [B, S_c] at absolute positions ``start..``; positions
+        below ``start`` of the caches are written.  Returns (logits [B, V]
+        at chunk-relative ``last_index``, new_k, new_v, counts), and with
+        ``state`` (a model with state layers) the state after
+        ``last_index`` behind them."""
+        p = variables["params"]
+        b, s = input_ids.shape
+        starts = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
+        pos = starts[:, None] + jnp.arange(s)[None]
+
+        heads = (self.c.num_kv_heads, self.c.head_dim)
+
+        def attention(q, k_view, v_view, window):
+            return ops.chunk_attention(
+                q, k_view.reshape(k_view.shape[:2] + heads),
+                v_view.reshape(v_view.shape[:2] + heads), starts,
+                scale=self.scale, window=window)
+
+        h, *rest = self._cached(p, input_ids, k_cache, v_cache, pos,
+                                attention, state=state, last=last_index)
+        idx = s - 1 if last_index is None else last_index
+        h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
+        return (self._head(p, h), *rest)
+
+    def decode_with_cache(self, variables, input_ids, k_cache, v_cache,
+                          lengths, *, state=None):
+        """One decode step; input_ids [B], lengths [B] tokens cached.
+        Returns (logits [B, V], new_k, new_v, counts), and with ``state``
+        the new state behind them."""
+        p = variables["params"]
+
+        def attention(q, k_view, v_view, window):
+            return ops.decode_attention(
+                q, k_view, v_view, lengths, scale=self.scale, window=window,
+                kv_heads=self.c.num_kv_heads)
+
+        h, *rest = self._cached(
+            p, input_ids[:, None], k_cache, v_cache, lengths[:, None],
+            attention, one_query=True, state=state)
+        return (self._head(p, h[:, 0]), *rest)
+
+    # ---- training (test size) ----
+    def lm_loss_fn(self):
+        """Next-token loss; batch = (input_ids,)."""
+        def fn(params, model_state, batch, rng, train):
+            ids = batch[0] if isinstance(batch, (tuple, list)) else batch
+            logits, _ = self.apply({"params": params, "state": {}}, ids,
+                                   train=train, rng=rng)
+            per = ops.softmax_cross_entropy_sparse(logits[:, :-1], ids[:, 1:])
+            return jnp.mean(per), ({}, model_state)
+        return fn

@@ -39,7 +39,7 @@ through grouped matmuls, ``hetu.moe.gmm``).  No auxiliary balance loss (the
 configuration gives no coefficient) and no next-token head (it gives no key
 for one).
 
-**Shared with** ``models/exaone_moe.py`` (``GroupedHeads``): the grouped
+**Shared with** ``models/block.py`` (``GroupedHeads``): the grouped
 projections with their per-head norms, the half-layout rotation and the
 out-projection.  **Split**: rotation on every layer, by two tables; the
 training attention (flash with ``window=`` and K, V at ``kv_heads``: no
@@ -71,7 +71,7 @@ import jax.numpy as jnp
 from hetu_tpu import ops
 from hetu_tpu.layers.base import Module
 from hetu_tpu.layers.moe import HeldExpertLayer
-from hetu_tpu.models.exaone_moe import FULL, WINDOW, GroupedHeads
+from hetu_tpu.models.block import FULL, WINDOW, GroupedHeads
 from hetu_tpu.ops.moe_ops import held_expert_path
 
 # the scalar ids of one step's expert layers, summed over the layers; the

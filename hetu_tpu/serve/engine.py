@@ -8,11 +8,15 @@ What a served model implements: ``prefill_chunk_with_cache`` and
 ``k_cache`` / ``v_cache`` arguments and return them beside the logits),
 and either ``kv_cache_spec()`` or the config fields
 :meth:`KVCacheSpec.from_model` reads.  ``models/gpt.py``,
-``models/llama.py``, ``models/longcat_flash.py`` and
-``models/exaone_moe.py`` do.  A model whose spec states several GROUPS of
-cache layers (window layers beside full ones) is handed a tuple of cache
-layers in each place, one a group in the spec's order, and returns the
-same.  Optionally
+``models/llama.py``, ``models/longcat_flash.py``,
+``models/exaone_moe.py`` and ``models/lfm2_moe.py`` do.  A model whose spec
+states several GROUPS of cache layers (window layers beside full ones) is
+handed a tuple of cache layers in each place, one a group in the spec's
+order, and returns the same.  A model whose spec states STATE LAYERS
+(``KVCacheSpec.state_layers``: a layer that remembers a sequence in a
+fixed-size array, ``models/lfm2_moe.py``'s short convolutions) takes them
+as ``state=`` (:class:`~hetu_tpu.serve.kv_cache.SlotStates`) in both entry
+points and returns them as its LAST result, behind its counts.  Optionally
 ``serving_params(params)``: the parameters as those two entry points READ
 them (a leaf they read only as ``astype(compute dtype)`` in that dtype, a
 leaf they read in two dtypes held in both, the rest as given).  The engine
@@ -55,7 +59,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from hetu_tpu.parallel.mesh import AXIS_TP
 from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
-    KVCacheSpec, PagedKVCache, PagedLayers, pow2_ceil,
+    KVCacheSpec, PagedKVCache, PagedLayers, SlotStates, pow2_ceil,
 )
 from hetu_tpu.serve.metrics import ServeMetrics
 from hetu_tpu.telemetry import trace
@@ -181,6 +185,24 @@ class PagedServeEngine:
     view as a chunk does).  No view of every layer is built and the pool is
     never copied (how that was checked: ``kv_cache.py``'s docstring).
 
+    STATE LAYERS (a cache whose spec states any, ``cache.state``): both
+    programs take the state array as a FIFTH argument, donated like the
+    pools, and return it; one more int32 of ``aux`` a sequence names its
+    slot.  A chunk reads and writes its one slot's state: zeros where it
+    starts at position 0, and what it leaves is the state after its last
+    REAL token, not after its bucket's padding (the model's ``last_index``).
+    A decode round reads and writes the active slots' rows by slot; a slot
+    bucket's padding rows write the scratch row ``num_slots``, which nothing
+    reads.  Such an engine takes NO prefix match (a match would hand over
+    the shared tokens' pages without the state at their boundary, which
+    nobody kept): its cache is built without an index, no probe hashes a
+    prompt, and each request that would have probed counts in
+    ``prefix_state_refusals``.  A preempted request prefills again from
+    position 0 and so rebuilds its state.  Live slots are not exported
+    (``kv_cache.GroupedCacheNotPortable``, as a grouped cache).  A model
+    without state layers pays nothing: its programs take four arguments and
+    trace to the jaxpr they traced to before.
+
     The scheduler/pool/migration stack drives it through
     :meth:`admission_ok`, :meth:`begin_prefill`, :meth:`prefill_step`
     (page-budget admission a group, chunked prefill interleaved with
@@ -255,6 +277,9 @@ class PagedServeEngine:
         # full ones): the fixed widths of their tables in the chunk programs
         # and in the decode program (a ring as wide as the step needs)
         self._more = self.cache.groups[1:]
+        # state layers (kv_cache.SlotStates): the cache built itself without
+        # a prefix index; both programs carry ``cache.state``
+        self._states = self.cache.state is not None
         self._ring_chunk = tuple(g.spec.ring_pages(self.prefill_chunk, ps)
                                  for g in self._more)
         self._ring_decode = tuple(g.spec.ring_pages(1, ps)
@@ -277,6 +302,10 @@ class PagedServeEngine:
                 f"g{i}_cache_layers": int(g.spec.num_layers),
                 f"g{i}_window": int(g.window or 0),
                 f"g{i}_pool_bytes": int(g.k.nbytes + g.v.nbytes)})
+        if self._states:
+            ids.update({"state_layers": int(spec.state_layers),
+                        "bytes_per_slot": int(spec.bytes_per_slot),
+                        "state_bytes": self.cache.state_bytes})
         trace.instant("serve.cache_spec", ids)
         trace.instant("serve.params_held", held)
 
@@ -311,9 +340,25 @@ class PagedServeEngine:
         layer to keep every position (all three in pages of one cache
         layer, and gauges of the same names), and ``kv_window_released``,
         the pages dropped from behind a window since the last call (a
-        counter).  A cache of one group: ``ids`` as given."""
-        if not self._more:
+        counter).  Over STATE LAYERS, besides: ``state_slots_held``, the
+        slots handed out, ``state_bytes``, what their state takes, and
+        ``kv_bytes_held``, the bytes of the pages their tables hold, all
+        groups.  A cache of one group without state: ``ids`` as given."""
+        if not self._more and not self._states:
             return ids
+        if self._states:
+            cache = self.cache
+            n = cache.num_slots - cache.num_free
+            # a freed slot's tables are empty
+            pages = [sum(map(len, g.tables)) for g in cache.groups]
+            ids = {**(ids or {}), "state_slots_held": n,
+                   "state_bytes": n * cache.spec.bytes_per_slot,
+                   "kv_bytes_held": cache.page_size * sum(
+                       g.spec.bytes_per_token * held
+                       for g, held in zip(cache.groups, pages))}
+            if not self._more:
+                ids["kv_pages_full"] = pages[0] * cache.spec.num_layers
+                return ids
         after = self.cache.lengths.copy()
         after[slots] = lengths
         full, window, one = self.cache.held_layer_pages(after)
@@ -382,13 +427,16 @@ class PagedServeEngine:
         more = tuple(zip((g.spec.row_shapes() for g in self._more),
                          self._ring_chunk))
 
-        def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux):
+        def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux, *state):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
             # operands (ids | write pages | write offsets | page table |
             # start | last) into one device_put, like the decode step; a
-            # further group appends its own (write pages | ring table)
+            # further group appends its own (write pages | ring table);
+            # over state layers ``state`` is their array and one int more,
+            # the last, names the slot
             rings = sum(ring for _, ring in more)
-            sc = (aux.shape[0] - n_table - 2 - rings) // (3 + len(more))
+            sc = (aux.shape[0] - n_table - 2 - rings - len(state)) \
+                // (3 + len(more))
             ids = aux[:sc][None]
             # per-token write map: real positions land in their pages,
             # pad positions in scratch 0
@@ -411,18 +459,26 @@ class PagedServeEngine:
                     v.append(PagedLayers(v_pool[i], table, wpage, woff, v_r))
                     at += sc + ring
                 k, v = tuple(k), tuple(v)
+            # the slot's state, read as zeros by the chunk that starts the
+            # sequence; the model hands it back last
+            held = {"state": SlotStates(state[0], aux[-1:],
+                                        (start == 0)[None])} if state else {}
             # a model may return a fourth value, its per-call counts
             # (``model.step_stats`` names them); most return none
             logits, k, v, *stats = model.prefill_chunk_with_cache(
                 {"params": params, "state": {}}, ids, k, v,
-                start, last_index=last)
+                start, last_index=last, **held)
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
+            if state:
+                *stats, held = stats
+                return _pools(k), _pools(v), tok, tuple(stats), held.rows
             return _pools(k), _pools(v), tok, tuple(stats)
 
         # the function's name is the program's on the device: the profiler's
         # ``XLA Modules`` events, compile logs and HLO dumps read
         # ``jit_hetu_serve_prefill_chunk``
-        return jax.jit(hetu_serve_prefill_chunk, donate_argnums=(1, 2))
+        return jax.jit(hetu_serve_prefill_chunk,
+                       donate_argnums=(1, 2, 4) if self._states else (1, 2))
 
     def _build_decode(self):
         model = self.model
@@ -432,15 +488,18 @@ class PagedServeEngine:
         # a pool laid over a mesh says so: its one-query step keeps the view
         sharded = self.mesh is not None
 
-        def hetu_serve_decode(params, k_pool, v_pool, aux):
+        def hetu_serve_decode(params, k_pool, v_pool, aux, *state):
             # aux [B, n_pg + 4] int32 packs every host-side operand of
             # the step (page table | length | token | write page | write
             # offset) into ONE device_put — five small uploads per step
             # cost more wall time than the decode math at serving batch
             # sizes; a further group appends its own (ring table | write
             # page), of a width fixed at build, so the program stays keyed
-            # by the first group's page bucket and the batch bucket
-            n_pg = aux.shape[1] - 4 - sum(ring + 1 for _, ring in more)
+            # by the first group's page bucket and the batch bucket; over
+            # state layers ``state`` is their array and one column more, the
+            # last, names each row's slot (a padding row: the scratch slot)
+            n_pg = aux.shape[1] - 4 - sum(ring + 1 for _, ring in more) \
+                - len(state)
             tables = aux[:, :n_pg]
             lengths = aux[:, n_pg]
             tokens = aux[:, n_pg + 1]
@@ -467,13 +526,20 @@ class PagedServeEngine:
                                          sharded))
                     at += ring + 1
                 k, v = tuple(k), tuple(v)
+            held = {"state": SlotStates(state[0], aux[:, -1])} \
+                if state else {}
             logits, k, v, *stats = model.decode_with_cache(
-                {"params": params, "state": {}}, tokens, k, v, lengths)
+                {"params": params, "state": {}}, tokens, k, v, lengths,
+                **held)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            if state:
+                *stats, held = stats
+                return _pools(k), _pools(v), nxt, tuple(stats), held.rows
             return _pools(k), _pools(v), nxt, tuple(stats)
 
         # named as the chunk program is: ``jit_hetu_serve_decode``
-        return jax.jit(hetu_serve_decode, donate_argnums=(1, 2))
+        return jax.jit(hetu_serve_decode,
+                       donate_argnums=(1, 2, 4) if self._states else (1, 2))
 
     # ---- admission (the scheduler's page-budget backpressure) ----
     def admission_pages(self, prompt_len: int, max_tokens: int,
@@ -549,6 +615,12 @@ class PagedServeEngine:
 
     def _match_on_first_chunk(self, slot: int, cur: _PrefillCursor) -> None:
         cur.matched = True
+        if self._states:
+            # no index exists (the class docstring says why): the request
+            # prefills every token, and is counted where it would have probed
+            self.metrics.inc("prefix_state_refusals")
+            self.metrics.inc("prefix_miss_tokens", cur.n)
+            return
         n_shared, pages = self.cache.match_prefix(cur.prompt)
         if n_shared and not any(g.tables[slot] for g in self.cache.groups):
             self.cache.adopt_prefix(slot, n_shared, pages)
@@ -569,7 +641,7 @@ class PagedServeEngine:
         cur = self._cursors.get(slot)
         if cur is None:
             raise ValueError(f"slot {slot} has no prefill in progress")
-        if self._more:
+        if self._more or self._states:
             return self._prefill_step_grouped(slot, cur)
         # one span for the whole chunk, tiled by its four seams: prep is
         # the host's work before the program can be called (prefix match
@@ -645,10 +717,11 @@ class PagedServeEngine:
                 return tok
 
     def _prefill_step_grouped(self, slot: int, cur) -> Optional[int]:
-        """:meth:`prefill_step` over a cache of several groups: the same
-        four seams, with each further group's write pages and ring table
-        packed behind the first group's operands, the pools handed over as
-        tuples, and the groups' page counts on the ``post`` span."""
+        """:meth:`prefill_step` over a cache of several groups or with state
+        layers: the same four seams, with each further group's write pages
+        and ring table packed behind the first group's operands, the pools
+        handed over as tuples, the state array and the slot behind them, and
+        the groups' page counts and the state's on the ``post`` span."""
         with trace.span("serve.prefill_chunk", {"slot": int(slot)}):
             with trace.span("serve.prefill_chunk.prep"):
                 if not cur.matched:
@@ -681,7 +754,8 @@ class PagedServeEngine:
                 pages, wo = self.cache.prepare_write(slot, start, size)
                 wp, wo = self.cache.padded_write_map(pages[0], wo, s)
                 aux = np.zeros(3 * s + n_table + 2 + sum(
-                    s + ring for ring in self._ring_chunk), np.int32)
+                    s + ring for ring in self._ring_chunk) + self._states,
+                    np.int32)
                 aux[:size] = cur.prompt[start:end]
                 aux[s:2 * s] = wp
                 aux[2 * s:3 * s] = wo
@@ -695,7 +769,10 @@ class PagedServeEngine:
                     aux[at:at + size] = wp
                     aux[at + s:at + s + ring] = g.device_table(slot, ring)
                     at += s + ring
+                if self._states:
+                    aux[-1] = slot
                 k_pool, v_pool = self._pool_args()
+                state = (self.cache.state,) if self._states else ()
                 self._seq += 1
                 launch = {"start": int(start), "tokens": int(size),
                           "bucket": int(s),
@@ -705,13 +782,13 @@ class PagedServeEngine:
                     launch[f"g{i}_view_bytes"] = \
                         ring * self._page_view_bytes[i]
             with trace.span("serve.prefill_chunk.launch", launch):
-                k, v, tok, stats = chunk_fn(
-                    self.params, k_pool, v_pool, jnp.asarray(aux))
+                k, v, tok, stats, *state = chunk_fn(
+                    self.params, k_pool, v_pool, jnp.asarray(aux), *state)
             with trace.span("serve.prefill_chunk.fetch", {"seq": self._seq}):
                 tok = int(tok)  # the host blocked on the device
                 counts = self._held(self._count(stats), slot, end)
             with trace.span("serve.prefill_chunk.post", counts):
-                self.cache.update(k, v)
+                self.cache.update(k, v, *state)
                 self.cache.lengths[slot] = end
                 cur.pos = end
                 self.metrics.inc("prefill_tokens", size)
@@ -749,7 +826,7 @@ class PagedServeEngine:
         act = np.nonzero(self.active)[0]
         if len(act) == 0:
             return {}
-        if self._more:
+        if self._more or self._states:
             return self._decode_grouped(act)
         # a cache of one group takes the round below, kept line for line as
         # it was before groups.  Folded into ONE round with the groups'
@@ -838,11 +915,12 @@ class PagedServeEngine:
             return out
 
     def _decode_grouped(self, act) -> dict:
-        """:meth:`decode` over a cache of several groups, ``act`` the active
-        slots: the same four seams, with each further group's ring table
-        and write page packed behind the first group's operands, the pools
-        handed over as tuples, and the groups' page counts on the ``post``
-        span."""
+        """:meth:`decode` over a cache of several groups or with state
+        layers, ``act`` the active slots: the same four seams, with each
+        further group's ring table and write page packed behind the first
+        group's operands, the pools handed over as tuples, the state array
+        and each row's slot behind them (a padding row: the scratch slot),
+        and the groups' page counts and the state's on the ``post`` span."""
         with trace.span("serve.decode", {"active": len(act)}):
             with trace.span("serve.decode.prep"):
                 if (self.cache.lengths[act] >= self.cache.max_len).any():
@@ -879,7 +957,8 @@ class PagedServeEngine:
                                   {"kind": "decode", "pages": int(n_pg),
                                    "batch": int(bb)})
                 aux = np.zeros((bb, n_pg + 4 + sum(
-                    ring + 1 for ring in self._ring_decode)), np.int32)
+                    ring + 1 for ring in self._ring_decode) + self._states),
+                    np.int32)
                 for i, slot in enumerate(sl):
                     t = self.cache.tables[slot][:n_pg]
                     aux[i, :len(t)] = t
@@ -894,19 +973,23 @@ class PagedServeEngine:
                         aux[i, at:at + ring] = g.device_table(int(slot), ring)
                     aux[:, at + ring] = wp_g
                     at += ring + 1
+                if self._states:
+                    aux[:, -1] = self.cache.num_slots      # scratch
+                    aux[:len(act), -1] = act
                 k_pool, v_pool = self._pool_args()
+                state = (self.cache.state,) if self._states else ()
                 self._seq += 1
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb),
                              "seq": self._seq}):
-                k, v, nxt, stats = self._decode_fn(
-                    self.params, k_pool, v_pool, jnp.asarray(aux))
+                k, v, nxt, stats, *state = self._decode_fn(
+                    self.params, k_pool, v_pool, jnp.asarray(aux), *state)
             with trace.span("serve.decode.fetch", {"seq": self._seq}):
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = self._held(self._count(stats), act,
                                      self.cache.lengths[act] + 1)
             with trace.span("serve.decode.post", counts):
-                self.cache.update(k, v)
+                self.cache.update(k, v, *state)
                 out = {}
                 for i, slot in enumerate(act):
                     self.cache.lengths[slot] += 1
@@ -1007,6 +1090,10 @@ class PagedServeEngine:
     def alloc_slot(self) -> int:
         slot = self.cache.alloc()
         self.active[slot] = False
+        if self._states:
+            # the slot's state is zeros again (read so by its first chunk)
+            self.metrics.inc("state_resets")
+            trace.instant("serve.state_reset", {"slot": int(slot)})
         return slot
 
     def release(self, slot: int) -> None:

@@ -5,7 +5,12 @@ device-resident pools ``[L, num_pages, page_size, kv_heads * head_dim]``, one
 pair for each GROUP of cache layers the model's :class:`KVCacheSpec` states
 (most models have one group; a model with window layers beside full ones has
 two, and its window group keeps only the pages a future query can still
-see), per-request page tables a group, refcounted PREFIX SHARING (hash-of-token-prefix
+see), beside them the STATE LAYERS of a model whose spec states any (a
+layer that remembers a sequence in a fixed-size array, not in rows a token:
+a short convolution's last inputs; arrays ``[state layers, slots + 1, *shape]``
+that a slot owns whole, never paged, never shared, read as zeros by the
+chunk that starts a sequence: :class:`SlotStates`),
+per-request page tables a group, refcounted PREFIX SHARING (hash-of-token-prefix
 → shared read-only pages, so identical system prompts across a pool's
 traffic dedup to one physical copy) with copy-on-write on the first
 divergent write, and an LRU prefix index whose pages are reclaimed under
@@ -77,7 +82,16 @@ class KVCacheSpec:
     i - j < window``), so the cache keeps the pages a future query can still
     see and drops the rest as a slot advances; None: every position.  The
     first group is the one whose tables grow with the sequence: the
-    engine's page buckets and ``pages_per_slot`` are its."""
+    engine's page buckets and ``pages_per_slot`` are its.
+
+    **State layers.**  A third kind of slot state, beside the pages that
+    keep every position and a window group's ring: ``state_layers`` layers
+    each remember a sequence in ONE array of ``state_shape`` (in
+    ``state_dtype``; None: ``dtype``), whatever the sequence's length, so
+    they cost :attr:`bytes_per_slot` a slot and nothing a token.  The cache
+    holds them as one array a slot owns whole (:class:`SlotStates`); only
+    the spec a model hands over states them (a group under ``also`` has
+    none of its own)."""
 
     num_layers: int
     num_kv_heads: int
@@ -86,12 +100,22 @@ class KVCacheSpec:
     v_head_dim: Optional[int] = None
     window: Optional[int] = None
     also: tuple = ()
+    state_layers: int = 0
+    state_shape: tuple = ()
+    state_dtype: object = None
 
     @property
     def groups(self) -> tuple:
         """Every group's own spec, this one's first."""
         return (replace(self, also=()),) + tuple(self.also) \
             if self.also else (self,)
+
+    @property
+    def bytes_per_slot(self) -> int:
+        """Bytes one slot's state takes over the state layers, whatever the
+        sequence's length; 0 for a model with none."""
+        return (self.state_layers * int(np.prod(self.state_shape, dtype=int))
+                * np.dtype(self.state_dtype or self.dtype).itemsize)
 
     @property
     def v_dim(self) -> int:
@@ -239,6 +263,45 @@ class PagedLayers:
             kv_heads=self.row[0], scale=scale)
 
 
+@partial(jax.tree_util.register_dataclass,
+         data_fields=("rows", "slots", "fresh"), meta_fields=())
+@dataclass(frozen=True)
+class SlotStates:
+    """The state layers of a cache as a jitted step hands them to the model's
+    cache entry points (their ``state=`` argument, handed back as their last
+    result): ``rows`` ``[state layers, num_slots + 1, *state_shape]``, the
+    cache's array itself (donated, carried through the layers, updated in
+    place); ``slots`` ``[B]`` int32, the slot of each of the step's
+    sequences, a bucket's padding row naming the SCRATCH slot ``num_slots``,
+    which no request owns; ``fresh`` ``[B]`` bool, or None in a decode round:
+    the sequence starts in this step (a chunk at position 0), so its state
+    READS as zeros whatever the slot's last owner left there.  A state layer
+    of the model reads its own layer's rows and writes them back; what it
+    writes is the state after the step's last REAL token (a chunk is padded
+    to its bucket: the model's ``last_index`` says where that is)."""
+
+    rows: jax.Array
+    slots: jax.Array
+    fresh: Optional[jax.Array] = None
+
+    def read(self, layer):
+        """State layer ``layer`` of the step's sequences, ``[B,
+        *state_shape]``."""
+        rows = self.rows[layer, self.slots]
+        if self.fresh is None:
+            return rows
+        fresh = self.fresh.reshape((-1,) + (1,) * (rows.ndim - 1))
+        return jnp.where(fresh, 0, rows)
+
+    def write(self, layer, rows):
+        """The step's sequences' new state ``[B, *state_shape]`` of state
+        layer ``layer``, each into its own slot (padding rows all into the
+        scratch slot, where the last one written stays and nothing reads
+        it)."""
+        return replace(self, rows=self.rows.at[layer, self.slots].set(
+            rows.astype(self.rows.dtype)))
+
+
 class PagePoolExhausted(RuntimeError):
     """The page pool has no free page and nothing reclaimable.
 
@@ -252,12 +315,14 @@ class PagePoolExhausted(RuntimeError):
 
 
 class GroupedCacheNotPortable(RuntimeError):
-    """A cache of more than one group was asked to export or import live
-    slots.  The wire form (:class:`KVSlotSnapshot`, ``serve/migrate.py``) is
-    one ``[layers, length, heads, width]`` pair a slot; a window group's
-    slot holds the rows of its last pages only, under another layer count,
-    and no peer could adopt them through that form.  Such a cache's requests
-    move by re-prefill, not by their rows:
+    """A cache of more than one group, or one with state layers, was asked
+    to export or import live slots.  The wire form (:class:`KVSlotSnapshot`,
+    ``serve/migrate.py``) is one ``[layers, length, heads, width]`` pair a
+    slot; a window group's slot holds the rows of its last pages only, under
+    another layer count, and a state layer's memory of the sequence is in no
+    page at all: no peer could adopt either through that form, and pages
+    without their state would continue to wrong tokens.  Such a cache's
+    requests move by re-prefill, not by their rows:
     ``ContinuousBatchingScheduler.export_inflight_with_slots`` catches this
     error and hands the requests over folded (``slot=None``, no snapshot),
     so a drain or a planned migration completes at a prefill a request."""
@@ -415,6 +480,22 @@ class PagedKVCache:
     width (:meth:`KVCacheSpec.ring_pages`), so the programs' shapes follow
     the first group's page count alone.
 
+    STATE LAYERS (``spec.state_layers``; :attr:`state`, None without any):
+    one array ``[state layers, num_slots + 1, *state_shape]`` that the
+    engine's two programs take donated beside the pools and hand back
+    (:class:`SlotStates`, :meth:`update`).  Row ``slot`` is that slot's and
+    nobody else's: never paged, never shared, in no prefix entry (so the
+    engine takes no prefix match over such a cache, and this class is built
+    with no index).  Row ``num_slots`` is SCRATCH, where a decode bucket's
+    padding rows write.  :meth:`alloc` does nothing about it: a slot's
+    state is DEFINED as zeros at position 0, and the chunk that starts at 0
+    reads zeros in the program (``SlotStates.fresh``).  No stale state can
+    be read: the only other readers are a chunk at ``start > 0``, which
+    follows this request's own earlier chunk in this slot (a preempted
+    request prefills again from 0), and a decode round, which runs a slot
+    only after its last chunk; a prefix match, the one way a first chunk
+    starts past 0, is off.  The bytes count in :attr:`state_bytes`.
+
     Ownership model: each page carries two refcounts — ``ref_table``
     (how many slot page-tables reference it) and ``ref_index`` (how many
     prefix-index entries do).  A page is WRITABLE by a slot only when it
@@ -468,6 +549,16 @@ class PagedKVCache:
                 else 1 + self.num_slots * (min(ring, self.pages_per_slot)
                                            + 1),
                 self.page_size, sharding))
+        self.state = None
+        if spec.state_layers:
+            if sharding is not None:
+                raise ValueError("state layers over a mesh are not laid out")
+            # one row more than slots: the scratch slot of padding rows
+            self.state = jnp.zeros(
+                (spec.state_layers, self.num_slots + 1)
+                + tuple(spec.state_shape), spec.state_dtype or spec.dtype)
+            max_prefix_entries = 0   # an entry would need the state AT its
+            #                          boundary, which nobody keeps
         self.lengths = np.zeros(self.num_slots, np.int32)
         self._free_slots = list(range(self.num_slots - 1, -1, -1))
         self.max_prefix_entries = int(max_prefix_entries)
@@ -517,6 +608,12 @@ class PagedKVCache:
     def occupancy(self) -> float:
         return self.pages_in_use / max(
             sum(g.num_pages - 1 for g in self.groups), 1)
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes the state layers' array takes on the device (every slot's
+        and the scratch row's), 0 without state layers."""
+        return 0 if self.state is None else int(self.state.nbytes)
 
     @property
     def window_released(self) -> int:
@@ -573,13 +670,16 @@ class PagedKVCache:
         for g, n in zip(self.groups, n_pages):
             g.reserve[slot] = g.reserve_cap[slot] = max(int(n), 0)
 
-    def update(self, k, v) -> None:
+    def update(self, k, v, state=None) -> None:
         """Swap in the pool arrays a jitted step returned: one pair, or a
-        sequence of each in the groups' order."""
+        sequence of each in the groups' order; with state layers, their
+        array too."""
         if not isinstance(k, (tuple, list)):
             k, v = (k,), (v,)
         for g, k_g, v_g in zip(self.groups, k, v):
             g.k, g.v = k_g, v_g
+        if state is not None:
+            self.state = state
 
     # ---- page lifecycle (internal) ----
     def _evict_one_entry(self) -> bool:
@@ -808,6 +908,13 @@ class PagedKVCache:
                 f"groups: a window group holds a slot's last pages only, "
                 f"which the one-pair snapshot cannot carry; requeue the "
                 f"requests instead")
+        if self.state is not None:
+            raise GroupedCacheNotPortable(
+                f"cannot {verb} slots of a cache with "
+                f"{self.spec.state_layers} state layers: the one-pair "
+                f"snapshot carries pages and no state, and pages without "
+                f"their state continue to wrong tokens; requeue the "
+                f"requests instead")
 
     def export_slots(self, slot_ids) -> list:
         """Snapshot occupied slots as CONTIGUOUS truncated K/V rows
@@ -816,8 +923,8 @@ class PagedKVCache:
         pages only: sharing means a page can back many slots, but a
         migration payload ships each slot's logical tokens (the adopter
         rebuilds page tables locally; re-dedup on import is the
-        adopter's prefix index's job).  A cache of several groups
-        refuses (:class:`GroupedCacheNotPortable`)."""
+        adopter's prefix index's job).  A cache of several groups, or with
+        state layers, refuses (:class:`GroupedCacheNotPortable`)."""
         self._one_group("export")
         snaps = []
         ps = self.page_size

@@ -143,13 +143,15 @@ class LogitsOut:
     def __getattr__(self, name):
         return getattr(self.model, name)
 
+    # a model's own counts are dropped; its state (a model with state
+    # layers returns it last, behind its counts) is handed on
     def prefill_chunk_with_cache(self, *args, **kw):
-        logits, k, v = self.model.prefill_chunk_with_cache(*args, **kw)
-        return logits, k, v, logits
+        logits, k, v, *rest = self.model.prefill_chunk_with_cache(*args, **kw)
+        return (logits, k, v, logits, *rest[1:])
 
     def decode_with_cache(self, *args, **kw):
-        logits, k, v = self.model.decode_with_cache(*args, **kw)
-        return logits, k, v, logits
+        logits, k, v, *rest = self.model.decode_with_cache(*args, **kw)
+        return (logits, k, v, logits, *rest[1:])
 
 
 def engine_logits(model, variables, prompt, n: int, *, as_given=False,

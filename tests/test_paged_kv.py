@@ -21,6 +21,7 @@ from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, PagedServeEngine, Request,
 )
+from hetu_tpu.serve.kv_cache import PagedLayers
 from hetu_tpu.telemetry import trace
 from paged_programs import engine_greedy as _engine_greedy
 from paged_programs import (
@@ -883,3 +884,73 @@ def test_admission_is_by_group():
     assert full.available_pages() > 40 and not e.admission_ok(prompt, 8)
     e.release(other)
     assert e.admission_ok(prompt, 8)
+
+
+# ---- state layers cost a model without them nothing (ISSUE 43) ----
+
+def _programs_before_state_layers(engine):
+    """Both programs of a cache of one group as they were written before
+    state layers existed (under the names they had, which a jaxpr shows):
+    four arguments, nothing of a state."""
+    model = engine.model
+    k_row, v_row = engine.cache.spec.row_shapes()
+    n_table = engine.cache.pages_per_slot
+
+    def hetu_serve_decode(params, k_pool, v_pool, aux):
+        n_pg = aux.shape[1] - 4
+        tables = aux[:, :n_pg]
+        lengths = aux[:, n_pg]
+        tokens = aux[:, n_pg + 1]
+        wpage = aux[:, n_pg + 2:n_pg + 3]
+        woff = aux[:, n_pg + 3:n_pg + 4]
+        k = PagedLayers(k_pool, tables, wpage, woff, k_row, False)
+        v = PagedLayers(v_pool, tables, wpage, woff, v_row, False)
+        logits, k, v, *stats = model.decode_with_cache(
+            {"params": params, "state": {}}, tokens, k, v, lengths)
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        return k.pool, v.pool, nxt, tuple(stats)
+
+    def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux):
+        sc = (aux.shape[0] - n_table - 2) // 3
+        ids = aux[:sc][None]
+        wpage = aux[sc:2 * sc][None]
+        woff = aux[2 * sc:3 * sc][None]
+        table = aux[3 * sc:3 * sc + n_table][None]
+        start = aux[3 * sc + n_table]
+        last = aux[3 * sc + n_table + 1]
+        k = PagedLayers(k_pool, table, wpage, woff, k_row)
+        v = PagedLayers(v_pool, table, wpage, woff, v_row)
+        logits, k, v, *stats = model.prefill_chunk_with_cache(
+            {"params": params, "state": {}}, ids, k, v, start,
+            last_index=last)
+        tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
+        return k.pool, v.pool, tok, tuple(stats)
+
+    return {"decode": jax.jit(hetu_serve_decode, donate_argnums=(1, 2)),
+            "chunk": jax.jit(hetu_serve_prefill_chunk,
+                             donate_argnums=(1, 2))}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("kind", ["gpt", "llama"])
+def test_a_model_without_state_layers_keeps_its_programs(kind, program, gpt,
+                                                         llama):
+    """The engine's program of a model with no state layers takes four
+    arguments and traces to the jaxpr of the program as it was written
+    before state layers: no argument, operand column or equation more."""
+    model, variables = gpt if kind == "gpt" else llama
+    engine = PagedServeEngine(model, variables, num_slots=4, max_len=64,
+                              page_size=8, prefill_chunk=16)
+    assert engine.cache.state is None and not engine._states
+    n_pg = engine.cache.pages_per_slot
+    if program == "decode":
+        fn, aux = engine._build_decode(), (4, n_pg + 4)
+    else:
+        fn, aux = engine._build_chunk(n_pg), (3 * 16 + n_pg + 2,)
+    args = (engine.params, engine.cache.k, engine.cache.v,
+            jax.ShapeDtypeStruct(aux, np.int32))
+    now = jax.make_jaxpr(fn)(*args)
+    before = jax.make_jaxpr(
+        _programs_before_state_layers(engine)[program])(*args)
+    assert len(now.jaxpr.invars) == len(before.jaxpr.invars)
+    assert str(now) == str(before)

@@ -323,3 +323,71 @@ def test_metrics_report_through_metric_logger(gpt, tmp_path):
     assert snap["ttft_avg_s"] > 0
     rec = json.loads(log_path.read_text().strip().splitlines()[-1])
     assert rec["requests_ok"] == 2 and "ttft_avg_s" in rec
+
+
+# ---- state layers behind the same scheduler (ISSUE 43) ----
+
+@pytest.fixture(scope="module")
+def lfm2():
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
+    m = Lfm2MoeModel(Lfm2MoeConfig(
+        vocab_size=97, hidden_size=32, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_size=64, expert_ffn_size=16,
+        first_dense=1, n_routed_experts=8, moe_topk=2,
+        layer_types=("conv", "full_attention", "conv", "conv",
+                     "full_attention"),
+        max_position=64, dtype=jnp.float32, param_dtype=jnp.float32,
+        init_std=0.2, router_init_std=0.5, expert_block_rows=4))
+    return m, jax.jit(m.init)(jax.random.PRNGKey(3))
+
+
+def _is_greedy(model, variables, prompt, tokens) -> bool:
+    """Whether ``tokens`` are the greedy continuation of ``prompt`` by ONE
+    jitted full forward over prompt + tokens (each token the argmax after
+    the tokens before it, which is what greedy means)."""
+    ids = np.asarray([list(prompt) + list(tokens)], np.int32)
+    logits = jax.jit(lambda v, x: model.apply(v, x)[0])(variables, ids)[0]
+    n = len(prompt)
+    return list(tokens) == np.argmax(
+        np.asarray(logits[n - 1:n - 1 + len(tokens)]), -1).tolist()
+
+
+@pytest.mark.parametrize("prompt_len", [1, 8, 13])
+def test_state_layers_decode_parity(lfm2, prompt_len):
+    """A model whose layers keep a state a slot and not rows a token, by the
+    entry points every other model is served by: token-exact against the
+    full forward, chunks of 8 that pad (13 = 8 + 5) and that do not."""
+    model, variables = lfm2
+    prompt = list(np.random.default_rng(prompt_len).integers(0, 97,
+                                                             prompt_len))
+    engine = _engine(model, variables, num_slots=2, max_len=32,
+                     prefill_chunk=8)
+    toks = _engine_greedy(engine, prompt, 6)
+    assert _is_greedy(model, variables, prompt, toks)
+    # the slot is handed out again: the same request, nothing left behind
+    assert _engine_greedy(engine, prompt, 6) == toks
+
+
+def test_state_layers_preempted_request_prefills_again(lfm2):
+    """Preemption by re-prefill rebuilds the state by construction: the
+    preempted request's prompt and tokens so far are prefilled from position
+    0 into whatever slot it is given."""
+    model, variables = lfm2
+    prompts = [[3, 14, 15, 9, 2, 6], [5, 3, 5, 8, 9, 7, 9, 3, 2]]
+    engine = _engine(model, variables, num_slots=2, max_len=32,
+                     prefill_chunk=8)
+    sched = ContinuousBatchingScheduler(engine)
+    reqs = [Request(prompt=p, max_tokens=8) for p in prompts]
+    for r in reqs:
+        sched.submit(r)
+    for _ in range(4):
+        sched.step()
+    assert all(0 < len(r.tokens) < 8 for r in reqs)
+    sched.replace_engine(_engine(model, variables, num_slots=2, max_len=32,
+                                 prefill_chunk=8))
+    sched.run([])
+    for p, r in zip(prompts, reqs):     # a requeued request's own prompt
+        assert r.status == "ok" and len(r.tokens) == 8      # is folded
+        assert _is_greedy(model, variables, p, r.tokens)
