@@ -37,8 +37,8 @@ the expert-parallel group first; here they are this chip's tokens'.
 here (``layers/moe.py`` ``HeldExpertLayer``, sigmoid, renormalised, shared
 expert); what absent experts would add is left out.  The walk over the held
 experts' pairs follows the load forward and backward
-(``ops.moe_ops.held_expert_ffn``: at a training step's shape, sorted rows
-through grouped matmuls, ``hetu.moe.gmm``).
+(``ops.moe_ops.held_expert_ffn``: experts that fit the grouped kernels, so
+sorted rows through grouped matmuls, ``hetu.moe.gmm``, at any row count).
 
 **Shared with** ``models/longcat_flash.py``: the rotary table
 (``LatentAttention.rope_at``), the pairwise rotation, the ``W_kvb`` view and

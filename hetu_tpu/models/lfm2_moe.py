@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from hetu_tpu import ops
 from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
 from hetu_tpu.models.block import FULL, BlockDecoder, LayerCall, draw_leaf
+from hetu_tpu.ops.moe_ops import held_expert_path
 
 CONV = "conv"
 
@@ -120,9 +121,11 @@ class Lfm2MoeModel(BlockDecoder):
     [H, H]} over the conv layers, ``ffn`` over the ``first_dense`` leading
     layers, ``moe`` (router, router_bias, gate, up, down) over the rest."""
 
-    # the expert layers' counts, and the held experts a call could hit at
-    # most (held x expert layers): a constant, for the share that were hit
-    step_stats = MOE_STATS + ("moe_experts",)
+    # the expert layers' counts; the held experts a call could hit at most
+    # (held x expert layers): a constant, for the share that were hit; and
+    # the held pairs that the walk's grouped path computed: all of them or
+    # none, by ops.moe_ops.held_expert_path's static rule
+    step_stats = MOE_STATS + ("moe_experts", "moe_grouped")
 
     def __init__(self, config: Lfm2MoeConfig):
         c = config
@@ -234,5 +237,10 @@ class Lfm2MoeModel(BlockDecoder):
 
     def _counts(self, stats):
         c = self.c
-        return jnp.concatenate([stats, jnp.array(
-            [c.held[1] * (c.num_layers - c.first_dense)], jnp.int32)])
+        # the rule reads an expert's size alone, so one token stands for a
+        # call of any row count
+        grouped = held_expert_path(1, c.moe_topk, c.held[1], c.hidden_size,
+                                   c.expert_ffn_size) == "grouped"
+        return jnp.concatenate([stats, jnp.stack([
+            jnp.int32(c.held[1] * (c.num_layers - c.first_dense)),
+            stats[0] * int(grouped)])])

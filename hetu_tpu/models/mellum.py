@@ -34,10 +34,10 @@ here (``layers/moe.py`` ``HeldExpertLayer``, softmax, renormalised, no shared
 expert, no bias); pairs that land on an absent expert are left out and the
 renormalising sum keeps all ``k`` scores.  The walk over the held experts'
 pairs follows the load forward and backward
-(``ops.moe_ops.held_expert_ffn``: at a training step's shape, sorted rows
-through grouped matmuls, ``hetu.moe.gmm``).  No auxiliary balance loss (the
-configuration gives no coefficient) and no next-token head (it gives no key
-for one).
+(``ops.moe_ops.held_expert_ffn``: experts that fit the grouped kernels, so
+sorted rows through grouped matmuls, ``hetu.moe.gmm``, at any row count).
+No auxiliary balance loss (the configuration gives no coefficient) and no
+next-token head (it gives no key for one).
 
 **Shared with** ``models/block.py`` (``GroupedHeads``): the grouped
 projections with their per-head norms, the half-layout rotation and the

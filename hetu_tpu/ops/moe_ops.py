@@ -20,9 +20,10 @@ served or trained as one chip's share (``models/longcat_flash.py``,
 a router as wide as published over experts of which this chip holds a
 contiguous share, no capacity, no drops, and work that follows the rows
 routed here, forward and backward.  It has two paths, chosen from static
-shapes by :func:`held_expert_path`: a loop over row blocks (decode rounds
-and prefill chunks) and sorted rows through grouped matmuls (training
-steps).
+shapes by :func:`held_expert_path`: sorted rows through grouped matmuls
+where an expert's weight fits the kernels' VMEM (the trained cells' steps,
+LFM2's decode rounds and prefill chunks) and a loop over row blocks where
+it does not (K-EXAONE's and LongCat's 6144 x 2048 experts).
 """
 
 from __future__ import annotations
@@ -336,28 +337,31 @@ _held.defvjp(_held_vjp_fwd, _held_vjp_bwd)
 # Python values (``first``, the row budget) static: every walk of a program
 # at one shape is then one traced function, lowered once, its Pallas calls
 # with it (why that matters, and what it asks of ``ops.remat``:
-# :func:`held_expert_ffn`).  XLA inlines the calls.
+# :func:`held_expert_ffn`).  XLA inlines the calls.  The forward has two
+# forms by the static row budget: trips of three grouped calls and a
+# scatter-add where a chip holds a share of the experts (a training step),
+# and ONE call and a gather where one trip holds every pair (a served model
+# that holds every expert).
 # ---------------------------------------------------------------------------
 
 # the most sorted rows a trip of the grouped path holds at once: its buffers
 # are [rows, H] and [rows, F], whatever T * k is
 GROUPED_ROW_BUDGET = 20480
-# the grouped path is taken from this many (token, choice) pairs an expert
-# held here, were every pair held (``T * k / E``) ...
-GROUPED_MIN_PAIRS_AN_EXPERT = 1024
-# ... where an expert's [H, F] weight is at most this many elements: the
-# grouped matmuls keep one whole in VMEM, two of them double-buffered in the
-# call that makes dx
+# the grouped path is taken where an expert's [H, F] weight is at most this
+# many elements: the grouped matmuls keep one whole in VMEM, two of them
+# double-buffered in the call that makes dx, three in the call that is a
+# whole expert
 GROUPED_MAX_WEIGHT = 4 * 1024 * 1024
 
 
 def held_expert_path(T: int, k: int, E: int, H: int, F: int) -> str:
     """``"grouped"`` or ``"loop"``: which walk :func:`held_expert_ffn` takes
     for ``T`` tokens of ``k`` choices over ``E`` held experts of ``[H, F]``.
-    Static shapes alone decide (the rule and the measurement behind it are
-    stated in :func:`held_expert_ffn`)."""
-    return "grouped" if (T * k >= GROUPED_MIN_PAIRS_AN_EXPERT * E
-                         and H * F <= GROUPED_MAX_WEIGHT) else "loop"
+    Static shapes alone decide, and of them the expert's size alone: where
+    the kernels can keep a weight whole the grouped path is ahead at every
+    row count measured, one token included (the rule and the measurement
+    behind it are stated in :func:`held_expert_ffn`)."""
+    return "grouped" if H * F <= GROUPED_MAX_WEIGHT else "loop"
 
 
 def grouped_row_budget(T: int, k: int, E: int, routed=None) -> int:
@@ -365,7 +369,9 @@ def grouped_row_budget(T: int, k: int, E: int, routed=None) -> int:
     router over ``routed`` experts sends to ``E`` of them, and an eighth
     more (every pair, where the router's width is not given), are cut into
     the fewest equal trips of at most :data:`GROUPED_ROW_BUDGET` rows, in
-    whole tiles.  Static; a load past it costs trips, never rows."""
+    whole tiles.  Static; a load past it costs trips, never rows.  Where the
+    chip holds every expert the budget is every pair, ``T * k`` rounded up
+    to a tile: one trip, whatever the router does."""
     pairs = T * k if routed is None else -(-T * k * E * 9 // (routed * 8))
     rows = min(T * k, pairs)
     rows = -(-rows // -(-rows // GROUPED_ROW_BUDGET))     # a trip's
@@ -441,6 +447,17 @@ def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
     plan = _grouped_plan(idx, first, w_gate.shape[-3], B)
     pair_w = weights.reshape(-1)
     (wg, wu, wd), layer = _layer_leaves((w_gate, w_up, w_down), layer, dt)
+
+    if B >= T * k:
+        # one trip holds every pair: a whole expert a visit in one call, and
+        # each token gathers its k rows back (a pair's row is where the
+        # sort put it: the inverse permutation)
+        t = _trip(plan, 0, B, T, k)
+        y = gm.gmm_ffn(x[t.tok], wg, wu, wd, pair_w[t.pairs], t.visits,
+                       layer=layer)
+        at = jnp.argsort(plan.order[:T * k])
+        y = jnp.where((at < plan.held)[:, None], y[at], 0.0)
+        return (y.reshape(T, k, -1).sum(1), plan.counts), plan
 
     def body(s, out):
         t = _trip(plan, s, B, T, k)
@@ -541,51 +558,88 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     follows the rows routed here and not ``T * k``, and an expert nobody
     chose costs nothing: its weights are not read.
 
+    **The grouped path** brings the rows into expert order once a trip
+    (``x[order // k]``) and multiplies each expert's contiguous rows by that
+    expert's weights in Pallas calls (``ops/pallas_kernels/grouped_matmul.py``,
+    ``hetu.moe.gmm`` in a trace: an expert's weight read in place and kept
+    whole in VMEM across its row tiles, fetched ONCE a call with the next
+    expert's fetch behind the current one's matmul, a tile two experts share
+    masked, not padded).  A trip holds :func:`grouped_row_budget`
+    sorted rows, a static number: memory is bounded by that row budget,
+    never by a capacity and never by ``T * k``; a load past it costs trips,
+    never rows.  The forward has two forms, by that static budget:
+
+    - *one trip holds every pair* (``budget >= T * k``: the chip holds every
+      expert the router chooses from, as a served LFM2 does; any decode
+      round, any prefill chunk): ONE call a walk (``gmm_ffn``: gate, up,
+      SwiGLU, down and the pair weight a visit, the ``[rows, F]``
+      intermediate never leaving VMEM), and each token gathers its ``k``
+      result rows back by the inverse of the sort and sums them: no
+      scatter-add (one of 8,192 rows costs 2.4 ms on a v5e, the gather 0.4);
+    - *a share of the experts* (a training step's expert-parallel share):
+      gate, up and down as three grouped matmuls a trip and ONE scatter-add
+      a trip into ``out``.
+
+    In reverse (either form: the backward reads only the sort) an expert's
+    ``dW`` is summed in VMEM over its row tiles and written once, the down
+    projection's result never leaves the chip (its product with the
+    cotangent is all the pair weights' gradient needs), and ``dx`` is one
+    more scatter-add.  The path's forward and backward are jitted functions,
+    so that every walk of a program at one shape is the same traced function
+    and each grouped kernel is lowered once a program, not once a layer: ten
+    Mosaic modules in a step whose scan body holds four expert layers, not
+    forty; one in an LFM2 decode or chunk program of twelve unrolled expert
+    layers, not twelve (a program's build cost, about 0.13 s of tracing and
+    0.03 s of lowering a ``pallas_call``, which a warm compile cache does not
+    remove; nothing the device runs changes).  ``layer`` reaches them as an
+    array index or None, never as a static value, which would make a
+    function a layer.  Under per-layer ``jax.checkpoint`` the sharing holds
+    where the layers share ONE policy object, as ``ops.remat`` hands out:
+    JAX splits a jitted call into kept and recomputed parts once a (jaxpr,
+    policy object) pair.
+
     **The loop path** walks the sorted pairs in blocks of ``block_rows``
     rows, ``ceil(count_e / block_rows)`` blocks for expert ``e``, the trip
     count read from the counts.  A block gathers its rows from ``x``,
-    multiplies them by its expert and adds its result into ``out``; in
-    reverse it also adds into its expert's whole float32 ``dW``.  Nothing is
-    bounded by a buffer of rows: only the sorted pair indices are held.  One
-    block an expert is the least a walk can do, which is what a decode round
-    or a prefill chunk needs (a handful of rows an expert, bound by the hit
-    experts' weight reads).
-
-    **The grouped path** brings the rows into expert order once a trip
-    (``x[order // k]``), runs gate, up and down as grouped matmuls over each
-    expert's contiguous rows (``ops/pallas_kernels/grouped_matmul.py``,
-    ``hetu.moe.gmm`` in a trace: an expert's weight read in place and kept in
-    VMEM across its row tiles, a tile two experts share masked, not padded)
-    and adds the result into ``out`` with ONE scatter-add a trip; in reverse
-    an expert's ``dW`` is summed in VMEM over its row tiles and written once,
-    the down projection's result never leaves the chip (its product with the
-    cotangent is all the pair weights' gradient needs), and ``dx`` is one
-    more scatter-add.  A trip holds :func:`grouped_row_budget` sorted rows,
-    a static number: memory is bounded by that row budget, never by a
-    capacity and never by ``T * k``; a load past it costs trips, never rows.
-    ``block_rows`` is the loop path's alone.  The path's forward and backward
-    are jitted functions, so that every walk of a program at one shape is
-    the same traced function and each grouped kernel is lowered once a
-    program, not once a layer of a scan body: ten Mosaic modules in a step
-    whose body holds four expert layers, not forty (a program's build cost,
-    which a warm compile cache does not remove; nothing the device runs
-    changes).  ``layer`` is an array index or None there, never a static
-    value, which would make a function a layer.  Under per-layer
-    ``jax.checkpoint`` the sharing holds where the layers share ONE policy
-    object, as ``ops.remat`` hands out: JAX splits a jitted call into kept
-    and recomputed parts once a (jaxpr, policy object) pair.
+    multiplies them by its expert (three XLA dots, each waiting for its own
+    weight: a block costs 55-62 us on a v5e whatever it holds) and adds its
+    result into ``out``; in reverse it also adds into its expert's whole
+    float32 ``dW``.  Nothing is bounded by a buffer of rows: only the sorted
+    pair indices are held.  It is what is left where an expert's weight does
+    not fit the grouped kernels' VMEM.  ``block_rows`` is this path's alone.
 
     **The rule** (:func:`held_expert_path`; static shapes only, no option):
-    grouped where ``T * k >= 1024 * E`` and ``H * F <= 4 Mi``.  Set on the
-    chip (v5e, PERF.md section 6, PR 41): at 2304 x 896, 16 held of 64, one
-    walk forward and backward takes 4.8 | 6.1 | 10.0 | 16.2 | 30.0 ms on the
-    loop path (128-row blocks) against 4.3 | 5.1 | 6.6 | 10.0 | 16.4 ms
-    grouped at ``T`` = 512 | 1024 | 2048 | 4096 | 8192 with ``k`` = 8, so
-    the paths part from 256 pairs an expert and by a third at 1,024; forward
-    alone at 6144 x 2048 the two are within a tenth of each other up to
-    ``T`` = 4096, and an expert's weight no longer fits the kernels' VMEM.
-    The two expert training cells sit at 6,144 and 8,192 pairs an expert,
-    the serving programs at 384 and under.
+    grouped where ``H * F <= 4 Mi``, at ANY row count; the loop otherwise.
+    Set on the chip (v5e).  *Forward alone* at LFM2's 2048 x 1792, 32 of 32
+    held, ``k`` = 4, twelve walks in a chain as its programs hold them
+    (PERF.md section 6, PR 44, call 6; ms, loop at 128-row blocks | grouped
+    at the kernels' one row tile of 256):
+
+        T      1     2      4      8     16     32     64    128    256
+        loop  4.14  5.58  11.82  16.03  19.37  22.12  22.32  22.41  22.50
+        grpd  2.68  3.73   6.67   9.58  11.36  12.68  12.73  13.24  14.25
+
+        T     512   1024   2048
+        loop 23.00  33.46  58.53
+        grpd 16.32  22.16  30.72
+
+    (a decode round of 64 slots reads its 384 hit experts at 81% of the
+    chip's bandwidth; a 2,048-token chunk's 256-row groups straddle two
+    256-row tiles and the kernel multiplies about twice the live rows, at
+    the MXU's rate: tiles of 64 and 128 read 30.0 and 30.2, of 512 46.1; a
+    tile of 32 reads 2.20 at one token and 12.59 at 64, a fifth and a
+    hundredth less, and was not kept: one tile for every shape).
+    *Forward and backward* at 2304 x 896, 16 held of 64, ``k`` = 8, one
+    walk: 4.8 | 6.1 | 10.0 | 16.2 | 30.0 ms on the loop against 4.3 | 5.1 |
+    6.6 | 10.0 | 16.4 grouped at ``T`` = 512 ... 8192 (PR 41), and under
+    the edge PR 41 drew at 1,024 pairs an expert 5.94 | 5.80 | 5.90 | 5.74 |
+    6.84 against 4.86 | 4.87 | 5.00 | 5.22 | 6.07 at ``T`` = 64 ... 1024 (8
+    to 128 pairs an expert; PR 44): no row count measured has the loop
+    ahead, so the rule has no lower edge.  Forward alone at 6144 x 2048 the
+    two are within a tenth of each other up to ``T`` = 4096, and an expert's
+    weight no longer fits the kernels' VMEM (three of 25 MB, double-buffered,
+    against its 128 MiB): those experts keep the loop until the kernels cut
+    ``[K, N]``.
 
     Reverse mode follows the path taken (a ``custom_vjp``: a loop whose trip
     count is read from data has no transpose of its own): the backward's
@@ -597,6 +651,8 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     T, k = idx.shape
     E, H, F = w_gate.shape[-3:]
     if held_expert_path(T, k, E, H, F) == "grouped":
+        if layer is not None:           # an array: a static layer would
+            layer = jnp.asarray(layer, jnp.int32)   # make a walk a layer
         return _grouped(x, weights, idx, w_gate, w_up, w_down, layer,
                         int(first), grouped_row_budget(T, k, E, routed))
     return _held(x, weights, idx, w_gate, w_up, w_down, layer, int(first),

@@ -18,8 +18,11 @@ result UNWRITTEN, and the caller masks them.
 
 ``gmm`` keeps a group's whole ``[K, N]`` weight in VMEM, read in place from
 the stacked ``[G, K, N]`` operand (with ``layer``: ``[L, G, K, N]``) and
-fetched once a group, the visits of one group being consecutive;
-:func:`gmm_down_back` is the down projection's backward in one such call,
+fetched once a group, the visits of one group being consecutive (the next
+group's fetch runs behind the current one's matmul); :func:`gmm_ffn` is a
+whole SwiGLU expert in one such call, gate, up and down, the ``[rows, F]``
+intermediate never leaving VMEM; :func:`gmm_down_back` is the down
+projection's backward in one such call,
 the forward's product recomputed and never written.  ``tgmm``
 keeps a group's ``[K, N]`` sum in a float32 VMEM scratch across that group's
 visits and writes it once, added to what the aliased accumulator held
@@ -207,6 +210,40 @@ def gmm(lhs, rhs, visits: Visits, *, layer=None, transpose_rhs: bool = False,
         functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
                           pairs=pairs, scaled=row_scale is not None),
         visits, layer_op, operands, specs, [(N, out_dtype)],
+        interpret=interpret)
+
+
+def _ffn_kernel(group, tile, starts, ends, layer, rows, w_gate, w_up, w_down,
+                scale, out, *, tm):
+    del layer
+    _, mask, fresh = _visit(group, tile, starts, ends, tm)
+    x, f32 = rows[...], jnp.float32
+    # gate and up rounded to the rows' type, as the three-call form hands
+    # them on; SwiGLU in float32, its product rounded once
+    g, u = (lax.dot_general(x, w[...], _NN, preferred_element_type=f32)
+            .astype(x.dtype).astype(f32) for w in (w_gate, w_up))
+    a = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+    y = lax.dot_general(a, w_down[...], _NN, preferred_element_type=f32)
+    _store(out, y * scale[...], mask, fresh)
+
+
+def gmm_ffn(rows, w_gate, w_up, w_down, row_scale, visits: Visits, *,
+            layer=None, interpret=None):
+    """A SwiGLU expert a group over sorted rows, in ONE call: ``rows``
+    [M, H], ``w_gate``/``w_up`` [G, H, F], ``w_down`` [G, F, H] (a leading
+    layer axis with ``layer``), ``row_scale`` [M] float32 -> [M, H] float32:
+    row ``r`` of group ``g`` is ``row_scale[r] * ((silu(rows[r] @ w_gate[g])
+    * (rows[r] @ w_up[g])) @ w_down[g])``.  A group's three whole weights
+    sit in VMEM across its row tiles, fetched once a group; the [tile, F]
+    intermediate is never written.  float32 accumulation, gate and up
+    rounded to ``rows``' type before SwiGLU, the down product and the scale
+    in float32.  Rows as :func:`gmm` leaves them."""
+    H = rows.shape[1]
+    specs = [_weight_spec(w, layer) for w in (w_gate, w_up, w_down)]
+    return _over_visits(
+        _ffn_kernel, visits, specs[0][1],
+        [rows, w_gate, w_up, w_down, _column(row_scale)],
+        [H] + [spec for spec, _ in specs] + [1], [(H, jnp.float32)],
         interpret=interpret)
 
 

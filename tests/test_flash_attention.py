@@ -529,7 +529,15 @@ def test_remat_keeps_the_forward_kernels_results_bit_for_bit(
     # forward, dK/dV, dQ | and the recomputed forward; a layer of the
     # unrolled period (three window layers, one full)
     calls = 4 if policy == "period" else 1
-    assert (text.count("pallas_call["), want_text.count("pallas_call[")) \
+
+    def flash_calls(t):
+        # the period's expert walks take the grouped matmuls (told by the
+        # VMEM limit they state): the same calls in both programs
+        from hetu_tpu.ops.pallas_kernels.grouped_matmul import _VMEM_LIMIT
+        return t.count("pallas_call[") \
+            - t.count(f"vmem_limit_bytes={_VMEM_LIMIT}")
+
+    assert (flash_calls(text), flash_calls(want_text)) \
         == (3 * calls, 4 * calls)
     if policy == "dots":
         monkeypatch.undo()

@@ -2,8 +2,10 @@
 sort once, grouped matmuls over each expert's contiguous rows, combine once)
 against the loop path and against the dense composition, values and
 gradients, at routings chosen to sit on and off a row tile's boundary and to
-take more than one trip; and the static rule that sends a shape down one
-path or the other.  CPU: the Pallas kernels run in interpret mode."""
+take more than one trip; a serving call's shapes, where one trip holds every
+pair and a visit is a whole expert; and the static rule that sends a shape
+down one path or the other.  CPU: the Pallas kernels run in interpret
+mode."""
 
 import jax
 import jax.numpy as jnp
@@ -62,13 +64,14 @@ def _operands(dtype, layer):
             draw(*lead, E, F, H, scale=0.3))
 
 
-def _dense(x, w, idx, wg, wu, wd, layer):
+def _dense(x, w, idx, wg, wu, wd, layer, first=FIRST):
     """Every pair through its expert, no sort and no loop: float32."""
     if layer is not None:
         wg, wu, wd = wg[layer], wu[layer], wd[layer]
     f32 = jnp.float32
     x, wg, wu, wd = (a.astype(f32) for a in (x, wg, wu, wd))
-    local = idx - FIRST
+    E = wg.shape[0]
+    local = idx - first
     held = (local >= 0) & (local < E)
     e = jnp.clip(local, 0, E - 1)                         # [T, K]
     g = jnp.einsum("th,tkhf->tkf", x, wg[e])
@@ -80,7 +83,8 @@ def _dense(x, w, idx, wg, wu, wd, layer):
 
 
 def _value_and_grads(fn, idx, operands):
-    probe = jnp.cos(jnp.arange(T * H, dtype=jnp.float32)).reshape(T, H)
+    x = operands[0]
+    probe = jnp.cos(jnp.arange(x.size, dtype=jnp.float32)).reshape(x.shape)
 
     def loss(x, w, wg, wu, wd):
         out, counts = fn(x, w, idx, wg, wu, wd)
@@ -91,14 +95,15 @@ def _value_and_grads(fn, idx, operands):
     return (out, *grads), counts
 
 
-def _walk(monkeypatch, path, layer):
-    """``held_expert_ffn`` held to ``path`` by the rule's own threshold."""
-    monkeypatch.setattr(moe_ops, "GROUPED_MIN_PAIRS_AN_EXPERT",
-                        1 if path == "grouped" else 10 ** 9)
+def _walk(monkeypatch, path, layer, first=FIRST, routed=ROUTED):
+    """``held_expert_ffn`` held to ``path`` by the rule's own limit: these
+    experts fit it, and none fits a limit of nothing."""
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
+                        4 << 20 if path == "grouped" else 0)
     assert moe_ops.held_expert_path(T, K, E, H, F) == path
     return lambda x, w, idx, wg, wu, wd: moe_ops.held_expert_ffn(
-        x, w, idx, wg, wu, wd, first=FIRST, block_rows=TILE, layer=layer,
-        routed=ROUTED)
+        x, w, idx, wg, wu, wd, first=first, block_rows=TILE, layer=layer,
+        routed=routed)
 
 
 CASES = [(r, layer, jnp.float32)
@@ -141,6 +146,95 @@ def test_the_grouped_path_equals_the_loop_and_the_dense_composition(
         # a stacked leaf's gradient is zero outside the layer walked
         for g in grouped[3:]:
             assert not np.asarray(g[0]).any() and not np.asarray(g[2]).any()
+
+
+# ---- a serving call: every expert held, one trip, a whole expert a visit
+
+S_K, S_E, S_H, S_F = 4, 32, 32, 24
+# (tokens, routing, stacked at a traced layer, gradients too)
+SERVING = [
+    (1, "top", True, False),              # 4 pairs: under one tile
+    (4, "top", False, True),
+    (64, "top", True, False),             # a decode round at every slot
+    (200, "top", True, True),             # a chunk, its bucket no power of 2
+    (64, "few", True, False),             # experts 8.. chosen by nobody
+    (16, "one expert", False, False),     # one expert takes every pair
+]
+
+
+def _serving_case(t: int, routing: str, stacked: bool):
+    rng = np.random.default_rng(11)
+
+    def draw(*shape, scale=1.0):
+        return jnp.asarray(rng.standard_normal(shape) * scale, jnp.float32)
+
+    pool = {"top": S_E, "few": 8}.get(routing)
+    idx = np.full((t, S_K), 5) if pool is None else np.stack(
+        [rng.permutation(pool)[:S_K] for _ in range(t)])
+    lead = (3,) if stacked else ()
+    return jnp.asarray(idx, jnp.int32), (
+        draw(t, S_H), jnp.asarray(rng.uniform(0.1, 1.0, (t, S_K)),
+                                  jnp.float32),
+        draw(*lead, S_E, S_H, S_F, scale=0.3),
+        draw(*lead, S_E, S_H, S_F, scale=0.3),
+        draw(*lead, S_E, S_F, S_H, scale=0.3))
+
+
+@pytest.mark.parametrize(
+    "t,routing,stacked,grads", SERVING,
+    ids=[f"T{t}-{r}-{'stacked' if s else 'one layer'}" for t, r, s, _ in
+         SERVING])
+def test_a_serving_call_on_the_grouped_path_equals_the_loop_and_the_dense_composition(
+        t, routing, stacked, grads, monkeypatch):
+    """All 32 experts held, 4 choices a token: one trip holds every pair
+    (``grouped_row_budget`` >= ``T * k``), so the walk is ONE grouped call,
+    a whole expert a visit, and each token gathers its ``k`` rows back.
+    Stacked leaves are read at a TRACED layer, as a program of several
+    expert layers hands it over."""
+    idx, operands = _serving_case(t, routing, stacked)
+    budget = moe_ops.grouped_row_budget(t, S_K, S_E, S_E)
+    assert 0 <= budget - t * S_K < grouped_matmul.TILE_ROWS
+
+    def walk(path):
+        monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
+                            4 << 20 if path == "grouped" else 0)
+        assert moe_ops.held_expert_path(t, S_K, S_E, S_H, S_F) == path
+
+        def fn(x, w, idx, wg, wu, wd, layer):
+            return moe_ops.held_expert_ffn(
+                x, w, idx, wg, wu, wd, first=0, block_rows=8, routed=S_E,
+                layer=layer if stacked else None)
+        return jax.jit(fn)
+
+    def run(fn):
+        call = lambda x, w, idx, wg, wu, wd: fn(x, w, idx, wg, wu, wd,
+                                                jnp.int32(1))
+        if grads:
+            return _value_and_grads(call, idx, operands)
+        out, counts = call(operands[0], operands[1], idx, *operands[2:])
+        return (out,), counts
+
+    grouped_fn = walk("grouped")
+    closed = jax.make_jaxpr(grouped_fn)(operands[0], operands[1], idx,
+                                        *operands[2:], jnp.int32(1))
+    assert _pallas_calls(closed.jaxpr) == 1
+    grouped, counts = run(grouped_fn)
+    loop, loop_counts = run(walk("loop"))
+    dense, dense_counts = run(
+        lambda x, w, idx, wg, wu, wd, layer: _dense(
+            x, w, idx, wg, wu, wd, 1 if stacked else None, first=0))
+    assert counts.tolist() == loop_counts.tolist() == dense_counts.tolist()
+    assert int(counts.sum()) == t * S_K                   # no pair dropped
+    assert int((counts > 0).sum()) == {"few": 8, "one expert": 1}.get(
+        routing, int((counts > 0).sum()))
+    for name, got, want, ref in zip(
+            ("out", "dx", "dweights", "dgate", "dup", "ddown"), grouped,
+            loop, dense):
+        assert got.shape == want.shape and got.dtype == want.dtype, name
+        got, want, ref = (np.asarray(a, np.float32) for a in (got, want, ref))
+        scale = max(1.0, float(np.abs(ref).max()))
+        assert np.abs(got - want).max() <= 2e-5 * scale, name
+        assert np.abs(got - ref).max() <= 2e-5 * scale, name
 
 
 def _period(walk, idx, layers: int = 4):
@@ -228,39 +322,56 @@ def test_the_layers_of_a_scan_body_share_one_traced_walk_and_keep_their_results(
             assert all(np.abs(got[l]).max() > 0 for l in range(4)), name
 
 
-# (cell and program, T, k, held experts, hidden, expert FFN): the shapes the
-# benchmark's four expert cells hand to held_expert_ffn
+# (cell and program, T, k, the router's width, held experts, hidden, expert
+# FFN): the shapes the benchmark's five expert cells hand to held_expert_ffn
 SHAPES = [
-    ("kanana train-ep8 step", 16384, 6, 16, 2048, 768, "grouped"),
-    ("mellum train-ep4 step", 16384, 8, 16, 2304, 896, "grouped"),
-    ("k-exaone batch-mixed decode", 16, 8, 16, 6144, 2048, "loop"),
-    ("k-exaone batch-mixed chunk", 512, 8, 16, 6144, 2048, "loop"),
-    ("longcat batch-long decode", 16, 12, 16, 6144, 2048, "loop"),
-    ("longcat batch-long chunk", 512, 12, 16, 6144, 2048, "loop"),
+    ("kanana train-ep8 step", 16384, 6, 128, 16, 2048, 768, "grouped"),
+    ("mellum train-ep4 step", 16384, 8, 64, 16, 2304, 896, "grouped"),
+    ("lfm2 batch-docs decode at one slot", 1, 4, 32, 32, 2048, 1792,
+     "grouped"),
+    ("lfm2 batch-docs decode", 64, 4, 32, 32, 2048, 1792, "grouped"),
+    ("lfm2 batch-docs chunk", 2048, 4, 32, 32, 2048, 1792, "grouped"),
     # an expert's weight too large to keep whole in VMEM: the loop, at any T
-    ("a 6144 x 2048 expert at a step's tokens", 16384, 8, 16, 6144, 2048,
+    ("k-exaone batch-mixed decode", 16, 8, 128, 16, 6144, 2048, "loop"),
+    ("k-exaone batch-mixed chunk", 512, 8, 128, 16, 6144, 2048, "loop"),
+    ("longcat batch-long decode", 16, 12, 768, 16, 6144, 2048, "loop"),
+    ("longcat batch-long chunk", 512, 12, 768, 16, 6144, 2048, "loop"),
+    ("a 6144 x 2048 expert at a step's tokens", 16384, 8, 64, 16, 6144, 2048,
      "loop"),
 ]
+# the rows a trip of the two training cells' walks holds, which this rule
+# must keep
+TRAINED = {"kanana train-ep8 step": 13824, "mellum train-ep4 step": 18432}
 
 
-@pytest.mark.parametrize("name,t,k,e,h,f,path", SHAPES,
+@pytest.mark.parametrize("name,t,k,routed,e,h,f,path", SHAPES,
                          ids=[s[0] for s in SHAPES])
-def test_the_rule_sends_training_shapes_to_the_grouped_path_and_serving_to_the_loop(
-        name, t, k, e, h, f, path):
-    """Static shapes alone decide, and the program says which it was: the
-    grouped path's is Pallas calls around one scatter-add a trip, the
-    loop's holds no Pallas call."""
+def test_the_rule_sends_experts_that_fit_the_kernels_to_the_grouped_path(
+        name, t, k, routed, e, h, f, path):
+    """Static shapes alone decide, at any row count, and the program says
+    which it was: the grouped path's is Pallas calls (three a trip and a
+    scatter-add where a chip holds a share of the experts, ONE where a trip
+    holds every pair), the loop's holds no Pallas call."""
     assert moe_ops.held_expert_path(t, k, e, h, f) == path
     bf16 = jnp.bfloat16
     args = [jax.ShapeDtypeStruct(s, d) for s, d in (
         ((t, h), bf16), ((t, k), jnp.float32), ((t, k), jnp.int32),
         ((e, h, f), bf16), ((e, h, f), bf16), ((e, f, h), bf16))]
     closed = jax.make_jaxpr(lambda *a: moe_ops.held_expert_ffn(
-        *a, first=0, block_rows=128))(*args)
-    assert _pallas_calls(closed.jaxpr) == (3 if path == "grouped" else 0)
-    # a trip holds a static number of rows, well under every pair
-    budget = moe_ops.grouped_row_budget(t, k, e, 4 * e)
-    assert budget % grouped_matmul.TILE_ROWS == 0
-    assert budget <= moe_ops.GROUPED_ROW_BUDGET + grouped_matmul.TILE_ROWS
-    if path == "grouped":
-        assert t * k // 4 <= budget * -(-t * k // 4 // budget) < t * k
+        *a, first=0, block_rows=128, routed=routed))(*args)
+    # a trip holds a static number of rows, in whole tiles
+    budget = moe_ops.grouped_row_budget(t, k, e, routed)
+    tile = grouped_matmul.TILE_ROWS
+    assert tile == 256 and budget % tile == 0
+    assert budget <= moe_ops.GROUPED_ROW_BUDGET + tile
+    whole = budget >= t * k
+    assert _pallas_calls(closed.jaxpr) == (
+        0 if path == "loop" else 1 if whole else 3)
+    if name in TRAINED:
+        assert budget == TRAINED[name]
+    if name.startswith("lfm2"):
+        # one trip for every serving shape
+        assert whole and budget - t * k < tile
+    elif path == "grouped":
+        assert t * k * e // routed <= budget * -(
+            -t * k * e // routed // budget) < t * k

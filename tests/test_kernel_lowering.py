@@ -50,6 +50,12 @@ MASKED_FLASH_CASES = (
 BENCH_GROUPED_SHAPES = {
     "kanana-2-30b-a3b-instruct-2601.train-ep8": (16384, 6, 128, 16, 2048, 768),
     "mellum2-12b-a2.5b-instruct.train-ep4": (16384, 8, 64, 16, 2304, 896)}
+# the walk of the served expert cell whose experts fit the grouped kernels,
+# one fused call a walk: (tokens a call, choices, held = routed experts,
+# expert layers in the stacked leaves, hidden, expert FFN)
+BENCH_SERVED_GROUPED_SHAPES = {
+    "lfm2-8b-a1b.batch-docs decode": (64, 4, 32, 12, 2048, 1792),
+    "lfm2-8b-a1b.batch-docs chunk": (2048, 4, 32, 12, 2048, 1792)}
 BENCH_PAGED_SHAPES = {
     "gpt2-large.batch": (8, 20, 20, 64, 36, 385, 16, 48),
     "k-exaone-236b-a23b.batch-mixed": (16, 64, 8, 128, 1, 4225, 128, 264)}
@@ -98,6 +104,13 @@ def _held_experts_vjp(routed):
     return fn
 
 
+def _held_experts_forward(x, w, idx, wg, wu, wd, layer):
+    from hetu_tpu.ops.moe_ops import held_expert_ffn
+
+    return held_expert_ffn(x, w, idx, wg, wu, wd, first=0, layer=layer,
+                           routed=wg.shape[1])
+
+
 def _cases():
     rows, width, n = FULL.emb
     tokens, experts, k = FULL.topk
@@ -135,6 +148,13 @@ def _cases():
                [((t, h), bf16), ((t, k), f32), ((t, k), i32),
                 ((e, h, f), bf16), ((e, h, f), bf16), ((e, f, h), bf16),
                 ((t, h), f32)], 10)
+    for cell, (t, k, e, layers, h, f) in BENCH_SERVED_GROUPED_SHAPES.items():
+        # a whole expert a visit: gate, up, SwiGLU and down in one call,
+        # three whole weights double-buffered in VMEM
+        yield (f"held experts grouped forward {cell}", _held_experts_forward,
+               [((t, h), bf16), ((t, k), f32), ((t, k), i32),
+                ((layers, e, h, f), bf16), ((layers, e, h, f), bf16),
+                ((layers, e, f, h), bf16), ((), i32)], 1)
     for cell, (b, nh, g, d, layers, pool, ps, n_pg) in \
             BENCH_PAGED_SHAPES.items():
         pool_of = ((layers, pool, ps, g * d), bf16)
@@ -295,13 +315,15 @@ def test_a_train_step_lowers_with_three_flash_calls_a_layer_body(build):
     and a full one, and every call of it takes K and V at the KV heads."""
     model, batch_shape, bodies = build()
     text = _step_text(model, batch_shape)
-    assert text.count("tpu_custom_call") == 3 * bodies
+    assert text.count("tpu_custom_call") - _grouped_calls(text) == 3 * bodies
     if build is _tiny_mellum:
         # 2 x 4 query heads of [256, 128] over 2 x 2 KV heads: no call reads
         # a K or V at the query heads, and dK/dV leave at the KV heads
         q, kv = "tensor<8x256x128xbf16>", "tensor<4x256x128xbf16>"
-        sigs = re.findall(r"stablehlo.custom_call @tpu_custom_call.*? : "
-                          r"\((.*?)\) -> (.*)", text)
+        sigs = [s for s in re.findall(
+            r"stablehlo.custom_call @tpu_custom_call.*? : "
+            r"\((.*?)\) -> (.*)", text) if "hetu.moe.gmm" not in s[1]]
+        sigs = [s for s in sigs if s[0].startswith(q)]   # the flash calls
         assert len(sigs) == 12
         assert all(s[0].split(", ")[:3] == [q, kv, kv] for s in sigs)
         assert sum(s[1].count(kv) == 2 for s in sigs) == 4
@@ -315,17 +337,18 @@ def _row_scatters(text, hidden):
 
 
 @pytest.mark.parametrize("build", [_tiny_deepseek_v3, _tiny_mellum])
-def test_a_train_step_over_the_threshold_lowers_with_grouped_calls(build):
-    """At 1,024 tokens of 2 choices over 2 held experts the walk takes the
-    grouped path (``ops.moe_ops.held_expert_path``): its Mosaic calls carry
+def test_a_train_step_whose_experts_fit_lowers_with_grouped_calls(
+        build, monkeypatch):
+    """Experts that fit the grouped kernels' VMEM take the grouped path
+    (``ops.moe_ops.held_expert_path``): the walk's Mosaic calls carry
     ``hetu.moe.gmm`` and no block of ``expert_block_rows`` rows is added into
-    the float32 ``[T, H]`` result; at 512 tokens the same model holds the
-    loop, a block's scatter-add inside a ``while``."""
-    from hetu_tpu.ops.moe_ops import grouped_row_budget, held_expert_path
+    the float32 ``[T, H]`` result; under a limit these experts pass the same
+    model holds the loop, a block's scatter-add inside a ``while``."""
+    from hetu_tpu.ops import moe_ops
 
     model, _, bodies = build()
     rows, hidden = model.c.expert_block_rows, model.c.hidden_size
-    assert held_expert_path(1024, 2, 2, hidden, 128) == "grouped"
+    assert moe_ops.held_expert_path(1024, 2, 2, hidden, 128) == "grouped"
     text = _step_text(model, (4, 256))
     # a walk: gate, up, down forward; gate, up, the down projection's
     # backward, three dW and one dx backward (the recomputed forward walk's
@@ -334,9 +357,11 @@ def test_a_train_step_over_the_threshold_lowers_with_grouped_calls(build):
     assert text.count("tpu_custom_call") == 3 * bodies + 10
     assert _grouped_calls(text) == 10
     adds = _row_scatters(text, hidden)
-    assert grouped_row_budget(1024, 2, 2, 8) in adds and rows not in adds
-    assert held_expert_path(512, 2, 2, hidden, 128) == "loop"
-    text = _step_text(model, (2, 256))
+    assert moe_ops.grouped_row_budget(1024, 2, 2, 8) in adds \
+        and rows not in adds
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", hidden * 128 - 1)
+    assert moe_ops.held_expert_path(1024, 2, 2, hidden, 128) == "loop"
+    text = _step_text(model, (4, 256))
     assert text.count("tpu_custom_call") == 3 * bodies
     assert "hetu.moe.gmm" not in text
     assert rows in _row_scatters(text, hidden)
@@ -350,6 +375,18 @@ def _grouped_calls(text, part=None):
     return sum(loc in scoped for loc in re.findall(
         r"custom_call @tpu_custom_call.*loc\((#loc\d+)\)$",
         text if part is None else part, flags=re.M))
+
+
+def _grouped_holders(text) -> dict:
+    """{function of the lowered program ``text`` that holds a grouped Mosaic
+    call: how many times the program calls it}."""
+    holders = {}
+    for fn in re.split(r"\n(?=\s*func\.func )", text):
+        name = re.match(r"\s*func\.func \w+ @([\w.]+)", fn)
+        if name and _grouped_calls(text, fn):
+            holders[name.group(1)] = len(re.findall(
+                rf"call @{re.escape(name.group(1))}\(", text))
+    return holders
 
 
 @pytest.mark.parametrize("build,walks", [(_tiny_deepseek_v3, 1),
@@ -366,12 +403,7 @@ def test_a_scan_body_lowers_each_grouped_kernel_once(build, walks):
     model, _, _ = build()
     text = _step_text(model, (4, 256))
     assert _grouped_calls(text) == 10
-    holders = {}
-    for fn in re.split(r"\n(?=\s*func\.func )", text):
-        name = re.match(r"\s*func\.func \w+ @([\w.]+)", fn)
-        if name and _grouped_calls(text, fn):
-            holders[name.group(1)] = len(re.findall(
-                rf"call @{re.escape(name.group(1))}\(", text))
+    holders = _grouped_holders(text)
     # the walk forward (gate, up, down) and the walk backward (the other
     # seven), each lowered once and called once a layer of the body
     assert sorted(n.split("_")[2] for n in holders) == ["backward",
@@ -379,38 +411,106 @@ def test_a_scan_body_lowers_each_grouped_kernel_once(build, walks):
     assert set(holders.values()) == {walks}
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_a_serving_program_of_exaone_moe_lowers_with_the_loop(program):
-    """Decode rounds and prefill chunks are far under the rule's threshold:
-    the expert walk is the loop, a ``while`` whose trip count is read from
-    the counts, and the program holds no grouped call."""
-    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
-    from hetu_tpu.serve import PagedServeEngine
+def _program_text(engine, program: str, slots: int = 4, chunk: int = 8):
+    """The engine's decode program at ``slots`` sequences or its chunk
+    program at ``chunk`` tokens, lowered for TPU with locations."""
+    k_pool, v_pool = engine._pool_args()
+    n_pg = engine.cache.pages_per_slot
+    state = () if engine.cache.state is None else (engine.cache.state,)
+    if program == "decode":
+        fn = engine._build_decode()
+        aux = (slots, n_pg + 4 + len(state)
+               + sum(r + 1 for r in engine._ring_decode))
+    else:
+        fn = engine._build_chunk(n_pg)
+        aux = (3 * chunk + n_pg + 2 + len(state)
+               + sum(chunk + r for r in engine._ring_chunk),)
+    return fn.trace(engine.params, k_pool, v_pool,
+                    jax.ShapeDtypeStruct(aux, i32), *state).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
 
-    model = ExaoneMoeModel(ExaoneMoeConfig(
+
+def _tiny_exaone():
+    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
+
+    return ExaoneMoeModel(ExaoneMoeConfig(
         vocab_size=96, hidden_size=128, num_layers=5, num_heads=4,
         num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
         first_dense=1, n_routed_experts=16, moe_topk=4, held=(4, 4),
         window=8, max_position=256, dtype=bf16, param_dtype=bf16,
         expert_block_rows=8))
+
+
+def _tiny_longcat():
+    from hetu_tpu.models.longcat_flash import (
+        LongcatFlashConfig, LongcatFlashModel,
+    )
+
+    return LongcatFlashModel(LongcatFlashConfig(
+        vocab_size=96, hidden_size=128, num_layers=2, num_heads=4,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, ffn_size=256,
+        expert_ffn_size=128, n_routed_experts=16, zero_expert_num=4,
+        moe_topk=4, held=(4, 4), max_position=256, dtype=bf16,
+        param_dtype=bf16, expert_block_rows=8))
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("build", [_tiny_exaone, _tiny_longcat])
+def test_a_serving_program_of_wide_experts_lowers_with_the_loop(
+        build, program, monkeypatch):
+    """K-EXAONE's and LongCat's experts (6144 x 2048) are three times what
+    the grouped kernels keep in VMEM, so their decode rounds and prefill
+    chunks walk the loop: a ``while`` whose trip count is read from the
+    counts, and no grouped call.  At the test's widths the limit is scaled
+    down with the experts."""
+    from hetu_tpu.ops import moe_ops
+    from hetu_tpu.serve import PagedServeEngine
+
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 128 * 128 // 3)
+    model = build()
     engine = PagedServeEngine(model, jax.jit(model.init)(
         jax.random.PRNGKey(0)), num_slots=4, max_len=160, page_size=4,
         prefill_chunk=8, min_bucket=4)
-    k_pool, v_pool = engine._pool_args()
-    n_pg = engine.cache.pages_per_slot
-    if program == "decode":
-        fn = engine._build_decode()
-        aux = (4, n_pg + 4 + sum(r + 1 for r in engine._ring_decode))
-    else:
-        fn = engine._build_chunk(n_pg)
-        aux = (3 * 8 + n_pg + 2 + sum(8 + r for r in engine._ring_chunk),)
-    text = fn.trace(engine.params, k_pool, v_pool,
-                    jax.ShapeDtypeStruct(aux, i32)).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    text = _program_text(engine, program)
     assert "hetu.moe.experts" in text and "hetu.moe.gmm" not in text
     # the walk: a while whose bound is no constant, one a layer's scan body
     assert re.search(r"stablehlo\.while", text)
     assert 8 in _row_scatters(text, 128)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_a_serving_program_of_lfm2_lowers_one_grouped_call_for_twelve_layers(
+        program, monkeypatch):
+    """LFM2's experts fit the kernels: a decode round and a prefill chunk
+    take the grouped path, every pair in one trip, a whole expert a visit in
+    ONE Mosaic call.  The walk is a jitted function (``layer`` reaches it
+    as an array), so the twelve expert layers of the unrolled program call
+    one lowered ``_grouped_forward``: one grouped Mosaic call a program,
+    which is what a program's build costs (PERF.md section 6, PR 42 and
+    44); and no loop walk is left."""
+    from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
+    from hetu_tpu.ops import moe_ops
+    from hetu_tpu.serve import PagedServeEngine
+
+    model = Lfm2MoeModel(Lfm2MoeConfig(
+        vocab_size=96, hidden_size=128, num_layers=14, num_heads=4,
+        num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
+        first_dense=2, n_routed_experts=8, moe_topk=4, max_position=256,
+        dtype=bf16, param_dtype=bf16, expert_block_rows=24))
+    engine = PagedServeEngine(model, jax.jit(model.init)(
+        jax.random.PRNGKey(0)), num_slots=4, max_len=160, page_size=4,
+        prefill_chunk=8, min_bucket=4)
+    text = _program_text(engine, program)
+    assert _grouped_calls(text) == 1
+    holders = _grouped_holders(text)
+    assert list(holders.values()) == [12]
+    assert next(iter(holders)).startswith("_grouped_forward")
+    # no loop walk is left: no block of ``expert_block_rows`` rows anywhere
+    assert "hetu.moe.experts" in text
+    assert "tensor<24x128xf32>" not in text
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 0)
+    assert "tensor<24x128xf32>" in _program_text(engine, program)
 
 
 @pytest.mark.slow

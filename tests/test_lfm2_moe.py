@@ -131,7 +131,7 @@ def test_the_model_states_one_page_group_and_its_state_layers(lfm2):
                                                      (2, 32))
     assert spec.bytes_per_slot == 4 * 2 * 32 * 4
     assert KVCacheSpec(2, 2, 8).bytes_per_slot == 0
-    assert model.step_stats == MOE_STATS + ("moe_experts",)
+    assert model.step_stats == MOE_STATS + ("moe_experts", "moe_grouped")
 
 
 # ---- (b) chunks, padded and not, then decode ----

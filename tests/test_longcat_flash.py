@@ -52,6 +52,20 @@ def tiny(**kw) -> LongcatFlashConfig:
     return LongcatFlashConfig(**base)
 
 
+@pytest.fixture(autouse=True)
+def experts_over_the_grouped_limit(monkeypatch):
+    """The published experts, 6144 x 2048, are three times over what the
+    grouped kernels keep whole in VMEM, so the served cell walks them with
+    the LOOP (``ops.moe_ops.held_expert_path``).  The tiny ones here are
+    held to that walk by a limit scaled down with them."""
+    from hetu_tpu.ops import moe_ops
+
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 32 * 16 // 3)
+    c = tiny()
+    assert moe_ops.held_expert_path(
+        1, c.moe_topk, c.held[1], c.hidden_size, c.expert_ffn_size) == "loop"
+
+
 def dims_of(c: LongcatFlashConfig) -> dict:
     return dict(heads=c.num_heads, q_rank=c.q_lora_rank,
                 kv_rank=c.kv_lora_rank, nope=c.qk_nope_head_dim,

@@ -277,9 +277,15 @@ def _dense_composition(x, w, idx, wg, wu, wd, first):
 
 
 @pytest.mark.parametrize("routing", ["even", "skewed", "empty"])
-def test_held_walk_gradients_equal_the_dense_compositions(routing):
+def test_held_walk_gradients_equal_the_dense_compositions(routing,
+                                                          monkeypatch):
+    """The LOOP walk's (experts this small fit the grouped kernels, whose
+    gradients ``tests/test_grouped_experts.py`` holds: the rule's limit is
+    set to nothing here)."""
+    from hetu_tpu.ops import moe_ops
     from hetu_tpu.ops.moe_ops import held_expert_ffn
 
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 0)
     x, w, idx, (wg, wu, wd) = _held_case(routing)
     probe = jnp.asarray(np.random.default_rng(9).normal(size=x.shape),
                         jnp.float32)
@@ -314,9 +320,12 @@ def test_held_walk_gradients_equal_the_dense_compositions(routing):
 def test_held_walk_trips_forward_and_backward_follow_the_counts(
         routing, monkeypatch):
     """Both walks run ``sum(ceil(count_e / R))`` trips: counted by a host
-    callback put round the block lookup both loop bodies share."""
+    callback put round the block lookup both loop bodies share (the loop
+    path, which experts this small leave to the grouped one unless the
+    rule's limit is nothing)."""
     from hetu_tpu.ops import moe_ops
 
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 0)
     x, w, idx, (wg, wu, wd) = _held_case(routing, seed=3)
     trips = []
     block = moe_ops._walk_block

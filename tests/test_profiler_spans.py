@@ -543,6 +543,49 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
         assert e[3]["g1_view_bytes"] == 5 * 4 * row
 
 
+@pytest.mark.parametrize("path", ["grouped", "loop"])
+def test_a_served_expert_walk_says_which_path_computed_its_pairs(
+        tmp_path, monkeypatch, path):
+    """``moe_grouped`` on the ``post`` span of every decode round and prefill
+    chunk of an LFM2 engine, beside the other ``moe_*`` ids: the call's held
+    pairs where the rule (``ops.moe_ops.held_expert_path``) sends its shape
+    down the grouped path, 0 where it says loop."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
+    from hetu_tpu.ops import moe_ops
+
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
+                        32 * 16 if path == "grouped" else 32 * 16 - 1)
+    model = Lfm2MoeModel(Lfm2MoeConfig(
+        vocab_size=97, hidden_size=32, num_layers=5, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_size=64, expert_ffn_size=16,
+        first_dense=1, n_routed_experts=8, moe_topk=2,
+        layer_types=("conv", "full_attention", "conv", "conv",
+                     "full_attention"),
+        max_position=64, dtype=jnp.float32, param_dtype=jnp.float32,
+        init_std=0.2, router_init_std=0.5, expert_block_rows=4))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(3))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=64,
+                               page_size=4, prefill_chunk=8, min_bucket=4)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    rounds = _named(events, "serve.decode.post")
+    chunks = _named(events, "serve.prefill_chunk.post")
+    assert rounds and chunks
+    for e in rounds + chunks:
+        ids = e[3]
+        assert {"moe_held", "moe_hit", "moe_experts", "moe_grouped"} \
+            <= set(ids)
+        # four expert layers of two choices a row, every expert held
+        assert ids["moe_held"] > 0 and ids["moe_held"] % (4 * 2) == 0
+        assert ids["moe_grouped"] == (ids["moe_held"] if path == "grouped"
+                                      else 0)
+    assert eng.metrics.count("moe_grouped") == sum(
+        e[3]["moe_grouped"] for e in rounds + chunks)
+
+
 # ------------------------------------------ a trained expert model's counts
 
 def _expert_model():
@@ -601,13 +644,13 @@ def test_train_moe_instant_carries_each_steps_counts(tmp_path):
 def test_train_moe_instant_says_what_the_grouped_path_computed(
         tmp_path, monkeypatch, path):
     """``moe_grouped``: the step's held pairs that the grouped path computed,
-    all of them at a shape over the rule's threshold
-    (``ops.moe_ops.held_expert_path``) and none under it."""
+    all of them where the experts fit the rule's limit
+    (``ops.moe_ops.held_expert_path``) and none where they pass it."""
     from hetu_tpu.ops import moe_ops
 
-    # 64 tokens of 2 choices over 4 held experts: 32 pairs an expert
-    monkeypatch.setattr(moe_ops, "GROUPED_MIN_PAIRS_AN_EXPERT",
-                        32 if path == "grouped" else 33)
+    # experts of 32 x 16: at the limit of what the kernels keep, or over it
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
+                        32 * 16 if path == "grouped" else 32 * 16 - 1)
     assert moe_ops.held_expert_path(64, 2, 4, 32, 16) == path
     model, variables, batch = _expert_model()
     ex = Executor(model.lm_loss_fn(), optim.AdamWOptimizer(1e-3))
