@@ -16,7 +16,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from hetu_tpu import init as initializers
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module, held_as
+from hetu_tpu.layers.base import (
+    Module, held_as, held_transposed, linear_held,
+)
 from hetu_tpu.ops.attention import SAVED_REDUCED
 
 
@@ -98,8 +100,12 @@ class MultiHeadAttention(Module):
 
     def serving_params(self, params):
         # mirrors _qkv and _out: all four leaves are read as
-        # astype(self.dtype) and as nothing else
-        return held_as(params, self.dtype)
+        # astype(self.dtype) and as nothing else; the fused projection's
+        # result is read head by head ([nh, 3, hd]), so its leaf is held
+        # transposed and split by those axes (Module.serving_params)
+        return held_transposed(
+            held_as(params, self.dtype),
+            qkv_weight=(self.num_heads, 3, self.head_dim))
 
     def _qkv(self, p, x):
         """Fused projection split into q/k/v in cache layout [B,S,nh,hd].
@@ -109,8 +115,7 @@ class MultiHeadAttention(Module):
         heads on each device and attention needs no gather of the heads.
         """
         b, s, _ = x.shape
-        qkv = ops.linear(x, p["qkv_weight"].astype(self.dtype),
-                         p["qkv_bias"].astype(self.dtype))
+        qkv = linear_held(x, p, "qkv_weight", self.dtype, p["qkv_bias"])
         qkv = qkv.reshape(b, s, self.num_heads, 3, self.head_dim)
         return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
 

@@ -24,7 +24,9 @@ weights have one, else tied to the embedding.
 
 The layers run as a Python loop, not a scan: layers of several kinds with
 caches of several shapes do not scan.  Parameter leaves are stacked over the
-layers that have them and read at a layer's own (static) index.
+layers that have them and read at a layer's own (static) index; a server
+holds the attention projections a layer an array
+(:meth:`BlockDecoder.serving_params`), which the same index reads.
 
 ``jax.named_scope``s mark the sub-layers in the jitted programs
 (``hetu.attn.window``, ``hetu.attn.full``, ``hetu.ffn.dense``; the expert
@@ -41,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import Module, held_by_layer
 from hetu_tpu.layers.moe import MOE_STATS
 
 # the names ``layer_types`` gives the two kinds of attention layer
@@ -159,6 +161,16 @@ class BlockDecoder(GroupedHeads, Module):
         self.attn_leaf, self.cache_layer = attn_leaf, cache_layer
         self.rotated = frozenset(rotated)
         self.window = dict(window or {})
+
+    # ---- the weights as a server holds them ----
+    def serving_params(self, params):
+        """The four projection leaves of the stacked attention leaves a
+        layer an array: the layers are a Python loop, and a layer cut out
+        of a stacked leaf at a static index is written into a buffer of
+        its own in every call (``layers/base.py``
+        ``Module.serving_params``).  ``p["q"][l]`` reads either form."""
+        attn = held_by_layer(params["layers"]["attn"], "q", "k", "v", "o")
+        return dict(params, layers=dict(params["layers"], attn=attn))
 
     # ---- pieces of a layer ----
     def rope_at(self, pos):

@@ -112,7 +112,8 @@ class GPTModel(Module):
 
     def serving_params(self, params):
         """The blocks' matmul leaves in the compute dtype (each layer's
-        own ``serving_params``; the stacked leaves cast whole).  The
+        own ``serving_params``; the stacked leaves cast whole, the fused
+        QKV projection's also held transposed and split by head).  The
         embeddings and norms stay as given: the lookup adds ``tok_emb`` and
         ``pos_emb`` rows in their own dtype and only then rounds.  The tied
         ``tok_emb`` is read a second way, by the head in the compute dtype,

@@ -51,7 +51,9 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu import ops
-from hetu_tpu.layers.base import Module
+from hetu_tpu.layers.base import (
+    HELD_TRANSPOSED, Module, held_transposed, linear_held,
+)
 from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
 
 
@@ -132,7 +134,7 @@ class LatentAttention:
         b, s, _ = x.shape
         q = ops.rms_norm(ops.linear(x, self._w(p["q_a"])), p["q_a_norm"],
                          eps=cfg.rms_eps) * self.q_scale
-        q = ops.linear(q.astype(cfg.dtype), self._w(p["q_b"])).reshape(
+        q = linear_held(q.astype(cfg.dtype), p, "q_b", cfg.dtype).reshape(
             b, s, cfg.num_heads, cfg.qk_head_dim)
         q_n, q_r = q[..., :cfg.qk_nope_head_dim], q[..., cfg.qk_nope_head_dim:]
         kv = ops.linear(x, self._w(p["kv_a"]))
@@ -144,6 +146,11 @@ class LatentAttention:
 
     def _kv_b(self, p):
         cfg = self.c
+        held = p.get("kv_b" + HELD_TRANSPOSED)
+        if held is not None:
+            return jnp.moveaxis(held.reshape(
+                cfg.num_heads, cfg.qk_nope_head_dim + cfg.v_head_dim,
+                cfg.kv_lora_rank), 2, 0)
         return self._w(p["kv_b"]).reshape(
             cfg.kv_lora_rank, cfg.num_heads,
             cfg.qk_nope_head_dim + cfg.v_head_dim)
@@ -248,6 +255,22 @@ class LongcatFlashModel(Module):
             k=config.moe_topk, scaling=config.routed_scaling_factor,
             held=config.held, block_rows=config.expert_block_rows,
             dtype=config.dtype)
+
+    # ---- the weights as a server holds them ----
+    def serving_params(self, params):
+        """``q_b`` and ``kv_b`` transposed: the double layers are scanned
+        and both products' results are read head by head (192 and 256
+        wide), so the compiler contracts over each leaf's minor axis and
+        relaid both whole leaves once a call (``layers/base.py``
+        ``Module.serving_params``).  ``q_b`` split by head as its result is
+        read; ``kv_b`` left whole: split ``[heads, 256, kv_rank]``, of which
+        the absorbed form reads the two halves of the 256, the compiler
+        relaid it whole again (a compile for a described v5e, PR 45)."""
+        c = self.c
+        attn = held_transposed(
+            params["layers"]["attn"], q_b=(c.num_heads, c.qk_head_dim),
+            kv_b=None)
+        return dict(params, layers=dict(params["layers"], attn=attn))
 
     # ---- the cache this model asks of the serving engine ----
     def kv_cache_spec(self):

@@ -17,7 +17,7 @@ from hetu_tpu.ops.elementwise import (
     mask,
 )
 from hetu_tpu.ops.matmul import (
-    matmul, linear, batch_matmul, addmm, baddbmm, matrix_dot,
+    matmul, linear, linear_minor, batch_matmul, addmm, baddbmm, matrix_dot,
 )
 from hetu_tpu.ops.conv import (
     conv2d, conv2d_add_bias, max_pool2d, avg_pool2d,

@@ -23,13 +23,19 @@ def _acc_dtype(a, b):
     return None
 
 
+def _as_operands(y, a, b):
+    """A product's float32 accumulation back in its operands' promoted
+    dtype."""
+    out = jnp.promote_types(a.dtype, b.dtype)
+    return y.astype(out) if y.dtype != out else y
+
+
 def _mm(a, b):
     """Matmul with f32 MXU accumulation, result cast back to the inputs'
     promoted dtype — a bf16 network stays bf16 (half the HBM traffic on every
     activation) while each dot still accumulates in full precision."""
-    y = jnp.matmul(a, b, preferred_element_type=_acc_dtype(a, b))
-    out = jnp.promote_types(a.dtype, b.dtype)
-    return y.astype(out) if y.dtype != out else y
+    return _as_operands(
+        jnp.matmul(a, b, preferred_element_type=_acc_dtype(a, b)), a, b)
 
 
 def matmul(a, b, trans_a: bool = False, trans_b: bool = False):
@@ -46,6 +52,20 @@ def linear(x, w, bias=None, trans_w: bool = False):
     if trans_w:
         w = w.T
     y = _mm(x, w)
+    if bias is not None:
+        y = y + bias
+    return y
+
+
+def linear_minor(x, w, bias=None):
+    """``x`` [..., K] contracted with the MINOR axis of ``w`` [*out, K] (+
+    ``bias`` [*out]) -> [..., *out]: :func:`linear` over a weight stored
+    with its contracting axis last and its output axis split as the caller
+    reads the result (heads, width), in one product with no transpose and no
+    reshape of the weight between its storage and the product."""
+    y = _as_operands(lax.dot_general(
+        x, w, (((x.ndim - 1,), (w.ndim - 1,)), ((), ())),
+        preferred_element_type=_acc_dtype(x, w)), x, w)
     if bias is not None:
         y = y + bias
     return y

@@ -19,12 +19,19 @@ as ``state=`` (:class:`~hetu_tpu.serve.kv_cache.SlotStates`) in both entry
 points and returns them as its LAST result, behind its counts.  Optionally
 ``serving_params(params)``: the parameters as those two entry points READ
 them (a leaf they read only as ``astype(compute dtype)`` in that dtype, a
-leaf they read in two dtypes held in both, the rest as given).  The engine
-calls it once, at build, and holds what it returns, so a weight is cast
-once and not in every decode round and every prefill chunk; a model
-without it, or whose leaves are already in the dtype they are read in, is
-served from the very arrays it was given.  The caller's ``variables`` are
-not kept: a caller that drops them after the build gets their bytes back.
+leaf they read in two dtypes held in both; an attention projection leaf
+where its product reads it: transposed where a scanned layer's product
+contracts its minor axis, a layer an array where a Python loop reads it at
+a static index, ``layers/base.py`` ``Module.serving_params``; the rest as
+given, stacked leaves included).  The engine calls it once, at build, and
+holds what it returns, so a weight is cast, cut out of its stacked leaf or
+relaid once and not in every decode round and every prefill chunk; a model
+without it is served from the very arrays it was given, and so is every
+leaf its rendering leaves alone.  The caller's ``variables`` are not kept:
+a caller that drops them after the build gets their bytes back, those of
+a stacked leaf the engine holds in another form too (a caller that keeps
+them holds that leaf twice).  ``serve.params_held`` says what the build
+did: leaves given, ``retyped``, ``relaid``, bytes on each side.
 
 Compilation discipline is the whole point of this module: serving traffic
 has arbitrary prompt lengths, and a naive jit would compile one executable
@@ -56,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from hetu_tpu.layers.base import held_sources
 from hetu_tpu.parallel.mesh import AXIS_TP
 from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
@@ -88,7 +96,8 @@ def _place_params_and_cache_spec(model, variables, mesh, spec):
     """The engine's tp placement: Megatron split points on the params,
     kv-head-sharded cache when GQA heads divide tp.  The placed leaves are
     then held as the model's cache entry points read them (the module
-    docstring's ``serving_params``; a cast keeps its leaf's sharding).
+    docstring's ``serving_params``; a cast, a layer's array and a
+    transposed hold keep their leaf's sharding on its other axes).
     Returns (params, cache sharding, the ids of ``serve.params_held``)."""
     given = params = variables["params"] if "params" in variables \
         else variables
@@ -110,19 +119,29 @@ def _place_params_and_cache_spec(model, variables, mesh, spec):
 def _params_held(given, held) -> dict:
     """What the build did to the parameters, by leaf path: the leaves it
     was given, those it holds in another dtype than they came in (or in a
-    second one), and the bytes on each side."""
+    second one), those it holds in another shape or structure (a leaf a
+    layer, the two minor axes exchanged: ``layers/base.py``), and the bytes
+    on each side."""
     given, held = ({jax.tree_util.keystr(path): leaf for path, leaf
                     in jax.tree_util.tree_leaves_with_path(tree)}
                    for tree in (given, held))
+
+    def source(path):
+        """The given leaf that the held leaf at ``path`` renders; None for
+        a leaf the model added."""
+        return next((p for p in held_sources(path) if p in given), None)
 
     def nbytes(leaves):
         return sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
                    for a in leaves.values())
 
+    sources = {path: source(path) for path in held}
+    retyped = {src or path for path, src in sources.items()
+               if src is None or given[src].dtype != held[path].dtype}
     return {
-        "leaves": len(given),
-        "retyped": sum(path not in given or given[path].dtype != leaf.dtype
-                       for path, leaf in held.items()),
+        "leaves": len(given), "retyped": len(retyped),
+        "relaid": sum(path not in held or held[path].shape != leaf.shape
+                      for path, leaf in given.items()),
         "bytes_given": nbytes(given), "bytes_held": nbytes(held)}
 
 
@@ -156,10 +175,10 @@ class PagedServeEngine:
     """Owns params + a :class:`PagedKVCache` + the jitted chunk/decode
     executables: paged gather/scatter decode, chunked prefill, prefix
     sharing with copy-on-write.  The params it owns are ``variables``'
-    leaves in the dtype the two programs read each in (the model's
-    ``serving_params``, once, here): the leaves themselves where that is
-    the dtype they came in, a cast copy where it is not.  ``variables``
-    itself is not kept.
+    leaves in the dtype, and an attention projection's in the form, the two
+    programs read each in (the model's ``serving_params``, once, here): the
+    leaves themselves where that is how they came in, a copy where it is
+    not.  ``variables`` itself is not kept.
 
     model: anything with the cache entry points the module docstring names.
     num_slots bounds concurrent sequences; max_len bounds tokens per

@@ -1,7 +1,8 @@
 """What the paged engine's two programs hold and where they write: the
 checks ``test_paged_kv.py`` (GPT, Llama) and ``test_longcat_flash.py`` share
 (ISSUE 29), which parameter leaves they convert and the logits they argmax
-(ISSUE 31), and the two token oracles every serving test holds the engine
+(ISSUE 31), how they read a weight and the tiny served families (ISSUE
+45), and the two token oracles every serving test holds the engine
 to, both independent of any engine, and the hold that lets a pool test
 catch a member mid-decode.  A helper module, no tests of its own."""
 
@@ -84,23 +85,30 @@ BODIES = {"scan", "pjit", "jit", "closed_call", "core_call", "checkpoint",
           "custom_jvp_call", "custom_vjp_call"}
 
 
-def _inner_leaves(eqn, sub, is_leaf):
-    """Which of ``sub``'s inputs are parameter leaves, given which of
-    ``eqn``'s operands are: a loop's or a call's body takes the operands
-    themselves, a scatter's or a reduction's combiner takes scalars."""
+def _inner_operands(eqn, sub, marks: list) -> dict:
+    """``sub``'s inputs that stand for marked operands of ``eqn``, with their
+    marks (``marks`` lies beside ``eqn.invars``, falsy for an operand of no
+    interest): a loop's or a call's body takes the operands themselves, a
+    scatter's or a reduction's combiner takes scalars."""
     name = eqn.primitive.name
     if name == "while":
         nc, nb = eqn.params["cond_nconsts"], eqn.params["body_nconsts"]
-        carry = is_leaf[nc + nb:]
-        is_leaf = (is_leaf[:nc] + carry
-                   if sub is eqn.params["cond_jaxpr"].jaxpr
-                   else is_leaf[nc:nc + nb] + carry)
+        carry = marks[nc + nb:]
+        marks = (marks[:nc] + carry
+                 if sub is eqn.params["cond_jaxpr"].jaxpr
+                 else marks[nc:nc + nb] + carry)
     elif name == "cond":
-        is_leaf = is_leaf[1:]
+        marks = marks[1:]
     elif name not in BODIES:
-        return set()
-    assert len(is_leaf) == len(sub.invars), (name, "operands not mapped")
-    return {v for v, leaf in zip(sub.invars, is_leaf) if leaf}
+        return {}
+    assert len(marks) == len(sub.invars), (name, "operands not mapped")
+    return {v: m for v, m in zip(sub.invars, marks) if m}
+
+
+def _inner_leaves(eqn, sub, is_leaf):
+    """Which of ``sub``'s inputs are parameter leaves, given which of
+    ``eqn``'s operands are."""
+    return set(_inner_operands(eqn, sub, is_leaf))
 
 
 def _leaf_converts(jaxpr, leaves: set):
@@ -130,6 +138,121 @@ def param_converts(engine, name: str, *, batch: int, chunk: int,
     closed, _ = program(engine, name, batch=batch, chunk=chunk, params=leaves)
     n = len(jax.tree_util.tree_leaves(leaves))
     return list(_leaf_converts(closed.jaxpr, set(closed.jaxpr.invars[:n])))
+
+
+def traced(engine, name: str, *, batch: int, chunk: int, params=None):
+    """The engine's ``decode`` program at ``batch`` sequences or its
+    ``chunk`` program at ``chunk`` tokens, traced (``.jaxpr``, ``.lower``)
+    over the engine's own leaves or over ``params``: a cache of several
+    groups or with state layers too."""
+    k_pool, v_pool = engine._pool_args()
+    n_pg = engine.cache.pages_per_slot
+    state = () if engine.cache.state is None else (engine.cache.state,)
+    if name == "decode":
+        fn = engine._build_decode()
+        aux = (batch, n_pg + 4 + len(state)
+               + sum(r + 1 for r in engine._ring_decode))
+    else:
+        fn = engine._build_chunk(n_pg)
+        aux = (3 * chunk + n_pg + 2 + len(state)
+               + sum(chunk + r for r in engine._ring_chunk),)
+    return fn.trace(engine.params if params is None else params, k_pool,
+                    v_pool, jax.ShapeDtypeStruct(aux, np.int32), *state)
+
+
+# what may lie between a weight's parameter and the product that reads it
+READ_THROUGH = LAYOUT_ONLY | {"convert_element_type", "copy", "gather"}
+
+
+def _leaf_reads(jaxpr, chains: dict):
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        held = [chains.get(v) if isinstance(v, jax.extend.core.Var) else None
+                for v in eqn.invars]
+        if not any(held):
+            continue
+        subs = list(_sub_jaxprs(eqn.params))
+        if name == "scan":      # a layer of a scanned leaf is the scan's own
+            n = eqn.params["num_consts"] + eqn.params["num_carry"]
+            held = held[:n] + [h and (h[0], h[1] + ("scan",))
+                               for h in held[n:]]
+        if subs:
+            for sub in subs:
+                yield from _leaf_reads(sub, _inner_operands(eqn, sub, held))
+        elif name in READ_THROUGH and held[0]:
+            leaf, chain = held[0]
+            chains.update((v, (leaf, chain + (name,))) for v in eqn.outvars)
+        else:
+            yield from ((h[0], h[1] + (name,)) for h in held if h)
+
+
+def weight_reads(engine, name: str, which, *, batch: int = 4, chunk: int = 8,
+                 params=None) -> dict:
+    """By leaf path, the set of ways program ``name`` reads each parameter
+    leaf whose path ``which`` accepts: every chain of primitives from the
+    parameter to the first one that computes with it (``("transpose",
+    "dot_general")``: the product contracts the leaf's minor axis;
+    ``("slice", "squeeze", "dot_general")``: a layer is cut out of a
+    stacked leaf at a static index first), loops' and calls' bodies
+    included, ``"scan"`` where the layer is a scan's own slice of its
+    operand.  Over the engine's own leaves, or over ``params``."""
+    leaves = engine.params if params is None else params
+    closed = traced(engine, name, batch=batch, chunk=chunk,
+                    params=leaves).jaxpr
+    paths = [jax.tree_util.keystr(path) for path, _
+             in jax.tree_util.tree_leaves_with_path(leaves)]
+    chains = {v: (path, ()) for v, path in zip(closed.jaxpr.invars, paths)
+              if which(path)}
+    reads = {}
+    for path, chain in _leaf_reads(closed.jaxpr, chains):
+        reads.setdefault(path, set()).add(chain)
+    return reads
+
+
+def tiny_model(kind: str):
+    """A tiny bfloat16 model of each served family."""
+    bf16 = jnp.bfloat16
+    if kind == "gpt":
+        from hetu_tpu.models.gpt import GPTConfig, GPTModel
+        model = GPTModel(GPTConfig(
+            vocab_size=96, hidden_size=64, num_layers=3, num_heads=4,
+            ffn_size=128, max_position=160, dropout_rate=0.0, dtype=bf16))
+    elif kind == "exaone":
+        from hetu_tpu.models.exaone_moe import (
+            ExaoneMoeConfig, ExaoneMoeModel,
+        )
+        model = ExaoneMoeModel(ExaoneMoeConfig(
+            vocab_size=96, hidden_size=128, num_layers=5, num_heads=4,
+            num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
+            first_dense=1, n_routed_experts=16, moe_topk=4, held=(4, 4),
+            window=8, max_position=256, dtype=bf16, param_dtype=bf16,
+            expert_block_rows=8))
+    elif kind == "lfm2":
+        from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
+        model = Lfm2MoeModel(Lfm2MoeConfig(
+            vocab_size=96, hidden_size=128, num_layers=7, num_heads=4,
+            num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
+            first_dense=2, n_routed_experts=8, moe_topk=4, max_position=256,
+            dtype=bf16, param_dtype=bf16, expert_block_rows=24))
+    else:
+        from hetu_tpu.models.longcat_flash import (
+            LongcatFlashConfig, LongcatFlashModel,
+        )
+        model = LongcatFlashModel(LongcatFlashConfig(
+            vocab_size=96, hidden_size=128, num_layers=2, num_heads=4,
+            q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, ffn_size=256,
+            expert_ffn_size=128, n_routed_experts=16, zero_expert_num=4,
+            moe_topk=4, held=(4, 4), max_position=256, dtype=bf16,
+            param_dtype=bf16, expert_block_rows=8))
+    return model
+
+
+def tiny_served(kind: str):
+    """(:func:`tiny_model`, its variables, an engine's keywords for it)."""
+    model = tiny_model(kind)
+    return model, jax.jit(model.init)(jax.random.PRNGKey(0)), dict(
+        num_slots=4, max_len=160, page_size=4, prefill_chunk=8, min_bucket=4)
 
 
 class LogitsOut:

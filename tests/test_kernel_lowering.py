@@ -282,9 +282,10 @@ def _tiny_mellum():
     return model, (2, 256), 4
 
 
-def _step_text(model, batch_shape):
+def _step_text(model, batch_shape, debug_info: bool = True):
     """The train step of ``model`` as lowered for TPU, locations included
-    (a Mosaic call's ``jax.named_scope`` is in its location only)."""
+    (a Mosaic call's ``jax.named_scope`` is in its location only) unless
+    ``debug_info`` is off."""
     import hetu_tpu as ht
     from hetu_tpu import optim
     from hetu_tpu.train.executor import TrainState
@@ -300,7 +301,7 @@ def _step_text(model, batch_shape):
 
     batch = (jax.ShapeDtypeStruct(batch_shape, i32),)
     return ex._compile("train").trace(jax.eval_shape(state), batch).lower(
-        lowering_platforms=("tpu",)).as_text(debug_info=True)
+        lowering_platforms=("tpu",)).as_text(debug_info=debug_info)
 
 
 @pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3,
@@ -414,45 +415,22 @@ def test_a_scan_body_lowers_each_grouped_kernel_once(build, walks):
 def _program_text(engine, program: str, slots: int = 4, chunk: int = 8):
     """The engine's decode program at ``slots`` sequences or its chunk
     program at ``chunk`` tokens, lowered for TPU with locations."""
-    k_pool, v_pool = engine._pool_args()
-    n_pg = engine.cache.pages_per_slot
-    state = () if engine.cache.state is None else (engine.cache.state,)
-    if program == "decode":
-        fn = engine._build_decode()
-        aux = (slots, n_pg + 4 + len(state)
-               + sum(r + 1 for r in engine._ring_decode))
-    else:
-        fn = engine._build_chunk(n_pg)
-        aux = (3 * chunk + n_pg + 2 + len(state)
-               + sum(chunk + r for r in engine._ring_chunk),)
-    return fn.trace(engine.params, k_pool, v_pool,
-                    jax.ShapeDtypeStruct(aux, i32), *state).lower(
+    from paged_programs import traced
+
+    return traced(engine, program, batch=slots, chunk=chunk).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
 
 
 def _tiny_exaone():
-    from hetu_tpu.models.exaone_moe import ExaoneMoeConfig, ExaoneMoeModel
+    from paged_programs import tiny_model
 
-    return ExaoneMoeModel(ExaoneMoeConfig(
-        vocab_size=96, hidden_size=128, num_layers=5, num_heads=4,
-        num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
-        first_dense=1, n_routed_experts=16, moe_topk=4, held=(4, 4),
-        window=8, max_position=256, dtype=bf16, param_dtype=bf16,
-        expert_block_rows=8))
+    return tiny_model("exaone")
 
 
 def _tiny_longcat():
-    from hetu_tpu.models.longcat_flash import (
-        LongcatFlashConfig, LongcatFlashModel,
-    )
+    from paged_programs import tiny_model
 
-    return LongcatFlashModel(LongcatFlashConfig(
-        vocab_size=96, hidden_size=128, num_layers=2, num_heads=4,
-        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
-        qk_rope_head_dim=8, v_head_dim=16, ffn_size=256,
-        expert_ffn_size=128, n_routed_experts=16, zero_expert_num=4,
-        moe_topk=4, held=(4, 4), max_position=256, dtype=bf16,
-        param_dtype=bf16, expert_block_rows=8))
+    return tiny_model("longcat")
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
@@ -511,6 +489,105 @@ def test_a_serving_program_of_lfm2_lowers_one_grouped_call_for_twelve_layers(
     assert "tensor<24x128xf32>" not in text
     monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 0)
     assert "tensor<24x128xf32>" in _program_text(engine, program)
+
+
+# the attention projection leaves of each served family, by what their leaf
+# paths hold, and how a program over the engine's own leaves reads each: the
+# chains of primitives from the parameter to the product (``weight_reads``)
+PROJECTION_READS = {
+    # layers scanned: the fused projection's leaf held with its contracting
+    # axis minor and its columns split by head, the scan's own slice of it
+    # the product's operand as it lies
+    "gpt": {"qkv_weight_t": ("scan", "dot_general"),
+            "out_weight": ("scan", "dot_general")},
+    # layers a Python loop: a leaf a layer, whole into the product (q and k
+    # are stored [out, in], ``trans_w``)
+    "exaone": {"['q'][": ("transpose", "dot_general"),
+               "['k'][": ("transpose", "dot_general"),
+               "['v'][": ("dot_general",), "['o'][": ("dot_general",)},
+    "lfm2": {"['q'][": ("transpose", "dot_general"),
+             "['k'][": ("transpose", "dot_general"),
+             "['v'][": ("dot_general",), "['o'][": ("dot_general",)},
+    # double layers scanned over their NUMBER, a leaf read at the traced
+    # ``[l, i]``: the two leaves whose products are read head by head held
+    # transposed
+    "longcat": {"q_b_t": ("dynamic_slice", "squeeze", "dot_general"),
+                "['o']": ("dynamic_slice", "squeeze", "dot_general")}}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("kind", sorted(PROJECTION_READS))
+def test_a_serving_program_reads_each_projection_weight_where_it_lies(
+        kind, program):
+    """No decode or chunk program over the leaves the engine holds cuts a
+    layer out of a stacked projection leaf at a STATIC index (``slice``: on
+    a v5e the compiler writes that layer into a buffer of its own in every
+    call, K-EXAONE's ``fusion.647``), copies one, reshapes one, or
+    transposes one other than into the product that contracts it (``q`` and
+    ``k`` of the grouped heads are stored ``[out, in]``; the compiler folds
+    that into the product).  A leaf whose product's result is read head by
+    head is held with its contracting axis minor and its columns split by
+    head, so that neither a relayout nor a reshape lies between the leaf and
+    the product: GPT-2's ``copy.31``, LongCat's ``copy.148``.  A layer taken
+    at a TRACED index, a scan's own slice or a ``dynamic_slice``, is read in
+    place (``PERF.md`` section 6, PR 45).  Over the leaves as GIVEN the same
+    walk finds the static slices and no transposed hold: the check can see
+    them."""
+    from hetu_tpu.serve import PagedServeEngine
+    from paged_programs import tiny_served, weight_reads
+
+    model, variables, kw = tiny_served(kind)
+    engine = PagedServeEngine(model, variables, **kw)
+    want = PROJECTION_READS[kind]
+    reads = weight_reads(engine, program, lambda path: "attn" in path
+                         and any(name in path for name in want))
+    layers = {"gpt": 1, "exaone": 5, "lfm2": 2, "longcat": 1}[kind]
+    assert len(reads) == layers * len(want)
+    for path, chains in reads.items():
+        (name,) = [n for n in want if n in path]
+        assert chains == {want[name]}, (path, chains)
+    given = weight_reads(engine, program, lambda path: "attn" in path,
+                         params=variables["params"])
+    seen = {step for chains in given.values() for c in chains for step in c}
+    if kind in ("exaone", "lfm2"):
+        assert "slice" in seen
+    else:
+        assert not any("_t'" in path for path in given)
+
+
+# the lowered text (no locations) of each train step, by digest: what a step
+# computes reads ``params`` and never the rendering a server holds, so a PR
+# that touches the serving side alone leaves these as they are, and one that
+# changes a step replaces its digest and says why.  Mellum's step lowers to
+# one of two texts by the process's hash seed (which of two equal rotation
+# tables an equation names), on this tree and on its parent alike
+STEP_DIGESTS = {
+    "_gpt2_small": {
+        "c8a54ffcd3a3be6b47a5cbe48b53842fb43443d4042d34451982b543756b2e51"},
+    "_tiny_deepseek_v3": {
+        "3dee0100d2c80b2a1c6f9d6fbb1d10ad02dfa91deb746975198d998542d7b852"},
+    "_tiny_mellum": {
+        "3111c1dfcb94c458968ed1fa55dcd0f09793e3d184c281bc732687138f707782",
+        "70143e83c599f4d9c8edbfcc5da16961278b0091e4f5900e1eb40397e177ae78"}}
+
+
+@pytest.mark.parametrize("build", [_gpt2_small, _tiny_deepseek_v3,
+                                   _tiny_mellum])
+def test_a_train_step_lowers_to_the_text_it_lowered_to(build, monkeypatch):
+    """ISSUE 45 (e): the held rendering is the serving engine's alone; the
+    train steps of the three trained families lower to the text of the
+    parent commit (481cdaf), digest for digest.  The kernels in interpret
+    mode, for once: a Mosaic call's payload carries the checkout's path."""
+    import hashlib
+
+    for mod in ("flash_attention", "grouped_matmul"):
+        monkeypatch.setattr(sys.modules[f"hetu_tpu.ops.pallas_kernels.{mod}"],
+                            "auto_interpret", lambda interpret: True)
+    jax.clear_caches()
+    model, batch_shape, _ = build()
+    text = _step_text(model, batch_shape, debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        in STEP_DIGESTS[build.__name__]
 
 
 @pytest.mark.slow

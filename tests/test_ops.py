@@ -56,6 +56,30 @@ def test_matmul_family():
     close(ops.matrix_dot(a, a), np.sum(a * a, axis=-1))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_linear_minor_is_linear_over_a_weight_stored_the_other_way(dtype):
+    """``linear_minor`` over ``[*out, K]`` is ``linear`` over ``[K, N]``, the
+    result and the bias split as the weight's output axes are: bit for bit
+    under bfloat16 operands (float32 accumulation in both), to rounding in
+    float32, where the CPU's kernel sums the other operand order its own
+    way."""
+    def same(a, b):
+        return np.array_equal(a, b) if dtype == jnp.bfloat16 \
+            else np.allclose(a, b, rtol=1e-5, atol=1e-5)
+
+    x = jnp.asarray(rnd(2, 5, 16), dtype)
+    w = jnp.asarray(rnd(16, 24, seed=1), dtype)
+    bias = jnp.asarray(rnd(24, seed=2), dtype)
+    want = ops.linear(x, w, bias)
+    got = ops.linear_minor(x, w.T, bias)
+    assert got.dtype == want.dtype == dtype and same(got, want)
+    split = ops.linear_minor(x, w.T.reshape(4, 3, 2, 16),
+                             bias.reshape(4, 3, 2))
+    assert split.shape == (2, 5, 4, 3, 2)
+    assert same(split.reshape(2, 5, 24), want)
+    assert same(ops.linear_minor(x, w.T), ops.linear(x, w))
+
+
 def test_conv_pool():
     torch = pytest.importorskip("torch")
     import torch.nn.functional as F

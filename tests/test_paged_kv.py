@@ -26,7 +26,7 @@ from hetu_tpu.telemetry import trace
 from paged_programs import engine_greedy as _engine_greedy
 from paged_programs import (
     dense_greedy, engine_logits, oversized, pad_writes, param_converts,
-    ref_greedy,
+    ref_greedy, tiny_served,
 )
 
 pytestmark = pytest.mark.paged
@@ -572,14 +572,21 @@ def test_engine_holds_each_leaf_in_the_dtype_it_is_read_in(kind):
         else:
             assert a.dtype == jnp.bfloat16, path_
             retyped += 1
-    # GPT-2's tied table is read both ways and held both ways
-    assert set(held) - set(given) == ({"['lm_head']"} if kind == "gpt"
-                                      else set())
+    # GPT-2's tied table is read both ways and held both ways, and its fused
+    # projection's leaf is held with its contracting axis minor (ISSUE 45)
+    qkv, qkv_t = (f"['blocks']['attn']['qkv_weight{t}']" for t in ("", "_t"))
+    assert set(held) - set(given) == ({"['lm_head']", qkv_t}
+                                      if kind == "gpt" else set())
+    assert set(given) - set(held) == ({qkv} if kind == "gpt" else set())
+    if kind == "gpt":      # [L, heads, 3, width, H]
+        assert np.array_equal(held[qkv_t], jnp.swapaxes(
+            given[qkv].astype(jnp.bfloat16), 1, 2).reshape(2, 4, 3, 16, 64))
     assert np.array_equal(
         held["['lm_head']"],
         given["['tok_emb']" if kind == "gpt" else "['lm_head']"]
         .astype(jnp.bfloat16))
     want = {"leaves": len(given), "retyped": retyped,
+            "relaid": int(kind == "gpt"),
             "bytes_given": sum(a.nbytes for a in given.values()),
             "bytes_held": sum(a.nbytes for a in held.values())}
     assert retyped == (9 if kind == "gpt" else 6)
@@ -633,15 +640,19 @@ def test_logits_over_held_leaves_equal_those_over_given_leaves(kind, case):
 def test_float32_compute_is_served_from_the_arrays_given(kind):
     """Every leaf is already in the dtype it is read in: the engine holds
     the very arrays it was given, no copy, no second head, nothing counted
-    as re-typed."""
+    as re-typed; but for the one leaf GPT-2's programs read the other way
+    round (ISSUE 45), which is held transposed whatever its dtype."""
     model, variables = _bf16(kind, jnp.float32)
     engine = PagedServeEngine(model, variables, num_slots=2, max_len=64,
                               page_size=8, prefill_chunk=16)
     given, held = _leaves(variables["params"]), _leaves(engine.params)
-    assert set(held) == set(given)
-    assert all(held[p] is given[p] for p in given)
+    relaid = {"['blocks']['attn']['qkv_weight']"} if kind == "gpt" else set()
+    assert set(given) - set(held) == relaid
+    assert {p.replace("_t']", "']") for p in held} == set(given)
+    assert all(held[p] is given[p] for p in set(given) - relaid)
     snap = engine.metrics.snapshot()
     assert snap["params_retyped"] == 0
+    assert snap["params_relaid"] == len(relaid)
     assert snap["params_bytes_held"] == snap["params_bytes_given"]
 
 
@@ -678,13 +689,110 @@ def test_retyped_leaves_keep_the_megatron_placement(kind):
         if path in want:
             assert a.sharding.is_equivalent_to(want[path], a.ndim), path
             split += not a.sharding.is_fully_replicated
-    assert split >= 4
+    assert split >= (3 if kind == "gpt" else 4)
+    if kind == "gpt":
+        # the leaf held transposed, its columns split by head, is split
+        # over the heads: whole heads a device still (ISSUE 45)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        qkv_t = engine.params["blocks"]["attn"]["qkv_weight_t"]
+        assert qkv_t.shape == (2, 4, 3, 16, 64)
+        assert qkv_t.sharding.is_equivalent_to(
+            NamedSharding(mesh, P(None, "tp", None, None, None)), 5)
     g = np.random.default_rng(7)
     prompt = [int(t) for t in g.integers(0, 97, 21)]
     as_given = PagedServeEngine(model, variables, **kw)
     as_given.params = _DecodeTP().place(variables["params"], mesh)
     assert _engine_greedy(engine, prompt, 6) == \
         _engine_greedy(as_given, prompt, 6)
+
+
+# ---- each projection weight held where its programs read it (ISSUE 45) ----
+
+SERVED = ["gpt", "exaone", "lfm2", "longcat"]
+
+
+@pytest.mark.parametrize("kind", SERVED)
+def test_logits_over_the_held_layout_equal_those_over_given_leaves(kind):
+    """Where the bytes of a projection weight lie changes no logit: every
+    chunk's and every decode round's logits, out of the engine's own
+    programs, are equal bit for bit over the leaves the engine holds (a leaf
+    a layer; the two minor axes exchanged) and over the tree it was given.
+    The contraction and its operands' order are the same in both."""
+    model, variables, kw = tiny_served(kind)
+    g = np.random.default_rng(45)
+    prompt = [int(t) for t in g.integers(0, 96, 21)]
+    held, engine = engine_logits(model, variables, prompt, 4, **kw)
+    given, _ = engine_logits(model, variables, prompt, 4, as_given=True, **kw)
+    assert engine.metrics.snapshot()["params_relaid"] == \
+        {"gpt": 1, "exaone": 4, "lfm2": 4, "longcat": 2}[kind]
+    assert len(held) == len(given) == 3 + 3
+    for a, b in zip(held, given):
+        assert a.shape[-1] == 96 and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("kind", SERVED)
+def test_an_engine_built_from_shapes_holds_the_same_layout(kind):
+    """``jax.eval_shape``'s tree in place of the arrays: the same leaves in
+    the same shapes and structure, counted the same."""
+    model, variables, kw = tiny_served(kind)
+    real = PagedServeEngine(model, variables, **kw)
+    described = PagedServeEngine(
+        model, jax.eval_shape(model.init, jax.random.PRNGKey(0)), **kw)
+    assert {p: (a.shape, a.dtype) for p, a in _leaves(real.params).items()} \
+        == {p: (a.shape, a.dtype)
+            for p, a in _leaves(described.params).items()}
+    want, got = real.metrics.snapshot(), described.metrics.snapshot()
+    assert want["params_relaid"] > 0
+    assert all(got[k] == want[k] for k in want if k.startswith("params_"))
+
+
+@pytest.mark.parametrize("kind", ["exaone", "lfm2", "longcat"])
+def test_the_held_layout_pins_no_leaf_it_was_given(kind):
+    """A caller that drops its tree after the build gets the stacked
+    originals' bytes back: what the engine holds of a leaf it relaid is
+    arrays of its own, and of every other leaf the very array given."""
+    import gc
+    model, variables, kw = tiny_served(kind)
+    given = _leaves(variables["params"])
+    engine = PagedServeEngine(model, variables, **kw)
+    held = _leaves(engine.params)
+    same = [p for p in held if p in given]
+    assert all(held[p] is given[p] for p in same)
+    relaid = set(given) - set(held)
+    assert len(relaid) == engine.metrics.snapshot()["params_relaid"]
+    ids = {p: id(given[p]) for p in relaid}
+    del given, variables
+    gc.collect()
+    assert not any(id(a) in ids.values() for a in jax.live_arrays())
+    assert sum(a.nbytes for a in _leaves(engine.params).values()) \
+        == engine.metrics.snapshot()["params_bytes_given"]
+
+
+def test_a_leaf_held_by_layer_or_transposed_keeps_its_other_axes_split():
+    """Under a ``tp`` mesh a stacked leaf split over one of its two minor
+    axes is held, a layer at a time, split over that axis, and transposed,
+    over the axis it became (the major one of those it is split into)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from hetu_tpu.layers.base import held_by_layer, held_transposed
+    mesh = ht.make_mesh(tp=2)
+    leaf = jax.device_put(jnp.arange(3 * 8 * 4, dtype=jnp.float32).reshape(
+        3, 8, 4), NamedSharding(mesh, P(None, "tp", None)))
+    layers = held_by_layer({"w": leaf}, "w")["w"]
+    assert len(layers) == 3
+    for l, a in enumerate(layers):
+        assert a.sharding.is_equivalent_to(
+            NamedSharding(mesh, P("tp", None)), 2)
+        assert np.array_equal(a, leaf[l])
+    t = held_transposed({"w": leaf}, w=None)["w_t"]
+    assert t.sharding.is_equivalent_to(
+        NamedSharding(mesh, P(None, None, "tp")), 3)
+    assert np.array_equal(t, np.swapaxes(np.asarray(leaf), 1, 2))
+    leaf = jax.device_put(leaf, NamedSharding(mesh, P(None, None, "tp")))
+    t = held_transposed({"w": leaf}, w=(2, 2))["w_t"]        # [3, 2, 2, 8]
+    assert t.sharding.is_equivalent_to(
+        NamedSharding(mesh, P(None, "tp", None, None)), 4)
+    assert np.array_equal(t, np.swapaxes(np.asarray(leaf), 1, 2).reshape(
+        3, 2, 2, 8))
 
 
 # ---- groups of cache layers: window layers beside full ones (ISSUE 32) ----

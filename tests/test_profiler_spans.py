@@ -386,8 +386,10 @@ def test_spans_a_scheduler_step(recorded, chunks, spans):
 
 def test_params_held_says_what_the_build_did_to_the_weights(tmp_path):
     """``serve.params_held`` is one instant at build, beside
-    ``serve.cache_spec``: the leaves given, those held in another dtype, and
-    the bytes on each side (float32 compute here: nothing re-typed)."""
+    ``serve.cache_spec``: the leaves given, those held in another dtype,
+    those held in another shape or structure (``relaid``, ISSUE 45: GPT-2's
+    fused projection, transposed) and the bytes on each side (float32
+    compute here: nothing re-typed)."""
     model, variables = _model()
     with profiled(tmp_path):
         eng, _ = _serving(model, variables)
@@ -395,8 +397,10 @@ def test_params_held_says_what_the_build_did_to_the_weights(tmp_path):
     (held,) = _named(events, "serve.params_held")
     leaves = jax.tree_util.tree_leaves(eng.params)
     nbytes = sum(a.nbytes for a in leaves)
-    assert held[3] == {"leaves": len(leaves), "retyped": 0,
+    assert held[3] == {"leaves": len(leaves), "retyped": 0, "relaid": 1,
                        "bytes_given": nbytes, "bytes_held": nbytes}
+    assert [k for k in eng.params["blocks"]["attn"] if k.endswith("_t")] \
+        == ["qkv_weight_t"]
     names = [e[0] for e in events]
     assert names.index("serve.params_held") \
         == names.index("serve.cache_spec") + 1
