@@ -6,8 +6,8 @@ the least-loaded healthy member.  Mid-run one member's engine is KILLED
 pool's health poll fails its queue over to the survivor, which
 re-prefills from prompt + tokens-so-far; every request still completes
 'ok' with the exact greedy continuation.  A planned preemption would
-instead live-migrate the KV slots (``pool.drain_member`` — see
-``bench.py migrate`` for when that wins).
+instead live-migrate the KV slots (``pool.drain_member``;
+``pytest tests/ -m migrate`` asserts its token parity).
 
     python examples/gpt_serve_pool.py --requests 8 --max-tokens 12
 """

@@ -32,9 +32,7 @@ def train(wire, port, *, vocab, dim, fields, batch, steps,
           verbose: bool = True):
     """Train the CTR model over a PS at ``port`` on ``wire``; returns
     ``(final_loss, step_seconds)`` — the mean loss over the last 20
-    steps plus per-step pull+push wall times.  `bench.py quant` imports
-    THIS function for its f32-vs-int8 A/B, so the example and the bench
-    measure the same model by construction."""
+    steps plus per-step pull+push wall times."""
     import time
 
     from hetu_tpu.ps import van

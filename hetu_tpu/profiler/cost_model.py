@@ -41,7 +41,7 @@ CHIPS = {
                    ici_bw=6 * 100e9 / 2, dcn_bw=25e9),
     # NOT a measurement of anything: a stand-in so the offline planner and
     # its tests can rank plans on a host with no TPU.  No utilization is
-    # ever computed from it (bench.py refuses a non-TPU device).
+    # ever computed from it (benchmarks/run.py refuses a non-TPU device).
     "cpu": ChipSpec("cpu", bf16_flops=2e11, hbm_bw=5e10, hbm_bytes=64e9,
                     ici_bw=1e10, dcn_bw=1e10),
 }

@@ -854,8 +854,8 @@ class SequentialFaultCampaign:
         return self._next >= len(self.draws)
 
     def report(self) -> dict:
-        """Rounds survived / drawn, plus per-kind recovery seconds —
-        the ``bench.py soak`` headline inputs."""
+        """Rounds survived / drawn, plus per-kind recovery seconds
+        (``pytest tests/ -m soak`` asserts the rounds survived)."""
         ok = [r for r in self.results if r["ok"]]
         per_kind: dict = defaultdict(list)
         for r in self.results:

@@ -123,10 +123,10 @@ def _fleet_event(name: str, rec: dict) -> None:
 
 def seeded_prompts(n: int, seed: int = 0, *, vocab: int = 89,
                    max_len: int = 6) -> list:
-    """Deterministic prompt set shared by the controller harness, the
-    chaos tests, and ``bench.py ctrlchaos`` — same (n, seed) → same
-    prompts in every process, so token-exactness is checkable across a
-    controller death without shipping the prompts anywhere."""
+    """Deterministic prompt set shared by the controller harness and the
+    chaos tests — same (n, seed) → same prompts in every process, so
+    token-exactness is checkable across a controller death without
+    shipping the prompts anywhere."""
     rng = np.random.default_rng((int(seed), 0xC7A0))
     out = []
     for _ in range(int(n)):
@@ -2071,7 +2071,7 @@ class CrossProcessServingPool:
         :func:`~hetu_tpu.telemetry.health.default_fleet_rules` compiled
         from this pool's ``slo_classes``; ``rule_kw`` (``burn_windows``,
         ``burn_budget``, ``burn_factor``, ``window_s``, ...) tunes that
-        compilation — tests and benches shrink the burn windows to
+        compilation — tests shrink the burn windows to
         match runs shorter than five minutes.
 
         Alert state rides ``fleet_metrics()`` as ``ctrl.health.*``

@@ -228,8 +228,7 @@ def estimate_payload_bytes(engine) -> int:
 def pick_codec(rate_mbps: float | None, payload_bytes: int,
                cache_dtype: str, *,
                fast_s: float = 0.05, slow_s: float = 0.5) -> str:
-    """Resolve ``codec="auto"`` to a concrete wire codec from the
-    crossover model ``bench.py migrate --quant`` measures: compression
+    """Resolve ``codec="auto"`` to a concrete wire codec: compression
     only wins when the LINK, not the CPU, is the bottleneck — loopback
     moves bytes for free and the codec would just burn encode time.
 
@@ -238,10 +237,10 @@ def pick_codec(rate_mbps: float | None, payload_bytes: int,
     * bf16 cache → ``bf16`` (bit-lossless, 2x) once transfer costs
       real time; escalate to ``int8`` (4x vs f32, 2x vs bf16,
       near-lossless block scales) when even the bf16 payload would
-      exceed ``slow_s`` — the preemption-deadline regime where the
-      bench's crossover shows int8 winning outright;
+      exceed ``slow_s`` — the preemption-deadline regime, where the
+      wire is all of the drain's time;
     * f32 cache → ``int8`` directly (bf16 would be lossy anyway at
-      only 2x; int8's block-scaled 4x is the measured winner).
+      only 2x; int8's block scales give 4x).
     """
     if rate_mbps is None or rate_mbps <= 0 or payload_bytes <= 0:
         return "none"
@@ -661,7 +660,7 @@ def migrate_inflight(src, dst, *, wire=None, codec: str = "none",
     caches at half the bytes; "int8" is ~4x smaller (2x for bf16 caches)
     with per-(layer, head) block scales, a near-lossless approximation
     whose drain payloads move the migrate-vs-re-prefill crossover to
-    shorter contexts (``bench.py migrate --quant``).
+    shorter contexts.
 
     Failure atomicity: any error re-adopts the requests AND their slots
     at the source (the slots were never released) and re-raises —

@@ -499,8 +499,7 @@ class ServingPool:
         if codec == "auto":
             # per-drain resolution from the measured link rate (netem
             # cap if one is installed, else the op-span-derived rate)
-            # and THIS member's live payload — the crossover model
-            # `bench.py migrate --quant` measures, applied at drain time
+            # and THIS member's live payload (`migrate.pick_codec`)
             codec = _migrate.resolve_codec("auto", m.scheduler.engine)
         with self._lock:
             if m.dead or m.draining:

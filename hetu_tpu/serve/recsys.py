@@ -98,8 +98,7 @@ class ServingEmbeddingCache:
     ``.table`` is shared (read-through wrapper: the serving side observes
     the trainer's pushes within the bound).
 
-    ``capacity=0`` disables caching (every row re-pulled — the
-    cache-less baseline ``bench.py ctr_serve`` measures against).
+    ``capacity=0`` disables caching (every row re-pulled).
 
     ``policy``: ``"lru"`` (default) or ``"lfu"``.
 
@@ -1038,7 +1037,7 @@ class RecsysBatcher:
     def _finish(self, req: RecsysRequest, status: str) -> None:
         finish_request(req, status, self.metrics)
 
-    # ---- convenience driver (tests / bench) ----
+    # ---- convenience driver (tests) ----
     def run(self, requests, *, max_steps: int = 100_000) -> dict:
         for r in requests:
             self.submit(r)

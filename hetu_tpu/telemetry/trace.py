@@ -41,9 +41,9 @@ and pays nothing for the second sink.
 Off-path contract (no tracer installed, no profiler session).  With jax
 loaded a span costs the construction of one inert ``TraceAnnotation`` and
 its enter and exit: 0.49-0.69 us a span against 0.19-0.32 us before the
-second sink (``disabled_span_ns_per_call`` of ``bench.py telemetry``, three
-runs each on this repo's CPU sandbox, PR 25); no lock, no clock read,
-nothing kept.  Without jax it is what it was: a branch and the preallocated
+second sink (a timed loop of calls of a disabled ``span()``, three runs
+each on this repo's CPU sandbox, not the chip's host; PR 25); no lock, no
+clock read, nothing kept.  Without jax it is what it was: a branch and the preallocated
 no-op ``NULL_SPAN``.  :func:`instant` costs the same inert object, then
 returns on the tracer's branch.
 """
@@ -401,9 +401,8 @@ def open_process_stream(stream_dir, name: str, *,
     was at its default disposition the default is re-raised so the
     process still dies).
 
-    Disabled (returns None) when ``HETU_OBS_STREAM`` is "0"/"false" —
-    the switch the telemetry-off arm of ``bench.py obs`` ships to its
-    member processes."""
+    Disabled (returns None) when ``HETU_OBS_STREAM`` is "0"/"false"
+    (member processes inherit it)."""
     if os.environ.get("HETU_OBS_STREAM", "1").lower() in ("0", "false"):
         return None
     from pathlib import Path

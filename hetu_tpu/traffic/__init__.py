@@ -6,10 +6,9 @@ plane"):
 
 * :mod:`loadgen`   — seeded open-loop workload synthesis (diurnal rate
   curves, bursty multi-tenant arrivals, Zipfian prompt/key popularity,
-  per-tenant deadlines) with a byte-stable JSON trace format, and
-  replay adapters for the LLM (:class:`~hetu_tpu.serve.crosshost.
-  CrossProcessServingPool`) and CTR (:class:`~hetu_tpu.serve.recsys.
-  RecsysPool`) pools;
+  per-tenant deadlines) with a byte-stable JSON trace format, and an
+  open-loop replay that paces them against any ``(event) -> handle``
+  submit callable;
 * :mod:`autoscale` — a control loop on the controller that reads
   MEASURED load from ``fleet_metrics()`` (queue depth, shed rate,
   windowed per-tenant TTFT p99 vs SLO) and scales the member fleet:
@@ -20,18 +19,18 @@ plane"):
   admission + weighted fair queueing) and ride the submit wire through
   ``serve/crosshost.py`` — the traffic plane only names them.
 
-``bench.py autoscale`` is the headline: a seeded 10x diurnal spike
-against a real cross-process pool, autoscaling on vs off.
+``pytest tests/ -m traffic`` asserts the plane: the trace's bytes, the
+replay's pacing, tiered admission and the autoscaler's decisions.
 """
 
 from hetu_tpu.traffic.autoscale import Autoscaler, AutoscalePolicy
-from hetu_tpu.traffic.loadgen import (TenantSpec, TraceSpec, ctr_submitter,
+from hetu_tpu.traffic.loadgen import (TenantSpec, TraceSpec,
                                       diurnal_multiplier, dumps_trace,
-                                      llm_submitter, load_trace, replay,
-                                      save_trace, synthesize)
+                                      load_trace, replay, save_trace,
+                                      synthesize)
 
 __all__ = [
     "Autoscaler", "AutoscalePolicy", "TenantSpec", "TraceSpec",
-    "ctr_submitter", "diurnal_multiplier", "dumps_trace", "llm_submitter",
-    "load_trace", "replay", "save_trace", "synthesize",
+    "diurnal_multiplier", "dumps_trace", "load_trace", "replay",
+    "save_trace", "synthesize",
 ]

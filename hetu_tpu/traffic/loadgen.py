@@ -34,9 +34,11 @@ Workload shape, per tenant:
 
 Replay (:func:`replay`) walks events in arrival order against an
 injectable clock/sleep pair — tests drive it with a fake clock and
-assert pacing without sleeping; benches pass real time.  The submit
-callable comes from :func:`llm_submitter` / :func:`ctr_submitter` (or
-anything with the same ``(event) -> handle`` shape).
+assert pacing without sleeping; a driver of a real pool passes real
+time.  The submit callable is anything of the shape ``(event) ->
+handle``: for an LLM pool, the event's ``prompt``, ``max_tokens``,
+``deadline_s`` (as ``timeout_s``), ``tenant`` and ``slo`` handed to
+``pool.submit``.
 """
 
 from __future__ import annotations
@@ -237,33 +239,6 @@ def load_trace(path) -> dict:
 # ---------------------------------------------------------------------------
 # replay
 # ---------------------------------------------------------------------------
-
-def llm_submitter(pool) -> Callable:
-    """Event → non-blocking submit against an LLM pool
-    (:class:`CrossProcessServingPool` or anything with its ``submit``
-    keyword surface).  Returns the pool's request handle."""
-    def _submit(ev: dict):
-        return pool.submit(ev["prompt"],
-                           max_tokens=int(ev.get("max_tokens", 8)),
-                           timeout_s=float(ev["deadline_s"]),
-                           tenant=ev.get("tenant"), slo=ev.get("slo"))
-    return _submit
-
-
-def ctr_submitter(rpool) -> Callable:
-    """Event → non-blocking submit against a :class:`RecsysPool`
-    (delegated ``submit(RecsysRequest)``); the handle's ``done`` event
-    resolves like the LLM pool's."""
-    def _submit(ev: dict):
-        from hetu_tpu.serve.recsys import RecsysRequest
-        req = RecsysRequest(
-            dense=np.asarray(ev["dense"], np.float32),
-            sparse=np.asarray(ev["sparse"], np.int64),
-            timeout_s=float(ev["deadline_s"]))
-        rpool.submit(req)
-        return req
-    return _submit
-
 
 def replay(trace: dict, submit: Callable, *,
            speed: float = 1.0,

@@ -165,55 +165,70 @@ def test_takeover_mid_compaction_restores_exact_request_set():
     assert set(got) == want | {"9"}
 
 
-def test_concurrent_reader_never_sees_torn_state():
-    """Fuzz the seqlock: a writer appends + compacts continuously while
-    a reader replays — every read must decode cleanly and yield a
-    request set the writer actually had at some instant."""
+class HookedPulls:
+    """The reader's view of the writer's table: ``before_pull(n)`` runs
+    ahead of the reader's n-th ``sparse_pull`` (0 is the header probe),
+    which is where a concurrent writer's frame would land."""
+
+    def __init__(self, inner, before_pull):
+        self._inner, self._before_pull, self.pulls = inner, before_pull, 0
+
+    def sparse_pull(self, idx):
+        self._before_pull(self.pulls)
+        self.pulls += 1
+        return self._inner.sparse_pull(idx)
+
+
+@pytest.mark.parametrize("lands_before, compacts, pulls, gives_up", [
+    pytest.param(lambda n: n == 1, False, 3, False, id="append_after_probe"),
+    pytest.param(lambda n: n == 1, True, 2, False, id="compact_after_probe"),
+    pytest.param(lambda n: n >= 1, False, 9, True,
+                 id="append_before_every_pull"),
+])
+def test_concurrent_reader_never_sees_torn_state(lands_before, compacts,
+                                                 pulls, gives_up):
+    """The seqlock, stepped by hand: the writer's frame lands between the
+    reader's header probe and its big pull, in each way ``read``'s
+    two-pull protocol can meet.  A head that grew costs one retry, a head
+    that fell back to a new base none, and the read yields the request set
+    the writer had at that instant; behind a writer that never pauses the
+    8 attempts run out, which is the designed refusal."""
     led = _ledger(rows=64, dim=16)
-    snapshots = []  # request-id frontier history (monotone)
-    stop = threading.Event()
-    errors = []
+    inflight = {}
 
-    def writer():
-        inflight = {}
-        for i in range(1, 400):
-            inflight[str(i)] = {"msg": {"p": [i]}}
-            if len(inflight) > 5:
-                rid = min(inflight, key=int)
-                del inflight[rid]
-                try:
-                    led.append({"r": [int(rid), "ok"]}, ctrl_inc=1)
-                except mb.LedgerCompactionNeeded:
-                    led.compact({"requests": dict(inflight)},
-                                ctrl_inc=1)
-            try:
-                led.append({"a": [i, {"p": [i]}]}, ctrl_inc=1)
-            except mb.LedgerCompactionNeeded:
-                led.compact({"requests": dict(inflight)}, ctrl_inc=1)
-                led.append({"a": [i, {"p": [i]}]}, ctrl_inc=1)
-            snapshots.append(i)
-        stop.set()
+    def accept(i):
+        inflight[str(i)] = {"msg": {"p": [i]}}
+        led.append({"a": [i, {"p": [i]}]}, ctrl_inc=1)
 
-    def reader():
-        r = _fresh_reader(led)
-        while not stop.is_set():
-            try:
-                got = r.read()
-                if got is not None:
-                    _replay(got)  # must decode, json-parse, replay
-            except Exception as e:  # pragma: no cover
-                errors.append(repr(e))
-                return
+    for i in range(1, 6):
+        accept(i)
+    ids = iter(range(6, 64))
 
-    w = threading.Thread(target=writer)
-    rd = threading.Thread(target=reader)
-    w.start()
-    rd.start()
-    w.join(60)
-    rd.join(60)
-    assert not errors, errors
-    reqs, _ = _replay(_fresh_reader(led).read())
-    assert max(int(k) for k in reqs) == 399
+    def before_pull(n):
+        if not lands_before(n):
+            return
+        if compacts:
+            led.append({"r": [1, "ok"]}, ctrl_inc=1)
+            del inflight["1"]
+            led.compact({"requests": dict(inflight)}, ctrl_inc=1)
+        else:
+            accept(next(ids))
+
+    r = _fresh_reader(led)
+    r._table = hooked = HookedPulls(led._table, before_pull)
+    if gives_up:
+        with pytest.raises(RuntimeError, match="quiescent header"):
+            r.read()
+        r._table = led._table  # the writer pauses: the next read lands
+    got = r.read()
+    assert hooked.pulls == pulls  # the probe, then each attempt
+    assert got["compactions"] == int(compacts)
+    # the new base comes with zero deltas, never old deltas on a new base:
+    # the old region's resolve record is not replayed
+    assert len(got["deltas"]) == (0 if compacts else len(inflight))
+    reqs, resolved = _replay(got)
+    assert set(reqs) == set(inflight)
+    assert resolved == {}
 
 
 def test_append_is_fenced_and_successor_geometry_adopted():

@@ -349,19 +349,3 @@ def test_sigterm_preemption_resume_is_step_exact(tmp_path):
     # fewer steps ran in the resumed process than the reference
     assert len([ln for ln in lines if ln.startswith("step")]) < 12
     assert resumed_done == ref_done  # step + params md5 + (seed, seqnum)
-
-
-def test_bench_resilience_smoke(tmp_path):
-    """`bench.py resilience` emits its one JSON line in smoke mode."""
-    import json
-    import os
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu", HETU_BENCH_SMOKE="1",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    r = subprocess.run([sys.executable, str(REPO / "bench.py"),
-                        "resilience"], capture_output=True, text=True,
-                       timeout=300, env=env, cwd=str(REPO))
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "resilience_supervisor_overhead_pct"
-    assert "steps_per_s_supervised" in rec["extra"]

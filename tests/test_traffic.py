@@ -6,8 +6,8 @@ prefix re-dedup, and the measured-load autoscaler.
 All fast lane: the loadgen is pure numpy, the replay tests drive a fake
 clock, the scheduler tests pump a tiny in-process GPT, and the
 autoscaler tests run against a fake pool with canned ``fleet_metrics``
-dumps.  The real cross-process arm lives in ``bench.py autoscale`` and
-the slow revive-survival test in tests/test_fleet_obs.py.
+dumps.  The real cross-process arms are the slow revive-survival test in
+tests/test_fleet_obs.py and the journaled scale-up of tests/test_soak.py.
 """
 
 import jax
