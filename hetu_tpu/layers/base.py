@@ -101,8 +101,10 @@ def held_by_layer(leaves: dict, *names) -> dict:
     """``leaves`` with each named leaf, stacked over layers, held as a tuple
     of its layers' arrays (a shape standing for its array too): ``held[l]``
     is then Python's indexing, and a program that reads layer ``l`` takes
-    that array whole."""
+    that array whole.  A leaf that already is such a tuple stays as given."""
     def layers(leaf):
+        if isinstance(leaf, tuple):      # given so: a model may yield it
+            return leaf
         if isinstance(leaf, jax.ShapeDtypeStruct):
             return tuple(jax.ShapeDtypeStruct(leaf.shape[1:], leaf.dtype)
                          for _ in range(leaf.shape[0]))

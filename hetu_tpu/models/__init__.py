@@ -16,3 +16,4 @@ from hetu_tpu.models.ctr_zoo import DeepFM, DCN, CrossNet
 from hetu_tpu.models.llama import (HeteroLlama, LlamaConfig, LlamaModel,
                                    llama2_7b)
 from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
+from hetu_tpu.models.falcon_h1 import FalconH1Config, FalconH1Model

@@ -1,26 +1,31 @@
-"""One decoder block for the expert models that are served, and its three
-calls.
+"""One decoder block for the models that are served through
+``hetu_tpu/serve``'s cache entry points (expert models and a dense hybrid),
+and its three calls.
 
 A layer is ``h + operator(N(h))`` then ``h + feed_forward(N(h))``, ``N``
 RMSNorm with its own weight: the operator grouped-query attention
-(:class:`GroupedHeads`: q and k normalised per head, the half-rotation layout
-where the layer is rotated, full or over a window) unless the model states
-another (:meth:`BlockDecoder._operator`); the feed-forward a dense SwiGLU on
-the ``first_dense`` leading layers and the model's
-:class:`~hetu_tpu.layers.moe.HeldExpertLayer` on the rest.  A layer runs in
-one of three calls, which :class:`LayerCall` describes: the dense forward, a
-prefill chunk over the serving engine's cache layers, a decode round over
-them.
+(:class:`GroupedHeads`: q and k normalised per head where the weights hold
+such norms, the half-rotation layout where the layer is rotated, full or over
+a window) unless the model states another (:meth:`BlockDecoder._operator`:
+a convolution in its place, or a second branch beside it); the feed-forward
+a dense SwiGLU on the ``first_dense`` leading layers and the model's
+:class:`~hetu_tpu.layers.moe.HeldExpertLayer` on the rest (a model without
+one is dense throughout).  A layer runs in one of three calls, which
+:class:`LayerCall` describes: the dense forward, a prefill chunk over the
+serving engine's cache layers, a decode round over them.
 
 :class:`BlockDecoder` is the layer, the three calls, both cache entry points
 of ``hetu_tpu/serve`` and the loss.  A model (``models/exaone_moe.py``: window
 and full attention layers in two cache groups; ``models/lfm2_moe.py``: short
-convolutions with state layers between full attention layers in one group)
-states through the constructor its expert layer and, by layer, where a
-layer's attention leaves and cache layer lie, whether it is rotated and what
-window it has; and itself holds its configuration, its weights (``init``) and
-the cache it asks for (``kv_cache_spec``).  The head is ``lm_head`` where the
-weights have one, else tied to the embedding.
+convolutions with state layers between full attention layers in one group;
+``models/falcon_h1.py``: a state-space branch beside attention in every
+layer, each layer a cache layer AND a state layer) states through the
+constructor its expert layer, the constant factors it scales its products by
+(``multipliers``) and, by layer, where a layer's attention leaves and cache
+layer lie, whether it is rotated and what window it has; and itself holds
+its configuration, its weights (``init``) and the cache it asks for
+(``kv_cache_spec``).  The head is ``lm_head`` where the weights have one,
+else tied to the embedding.
 
 The layers run as a Python loop, not a scan: layers of several kinds with
 caches of several shapes do not scan.  Parameter leaves are stacked over the
@@ -91,9 +96,14 @@ class GroupedHeads:
     """Grouped-query attention's projections for any model whose
     configuration ``self.c`` gives ``num_heads``, ``num_kv_heads``,
     ``head_dim``, ``rms_eps`` and ``dtype`` (:class:`BlockDecoder`'s models;
-    ``models/mellum.py``, which trains): Q and K normalised per head, the
+    ``models/mellum.py``, which trains): Q and K normalised per head where
+    the leaves hold ``q_norm`` / ``k_norm`` (a model without them has no such
+    norm), K times ``multipliers["key"]`` where the model states one, the
     half-rotation layout over the whole head, the out-projection.  ``p`` is
     the attention leaves stacked over layers, ``l`` the layer read."""
+
+    # constant factors a model scales its products by, by name; none here
+    multipliers: dict = {}
 
     def _norm(self, x, scale):
         return ops.rms_norm(x, scale, eps=self.c.rms_eps)
@@ -111,8 +121,9 @@ class GroupedHeads:
 
     def _qkv(self, p, l: int, a, cos, sin, rotate: bool):
         """a [B, S, H] normed -> (q [B, heads, S, D], k [B, S, kv_heads, D],
-        v the same) of layer ``l``: q and k normalised per head, rotated
-        where ``rotate``.  k and v are the rows a cache holds."""
+        v the same) of layer ``l``: q and k normalised per head where the
+        leaves hold the norms, rotated where ``rotate``.  k and v are the
+        rows a cache holds."""
         c, dt = self.c, self.c.dtype
         b, s, _ = a.shape
         q = ops.linear(a, p["q"][l].astype(dt), trans_w=True).reshape(
@@ -121,8 +132,11 @@ class GroupedHeads:
             b, s, c.num_kv_heads, c.head_dim)
         v = ops.linear(a, p["v"][l].astype(dt)).reshape(
             b, s, c.num_kv_heads, c.head_dim)
-        q = self._norm(q, p["q_norm"][l])
-        k = self._norm(k, p["k_norm"][l])
+        if "q_norm" in p:
+            q = self._norm(q, p["q_norm"][l])
+            k = self._norm(k, p["k_norm"][l])
+        if "key" in self.multipliers:
+            k = k * self.multipliers["key"]
         if rotate:
             q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
         return jnp.moveaxis(q, 1, 2), k, v
@@ -138,7 +152,12 @@ class GroupedHeads:
 class BlockDecoder(GroupedHeads, Module):
     """``config`` gives ``num_layers``, ``first_dense``,
     :class:`GroupedHeads`' widths, ``rope_theta`` and ``dtype``; ``moe`` is
-    the model's expert layer.  The tables, each by layer index and holding
+    the model's expert layer (None: every layer is dense, ``first_dense`` =
+    ``num_layers``); ``multipliers`` the constant factors the model scales
+    by, each applied where it is named and nowhere when absent: ``embed`` the
+    embedded rows, ``key`` attention's K, ``gate`` the feed-forward's gate
+    product inside its activation, ``down`` its result, ``head`` the logits.
+    The tables, each by layer index and holding
     the attention layers alone: ``attn_leaf`` the layer's index in the
     stacked attention leaves, ``cache_layer`` its (group, cache layer in the
     group) of the serving cache, ``rotated`` the layers whose q and k are
@@ -154,9 +173,10 @@ class BlockDecoder(GroupedHeads, Module):
     step_stats = MOE_STATS
 
     def __init__(self, config, moe, *, attn_leaf, cache_layer, rotated,
-                 window=None):
+                 window=None, multipliers=None):
         self.c = config
         self.moe = moe
+        self.multipliers = dict(multipliers or {})
         self.scale = config.head_dim ** -0.5
         self.attn_leaf, self.cache_layer = attn_leaf, cache_layer
         self.rotated = frozenset(rotated)
@@ -182,11 +202,14 @@ class BlockDecoder(GroupedHeads, Module):
         return jnp.cos(ang), jnp.sin(ang)
 
     def _ffn(self, p, l: int, x):
-        dt = self.c.dtype
+        dt, m = self.c.dtype, self.multipliers
         with jax.named_scope("hetu.ffn.dense"):
             g = ops.linear(x, p["gate"][l].astype(dt))
+            if "gate" in m:
+                g = g * m["gate"]
             u = ops.linear(x, p["up"][l].astype(dt))
-            return ops.linear(ops.silu(g) * u, p["down"][l].astype(dt))
+            out = ops.linear(ops.silu(g) * u, p["down"][l].astype(dt))
+            return out * m["down"] if "down" in m else out
 
     def _operator(self, p, l: int, a, call: LayerCall):
         """Layer ``l``'s operator on its normed input ``a`` [B, S, H]:
@@ -257,14 +280,18 @@ class BlockDecoder(GroupedHeads, Module):
         return h + m, stats
 
     def _embed(self, p, ids):
-        return ops.embedding_lookup(p["tok_emb"], ids).astype(self.c.dtype)
+        h = ops.embedding_lookup(p["tok_emb"], ids).astype(self.c.dtype)
+        return h * self.multipliers["embed"] if "embed" in self.multipliers \
+            else h
 
     def _head(self, p, h):
         """h: the stream after the last layer -> logits; the head tied to
         the embedding where the weights have no ``lm_head``."""
-        return ops.linear(self._norm(h, p["norm_f"]),
-                          p.get("lm_head", p["tok_emb"]).T.astype(
-                              self.c.dtype))
+        logits = ops.linear(self._norm(h, p["norm_f"]),
+                            p.get("lm_head", p["tok_emb"]).T.astype(
+                                self.c.dtype))
+        return logits * self.multipliers["head"] \
+            if "head" in self.multipliers else logits
 
     # ---- dense forward ----
     def hidden_states(self, variables, input_ids, *, train: bool = False,
@@ -287,9 +314,10 @@ class BlockDecoder(GroupedHeads, Module):
     # k_cache and v_cache are each the cache layers of the model's one group,
     # or a tuple of them, one a group of its ``kv_cache_spec()`` in order,
     # with ``read(layer)`` -> [B, T, kv_heads, D] and ``write(layer, rows)``;
-    # a window group's view is a ring.  Both entry points return a fourth
-    # value, the expert layers' counts (``step_stats`` names them) summed
-    # over the layers.
+    # a window group's view is a ring.  Both entry points of a model with
+    # an expert layer return a fourth value, the expert layers' counts
+    # (``step_stats`` names them) summed over the layers; a model without
+    # one states no ``step_stats`` and returns none.
 
     def _cached(self, p, input_ids, k_cache, v_cache, pos, attention,
                 one_query: bool = False, state=None, last=None):
@@ -297,8 +325,8 @@ class BlockDecoder(GroupedHeads, Module):
         the cache layers (a group's pair, or one pair bare where the model's
         cache has one group) from each sequence's first position
         ``pos[:, 0]`` on, ``attention`` the call's step.  Returns (the
-        stream, new_k, new_v, counts) and, given ``state``, the state
-        last."""
+        stream, new_k, new_v), the counts of a model with an expert layer
+        and, given ``state``, the state last."""
         h = self._embed(p, input_ids)
         bare = not isinstance(k_cache, (tuple, list))
         call = LayerCall(
@@ -311,7 +339,9 @@ class BlockDecoder(GroupedHeads, Module):
             stats = stats + n
         k_cache, v_cache = (call.k[0], call.v[0]) if bare \
             else (tuple(call.k), tuple(call.v))
-        out = (h, k_cache, v_cache, self._counts(stats))
+        out = (h, k_cache, v_cache)
+        if self.moe is not None:
+            out += (self._counts(stats),)
         return out if state is None else out + (call.state,)
 
     def _counts(self, stats):
@@ -324,7 +354,8 @@ class BlockDecoder(GroupedHeads, Module):
                                  state=None):
         """input_ids [B, S_c] at absolute positions ``start..``; positions
         below ``start`` of the caches are written.  Returns (logits [B, V]
-        at chunk-relative ``last_index``, new_k, new_v, counts), and with
+        at chunk-relative ``last_index``, new_k, new_v, counts where the
+        model has an expert layer), and with
         ``state`` (a model with state layers) the state after
         ``last_index`` behind them."""
         p = variables["params"]
@@ -349,8 +380,8 @@ class BlockDecoder(GroupedHeads, Module):
     def decode_with_cache(self, variables, input_ids, k_cache, v_cache,
                           lengths, *, state=None):
         """One decode step; input_ids [B], lengths [B] tokens cached.
-        Returns (logits [B, V], new_k, new_v, counts), and with ``state``
-        the new state behind them."""
+        Returns (logits [B, V], new_k, new_v, counts where the model has an
+        expert layer), and with ``state`` the new state behind them."""
         p = variables["params"]
 
         def attention(q, k_view, v_view, window):

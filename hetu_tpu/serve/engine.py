@@ -9,12 +9,15 @@ What a served model implements: ``prefill_chunk_with_cache`` and
 and either ``kv_cache_spec()`` or the config fields
 :meth:`KVCacheSpec.from_model` reads.  ``models/gpt.py``,
 ``models/llama.py``, ``models/longcat_flash.py``,
-``models/exaone_moe.py`` and ``models/lfm2_moe.py`` do.  A model whose spec
+``models/exaone_moe.py``, ``models/lfm2_moe.py`` and
+``models/falcon_h1.py`` do.  A model whose spec
 states several GROUPS of cache layers (window layers beside full ones) is
 handed a tuple of cache layers in each place, one a group in the spec's
 order, and returns the same.  A model whose spec states STATE LAYERS
 (``KVCacheSpec.state_layers``: a layer that remembers a sequence in a
-fixed-size array, ``models/lfm2_moe.py``'s short convolutions) takes them
+fixed-size array or several, ``models/lfm2_moe.py``'s short convolutions,
+``models/falcon_h1.py``'s convolution rows beside its recurrence's matrix)
+takes them
 as ``state=`` (:class:`~hetu_tpu.serve.kv_cache.SlotStates`) in both entry
 points and returns them as its LAST result, behind its counts.  Optionally
 ``serving_params(params)``: the parameters as those two entry points READ
@@ -56,6 +59,7 @@ row-parallel all-reduces inside both jitted steps.
 
 from __future__ import annotations
 
+import itertools
 from typing import Optional
 
 import jax
@@ -205,8 +209,9 @@ class PagedServeEngine:
     never copied (how that was checked: ``kv_cache.py``'s docstring).
 
     STATE LAYERS (a cache whose spec states any, ``cache.state``): both
-    programs take the state array as a FIFTH argument, donated like the
-    pools, and return it; one more int32 of ``aux`` a sequence names its
+    programs take the state array (a tree of arrays, a part and a layer
+    each, where a state layer keeps several parts) as a FIFTH argument,
+    donated like the pools, and return it; one more int32 of ``aux`` a sequence names its
     slot.  A chunk reads and writes its one slot's state: zeros where it
     starts at position 0, and what it leaves is the state after its last
     REAL token, not after its bucket's padding (the model's ``last_index``).
@@ -325,6 +330,10 @@ class PagedServeEngine:
             ids.update({"state_layers": int(spec.state_layers),
                         "bytes_per_slot": int(spec.bytes_per_slot),
                         "state_bytes": self.cache.state_bytes})
+            # a state layer of several parts: each part's array besides
+            ids.update({f"state_{name}_bytes": sum(int(a.nbytes) for a in held)
+                        for (name, *_), held
+                        in zip(spec.state_parts, self.cache.state)})
         trace.instant("serve.cache_spec", ids)
         trace.instant("serve.params_held", held)
 
@@ -360,9 +369,11 @@ class PagedServeEngine:
         layer, and gauges of the same names), and ``kv_window_released``,
         the pages dropped from behind a window since the last call (a
         counter).  Over STATE LAYERS, besides: ``state_slots_held``, the
-        slots handed out, ``state_bytes``, what their state takes, and
-        ``kv_bytes_held``, the bytes of the pages their tables hold, all
-        groups.  A cache of one group without state: ``ids`` as given."""
+        slots handed out, ``state_bytes``, what their state takes (and
+        ``state_<part>_bytes`` what it takes in each part, where a state
+        layer keeps several), and ``kv_bytes_held``, the bytes of the pages
+        their tables hold, all groups; gauges of the same names.  A cache
+        of one group without state: ``ids`` as given."""
         if not self._more and not self._states:
             return ids
         if self._states:
@@ -370,11 +381,18 @@ class PagedServeEngine:
             n = cache.num_slots - cache.num_free
             # a freed slot's tables are empty
             pages = [sum(map(len, g.tables)) for g in cache.groups]
-            ids = {**(ids or {}), "state_slots_held": n,
-                   "state_bytes": n * cache.spec.bytes_per_slot,
-                   "kv_bytes_held": cache.page_size * sum(
-                       g.spec.bytes_per_token * held
-                       for g, held in zip(cache.groups, pages))}
+            held = {"state_slots_held": n,
+                    "state_bytes": n * cache.spec.bytes_per_slot,
+                    "kv_bytes_held": cache.page_size * sum(
+                        g.spec.bytes_per_token * held
+                        for g, held in zip(cache.groups, pages))}
+            if cache.spec.state_parts:
+                held.update(
+                    (f"state_{name}_bytes", n * each) for name, each
+                    in cache.spec.part_bytes_per_slot.items())
+            for name, value in held.items():
+                self.metrics.set_gauge(name, value)
+            ids = {**(ids or {}), **held}
             if not self._more:
                 ids["kv_pages_full"] = pages[0] * cache.spec.num_layers
                 return ids
@@ -949,26 +967,25 @@ class PagedServeEngine:
                 if self._decode_fn is None:
                     self._decode_fn = self._build_decode()
                 cow0 = self.cache.cow_copies
-                bb = pow2_ceil(len(act), self.cache.num_slots)
+                n = len(act)
+                bb = pow2_ceil(n, self.cache.num_slots)
                 sl = np.zeros(bb, np.int32)
-                sl[:len(act)] = act
+                sl[:n] = act
                 # grow/COW the write target of every active slot BEFORE
                 # the step
                 wp = np.zeros((len(self.cache.groups), bb), np.int32)
                 wo = np.zeros(bb, np.int32)
-                for i, slot in enumerate(act):
-                    p, o = self.cache.prepare_write(
-                        int(slot), int(self.cache.lengths[slot]), 1)
-                    wp[:, i], wo[i] = [g[0] for g in p], o[0]
-                # page bucket over ACTIVE slots only (after prepare_write
+                wp[:, :n], wo[:n] = self.cache.prepare_round(act)
+                # page bucket over ACTIVE slots only (after prepare_round
                 # grew them): an inactive mid-chunked-prefill long prompt
                 # must not inflate every interleaved decode's gather to its
                 # table width — that would re-create exactly the
                 # long-arrival latency spike the chunk interleave exists to
                 # remove
-                n_pg = pow2_ceil(
-                    max(len(self.cache.tables[int(s)]) for s in act),
-                    self.cache.pages_per_slot)
+                tables = [self.cache.tables[s] for s in act.tolist()]
+                widths = np.fromiter(map(len, tables), np.int64, n)
+                n_pg = pow2_ceil(int(widths.max()),
+                                 self.cache.pages_per_slot)
                 if (bb, n_pg) not in self._seen_page_buckets:
                     self._seen_page_buckets.add((bb, n_pg))
                     self.metrics.inc("decode_compiles")
@@ -978,8 +995,13 @@ class PagedServeEngine:
                 aux = np.zeros((bb, n_pg + 4 + sum(
                     ring + 1 for ring in self._ring_decode) + self._states),
                     np.int32)
-                for i, slot in enumerate(sl):
-                    t = self.cache.tables[slot][:n_pg]
+                # the active slots' tables in ONE assignment (row by row,
+                # 64 rows were a fifth of a millisecond a round)
+                aux[:n, :n_pg][np.arange(n_pg) < widths[:, None]] = \
+                    np.fromiter(itertools.chain.from_iterable(tables),
+                                np.int32, int(widths.sum()))
+                for i in range(n, bb):    # pad rows: slot 0's, harmless
+                    t = self.cache.tables[0][:n_pg]
                     aux[i, :len(t)] = t
                 aux[:, n_pg] = self.cache.lengths[sl]
                 aux[:, n_pg + 1] = self.last_tokens[sl]
@@ -1002,18 +1024,21 @@ class PagedServeEngine:
                             {"pages": int(n_pg), "batch": int(bb),
                              "seq": self._seq}):
                 k, v, nxt, stats, *state = self._decode_fn(
-                    self.params, k_pool, v_pool, jnp.asarray(aux), *state)
+                    self.params, k_pool, v_pool, aux, *state)
+                # the copies to the host queued behind the program, not
+                # asked for once the host has seen it end
+                for result in (nxt, *stats):
+                    result.copy_to_host_async()
             with trace.span("serve.decode.fetch", {"seq": self._seq}):
+                # the books that need no token, while the device runs
+                held = self._held(None, act, self.cache.lengths[act] + 1)
                 nxt = np.asarray(nxt)  # the host blocked on the device
-                counts = self._held(self._count(stats), act,
-                                     self.cache.lengths[act] + 1)
+                counts = {**(self._count(stats) or {}), **held}
             with trace.span("serve.decode.post", counts):
                 self.cache.update(k, v, *state)
-                out = {}
-                for i, slot in enumerate(act):
-                    self.cache.lengths[slot] += 1
-                    self.last_tokens[slot] = nxt[i]
-                    out[int(slot)] = int(nxt[i])
+                self.cache.lengths[act] += 1
+                self.last_tokens[act] = nxt[:n]
+                out = dict(zip(act.tolist(), nxt[:n].tolist()))
                 if self.cache.cow_copies > cow0:
                     self.metrics.inc("cow_copies",
                                      self.cache.cow_copies - cow0)

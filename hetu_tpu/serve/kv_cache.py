@@ -7,8 +7,9 @@ pair for each GROUP of cache layers the model's :class:`KVCacheSpec` states
 two, and its window group keeps only the pages a future query can still
 see), beside them the STATE LAYERS of a model whose spec states any (a
 layer that remembers a sequence in a fixed-size array, not in rows a token:
-a short convolution's last inputs; arrays ``[state layers, slots + 1, *shape]``
-that a slot owns whole, never paged, never shared, read as zeros by the
+a short convolution's last inputs, a state-space recurrence's matrix;
+arrays ``[state layers, slots + 1, *shape]``, or ``[slots + 1, *shape]`` a
+layer for each part a layer keeps, rows that a slot owns whole, never paged, never shared, read as zeros by the
 chunk that starts a sequence: :class:`SlotStates`),
 per-request page tables a group, refcounted PREFIX SHARING (hash-of-token-prefix
 → shared read-only pages, so identical system prompts across a pool's
@@ -86,12 +87,19 @@ class KVCacheSpec:
 
     **State layers.**  A third kind of slot state, beside the pages that
     keep every position and a window group's ring: ``state_layers`` layers
-    each remember a sequence in ONE array of ``state_shape`` (in
-    ``state_dtype``; None: ``dtype``), whatever the sequence's length, so
-    they cost :attr:`bytes_per_slot` a slot and nothing a token.  The cache
-    holds them as one array a slot owns whole (:class:`SlotStates`); only
-    the spec a model hands over states them (a group under ``also`` has
-    none of its own)."""
+    each remember a sequence in arrays of fixed shape, whatever the
+    sequence's length, so they cost :attr:`bytes_per_slot` a slot and
+    nothing a token.  A layer that keeps ONE array states ``state_shape``
+    (in ``state_dtype``; None: ``dtype``); one that keeps SEVERAL states
+    ``state_parts``, each ``(name, shape, dtype)`` (a convolution's last
+    rows in the compute type beside a recurrence's float32 matrix), and
+    every state layer keeps every part.  :attr:`parts` reads either form.
+    The cache holds them as rows a slot owns whole (:class:`SlotStates`):
+    one array ``[state layers, slots + 1, *state_shape]`` where the spec
+    states ``state_shape``; where it states ``state_parts`` a tuple, in the
+    parts' order, of tuples of the layers' arrays ``[slots + 1, *shape]``;
+    only the spec a model hands over states them (a group under ``also``
+    has none of its own)."""
 
     num_layers: int
     num_kv_heads: int
@@ -103,6 +111,27 @@ class KVCacheSpec:
     state_layers: int = 0
     state_shape: tuple = ()
     state_dtype: object = None
+    state_parts: tuple = ()
+
+    @property
+    def parts(self) -> tuple:
+        """((name, shape, dtype), ...) of what ONE state layer keeps of a
+        slot: ``state_parts`` as stated, or the one array ``state_shape``
+        under the name ``state``; empty without state layers."""
+        if not self.state_layers:
+            return ()
+        if self.state_parts:
+            return tuple((str(n), tuple(sh), np.dtype(dt))
+                         for n, sh, dt in self.state_parts)
+        return (("state", tuple(self.state_shape),
+                 np.dtype(self.state_dtype or self.dtype)),)
+
+    @property
+    def part_bytes_per_slot(self) -> dict:
+        """By part's name, the bytes one slot's state takes in it over the
+        state layers."""
+        return {n: self.state_layers * int(np.prod(sh, dtype=int))
+                * dt.itemsize for n, sh, dt in self.parts}
 
     @property
     def groups(self) -> tuple:
@@ -112,10 +141,9 @@ class KVCacheSpec:
 
     @property
     def bytes_per_slot(self) -> int:
-        """Bytes one slot's state takes over the state layers, whatever the
-        sequence's length; 0 for a model with none."""
-        return (self.state_layers * int(np.prod(self.state_shape, dtype=int))
-                * np.dtype(self.state_dtype or self.dtype).itemsize)
+        """Bytes one slot's state takes over the state layers, every part,
+        whatever the sequence's length; 0 for a model with none."""
+        return sum(self.part_bytes_per_slot.values())
 
     @property
     def v_dim(self) -> int:
@@ -271,35 +299,107 @@ class SlotStates:
     cache entry points (their ``state=`` argument, handed back as their last
     result): ``rows`` ``[state layers, num_slots + 1, *state_shape]``, the
     cache's array itself (donated, carried through the layers, updated in
-    place); ``slots`` ``[B]`` int32, the slot of each of the step's
+    place), or where a state layer keeps several parts
+    (``KVCacheSpec.state_parts``) a tuple, one a part, each a tuple of the
+    LAYERS' arrays ``[num_slots + 1, *shape]``; ``slots`` ``[B]`` int32, the slot of each of the step's
     sequences, a bucket's padding row naming the SCRATCH slot ``num_slots``,
     which no request owns; ``fresh`` ``[B]`` bool, or None in a decode round:
     the sequence starts in this step (a chunk at position 0), so its state
-    READS as zeros whatever the slot's last owner left there.  A state layer
-    of the model reads its own layer's rows and writes them back; what it
-    writes is the state after the step's last REAL token (a chunk is padded
-    to its bucket: the model's ``last_index`` says where that is)."""
+    READS as zeros whatever the slot's last owner left there, in every
+    part.  A state layer of the model reads its own layer's rows and writes
+    them back; what it writes is the state after the step's last REAL token
+    (a chunk is padded to its bucket: the model's ``last_index`` says where
+    that is).
 
-    rows: jax.Array
+    Two ways to a part's rows.  BY SEQUENCE (:meth:`read`, :meth:`write`):
+    the step's sequences' rows gathered by slot and scattered back; one
+    sequence's row (a chunk) is cut out and put back where it lies, but the
+    rows of MANY are a gather, which the TPU's compiler runs over a copy of
+    the whole array, so this is for parts that are small (a convolution's
+    last rows).  A LAYER WHOLE (:meth:`whole`, :meth:`put_whole`), for a
+    decode round over a part that is large (a recurrence's matrix, 4 MB a
+    slot a layer): the model computes every slot's row at once, its small
+    per-sequence inputs laid out by slot (:meth:`spread`; a slot of no
+    sequence of the step gets zeros, and the model's update must leave such
+    a row as it is) and its per-slot results read back by sequence
+    (:meth:`pick`); the update is one elementwise pass over the layer's
+    array in place, each row read and written once.  That is why the parts
+    of a ``state_parts`` spec are held a layer an array: a layer's rows
+    rewritten whole inside ONE array over the layers is a chain of in-place
+    updates of 1.6 GB values, which the TPU compiler's rematerialisation,
+    blind to the aliasing, computed twice (PR 47, ``PERF.md`` section 6)."""
+
+    rows: object
     slots: jax.Array
     fresh: Optional[jax.Array] = None
 
-    def read(self, layer):
-        """State layer ``layer`` of the step's sequences, ``[B,
-        *state_shape]``."""
-        rows = self.rows[layer, self.slots]
+    def _part(self, part):
+        return self.rows if part is None else self.rows[part]
+
+    def _with(self, part, new):
+        return replace(self, rows=new if part is None else
+                       self.rows[:part] + (new,) + self.rows[part + 1:])
+
+    def _read(self, held, layer):
+        # one array over the layers, or a layer an array
+        rows = held[layer][self.slots] if isinstance(held, tuple) \
+            else held[layer, self.slots]
         if self.fresh is None:
             return rows
         fresh = self.fresh.reshape((-1,) + (1,) * (rows.ndim - 1))
         return jnp.where(fresh, 0, rows)
 
-    def write(self, layer, rows):
+    def _write(self, held, layer, new):
+        if isinstance(held, tuple):
+            one = held[layer]
+            return held[:layer] + (one.at[self.slots].set(
+                new.astype(one.dtype)),) + held[layer + 1:]
+        return held.at[layer, self.slots].set(new.astype(held.dtype))
+
+    def read(self, layer, part=None):
+        """State layer ``layer`` of the step's sequences, ``[B,
+        *state_shape]``; a tuple of them, one a part, where ``rows`` is, or
+        of these the part of index ``part`` alone."""
+        if part is None and isinstance(self.rows, tuple):
+            return tuple(self._read(held, layer) for held in self.rows)
+        return self._read(self._part(part), layer)
+
+    def write(self, layer, rows, part=None):
         """The step's sequences' new state ``[B, *state_shape]`` of state
-        layer ``layer``, each into its own slot (padding rows all into the
-        scratch slot, where the last one written stays and nothing reads
-        it)."""
-        return replace(self, rows=self.rows.at[layer, self.slots].set(
-            rows.astype(self.rows.dtype)))
+        layer ``layer`` (a tuple of them, one a part, where ``rows`` is; the
+        part of index ``part`` alone where given), each into its own slot
+        (padding rows all into the scratch slot, where the last one written
+        stays and nothing reads it)."""
+        if part is None and isinstance(self.rows, tuple):
+            return replace(self, rows=tuple(
+                self._write(held, layer, new)
+                for held, new in zip(self.rows, rows)))
+        return self._with(part, self._write(self._part(part), layer, rows))
+
+    def whole(self, layer, part):
+        """Every slot's row of part ``part`` of state layer ``layer``,
+        ``[num_slots + 1, *shape]``, the scratch slot's last."""
+        return self.rows[part][layer]
+
+    def put_whole(self, layer, part, rows):
+        """:meth:`whole`'s rows, every slot's, in the layer's place: the
+        layer's own array, so a program that computed them from
+        :meth:`whole`'s writes them where it read them."""
+        held = self.rows[part]
+        return self._with(part, held[:layer] + (
+            rows.astype(held[layer].dtype),) + held[layer + 1:])
+
+    def spread(self, values, like):
+        """Per-sequence ``values`` ``[B, ...]`` laid out by slot beside
+        :meth:`whole`'s rows ``like``, ``[num_slots + 1, ...]``: zeros at
+        every slot of no sequence of the step."""
+        return jnp.zeros(like.shape[:1] + values.shape[1:], values.dtype) \
+            .at[self.slots].set(values)
+
+    def pick(self, values):
+        """Per-slot ``values`` ``[num_slots + 1, ...]`` of the step's
+        sequences, ``[B, ...]``."""
+        return values[self.slots]
 
 
 class PagePoolExhausted(RuntimeError):
@@ -481,7 +581,9 @@ class PagedKVCache:
     the first group's page count alone.
 
     STATE LAYERS (``spec.state_layers``; :attr:`state`, None without any):
-    one array ``[state layers, num_slots + 1, *state_shape]`` that the
+    one array ``[state layers, num_slots + 1, *state_shape]``, or for a spec
+    of ``state_parts`` a tuple, one a part, of tuples of the layers' arrays
+    ``[num_slots + 1, *shape]``, that the
     engine's two programs take donated beside the pools and hand back
     (:class:`SlotStates`, :meth:`update`).  Row ``slot`` is that slot's and
     nobody else's: never paged, never shared, in no prefix entry (so the
@@ -554,9 +656,16 @@ class PagedKVCache:
             if sharding is not None:
                 raise ValueError("state layers over a mesh are not laid out")
             # one row more than slots: the scratch slot of padding rows
-            self.state = jnp.zeros(
-                (spec.state_layers, self.num_slots + 1)
-                + tuple(spec.state_shape), spec.state_dtype or spec.dtype)
+            rows = self.num_slots + 1
+            if spec.state_parts:    # a part a tuple of its layers' arrays
+                self.state = tuple(
+                    tuple(jnp.zeros((rows,) + shape, dtype)
+                          for _ in range(spec.state_layers))
+                    for _, shape, dtype in spec.parts)
+            else:
+                (_, shape, dtype), = spec.parts
+                self.state = jnp.zeros((spec.state_layers, rows) + shape,
+                                       dtype)
             max_prefix_entries = 0   # an entry would need the state AT its
             #                          boundary, which nobody keeps
         self.lengths = np.zeros(self.num_slots, np.int32)
@@ -611,9 +720,10 @@ class PagedKVCache:
 
     @property
     def state_bytes(self) -> int:
-        """Bytes the state layers' array takes on the device (every slot's
-        and the scratch row's), 0 without state layers."""
-        return 0 if self.state is None else int(self.state.nbytes)
+        """Bytes the state layers' arrays take on the device (every slot's
+        and the scratch row's, every part), 0 without state layers."""
+        return sum(int(a.nbytes)
+                   for a in jax.tree_util.tree_leaves(self.state))
 
     @property
     def window_released(self) -> int:
@@ -756,22 +866,54 @@ class PagedKVCache:
         for gi, g in enumerate(self.groups):
             if g.window is not None:
                 g.release_behind(slot, start, ps)
-            table = g.tables[slot]
             pages = np.empty(n, np.int32)
             for pi in range(start // ps, (start + n - 1) // ps + 1):
-                idx = pi - int(g.base[slot])
-                if idx == len(table):
-                    table.append(self._alloc_page(slot, gi))
-                elif not 0 <= idx < len(table):
-                    raise AssertionError(
-                        f"write at page {pi} skips pages (table holds "
-                        f"{int(g.base[slot])}..+{len(table)})")
-                page = table[idx]
-                if g.ref_table[page] + g.ref_index[page] > 1:
-                    page = self._cow(slot, idx, gi)
-                pages[max(pi * ps - start, 0):(pi + 1) * ps - start] = page
+                pages[max(pi * ps - start, 0):(pi + 1) * ps - start] = \
+                    self._writable_page(slot, pi, gi)
             by_group.append(pages)
         return by_group, offs
+
+    def _writable_page(self, slot: int, pi: int, group: int) -> int:
+        """The physical page that holds logical page ``pi`` of ``slot`` in
+        ``group``, made writable: claimed where the table ends just before
+        it, copied first where it is shared."""
+        g = self.groups[group]
+        table = g.tables[slot]
+        idx = pi - int(g.base[slot])
+        if idx == len(table):
+            table.append(self._alloc_page(slot, group))
+        elif not 0 <= idx < len(table):
+            raise AssertionError(
+                f"write at page {pi} skips pages (table holds "
+                f"{int(g.base[slot])}..+{len(table)})")
+        page = table[idx]
+        if g.ref_table[page] + g.ref_index[page] > 1:
+            page = self._cow(slot, idx, group)
+        return page
+
+    def prepare_round(self, slots):
+        """:meth:`prepare_write` of ONE position, its next, for each of
+        ``slots`` in turn (a decode round's): the same pages claimed, copied
+        and dropped in the same order, the same errors, and as safe to
+        repeat; one pass in plain ints, where sixty-four calls build five
+        small arrays each.  Returns ``(write_pages, write_off)``: an int32
+        array ``[groups, len(slots)]`` of each slot's physical page a group,
+        and the offsets in the page, which all groups share."""
+        ps = self.page_size
+        starts = self.lengths[slots]
+        if len(starts) and int(starts.max()) >= self.max_len:
+            start = int(starts.max())
+            raise ValueError(f"write [{start}, {start + 1}) overruns "
+                             f"max_len {self.max_len}")
+        pages = []
+        for slot, start in zip(slots.tolist(), starts.tolist()):
+            for gi, g in enumerate(self.groups):
+                if g.window is not None:
+                    g.release_behind(slot, start, ps)
+                pages.append(self._writable_page(slot, start // ps, gi))
+        pages = np.array(pages, np.int32).reshape(len(starts),
+                                                  len(self.groups))
+        return pages.T, (starts % ps).astype(np.int32)
 
     def padded_write_map(self, pages, offs, total: int):
         """Extend a :meth:`prepare_write` map (one group's pages, the

@@ -6,6 +6,8 @@ checks ``test_paged_kv.py`` (GPT, Llama) and ``test_longcat_flash.py`` share
 to, both independent of any engine, and the hold that lets a pool test
 catch a member mid-decode.  A helper module, no tests of its own."""
 
+import hashlib
+import re
 import threading
 import time
 
@@ -40,6 +42,36 @@ def _results(jaxpr):
                 yield eqn.primitive.name, tuple(shape)
         for sub in _sub_jaxprs(eqn.params):
             yield from _results(sub)
+
+
+def _holds_jaxpr(value) -> bool:
+    return any(hasattr(getattr(x, "jaxpr", x), "eqns") for x in
+               (value if isinstance(value, (tuple, list)) else (value,)))
+
+
+def _equations(jaxpr):
+    """Every equation, the bodies of loops and calls included, as (its
+    primitive, its operands' types, a literal's value, its results' types,
+    its parameters but for inner jaxprs and addresses)."""
+    for eqn in jaxpr.eqns:
+        params = sorted((k, re.sub(r" at 0x[0-9a-f]+", "", repr(v)))
+                        for k, v in eqn.params.items() if not _holds_jaxpr(v))
+        yield repr((eqn.primitive.name,
+                    [str(v.val) if isinstance(v, jax.extend.core.Literal)
+                     else str(v.aval) for v in eqn.invars],
+                    [str(v.aval) for v in eqn.outvars], params))
+        for sub in _sub_jaxprs(eqn.params):
+            yield from _equations(sub)
+
+
+def program_digest(jaxpr) -> str:
+    """A traced program as the MULTISET of its equations, hashed: equal for
+    two traces of one program, whatever names the tracer gave the values
+    and whatever order it closed over constants in (both vary from process
+    to process under ``jax.grad``); any equation added, dropped or changed
+    in a type or a parameter changes it."""
+    return hashlib.sha256(
+        "\n".join(sorted(_equations(jaxpr))).encode()).hexdigest()
 
 
 def program(engine, name: str, *, batch: int, chunk: int, params=None):
@@ -234,6 +266,13 @@ def tiny_model(kind: str):
             num_kv_heads=2, head_dim=32, ffn_size=256, expert_ffn_size=128,
             first_dense=2, n_routed_experts=8, moe_topk=4, max_position=256,
             dtype=bf16, param_dtype=bf16, expert_block_rows=24))
+    elif kind == "falcon":
+        from hetu_tpu.models.falcon_h1 import FalconH1Config, FalconH1Model
+        model = FalconH1Model(FalconH1Config(
+            vocab_size=96, hidden_size=128, num_layers=3, num_heads=4,
+            num_kv_heads=2, head_dim=32, ffn_size=256, ssm_heads=4,
+            ssm_head_dim=16, ssm_state=16, ssm_groups=2, ssm_chunk=8,
+            max_position=256, dtype=bf16, param_dtype=bf16))
     else:
         from hetu_tpu.models.longcat_flash import (
             LongcatFlashConfig, LongcatFlashModel,
@@ -266,15 +305,18 @@ class LogitsOut:
     def __getattr__(self, name):
         return getattr(self.model, name)
 
-    # a model's own counts are dropped; its state (a model with state
-    # layers returns it last, behind its counts) is handed on
+    # a model's own counts (one that names any returns them fourth) are
+    # dropped; its state (a model with state layers returns it last) is
+    # handed on
+    def _out(self, logits, k, v, *rest):
+        return (logits, k, v, logits,
+                *rest[bool(getattr(self.model, "step_stats", ())):])
+
     def prefill_chunk_with_cache(self, *args, **kw):
-        logits, k, v, *rest = self.model.prefill_chunk_with_cache(*args, **kw)
-        return (logits, k, v, logits, *rest[1:])
+        return self._out(*self.model.prefill_chunk_with_cache(*args, **kw))
 
     def decode_with_cache(self, *args, **kw):
-        logits, k, v, *rest = self.model.decode_with_cache(*args, **kw)
-        return (logits, k, v, logits, *rest[1:])
+        return self._out(*self.model.decode_with_cache(*args, **kw))
 
 
 def engine_logits(model, variables, prompt, n: int, *, as_given=False,

@@ -21,7 +21,9 @@ from hetu_tpu.models.llama import LlamaConfig, LlamaModel
 from hetu_tpu.serve import (
     ContinuousBatchingScheduler, PagedServeEngine, Request,
 )
-from hetu_tpu.serve.kv_cache import PagedLayers
+from hetu_tpu.serve.kv_cache import (
+    KVCacheSpec, PagedKVCache, PagedLayers, SlotStates,
+)
 from hetu_tpu.telemetry import trace
 from paged_programs import engine_greedy as _engine_greedy
 from paged_programs import (
@@ -711,7 +713,7 @@ def test_retyped_leaves_keep_the_megatron_placement(kind):
 SERVED = ["gpt", "exaone", "lfm2", "longcat"]
 
 
-@pytest.mark.parametrize("kind", SERVED)
+@pytest.mark.parametrize("kind", SERVED + ["falcon"])
 def test_logits_over_the_held_layout_equal_those_over_given_leaves(kind):
     """Where the bytes of a projection weight lie changes no logit: every
     chunk's and every decode round's logits, out of the engine's own
@@ -724,7 +726,7 @@ def test_logits_over_the_held_layout_equal_those_over_given_leaves(kind):
     held, engine = engine_logits(model, variables, prompt, 4, **kw)
     given, _ = engine_logits(model, variables, prompt, 4, as_given=True, **kw)
     assert engine.metrics.snapshot()["params_relaid"] == \
-        {"gpt": 1, "exaone": 4, "lfm2": 4, "longcat": 2}[kind]
+        {"gpt": 1, "exaone": 4, "lfm2": 4, "longcat": 2, "falcon": 0}[kind]
     assert len(held) == len(given) == 3 + 3
     for a, b in zip(held, given):
         assert a.shape[-1] == 96 and np.array_equal(a, b)
@@ -766,6 +768,108 @@ def test_the_held_layout_pins_no_leaf_it_was_given(kind):
     assert not any(id(a) in ids.values() for a in jax.live_arrays())
     assert sum(a.nbytes for a in _leaves(engine.params).values()) \
         == engine.metrics.snapshot()["params_bytes_given"]
+
+
+def test_a_model_that_yields_its_matrices_a_layer_an_array_is_held_as_given():
+    """Falcon-H1's ``init`` yields each matrix of a layer as a tuple of the
+    layers' arrays: the engine re-holds none, every leaf it serves from IS
+    the array given, so a caller that keeps its tree holds nothing twice."""
+    model, variables, kw = tiny_served("falcon")
+    engine = PagedServeEngine(model, variables, **kw)
+    given, held = _leaves(variables["params"]), _leaves(engine.params)
+    assert set(given) == set(held) and all(held[p] is given[p] for p in held)
+    assert isinstance(engine.params["layers"]["ffn"]["gate"], tuple)
+    snap = engine.metrics.snapshot()
+    assert snap["params_relaid"] == 0
+    assert snap["params_bytes_held"] == snap["params_bytes_given"]
+
+
+# ---- a state layer of several parts (ISSUE 47) ----
+
+def _two_part_states(fresh=None):
+    """Two layers, four slots; a part a tuple of its layers' arrays."""
+    conv = jnp.arange(2 * 4 * 3, dtype=jnp.bfloat16).reshape(2, 4, 3) + 1
+    ssm = jnp.arange(2 * 4 * 2 * 5, dtype=jnp.float32).reshape(2, 4, 2, 5) + 1
+    return conv, ssm, SlotStates((tuple(conv), tuple(ssm)),
+                                 jnp.asarray([2, 0]), fresh)
+
+
+def test_slot_states_of_two_parts_read_zeros_where_fresh_in_both():
+    conv, ssm, st = _two_part_states(jnp.asarray([True, False]))
+    got_conv, got_ssm = st.read(1)
+    assert got_conv.dtype == jnp.bfloat16 and got_ssm.dtype == jnp.float32
+    for got, held in ((got_conv, conv), (got_ssm, ssm)):
+        np.testing.assert_array_equal(np.asarray(got[0], np.float32), 0.0)
+        np.testing.assert_array_equal(got[1], held[1, 0])
+    # one part alone
+    np.testing.assert_array_equal(st.read(1, 1), got_ssm)
+
+
+@pytest.mark.parametrize("part", [None, 0, 1])
+def test_slot_states_of_two_parts_write_each_part_in_its_own_dtype(part):
+    conv, ssm, st = _two_part_states()
+    new = (jnp.full((2, 3), -1.0), jnp.full((2, 2, 5), -2.0))
+    out = st.write(0, new if part is None else new[part], part)
+    for i, (held, was) in enumerate(zip(out.rows, (conv, ssm))):
+        assert isinstance(held, tuple) and held[0].dtype == was.dtype
+        value = -1.0 - i
+        for slot in range(4):
+            row = np.asarray(held[0][slot], np.float32)
+            if part in (None, i) and slot in (0, 2):
+                assert (row == value).all()
+            else:
+                np.testing.assert_array_equal(
+                    row, np.asarray(was[0, slot], np.float32))
+        assert held[1] is st.rows[i][1]       # the other layer: untouched
+
+
+def test_slot_states_hand_a_large_part_over_a_layer_whole():
+    """A decode round's way to a part too large to gather: every slot's row
+    of a layer, the step's small inputs laid out by slot with zeros where no
+    sequence is, its results read back by sequence, and the layer's array
+    put back whole."""
+    conv, ssm, st = _two_part_states()
+    rows = st.whole(1, 1)
+    np.testing.assert_array_equal(rows, ssm[1])
+    spread = st.spread(jnp.asarray([[5.0, 6.0], [7.0, 8.0]]), rows)
+    np.testing.assert_array_equal(
+        spread, [[7.0, 8.0], [0.0, 0.0], [5.0, 6.0], [0.0, 0.0]])
+    np.testing.assert_array_equal(st.pick(jnp.arange(4.0) * 10), [20.0, 0.0])
+    out = st.put_whole(1, 1, rows * 2)
+    np.testing.assert_array_equal(out.rows[1][1], ssm[1] * 2)
+    assert out.rows[1][0] is st.rows[1][0] and out.rows[0] is st.rows[0]
+
+
+def test_a_cache_of_a_two_part_spec_holds_an_array_a_part_a_layer():
+    spec = KVCacheSpec(2, 2, 8, state_layers=3, state_parts=(
+        ("conv", (18,), jnp.bfloat16), ("ssm", (2, 4, 5), jnp.float32)))
+    assert spec.part_bytes_per_slot == {"conv": 3 * 18 * 2,
+                                        "ssm": 3 * 40 * 4}
+    assert spec.bytes_per_slot == 3 * (36 + 160)
+    cache = PagedKVCache(spec, 4, 64, page_size=4)
+    conv, ssm = cache.state
+    assert [(a.shape, a.dtype) for a in conv] == 3 * [((5, 18), jnp.bfloat16)]
+    assert [(a.shape, a.dtype) for a in ssm] \
+        == 3 * [((5, 2, 4, 5), jnp.float32)]
+    assert cache.state_bytes == 5 * spec.bytes_per_slot
+    assert cache.max_prefix_entries == 0
+    # one array over the layers, as before, for a spec of one shape
+    one = KVCacheSpec(2, 2, 8, state_layers=3, state_shape=(2, 6))
+    assert one.parts == (("state", (2, 6), np.dtype("float32")),)
+    assert PagedKVCache(one, 4, 64, page_size=4).state.shape == (3, 5, 2, 6)
+    assert KVCacheSpec(2, 2, 8).parts == () \
+        and KVCacheSpec(2, 2, 8).bytes_per_slot == 0
+
+
+def test_state_layers_over_a_mesh_stay_refused_whatever_their_parts():
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    spec = KVCacheSpec(2, 2, 8, state_layers=1, state_parts=(
+        ("conv", (3, 6), jnp.float32), ("ssm", (2, 4, 5), jnp.float32)))
+    mesh = ht.make_mesh(tp=2)
+    with pytest.raises(ValueError, match="state layers over a mesh"):
+        PagedKVCache(spec, 4, 64, page_size=4,
+                     sharding=NamedSharding(mesh, P()))
 
 
 def test_a_leaf_held_by_layer_or_transposed_keeps_its_other_axes_split():
@@ -908,6 +1012,55 @@ def test_a_prefix_entry_needs_every_groups_pages():
         pass
     assert cache.pages_in_use == 0
     assert not np.any(win.ref_index) and not np.any(full.ref_index)
+
+
+def _books(cache):
+    return [(g.tables, g.base.tolist(), g.free_pages, g.ref_table.tolist(),
+             g.ref_index.tolist(), g.released) for g in cache.groups] \
+        + [cache.cow_copies, cache.lengths.tolist()]
+
+
+@pytest.mark.parametrize("window", [8, None])
+def test_a_rounds_write_map_is_each_slots_own_in_turn(window):
+    """``prepare_round`` over the active slots leaves the books, and hands
+    back the pages and offsets, that ``prepare_write`` of one position does
+    slot by slot: pages claimed at a boundary, a shared tail copied first,
+    a window's pages dropped, twice over without a step between."""
+    def grown():
+        cache = _grouped_cache(window=window or 8, slots=4) if window \
+            else _grouped_cache(slots=4, layers=(1, 1), window=64)
+        tokens = list(range(100, 122))
+        first = cache.alloc()
+        for start in (0, 8, 16):
+            _write(cache, first, start, min(8, 22 - start))
+        cache.register_prefix(first, tokens)
+        second = cache.alloc()
+        n, pages = cache.match_prefix(tokens)
+        cache.adopt_prefix(second, n, pages)       # shares a partial tail
+        third = cache.alloc()
+        _write(cache, third, 0, 3)
+        return cache, np.array([first, second, third])
+
+    one, act = grown()
+    many, _ = grown()
+    assert _books(one) == _books(many)
+    for _ in range(14):
+        each = [one.prepare_write(int(s), int(one.lengths[s]), 1)
+                for s in act]
+        pages, offs = many.prepare_round(act)
+        again = many.prepare_round(act)             # safe to repeat
+        assert pages.dtype == offs.dtype == np.int32
+        assert pages.shape == (2, 3) and offs.shape == (3,)
+        assert pages.tolist() == again[0].tolist() == [
+            [int(p[g][0]) for p, _ in each] for g in range(2)]
+        assert offs.tolist() == [int(o[0]) for _, o in each]
+        for cache in (one, many):
+            cache.lengths[act] += 1
+        assert _books(one) == _books(many)
+    assert many.cow_copies >= 2
+    many.lengths[act[0]] = many.max_len
+    with pytest.raises(ValueError, match="overruns max_len"):
+        many.prepare_round(act)
 
 
 def test_a_grouped_slot_freed_twice_raises():

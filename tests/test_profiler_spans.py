@@ -590,6 +590,76 @@ def test_a_served_expert_walk_says_which_path_computed_its_pairs(
         e[3]["moe_grouped"] for e in rounds + chunks)
 
 
+# ------------------------------- a state layer of several parts (ISSUE 47)
+
+def test_a_two_part_states_ids_and_the_mixers_plan_and_scopes(tmp_path):
+    """A model whose every layer keeps a convolution's rows AND a
+    recurrence's matrix: ``serve.cache_spec`` and the ``post`` span of every
+    decode round and prefill chunk state the state by part beside the pages;
+    ONE ``ssm.plan`` instant a program traced says which form it holds (a
+    chunk scans, a round steps); the programs carry the mixer's scopes; a
+    scheduler step holds no span more than a model without state."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.falcon_h1 import FalconH1Config, FalconH1Model
+
+    model = FalconH1Model(FalconH1Config(
+        vocab_size=97, hidden_size=32, num_layers=3, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_size=64, ssm_heads=4, ssm_head_dim=8,
+        ssm_state=16, ssm_groups=2, ssm_chunk=8, max_position=128,
+        dtype=jnp.float32, param_dtype=jnp.float32))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=128,
+                               page_size=4, prefill_chunk=8, min_bucket=4)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    conv, ssm = (sum(a.nbytes for a in part) for part in eng.cache.state)
+    per = eng.cache.spec.part_bytes_per_slot
+    assert spec[3]["state_layers"] == 3
+    assert spec[3]["bytes_per_slot"] == per["conv"] + per["ssm"]
+    assert spec[3]["state_conv_bytes"] == conv == 5 * per["conv"]
+    assert spec[3]["state_ssm_bytes"] == ssm == 5 * per["ssm"]
+    assert spec[3]["state_bytes"] == conv + ssm
+    posts = _named(events, "serve.decode.post") \
+        + _named(events, "serve.prefill_chunk.post")
+    keys = {"state_slots_held", "state_bytes", "state_conv_bytes",
+            "state_ssm_bytes", "kv_bytes_held", "kv_pages_full"}
+    assert posts and all(set(e[3]) == keys for e in posts)
+    for e in posts:
+        ids = e[3]
+        assert ids["state_bytes"] == ids["state_conv_bytes"] \
+            + ids["state_ssm_bytes"] == ids["state_slots_held"] \
+            * (per["conv"] + per["ssm"])
+    plans = [e[3] for e in _named(events, "ssm.plan")]
+    assert len(plans) == eng.compiled_executables()   # one a program traced
+    assert {p["form"] for p in plans} == {"scan", "step"}
+    for p in plans:
+        assert (p["chunk"], p["heads"], p["d_head"], p["d_state"],
+                p["groups"]) == (8, 4, 8, 16, 2)
+        assert p["state_bytes_per_slot"] * 3 == per["conv"] + per["ssm"]
+        assert p["rows"] == 1 if p["form"] == "step" else p["batch"] == 1
+    from paged_programs import traced
+    for name, scopes in (
+            ("decode", ("hetu.ssm.proj", "hetu.ssm.conv", "hetu.ssm.step",
+                        "hetu.ssm.norm", "hetu.attn.full", "hetu.ffn.dense")),
+            ("chunk", ("hetu.ssm.proj", "hetu.ssm.conv", "hetu.ssm.scan",
+                       "hetu.ssm.norm", "hetu.attn.full", "hetu.ffn.dense"))):
+        text = traced(eng, name, batch=4, chunk=8).lower().as_text(
+            debug_info=True)
+        assert all(scope in text for scope in scopes), name
+        assert ("hetu.ssm.scan" in text) == (name == "chunk")
+    # the same spans a scheduler step as a model with no state has
+    step = _named(events, "serve.step")[0]
+    assert {e[0] for e in _children(events, step)} <= {
+        "serve.admit", "serve.advance_prefills", "serve.evict",
+        "serve.decode", "serve.prefill_chunk", "ssm.plan", "paged_attn.plan",
+        "serve.state_reset", "serve.recompile",
+        *(f"serve.decode.{s}" for s in SEAMS),
+        *(f"serve.prefill_chunk.{s}" for s in SEAMS)}
+
+
 # ------------------------------------------ a trained expert model's counts
 
 def _expert_model():
