@@ -18,10 +18,13 @@ shapes (:func:`_resident`), never by a caller:
     walking the Q blocks from the first live one.  The softmax state and
     dq^T are loop values; dk and dv accumulate in 2-D VMEM scratch.
   * **streamed** (S of tens of thousands): grid (batch*heads, q_blocks,
-    k_blocks) with the walked axis innermost, the same tile bodies, the state
-    in 2-D VMEM scratch across the steps, and index maps clamped to the last
-    (first) live block so that a dead step re-names the block already in VMEM
-    and fetches nothing.  VMEM use is O(block_q * D + block_k * D) whatever S.
+    walked steps) with the walked axis innermost, the same tile bodies, the
+    state in 2-D VMEM scratch across the steps, and index maps clamped to the
+    last (first) live block so that a dead step re-names the block already in
+    VMEM and fetches nothing.  Without a window the walked axis is the whole
+    row, k_blocks; with one it is as long as the LONGEST live walk of any
+    block (``_Walk.steps``) and step j stands at the walk's first block + j
+    (``_Walk.at``).  VMEM use is O(block_q * D + block_k * D) whatever S.
 
 A tile is held TRANSPOSED, [block_k, block_q]: keys down the sublanes,
 queries along the lanes.  The row statistics of the softmax (m, l, and the
@@ -71,10 +74,14 @@ crosses (masked), the blocks wholly inside (no iota, compare or select) and
 the blocks the diagonal crosses (masked; a block both edges cross is masked
 once, with both), and a K block's Q blocks mirror it and END at the last
 query block that still sees the K block.  Blocks outside every span are
-neither fetched nor stepped over (resident) or re-name the block already in
-VMEM (streamed: the index maps clamp to the first AND the last live block).
-At S = 16,384, W = 1,024 and 512 x 512 tiles a Q block walks 3 K blocks
-where a causal one walks 16.5 on average.
+neither fetched nor stepped over, resident or streamed: a streamed call's
+walked grid axis starts at each walk's first live block and is as long as
+the longest walk, so the only dead steps left are at the end of a walk
+shorter than that (the first rows of blocks, and what ``offset`` cuts), and
+there the index maps clamp to the last live block.  At S = 16,384, W = 1,024
+and 512 x 512 tiles a Q block walks 3 K blocks where a causal one walks 16.5
+on average, and the grid of a kernel is 32 x 32 x 3 steps for 2,976 tiles
+(``flash.plan``'s ``steps`` beside ``tiles_live``), not 32 x 32 x 32.
 
 **Grouped heads**: K and V may come with ``heads / g`` heads.  The program of
 query head ``h`` reads K and V of head ``h // g`` through its index map, and
@@ -84,7 +91,8 @@ dO and statistics are one block and the heads a loop; streamed: the walked
 axes are heads x Q blocks), so dK and dV leave at ``heads / g`` heads.
 Neither a repeated copy of K or V nor a ``[B, heads, S, D]`` dK or dV ever
 exists in HBM.  With ``g = 1`` and no window every call traces to the
-kernels it traced to before either existed.
+kernels it traced to before either existed, and every call without a window
+to the kernels it traced to before the walked axis followed the spans.
 Interpret mode runs the same kernels on CPU for correctness tests.
 """
 
@@ -240,6 +248,25 @@ class _Walk:
         dQ's is the same walk and dK/dV's the same tiles by columns)."""
         return sum(hi - lo for qi in range(self.n_q)
                    for lo, hi, _ in self.k_spans(qi))
+
+    # A streamed walk is an axis of the grid.  Under a window a walk is a
+    # few blocks of a long row wherever its owner stands, so the axis is as
+    # long as the LONGEST live walk and step j of it is the walk's first
+    # block + j; without one it is the whole row and step j block j.
+
+    def steps(self, own_q: bool) -> int:
+        """Steps of the walked grid axis of a streamed call: the K blocks
+        of a Q block's walk (``own_q``) or the Q blocks of a K block's."""
+        if self.window is None:
+            return self.n_k if own_q else self.n_q
+        walks = map(self.k_spans, range(self.n_q)) if own_q \
+            else map(self.q_spans, range(self.n_k))
+        return max(spans[-1][1] - spans[0][0] for spans in walks)
+
+    def at(self, spans, j):
+        """The block that step ``j`` of a streamed walk over ``spans``
+        stands at; past the walk's end under a window, a dead step."""
+        return j if self.window is None else spans[0][0] + j
 
 
 def _walk(spans, tile, carry=None):
@@ -399,63 +426,74 @@ def _dkdv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                          acc_ref, *, w):
-    """Program (bh, qi, ki): one tile; m/l [1, block_q] and acc^T
-    [D, block_q] carry the online-softmax state across ki in scratch."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    """Program (bh, qi, j): one tile, K block ``ki`` of the walk's step j;
+    m/l [1, block_q] and acc^T [D, block_q] carry the online-softmax state
+    across the walk in scratch."""
+    qi, j = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         m_ref[:], l_ref[:], acc_ref[:] = _fwd_init(w, acc_ref.shape[0])
+
+    spans = w.k_spans(qi)
+    ki = w.at(spans, j)
 
     def tile(masked):
         m_ref[:], l_ref[:], acc_ref[:] = _fwd_tile(
             w, w.q_block(q_ref[:]), k_ref[:], v_ref[:], m_ref[:], l_ref[:],
             acc_ref[:], w.mask(qi, ki) if masked else None)
 
-    _step(w.k_spans(qi), ki, tile)
+    _step(spans, ki, tile)
 
-    @pl.when(ki == w.n_k - 1)
+    @pl.when(j == w.steps(True) - 1)
     def _():
         _fwd_finish(o_ref, lse_ref, m_ref[:], l_ref[:], acc_ref[:])
 
 
 def _dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dq_ref, dq_acc, *, w):
-    """Program (bh, qi, ki): accumulate dq^T of one Q block over K blocks."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    """Program (bh, qi, j): accumulate dq^T of one Q block over the K
+    blocks of its walk."""
+    qi, j = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    spans = w.k_spans(qi)
+    ki = w.at(spans, j)
 
     def tile(masked):
         dq_acc[:] += _dq_tile(w, w.q_block(q_ref[:]), k_ref[:], v_ref[:],
                               do_ref[:], lse_ref[:], delta_ref[:],
                               w.mask(qi, ki) if masked else None)
 
-    _step(w.k_spans(qi), ki, tile)
+    _step(spans, ki, tile)
 
-    @pl.when(ki == w.n_k - 1)
+    @pl.when(j == w.steps(True) - 1)
     def _():
         _dq_finish(w, dq_ref, dq_acc[:])
 
 
 def _dkdv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, w):
-    """Program (bh, ki, qi): accumulate dk/dv of one K block over Q blocks;
-    with ``g`` query heads a KV head, program (b * kv_heads, ki, head of the
-    group, qi): over the group's heads as well."""
-    ki, qi = pl.program_id(1), pl.program_id(2 if w.g == 1 else 3)
+    """Program (bh, ki, j): accumulate dk/dv of one K block over the Q
+    blocks of its walk; with ``g`` query heads a KV head, program (b *
+    kv_heads, ki, head of the group, j): over the group's heads as well."""
+    ki, j = pl.program_id(1), pl.program_id(2 if w.g == 1 else 3)
 
-    def at(head, block):
-        """This step is Q block ``block`` of the group's head ``head``."""
-        here = qi == block
+    def at(head, step):
+        """This is step ``step`` of the walk of the group's head ``head``."""
+        here = j == step
         return here if w.g == 1 else (pl.program_id(2) == head) & here
 
     @pl.when(at(0, 0))
     def _():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    spans = w.q_spans(ki)
+    qi = w.at(spans, j)
 
     def tile(masked):
         dk, dv = _dkdv_tile(w, w.q_block(q_ref[:]), k_ref[:], v_ref[:],
@@ -464,9 +502,9 @@ def _dkdv_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] += dk
         dv_acc[:] += dv
 
-    _step(w.q_spans(ki), qi, tile)
+    _step(spans, qi, tile)
 
-    @pl.when(at(w.g - 1, w.n_q - 1))
+    @pl.when(at(w.g - 1, w.steps(False) - 1))
     def _():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -510,11 +548,13 @@ def _params(grid):
         ("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2)))
 
 
-def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window):
+def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window, grid):
     """Which feeding a kernel got is fixed when the program is traced: one
     instant per pallas_call built says so in a JSONL trace or the xplane of
     a profiled compile.  ``tiles_live`` is what the call's walk visits (every
-    head's), ``tiles_causal`` what it would without the window."""
+    head's), ``tiles_causal`` what it would without the window, ``steps``
+    the steps of the call's grid: of a streamed call those that run a tile
+    and the dead ones."""
     (b, h, s_q, d), s_k = q.shape, k.shape[2]
     plain = _Walk(s_q=s_q, s_k=s_k, block_q=w.bq, block_k=w.bk,
                   scale=w.scale, causal=w.causal)
@@ -523,15 +563,16 @@ def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window):
         "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d,
         "d_v": v.shape[3], "window": int(window or 0),
         "kv_heads": k.shape[1], "tiles_live": b * h * w.tiles(),
-        "tiles_causal": b * h * plain.tiles()})
+        "tiles_causal": b * h * plain.tiles(), "steps": math.prod(grid)})
 
 
 def _live(i, spans, n, *, ends: str = "both"):
-    """Grid index ``i`` of a streamed walk over ``n`` blocks clamped into
-    the walk's live range, so a dead step re-names a block already in
-    VMEM.  ``ends``: a walk without a window is dead at one end only (a Q
-    block's K blocks start at 0: ``"last"``; a K block's Q blocks run to
-    the end: ``"first"``), and is clamped there alone, as it always was."""
+    """The block ``i`` a step of a streamed walk over ``n`` blocks stands
+    at (``_Walk.at``) clamped into the walk's live range, so a dead step
+    re-names a block already in VMEM.  ``ends``: a walk without a window is
+    dead at one end only (a Q block's K blocks start at 0: ``"last"``; a K
+    block's Q blocks run to the end: ``"first"``), and is clamped there
+    alone, as it always was."""
     first = jnp.minimum(spans[0][0], n - 1)
     if ends == "first":
         return jnp.maximum(i, first)
@@ -547,8 +588,9 @@ def _specs(resident, w, s_q, s_k, *, own_q: bool):
     ``own_q``: the program owns a Q block and walks K blocks (forward, dQ);
     else it owns a K block and walks Q blocks (dK/dV).  Resident: the walked
     side is the whole row.  Streamed: the walked side follows the innermost
-    grid axis, clamped into the live range so dead steps fetch nothing.  The
-    statistics (lse, delta) are [bh, q_blocks, 1, block_q].
+    grid axis from the block ``_Walk.at`` puts its step 0 at, clamped into
+    the live range so dead steps fetch nothing.  The statistics (lse, delta)
+    are [bh, q_blocks, 1, block_q].
 
     With ``g`` query heads a KV head the leading axis of the Q side counts
     query heads and the K side's KV heads: a program that owns a Q block
@@ -578,17 +620,19 @@ def _specs(resident, w, s_q, s_k, *, own_q: bool):
     else:
         windowed = w.window is not None
         if own_q:
-            def q_at(bh, qi, ki):
+            def q_at(bh, qi, j):
                 return bh, qi
-            def k_at(bh, qi, ki):
-                return kv(bh), _live(ki, w.k_spans(qi), w.n_k,
-                                     ends="both" if windowed else "last")
+            def k_at(bh, qi, j):
+                head, spans = kv(bh), w.k_spans(qi)
+                return head, _live(w.at(spans, j), spans, w.n_k,
+                                   ends="both" if windowed else "last")
         else:
             def k_at(bh, ki, *walked):
                 return bh, ki
-            def q_at(bh, ki, *walked):     # walked: (head of the group,) qi
+            def q_at(bh, ki, *walked):     # walked: (head of the group,) j
                 head = bh if g == 1 else bh * g + walked[0]
-                return head, _live(walked[-1], w.q_spans(ki), w.n_q,
+                spans = w.q_spans(ki)
+                return head, _live(w.at(spans, walked[-1]), spans, w.n_q,
                                    ends="both" if windowed else "first")
         q_rows, k_rows, stat_blocks = w.bq, w.bk, None
     return (lambda d: pl.BlockSpec((lead, q_rows, d),
@@ -613,9 +657,9 @@ def _flash_fwd(q, k, v, *, scale, causal, window, block_q, block_k,
     w = _call_walk(q, k, scale=scale, causal=causal, window=window,
                    block_q=block_q, block_k=block_k)
     resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
-    _plan("fwd", resident, w, q, k, v, window)
+    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.steps(True))
+    _plan("fwd", resident, w, q, k, v, window, grid)
     q_side, lse_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
-    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_resident_kernel if resident
                           else _fwd_streamed_kernel, w=w),
@@ -658,11 +702,11 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, window, block_q,
     resident = _resident((w.g * s_q, d, q.dtype), (w.g * s_q, d_v, g.dtype),
                          (w.g * 8 * w.n_q, w.bq, jnp.float32),
                          (w.g * 8 * w.n_q, w.bq, jnp.float32))
-    _plan("dkdv", resident, w, q, k, v, window)
-    q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=False)
     grid = (b * h_kv, w.n_k) if resident \
-        else (b * h_kv, w.n_k, w.n_q) if w.g == 1 \
-        else (b * h_kv, w.n_k, w.g, w.n_q)
+        else (b * h_kv, w.n_k, w.steps(False)) if w.g == 1 \
+        else (b * h_kv, w.n_k, w.g, w.steps(False))
+    _plan("dkdv", resident, w, q, k, v, window, grid)
+    q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=False)
     dk, dv = pl.pallas_call(
         functools.partial(_dkdv_resident_kernel if resident
                           else _dkdv_streamed_kernel, w=w),
@@ -682,9 +726,9 @@ def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, window, block_q,
 
     # dQ: a program owns a Q block and walks the K side
     resident = _resident((s_k, d, k.dtype), (s_k, d_v, v.dtype))
-    _plan("dq", resident, w, q, k, v, window)
+    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.steps(True))
+    _plan("dq", resident, w, q, k, v, window, grid)
     q_side, stat_spec, k_side = _specs(resident, w, s_q, s_k, own_q=True)
-    grid = (b * h, w.n_q) if resident else (b * h, w.n_q, w.n_k)
     dq = pl.pallas_call(
         functools.partial(_dq_resident_kernel if resident
                           else _dq_streamed_kernel, w=w),

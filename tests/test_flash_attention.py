@@ -255,15 +255,32 @@ def test_flash_scale_not_a_power_of_two_stays_on_the_scores():
 
 # ---- two widths: Q, K of one, V, O, dO of another (latent attention) ----
 
-@pytest.mark.parametrize("window", [None, 40], ids=["causal", "w40"])
-@pytest.mark.parametrize("shape", [(128, 128), (64, 192), (192, 64)],
-                         ids=["square", "sq_lt_sk", "sq_gt_sk"])
-@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+# a streamed window call with MANY dead blocks a side of every walk: 64 | 32
+# blocks of 32 a row, 4 of them live under a window of 96
+_LONG = {"long_sq_eq_sk": (2048, 2048), "long_sq_lt_sk": (1024, 2048),
+         "long_sq_gt_sk": (2048, 1024)}
+_LONG_WINDOW = 96
+
+
+def _case_id(v):
+    """A readable id for a (s_q, s_k) pair, a window or a group size."""
+    if isinstance(v, tuple):
+        return "x".join(map(str, v))
+    return "causal" if v is None else str(v)
+
+
+@pytest.mark.parametrize("feeding,shape,window", [
+    *((f, s, w) for f in ("resident", "streamed")
+      for s in ((128, 128), (64, 192), (192, 64)) for w in (None, 40)),
+    *(("streamed", _LONG[s], _LONG_WINDOW)
+      for s in ("long_sq_lt_sk", "long_sq_gt_sk"))],
+    indirect=["feeding"], ids=_case_id)
 def test_flash_two_widths_match_the_oracle(feeding, shape, window,
                                            monkeypatch):
     """Forward, dQ and dK/dV with Q, K 48 wide and V, O, dO 32 wide, against
     ``ops.causal_attention``, resident and streamed, ``S_q != S_k`` either
-    way round, with and without a window; every plan carries both widths."""
+    way round, with and without a window, and streamed over rows of which a
+    window leaves most blocks dead; every plan carries both widths."""
     s_q, s_k = shape
     plans = _plans(monkeypatch)
     ks = jax.random.split(jax.random.PRNGKey(21), 4)
@@ -349,19 +366,21 @@ _WINDOWS = {"lt_block": lambda s_k: 8, "ragged": lambda s_k: 40,
             "gt_s": lambda s_k: 2 * s_k + 3}
 
 
-@pytest.mark.parametrize("window", list(_WINDOWS))
-@pytest.mark.parametrize("shape", list(_SHAPES))
-@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
+@pytest.mark.parametrize("feeding,shape,window", [
+    *((f, s, w) for f in ("resident", "streamed") for s in _SHAPES
+      for w in _WINDOWS),
+    *(("streamed", s, "many_dead") for s in _LONG)], indirect=["feeding"])
 def test_flash_window_matches_the_oracle(feeding, shape, window,
                                          monkeypatch):
     """Forward and all three gradients against
     ``ops.causal_attention(window=)``: windows smaller than a block, not a
     multiple of it, equal to it, equal to and larger than S, ``s_q`` <, ==,
-    > ``s_k``, resident and streamed.  The plan says what the walk visits: no
-    more than a causal walk, and as much where the window hides nothing."""
+    > ``s_k``, resident and streamed, and streamed over 64 blocks a row of
+    which a walk has 4 live.  The plan says what the walk visits: no more
+    than a causal walk, and as much where the window hides nothing."""
     plans = _plans(monkeypatch)
-    s_q, s_k = _SHAPES[shape]
-    w = _WINDOWS[window](s_k)
+    s_q, s_k = {**_SHAPES, **_LONG}[shape]
+    w = {**_WINDOWS, "many_dead": lambda s_k: _LONG_WINDOW}[window](s_k)
     got, want, lo = _masked_case(s_q, s_k, w, seed=s_q + w)
     _assert_agree(got, want, lo)
     assert [a["kernel"] for _, a in plans] == ["fwd", "dkdv", "dq"]
@@ -373,20 +392,27 @@ def test_flash_window_matches_the_oracle(feeding, shape, window,
             assert a["tiles_live"] == a["tiles_causal"]
         elif s_q == s_k:      # 4 x 4 tiles: the corner of 3 is behind it
             assert a["tiles_live"] < a["tiles_causal"]
+        if shape == "long_sq_eq_sk":    # the grid walks few blocks more
+            assert a["tiles_live"] <= a["steps"] < 1.1 * a["tiles_live"]
 
 
-@pytest.mark.parametrize("window", [None, 40], ids=["causal", "w40"])
-@pytest.mark.parametrize("group", [1, 4, 8])
-@pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
-def test_flash_grouped_heads_match_repeated_kv(feeding, group, window,
+@pytest.mark.parametrize("feeding,group,window,shape", [
+    *((f, g, w, (128, 128)) for f in ("resident", "streamed")
+      for g in (1, 4, 8) for w in (None, 40)),
+    *(("streamed", 4, _LONG_WINDOW, _LONG[s])
+      for s in ("long_sq_lt_sk", "long_sq_gt_sk"))], indirect=["feeding"],
+    ids=_case_id)
+def test_flash_grouped_heads_match_repeated_kv(feeding, group, window, shape,
                                                monkeypatch):
     """8 query heads over 8, 2 and 1 KV heads: value, dQ, and dK, dV AT THE
     KV HEADS, against the oracle over K and V repeated to 8 heads (whose
-    gradient sums each group), with and without a window."""
+    gradient sums each group), with and without a window, and streamed over
+    rows of which a window leaves most blocks dead."""
     plans = _plans(monkeypatch)
-    got, want, lo = _masked_case(128, 128, window, heads=8, group=group,
+    s_q, s_k = shape
+    got, want, lo = _masked_case(s_q, s_k, window, heads=8, group=group,
                                  d=32, seed=group)
-    assert got[2].shape == got[3].shape == (1, 8 // group, 128, 32)
+    assert got[2].shape == got[3].shape == (1, 8 // group, s_k, 32)
     _assert_agree(got, want, lo)
     assert all(a["kv_heads"] == 8 // group
                and a["resident"] == (feeding == "resident")
@@ -407,9 +433,11 @@ def test_flash_refuses_heads_that_do_not_group_and_a_window_alone():
 
 @pytest.mark.parametrize("case", [
     (128, 128, None, 1), (128, 128, 40, 1), (128, 128, 8, 4),
-    (64, 128, 40, 2), (128, 64, 24, 1), (128, 128, 128, 1)],
+    (64, 128, 40, 2), (128, 64, 24, 1), (128, 128, 128, 1),
+    (1024, 1024, 96, 1), (512, 1024, 96, 2), (1024, 512, 96, 4)],
     ids=["causal", "w40", "w8-g4", "sq_lt_sk-w40-g2", "sq_gt_sk-w24",
-         "w_eq_s"])
+         "w_eq_s", "many_dead-w96", "many_dead-sq_lt_sk-w96-g2",
+         "many_dead-sq_gt_sk-w96-g4"])
 @pytest.mark.parametrize("feeding", ["resident", "streamed"], indirect=True)
 def test_each_kernel_visits_exactly_the_live_tiles(feeding, case,
                                                    monkeypatch):
@@ -417,7 +445,9 @@ def test_each_kernel_visits_exactly_the_live_tiles(feeding, case,
     round each: every one runs ``tiles_live`` times a call, the plan's
     count, which is the number of (Q block, K block) pairs that hold a
     visible score, times the query heads; a streamed walk's dead grid steps
-    run none."""
+    run none.  ``steps`` is the call's grid: resident, a step a block a
+    program owns; streamed, heads x owned blocks x the whole row without a
+    window, x the LONGEST live walk with one."""
     import sys
     mod = sys.modules[_MOD]
     s_q, s_k, window, group = case
@@ -447,6 +477,29 @@ def test_each_kernel_visits_exactly_the_live_tiles(feeding, case,
         dict.fromkeys(trips, heads * int(live.sum()))
     assert trips == dict.fromkeys(trips, heads * int(live.sum()))
     assert all(a["tiles_causal"] >= a["tiles_live"] for _, a in plans)
+    w = mod._Walk(s_q=s_q, s_k=s_k, block_q=block, block_k=block, scale=1.0,
+                  causal=True, window=window, group=group)
+
+    def longest(spans_of, n):
+        walks = [spans_of(i) for i in range(n)]    # on plain integers
+        return max(spans[-1][1] - spans[0][0] for spans in walks)
+
+    for _, a in plans:
+        own_q = a["kernel"] != "dkdv"
+        owned, row = (w.n_q, w.n_k) if own_q else (w.n_k, w.n_q)
+        if a["resident"]:        # the group's heads are a loop in a program
+            want = (heads if own_q else heads // group) * owned
+        elif w.window is None:
+            want = heads * owned * row
+        else:
+            want = heads * owned * (longest(w.k_spans, w.n_q) if own_q
+                                    else longest(w.q_spans, w.n_k))
+        assert a["steps"] == want, a
+        if not a["resident"] and window == 96:
+            # 4 blocks of a row are live; a row of the longer side that
+            # sees nothing of the shorter still takes its walk's steps
+            assert a["steps"] < 1.15 * max(s_q, s_k) / min(s_q, s_k) \
+                * a["tiles_live"]
 
 
 # ---- a recomputed layer keeps the forward kernel's output and LSE rows

@@ -84,10 +84,10 @@ def _flash_vjp(q, k, v, g):
     return (out, *vjp(g))
 
 
-def _flash_vjp_masked(window):
+def _flash_vjp_masked(window, causal=True):
     def fn(q, k, v, g):
         out, vjp = jax.vjp(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, window=window), q, k, v)
+            q, k, v, causal=causal, window=window), q, k, v)
         return (out, *vjp(g))
     return fn
 
@@ -245,6 +245,79 @@ def test_grouped_window_calls_keep_k_v_dk_and_dv_at_the_kv_heads():
     assert [result(c) for c in hits["bwd_count"]] == [q]
     for c in hits["bwd"]:
         assert operands(c)[:4] == [q, kv, kv, q]
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` in ``jaxpr``, in program order."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+def test_a_streamed_window_call_walks_its_longest_live_walk(monkeypatch):
+    """Mellum's window call (``[32 | 4, 16384, 128]``, W = 1,024, blocks of
+    512: streamed, 32 blocks a row) lowers for TPU with the walked axis of
+    each kernel's grid as long as the longest live walk, 3 blocks, and not
+    the row's 32: ``flash.plan`` reads 3,072 ``steps`` a kernel beside 2,976
+    ``tiles_live`` (32,768 before ISSUE 49).  The full call beside it keeps
+    the whole row."""
+    seen = []
+    monkeypatch.setattr(
+        sys.modules["hetu_tpu.ops.pallas_kernels.flash_attention"].trace,
+        "instant", lambda name, attrs=None, cat="hetu": seen.append(attrs))
+    for (_, qs, ks, window), grids, steps, tiles in zip(
+            MASKED_FLASH_CASES,
+            ([(32, 32, 3), (4, 32, 8, 3), (32, 32, 3)],
+             [(32, 32, 32), (4, 32, 8, 32), (32, 32, 32)]),
+            (3072, 32768), (2976, 16896)):
+        del seen[:]
+        abstract = [jax.ShapeDtypeStruct(s, bf16) for s in (qs, ks, ks, qs)]
+        traced = jax.jit(_flash_vjp_masked(window)).trace(*abstract)
+        assert _pallas_grids(traced.jaxpr.jaxpr) == grids   # fwd, dkdv, dq
+        assert traced.lower(lowering_platforms=("tpu",)).as_text().count(
+            "tpu_custom_call") == 3
+        assert [(a["kernel"], a["resident"], a["steps"], a["tiles_live"])
+                for a in seen] == [(k, 0, steps, tiles)
+                                   for k in ("fwd", "dkdv", "dq")]
+
+
+# every windowless STREAMED call lowers to the text it lowered to at the
+# parent of ISSUE 49 (9b9e92f): a grid as long as the row, the one-ended
+# clamp.  (The step digests below hold the resident ones.)
+STREAMED_DIGESTS = {
+    "mellum full": (
+        (1, 32, 16384, 128), (1, 4, 16384, 128), True,
+        "0ad240f3b760272430a0a51bd4dd2368687b5b523318dc8ebd225eab92b457f6"),
+    "causal sq_lt_sk": (
+        (1, 2, 8192, 128), (1, 2, 16384, 128), True,
+        "9b9181f3c9b265f08231febc211b57115d5e0b88942a64b41b99e3d1a240371b"),
+    "causal sq_gt_sk": (
+        (1, 2, 16384, 128), (1, 2, 8192, 128), True,
+        "608afdd9c0f28d062d9ace0619c3aaa3b05917b98c81a69b4f55f7151c924025"),
+    "unmasked grouped": (
+        (1, 4, 16384, 128), (1, 2, 16384, 128), False,
+        "e9bd9abe7de2472d9d149be993e4fb8b05a6566cd661b250c0717e78baf3fab7")}
+
+
+@pytest.mark.parametrize("case", sorted(STREAMED_DIGESTS))
+def test_a_windowless_streamed_call_lowers_to_the_text_it_lowered_to(
+        case, monkeypatch):
+    """The kernels in interpret mode, as the step digests are taken: a
+    Mosaic call's payload carries the checkout's path."""
+    import hashlib
+
+    monkeypatch.setattr(
+        sys.modules["hetu_tpu.ops.pallas_kernels.flash_attention"],
+        "auto_interpret", lambda interpret: True)
+    qs, ks, causal, digest = STREAMED_DIGESTS[case]
+    abstract = [jax.ShapeDtypeStruct(s, bf16) for s in (qs, ks, ks, qs)]
+    text = jax.jit(_flash_vjp_masked(None, causal)).trace(*abstract).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
 
 
 def _gpt2_small():
@@ -560,7 +633,10 @@ def test_a_serving_program_reads_each_projection_weight_where_it_lies(
 # that touches the serving side alone leaves these as they are, and one that
 # changes a step replaces its digest and says why.  Mellum's step lowers to
 # one of two texts by the process's hash seed (which of two equal rotation
-# tables an equation names), on this tree and on its parent alike
+# tables an equation names), on this tree and on its parent alike.  ISSUE 49
+# changed the streamed WINDOW calls and left all three as they are: no call
+# of these steps has both (``_tiny_mellum``'s window calls, 256 keys of 128,
+# are resident)
 STEP_DIGESTS = {
     "_gpt2_small": {
         "c8a54ffcd3a3be6b47a5cbe48b53842fb43443d4042d34451982b543756b2e51"},

@@ -823,7 +823,9 @@ def test_flash_plan_says_the_window_the_kv_heads_and_the_tiles(window,
     """One ``flash.plan`` instant per kernel built, when the call is TRACED:
     beside the ids it had, ``window`` (0 for none), ``kv_heads``, and
     ``tiles_live`` beside ``tiles_causal``: what the walk visits and what a
-    causal walk would, a call (all heads).  S = 128 in tiles of 32: the
+    causal walk would, a call (all heads), and ``steps``, the steps of the
+    call's grid (``tests/test_flash_attention.py`` holds a streamed call's
+    to its longest live walk).  S = 128 in tiles of 32: the
     diagonal leaves 10 of 16 tiles a head; a window of 40 hides the corner
     tile (9), one of 8 the three below the subdiagonal (7), one as long as
     the keys nothing."""
@@ -849,6 +851,10 @@ def test_flash_plan_says_the_window_the_kv_heads_and_the_tiles(window,
     for p in plans:
         assert set(p) == {"kernel", "resident", "block_q", "block_k", "s_q",
                           "s_k", "d", "d_v", "window", "kv_heads",
-                          "tiles_live", "tiles_causal"}
+                          "tiles_live", "tiles_causal", "steps"}
         assert (p["window"], p["kv_heads"]) == (window or 0, kv_heads)
+        # resident at this size: the grid is a step a block a program owns,
+        # and the dK/dV program of a KV head walks its group's heads itself
+        assert p["steps"] == \
+            2 * (kv_heads if p["kernel"] == "dkdv" else 4) * 4
         assert (p["tiles_live"], p["tiles_causal"]) == (8 * live, 80)
