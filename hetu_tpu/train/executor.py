@@ -49,6 +49,21 @@ def gradients(loss_fn: Callable, argnums=0, has_aux: bool = False):
     return jax.grad(loss_fn, argnums=argnums, has_aux=has_aux)
 
 
+def async_collective_options(mesh: Optional[Mesh]) -> Dict[str, bool]:
+    """Compiler options for a train step's executable on ``mesh``: on
+    several TPU devices XLA:TPU starts each all-reduce asynchronously and
+    fuses the matmuls that do not read its result between the start and the
+    done, so a backward input-gradient all-reduce runs under the same
+    layer's weight-gradient matmuls.  Empty for anything else.  The options
+    belong to that one executable and its compile-cache key; nothing in the
+    process reads them."""
+    if mesh is None or mesh.size < 2 \
+            or mesh.devices.flat[0].platform != "tpu":
+        return {}
+    return {"xla_enable_async_all_reduce": True,
+            "xla_tpu_enable_async_collective_fusion_fuse_all_reduce": True}
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class TrainState:
@@ -352,8 +367,9 @@ class Executor:
             fn = (self._train_step_guarded if name == "train_guarded"
                   else self._train_step)
             donate = (0,)
+            options = async_collective_options(self.mesh)
         elif name in ("validate", "eval", "test"):
-            fn, donate = self._eval_step, ()
+            fn, donate, options = self._eval_step, (), {}
         else:
             raise KeyError(f"unknown subexecutor {name!r}")
         kwargs = {}
@@ -361,6 +377,8 @@ class Executor:
             # batch sharded over dp; everything else left to XLA/SPMD
             kwargs["in_shardings"] = (
                 None, NamedSharding(self.mesh, P(self.dp_axis)))
+        if options:
+            kwargs["compiler_options"] = options
         return jax.jit(fn, donate_argnums=donate, **kwargs)
 
     def run(self, name: str, state: TrainState, batch):
