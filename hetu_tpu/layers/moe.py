@@ -345,13 +345,17 @@ class HeldExpertLayer:
         i identity:  + w_i * u
         i absent:    nothing (that chip adds it; nothing stands in for it)
         shared:      + W_down(silu(W_gate u) * W_up u)      # every token
+                     (times sigmoid(w_sg . u) where the leaves hold w_sg)
 
     ``scoring`` is the published rule, ``"softmax"`` over all experts or
     ``"sigmoid"`` of each; ``renormalise`` whether the chosen weights are
     divided by their sum (the sum keeps ALL ``k`` chosen scores, the absent
     experts' too: the router is whole on every chip); ``shared`` whether the
     layer has a shared expert, one more dense SwiGLU that every chip
-    computes for its own tokens and that is no part of the routing.  All
+    computes for its own tokens and that is no part of the routing; where
+    the leaves hold ``shared_gate_w`` [H] its result is scaled a token by
+    ``sigmoid(w_sg . u)`` (absent: as it is), and where they hold no
+    ``router_bias`` the choice is by the scores alone.  All
     three are the model's, set from its configuration, not options of a
     deployment.  The identity experts are one weighted sum of ``u``, never a
     matmul; the held experts' work follows the pairs routed to them
@@ -376,7 +380,7 @@ class HeldExpertLayer:
     [H, n_routed + n_zero] float32, ``router_bias`` [n_routed + n_zero]
     float32, ``gate``/``up`` [count, H, F], ``down`` [count, F, H]; with a
     shared expert ``shared_gate``/``shared_up`` [H, F_s], ``shared_down``
-    [F_s, H].  ``router_bias`` is whatever the model hands over under that
+    [F_s, H] and, where it is gated a token, ``shared_gate_w`` [H].  ``router_bias`` is whatever the model hands over under that
     name: a parameter leaf where the model serves published weights, the
     model's STATE where it trains without an auxiliary loss and moves the
     bias itself (``models/deepseek_v3.py``); it steers the choice only, so
@@ -404,7 +408,10 @@ class HeldExpertLayer:
                              precision=jax.lax.Precision.HIGHEST)
             scores = jax.nn.softmax(logits, axis=-1) \
                 if self.scoring == "softmax" else jax.nn.sigmoid(logits)
-            w, idx = route_biased_top_k(scores, p["router_bias"], self.k)
+            if "router_bias" in p:
+                w, idx = route_biased_top_k(scores, p["router_bias"], self.k)
+            else:
+                w, idx = jax.lax.top_k(scores, self.k)
             if self.renormalise:
                 w = w / (w.sum(-1, keepdims=True) + 1e-20)
             return w * self.scaling, idx
@@ -421,8 +428,13 @@ class HeldExpertLayer:
             x = tokens.astype(dt)
             h = jax.nn.silu(jnp.dot(x, of("shared_gate"))) \
                 * jnp.dot(x, of("shared_up"))
-            return jnp.dot(h, of("shared_down"),
-                           preferred_element_type=jnp.float32)
+            out = jnp.dot(h, of("shared_down"),
+                          preferred_element_type=jnp.float32)
+            if "shared_gate_w" in p:
+                out = out * jax.nn.sigmoid(jnp.dot(
+                    x, of("shared_gate_w"),
+                    preferred_element_type=jnp.float32))[:, None]
+            return out
 
     def combine(self, p, tokens, w, idx, layer=None):
         """What the chosen experts add for tokens [T, H] under the router's

@@ -3,11 +3,14 @@
 and its three calls.
 
 A layer is ``h + operator(N(h))`` then ``h + feed_forward(N(h))``, ``N``
-RMSNorm with its own weight: the operator grouped-query attention
-(:class:`GroupedHeads`: q and k normalised per head where the weights hold
-such norms, the half-rotation layout where the layer is rotated, full or over
-a window) unless the model states another (:meth:`BlockDecoder._operator`:
-a convolution in its place, or a second branch beside it); the feed-forward
+RMSNorm with its own weight (``w``, or ``1 + w`` where the model states so):
+the operator grouped-query attention (:class:`GroupedHeads`: q and k
+normalised per head where the weights hold such norms, the half-rotation
+layout where the layer is rotated, over the whole head or its leading
+``rotary_dim`` dims, full or over a window, its result gated by a second
+half of the query projection where the model states one) unless the model
+states another (:meth:`BlockDecoder._operator`: a convolution or a delta-rule
+mixer in its place, or a second branch beside it); the feed-forward
 a dense SwiGLU on the ``first_dense`` leading layers and the model's
 :class:`~hetu_tpu.layers.moe.HeldExpertLayer` on the rest (a model without
 one is dense throughout).  A layer runs in one of three calls, which
@@ -19,7 +22,9 @@ of ``hetu_tpu/serve`` and the loss.  A model (``models/exaone_moe.py``: window
 and full attention layers in two cache groups; ``models/lfm2_moe.py``: short
 convolutions with state layers between full attention layers in one group;
 ``models/falcon_h1.py``: a state-space branch beside attention in every
-layer, each layer a cache layer AND a state layer) states through the
+layer, each layer a cache layer AND a state layer; ``models/qwen3_next.py``:
+Gated DeltaNet state layers of two parts between gated, partly rotated full
+attention layers in one group) states through the
 constructor its expert layer, the constant factors it scales its products by
 (``multipliers``) and, by layer, where a layer's attention leaves and cache
 layer lie, whether it is rotated and what window it has; and itself holds
@@ -50,6 +55,7 @@ import jax.numpy as jnp
 from hetu_tpu import ops
 from hetu_tpu.layers.base import Module, held_by_layer
 from hetu_tpu.layers.moe import MOE_STATS
+from hetu_tpu.ops.moe_ops import held_expert_path
 
 # the names ``layer_types`` gives the two kinds of attention layer
 WINDOW, FULL = "sliding_attention", "full_attention"
@@ -67,6 +73,21 @@ def draw_leaf(key, lead: tuple, shape: tuple, std, dtype):
                     * std).astype(dtype),
         jax.random.split(key, math.prod(lead) * rows))
     return out.reshape(lead + shape)
+
+
+def counts_with_grouped(c, stats):
+    """The counts a cache entry point of a model whose ``step_stats`` are
+    ``MOE_STATS + ("moe_experts", "moe_grouped")`` returns, from the expert
+    layers' sum ``stats`` [4]: behind them the held experts a call could hit
+    at most (held x expert layers, a constant) and the held pairs that the
+    walk's grouped path computed, all of them or none by
+    ``ops.moe_ops.held_expert_path``'s static rule (which reads an expert's
+    size alone, so one token stands for a call of any row count)."""
+    grouped = held_expert_path(1, c.moe_topk, c.held[1], c.hidden_size,
+                               c.expert_ffn_size) == "grouped"
+    return jnp.concatenate([stats, jnp.stack([
+        jnp.int32(c.held[1] * (c.num_layers - c.first_dense)),
+        stats[0] * int(grouped)])])
 
 
 @dataclass
@@ -99,35 +120,55 @@ class GroupedHeads:
     ``models/mellum.py``, which trains): Q and K normalised per head where
     the leaves hold ``q_norm`` / ``k_norm`` (a model without them has no such
     norm), K times ``multipliers["key"]`` where the model states one, the
-    half-rotation layout over the whole head, the out-projection.  ``p`` is
-    the attention leaves stacked over layers, ``l`` the layer read."""
+    half-rotation layout over the head's rotated dims, the out-projection.
+    Three things a model may STATE, each absent here: ``rotary_dim``, the
+    leading dims of a head that are rotated (None: the whole head; the rest
+    pass as they are); ``gated_query``, a ``q`` leaf of twice the width,
+    ``[query | gate]`` a head, the attention's result times ``sigmoid(gate)``
+    before the out-projection; ``unit_offset_norms``, every norm weighs by
+    ``1 + w``.  ``p`` is the attention leaves stacked over layers, ``l`` the
+    layer read."""
 
     # constant factors a model scales its products by, by name; none here
     multipliers: dict = {}
+    rotary_dim = None
+    gated_query = False
+    unit_offset_norms = False
 
     def _norm(self, x, scale):
+        if self.unit_offset_norms:
+            scale = 1.0 + scale.astype(jnp.float32)
         return ops.rms_norm(x, scale, eps=self.c.rms_eps)
 
     @staticmethod
     def _rotate(x, cos, sin):
-        """Half-rotation layout over the whole head: x [B, S, heads, D],
-        cos/sin [B, S, D / 2]; float32 inside, result in x's dtype."""
+        """Half-rotation layout over the leading ``2 r`` dims of the head,
+        the whole head where the tables are that wide: x [B, S, heads, D],
+        cos/sin [B, S, r]; the other ``D - 2 r`` dims pass as they are;
+        float32 inside, result in x's dtype."""
         xf = x.astype(jnp.float32)
-        d2 = x.shape[-1] // 2
-        x1, x2 = xf[..., :d2], xf[..., d2:]
+        d2 = cos.shape[-1]
+        x1, x2 = xf[..., :d2], xf[..., d2:2 * d2]
         cos, sin = cos[:, :, None], sin[:, :, None]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               axis=-1).astype(x.dtype)
+        turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if 2 * d2 < x.shape[-1]:
+            turned.append(xf[..., 2 * d2:])
+        return jnp.concatenate(turned, axis=-1).astype(x.dtype)
 
     def _qkv(self, p, l: int, a, cos, sin, rotate: bool):
         """a [B, S, H] normed -> (q [B, heads, S, D], k [B, S, kv_heads, D],
         v the same) of layer ``l``: q and k normalised per head where the
         leaves hold the norms, rotated where ``rotate``.  k and v are the
-        rows a cache holds."""
+        rows a cache holds.  A model with a gated query projection gets a
+        fourth value, the gate [B, S, heads * D]."""
         c, dt = self.c, self.c.dtype
         b, s, _ = a.shape
         q = ops.linear(a, p["q"][l].astype(dt), trans_w=True).reshape(
-            b, s, c.num_heads, c.head_dim)
+            b, s, c.num_heads, -1)
+        gate = ()
+        if self.gated_query:
+            q, g = jnp.split(q, 2, axis=-1)
+            gate = (g.reshape(b, s, -1),)
         k = ops.linear(a, p["k"][l].astype(dt), trans_w=True).reshape(
             b, s, c.num_kv_heads, c.head_dim)
         v = ops.linear(a, p["v"][l].astype(dt)).reshape(
@@ -139,12 +180,17 @@ class GroupedHeads:
             k = k * self.multipliers["key"]
         if rotate:
             q, k = self._rotate(q, cos, sin), self._rotate(k, cos, sin)
-        return jnp.moveaxis(q, 1, 2), k, v
+        return (jnp.moveaxis(q, 1, 2), k, v) + gate
 
-    def _out(self, p, l: int, o):
-        """o [B, heads, S, D] -> [B, S, H]."""
+    def _out(self, p, l: int, o, gate=None):
+        """o [B, heads, S, D] -> [B, S, H]; times ``sigmoid(gate)`` [B, S,
+        heads * D] first where the query projection has one."""
         b, _, s, _ = o.shape
         o = jnp.moveaxis(o, 1, 2).reshape(b, s, -1)
+        if gate is not None:
+            with jax.named_scope("hetu.attn.full"):
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    o.dtype)
         return ops.linear(o.astype(self.c.dtype),
                           p["o"][l].astype(self.c.dtype))
 
@@ -161,7 +207,9 @@ class BlockDecoder(GroupedHeads, Module):
     the attention layers alone: ``attn_leaf`` the layer's index in the
     stacked attention leaves, ``cache_layer`` its (group, cache layer in the
     group) of the serving cache, ``rotated`` the layers whose q and k are
-    rotated, ``window`` the window of those that have one.
+    rotated, ``window`` the window of those that have one.  ``rotary_dim``,
+    ``gated_query`` and ``unit_offset_norms`` are :class:`GroupedHeads`',
+    stated by the models that have them.
 
     ``params``: ``tok_emb`` [V, H], ``lm_head`` [V, H] unless the head is
     tied, ``norm_f``, ``layers``: ``attn_norm``/``ffn_norm`` [L, H] (the
@@ -173,10 +221,13 @@ class BlockDecoder(GroupedHeads, Module):
     step_stats = MOE_STATS
 
     def __init__(self, config, moe, *, attn_leaf, cache_layer, rotated,
-                 window=None, multipliers=None):
+                 window=None, multipliers=None, rotary_dim=None,
+                 gated_query: bool = False, unit_offset_norms: bool = False):
         self.c = config
         self.moe = moe
         self.multipliers = dict(multipliers or {})
+        self.rotary_dim, self.gated_query = rotary_dim, bool(gated_query)
+        self.unit_offset_norms = bool(unit_offset_norms)
         self.scale = config.head_dim ** -0.5
         self.attn_leaf, self.cache_layer = attn_leaf, cache_layer
         self.rotated = frozenset(rotated)
@@ -194,8 +245,8 @@ class BlockDecoder(GroupedHeads, Module):
 
     # ---- pieces of a layer ----
     def rope_at(self, pos):
-        """cos/sin [..., head_dim / 2] float32 at absolute positions."""
-        d = self.c.head_dim
+        """cos/sin [..., rotated dims / 2] float32 at absolute positions."""
+        d = self.rotary_dim or self.c.head_dim
         inv = 1.0 / self.c.rope_theta ** (
             jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         ang = pos.astype(jnp.float32)[..., None] * inv
@@ -232,7 +283,8 @@ class BlockDecoder(GroupedHeads, Module):
         c = self.c
         window = self.window.get(l)
         al = self.attn_leaf[l]
-        q, k, v = self._qkv(pa, al, a, call.cos, call.sin, l in self.rotated)
+        q, k, v, *gate = self._qkv(pa, al, a, call.cos, call.sin,
+                                   l in self.rotated)
         b, s = k.shape[:2]
         scope = "hetu.attn.window" if window else "hetu.attn.full"
         if call.k is None:
@@ -245,14 +297,15 @@ class BlockDecoder(GroupedHeads, Module):
                     jnp.moveaxis(k, 1, 2)[:, :, None],
                     jnp.moveaxis(v, 1, 2)[:, :, None],
                     scale=self.scale, window=window)
-            return self._out(pa, al, o.reshape(b, c.num_heads, s, c.head_dim))
+            return self._out(pa, al, o.reshape(b, c.num_heads, s, c.head_dim),
+                             *gate)
         g, cl = self.cache_layer[l]
         with jax.named_scope(scope):
             if call.one_query and window is None:
                 o, call.k[g], call.v[g] = ops.decode_layer_attention(
                     q, k, v, call.k[g], call.v[g], cl, call.at,
                     scale=self.scale)
-                return self._out(pa, al, o)
+                return self._out(pa, al, o, *gate)
             update = ops.ring_update if window else ops.cache_update
             k_view, v_view = call.k[g].read(cl), call.v[g].read(cl)
             t = k_view.shape[1]
@@ -262,7 +315,7 @@ class BlockDecoder(GroupedHeads, Module):
             o = call.attention(q, k_view, v_view, window)
         call.k[g] = call.k[g].write(cl, k)
         call.v[g] = call.v[g].write(cl, v)
-        return self._out(pa, al, o)
+        return self._out(pa, al, o, *gate)
 
     def _layer(self, p, l: int, h, call: LayerCall):
         """Layer ``l`` over ``h`` [B, S, H] in the call ``call``, ``p`` the
@@ -273,10 +326,11 @@ class BlockDecoder(GroupedHeads, Module):
         if l < self.c.first_dense:
             return h + self._ffn(p["ffn"], l, u), jnp.zeros((4,), jnp.int32)
         moe, e = p["moe"], l - self.c.first_dense
-        m, stats = self.moe.apply(
-            dict(moe, router=moe["router"][e],
-                 router_bias=moe["router_bias"][e]),
-            u, layer=e)
+        # the router's leaves are this layer's; a router with no correction
+        # bias has no such leaf
+        own = {name: moe[name][e] for name in ("router", "router_bias")
+               if name in moe}
+        m, stats = self.moe.apply(dict(moe, **own), u, layer=e)
         return h + m, stats
 
     def _embed(self, p, ids):

@@ -55,8 +55,9 @@ import jax.numpy as jnp
 
 from hetu_tpu import ops
 from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
-from hetu_tpu.models.block import FULL, BlockDecoder, LayerCall, draw_leaf
-from hetu_tpu.ops.moe_ops import held_expert_path
+from hetu_tpu.models.block import (
+    FULL, BlockDecoder, LayerCall, counts_with_grouped, draw_leaf,
+)
 
 CONV = "conv"
 
@@ -236,11 +237,4 @@ class Lfm2MoeModel(BlockDecoder):
             return ops.linear(gate_out * c, p["out"][cl].astype(dt))
 
     def _counts(self, stats):
-        c = self.c
-        # the rule reads an expert's size alone, so one token stands for a
-        # call of any row count
-        grouped = held_expert_path(1, c.moe_topk, c.held[1], c.hidden_size,
-                                   c.expert_ffn_size) == "grouped"
-        return jnp.concatenate([stats, jnp.stack([
-            jnp.int32(c.held[1] * (c.num_layers - c.first_dense)),
-            stats[0] * int(grouped)])])
+        return counts_with_grouped(self.c, stats)

@@ -23,7 +23,9 @@ new one back in float32:
 
 Beside them what the layer puts round the recurrence: the causal depth-wise
 convolution over ``[state | new rows]`` (:func:`causal_conv`) and the gated
-RMS norm over groups of channels (:func:`gated_group_rms_norm`).  Plain
+RMS norm over groups of channels (:func:`gated_group_rms_norm`).  The
+convolution also serves the Gated DeltaNet mixer, whose recurrence is NOT
+this one (its update reads the state before it writes it: ``ops/delta_rule.py``).  Plain
 ``jax.numpy`` / ``lax``; decays, their cumulative sums and the state in
 float32 whatever the rows' dtype, the matmuls over the rows' dtype with
 float32 sums.

@@ -861,6 +861,46 @@ def test_a_cache_of_a_two_part_spec_holds_an_array_a_part_a_layer():
         and KVCacheSpec(2, 2, 8).bytes_per_slot == 0
 
 
+def test_two_part_state_layers_beside_fewer_cache_layers_than_layers():
+    """A model of eight layers, two of them cache layers of ONE page group
+    and six state layers of two parts (ISSUE 51): the pools hold the two,
+    the state an array a part a state layer, a slot's pages and its state
+    are counted apart, and a slot handed on reads as zeros in both parts
+    while its pages are its own."""
+    spec = KVCacheSpec(2, 2, 16, dtype=jnp.bfloat16, state_layers=6,
+                       state_parts=(("conv", (3 * 64,), jnp.bfloat16),
+                                    ("delta", (4, 8, 8), jnp.float32)))
+    assert len(spec.groups) == 1 and spec.num_layers == 2
+    assert spec.bytes_per_token == 2 * 2 * (16 + 16) * 2
+    assert spec.part_bytes_per_slot == {"conv": 6 * 192 * 2,
+                                        "delta": 6 * 256 * 4}
+    cache = PagedKVCache(spec, 4, 64, page_size=4)
+    assert cache.k.shape[0] == cache.v.shape[0] == 2
+    conv, delta = cache.state
+    assert [(a.shape, a.dtype) for a in conv] == 6 * [((5, 192), jnp.bfloat16)]
+    assert [(a.shape, a.dtype) for a in delta] \
+        == 6 * [((5, 4, 8, 8), jnp.float32)]
+    assert cache.state_bytes == 5 * spec.bytes_per_slot
+    slot = cache.alloc()
+    cache.prepare_write(slot, 0, 9)                 # three pages of four
+    assert cache.pages_in_use == 3
+    # the slot's last owner left something in both parts of state layer 4
+    cache.state = tuple(tuple(a.at[slot].set(3.0) for a in part)
+                        for part in cache.state)
+    fresh = SlotStates(cache.state, jnp.asarray([slot]),
+                       jnp.asarray([True]))
+    assert all(float(jnp.abs(r).max()) == 0.0 for r in fresh.read(4))
+    again = SlotStates(cache.state, jnp.asarray([slot]),
+                       jnp.asarray([False]))
+    assert all(float(r.min()) == 3.0 for r in again.read(4))
+    # a round's whole-layer update of the large part leaves the small one
+    rows = again.whole(4, 1)
+    out = again.put_whole(4, 1, rows + 1.0)
+    assert float(out.rows[1][4].min()) == 1.0       # the idle rows' too
+    assert out.rows[0] is again.rows[0]
+    assert all(out.rows[1][l] is again.rows[1][l] for l in (0, 1, 2, 3, 5))
+
+
 def test_state_layers_over_a_mesh_stay_refused_whatever_their_parts():
     from jax.sharding import NamedSharding, PartitionSpec as P
 

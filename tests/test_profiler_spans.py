@@ -660,6 +660,72 @@ def test_a_two_part_states_ids_and_the_mixers_plan_and_scopes(tmp_path):
         *(f"serve.prefill_chunk.{s}" for s in SEAMS)}
 
 
+def test_a_delta_rule_models_plan_scopes_and_state_gauges_by_part(tmp_path):
+    """Gated DeltaNet state layers of two parts beside FEWER cache layers
+    than layers (ISSUE 51): ``serve.cache_spec``, both ``post`` spans and the
+    gauges state the state by part (``state_conv_bytes``,
+    ``state_delta_bytes``) beside the expert layers' counts; ONE ``gdn.plan``
+    instant a program traced says which form it holds (a chunk solves in
+    chunks, a round steps) and how the triangular system is solved; the
+    programs carry the mixer's scopes."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextModel
+
+    model = Qwen3NextModel(Qwen3NextConfig(
+        vocab_size=97, hidden_size=32, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=16, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=8, gdn_value_dim=8, gdn_chunk=8, expert_ffn_size=16,
+        shared_ffn_size=16, n_routed_experts=16, moe_topk=4, held=(4, 4),
+        max_position=128, dtype=jnp.float32, param_dtype=jnp.float32))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=128,
+                               page_size=4, prefill_chunk=8, min_bucket=4)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    conv, delta = (sum(a.nbytes for a in part) for part in eng.cache.state)
+    per = eng.cache.spec.part_bytes_per_slot
+    assert (spec[3]["state_layers"], spec[3]["cache_layers"]) == (3, 1)
+    assert spec[3]["bytes_per_slot"] == per["conv"] + per["delta"]
+    assert spec[3]["state_conv_bytes"] == conv == 5 * per["conv"]
+    assert spec[3]["state_delta_bytes"] == delta == 5 * per["delta"]
+    posts = _named(events, "serve.decode.post") \
+        + _named(events, "serve.prefill_chunk.post")
+    keys = {"state_slots_held", "state_bytes", "state_conv_bytes",
+            "state_delta_bytes", "kv_bytes_held", "kv_pages_full",
+            *model.step_stats}
+    assert posts and all(set(e[3]) == keys for e in posts)
+    for e in posts:
+        ids = e[3]
+        assert ids["state_bytes"] == ids["state_conv_bytes"] \
+            + ids["state_delta_bytes"] == ids["state_slots_held"] \
+            * (per["conv"] + per["delta"])
+        assert ids["moe_experts"] == 4 * 4          # held x expert layers
+        assert ids["moe_grouped"] == ids["moe_held"]
+    snap = eng.metrics.snapshot()
+    assert snap["state_conv_bytes"] > 0 and snap["state_delta_bytes"] > 0
+    plans = [e[3] for e in _named(events, "gdn.plan")]
+    assert len(plans) == eng.compiled_executables()   # one a program traced
+    assert {p["form"] for p in plans} == {"chunk", "step"}
+    for p in plans:
+        assert (p["chunk"], p["heads_k"], p["heads_v"], p["d_k"], p["d_v"],
+                p["solve"]) == (8, 2, 4, 8, 8, "product")
+        assert p["state_bytes_per_slot"] * 3 == per["conv"] + per["delta"]
+        assert p["rows"] == 1 if p["form"] == "step" else p["batch"] == 1
+    from paged_programs import traced
+    shared = ("hetu.gdn.proj", "hetu.gdn.conv", "hetu.gdn.norm",
+              "hetu.attn.full", "hetu.moe.route", "hetu.moe.experts",
+              "hetu.moe.shared")
+    for name, own, other in (("decode", "hetu.gdn.step", "hetu.gdn.rule"),
+                             ("chunk", "hetu.gdn.rule", "hetu.gdn.step")):
+        text = traced(eng, name, batch=4, chunk=8).lower().as_text(
+            debug_info=True)
+        assert all(scope in text for scope in shared + (own,)), name
+        assert other not in text, name
+
+
 # ------------------------------------------ a trained expert model's counts
 
 def _expert_model():
