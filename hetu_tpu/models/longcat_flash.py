@@ -49,12 +49,26 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from hetu_tpu import ops
 from hetu_tpu.layers.base import (
     HELD_TRANSPOSED, Module, held_transposed, linear_held,
 )
 from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
+from hetu_tpu.ops.pallas_kernels.flash_attention import (
+    flash_chunk_attention, unwritten, write_rows,
+)
+
+
+# A chunk's expanded attention rebuilds K and V of all heads for the flash
+# kernel when a key block's float32 scores, heads x queries x keys, are at
+# least this many.  The rebuild costs the same a block of 1,024 keys whatever
+# the chunk, the walk's softmax passes 1.2 ms at 512 queries of 64 heads, 0.65
+# at 256 and 0.38 at 128; the line was drawn when the rebuild still cost 0.9
+# ms a block and has not been measured again at its 0.2 (my chip runs, PR 52:
+# PERF.md section 6, ROADMAP S9 (b)).
+REBUILD_MIN_SCORES = 1 << 25
 
 
 @dataclass
@@ -163,17 +177,26 @@ class LatentAttention:
     def expanded(self, p, q_n, q_r, c_all, r_all, q_pos, *,
                  static_trip: bool = False):
         """Expanded attention of queries at absolute positions ``q_pos``
-        [B, S] over latents ``c_all`` [B, T, kv_rank] and rotated keys
-        ``r_all`` [B, T, rope]: key ``t`` is seen by a query at position
-        ``>= t``.  The keys are walked ``attn_key_block`` at a time with a
-        running softmax: a block's keys and values of all heads are rebuilt
-        from its latents, so the heads-wide history is never whole and no
-        [heads, S, T] scores are; and the walk ends at the last key any
-        query can see, so a chunk's cost follows its history and not the
-        width of the table it was handed.  That trip count is read from
-        ``q_pos``; under ``static_trip`` (reverse-mode differentiation
-        needs a static one) every block of the table is walked.  Returns
-        [B, S, H]."""
+        [B, S] (a chunk: row ``i`` at ``q_pos[b, 0] + i``) over latents
+        ``c_all`` [B, T, kv_rank] and rotated keys ``r_all`` [B, T, rope]:
+        key ``t`` is seen by a query at position ``>= t``.  The keys are
+        walked ``attn_key_block`` at a time as far as the last key any query
+        can see, so a chunk's cost follows its history and not the width of
+        the table it was handed.  That trip count is read from ``q_pos``;
+        under ``static_trip`` (reverse-mode differentiation needs a static
+        one) every block of the table is walked.
+
+        Two forms of one mathematics, chosen on what is observed here
+        (``ops.chunk_kernel_why``; a ``chunk_attn.plan`` instant says which
+        and why).  On a TPU backend with no mesh in context, not under
+        ``static_trip``, and with queries enough that a block's scores are
+        the larger cost (``REBUILD_MIN_SCORES``; else ``few_queries``): the
+        walk only REBUILDS the keys and values of all heads from the
+        latents, block by block into two head-major arrays, and one chunk
+        call of the flash forward kernel attends over them at 192 | 128,
+        its score tiles in VMEM.  Anywhere else the walk itself attends
+        under a running softmax: the heads-wide history is never whole, but
+        each block's float32 scores go through HBM.  Returns [B, S, H]."""
         cfg = self.c
         nope = cfg.qk_nope_head_dim
         b, s = q_pos.shape
@@ -182,6 +205,53 @@ class LatentAttention:
         blocks = -(-t // kb)
         kv_b = self._kv_b(p)
         heads = cfg.num_heads
+        trips = blocks if static_trip else jnp.minimum(
+            jnp.max(q_pos) // kb + 1, blocks)
+        why = "static_trip" if static_trip else ops.chunk_kernel_why() \
+            or ("" if heads * s * kb >= REBUILD_MIN_SCORES else "few_queries")
+        ops.chunk_plan(jax.ShapeDtypeStruct(
+            (b, heads, s, cfg.qk_head_dim), cfg.dtype), t, heads,
+            cfg.v_head_dim, why)
+
+        if not why:
+            # a head's rebuilt keys lie [key, width] as the kernel reads
+            # them; left to itself the compiler lays the products keys-minor
+            # and relays both arrays whole after the walk
+            rows_major = Layout(major_to_minor=(0, 1, 2, 3))
+            # keys and queries padded with zeros to whole lane tiles (192 ->
+            # 256: what a tiled array holds and the MXU multiplies anyway),
+            # so that a block's rows can be put in place by a DMA
+            lanes = -cfg.qk_head_dim % 128
+
+            def fill(j, kv_all):
+                at = jnp.minimum(j * kb, t - kb)
+                c_blk = jax.lax.dynamic_slice_in_dim(c_all, at, kb, 1)
+                r_blk = jax.lax.dynamic_slice_in_dim(r_all, at, kb, 1)
+                k_n, v_blk = (with_layout_constraint(
+                    jnp.einsum("btc,chd->bhtd", c_blk, part), rows_major)
+                    for part in (kv_b[..., :nope], kv_b[..., nope:]))
+                k_blk = jnp.concatenate([k_n, jnp.broadcast_to(
+                    r_blk[:, None], (b, heads) + r_blk.shape[1:]),
+                    jnp.zeros((b, heads, kb, lanes), cfg.dtype)], -1)
+                return tuple(
+                    write_rows(whole, blk, at, multiple_of=math.gcd(kb, t))
+                    for whole, blk in zip(kv_all, (k_blk, v_blk)))
+
+            # nobody writes the rows past the walk's end and the kernel
+            # fetches none of them: its K blocks lie inside the walk's, and
+            # a block it does not step over is not fetched.  The table's
+            # padding to whole blocks it may fetch (masked: zeros)
+            k_all, v_all = (unwritten((b, heads, blocks * kb, d), cfg.dtype)
+                            for d in (cfg.qk_head_dim + lanes, cfg.v_head_dim))
+            if blocks * kb > t:
+                k_all, v_all = (x.at[:, :, t:].set(0) for x in (k_all, v_all))
+            k_all, v_all = jax.lax.fori_loop(0, trips, fill, (k_all, v_all))
+            out = flash_chunk_attention(
+                jnp.moveaxis(jnp.concatenate([q_n, q_r, jnp.zeros(
+                    q_n.shape[:3] + (lanes,), cfg.dtype)], -1), 1, 2),
+                k_all, v_all, q_pos[:, 0], scale=self.scale,
+                block_k=math.gcd(kb, 512), head_major=True)
+            return self._out(p, jnp.moveaxis(out, 1, 2))
 
         def block(j, carry):
             m, l, acc = carry
@@ -211,8 +281,6 @@ class LatentAttention:
         carry = (jnp.full((b, heads, s), -1e30, jnp.float32),
                  jnp.zeros((b, heads, s), jnp.float32),
                  jnp.zeros((b, s, heads, cfg.v_head_dim), jnp.float32))
-        trips = blocks if static_trip else jnp.minimum(
-            jnp.max(q_pos) // kb + 1, blocks)
         _, l, acc = jax.lax.fori_loop(0, trips, block, carry)
         return self._out(p, acc / jnp.moveaxis(l, 1, 2)[..., None])
 

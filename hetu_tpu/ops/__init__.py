@@ -64,8 +64,9 @@ from hetu_tpu.ops.moe_ops import (
 )
 from hetu_tpu.ops.attention import (
     attention, cache_update, causal_attention, chunk_attention,
-    decode_attention, decode_layer_attention, read_cache_layer, remat,
-    ring_update, scan_cached_layers, scan_layers_over_caches, write_cache_layer,
+    chunk_kernel_why, chunk_plan, decode_attention, decode_layer_attention,
+    read_cache_layer, remat, ring_update, scan_cached_layers,
+    scan_layers_over_caches, write_cache_layer,
 )
 from hetu_tpu.ops.graph_ops import (
     coo_spmm, gcn_norm, gcn_conv,

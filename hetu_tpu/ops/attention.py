@@ -16,7 +16,9 @@ import jax
 import jax.numpy as jnp
 
 from hetu_tpu.ops.pallas_kernels import paged_attention
-from hetu_tpu.ops.pallas_kernels.flash_attention import SAVED_LSE, SAVED_OUT
+from hetu_tpu.ops.pallas_kernels.flash_attention import (
+    SAVED_LSE, SAVED_OUT, flash_chunk_attention,
+)
 from hetu_tpu.parallel.mesh import AXIS_TP
 from hetu_tpu.telemetry import trace
 from hetu_tpu.utils.platform import (
@@ -306,6 +308,37 @@ def _attend_blocks(q, k_cache, v_cache, pos, scale, block: int):
         b, nh, s, v_cache.shape[-1])
 
 
+def chunk_kernel_why() -> str:
+    """Why a chunk's attention over a long view can NOT run in the flash
+    forward kernel (``pallas_kernels.flash_attention.flash_chunk_attention``)
+    where this is traced, "" when it can: ``backend``, not a TPU (the
+    interpreter would run); ``sharded``, a mesh is in context (the serving
+    engine traces its chunk program under the mesh it laid its pools over),
+    and the partitioner cannot split a Mosaic call."""
+    if not _default_backend_is_tpu():
+        return "backend"
+    if not jax.sharding.get_abstract_mesh().empty:
+        return "sharded"
+    return ""
+
+
+def chunk_plan(q, rows: int, kv_heads: int, d_v: int, why: str):
+    """Which way a chunk's attention went is fixed when the program is
+    traced: one instant per attention built says whether the flash forward
+    kernel runs it (``kernel`` 1, ``why`` "") or an XLA composition does,
+    and then why (:func:`chunk_kernel_why`'s reasons; ``short``, a view of
+    at most ``KEY_BLOCK`` rows; ``window``, a ring; ``static_trip``, a walk
+    that must be reverse-differentiable, and ``few_queries``, a chunk too
+    short to pay for rebuilding the keys, both of
+    ``LatentAttention.expanded``), as ``paged_attn.plan`` does for a decode
+    round.  q: [B, heads, S_c, D]; ``rows``: the view's length."""
+    b, nh, s_c, d = q.shape
+    trace.instant("chunk_attn.plan", {
+        "kernel": int(not why), "why": why, "heads": nh,
+        "kv_heads": int(kv_heads), "d": d, "d_v": int(d_v), "s_c": s_c,
+        "rows": int(rows), "batch": b})
+
+
 def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
     """Multi-token chunk attention against a cache (the chunked-prefill /
     prefix-sharing core, GQA-aware).
@@ -325,9 +358,20 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
     rides on.
 
     kv_heads < heads (GQA): the query heads are grouped by the KV head they
-    read and the view is used as it is, never repeated.  A view longer than
-    ``KEY_BLOCK`` is walked in key blocks under a running softmax, as far
-    as the chunk's last position (:func:`_attend_blocks`).
+    read and the view is used as it is, never repeated.
+
+    **A view longer than** ``KEY_BLOCK`` is walked in key blocks under a
+    running softmax, as far as the chunk's last position, and the rule for
+    who walks it is on what is observed here (:func:`chunk_kernel_why`).  On
+    a TPU backend with no mesh in context it is a CHUNK CALL of the flash
+    forward kernel: the score and probability tiles kept in VMEM, the
+    causal offset each sequence's own ``starts[b]``, and the view read where
+    it lies at head widths that are whole lane tiles (128, 256; others from
+    a head-major copy).  Anywhere else :func:`_attend_blocks`
+    walks it, the same mathematics as a ``fori_loop`` of XLA operations
+    whose float32 score blocks go through HBM: the portable path and the
+    tests' oracle.  A ``chunk_attn.plan`` instant (:func:`chunk_plan`) says
+    which, and why, when the program is traced.
 
     ``window``: the layer sees the last ``window`` positions only, and the
     cache is the window group's RING of ``T`` rows (:func:`ring_update`
@@ -338,8 +382,14 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     nh, nkv = q.shape[1], k_cache.shape[2]
-    if window is not None or nkv != nh or k_cache.shape[1] > KEY_BLOCK:
-        t, s_c = k_cache.shape[1], q.shape[-2]
+    t, s_c = k_cache.shape[1], q.shape[-2]
+    why = "window" if window is not None else "short" if t <= KEY_BLOCK \
+        else chunk_kernel_why()
+    chunk_plan(q, t, nkv, v_cache.shape[-1], why)
+    if not why:
+        return flash_chunk_attention(q, k_cache, v_cache, starts,
+                                     scale=scale)
+    if why != "short" or nkv != nh:
         pos = starts[:, None] + jnp.arange(s_c)              # [B, S_c]
         if window is not None:
             held = _ring_positions(t, starts + s_c - 1)      # [B, T]
@@ -358,8 +408,6 @@ def chunk_attention(q, k_cache, v_cache, starts, *, scale=None, window=None):
     v = jnp.moveaxis(v_cache, 1, 2)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
-    t = k_cache.shape[1]
-    s_c = q.shape[-2]
     pos = starts[:, None] + jnp.arange(s_c)                  # [B, S_c]
     valid = jnp.arange(t)[None, None, :] <= pos[:, :, None]  # [B, S_c, T]
     scores = jnp.where(valid[:, None], scores,

@@ -1,4 +1,6 @@
-from hetu_tpu.ops.pallas_kernels.flash_attention import flash_attention
+from hetu_tpu.ops.pallas_kernels.flash_attention import (
+    flash_attention, flash_chunk_attention,
+)
 from hetu_tpu.ops.pallas_kernels.embedding import (
     embedding_gather, embedding_scatter_add, topk_gating, routed_gather,
 )

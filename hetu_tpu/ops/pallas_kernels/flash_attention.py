@@ -6,7 +6,8 @@ softmax ops, layers/attention.py); on TPU the fusion matters because the
 
 One algorithm — online softmax over tiles, forward, and the FlashAttention-2
 two-kernel backward — and two ways of feeding it, chosen from the operand
-shapes (:func:`_resident`), never by a caller:
+shapes (:func:`_resident`), never by a caller (a CHUNK call, further down,
+is the streamed forward with a traced diagonal and a traced walk length):
 
   * **resident** (a row's operands fit VMEM; every shape up to S of a few
     thousand): one program owns a whole ROW of tiles.  Forward and dQ run on
@@ -93,11 +94,30 @@ Neither a repeated copy of K or V nor a ``[B, heads, S, D]`` dK or dV ever
 exists in HBM.  With ``g = 1`` and no window every call traces to the
 kernels it traced to before either existed, and every call without a window
 to the kernels it traced to before the walked axis followed the spans.
+
+**A chunk call** (:func:`flash_chunk_attention`; serving's chunked prefill,
+``ops.chunk_attention`` and ``LatentAttention.expanded``): ``S_c`` queries of
+each sequence against a longer history of ``T`` keys, FORWARD only.  The
+diagonal is not where the lengths put it but where the sequence stands:
+query ``i`` sits at position ``starts[b] + i``, ``starts`` [B] traced, handed
+to the kernel and its index maps by scalar prefetch, and ``_Walk``'s span
+arithmetic, which already runs on traced block indices, reads it where a
+training call reads ``s_k - s_q`` (:meth:`_Walk.from_start`).  It is always
+the streamed forward, the same tile bodies: the walked axis of the grid is a
+TRACED bound, the K blocks up to the one the call's longest history ends in,
+so a chunk's cost follows its history and not the width of the table it was
+handed; a sequence of the call with a shorter history ends in dead steps
+clamped as above, and keys past its chunk (unwritten rows, a page's stale
+occupant, padding) are masked by the diagonal.  No LSE leaves and there is
+no backward: what trains has static lengths and calls
+:func:`flash_attention`.
+
 Interpret mode runs the same kernels on CPU for correctness tests.
 """
 
 from __future__ import annotations
 
+import copy
 import functools
 import math
 
@@ -172,6 +192,15 @@ class _Walk:
         # wiped by the correction of the first tile that holds a real score
         # (exp(-1e30 - m) is 0), and the diagonal tile always does.
         self.guard = causal and self.offset < 0
+
+    def from_start(self, start):
+        """This walk with the diagonal where a CHUNK's puts it: query row 0
+        sits at key position ``start`` (traced: a sequence's entry of the
+        prefetched ``starts``), not at ``s_k - s_q``.  ``start >= 0``, so
+        every row sees key 0 and nothing needs the guard."""
+        w = copy.copy(self)
+        w.offset = start
+        return w
 
     def q_block(self, q):
         return q * jnp.asarray(self.scale, q.dtype) if self.fold else q
@@ -321,7 +350,8 @@ def _fwd_init(w, d):
 def _fwd_finish(o_ref, lse_ref, m, l, acc):
     l = jnp.maximum(l, 1e-20)
     o_ref[:] = (acc / l).T.astype(o_ref.dtype)
-    lse_ref[:] = m + jnp.log(l)
+    if lse_ref is not None:         # a chunk call has no backward to read it
+        lse_ref[:] = m + jnp.log(l)
 
 
 def _p_tile(w, q, k, lse, mask):
@@ -424,11 +454,11 @@ def _dkdv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 # ------------------------------------------------------- streamed kernels
 
-def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                         acc_ref, *, w):
+def _fwd_streamed_step(w, last, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
+                       l_ref, acc_ref):
     """Program (bh, qi, j): one tile, K block ``ki`` of the walk's step j;
     m/l [1, block_q] and acc^T [D, block_q] carry the online-softmax state
-    across the walk in scratch."""
+    across the walk in scratch, and step ``last`` writes the results."""
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -445,9 +475,27 @@ def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     _step(spans, ki, tile)
 
-    @pl.when(j == w.steps(True) - 1)
+    @pl.when(j == last)
     def _():
         _fwd_finish(o_ref, lse_ref, m_ref[:], l_ref[:], acc_ref[:])
+
+
+def _fwd_streamed_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
+                         acc_ref, *, w):
+    _fwd_streamed_step(w, w.steps(True) - 1, q_ref, k_ref, v_ref, o_ref,
+                       lse_ref, m_ref, l_ref, acc_ref)
+
+
+def _fwd_chunk_kernel(starts_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                      acc_ref, *, w, heads):
+    """The streamed forward step of a CHUNK call: the diagonal is where the
+    program's sequence starts (``starts_ref`` [B], prefetched), and the
+    walked axis is as long as the call's longest live walk, a traced bound
+    of the grid."""
+    _fwd_streamed_step(
+        w.from_start(starts_ref[pl.program_id(0) // heads]),
+        pl.num_programs(2) - 1, q_ref, k_ref, v_ref, o_ref, None, m_ref,
+        l_ref, acc_ref)
 
 
 def _dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -548,13 +596,16 @@ def _params(grid):
         ("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2)))
 
 
-def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window, grid):
+def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window, grid,
+          **more):
     """Which feeding a kernel got is fixed when the program is traced: one
     instant per pallas_call built says so in a JSONL trace or the xplane of
     a profiled compile.  ``tiles_live`` is what the call's walk visits (every
     head's), ``tiles_causal`` what it would without the window, ``steps``
     the steps of the call's grid: of a streamed call those that run a tile
-    and the dead ones."""
+    and the dead ones (of a chunk call, both for a chunk that ends the
+    view: its walk's traced length is at most that).  ``more``: a kernel's
+    own facts."""
     (b, h, s_q, d), s_k = q.shape, k.shape[2]
     plain = _Walk(s_q=s_q, s_k=s_k, block_q=w.bq, block_k=w.bk,
                   scale=w.scale, causal=w.causal)
@@ -563,7 +614,8 @@ def _plan(kernel: str, resident: bool, w: _Walk, q, k, v, window, grid):
         "block_k": w.bk, "s_q": s_q, "s_k": s_k, "d": d,
         "d_v": v.shape[3], "window": int(window or 0),
         "kv_heads": k.shape[1], "tiles_live": b * h * w.tiles(),
-        "tiles_causal": b * h * plain.tiles(), "steps": math.prod(grid)})
+        "tiles_causal": b * h * plain.tiles(), "steps": math.prod(grid),
+        **more})
 
 
 def _live(i, spans, n, *, ends: str = "both"):
@@ -679,6 +731,77 @@ def _flash_fwd(q, k, v, *, scale, causal, window, block_q, block_k,
     )(q.reshape(b * h, s_q, d), k.reshape(b * h_kv, s_k, d),
       v.reshape(b * h_kv, s_k, d_v))
     return out.reshape(b, h, s_q, d_v), lse
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_q", "block_k", "head_major", "interpret"))
+def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
+                 interpret):
+    """The forward of a chunk call, q [B, H, S_c, D_qk] against the
+    TIME-major k [B, T, H / g, D_qk] and v [B, T, H / g, D_v] (``head_major``:
+    [B, H / g, T, .]).  Jitted: a program that attends in several places
+    (layers outside a scan, the branches of a switch) traces and lowers the
+    kernel once.
+
+    Where both widths of a time-major view are whole lane tiles (128, 256)
+    it is fed AS IT LIES, its flat rows [B, T, (H / g) * D] with a KV head a
+    column block of the index map: no copy of it is made.  Any other width
+    (64; 192 | 128) cannot be a column block, and the view is relaid
+    head-major first, two passes over it; operands given head-major are fed
+    as they lie."""
+    b, h, s_q, d = q.shape
+    t, h_kv = (k.shape[2], k.shape[1]) if head_major else k.shape[1:3]
+    d_v = v.shape[3]
+    # a view that is not a whole number of K blocks is padded to one, as it
+    # is fed: the keys there lie past every chunk
+    s_k = t + -t % min(block_k, t)
+    w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
+              block_k=_fit_block(s_k, block_k), scale=scale, causal=True,
+              group=h // h_kv)
+    # the K blocks up to the one the last chunk of the call ends in: the
+    # walked axis of the grid, a traced bound
+    steps = jnp.clip((jnp.max(starts) + s_q + w.bk - 1) // w.bk, 1, w.n_k)
+    grid = (b * h, w.n_q, steps)
+    as_rows = not head_major and d % 128 == 0 and d_v % 128 == 0
+    _plan("fwd_chunk", False, w, q,
+          jax.ShapeDtypeStruct((b, h_kv, s_k, d), k.dtype),
+          jax.ShapeDtypeStruct((b, h_kv, s_k, d_v), v.dtype), None,
+          (b * h, w.n_q, w.n_k), as_rows=int(as_rows))
+
+    def q_at(bh, qi, j, starts):
+        return bh, qi, 0
+
+    def k_at(bh, qi, j, starts):
+        spans = w.from_start(starts[bh // h]).k_spans(qi)
+        at = _live(j, spans, w.n_k, ends="last")
+        if as_rows:
+            return bh // h, at, bh % h // w.g
+        return bh // w.g, at, 0
+
+    def fed(x):
+        if as_rows:
+            x = x.reshape(b, t, -1)
+        else:
+            x = (x if head_major else jnp.moveaxis(x, 1, 2)).reshape(
+                b * h_kv, t, -1)
+        return x if t == s_k else jnp.pad(x, ((0, 0), (0, s_k - t), (0, 0)))
+
+    out = pl.pallas_call(
+        functools.partial(_fwd_chunk_kernel, w=w, heads=h),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[pl.BlockSpec((None, w.bq, d), q_at),
+                      pl.BlockSpec((None, w.bk, d), k_at),
+                      pl.BlockSpec((None, w.bk, d_v), k_at)],
+            out_specs=pl.BlockSpec((None, w.bq, d_v), q_at),
+            scratch_shapes=[pltpu.VMEM((1, w.bq), jnp.float32),
+                            pltpu.VMEM((1, w.bq), jnp.float32),
+                            pltpu.VMEM((d_v, w.bq), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b * h, s_q, d_v), q.dtype),
+        compiler_params=_params(grid),
+        interpret=interpret,
+    )(starts, q.reshape(b * h, s_q, d), fed(k), fed(v))
+    return out.reshape(b, h, s_q, d_v)
 
 
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, window, block_q,
@@ -851,3 +974,84 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     return shard_map(lambda q, k, v: _flash(q, k, v, *static),
                      in_specs=(spec, spec, spec), out_specs=spec,
                      axis_names=axes, check_vma=False)(q, k, v)
+
+
+def unwritten(shape, dtype, *, interpret=None):
+    """An array of ``shape`` that NOBODY WROTE: what a caller fills part of
+    (the keys and values a chunk call will walk, rebuilt as far as the
+    history goes) without paying a pass of zeros over the whole of it first.
+    Every element that is read must have been written; interpret mode hands
+    out NaNs where a float was not."""
+    return pl.pallas_call(
+        lambda out_ref: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY), name="unwritten",
+        interpret=auto_interpret(interpret))()
+
+
+def write_rows(whole, rows, at, *, multiple_of: int, interpret=None):
+    """``whole`` [B, H, T, D] with ``rows`` [B, H, n, D] put at key rows
+    ``at .. at + n`` of every head IN PLACE, ``at`` traced and a multiple of
+    ``multiple_of``: one DMA from HBM to HBM.  What
+    ``lax.dynamic_update_slice`` says, which the TPU's compiler runs as a
+    copy of its own at a quarter of the memory's bandwidth (0.30 ms for 25
+    MB: ``PERF.md`` section 6, PR 52), and which is what runs where ``at``
+    may fall inside a tile of 16 rows or ``D`` is not whole lane tiles: a
+    DMA cannot start or end there."""
+    if multiple_of % 16 or rows.shape[3] % 128:
+        return lax.dynamic_update_slice_in_dim(whole, rows, at, 2)
+    n = rows.shape[2]
+
+    def kernel(at_ref, rows_ref, _, out_ref, sem):
+        start = pl.multiple_of(at_ref[0], multiple_of)
+        copy = pltpu.make_async_copy(
+            rows_ref, out_ref.at[:, :, pl.ds(start, n)], sem)
+        copy.start()
+        copy.wait()
+
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(whole.shape, whole.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        input_output_aliases={2: 0}, name="write_rows",
+        interpret=auto_interpret(interpret),
+    )(jnp.reshape(at, (1,)).astype(jnp.int32), rows, whole)
+
+
+def flash_chunk_attention(q, k, v, starts, *, scale=None, block_q: int = 512,
+                          block_k: int = 512, head_major: bool = False,
+                          interpret=None):
+    """A CHUNK of queries against a longer history, forward only: q [B, H,
+    S_c, D_qk] whose row ``i`` sits at absolute position ``starts[b] + i``
+    (``starts`` [B] int32, traced); k [B, T, H / g, D_qk] and v [B, T,
+    H / g, D_v] TIME-major, as a cache's view lies, with ``T >= starts[b] +
+    S_c`` static -> [B, H, S_c, D_v].  ``head_major``: k and v are given
+    [B, H / g, T, .], as a caller that builds them for this call lays them.
+
+    Query ``i`` sees key ``t <= starts[b] + i``: the history and the chunk's
+    own triangle.  Keys past the chunk's last position (unwritten rows, a
+    page's stale occupant, padding) are masked, and the K blocks wholly past
+    it are never stepped over (the walked axis of the grid ends with the
+    longest history of the call) or, for a sequence shorter than that, are
+    dead steps that fetch nothing: the cost follows the history, not ``T``.
+    A ``T`` that is not a whole number of K blocks is padded to one (a copy
+    of the view); widths that are whole lane tiles are read from the view
+    where it lies, others from a head-major copy (:func:`_flash_chunk`).
+
+    There is no backward (``jax.grad`` through it fails): what trains calls
+    :func:`flash_attention`, whose lengths are static.  No mesh either: the
+    caller keeps a view laid over one away (``ops.chunk_attention``)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    heads = 1 if head_major else 2
+    if k.shape[heads] != v.shape[heads] or q.shape[1] % k.shape[heads]:
+        raise ValueError(
+            f"{q.shape[1]} query heads over {k.shape[heads]} key and "
+            f"{v.shape[heads]} value heads: K and V share a head count that "
+            "divides the queries'")
+    return _flash_chunk(q, k, v, starts.astype(jnp.int32),
+                        scale=float(scale), block_q=int(block_q),
+                        block_k=int(block_k), head_major=bool(head_major),
+                        interpret=auto_interpret(interpret))

@@ -59,6 +59,7 @@ row-parallel all-reduces inside both jitted steps.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 from typing import Optional
 
@@ -463,6 +464,9 @@ class PagedServeEngine:
         k_row, v_row = self.cache.spec.row_shapes()
         more = tuple(zip((g.spec.row_shapes() for g in self._more),
                          self._ring_chunk))
+        mesh = None if self.mesh is None else self.mesh.abstract_mesh
+        in_context = contextlib.nullcontext if mesh is None \
+            else jax.sharding.use_abstract_mesh
 
         def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux, *state):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
@@ -501,10 +505,13 @@ class PagedServeEngine:
             held = {"state": SlotStates(state[0], aux[-1:],
                                         (start == 0)[None])} if state else {}
             # a model may return a fourth value, its per-call counts
-            # (``model.step_stats`` names them); most return none
-            logits, k, v, *stats = model.prefill_chunk_with_cache(
-                {"params": params, "state": {}}, ids, k, v,
-                start, last_index=last, **held)
+            # (``model.step_stats`` names them); most return none.  The
+            # views it gathers do not say that the pools are laid over a
+            # mesh: the mesh in context does (``ops.chunk_kernel_why``)
+            with in_context(mesh):
+                logits, k, v, *stats = model.prefill_chunk_with_cache(
+                    {"params": params, "state": {}}, ids, k, v,
+                    start, last_index=last, **held)
             tok = jnp.argmax(logits[0], -1).astype(jnp.int32)
             if state:
                 *stats, held = stats

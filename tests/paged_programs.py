@@ -4,7 +4,9 @@ checks ``test_paged_kv.py`` (GPT, Llama) and ``test_longcat_flash.py`` share
 (ISSUE 31), how they read a weight and the tiny served families (ISSUE
 45), and the two token oracles every serving test holds the engine
 to, both independent of any engine, and the hold that lets a pool test
-catch a member mid-decode.  A helper module, no tests of its own."""
+catch a member mid-decode, and an engine's chunk programs with the flash
+forward kernel beside the XLA key-block walk (ISSUE 52).  A helper module,
+no tests of its own."""
 
 import hashlib
 import re
@@ -444,3 +446,52 @@ def submit_and_hold_mid_decode(member, requests, steps: int = 3) -> None:
         assert time.monotonic() < deadline, "the engine loop never stepped"
         time.sleep(0.005)
     assert all(r.tokens and not r.done.is_set() for r in requests)
+
+
+# ---- a chunk's attention over a long view: the kernel beside the walk ----
+
+def chunk_plans(monkeypatch) -> list:
+    """The ``chunk_attn.plan`` instants of the programs traced from here on
+    (``ops.chunk_plan``: one an attention built)."""
+    import sys
+
+    seen = []
+    monkeypatch.setattr(
+        sys.modules["hetu_tpu.ops.attention"].trace, "instant",
+        lambda name, attrs=None, cat="hetu": seen.append(attrs)
+        if name == "chunk_attn.plan" else None)
+    return seen
+
+
+def on_a_tpu(monkeypatch, tpu: bool = True) -> None:
+    """The rules of ``ops.attention`` read the backend: say TPU, and a chunk
+    over a long view takes the flash forward kernel and a decode round the
+    paged one (both interpreted on this CPU)."""
+    import sys
+
+    monkeypatch.setattr(sys.modules["hetu_tpu.ops.attention"],
+                        "_default_backend_is_tpu", lambda: tpu)
+
+
+def chunk_kernel_beside_the_walk(monkeypatch, model, variables, prompt,
+                                 n: int, **engine_kw):
+    """One request of several chunks through an engine whose chunk programs
+    walk the view with the XLA loop (this CPU's rule) and through one whose
+    chunk programs take the kernel: the worst distance between their logits
+    (every chunk's and ``n - 1`` decode rounds'), as a share of the logits'
+    range, with both runs' tokens and each run's plans."""
+    plans = chunk_plans(monkeypatch)
+    on_a_tpu(monkeypatch, False)
+    walk, _ = engine_logits(model, variables, prompt, n, **engine_kw)
+    walk_plans = list(plans)
+    del plans[:]
+    on_a_tpu(monkeypatch)
+    kernel, _ = engine_logits(model, variables, prompt, n, **engine_kw)
+    assert len(walk) == len(kernel) > n
+    worst = max(float(np.max(np.abs(a.astype(np.float32)
+                                    - b.astype(np.float32)))
+                      / (b.max() - b.min()).astype(np.float32))
+                for a, b in zip(kernel, walk))
+    tokens = [[int(np.argmax(row[0])) for row in rows[-n:]]
+              for rows in (walk, kernel)]
+    return worst, tokens, walk_plans, list(plans)

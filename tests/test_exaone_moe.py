@@ -213,6 +213,47 @@ def test_chunked_prefill_and_decode_in_bfloat16(monkeypatch):
     assert 1e-4 < err < 0.15
 
 
+def test_chunk_programs_with_the_flash_kernel_give_the_walks_logits(
+        monkeypatch):
+    """A prompt of five chunks over views of 64 rows, past a ``KEY_BLOCK``
+    scaled down to 16 with them: the chunk programs whose full layer takes
+    the flash forward kernel (interpreted) give the tokens of the programs
+    that walk the view's key blocks in XLA, and their logits within the
+    float32 tolerance; the window layers read their rings either way."""
+    import sys
+
+    from paged_programs import chunk_kernel_beside_the_walk
+
+    monkeypatch.setattr(sys.modules["hetu_tpu.ops.attention"], "KEY_BLOCK",
+                        16)
+    model, v = make()
+    worst, (walk, kernel), walk_plans, plans = chunk_kernel_beside_the_walk(
+        monkeypatch, model, v, ids_of(37, seed=5).tolist(), 3, num_slots=2,
+        max_len=64, page_size=4, prefill_chunk=8, min_bucket=4)
+    assert kernel == walk and worst < F32_TOL
+    c = model.c
+    full = {(p["kernel"], p["why"]) for p in plans if p["why"] != "window"}
+    assert full == {(1, "")} and {
+        (p["kernel"], p["why"]) for p in walk_plans
+        if p["why"] != "window"} == {(0, "backend")}
+    assert {(p["heads"], p["kv_heads"], p["d"], p["rows"]) for p in plans
+            if p["kernel"]} == {(c.num_heads, c.num_kv_heads, c.head_dim, 64)}
+
+
+def test_a_view_within_a_key_block_is_short_on_any_backend(monkeypatch):
+    """Under ``KEY_BLOCK`` rows the whole view is one softmax and no kernel
+    is asked for: the choice says ``short``, a TPU backend or not."""
+    from paged_programs import chunk_plans, engine_logits, on_a_tpu
+
+    model, v = make()
+    plans = chunk_plans(monkeypatch)
+    on_a_tpu(monkeypatch)
+    engine_logits(model, v, ids_of(37, seed=5).tolist()[:11], 2, num_slots=2,
+                  max_len=64, page_size=4, prefill_chunk=8, min_bucket=4)
+    assert {(p["kernel"], p["why"]) for p in plans} == {
+        (0, "short"), (0, "window")}
+
+
 def test_a_window_read_one_position_off_fails_the_comparison(monkeypatch):
     model, v = make()
     ring = ops.ring_update

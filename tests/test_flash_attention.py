@@ -597,3 +597,167 @@ def test_remat_keeps_the_forward_kernels_results_bit_for_bit(
         full_text = _remat_gpt_grads(mesh_axes, "full", True, monkeypatch,
                                      run=False)[2]
         assert text.count("dot_general") < full_text.count("dot_general")
+
+
+# ---- a chunk call: queries at traced positions over a longer history ----
+
+def _chunk_case(heads, kv_heads, d, d_v, starts, extra, s_c=32, block=32,
+                dtype=jnp.float32, seed=0):
+    """q [B, heads, S_c, d], a time-major view [B, T, kv_heads, .] with
+    ``T = max(starts) + S_c + extra`` whose rows past each sequence's chunk
+    hold garbage, and ``starts``."""
+    b, t = len(starts), max(starts) + s_c + extra
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, heads, s_c, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, t, kv_heads, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, t, kv_heads, d_v)).astype(dtype)
+    starts = jnp.asarray(starts, jnp.int32)
+    unseen = (jnp.arange(t)[None] >= (starts + s_c)[:, None])[:, :, None,
+                                                             None]
+    return q, jnp.where(unseen, 3e3, k), jnp.where(unseen, -7e3, v), starts
+
+
+def _dense_chunk(q, k, v, starts, scale):
+    """A float32 softmax over the whole view, the heads repeated."""
+    g = q.shape[1] // k.shape[2]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhsd,bthd->bhst", q, jnp.repeat(k, g, 2)) * scale
+    pos = starts[:, None] + jnp.arange(q.shape[2])
+    seen = jnp.arange(k.shape[1])[None, None, :] <= pos[:, :, None]
+    p = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), -1)
+    return jnp.einsum("bhst,bthd->bhsd", p, jnp.repeat(v, g, 2))
+
+
+CHUNK_CASES = {
+    # heads | KV heads
+    "h4kv4": dict(heads=4, kv_heads=4, d=64, d_v=64, starts=[40], extra=24),
+    "h8kv2": dict(heads=8, kv_heads=2, d=64, d_v=64, starts=[40], extra=24),
+    "h16kv2": dict(heads=16, kv_heads=2, d=64, d_v=64, starts=[40],
+                   extra=24),
+    # widths
+    "d128": dict(heads=4, kv_heads=2, d=128, d_v=128, starts=[40], extra=24),
+    "d256": dict(heads=4, kv_heads=2, d=256, d_v=256, starts=[40], extra=24),
+    "d192v128": dict(heads=4, kv_heads=4, d=192, d_v=128, starts=[40],
+                     extra=24),
+    # where the chunk starts
+    "start0": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[0], extra=32),
+    "start_on_a_block": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[64],
+                             extra=0),
+    "start_unaligned": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[37],
+                            extra=27),
+    "two_sequences": dict(heads=4, kv_heads=2, d=64, d_v=64,
+                          starts=[96, 5], extra=0),
+    "two_sequences_192": dict(heads=4, kv_heads=4, d=192, d_v=128,
+                              starts=[7, 64], extra=32),
+    # a view wider than the history: by one row (a last block of one, the
+    # view padded to whole blocks), by several whole K blocks (dead steps)
+    "view_one_wider": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[32],
+                           extra=1),
+    "view_blocks_wider": dict(heads=4, kv_heads=2, d=64, d_v=64,
+                              starts=[32], extra=160),
+    "view_blocks_wider_two": dict(heads=8, kv_heads=2, d=128, d_v=128,
+                                  starts=[0, 70], extra=128),
+    # several Q blocks a chunk, the K blocks another size
+    "q_blocks": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[50],
+                     extra=14, s_c=64, block=16),
+    "bf16": dict(heads=8, kv_heads=2, d=128, d_v=128, starts=[45, 64],
+                 extra=64, dtype=jnp.bfloat16),
+    "bf16_192": dict(heads=4, kv_heads=4, d=192, d_v=128, starts=[33],
+                     extra=31, dtype=jnp.bfloat16),
+    # K and V built head-major for the call (LongCat's rebuilt keys, padded
+    # to whole lane tiles): fed as they lie
+    "head_major": dict(heads=4, kv_heads=4, d=256, d_v=128, starts=[40, 9],
+                       extra=24, head_major=True),
+    # no history, a view as long as the chunk: plain causal attention
+    "causal": dict(heads=4, kv_heads=2, d=64, d_v=64, starts=[0, 0],
+                   extra=0, s_c=64),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_a_chunk_call_matches_the_key_block_walk_and_a_dense_softmax(
+        case, monkeypatch):
+    """The forward kernel fed a chunk (interpret mode) against the XLA walk
+    it replaces on the chip (``ops.attention._attend_blocks``) and a float32
+    softmax over the whole view; its ``flash.plan`` instant says
+    ``fwd_chunk`` with the bucket's tiles and steps."""
+    from hetu_tpu.ops.attention import _attend_blocks
+    from hetu_tpu.ops.pallas_kernels.flash_attention import (
+        flash_chunk_attention,
+    )
+
+    kw = dict(CHUNK_CASES[case])
+    block = kw.get("block", 32)
+    head_major = kw.pop("head_major", False)
+    q, k, v, starts = _chunk_case(**kw)
+    scale = q.shape[-1] ** -0.5
+    jax.clear_caches()      # the call is jitted: a shape met before says
+    seen = _plans(monkeypatch)                       # nothing a second time
+    if head_major:
+        out = flash_chunk_attention(
+            q, jnp.moveaxis(k, 1, 2), jnp.moveaxis(v, 1, 2), starts,
+            block_q=block, block_k=block, head_major=True)
+    else:
+        out = flash_chunk_attention(q, k, v, starts, block_q=block,
+                                    block_k=block)
+    assert out.shape == q.shape[:3] + v.shape[3:] and out.dtype == q.dtype
+    pos = starts[:, None] + jnp.arange(q.shape[2])
+    walk = _attend_blocks(q, k, v, pos, scale, block)
+    dense = _dense_chunk(q, k, v, starts, scale)
+    tol = dict(rtol=2e-2, atol=2e-2) if q.dtype == jnp.bfloat16 \
+        else dict(rtol=2e-4, atol=2e-5)
+    out = np.asarray(out.astype(jnp.float32))
+    np.testing.assert_allclose(out, np.asarray(walk.astype(jnp.float32)),
+                               **tol)
+    np.testing.assert_allclose(out, np.asarray(dense), **tol)
+    (name, plan), = seen
+    rows = -(-k.shape[1] // block) * block
+    assert name == "flash.plan" and plan["kernel"] == "fwd_chunk" \
+        and (plan["s_q"], plan["s_k"]) == (q.shape[2], rows) \
+        and plan["kv_heads"] == k.shape[2] \
+        and plan["as_rows"] == int(not head_major and q.shape[3] % 128
+                                   == v.shape[3] % 128 == 0) \
+        and plan["steps"] == q.shape[0] * q.shape[1] \
+        * (q.shape[2] // block) * (rows // block) >= plan["tiles_live"] > 0
+    if case == "causal":
+        want = flash_attention(q, jnp.moveaxis(k, 1, 2),
+                               jnp.moveaxis(v, 1, 2), causal=True,
+                               block_q=block, block_k=block)
+        np.testing.assert_array_equal(out, np.asarray(want))
+
+
+def test_a_chunk_call_has_no_backward():
+    from hetu_tpu.ops.pallas_kernels.flash_attention import (
+        flash_chunk_attention,
+    )
+
+    q, k, v, starts = _chunk_case(4, 2, 64, 64, [8], 24)
+    with pytest.raises(Exception):
+        jax.grad(lambda q: flash_chunk_attention(
+            q, k, v, starts, block_q=32, block_k=32).sum())(q)
+
+
+@pytest.mark.parametrize("case,width,at,step", [
+    ("dma", 128, 32, 16), ("narrow_rows", 24, 32, 16),
+    ("inside_a_tile", 128, 20, 4)])
+def test_write_rows_is_a_dynamic_update_slice_in_place(case, width, at, step):
+    """A block of key rows put into an array nobody wrote, by the DMA where
+    its start and width allow one and by ``dynamic_update_slice`` elsewhere:
+    the rows written read back, the rest is still unwritten (NaN in
+    interpret mode)."""
+    from hetu_tpu.ops.pallas_kernels.flash_attention import (
+        unwritten, write_rows,
+    )
+
+    rows = jax.random.normal(jax.random.PRNGKey(3), (2, 3, 16, width))
+    out = jax.jit(lambda r, at: write_rows(
+        unwritten((2, 3, 64, width), r.dtype), r, at, multiple_of=step))(
+            rows, jnp.int32(at))
+    np.testing.assert_array_equal(np.asarray(out[:, :, at:at + 16]),
+                                  np.asarray(rows))
+    rest = np.delete(np.asarray(out), np.s_[at:at + 16], axis=2)
+    assert np.isnan(rest).all()
+    text = str(jax.make_jaxpr(lambda r: write_rows(
+        jnp.zeros((2, 3, 64, width)), r, jnp.int32(at), multiple_of=step))(
+            rows))
+    assert ("pallas_call" in text) == (case == "dma")

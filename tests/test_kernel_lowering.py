@@ -25,6 +25,9 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
     topk_gating,
 )
 from hetu_tpu.ops.pallas_kernels import grouped_matmul  # noqa: E402,F401
+from hetu_tpu.ops.pallas_kernels.flash_attention import (  # noqa: E402
+    flash_chunk_attention, write_rows,
+)
 from hetu_tpu.ops.pallas_kernels.paged_attention import (  # noqa: E402
     paged_decode_attention,
 )
@@ -59,6 +62,16 @@ BENCH_SERVED_GROUPED_SHAPES = {
 BENCH_PAGED_SHAPES = {
     "gpt2-large.batch": (8, 20, 20, 64, 36, 385, 16, 48),
     "k-exaone-236b-a23b.batch-mixed": (16, 64, 8, 128, 1, 4225, 128, 264)}
+# the chunk call of the flash forward kernel in the serving cells whose
+# views are longer than ``ops.attention.KEY_BLOCK``, at the smallest and the
+# largest chunk bucket that takes it over the widest view: (query heads, KV heads, d_qk,
+# d_v, view rows, chunk buckets)
+BENCH_CHUNK_SHAPES = {
+    "k-exaone-236b-a23b.batch-mixed": (64, 8, 128, 128, 34304, (16, 512)),
+    "qwen3-next-80b-a3b-instruct.batch-mixed":
+        (16, 2, 256, 256, 35968, (16, 2048)),
+    "longcat-flash-omni.batch-long": (64, 64, 256, 128, 9216, (512,)),
+    "lfm2-8b-a1b.batch-docs": (32, 8, 64, 64, 10240, (16, 2048))}
 
 
 @pytest.fixture(autouse=True)
@@ -164,6 +177,24 @@ def _cases():
                                       kv_heads=g),
                [((b, nh, 1, d), bf16), pool_of, pool_of, ((), i32),
                 ((b, n_pg), i32), ((b,), i32)], 1)
+
+
+    for cell, (h, h_kv, d, d_v, rows, buckets) in BENCH_CHUNK_SHAPES.items():
+        for s_c in buckets:
+            yield (f"flash chunk call {cell} S_c={s_c}",
+                   flash_chunk_attention,
+                   [((1, h, s_c, d), bf16), ((1, rows, h_kv, d), bf16),
+                    ((1, rows, h_kv, d_v), bf16), ((1,), i32)], 1)
+
+
+    # LongCat's rebuild: a block of 1,024 keys of 64 heads put in place by
+    # one DMA, keys at 256 lanes and values at 128
+    for width in (256, 128):
+        yield (f"write_rows LongCat {width}",
+               lambda whole, rows, at: write_rows(whole, rows, at,
+                                                  multiple_of=128),
+               [((1, 64, 9216, width), bf16), ((1, 64, 1024, width), bf16),
+                ((), i32)], 1)
 
 
 CASES = list(_cases())
@@ -318,6 +349,31 @@ def test_a_windowless_streamed_call_lowers_to_the_text_it_lowered_to(
     text = jax.jit(_flash_vjp_masked(None, causal)).trace(*abstract).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=False)
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def test_a_chunk_call_lowers_to_one_text(monkeypatch):
+    """K-EXAONE's chunk call (512 queries of 64 heads over a 33,792-row view
+    of 8 KV heads, read where it lies), in interpret mode as the digests above:
+    one grid whose
+    walked axis is a traced bound, the starts prefetched."""
+    import hashlib
+
+    monkeypatch.setattr(
+        sys.modules["hetu_tpu.ops.pallas_kernels.flash_attention"],
+        "auto_interpret", lambda interpret: True)
+    abstract = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((1, 64, 512, 128), bf16), ((1, 33792, 8, 128), bf16),
+        ((1, 33792, 8, 128), bf16), ((1,), i32))]
+    traced = jax.jit(flash_chunk_attention).trace(*abstract)
+    (grid,), = _pallas_grids(traced.jaxpr.jaxpr),
+    assert grid[:2] == (64, 1) and not isinstance(grid[2], int)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text(
+        debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() == CHUNK_DIGEST
+
+
+CHUNK_DIGEST = (
+    "5643625dda529f3d7e0c9b5816183ef692001d5a6a67202ff24538728b75ae70")
 
 
 def _gpt2_small():

@@ -175,6 +175,54 @@ def test_chunked_prefill_and_decode_match_the_reference(monkeypatch):
     assert engine_err(model, v, monkeypatch) < F32_TOL
 
 
+def test_chunk_programs_with_the_flash_kernel_give_the_walks_logits(
+        monkeypatch):
+    """A prompt of five chunks over views of 64 rows, four key blocks of
+    16: the chunk programs whose expanded attention rebuilds K and V and
+    calls the flash forward kernel (interpreted) give the tokens of the
+    programs that attend inside the walk, and their logits within the
+    float32 tolerance; each program's attention says which it took."""
+    from hetu_tpu.models import longcat_flash
+    from paged_programs import chunk_kernel_beside_the_walk
+
+    model, v = make()
+    # 4 heads x 8 queries x 16 keys a block: the chunks of 8 are asked for,
+    # the last chunk's bucket of 4 has too few queries
+    monkeypatch.setattr(longcat_flash, "REBUILD_MIN_SCORES", 4 * 8 * 16)
+    worst, (walk, kernel), walk_plans, plans = chunk_kernel_beside_the_walk(
+        monkeypatch, model, v, ids_of(35, seed=5).tolist(), 3, num_slots=2,
+        max_len=64, page_size=4, prefill_chunk=8, min_bucket=4)
+    assert kernel == walk and worst < F32_TOL
+    assert {(p["kernel"], p["why"]) for p in walk_plans} == {(0, "backend")}
+    assert {(p["kernel"], p["why"], p["s_c"]) for p in plans} == {
+        (1, "", 8), (0, "few_queries", 4)}
+    plans = [p for p in plans if p["kernel"]]
+    c = model.c
+    assert {(p["heads"], p["d"], p["d_v"], p["rows"]) for p in plans} \
+        == {(c.num_heads, c.qk_head_dim, c.v_head_dim, 64)}
+
+
+def test_a_static_trip_keeps_the_walk_on_any_backend(monkeypatch):
+    """Reverse mode needs the loop (a kernel call has no backward): under
+    ``static_trip`` the choice says so, a TPU backend or not, and the
+    training forward differentiates."""
+    from hetu_tpu.models import longcat_flash
+    from paged_programs import chunk_plans, on_a_tpu
+
+    model, v = make()
+    monkeypatch.setattr(longcat_flash, "REBUILD_MIN_SCORES", 0)
+    plans = chunk_plans(monkeypatch)
+    on_a_tpu(monkeypatch)
+    ids = jnp.asarray(ids_of((1, 24), seed=2))
+    grads = jax.grad(lambda p: model.apply(
+        {"params": p, "state": {}}, ids, train=True)[0].sum())(v["params"])
+    assert {(p["kernel"], p["why"]) for p in plans} == {(0, "static_trip")}
+    assert float(jnp.abs(grads["layers"]["attn"]["kv_b"]).max()) > 0
+    del plans[:]
+    model.apply(v, ids)                  # the dense forward, not trained
+    assert {(p["kernel"], p["why"]) for p in plans} == {(1, "")}
+
+
 # --------------------------------------- (c) absorbed equals expanded
 
 def test_absorbed_decode_equals_expanded_attention_on_one_cache():

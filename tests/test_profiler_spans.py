@@ -655,6 +655,7 @@ def test_a_two_part_states_ids_and_the_mixers_plan_and_scopes(tmp_path):
     assert {e[0] for e in _children(events, step)} <= {
         "serve.admit", "serve.advance_prefills", "serve.evict",
         "serve.decode", "serve.prefill_chunk", "ssm.plan", "paged_attn.plan",
+        "chunk_attn.plan",
         "serve.state_reset", "serve.recompile",
         *(f"serve.decode.{s}" for s in SEAMS),
         *(f"serve.prefill_chunk.{s}" for s in SEAMS)}
