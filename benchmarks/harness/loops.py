@@ -65,12 +65,18 @@ class CompileCounter:
 
 @contextmanager
 def traced(rec: Recorder):
-    """Profile the body, marked as ``bench:trace_window``."""
+    """Profile the body, marked as ``bench:trace_window``.  The profiler's
+    Python tracer is off: it slows the host's Python (a tenth of the
+    fastest serving cell's rate inside the stretch, PR 36), and the stretch
+    the host metrics are read in should run as the window runs.  The
+    ``bench:`` and ``hetu:`` annotations are TraceMe events either way."""
     import jax
 
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     TRACE_DIR.mkdir(parents=True)
-    jax.profiler.start_trace(str(TRACE_DIR))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(TRACE_DIR), profiler_options=options)
     rec.clear()    # what the readers lay over the trace starts here
     rec.annotate = True
     try:
@@ -344,7 +350,8 @@ class Serving:
         """Where a run that reads far off lost its time: the longest step
         and the longest pause between steps (the host's own code, a
         collection, a descheduled process) with when they fell, the time
-        steps took beyond three times the median step, the mean of each
+        steps took beyond three times the median step, the steps longer
+        than 1.5 times the median, mean, median and 95th percentile of each
         engine call, and how fast the host ran a fixed piece of Python
         during the window (a host shared with other tenants slows all of a
         synchronous serving loop's steps alike)."""
@@ -357,16 +364,24 @@ class Serving:
         gap, gap_at = max(gaps, default=(0.0, opened))
         median = float(np.median(took))
 
-        def mean_ms(span):
-            iv = self.rec.spans.get(span)
-            return 1e3 * sum(b - a for a, b in iv) / len(iv) if iv else 0.0
+        def span_ms(prefix, span):
+            """Mean, median and 95th percentile of a span's calls in the
+            window: a slow-mode process moves the median of every call, a
+            stall only the longest."""
+            ms = [1e3 * (b - a)
+                  for a, b in self.rec.spans.get(span, ())] or [0.0]
+            return {prefix + "_span_mean_ms": sum(ms) / len(ms),
+                    prefix + "_span_median_ms": float(np.median(ms)),
+                    prefix + "_span_p95_ms": percentile(ms, 95)}
 
         return {"steps_in_window": len(took),
-                "decode_span_mean_ms": mean_ms("engine.decode"),
-                "chunk_span_mean_ms": mean_ms("engine.prefill_step"),
+                **span_ms("decode", "engine.decode"),
+                **span_ms("chunk", "engine.prefill_step"),
                 "host_probe_median_us": 1e6 * float(
                     np.median(self.host_probe)) if self.host_probe else 0.0,
                 "median_step_ms": median * 1e3,
+                "steps_over_1p5x_median": sum(t > 1.5 * median
+                                              for t in took),
                 "longest_step_ms": took[worst] * 1e3,
                 "longest_step_at_s": self.steps[worst][0] - opened,
                 "longest_pause_ms": gap * 1e3,
@@ -421,14 +436,15 @@ def backlog(ctx) -> Run:
         while len(sv.finished) < int(tr["warmup_finished_requests"]):
             top_up()
             sv.step()
-    trace_path = None
+    trace_path, traced_series = None, {}
     if ctx.trace:
         with traced(rec):
             run_for(float(tr["trace_s"]))
         trace_path = trace_file()
+        # the stretch's own rounds, one a decode launch in its trace
+        traced_series = {k: list(v) for k, v in rec.series.items()}
         run_for(SETTLE_S)
 
-    traced_series = {k: list(v) for k, v in rec.series.items()}
     phases = {**sv.setup, **setup_phases(rec)}
     rec.clear()
     sv.finished.clear()
@@ -525,13 +541,13 @@ def open_loop(ctx, *, rate_rps: float = None, serving: Serving = None,
             stall = time.monotonic()
             trace_cm.__exit__(None, None, None)
             trace_path = trace_file()
+            traced_series = {k: list(v) for k, v in rec.series.items()}
             start += time.monotonic() - stall
             continue
         if opened_at is None and now >= win0:
             opened_at = start + win0
             compiles0 = ctx.compiles.n
             engine0 = sv.engine.compiled_executables()
-            traced_series = {k: list(v) for k, v in rec.series.items()}
             rec.clear()
             sv.steps.clear()
             sv.host_probe.clear()

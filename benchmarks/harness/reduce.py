@@ -266,14 +266,6 @@ class TraceSummary:
         return [(e, own) for e, own, _ in (chip or self.first_chip()).ops
                 if rx.search(e.name)]
 
-    def busy_within(self, span: str) -> list:
-        """Per occurrence of ``span`` inside the window: seconds the first
-        chip was busy during it."""
-        busy = self.first_chip().busy
-        return [measure(intersect([(a, b)], busy)) / 1e9
-                for a, b in sorted(self.spans.get(span, ()))
-                if a >= self.window[0] and b <= self.window[1]]
-
     def exposed_collective_s(self, chip: ChipTrace = None) -> float:
         """Seconds in which a collective operation ran on the chip and no
         other operation did."""

@@ -79,18 +79,25 @@ def metrics_of(entries, cell_name: str) -> list:
             if "workloads" not in m or cell_name in m["workloads"]]
 
 
-def layer_metric_file(name: str) -> dict:
-    """How a per-layer metric is read: ``{"reader": ..., "params": {...}}``
-    from ``benchmarks/layer_metrics/<name>.json``.  ``BENCHMARK.json`` alone
-    says what the metric is (layer, unit, moves, cells).  A name with
-    suffixes (``decode_step_ms.batch``) that has no file of its own is read
-    as its stem is (``decode_step_ms.json``), so the same quantity in
-    another family of cells needs an entry and no file."""
+def layer_metric_path(name: str) -> Path:
+    """The file that says how a per-layer metric is read:
+    ``benchmarks/layer_metrics/<name>.json``.  A name with suffixes
+    (``device_idle_share.serve``) that has no file of its own is read as
+    its stem is (``device_idle_share.json``), so the same quantity under
+    another end-to-end metric needs an entry and no file."""
     parts = name.split(".")
     for n in range(len(parts), 0, -1):
         path = BENCH / "layer_metrics" / (".".join(parts[:n]) + ".json")
         if path.is_file():
-            return _read(path)
+            return path
     raise FileNotFoundError(
         f"no file for per-layer metric {name!r} under "
         f"{BENCH / 'layer_metrics'}")
+
+
+def layer_metric_file(name: str) -> dict:
+    """How a per-layer metric is read: ``{"reader": ..., "params": {...}}``
+    from its file.  ``BENCHMARK.json`` alone says what the metric is (layer,
+    unit, moves, cells): one entry a quantity, its ``workloads`` the cells
+    that report it."""
+    return _read(layer_metric_path(name))

@@ -163,8 +163,8 @@ def scan_file(path: str) -> dict:
 def _completions(scan: dict, chip: int, chips: int) -> Optional[list]:
     """[(=>Done opens, run_id or None)] of ``chip``, in order.  One
     ``CompleteCallbacks`` may report several programs that ended close
-    together; it carries the ``run_id`` of the LAST of them (seen in
-    ``gpt2-large.batch``'s traces, PR 36), so the others have none to check.
+    together; it carries the ``run_id`` of the LAST of them (seen in the
+    fastest serving cell's traces, PR 36), so the others have none to check.
     Without the callbacks' ``device_ordinal`` only a trace of one chip can
     be read."""
     out, last = [], None

@@ -162,12 +162,16 @@ def test_the_traffic_is_the_issues():
     assert (tr["distinct_batches"], tr["lookahead_steps"],
             tr["check_sequences"]) == (8, 1, 1)
     names = {m["name"] for m in spec.metrics_of(man["per_layer"], CELL)}
+    # the issue's list, with what PRs 36 and 42 added for every train cell
+    # and without ``moe_block_fill``, which PR 53 retired: it divided by
+    # blocks no program of this cell has run since PR 42
     assert names == {
-        "train_step_ms", "train_dispatch_ms", "mfu_pct",
+        "train_step_ms", "train_dispatch_ms", "train_program_ms", "mfu_pct",
         "device_idle_share.train", "hbm_heap_gb.train", "hbm_stack_gb.train",
         "flash_roofline.train-ep8", "flash_time_share.train-ep8",
         "mla_time_share.train-ep8", "moe_time_share.train-ep8",
-        "moe_rows_per_hit_expert.train-ep8", "moe_block_fill.train-ep8"}
+        "moe_rows_per_hit_expert.train-ep8", "moe_grouped_share.train-ep8",
+        "moe_gmm_time_share.train-ep8"}
     assert {m["name"] for m in spec.metrics_of(man["end_to_end"], CELL)} \
         == {"train_tokens_per_s", "setup_s"}
 
@@ -188,7 +192,7 @@ def test_the_new_cell_runs_through_run_py_and_is_correct(capsys,
 
 
 def test_the_moe_readers_read_the_trainers_instant(tmp_path):
-    """``moe_rows_per_hit_expert`` and ``moe_block_fill`` off a trace
+    """``moe_rows_per_hit_expert`` and ``moe_grouped_share`` off a trace
     recorded here: the ids of ``train.moe``; a trace without them (GPT-2's
     trainer, the parent's) gives nothing."""
     import hetu_tpu as ht
@@ -217,16 +221,14 @@ def test_the_moe_readers_read_the_trainers_instant(tmp_path):
     config, _ = tiny()
     path = trace_of(config, tmp_path / "experts")
     # 2 x 64 tokens x 4 choices, 4 of 16 held: about 128 pairs a layer on 4
-    # experts, in blocks of 128 rows: a hit expert's block is about a
-    # quarter full here
+    # experts, every one of them computed by the grouped path
     rows = read("moe_rows_per_hit_expert.train-ep8", path)
-    fill = read("moe_block_fill.train-ep8", path)
     assert 10.0 < rows < 80.0
-    assert fill == pytest.approx(rows / 128.0, rel=1e-6)
+    assert read("moe_grouped_share.train-ep8", path) == 100.0
     gpt = spec.config(spec.manifest(), "gpt2-small", rehearse=True)
     path = trace_of(gpt, tmp_path / "dense")
     for name in ("moe_rows_per_hit_expert.train-ep8",
-                 "moe_block_fill.train-ep8"):
+                 "moe_grouped_share.train-ep8"):
         assert read(name, path) is None
         assert read(name, None) is None
 

@@ -187,22 +187,25 @@ def test_the_new_cell_runs_through_run_py_and_is_correct(capsys,
 
 
 def test_every_new_metric_names_this_cell_alone_and_moves_the_rate():
-    """``per_layer`` may hold 128 entries and held 122, so only the four
-    shares that need pattern files of their own are entries; the serving
-    family's twenty are LFM2's entries with this cell appended to their
-    ``workloads`` (same stems, same readers)."""
+    """The four shares that need pattern files of their own are this
+    cell's entries; the serving family's seventeen are the six serving
+    cells' (one entry a quantity since PR 53), and ``state_bytes_share``
+    is read under the entry LFM2's cell brought."""
     man = spec.manifest()
-    assert len(man["per_layer"]) <= 128
+    assert len(man["per_layer"]) <= 72
     mine = [m for m in man["per_layer"] if m["name"].endswith(".batch-gen")]
     assert sorted(m["name"] for m in mine) \
         == sorted(f"{m}.batch-gen" for m in SHARES)
     assert all(m["workloads"] == [CELL]
                and m["moves"] == "serve_tokens_per_s" for m in mine)
     shared = [m for m in man["per_layer"]
-              if m["name"].endswith(".batch-docs") and CELL in m["workloads"]]
-    assert len(shared) == 20
-    assert all(m["workloads"] == ["lfm2-8b-a1b.batch-docs", CELL]
-               and m["moves"] == "serve_tokens_per_s" for m in shared)
+              if m not in mine and CELL in m["workloads"]]
+    assert len(shared) == 18
+    assert [m["name"] for m in shared if len(m["workloads"]) != 6] \
+        == ["state_bytes_share.batch-docs"]
+    assert all(m["workloads"][:2] == ["lfm2-8b-a1b.batch-docs", CELL]
+               or len(m["workloads"]) == 6 for m in shared)
+    assert all(m["moves"] == "serve_tokens_per_s" for m in shared)
     assert {m["name"] for m in spec.metrics_of(man["per_layer"], CELL)} \
         == {m["name"] for m in mine + shared}
     for m in mine + shared:

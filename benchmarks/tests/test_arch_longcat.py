@@ -172,3 +172,70 @@ def test_a_wrong_cache_read_fails_the_serving_check(monkeypatch):
     broken = check.serving(model, variables, engine, scheduler, config, 7)
     assert not broken["ok"]
     assert broken["token_gap"] > 3 * broken["limits"]["token_gap"]
+
+
+# ------------------------------- the time shares over recorded operations
+
+SHARES = {"hetu.mla.": "mla_time_share", "hetu.moe.": "moe_time_share",
+          "hetu.ffn.dense": "dense_ffn_time_share"}
+
+
+def _recorded():
+    """(share of busy time in %, the event's whole name, its scope) of the
+    400 operations with the most own time in a traced run of the cell on
+    the v5e (PR 53, call 1, seed 5300000021: 99.8% of the chip's busy
+    time), the scope by the ``op_name`` of the instruction with the same
+    result types, op kind and operand count in the chunk and decode
+    programs compiled for a described v5e over the tree the engine holds
+    (every chunk bucket, the decode programs of 16 and 8 slots at every
+    page bucket): ``outside`` where the model computes it outside its
+    scopes (the attention's projections), ``a|b`` where instructions of two
+    scopes share that key, ``?`` where none has it."""
+    path = os.path.join(os.path.dirname(__file__), "data",
+                        "longcat_batch_long_ops.txt")
+    for line in open(path):
+        share, scope, name = line.rstrip("\n").split("\t")
+        yield float(share), name, scope.split("|")
+
+
+def test_the_time_share_patterns_take_their_own_scopes_and_no_other():
+    """``mla_time_share.batch-long`` was re-pointed in PR 53 at what a chunk's
+    attention runs since PR 52: the flash kernel's chunk call, the rebuild's
+    ``write_rows`` and ``unwritten`` calls BY NAME, and the head-major K and
+    V blocks, the queries padded to 256 lanes and the carried arrays by
+    shape.  Over the recorded operations: none is counted in two shares
+    (the layer scans' ``while`` containers aside: their text holds every
+    shape and their own time is loop glue, 0.03% of busy time); one whose
+    instruction carries ANOTHER share's scope is not taken; under 1% of
+    busy time inside ``hetu.mla.*`` is missed; the attention's share is a
+    third of busy time where the stale pattern read a fifth."""
+    import re
+
+    rx = {m: re.compile(spec.layer_metric_file(f"{m}.batch-long")
+                        ["params"]["pattern"]) for m in SHARES.values()}
+    taken, missed, glue = dict.fromkeys(rx, 0.0), 0.0, 0.0
+    by_name = {}
+    for share, name, scopes in _recorded():
+        hit = {m for m in rx if rx[m].search(name)}
+        if name.startswith("%while") and len(hit) > 1:   # a layer scan
+            glue += share
+            continue
+        assert len(hit) < 2, (hit, name)
+        own = {m for s in scopes for pre, m in SHARES.items()
+               if s.startswith(pre)}
+        if own:
+            assert hit <= own, (hit, scopes, name)
+        if own == {"mla_time_share"} and not hit:
+            missed += share
+        for m in hit:
+            taken[m] += share
+        kernel = re.match(r"%(_flash_chunk|write_rows|unwritten)", name)
+        if kernel:
+            assert hit == {"mla_time_share"}, name
+            by_name[kernel.group(1)] = by_name.get(kernel.group(1), 0) + share
+    assert glue < 0.1 and missed < 1.0, (glue, missed)
+    assert 8.0 < by_name["_flash_chunk"] < 9.0       # two calls a double layer
+    assert 2.5 < by_name["write_rows"] < 4.0      # ``unwritten`` has no body
+    assert 33.0 < taken["mla_time_share"] < 36.0, taken
+    assert 20.0 < taken["moe_time_share"] < 25.0, taken
+    assert 25.0 < taken["dense_ffn_time_share"] < 29.0, taken

@@ -227,7 +227,8 @@ def test_the_traffic_is_the_issues():
         "device_idle_share.train", "hbm_heap_gb.train", "hbm_stack_gb.train",
         "flash_roofline.train-ep4", "attn_window_time_share.train-ep4",
         "attn_full_time_share.train-ep4", "moe_time_share.train-ep4",
-        "moe_rows_per_hit_expert.train-ep4", "moe_block_fill.train-ep4"}
+        "moe_rows_per_hit_expert.train-ep4", "moe_grouped_share.train-ep4",
+        "moe_gmm_time_share.train-ep4"}
     assert {m["name"] for m in spec.metrics_of(man["end_to_end"], CELL)} \
         == {"train_tokens_per_s", "setup_s"}
     # every metric this PR adds is this cell's alone
@@ -325,8 +326,10 @@ def test_the_readers_leave_a_run_without_a_trace_alone(full):
 
 
 def test_the_moe_readers_read_the_trainers_instant(tmp_path):
-    """``moe_rows_per_hit_expert.train-ep4`` and ``moe_block_fill.train-ep4``
-    off a trace recorded here: the ids of ``train.moe``."""
+    """``moe_rows_per_hit_expert.train-ep4`` and
+    ``moe_grouped_share.train-ep4`` off a trace recorded here: the ids of
+    ``train.moe`` (``moe_block_fill``, which divided by the loop's blocks,
+    went in PR 53)."""
     import hetu_tpu as ht
     from hetu_tpu import optim
 
@@ -342,14 +345,11 @@ def test_the_moe_readers_read_the_trainers_instant(tmp_path):
     path = str(sorted(tmp_path.glob("plugins/profile/*/*.xplane.pb"))[-1])
     ctx = SimpleNamespace(run=SimpleNamespace(trace_path=path))
     # 2 x 64 tokens x 4 choices, 4 of 16 held: about 128 pairs a layer on 4
-    # experts, in the configuration's blocks of 768 rows: a hit expert's
-    # one block is nearly empty here
+    # experts, every one of them computed by the grouped path
     rows, _ = _read("moe_rows_per_hit_expert.train-ep4", ctx)
-    fill, _ = _read("moe_block_fill.train-ep4", ctx)
     assert 10.0 < rows < 80.0
-    assert model.c.expert_block_rows == 768
-    assert fill == pytest.approx(rows / 768.0, rel=1e-6)
-    assert _read("moe_block_fill.train-ep4", SimpleNamespace(
+    assert _read("moe_grouped_share.train-ep4", ctx)[0] == 100.0
+    assert _read("moe_grouped_share.train-ep4", SimpleNamespace(
         run=SimpleNamespace(trace_path=None)))[0] is None
 
 

@@ -203,33 +203,35 @@ def test_the_new_cell_runs_through_run_py_and_is_correct(capsys,
     assert line["metric_names"] == ["serve_tokens_per_s", "setup_s"]
 
 
+# one entry a quantity since PR 53: the serving family's entries list the
+# six serving cells (``decode_device_ms`` / ``prefill_device_ms`` went)
 SERVING = ("decode_step_ms", "prefill_chunk_ms", "decode_roofline",
-           "decode_batch_mean", "engine_compiles", "device_idle_share",
-           "hbm_heap_gb", "hbm_stack_gb", "decode_host_ms",
-           "decode_device_ms", "prefill_host_ms", "prefill_device_ms",
-           "sched_host_ms", "host_gap_share", "decode_program_ms",
-           "prefill_program_ms", "decode_issue_ms", "decode_runtime_ms",
-           "decode_readback_ms")
+           "decode_batch_mean", "engine_compiles", "device_idle_share.serve",
+           "hbm_heap_gb.serve", "hbm_stack_gb.serve", "decode_host_ms",
+           "prefill_host_ms", "sched_host_ms", "host_gap_share",
+           "decode_program_ms", "prefill_program_ms", "decode_issue_ms",
+           "decode_runtime_ms", "decode_readback_ms")
 DOCS = ("state_bytes_share", "moe_experts_hit_share", "moe_gmm_time_share",
         "moe_grouped_share")
 
 
 def test_one_new_entry_and_the_accepted_ones_this_cell_is_appended_to():
-    """``per_layer`` may hold 128 entries and held 127: ONE new entry, and
-    the cell appended to the ``workloads`` of the nineteen ``*.batch-mixed``
-    serving entries, of ``moe_rows_per_hit_expert.batch-mixed`` and of four
-    ``*.batch-docs`` readers of ids and names this model emits too; not to
-    K-EXAONE's own shape-pattern shares nor to its second page group's."""
+    """ONE entry of its own, and the cell in the ``workloads`` of the
+    serving family's seventeen entries, of
+    ``moe_rows_per_hit_expert.serve`` and of four ``*.batch-docs`` readers
+    of ids and names this model emits too; not in K-EXAONE's own
+    shape-pattern shares nor in its second page group's.  ``per_layer``
+    held 128 of 128 when the cell came (PR 51) and holds at most 72 since
+    PR 53 folded a quantity's per-cell entries into one."""
     man = spec.manifest()
-    assert len(man["per_layer"]) == 128
+    assert len(man["per_layer"]) <= 72
     (mine,) = [m for m in man["per_layer"] if m["workloads"] == [CELL]]
     assert mine == {"name": "gdn_time_share.batch-mixed", "unit": "%",
                     "better": "lower", "source": "device_trace",
                     "layer": "model", "moves": "serve_tokens_per_s",
                     "workloads": [CELL]}
     assert man["per_layer"][-1] == mine
-    appended = [f"{m}.batch-mixed" for m in SERVING] \
-        + ["moe_rows_per_hit_expert.batch-mixed"] \
+    appended = list(SERVING) + ["moe_rows_per_hit_expert.serve"] \
         + [f"{m}.batch-docs" for m in DOCS]
     by_name = {m["name"]: m for m in man["per_layer"]}
     for name in appended:

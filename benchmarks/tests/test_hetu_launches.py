@@ -27,12 +27,23 @@ NEW = {
     "decode_runtime_ms": ("serve.decode", "runtime", "device_trace"),
     "decode_readback_ms": ("serve.decode", "readback", "program_span"),
 }
-SUFFIXES = ("batch", "batch-long", "batch-mixed")
+# what the benchmark's wrapper noted of the six rounds of a stretch: slots
+# decoded, tokens live in them
+ROUNDS = {"decode_active": [8, 8, 7, 7, 6, 6],
+          "decode_cached_tokens": [1000, 1008, 900, 907, 800, 806]}
+V5E = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
-def ctx_of(path: str):
-    return SimpleNamespace(trace=reduce.summarize(reduce.load(path)),
-                           run=SimpleNamespace(trace_path=path))
+def ctx_of(path: str, series=None, peaks=V5E):
+    """A reader's context over a recorded trace; ``decode_roofline`` also
+    reads the device's peaks, a configuration (gpt2-small's, whose counts
+    ``test_reduce.py`` works by hand) and the stretch's series."""
+    man = spec.manifest()
+    return SimpleNamespace(
+        trace=reduce.summarize(reduce.load(path)), peaks=peaks,
+        config=spec.config(man, "gpt2-small"),
+        run=SimpleNamespace(trace_path=path, values={
+            "traced_series": ROUNDS if series is None else series}))
 
 
 def read(name: str, ctx):
@@ -107,7 +118,7 @@ def test_metric_is_the_mean_of_its_part(serial, stem):
     recs = hetu_launches.launches(serial, kind)
     values = [r.part_ns(part) for r in recs]
     assert len(values) == (2 if kind == "serve.prefill_chunk" else 6)
-    assert read(stem + ".batch", serial) == pytest.approx(
+    assert read(stem, serial) == pytest.approx(
         sum(values) / len(values) / 1e6, rel=1e-12)
     assert all(v > 0 for v in values)
 
@@ -119,42 +130,94 @@ def test_the_parts_tile_the_call(serial):
     call = sum(b - a for a, b in sp["serve.decode"]) / 6e6
     host = sum(b - a for s in ("prep", "post")
                for a, b in sp[f"serve.decode.{s}"]) / 6e6
-    parts = sum(read(f"decode_{p}_ms.batch", serial)
+    parts = sum(read(f"decode_{p}_ms", serial)
                 for p in ("issue", "program", "runtime", "readback"))
     assert host + parts == pytest.approx(call, rel=0.02)
     assert host + parts <= call
 
 
+def _busy_under_the_calls_spans(ctx) -> float:
+    """What the retired ``decode_device_ms`` read: milliseconds a round the
+    chip was busy under the call's ``launch`` + ``fetch`` spans."""
+    sp = hetu_spans.spans(ctx)
+    under = hetu_spans.intervals(
+        sp, ["serve.decode.launch", "serve.decode.fetch"])
+    return reduce.measure(reduce.intersect(
+        under, ctx.trace.first_chip().busy)) / 1e6 / len(sp["serve.decode"])
+
+
 def test_serial_program_time_is_the_busy_time_under_the_calls_spans(serial):
     """On the serial engine the program's own run and the device's busy time
-    under launch + fetch are the same thing, read two ways."""
-    for new, old in (("decode_program_ms", "decode_device_ms"),
-                     ("prefill_program_ms", "prefill_device_ms")):
-        assert read(new + ".batch", serial) == pytest.approx(
-            read(old + ".batch", serial), rel=0.02)
+    under launch + fetch are the same thing, read two ways: which is why
+    the second reader could go (PR 53)."""
+    assert read("decode_program_ms", serial) == pytest.approx(
+        _busy_under_the_calls_spans(serial), rel=0.02)
 
 
 # ------------------------------------------------------------- run-ahead
 
 def test_program_time_is_the_same_under_run_ahead(serial, run_ahead):
-    a = read("decode_program_ms.batch", serial)
-    b = read("decode_program_ms.batch", run_ahead)
+    a = read("decode_program_ms", serial)
+    b = read("decode_program_ms", run_ahead)
     assert b == pytest.approx(a, rel=0.02)
 
 
-def test_the_spans_reader_reads_another_programs_time_under_run_ahead(
+def test_busy_time_under_a_calls_spans_is_another_programs_under_run_ahead(
         run_ahead):
-    """``hetu_device_ms`` lays the device's busy time under the call's
-    ``launch`` + ``fetch``: with round n + 1 launched before round n is
-    read that is part of two programs, neither its own."""
-    own = read("decode_program_ms.batch", run_ahead)
-    under_spans = read("decode_device_ms.batch", run_ahead)
-    assert abs(under_spans - own) > 0.1 * own
+    """With round n + 1 launched before round n is read, the device's busy
+    time under a call's ``launch`` + ``fetch`` is part of two programs,
+    neither its own: no reader lays device time under host spans."""
+    own = read("decode_program_ms", run_ahead)
+    assert abs(_busy_under_the_calls_spans(run_ahead) - own) > 0.1 * own
     recs = hetu_launches.launches(run_ahead, "serve.decode")
     assert [r.fetch is not None for r in recs] == [True] * 6
     # the fetch that waits for a launch opens after the NEXT launch closed
     for r, nxt in zip(recs, recs[1:]):
         assert r.fetch[0] >= nxt.l1
+
+
+# ---------------------------------------------------- the roofline's share
+
+def _least_s(active: int, cached: int) -> float:
+    """gpt2-small's decode round by hand (``test_reduce.py``): every matmul
+    weight and the live cache read once in bfloat16, or its operations."""
+    bytes_ = 2 * (123_651_840 + 2 * 12 * 768 * cached)
+    flops = 2 * 123_651_840 * active + 4 * 12 * 768 * cached
+    return max(bytes_ / 819e9, flops / 197e12)
+
+
+def test_decode_roofline_divides_by_the_rounds_own_module_runs(serial):
+    runs = _raw(SERIAL)["jit_hetu_serve_decode"]
+    assert len(runs) == 6
+    first = hetu_launches.launches(serial, "serve.decode")[0]
+    assert first.program_ns == runs[0][1] - runs[0][0]
+    assert _least_s(8, 1000) == pytest.approx(284_167_680 / 819e9)
+    least = sum(_least_s(a, c) for a, c in zip(*ROUNDS.values()))
+    on_device = sum(b - a for a, b, _ in runs) / 1e9
+    assert read("decode_roofline", serial) == pytest.approx(
+        100.0 * least / on_device, rel=1e-9)
+    # one round alone: its least time over its own run
+    one = ctx_of(SERIAL, {k: v[:1] for k, v in ROUNDS.items()})
+    one.trace.window = (first.l0 - 1.0, first.fetch[1] + 1.0)
+    assert read("decode_roofline", one) == pytest.approx(
+        100.0 * _least_s(8, 1000) / (first.program_ns / 1e9), rel=1e-9)
+
+
+def test_decode_roofline_reads_the_same_under_run_ahead(serial, run_ahead):
+    """The host's spans lie elsewhere, the programs' runs are the same."""
+    a = read("decode_roofline", serial)
+    b = read("decode_roofline", run_ahead)
+    assert b == pytest.approx(a, rel=0.02)
+
+
+def test_decode_roofline_gives_none_where_it_cannot_pair():
+    short = {k: v[:5] for k, v in ROUNDS.items()}
+    assert read("decode_roofline", ctx_of(SERIAL, short)) is None
+    assert read("decode_roofline", ctx_of(SERIAL, {})) is None
+    assert read("decode_roofline", ctx_of(RECORDED)) is None     # no seq
+    assert read("decode_roofline", ctx_of(SERIAL, peaks=None)) is None
+    assert spec.layer_metric_file("decode_roofline") == {
+        "reader": "decode_roofline"}
 
 
 def test_runtime_part_leaves_out_the_queued_time(serial, run_ahead):
@@ -173,7 +236,7 @@ def test_runtime_part_leaves_out_the_queued_time(serial, run_ahead):
     # a queued program starts when its predecessor ends (the module runs lie
     # a microsecond apart), so what is left is the jitter of two completion
     # reports: nothing like the 0.8 ms a program pays when launched alone
-    serial_runtime = read("decode_runtime_ms.batch", serial) * 1e6
+    serial_runtime = read("decode_runtime_ms", serial) * 1e6
     assert serial_runtime > 0.5e6
     assert abs(counted) < 0.2 * serial_runtime < 0.2 * waited
     assert all(abs(r.runtime_ns) < 0.3e6 for r in queued)
@@ -211,7 +274,7 @@ def test_train_program_ms_is_the_module_runs_mean_whatever_the_host_did():
 @pytest.mark.parametrize("stem", sorted(NEW))
 def test_a_trace_without_launch_ids_gives_none(stem):
     """The trace PR 25 recorded: the same spans, no ``seq``."""
-    assert read(stem + ".batch", ctx_of(RECORDED)) is None
+    assert read(stem, ctx_of(RECORDED)) is None
     assert hetu_launches.pair(RECORDED) is None
     assert "seq" in hetu_launches.pair_scan(
         hetu_launches.scan_file(RECORDED))[1]
@@ -327,17 +390,22 @@ def test_no_trace_gives_none():
 
 @pytest.mark.parametrize("stem", sorted(NEW))
 def test_new_entries_resolve_to_the_one_reader(stem):
+    """One entry a quantity (PR 53): the stem, listing the serving cells."""
     kind, part, source = NEW[stem]
     man = spec.manifest()
-    for suffix in SUFFIXES:
-        entry = next(m for m in man["per_layer"]
-                     if m["name"] == f"{stem}.{suffix}")
-        assert entry["source"] == source and entry["better"] == "lower"
-        assert entry["moves"] == "serve_tokens_per_s"
-        assert len(entry["workloads"]) == 1
-        assert spec.layer_metric_file(entry["name"]) == {
-            "reader": "hetu_launch_ms",
-            "params": {"kind": kind, "part": part}}
+    (entry,) = [m for m in man["per_layer"]
+                if m["name"].split(".")[0] == stem]
+    assert entry["name"] == stem
+    assert entry["source"] == source and entry["better"] == "lower"
+    assert entry["moves"] == "serve_tokens_per_s"
+    assert set(entry["workloads"]) == {
+        m["name"] for m in man["workloads"]
+        if "serve_tokens_per_s" in {
+            e["name"] for e in spec.metrics_of(man["end_to_end"],
+                                               m["name"])}}
+    assert spec.layer_metric_file(stem) == {
+        "reader": "hetu_launch_ms",
+        "params": {"kind": kind, "part": part}}
 
 
 def test_train_program_ms_is_reported_by_the_train_cells():

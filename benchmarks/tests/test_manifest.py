@@ -80,8 +80,8 @@ def test_every_per_layer_metric_can_be_read(man):
         assert set(f) <= {"reader", "params"}, m["name"]
         reader = importlib.import_module(f"benchmarks.readers.{f['reader']}")
         assert callable(reader.read)
-    assert spec.layer_metric_file("decode_step_ms.some-new-cells") == \
-        spec.layer_metric_file("decode_step_ms")
+    assert spec.layer_metric_path("decode_step_ms.some-new-cells") == \
+        spec.layer_metric_path("decode_step_ms")
     with pytest.raises(FileNotFoundError):
         spec.layer_metric_file("no_such_metric.batch")
 
@@ -107,3 +107,70 @@ def test_manifest_is_small_and_well_formed(man):
                                     "per_layer"}
     for w in man["workloads"]:
         assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
+
+
+# ------------------------------------------- one entry a quantity (PR 53)
+
+BEFORE = json.loads((Path(__file__).parent / "data"
+                     / "per_layer_before_pr53.json").read_text())["entries"]
+
+
+def test_no_two_entries_name_one_quantity(man):
+    """Two entries that one file reads with the same parameters, and that
+    move the same metric in the same unit, layer, source and direction, are
+    one quantity under two names: they are one entry with a ``workloads``
+    list.  There is room for those to come: at most 72 of the 128."""
+    seen = {}
+    for m in man["per_layer"]:
+        key = (spec.layer_metric_path(m["name"]), m["moves"], m["unit"],
+               m["layer"], m["source"], m["better"])
+        assert key not in seen, (m["name"], seen[key])
+        seen[key] = m["name"]
+    assert len(man["per_layer"]) <= 72
+
+
+def test_every_folded_entry_lists_the_cells_its_predecessors_listed(man):
+    now = {m["name"]: m for m in man["per_layer"]}
+    for was in BEFORE:
+        if was["now"] is not None:
+            assert set(was["workloads"]) <= set(
+                now[was["now"]]["workloads"]), was["was"]
+    retired = {w["was"].split(".")[0] for w in BEFORE if w["now"] is None}
+    assert retired == {"decode_device_ms", "prefill_device_ms",
+                       "moe_block_fill"}
+    assert not retired & {n.split(".")[0] for n in now}
+    for name in list(retired) + ["hetu_device_ms"]:
+        assert not list((spec.BENCH / "layer_metrics").glob(name + "*"))
+        assert not (spec.BENCH / "readers" / f"{name}.py").exists()
+
+
+def test_every_cell_reports_the_quantities_it_reported_each_under_one_name(
+        man):
+    for cell in man["workloads"]:
+        was = [w["now"] for w in BEFORE
+               if cell["name"] in w["workloads"] and w["now"] is not None]
+        assert len(was) == len(set(was)), cell["name"]
+        assert set(was) == {m["name"] for m in spec.metrics_of(
+            man["per_layer"], cell["name"])}, cell["name"]
+
+
+def test_a_traced_rehearsal_prints_the_names_the_manifest_gives_the_cell(
+        man, monkeypatch, capsys):
+    """Off a chip the device's metrics have nothing to read and are left
+    out; what is printed is printed under the manifest's names."""
+    import os
+
+    from benchmarks import run as bench
+
+    for var in ("JAX_PLATFORMS", "XLA_FLAGS"):   # --rehearse sets them
+        monkeypatch.setenv(var, os.environ.get(var, ""))
+    rc = bench.main(["--workload", "gpt2-large.batch", "--seconds", "1",
+                     "--seed", "3000000019", "--rehearse", "--trace", "1"])
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and line["correct"]
+    mine = {m["name"] for m in spec.metrics_of(man["per_layer"],
+                                               "gpt2-large.batch")}
+    assert {"decode_step_ms", "prefill_chunk_ms", "decode_host_ms",
+            "prefill_host_ms", "sched_host_ms", "decode_batch_mean",
+            "engine_compiles"} <= set(line["metric_names"]) <= mine
+    assert "steps_over_1p5x_median" in line["detail"]["counts"]

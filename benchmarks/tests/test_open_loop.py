@@ -60,3 +60,63 @@ def test_latency_readers_read_the_replay(replay, name):
                          cell=ctx.cell, peaks=None, memory=run.memory)
     got = bench.read_layer_metrics(rctx, [{"name": name, "unit": "ms"}])
     assert got[name]["value"] >= 0
+
+
+def test_a_slow_process_and_a_stall_read_apart_in_the_windows_counts():
+    """``Serving.stalls`` from the lists the window already keeps: a
+    process in which every call is slower moves the MEDIAN of its engine
+    spans; one held step moves the longest and the mean and leaves the
+    median where it was."""
+    def counts(decode_ms):
+        sv = loops.Serving.__new__(loops.Serving)
+        sv.rec, sv.host_probe, t = Recorder(), [], 0.0
+        sv.steps = []
+        for ms in decode_ms:
+            sv.rec.spans["engine.decode"].append((t, t + ms / 1e3))
+            sv.steps.append((t, t + ms / 1e3 + 1e-4))
+            t += ms / 1e3 + 2e-4
+        sv.rec.spans["engine.prefill_step"] += [(0.0, 0.040), (1.0, 1.050)]
+        return sv.stalls(0.0)
+
+    fast = counts([5.0] * 99 + [6.0])
+    assert fast["decode_span_median_ms"] == pytest.approx(5.0)
+    assert fast["decode_span_p95_ms"] == pytest.approx(5.0)
+    assert fast["decode_span_mean_ms"] == pytest.approx(5.01)
+    assert fast["chunk_span_median_ms"] == pytest.approx(45.0)
+    assert fast["chunk_span_p95_ms"] == pytest.approx(49.5)
+    assert fast["steps_over_1p5x_median"] == 0
+    slow = counts([7.2] * 99 + [8.2])        # every call 2.2 ms up
+    assert slow["decode_span_median_ms"] == pytest.approx(7.2)
+    assert slow["steps_over_1p5x_median"] == 0
+    held = counts([5.0] * 97 + [9.0, 9.0, 2500.0])   # one launch held
+    assert held["decode_span_median_ms"] == pytest.approx(5.0)
+    assert held["decode_span_mean_ms"] > 6 * held["decode_span_median_ms"]
+    assert held["steps_over_1p5x_median"] == 3
+    assert held["longest_step_ms"] == pytest.approx(2500.1)
+
+
+def test_the_traced_stretch_runs_without_the_python_tracer(
+        monkeypatch, tmp_path):
+    """``traced`` hands the profiler ``python_tracer_level`` 0 and leaves
+    the host tracer, which writes the spans and the runtime's events, as
+    it is."""
+    import jax
+
+    calls = []
+    monkeypatch.setattr(loops, "TRACE_DIR", tmp_path / "trace")
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda log_dir, **kw: calls.append((log_dir, kw)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    rec = Recorder()
+    rec.series["decode_active"].append(3)       # from before the stretch
+    with loops.traced(rec):
+        assert rec.annotate and not rec.series
+    (log_dir, kw), stop = calls
+    assert log_dir == str(tmp_path / "trace") and stop == "stop"
+    options = kw["profiler_options"]
+    assert options.python_tracer_level == 0
+    assert options.host_tracer_level == \
+        jax.profiler.ProfileOptions().host_tracer_level
+    assert not rec.annotate
