@@ -61,6 +61,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import time
 from typing import Optional
 
 import jax
@@ -74,7 +75,7 @@ from hetu_tpu.parallel.strategies.simple import MegatronLM
 from hetu_tpu.serve.kv_cache import (
     KVCacheSpec, PagedKVCache, PagedLayers, SlotStates, pow2_ceil,
 )
-from hetu_tpu.serve.metrics import ServeMetrics
+from hetu_tpu.serve.metrics import CHUNK, DECODE, ServeMetrics
 from hetu_tpu.telemetry import trace
 
 
@@ -295,9 +296,12 @@ class PagedServeEngine:
         self._stat_names = tuple(getattr(model, "step_stats", ()))
         self._released_seen = 0   # of cache.window_released, by _count
         # programs launched so far, chunks and decode rounds counted
-        # together: ``seq`` on a launch span, and on the fetch span that
-        # waits for that launch
+        # together: ``seq`` on a launch span, on the fetch span that waits
+        # for that launch, and on the call's row of the round log
         self._seq = 0
+        # the running call's seam times (prep, launch, fetch, post) for its
+        # row of the round log; a list here and no locals there: decode()
+        self._stamps = [0, 0, 0, 0]
         # the further groups of the model's cache (window layers beside
         # full ones): the fixed widths of their tables in the chunk programs
         # and in the decode program (a ring as wide as the step needs)
@@ -695,6 +699,7 @@ class PagedServeEngine:
         # Inline, not a helper: see decode()
         with trace.span("serve.prefill_chunk", {"slot": int(slot)}):
             with trace.span("serve.prefill_chunk.prep"):
+                self._stamps[0] = time.monotonic_ns()
                 if not cur.matched:
                     self._match_on_first_chunk(slot, cur)
                 start = cur.pos
@@ -738,12 +743,15 @@ class PagedServeEngine:
                              "bucket": int(s),
                              "view_bytes": n_table * self._page_view_bytes[0],
                              "seq": self._seq}):
+                self._stamps[1] = time.monotonic_ns()
                 k, v, tok, stats = chunk_fn(
                     self.params, self.cache.k, self.cache.v, jnp.asarray(aux))
             with trace.span("serve.prefill_chunk.fetch", {"seq": self._seq}):
+                self._stamps[2] = time.monotonic_ns()
                 tok = int(tok)  # the host blocked on the device
                 counts = self._count(stats)
             with trace.span("serve.prefill_chunk.post", counts):
+                self._stamps[3] = time.monotonic_ns()
                 self.cache.update(k, v)
                 self.cache.lengths[slot] = end
                 cur.pos = end
@@ -752,13 +760,15 @@ class PagedServeEngine:
                 if self.cache.cow_copies > cow0:
                     self.metrics.inc("cow_copies",
                                      self.cache.cow_copies - cow0)
-                if not cur.done:
-                    return None
-                del self._cursors[slot]
-                self.cache.register_prefix(slot, cur.prompt)
-                self.last_tokens[slot] = tok
-                self.active[slot] = True
-                return tok
+                first = None
+                if cur.done:
+                    del self._cursors[slot]
+                    self.cache.register_prefix(slot, cur.prompt)
+                    self.last_tokens[slot] = first = tok
+                    self.active[slot] = True
+        self.metrics.observe_round(
+            self._seq, CHUNK, *self._stamps, time.monotonic_ns(), 1, s, size)
+        return first
 
     def _prefill_step_grouped(self, slot: int, cur) -> Optional[int]:
         """:meth:`prefill_step` over a cache of several groups or with state
@@ -768,6 +778,7 @@ class PagedServeEngine:
         the groups' page counts and the state's on the ``post`` span."""
         with trace.span("serve.prefill_chunk", {"slot": int(slot)}):
             with trace.span("serve.prefill_chunk.prep"):
+                self._stamps[0] = time.monotonic_ns()
                 if not cur.matched:
                     self._match_on_first_chunk(slot, cur)
                 start = cur.pos
@@ -826,12 +837,15 @@ class PagedServeEngine:
                     launch[f"g{i}_view_bytes"] = \
                         ring * self._page_view_bytes[i]
             with trace.span("serve.prefill_chunk.launch", launch):
+                self._stamps[1] = time.monotonic_ns()
                 k, v, tok, stats, *state = chunk_fn(
                     self.params, k_pool, v_pool, jnp.asarray(aux), *state)
             with trace.span("serve.prefill_chunk.fetch", {"seq": self._seq}):
+                self._stamps[2] = time.monotonic_ns()
                 tok = int(tok)  # the host blocked on the device
                 counts = self._held(self._count(stats), slot, end)
             with trace.span("serve.prefill_chunk.post", counts):
+                self._stamps[3] = time.monotonic_ns()
                 self.cache.update(k, v, *state)
                 self.cache.lengths[slot] = end
                 cur.pos = end
@@ -840,13 +854,15 @@ class PagedServeEngine:
                 if self.cache.cow_copies > cow0:
                     self.metrics.inc("cow_copies",
                                      self.cache.cow_copies - cow0)
-                if not cur.done:
-                    return None
-                del self._cursors[slot]
-                self.cache.register_prefix(slot, cur.prompt)
-                self.last_tokens[slot] = tok
-                self.active[slot] = True
-                return tok
+                first = None
+                if cur.done:
+                    del self._cursors[slot]
+                    self.cache.register_prefix(slot, cur.prompt)
+                    self.last_tokens[slot] = first = tok
+                    self.active[slot] = True
+        self.metrics.observe_round(
+            self._seq, CHUNK, *self._stamps, time.monotonic_ns(), 1, s, size)
+        return first
 
     def prefill(self, slot: int, prompt_ids) -> int:
         """Whole-prompt prefill, for callers with nothing to interleave:
@@ -886,9 +902,19 @@ class PagedServeEngine:
         # and calls the program, fetch is the host blocked on the device,
         # post the books.  Inline on purpose: with the round in a helper
         # method the first call of each of the 28 decode programs took
-        # 0.16 s longer on the v5e (warm-up 11.6 s -> 16.2 s; PERF.md, PR 25)
+        # 0.16 s longer on the v5e (warm-up 11.6 s -> 16.2 s; PERF.md, PR 25).
+        # Each seam also reads ``time.monotonic_ns()`` as its span's first
+        # statement, and the reads are the call's row of the round log
+        # (serve/metrics.py), written once the outer span has closed: no
+        # profiler session needed to read it.  The reads go into a list the
+        # engine holds, NOT into locals of this frame: four more locals round
+        # the jitted call made the first call of each of 52 programs 0.055 s
+        # slower on the v5e (warm-up 9.5 s -> 12.5 s, the same reads into
+        # ``self._stamps`` 9.5 s; PERF.md, PR 54), which is also what PR 32's
+        # "pools in two locals" paid
         with trace.span("serve.decode", {"active": len(act)}):
             with trace.span("serve.decode.prep"):
+                self._stamps[0] = time.monotonic_ns()
                 if (self.cache.lengths[act] >= self.cache.max_len).any():
                     raise RuntimeError(
                         "an active slot is at max_len; the scheduler must "
@@ -934,13 +960,16 @@ class PagedServeEngine:
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb),
                              "seq": self._seq}):
+                self._stamps[1] = time.monotonic_ns()
                 k, v, nxt, stats = self._decode_fn(
                     self.params, self.cache.k, self.cache.v,
                     jnp.asarray(aux))
             with trace.span("serve.decode.fetch", {"seq": self._seq}):
+                self._stamps[2] = time.monotonic_ns()
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = self._count(stats)
             with trace.span("serve.decode.post", counts):
+                self._stamps[3] = time.monotonic_ns()
                 self.cache.update(k, v)
                 out = {}
                 for i, slot in enumerate(act):
@@ -951,12 +980,14 @@ class PagedServeEngine:
                     self.metrics.inc("cow_copies",
                                      self.cache.cow_copies - cow0)
                 self.metrics.inc("decode_steps")
-                self.metrics.observe_decode(len(out))
                 self.metrics.set_gauge("pages_in_use",
                                        self.cache.pages_in_use)
                 self.metrics.set_gauge("prefix_entries",
                                        self.cache.prefix_entries)
-            return out
+        self.metrics.observe_round(
+            self._seq, DECODE, *self._stamps, time.monotonic_ns(),
+            bb, n_pg, len(out))
+        return out
 
     def _decode_grouped(self, act) -> dict:
         """:meth:`decode` over a cache of several groups or with state
@@ -967,6 +998,7 @@ class PagedServeEngine:
         and the groups' page counts and the state's on the ``post`` span."""
         with trace.span("serve.decode", {"active": len(act)}):
             with trace.span("serve.decode.prep"):
+                self._stamps[0] = time.monotonic_ns()
                 if (self.cache.lengths[act] >= self.cache.max_len).any():
                     raise RuntimeError(
                         "an active slot is at max_len; the scheduler must "
@@ -1030,6 +1062,7 @@ class PagedServeEngine:
             with trace.span("serve.decode.launch",
                             {"pages": int(n_pg), "batch": int(bb),
                              "seq": self._seq}):
+                self._stamps[1] = time.monotonic_ns()
                 k, v, nxt, stats, *state = self._decode_fn(
                     self.params, k_pool, v_pool, aux, *state)
                 # the copies to the host queued behind the program, not
@@ -1037,11 +1070,13 @@ class PagedServeEngine:
                 for result in (nxt, *stats):
                     result.copy_to_host_async()
             with trace.span("serve.decode.fetch", {"seq": self._seq}):
+                self._stamps[2] = time.monotonic_ns()
                 # the books that need no token, while the device runs
                 held = self._held(None, act, self.cache.lengths[act] + 1)
                 nxt = np.asarray(nxt)  # the host blocked on the device
                 counts = {**(self._count(stats) or {}), **held}
             with trace.span("serve.decode.post", counts):
+                self._stamps[3] = time.monotonic_ns()
                 self.cache.update(k, v, *state)
                 self.cache.lengths[act] += 1
                 self.last_tokens[act] = nxt[:n]
@@ -1050,12 +1085,14 @@ class PagedServeEngine:
                     self.metrics.inc("cow_copies",
                                      self.cache.cow_copies - cow0)
                 self.metrics.inc("decode_steps")
-                self.metrics.observe_decode(len(out))
                 self.metrics.set_gauge("pages_in_use",
                                        self.cache.pages_in_use)
                 self.metrics.set_gauge("prefix_entries",
                                        self.cache.prefix_entries)
-            return out
+        self.metrics.observe_round(
+            self._seq, DECODE, *self._stamps, time.monotonic_ns(),
+            bb, n_pg, len(out))
+        return out
 
     # ---- live-slot migration ----
     def export_slots(self, slot_ids) -> list:

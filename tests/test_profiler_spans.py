@@ -182,6 +182,7 @@ def recorded(tmp_path_factory):
         out["loss_session"], state = _train(ex, variables, batch)
         out["lowered_session"] = _lowered(eng, ex, state, batch)
     out["programs_session"] = eng.compiled_executables()
+    out["rounds"] = eng.metrics.rounds()    # the round log, both passes
     threads = hetu_threads(log)
     assert len(threads) == 1, "one thread did the work"
     out["events"] = threads[0]
@@ -346,6 +347,63 @@ def test_a_fetch_carries_the_seq_of_the_launch_it_waits_for(recorded, kind):
         launch, fetch = kids[kind + ".launch"], kids[kind + ".fetch"]
         assert launch[3]["seq"] == fetch[3]["seq"]
         assert launch[2] <= fetch[1]
+
+
+# ------------------------------------------------ the round log (ISSUE 54)
+
+def test_the_round_log_has_a_row_a_launch_numbered_as_the_spans_are(recorded):
+    """One row an engine call, rounds and chunks together, ``seq`` rising by
+    one from the engine's first call; the session's rows are the rows whose
+    ``seq`` its launch spans carry, and each is of its span's kind."""
+    from hetu_tpu.serve.metrics import CHUNK, DECODE, ROUND_FIELDS
+
+    rows = recorded["rounds"]
+    assert rows.dtype == np.int64 and rows.shape[1] == len(ROUND_FIELDS)
+    assert rows[:, 0].tolist() == list(range(1, len(rows) + 1))
+    assert set(rows[:, 1].tolist()) == {DECODE, CHUNK}
+    kind_of = {"serve.decode.launch": DECODE,
+               "serve.prefill_chunk.launch": CHUNK}
+    launches = {e[3]["seq"]: kind_of[e[0]] for e in recorded["events"]
+                if e[0] in kind_of}
+    assert len(launches) == len(rows) // 2    # the second pass of two
+    assert {int(r[0]): int(r[1]) for r in rows[len(rows) // 2:]} == launches
+
+
+def test_a_rows_phases_tile_its_call_and_calls_follow_each_other(recorded):
+    """``t_prep <= t_launch <= t_fetch <= t_post <= t_close``: the four
+    phases are the differences, so they tile the call with no gap; and a
+    call opens after the call before it closed."""
+    rows = recorded["rounds"]
+    seams = rows[:, 2:7]
+    assert (np.diff(seams, axis=1) >= 0).all()
+    assert (seams[1:, 0] >= seams[:-1, 4]).all()
+    assert (seams[:, 4] - seams[:, 0]).min() > 0
+
+
+def test_a_rows_launch_and_fetch_lie_inside_the_spans_of_its_seq(recorded):
+    """The log reads ``time.monotonic_ns()`` as the first statement of each
+    seam's span, the profiler stamps the span on a clock of its own: there
+    is ONE offset between the two under which every row's ``t_launch`` lies
+    inside the launch span of its ``seq`` and its ``t_fetch`` inside the
+    fetch span, and the spans' openings sit within microseconds of the
+    log's reads under it."""
+    events = recorded["events"]
+    spans = {}
+    for name, a, b, ids in events:
+        seam = name.rsplit(".", 1)[-1]
+        if seam in ("launch", "fetch"):
+            spans[(ids["seq"], seam)] = (a, b)
+    rows = [r for r in recorded["rounds"] if (int(r[0]), "launch") in spans]
+    assert len(rows) >= 12
+    lo, hi, opened = -np.inf, np.inf, []
+    for seq, _, _, t_launch, t_fetch, *_ in rows:
+        for seam, t in (("launch", t_launch), ("fetch", t_fetch)):
+            a, b = spans[(int(seq), seam)]
+            lo, hi = max(lo, a - t), min(hi, b - t)
+            opened.append(a - t)
+    assert lo <= hi, "no one offset puts every read inside its span"
+    # a span's opening to the read inside it: the annotation's own cost
+    assert np.median(np.asarray(opened) - lo) > -50_000
 
 
 def test_train_step_span_carries_the_steps_number(recorded):

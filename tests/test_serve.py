@@ -317,9 +317,19 @@ def test_metrics_report_through_metric_logger(gpt, tmp_path):
     logger.close()
     for key in ("ttft_avg_s", "tokens_per_sec", "queue_depth",
                 "slot_occupancy", "prefill_compiles", "decode_compiles",
-                "requests_ok", "generated_tokens"):
+                "requests_ok", "generated_tokens", "rounds_kept",
+                "decode_launch_p50_ms", "decode_fetch_p95_ms",
+                "chunk_fetch_p50_ms", "engine_gap_p50_ms"):
         assert key in snap, key
     assert snap["requests_ok"] == 2
+    # from the round log: a row a decode round and a prefill chunk, and the
+    # rounds' tokens over the time from the first opening to the last close
+    rows = metrics.rounds()
+    assert snap["rounds_kept"] == len(rows) \
+        == snap["decode_steps"] + snap["prefill_chunks"]
+    rounds = rows[rows[:, 1] == 0]
+    assert snap["tokens_per_sec"] == pytest.approx(
+        rounds[:, -1].sum() * 1e9 / (rounds[-1, 6] - rounds[0, 2]))
     assert snap["ttft_avg_s"] > 0
     rec = json.loads(log_path.read_text().strip().splitlines()[-1])
     assert rec["requests_ok"] == 2 and "ttft_avg_s" in rec

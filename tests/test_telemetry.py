@@ -312,12 +312,18 @@ def test_serve_metrics_report_through_logger():
     m.inc("requests_ok", 2)
     m.set_gauge("queue_depth", 1)
     m.observe_ttft(0.02)
-    m.observe_decode(8)
+    # one decode round of the round log (seq, kind, five seams in ns, batch,
+    # pages, tokens): 8 tokens in 4 ms
+    m.observe_round(1, 0, 1_000_000, 2_000_000, 2_500_000, 4_500_000,
+                    5_000_000, 8, 4, 8)
     lg = ht.utils.logger.MetricLogger()
     snap = m.report(lg, step=1)
     for key in ("requests_ok", "queue_depth", "ttft_avg_s", "ttft_p50_s",
-                "ttft_p90_s", "ttft_p99_s", "ttft_max_s"):
+                "ttft_p90_s", "ttft_p99_s", "ttft_max_s", "tokens_per_sec",
+                "rounds_kept", "decode_fetch_p50_ms", "decode_prep_p95_ms"):
         assert key in snap
+    assert snap["tokens_per_sec"] == pytest.approx(2000.0)
+    assert snap["decode_fetch_p50_ms"] == pytest.approx(2.0)
     assert lg.means()["requests_ok"] == 2
 
 
