@@ -365,9 +365,11 @@ class HeldExpertLayer:
     fits the kernels' VMEM, at any row count (a training step's walk, three
     grouped calls and one scatter-add a trip; a served model that holds
     every expert, as LFM2's decode rounds and prefill chunks, ONE call a
-    walk that reads each hit expert's weights once and a gather back), and
-    a loop over blocks of ``block_rows`` sorted rows where it does not, as
-    K-EXAONE's and LongCat's 6144 x 2048 experts; measured on a v5e at
+    walk that reads each hit expert's weights once and a gather back); where
+    it does not, as K-EXAONE's and LongCat's 6144 x 2048 experts, a served
+    round or chunk still takes the grouped path's forward, an expert ONE
+    fused call cut along its intermediate width, and only reverse mode walks
+    a loop over blocks of ``block_rows`` sorted rows; measured on a v5e at
     2048 x 1792, 32 of 32 held, twelve walks: a round of 64 slots 22.3 ms on
     the loop and 12.7 grouped, a 2,048-token chunk 58.5 and 30.7, one token
     4.1 and 2.7: the table is in ``held_expert_ffn``.  ``block_rows``

@@ -75,19 +75,34 @@ def draw_leaf(key, lead: tuple, shape: tuple, std, dtype):
     return out.reshape(lead + shape)
 
 
+def _evaluated_grouped(c) -> int:
+    """1 where grouped calls compute the held pairs of a cache entry point
+    of configuration ``c``, 0 where the loop does.  An entry point is
+    evaluated and never differentiated, so 1 unless
+    ``ops.moe_ops.held_expert_path``'s static rule says ``"loop"`` (it reads
+    an expert's size alone, so one token stands for a call of any row
+    count)."""
+    return int(held_expert_path(1, c.moe_topk, c.held[1], c.hidden_size,
+                                c.expert_ffn_size) != "loop")
+
+
+def with_grouped(c, stats):
+    """``stats`` (the expert layers' sum, ``MOE_STATS`` first) with
+    ``moe_grouped`` behind them: the held pairs that grouped calls computed,
+    all of them or none (:func:`_evaluated_grouped`)."""
+    return jnp.concatenate([stats, (stats[0] * _evaluated_grouped(c))[None]])
+
+
 def counts_with_grouped(c, stats):
     """The counts a cache entry point of a model whose ``step_stats`` are
     ``MOE_STATS + ("moe_experts", "moe_grouped")`` returns, from the expert
     layers' sum ``stats`` [4]: behind them the held experts a call could hit
-    at most (held x expert layers, a constant) and the held pairs that the
-    walk's grouped path computed, all of them or none by
-    ``ops.moe_ops.held_expert_path``'s static rule (which reads an expert's
-    size alone, so one token stands for a call of any row count)."""
-    grouped = held_expert_path(1, c.moe_topk, c.held[1], c.hidden_size,
-                               c.expert_ffn_size) == "grouped"
+    at most (held x expert layers, a constant) and the held pairs that
+    grouped calls computed, all of them or none
+    (:func:`_evaluated_grouped`)."""
     return jnp.concatenate([stats, jnp.stack([
         jnp.int32(c.held[1] * (c.num_layers - c.first_dense)),
-        stats[0] * int(grouped)])])
+        stats[0] * _evaluated_grouped(c)])])
 
 
 @dataclass

@@ -56,8 +56,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from hetu_tpu.layers.moe import HeldExpertLayer
-from hetu_tpu.models.block import FULL, WINDOW, BlockDecoder, draw_leaf
+from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
+from hetu_tpu.models.block import (
+    FULL, WINDOW, BlockDecoder, draw_leaf, with_grouped,
+)
 
 
 @dataclass
@@ -115,6 +117,10 @@ class ExaoneMoeModel(BlockDecoder):
     down} over the ``first_dense`` leading layers, ``moe``
     (:class:`HeldExpertLayer`'s parameters) over the L - ``first_dense``
     expert layers."""
+
+    # the expert layers' counts and the held pairs that the walk's grouped
+    # calls computed (``models.block.with_grouped``)
+    step_stats = MOE_STATS + ("moe_grouped",)
 
     def __init__(self, config: ExaoneMoeConfig):
         c = config
@@ -207,3 +213,6 @@ class ExaoneMoeModel(BlockDecoder):
             "norm_f": ones(H),
             "layers": layers,
         }, "state": {}}
+
+    def _counts(self, stats):
+        return with_grouped(self.c, stats)

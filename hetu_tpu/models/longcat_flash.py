@@ -56,6 +56,7 @@ from hetu_tpu.layers.base import (
     HELD_TRANSPOSED, Module, held_transposed, linear_held,
 )
 from hetu_tpu.layers.moe import MOE_STATS, HeldExpertLayer
+from hetu_tpu.models.block import with_grouped
 from hetu_tpu.ops.pallas_kernels.flash_attention import (
     flash_chunk_attention, unwritten, write_rows,
 )
@@ -312,8 +313,10 @@ class LongcatFlashModel(Module):
     layers, and the two attention blocks / dense FFNs of a double layer
     over a second axis of 2."""
 
-    # what the fourth value of the two cache entry points counts, in order
-    step_stats = MOE_STATS
+    # what the fourth value of the two cache entry points counts, in order:
+    # the expert layers' counts and the held pairs that the walk's grouped
+    # calls computed (``models.block.with_grouped``)
+    step_stats = MOE_STATS + ("moe_grouped",)
 
     def __init__(self, config: LongcatFlashConfig):
         self.c = config
@@ -530,7 +533,8 @@ class LongcatFlashModel(Module):
 
         (h, k_cache, v_cache), stats = jax.lax.scan(
             layer, (h, k_cache, v_cache), jnp.arange(self.c.num_layers))
-        return self._norm(h, p["norm_f"]), k_cache, v_cache, stats.sum(0)
+        return (self._norm(h, p["norm_f"]), k_cache, v_cache,
+                with_grouped(self.c, stats.sum(0)))
 
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None):

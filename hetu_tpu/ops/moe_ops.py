@@ -21,9 +21,12 @@ a router as wide as published over experts of which this chip holds a
 contiguous share, no capacity, no drops, and work that follows the rows
 routed here, forward and backward.  It has two paths, chosen from static
 shapes by :func:`held_expert_path`: sorted rows through grouped matmuls
-where an expert's weight fits the kernels' VMEM (the trained cells' steps,
-LFM2's decode rounds and prefill chunks) and a loop over row blocks where
-it does not (K-EXAONE's and LongCat's 6144 x 2048 experts).
+where an expert's weight fits the kernels' VMEM whole (the trained cells'
+steps, LFM2's and Qwen3-Next's decode rounds and prefill chunks); and where
+it does not (K-EXAONE's and LongCat's 6144 x 2048 experts) the same sorted
+rows through ONE fused grouped call cut along the expert's intermediate
+width wherever the walk is evaluated, and a loop over row blocks wherever it
+is differentiated.
 """
 
 from __future__ import annotations
@@ -311,19 +314,26 @@ def _held_backward(x, weights, idx, w_gate, w_up, w_down, layer, plan, d_out,
         back(w_down, dwd), None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _held(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
-    return _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
-                         first, R)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _held(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B):
+    # evaluated, not differentiated (JAX runs the two rules below only under
+    # autodiff): the grouped path's forward in trips of ``B`` rows, an
+    # expert a fused call cut along F, and the loop reverse mode's alone;
+    # without ``B`` (no tile of F fits) the loop's own forward
+    if B is None:
+        return _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
+                             first, R)[0]
+    return _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer,
+                            first, B, True)[0]
 
 
-def _held_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
+def _held_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B):
     out, plan = _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
                               first, R)
     return out, (x, weights, idx, w_gate, w_up, w_down, layer, plan)
 
 
-def _held_vjp_bwd(first, R, res, g):
+def _held_vjp_bwd(first, R, B, res, g):
     return _held_backward(*res, g[0], R)
 
 
@@ -347,21 +357,32 @@ _held.defvjp(_held_vjp_fwd, _held_vjp_bwd)
 # the most sorted rows a trip of the grouped path holds at once: its buffers
 # are [rows, H] and [rows, F], whatever T * k is
 GROUPED_ROW_BUDGET = 20480
-# the grouped path is taken where an expert's [H, F] weight is at most this
-# many elements: the grouped matmuls keep one whole in VMEM, two of them
-# double-buffered in the call that makes dx, three in the call that is a
-# whole expert
+# the grouped path is taken, forward and backward, where an expert's [H, F]
+# weight is at most this many elements: the grouped matmuls keep one whole in
+# VMEM, two of them double-buffered in the call that makes dx, three in the
+# call that is a whole expert.  Past it the constant separates what is
+# EVALUATED (a served round or chunk: the fused call of a whole expert, cut
+# along F, ``gm.ffn_tiles``) from what is DIFFERENTIATED (the loop: the calls
+# that make dx and dW hold two or three whole weights and are not cut)
 GROUPED_MAX_WEIGHT = 4 * 1024 * 1024
 
 
-def held_expert_path(T: int, k: int, E: int, H: int, F: int) -> str:
-    """``"grouped"`` or ``"loop"``: which walk :func:`held_expert_ffn` takes
-    for ``T`` tokens of ``k`` choices over ``E`` held experts of ``[H, F]``.
+def held_expert_path(T: int, k: int, E: int, H: int, F: int,
+                     itemsize: int = 2) -> str:
+    """``"grouped"``, ``"cut"`` or ``"loop"``: which walk
+    :func:`held_expert_ffn` takes for ``T`` tokens of ``k`` choices over
+    ``E`` held experts of ``[H, F]`` (``itemsize`` bytes an element).
     Static shapes alone decide, and of them the expert's size alone: where
     the kernels can keep a weight whole the grouped path is ahead at every
-    row count measured, one token included (the rule and the measurement
-    behind it are stated in :func:`held_expert_ffn`)."""
-    return "grouped" if H * F <= GROUPED_MAX_WEIGHT else "loop"
+    row count measured, one token included; where they cannot, a walk that
+    is EVALUATED runs the grouped path's forward with an expert a fused call
+    cut along F, and one that is differentiated runs the loop's rules
+    (``"cut"``); the loop evaluates too only where no whole-lane tile of F
+    fits (``"loop"``: no published width).  The rule and the measurements
+    behind it are stated in :func:`held_expert_ffn`."""
+    if H * F <= GROUPED_MAX_WEIGHT:
+        return "grouped"
+    return "cut" if gm.ffn_tiles(H, F, itemsize) else "loop"
 
 
 def grouped_row_budget(T: int, k: int, E: int, routed=None) -> int:
@@ -429,7 +450,8 @@ class _Trip(NamedTuple):
     #                        grouped call of the trip reads them
 
 
-def _trip(plan: _GroupedPlan, s, B: int, T: int, k: int) -> _Trip:
+def _trip(plan: _GroupedPlan, s, B: int, T: int, k: int,
+          tile_rows=None) -> _Trip:
     lo = s * B
     pairs = lax.dynamic_slice_in_dim(plan.order, lo, B)
     live = lo + jnp.arange(B) < plan.held
@@ -437,22 +459,26 @@ def _trip(plan: _GroupedPlan, s, B: int, T: int, k: int) -> _Trip:
     return _Trip(pairs, pairs // k, jnp.where(live, pairs, T * k),
                  jnp.where(live, pairs // k, T),
                  gm.group_visits(jnp.clip(plan.row_start - lo, 0, B),
-                                 jnp.clip(end - lo, 0, B), B))
+                                 jnp.clip(end - lo, 0, B), B, tile_rows))
 
 
-@functools.partial(jax.jit, static_argnames=("first", "B"))
+@functools.partial(jax.jit, static_argnames=("first", "B", "fused"))
 def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
-                     B):
+                     B, fused=False):
+    """``fused``: a trip's experts in ONE call (``gmm_ffn``) where the rule
+    says three; the row tile is then that call's (``gm.ffn_tiles``)."""
     (T, _), k, dt = x.shape, idx.shape[1], x.dtype
     plan = _grouped_plan(idx, first, w_gate.shape[-3], B)
     pair_w = weights.reshape(-1)
     (wg, wu, wd), layer = _layer_leaves((w_gate, w_up, w_down), layer, dt)
+    tile_rows = gm.ffn_tiles(*wg.shape[-2:], dt.itemsize)[0] if fused \
+        else None
 
     if B >= T * k:
         # one trip holds every pair: a whole expert a visit in one call, and
         # each token gathers its k rows back (a pair's row is where the
         # sort put it: the inverse permutation)
-        t = _trip(plan, 0, B, T, k)
+        t = _trip(plan, 0, B, T, k, tile_rows)
         y = gm.gmm_ffn(x[t.tok], wg, wu, wd, pair_w[t.pairs], t.visits,
                        layer=layer)
         at = jnp.argsort(plan.order[:T * k])
@@ -460,12 +486,16 @@ def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
         return (y.reshape(T, k, -1).sum(1), plan.counts), plan
 
     def body(s, out):
-        t = _trip(plan, s, B, T, k)
+        t = _trip(plan, s, B, T, k, tile_rows)
         rows = x[t.tok]                                   # [B, H], once
-        g = gm.gmm(rows, wg, t.visits, layer=layer, out_dtype=dt)
-        u = gm.gmm(rows, wu, t.visits, layer=layer, out_dtype=dt)
-        y = gm.gmm(jax.nn.silu(g) * u, wd, t.visits, layer=layer,
-                   row_scale=pair_w[t.pairs])
+        if fused:
+            y = gm.gmm_ffn(rows, wg, wu, wd, pair_w[t.pairs], t.visits,
+                           layer=layer)
+        else:
+            g = gm.gmm(rows, wg, t.visits, layer=layer, out_dtype=dt)
+            u = gm.gmm(rows, wu, t.visits, layer=layer, out_dtype=dt)
+            y = gm.gmm(jax.nn.silu(g) * u, wd, t.visits, layer=layer,
+                       row_scale=pair_w[t.pairs])
         # rows past the held pairs were never written: dropped, not added
         return out.at[t.add_tok].add(y, mode="drop")
 
@@ -553,7 +583,7 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     slice is copied every step).  Returns (out [T, H] float32, counts [E]
     int32 pairs per held expert).
 
-    No capacity and no drops, on either of two paths: the pairs are sorted by
+    No capacity and no drops, on either path: the pairs are sorted by
     held expert once, every pair is computed at any imbalance, the work
     follows the rows routed here and not ``T * k``, and an expert nobody
     chose costs nothing: its weights are not read.
@@ -598,6 +628,33 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     JAX splits a jitted call into kept and recomputed parts once a (jaxpr,
     policy object) pair.
 
+    **Experts too wide to keep whole** (``H * F > 4 Mi``: K-EXAONE's and
+    LongCat's 6144 x 2048, three weights of 25 MB) take the grouped path's
+    forward wherever the walk is EVALUATED (a decode round, a prefill chunk,
+    a dense forward that nothing differentiates), in either of its two forms
+    by the same static budget, with ONE call where the other experts have
+    one or three: ``gmm_ffn`` cut along F.  A visit walks the expert's
+    intermediate width in tiles of ``f_t`` columns on a second, innermost
+    grid axis: a step holds ``w_gate[:, f]``, ``w_up[:, f]`` and
+    ``w_down[f, :]``, computes ``silu(x w_gate_f) * (x w_up_f)`` whole (the
+    contraction over H is whole inside a tile, rounded exactly as the
+    whole-weight call rounds it) and adds its down product into a float32
+    ``[rows, H]`` sum in VMEM that the last step scales by the pair weight
+    and stores; neither the ``[rows, F]`` intermediate nor a partial sum
+    reaches HBM, and the next tile's fetch runs behind the current one's
+    matmuls.  ``f_t`` and the row tile are constants read from the shapes
+    (``gm.ffn_tiles``: 1,024 columns and 128 rows at 6144 x 2048, with the
+    chip's readings).  A served round of K-EXAONE (16 slots x 8 choices of
+    128 experts, 16 held: ``budget`` 256 >= 128 pairs) is the one-trip form;
+    its 512-token chunk (``budget`` 768 < 4,096) the trips form, the call
+    standing where the three calls stand and the trip's combine ONE
+    scatter-add (0.87 ms for 768 rows timed alone, 1.04 for the gather of
+    4,096 that the one-trip form would need there: it stays).  Where the
+    walk is DIFFERENTIATED the loop below runs, forward and backward: JAX
+    runs a ``custom_vjp``'s rules only under autodiff, so static shapes and
+    JAX's own split decide, no option and no model's name.  No cell of the
+    benchmark trains such experts (``tests/test_moe.py`` does).
+
     **The loop path** walks the sorted pairs in blocks of ``block_rows``
     rows, ``ceil(count_e / block_rows)`` blocks for expert ``e``, the trip
     count read from the counts.  A block gathers its rows from ``x``,
@@ -605,11 +662,15 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     weight: a block costs 55-62 us on a v5e whatever it holds) and adds its
     result into ``out``; in reverse it also adds into its expert's whole
     float32 ``dW``.  Nothing is bounded by a buffer of rows: only the sorted
-    pair indices are held.  It is what is left where an expert's weight does
-    not fit the grouped kernels' VMEM.  ``block_rows`` is this path's alone.
+    pair indices are held.  It is reverse mode's where an expert's weight
+    does not fit the grouped kernels' VMEM (``gmm(..., also=)``,
+    ``gmm_down_back`` and ``tgmm`` hold two or three whole weights and are
+    not cut), and evaluates too only where no whole-lane tile of F fits.
+    ``block_rows`` is this path's alone.
 
     **The rule** (:func:`held_expert_path`; static shapes only, no option):
-    grouped where ``H * F <= 4 Mi``, at ANY row count; the loop otherwise.
+    grouped where ``H * F <= 4 Mi``, at ANY row count; past that the fused
+    call cut along F evaluates and the loop differentiates.
     Set on the chip (v5e).  *Forward alone* at LFM2's 2048 x 1792, 32 of 32
     held, ``k`` = 4, twelve walks in a chain as its programs hold them
     (PERF.md section 6, PR 44, call 6; ms, loop at 128-row blocks | grouped
@@ -629,17 +690,34 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     the MXU's rate: tiles of 64 and 128 read 30.0 and 30.2, of 512 46.1; a
     tile of 32 reads 2.20 at one token and 12.59 at 64, a fifth and a
     hundredth less, and was not kept: one tile for every shape).
+    *Forward alone* at 6144 x 2048, 16 held, twelve walks in a chain over
+    four stacked layers (PERF.md section 6, PR 55, call 1; ms, loop at
+    128-row blocks | the fused call cut along F at 128 rows and ``f_t``
+    1,024; K-EXAONE ``k`` = 8 of 128 experts, LongCat ``k`` = 12 of 768):
+
+        T (K-EXAONE)   1      4      16     512     T (LongCat)  16     512
+        loop          3.29   7.16  19.44  32.98                 7.89  33.04
+        cut           3.02   5.88  14.61  28.59                 6.65  23.26
+
+    (T = 1 and 4 from call 3.  A round of 16 slots reads its 129 hit
+    experts' 75.5 MB each at 667 GB/s, 81% of the chip's 819, where the
+    loop reads 501, 61%; a chunk of K-EXAONE's re-reads the expert whose
+    rows cross one of its three 128-row tile boundaries, a cut visit
+    keeping no slab across visits, and reads 507 GB/s of the hit experts'
+    own bytes; LongCat's 128 held pairs a chunk are one tile, 623.  Row
+    tiles of 64 | 128 | 256 at ``f_t`` 512 read 15.36 | 15.47 | 15.81 for
+    that round and 34.94 | 30.17 | 28.42 for that chunk, 256 rows do not fit
+    VMEM beside ``f_t`` 1,024, and 64 there read 14.48 and 33.07: 128 rows
+    are kept, one tile for every shape; ``gm.ffn_tiles`` has the table.)
     *Forward and backward* at 2304 x 896, 16 held of 64, ``k`` = 8, one
     walk: 4.8 | 6.1 | 10.0 | 16.2 | 30.0 ms on the loop against 4.3 | 5.1 |
     6.6 | 10.0 | 16.4 grouped at ``T`` = 512 ... 8192 (PR 41), and under
     the edge PR 41 drew at 1,024 pairs an expert 5.94 | 5.80 | 5.90 | 5.74 |
     6.84 against 4.86 | 4.87 | 5.00 | 5.22 | 6.07 at ``T`` = 64 ... 1024 (8
     to 128 pairs an expert; PR 44): no row count measured has the loop
-    ahead, so the rule has no lower edge.  Forward alone at 6144 x 2048 the
-    two are within a tenth of each other up to ``T`` = 4096, and an expert's
-    weight no longer fits the kernels' VMEM (three of 25 MB, double-buffered,
-    against its 128 MiB): those experts keep the loop until the kernels cut
-    ``[K, N]``.
+    ahead, so the rule has no lower edge.  At 6144 x 2048 the THREE-call
+    form and the loop are within a tenth of each other up to ``T`` = 4096
+    (PR 44), which is why the cure at that width is the one fused call.
 
     Reverse mode follows the path taken (a ``custom_vjp``: a loop whose trip
     count is read from data has no transpose of its own): the backward's
@@ -650,10 +728,12 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     leaf's gradient is zero outside that layer."""
     T, k = idx.shape
     E, H, F = w_gate.shape[-3:]
-    if held_expert_path(T, k, E, H, F) == "grouped":
-        if layer is not None:           # an array: a static layer would
-            layer = jnp.asarray(layer, jnp.int32)   # make a walk a layer
+    if layer is not None:               # an array: a static layer would
+        layer = jnp.asarray(layer, jnp.int32)       # make a walk a layer
+    path = held_expert_path(T, k, E, H, F, x.dtype.itemsize)
+    B = grouped_row_budget(T, k, E, routed)
+    if path == "grouped":
         return _grouped(x, weights, idx, w_gate, w_up, w_down, layer,
-                        int(first), grouped_row_budget(T, k, E, routed))
+                        int(first), B)
     return _held(x, weights, idx, w_gate, w_up, w_down, layer, int(first),
-                 int(block_rows))
+                 int(block_rows), B if path == "cut" else None)

@@ -66,6 +66,28 @@ def _equations(jaxpr):
             yield from _equations(sub)
 
 
+def all_eqns(jaxpr):
+    """Every equation of ``jaxpr``, bodies of loops, calls and checkpoints
+    included, in program order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn.params):
+            yield from all_eqns(sub)
+
+
+def eqn_names(jaxpr) -> set:
+    """What ``jaxpr`` holds, by name: each equation's primitive, a jitted
+    call by the function's own name."""
+    return {eqn.params["name"] if eqn.primitive.name == "jit"
+            else eqn.primitive.name for eqn in all_eqns(jaxpr)}
+
+
+def pallas_grids(jaxpr) -> list:
+    """The grid of every ``pallas_call`` in ``jaxpr``, in program order."""
+    return [tuple(eqn.params["grid_mapping"].grid) for eqn in all_eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
 def program_digest(jaxpr) -> str:
     """A traced program as the MULTISET of its equations, hashed: equal for
     two traces of one program, whatever names the tracer gave the values
@@ -495,3 +517,36 @@ def chunk_kernel_beside_the_walk(monkeypatch, model, variables, prompt,
     tokens = [[int(np.argmax(row[0])) for row in rows[-n:]]
               for rows in (walk, kernel)]
     return worst, tokens, walk_plans, list(plans)
+
+
+# ---- experts past the grouped kernels' whole-weight limit (ISSUE 55)
+
+def cut_tiny_experts(monkeypatch, hidden: int = 32, ffn: int = 16):
+    """The limits of ``ops.moe_ops`` and the grouped kernels scaled down to
+    float32 experts of ``hidden x ffn``: past the whole-weight limit
+    (``held_expert_path`` says ``"cut"``), an F tile of 4 columns, so a
+    visit walks ``ffn / 4``, row tiles of 8 and a row budget in tiles of
+    16, so that a round's pairs fit one trip and a chunk's do not, as the
+    cells'.  The walk is a jitted function of shapes alone: the caller
+    empties JAX's caches on the way in and out."""
+    from hetu_tpu.ops import moe_ops
+    from hetu_tpu.ops.pallas_kernels import grouped_matmul
+
+    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", hidden * ffn // 3)
+    monkeypatch.setattr(grouped_matmul, "_LANES", 4)
+    monkeypatch.setattr(grouped_matmul, "CUT_TILE_ROWS", 8)
+    monkeypatch.setattr(grouped_matmul, "TILE_ROWS", 16)
+    for budget in ("_FFN_WEIGHT_BYTES", "_CUT_WEIGHT_BYTES"):
+        monkeypatch.setattr(grouped_matmul, budget, 3 * hidden * 4 * 4 * 2)
+    assert moe_ops.held_expert_path(1, 2, 4, hidden, ffn) == "cut"
+    assert grouped_matmul.ffn_tiles(hidden, ffn, 4) == (8, 4)
+
+
+def loop_evaluates(monkeypatch):
+    """The cut path's evaluation put back on the loop's forward, which since
+    ISSUE 55 only reverse mode reaches."""
+    from hetu_tpu.ops import moe_ops
+
+    monkeypatch.setattr(
+        moe_ops, "_held", lambda x, w, idx, wg, wu, wd, layer, first, R, B:
+        moe_ops._held_forward(x, w, idx, wg, wu, wd, layer, first, R)[0])

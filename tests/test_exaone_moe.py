@@ -52,15 +52,18 @@ def tiny(**kw) -> ExaoneMoeConfig:
 @pytest.fixture(autouse=True)
 def experts_over_the_grouped_limit(monkeypatch):
     """The published experts, 6144 x 2048, are three times over what the
-    grouped kernels keep whole in VMEM, so the served cell walks them with
-    the LOOP (``ops.moe_ops.held_expert_path``).  The tiny ones here are
-    held to that walk by a limit scaled down with them."""
-    from hetu_tpu.ops import moe_ops
+    grouped kernels keep whole in VMEM, so the served cell EVALUATES them by
+    the fused call cut along F and reverse mode walks them with the loop
+    (``ops.moe_ops.held_expert_path``: ``"cut"``).  The tiny ones here are
+    held to that path by limits scaled down with them
+    (``paged_programs.cut_tiny_experts``)."""
+    from paged_programs import cut_tiny_experts
 
-    monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 32 * 16 // 3)
     c = tiny()
-    assert moe_ops.held_expert_path(
-        1, c.moe_topk, c.held[1], c.hidden_size, c.expert_ffn_size) == "loop"
+    cut_tiny_experts(monkeypatch, c.hidden_size, c.expert_ffn_size)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def dims_of(c: ExaoneMoeConfig) -> dict:
@@ -545,3 +548,54 @@ def test_assumed_correction_bias_steers_the_choice_and_never_the_weight():
     chosen = np.take_along_axis(np.asarray(scores), np.asarray(idx), -1)
     np.testing.assert_allclose(
         w, 2.5 * chosen / chosen.sum(-1, keepdims=True), rtol=1e-5)
+
+
+# ------------- the cut path: evaluated by the fused call, trained by the loop
+
+def test_rounds_and_chunks_emit_the_tokens_the_loop_emitted(monkeypatch):
+    """Requests in flight together through the engine, chunks and decode
+    rounds: the fused call cut along F (four F tiles a visit here) emits
+    the tokens the loop's forward emits, and counts every held pair as a
+    grouped call's (``moe_grouped`` beside ``moe_held``)."""
+    from hetu_tpu.ops import moe_ops
+    from paged_programs import loop_evaluates
+
+    model, v = make()
+    prompts = [ids_of(n, seed=n) for n in (5, 37, 70)]
+    engine, reqs = serve(model, v, prompts, 12)
+    assert model.step_stats == MOE_STATS + ("moe_grouped",)
+    assert engine.metrics.count("moe_grouped") \
+        == engine.metrics.count("moe_held") > 0
+    c = model.c
+    routed = c.n_routed_experts + getattr(c, "zero_expert_num", 0)
+    # a round's pairs fit one trip; a chunk's take the trips form
+    for t, whole in ((4, True), (8, False)):
+        assert moe_ops.held_expert_path(
+            t, c.moe_topk, c.held[1], c.hidden_size,
+            c.expert_ffn_size) == "cut"
+        assert (moe_ops.grouped_row_budget(t, c.moe_topk, c.held[1], routed)
+                >= t * c.moe_topk) == whole
+    loop_evaluates(monkeypatch)
+    jax.clear_caches()
+    _, loop_reqs = serve(model, v, prompts, 12)
+    assert [r.tokens for r in reqs] == [r.tokens for r in loop_reqs]
+
+
+def test_a_training_step_past_the_limit_still_takes_the_loop():
+    """The dense forward EVALUATED holds the grouped walk; differentiated,
+    the loop's two ``while`` walks and no grouped call."""
+    from paged_programs import eqn_names
+
+    model, v = make()
+    ids = jnp.asarray(ids_of((2, 24), seed=2))
+
+    def loss(p):
+        return model.apply({"params": p, "state": {}}, ids,
+                           train=True)[0].sum()
+
+    evaluated = eqn_names(jax.make_jaxpr(loss)(v["params"]).jaxpr)
+    assert "_grouped_forward" in evaluated
+    trained = eqn_names(jax.make_jaxpr(jax.grad(loss))(v["params"]).jaxpr)
+    assert "_grouped_forward" not in trained and "while" in trained
+    grads = jax.grad(loss)(v["params"])
+    assert float(jnp.abs(grads["layers"]["moe"]["gate"]).max()) > 0

@@ -3,9 +3,10 @@ sort once, grouped matmuls over each expert's contiguous rows, combine once)
 against the loop path and against the dense composition, values and
 gradients, at routings chosen to sit on and off a row tile's boundary and to
 take more than one trip; a serving call's shapes, where one trip holds every
-pair and a visit is a whole expert; and the static rule that sends a shape
-down one path or the other.  CPU: the Pallas kernels run in interpret
-mode."""
+pair and a visit is a whole expert; the fused call CUT ALONG F, which
+evaluates the experts too wide to keep whole (the loop is their reverse
+mode's); and the static rule that sends a shape down one path or the other.
+CPU: the Pallas kernels run in interpret mode."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +15,7 @@ import pytest
 
 from hetu_tpu.ops import moe_ops
 from hetu_tpu.ops.pallas_kernels import grouped_matmul
-from paged_programs import _sub_jaxprs
+from paged_programs import _sub_jaxprs, all_eqns, pallas_grids
 
 T, K, ROUTED, FIRST, E, H, F = 32, 2, 8, 2, 3, 16, 8
 TILE, BUDGET = 8, 24
@@ -97,10 +98,13 @@ def _value_and_grads(fn, idx, operands):
 
 def _walk(monkeypatch, path, layer, first=FIRST, routed=ROUTED):
     """``held_expert_ffn`` held to ``path`` by the rule's own limit: these
-    experts fit it, and none fits a limit of nothing."""
+    experts fit it, and none fits a limit of nothing.  ``"loop"``: the path
+    of experts past the limit (``"cut"``) as reverse mode runs it, which is
+    how every caller here runs it."""
     monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
                         4 << 20 if path == "grouped" else 0)
-    assert moe_ops.held_expert_path(T, K, E, H, F) == path
+    assert moe_ops.held_expert_path(T, K, E, H, F) == {
+        "loop": "cut"}.get(path, path)
     return lambda x, w, idx, wg, wu, wd: moe_ops.held_expert_ffn(
         x, w, idx, wg, wu, wd, first=first, block_rows=TILE, layer=layer,
         routed=routed)
@@ -196,9 +200,18 @@ def test_a_serving_call_on_the_grouped_path_equals_the_loop_and_the_dense_compos
     assert 0 <= budget - t * S_K < grouped_matmul.TILE_ROWS
 
     def walk(path):
+        # "loop": the loop's forward itself, which since ISSUE 55 only
+        # reverse mode reaches (the cases without gradients would otherwise
+        # evaluate by the cut path's fused call)
         monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT",
                             4 << 20 if path == "grouped" else 0)
-        assert moe_ops.held_expert_path(t, S_K, S_E, S_H, S_F) == path
+        assert moe_ops.held_expert_path(t, S_K, S_E, S_H, S_F) == {
+            "loop": "cut"}.get(path, path)
+        if path == "loop" and not grads:
+            return jax.jit(lambda x, w, idx, wg, wu, wd, layer:
+                           moe_ops._held_forward(
+                               x, w, idx, wg, wu, wd,
+                               layer if stacked else None, 0, 8)[0])
 
         def fn(x, w, idx, wg, wu, wd, layer):
             return moe_ops.held_expert_ffn(
@@ -274,6 +287,11 @@ def _walk_jaxprs(jaxpr, name: str):
             yield from _walk_jaxprs(sub, name)
 
 
+def _pallas_grids(jaxpr) -> list:
+    """The grid rank of every ``pallas_call`` in ``jaxpr``."""
+    return [len(grid) for grid in pallas_grids(jaxpr)]
+
+
 def _pallas_calls(jaxpr) -> int:
     return sum((eqn.primitive.name == "pallas_call")
                + sum(map(_pallas_calls, _sub_jaxprs(eqn.params)))
@@ -331,13 +349,17 @@ SHAPES = [
      "grouped"),
     ("lfm2 batch-docs decode", 64, 4, 32, 32, 2048, 1792, "grouped"),
     ("lfm2 batch-docs chunk", 2048, 4, 32, 32, 2048, 1792, "grouped"),
-    # an expert's weight too large to keep whole in VMEM: the loop, at any T
-    ("k-exaone batch-mixed decode", 16, 8, 128, 16, 6144, 2048, "loop"),
-    ("k-exaone batch-mixed chunk", 512, 8, 128, 16, 6144, 2048, "loop"),
-    ("longcat batch-long decode", 16, 12, 768, 16, 6144, 2048, "loop"),
-    ("longcat batch-long chunk", 512, 12, 768, 16, 6144, 2048, "loop"),
+    # an expert's weight too large to keep whole in VMEM: evaluated by the
+    # fused call cut along F, at any T
+    ("k-exaone batch-mixed decode", 16, 8, 128, 16, 6144, 2048, "cut"),
+    ("k-exaone batch-mixed chunk", 512, 8, 128, 16, 6144, 2048, "cut"),
+    ("longcat batch-long decode", 16, 12, 768, 16, 6144, 2048, "cut"),
+    ("longcat batch-long chunk", 512, 12, 768, 16, 6144, 2048, "cut"),
     ("a 6144 x 2048 expert at a step's tokens", 16384, 8, 64, 16, 6144, 2048,
-     "loop"),
+     "cut"),
+    # too large whole, and no whole-lane tile divides its F: the loop
+    # evaluates too (no published width)
+    ("a 6144 x 2000 expert", 16, 8, 128, 16, 6144, 2000, "loop"),
 ]
 # the rows a trip of the two training cells' walks holds, which this rule
 # must keep
@@ -351,7 +373,8 @@ def test_the_rule_sends_experts_that_fit_the_kernels_to_the_grouped_path(
     """Static shapes alone decide, at any row count, and the program says
     which it was: the grouped path's is Pallas calls (three a trip and a
     scatter-add where a chip holds a share of the experts, ONE where a trip
-    holds every pair), the loop's holds no Pallas call."""
+    holds every pair); the cut path's evaluation is ONE call a trip in
+    either form, over ``F / f_t`` steps a visit."""
     assert moe_ops.held_expert_path(t, k, e, h, f) == path
     bf16 = jnp.bfloat16
     args = [jax.ShapeDtypeStruct(s, d) for s, d in (
@@ -366,7 +389,18 @@ def test_the_rule_sends_experts_that_fit_the_kernels_to_the_grouped_path(
     assert budget <= moe_ops.GROUPED_ROW_BUDGET + tile
     whole = budget >= t * k
     assert _pallas_calls(closed.jaxpr) == (
-        0 if path == "loop" else 1 if whole else 3)
+        0 if path == "loop" else 1 if path == "cut" or whole else 3)
+    if path == "cut":
+        rows, f_t = grouped_matmul.ffn_tiles(h, f)
+        assert (rows, f_t) == (grouped_matmul.CUT_TILE_ROWS, 1024) == (128, 1024)
+        assert _pallas_grids(closed.jaxpr) == [2]     # (visits, F tiles)
+        # K-EXAONE's and LongCat's rounds take the one-trip form, their
+        # chunks the trips form (``routed`` is always given)
+        assert whole == ("decode" in name)
+    elif path == "loop":
+        assert grouped_matmul.ffn_tiles(h, f) is None
+    else:
+        assert grouped_matmul.ffn_tiles(h, f) == (tile, f)
     if name in TRAINED:
         assert budget == TRAINED[name]
     if name.startswith("lfm2"):
@@ -375,3 +409,148 @@ def test_the_rule_sends_experts_that_fit_the_kernels_to_the_grouped_path(
     elif path == "grouped":
         assert t * k * e // routed <= budget * -(
             -t * k * e // routed // budget) < t * k
+
+
+# ---- the fused call cut along F (ISSUE 55): experts too wide to keep whole
+
+@pytest.fixture
+def narrow_vmem(monkeypatch):
+    """A weight budget that holds an F tile of 8 columns of the experts
+    below and no more, tiles of 8 rows and of 4 lanes: ``F = 4 f_t`` at
+    sizes the interpreter walks in a second."""
+    monkeypatch.setattr(grouped_matmul, "CUT_TILE_ROWS", 8)
+    monkeypatch.setattr(grouped_matmul, "_LANES", 4)
+    for budget in ("_FFN_WEIGHT_BYTES", "_CUT_WEIGHT_BYTES"):
+        monkeypatch.setattr(grouped_matmul, budget, 3 * C_H * 8 * 4 * 2)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+C_H, C_F, C_G = 16, 32, 5
+
+
+def _cut_reference(rows, wg, wu, wd, scale, starts, ends):
+    """Every row through the group that owns it, in float32; rows no group
+    owns are zero."""
+    out = np.zeros((rows.shape[0], wg.shape[-2]), np.float32)
+    for g, (s, e) in enumerate(zip(starts, ends)):
+        x = np.asarray(rows, np.float32)[s:e]
+        gate, up = x @ np.asarray(wg[g]), x @ np.asarray(wu[g])
+        a = gate / (1.0 + np.exp(-gate)) * up
+        out[s:e] = (a @ np.asarray(wd[g])) * np.asarray(scale)[s:e, None]
+    return out
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["one layer", "stacked"])
+def test_the_cut_call_equals_a_plain_reference(stacked, narrow_vmem):
+    """``gmm_ffn`` over experts whose three weights pass the budget: four F
+    tiles a visit (``F = 4 f_t``), a row tile that three groups share, a
+    group without rows (never visited, its weights never read) and two
+    tiles past the last group's rows, which stay unwritten; the leaves read
+    in place from a stacked ``[L, G, ...]`` operand at a traced layer."""
+    assert grouped_matmul.ffn_tiles(C_H, C_F, 4) == (8, 8)
+    rng = np.random.default_rng(5)
+    M = 40
+    # rows 0-2 | 3-6 | (none) | 7-19 | 20-22: tile 0 holds three groups,
+    # group 3 spans three tiles, tiles 3 and 4 hold no group's row
+    starts, ends = [0, 3, 7, 7, 20], [3, 7, 7, 20, 23]
+    rows = jnp.asarray(rng.standard_normal((M, C_H)), jnp.float32)
+    scale = jnp.asarray(rng.uniform(0.1, 1.0, (M,)), jnp.float32)
+    lead = (3,) if stacked else ()
+    wg, wu, wd = (jnp.asarray(rng.standard_normal(lead + shape) * 0.3,
+                              jnp.float32)
+                  for shape in ((C_G, C_H, C_F), (C_G, C_H, C_F),
+                                (C_G, C_F, C_H)))
+    # the group without rows holds what would poison any row that read it
+    poison = (slice(None),) * len(lead) + (2,)
+    wg = wg.at[poison].set(jnp.nan)
+
+    def call(rows, wg, wu, wd, scale, layer):
+        visits = grouped_matmul.group_visits(
+            jnp.asarray(starts), jnp.asarray(ends), M,
+            grouped_matmul.CUT_TILE_ROWS)
+        return grouped_matmul.gmm_ffn(rows, wg, wu, wd, scale, visits,
+                                      layer=layer if stacked else None)
+
+    closed = jax.make_jaxpr(call)(rows, wg, wu, wd, scale, jnp.int32(1))
+    assert _pallas_grids(closed.jaxpr) == [2]
+    got = np.asarray(jax.jit(call)(rows, wg, wu, wd, scale, jnp.int32(1)))
+    one = (lambda w: w[1]) if stacked else (lambda w: w)
+    want = _cut_reference(rows, one(wg), one(wu), one(wd), scale, starts,
+                          ends)
+    written = np.arange(M) < 24                 # the three visited tiles
+    assert np.abs(got[written] - want[written]).max() <= 2e-5 * max(
+        1.0, np.abs(want).max())
+    assert not want[23:24].any() and not got[23:24].any()   # owned by none
+
+
+# experts past the rule's real limit: 2048 x 2560 = 5 Mi, float32, of which
+# an F tile of 1,280 columns fits the kernels' budget: F = 2 f_t.  (name,
+# tokens, choices, the router's width, held from, held)
+WIDE_H, WIDE_F = 2048, 2560
+WIDE = [("a round: one trip", 16, 2, 8, 2, 2),
+        ("a chunk: the trips form", 512, 2, 8, 2, 2)]
+
+
+@pytest.mark.parametrize("name,t,k,routed,first,e", WIDE,
+                         ids=[w[0] for w in WIDE])
+def test_experts_past_the_limit_are_evaluated_by_the_cut_call_as_the_loop_computes(
+        name, t, k, routed, first, e):
+    """``held_expert_ffn`` over experts of ``H * F > 4 Mi`` with nothing
+    patched: evaluated, the fused call cut along F in either forward form,
+    equal to the loop path's output and counts."""
+    assert WIDE_H * WIDE_F > moe_ops.GROUPED_MAX_WEIGHT
+    assert moe_ops.held_expert_path(t, k, e, WIDE_H, WIDE_F) == "cut"
+    assert grouped_matmul.ffn_tiles(WIDE_H, WIDE_F, 4) == (128, 1280)
+    budget = moe_ops.grouped_row_budget(t, k, e, routed)
+    assert (budget >= t * k) == name.startswith("a round")
+    rng = np.random.default_rng(13)
+    f32 = jnp.float32
+    x = jnp.asarray(rng.standard_normal((t, WIDE_H)), f32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (t, k)), f32)
+    idx = jnp.asarray(np.stack([rng.permutation(routed)[:k]
+                                for _ in range(t)]), jnp.int32)
+    wg, wu, wd = (jnp.asarray(rng.standard_normal(shape) * 0.02, f32)
+                  for shape in ((e, WIDE_H, WIDE_F), (e, WIDE_H, WIDE_F),
+                                (e, WIDE_F, WIDE_H)))
+    walk = jax.jit(lambda *a: moe_ops.held_expert_ffn(
+        *a, first=first, routed=routed))
+    closed = jax.make_jaxpr(walk)(x, w, idx, wg, wu, wd)
+    assert _pallas_grids(closed.jaxpr) == [2]
+    out, counts = walk(x, w, idx, wg, wu, wd)
+    (want, want_counts), _ = jax.jit(
+        lambda *a: moe_ops._held_forward(*a, None, first, 128))(
+            x, w, idx, wg, wu, wd)
+    assert counts.tolist() == want_counts.tolist()
+    held = int(counts.sum())
+    assert 0 < held < t * k
+    out, want = np.asarray(out), np.asarray(want)
+    assert np.abs(out - want).max() <= 2e-5 * max(1.0, np.abs(want).max())
+
+
+def test_reverse_mode_past_the_limit_still_runs_the_loops_rules():
+    """``jax.grad`` of the same call: no Pallas call, the loop's ``while``
+    in both directions (``gmm_down_back`` and ``tgmm`` keep two or three
+    whole weights and are not cut).  K-EXAONE's shapes, traced only."""
+    t, k, e, h, f = 512, 8, 16, 6144, 2048
+    bf16 = jnp.bfloat16
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((t, h), bf16), ((t, k), jnp.float32), ((t, k), jnp.int32),
+        ((e, h, f), bf16), ((e, h, f), bf16), ((e, f, h), bf16))]
+
+    def loss(x, w, idx, wg, wu, wd):
+        out, _ = moe_ops.held_expert_ffn(x, w, idx, wg, wu, wd, first=0,
+                                         routed=128)
+        return out.sum()
+
+    evaluated = jax.make_jaxpr(loss)(*args).jaxpr
+    assert _pallas_calls(evaluated) == 1
+    reverse = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 3, 4, 5)))(
+        *args).jaxpr
+    assert _pallas_calls(reverse) == 0
+    whiles = [eqn for eqn in all_eqns(reverse)
+              if eqn.primitive.name == "while"]
+    assert len(whiles) == 2                     # the walk, twice
+

@@ -7,6 +7,7 @@ topology, which is where Mosaic itself refuses (a one-row DMA out of a tiled
 table, a dynamic sublane index on a packed dtype).
 """
 
+import functools
 import json
 import re
 import sys
@@ -59,6 +60,15 @@ BENCH_GROUPED_SHAPES = {
 BENCH_SERVED_GROUPED_SHAPES = {
     "lfm2-8b-a1b.batch-docs decode": (64, 4, 32, 12, 2048, 1792),
     "lfm2-8b-a1b.batch-docs chunk": (2048, 4, 32, 12, 2048, 1792)}
+# the walk of the two served cells whose experts do not, one fused call a
+# trip, cut along F (ISSUE 55): a round's pairs in one trip, a chunk's in the
+# trips form: (tokens a call, choices, the router's width, held experts,
+# expert layers in the stacked leaves, hidden, expert FFN)
+BENCH_SERVED_CUT_SHAPES = {
+    "k-exaone-236b-a23b.batch-mixed decode": (16, 8, 128, 16, 4, 6144, 2048),
+    "k-exaone-236b-a23b.batch-mixed chunk": (512, 8, 128, 16, 4, 6144, 2048),
+    "longcat-flash-omni.batch-long decode": (16, 12, 768, 16, 4, 6144, 2048),
+    "longcat-flash-omni.batch-long chunk": (512, 12, 768, 16, 4, 6144, 2048)}
 BENCH_PAGED_SHAPES = {
     "gpt2-large.batch": (8, 20, 20, 64, 36, 385, 16, 48),
     "k-exaone-236b-a23b.batch-mixed": (16, 64, 8, 128, 1, 4225, 128, 264)}
@@ -117,11 +127,11 @@ def _held_experts_vjp(routed):
     return fn
 
 
-def _held_experts_forward(x, w, idx, wg, wu, wd, layer):
+def _held_experts_forward(x, w, idx, wg, wu, wd, layer, routed=None):
     from hetu_tpu.ops.moe_ops import held_expert_ffn
 
     return held_expert_ffn(x, w, idx, wg, wu, wd, first=0, layer=layer,
-                           routed=wg.shape[1])
+                           routed=routed or wg.shape[1])
 
 
 def _cases():
@@ -165,6 +175,15 @@ def _cases():
         # a whole expert a visit: gate, up, SwiGLU and down in one call,
         # three whole weights double-buffered in VMEM
         yield (f"held experts grouped forward {cell}", _held_experts_forward,
+               [((t, h), bf16), ((t, k), f32), ((t, k), i32),
+                ((layers, e, h, f), bf16), ((layers, e, h, f), bf16),
+                ((layers, e, f, h), bf16), ((), i32)], 1)
+    for cell, (t, k, routed, e, layers, h, f) in \
+            BENCH_SERVED_CUT_SHAPES.items():
+        # an expert a visit in F tiles of 1,024 columns: three weight tiles
+        # double-buffered and the float32 sum in VMEM
+        yield (f"held experts cut along F forward {cell}",
+               functools.partial(_held_experts_forward, routed=routed),
                [((t, h), bf16), ((t, k), f32), ((t, k), i32),
                 ((layers, e, h, f), bf16), ((layers, e, h, f), bf16),
                 ((layers, e, f, h), bf16), ((), i32)], 1)
@@ -473,7 +492,9 @@ def test_a_train_step_whose_experts_fit_lowers_with_grouped_calls(
     (``ops.moe_ops.held_expert_path``): the walk's Mosaic calls carry
     ``hetu.moe.gmm`` and no block of ``expert_block_rows`` rows is added into
     the float32 ``[T, H]`` result; under a limit these experts pass the same
-    model holds the loop, a block's scatter-add inside a ``while``."""
+    model's step, which differentiates, holds the loop (past the limit the
+    fused call cut along F EVALUATES a walk and reverse mode keeps the
+    loop's rules): a block's scatter-add inside a ``while``."""
     from hetu_tpu.ops import moe_ops
 
     model, _, bodies = build()
@@ -490,7 +511,7 @@ def test_a_train_step_whose_experts_fit_lowers_with_grouped_calls(
     assert moe_ops.grouped_row_budget(1024, 2, 2, 8) in adds \
         and rows not in adds
     monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", hidden * 128 - 1)
-    assert moe_ops.held_expert_path(1024, 2, 2, hidden, 128) == "loop"
+    assert moe_ops.held_expert_path(1024, 2, 2, hidden, 128) == "cut"
     text = _step_text(model, (4, 256))
     assert text.count("tpu_custom_call") == 3 * bodies
     assert "hetu.moe.gmm" not in text
@@ -564,13 +585,16 @@ def _tiny_longcat():
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
 @pytest.mark.parametrize("build", [_tiny_exaone, _tiny_longcat])
-def test_a_serving_program_of_wide_experts_lowers_with_the_loop(
+def test_a_serving_program_of_wide_experts_lowers_with_the_cut_call(
         build, program, monkeypatch):
     """K-EXAONE's and LongCat's experts (6144 x 2048) are three times what
-    the grouped kernels keep in VMEM, so their decode rounds and prefill
-    chunks walk the loop: a ``while`` whose trip count is read from the
-    counts, and no grouped call.  At the test's widths the limit is scaled
-    down with the experts."""
+    the grouped kernels keep whole in VMEM: their decode rounds and prefill
+    chunks evaluate them by ONE fused Mosaic call a walk, in one lowered
+    ``_grouped_forward`` that every expert layer of the program calls, and
+    no loop walk is left.  At the test's widths the rule's limit is scaled
+    down with the experts; their 128 columns are one lane tile, so what
+    lowers here is the call at ONE F tile, and the cut lowers at the cells'
+    own shapes among ``CASES`` (``held experts cut along F``)."""
     from hetu_tpu.ops import moe_ops
     from hetu_tpu.serve import PagedServeEngine
 
@@ -580,10 +604,14 @@ def test_a_serving_program_of_wide_experts_lowers_with_the_loop(
         jax.random.PRNGKey(0)), num_slots=4, max_len=160, page_size=4,
         prefill_chunk=8, min_bucket=4)
     text = _program_text(engine, program)
-    assert "hetu.moe.experts" in text and "hetu.moe.gmm" not in text
-    # the walk: a while whose bound is no constant, one a layer's scan body
-    assert re.search(r"stablehlo\.while", text)
-    assert 8 in _row_scatters(text, 128)
+    assert "hetu.moe.experts" in text
+    assert _grouped_calls(text) == 1
+    holders = _grouped_holders(text)
+    layers = model.c.num_layers - getattr(model.c, "first_dense", 0)
+    # LongCat scans its double layers: one call of the walk in the body
+    assert list(holders.values()) == [1 if build is _tiny_longcat
+                                      else layers]
+    assert next(iter(holders)).startswith("_grouped_forward")
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk"])
@@ -616,8 +644,65 @@ def test_a_serving_program_of_lfm2_lowers_one_grouped_call_for_twelve_layers(
     # no loop walk is left: no block of ``expert_block_rows`` rows anywhere
     assert "hetu.moe.experts" in text
     assert "tensor<24x128xf32>" not in text
+    # nor under a limit these experts pass: evaluated, the fused call still
     monkeypatch.setattr(moe_ops, "GROUPED_MAX_WEIGHT", 0)
-    assert "tensor<24x128xf32>" in _program_text(engine, program)
+    jax.clear_caches()
+    text = _program_text(engine, program)
+    assert _grouped_calls(text) == 1 and "tensor<24x128xf32>" not in text
+
+
+# the lowered text (no locations) of the serving programs of the two served
+# families whose experts the kernels keep WHOLE, by digest, taken on the
+# parent of ISSUE 55 (33e299d): the F-cut call is a second kernel beside
+# ``_ffn_kernel``, which these programs keep, and a PR that changes what
+# they lower to replaces a digest and says why
+SERVED_WHOLE_DIGESTS = {
+    ("lfm2", "decode"):
+        "383ee2118cb772af5e7ec4eabc58e7ab0eee0c7c1b7decfca5d955ba47d4c219",
+    ("lfm2", "chunk"):
+        "370fe727f8907dd895fa03d438981420f35abbdcf56293d5e9004c2aa5776545",
+    ("qwen3_next", "decode"):
+        "a326af72ac2175f6fc0f476bfd42c285036897926d55e7b4aa5539eb5fe5914f",
+    ("qwen3_next", "chunk"):
+        "12bbb7c27e01cb2bab21672596e816ba73d6ff1c910df04d709996a338f19ff1"}
+
+
+def _tiny_qwen3_next():
+    from hetu_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextModel
+
+    return Qwen3NextModel(Qwen3NextConfig(
+        vocab_size=96, hidden_size=128, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=32, gdn_key_heads=2, gdn_value_heads=4,
+        gdn_key_dim=16, gdn_value_dim=16, gdn_chunk=8, expert_ffn_size=128,
+        shared_ffn_size=128, n_routed_experts=8, moe_topk=2, held=(0, 8),
+        max_position=256, dtype=bf16, param_dtype=bf16,
+        expert_block_rows=8))
+
+
+@pytest.mark.parametrize("family,program", sorted(SERVED_WHOLE_DIGESTS))
+def test_a_serving_program_of_whole_experts_lowers_to_the_text_it_lowered_to(
+        family, program, monkeypatch):
+    """ISSUE 55 cut ``gmm_ffn`` along F for experts past the kernels' VMEM;
+    LFM2's and Qwen3-Next's programs, whose experts fit, lower to the text
+    of the parent commit.  The kernels in interpret mode, as the step
+    digests are taken."""
+    import hashlib
+
+    from paged_programs import tiny_model, traced
+    from hetu_tpu.serve import PagedServeEngine
+
+    for mod in ("flash_attention", "grouped_matmul"):
+        monkeypatch.setattr(sys.modules[f"hetu_tpu.ops.pallas_kernels.{mod}"],
+                            "auto_interpret", lambda interpret: True)
+    jax.clear_caches()
+    model = tiny_model("lfm2") if family == "lfm2" else _tiny_qwen3_next()
+    engine = PagedServeEngine(model, jax.jit(model.init)(
+        jax.random.PRNGKey(0)), num_slots=4, max_len=160, page_size=4,
+        prefill_chunk=8, min_bucket=4)
+    text = traced(engine, program, batch=4, chunk=8).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=False)
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == SERVED_WHOLE_DIGESTS[family, program]
 
 
 # the attention projection leaves of each served family, by what their leaf

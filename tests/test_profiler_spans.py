@@ -582,7 +582,8 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
         == 4 * (1 + 4 * 6) * 4 * row
     posts = _named(events, "serve.decode.post") \
         + _named(events, "serve.prefill_chunk.post")
-    keys = {"moe_held", "moe_zero", "moe_absent", "moe_hit", "kv_pages_full",
+    keys = {"moe_held", "moe_zero", "moe_absent", "moe_hit", "moe_grouped",
+            "kv_pages_full",
             "kv_pages_window", "kv_pages_if_one_group", "kv_window_released"}
     assert posts and all(set(e[3]) == keys for e in posts)
     assert sum(e[3]["kv_window_released"] for e in posts) \
@@ -591,6 +592,18 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
         ids = e[3]
         assert ids["kv_pages_full"] * 5 == ids["kv_pages_if_one_group"]
         assert ids["kv_pages_window"] <= 4 * 5 * 4   # 4 layers, ring, slots
+        # every held pair a grouped call's (ISSUE 55): an id on the span
+        # that was there, and no span more a scheduler step
+        assert ids["moe_grouped"] == ids["moe_held"]
+    assert eng.metrics.count("moe_grouped") == sum(
+        e[3]["moe_grouped"] for e in posts) > 0
+    for step in _named(events, "serve.step"):
+        assert {e[0] for e in _children(events, step)} <= {
+            "serve.admit", "serve.advance_prefills", "serve.evict",
+            "serve.decode", "serve.prefill_chunk", "paged_attn.plan",
+            "chunk_attn.plan", "serve.recompile",
+            *(f"serve.decode.{s}" for s in SEAMS),
+            *(f"serve.prefill_chunk.{s}" for s in SEAMS)}
     # at 33 + 6 tokens the grouped cache holds well under one group's pages
     last = max(posts, key=lambda e: e[1])[3]
     assert last["kv_pages_full"] + last["kv_pages_window"] \
@@ -605,13 +618,15 @@ def test_a_grouped_caches_ids_reach_the_post_spans(tmp_path):
         assert e[3]["g1_view_bytes"] == 5 * 4 * row
 
 
-@pytest.mark.parametrize("path", ["grouped", "loop"])
+@pytest.mark.parametrize("path", ["grouped", "cut"])
 def test_a_served_expert_walk_says_which_path_computed_its_pairs(
         tmp_path, monkeypatch, path):
     """``moe_grouped`` on the ``post`` span of every decode round and prefill
     chunk of an LFM2 engine, beside the other ``moe_*`` ids: the call's held
-    pairs where the rule (``ops.moe_ops.held_expert_path``) sends its shape
-    down the grouped path, 0 where it says loop."""
+    pairs on either path of the rule (``ops.moe_ops.held_expert_path``),
+    since a served walk is evaluated and both paths evaluate by grouped
+    calls: experts kept whole, or cut along F (ISSUE 55; the loop that
+    counted 0 here is reverse mode's alone)."""
     import jax.numpy as jnp
 
     from hetu_tpu.models.lfm2_moe import Lfm2MoeConfig, Lfm2MoeModel
@@ -642,8 +657,8 @@ def test_a_served_expert_walk_says_which_path_computed_its_pairs(
             <= set(ids)
         # four expert layers of two choices a row, every expert held
         assert ids["moe_held"] > 0 and ids["moe_held"] % (4 * 2) == 0
-        assert ids["moe_grouped"] == (ids["moe_held"] if path == "grouped"
-                                      else 0)
+        assert ids["moe_grouped"] == ids["moe_held"]
+    assert moe_ops.held_expert_path(4, 2, 8, 32, 16) == path
     assert eng.metrics.count("moe_grouped") == sum(
         e[3]["moe_grouped"] for e in rounds + chunks)
 
@@ -839,12 +854,14 @@ def test_train_moe_instant_carries_each_steps_counts(tmp_path):
     assert 0 < events[-1][3]["router_bias_absmax"] <= 0.0031
 
 
-@pytest.mark.parametrize("path", ["grouped", "loop"])
+@pytest.mark.parametrize("path", ["grouped", "cut"])
 def test_train_moe_instant_says_what_the_grouped_path_computed(
         tmp_path, monkeypatch, path):
     """``moe_grouped``: the step's held pairs that the grouped path computed,
     all of them where the experts fit the rule's limit
-    (``ops.moe_ops.held_expert_path``) and none where they pass it."""
+    (``ops.moe_ops.held_expert_path``) and none where they pass it: a
+    training step differentiates, and past the limit reverse mode walks
+    the loop."""
     from hetu_tpu.ops import moe_ops
 
     # experts of 32 x 16: at the limit of what the kernels keep, or over it
