@@ -24,7 +24,10 @@ convolutions with state layers between full attention layers in one group;
 ``models/falcon_h1.py``: a state-space branch beside attention in every
 layer, each layer a cache layer AND a state layer; ``models/qwen3_next.py``:
 Gated DeltaNet state layers of two parts between gated, partly rotated full
-attention layers in one group) states through the
+attention layers in one group; ``models/minicpm_sala.py``: block-sparse
+layers that CHOOSE the positions a query reads by compressed keys, a third
+kind of cached attention layer beside window and full, between
+linear-attention state layers) states through the
 constructor its expert layer, the constant factors it scales its products by
 (``multipliers``) and, by layer, where a layer's attention leaves and cache
 layer lie, whether it is rotated and what window it has; and itself holds
@@ -39,8 +42,9 @@ holds the attention projections a layer an array
 (:meth:`BlockDecoder.serving_params`), which the same index reads.
 
 ``jax.named_scope``s mark the sub-layers in the jitted programs
-(``hetu.attn.window``, ``hetu.attn.full``, ``hetu.ffn.dense``; the expert
-layer's are ``layers/moe.py``'s).
+(``hetu.attn.window``, ``hetu.attn.full``, ``hetu.ffn.dense``; a sparse
+layer's ``hetu.sparse.compress|select|attend``; the expert layer's are
+``layers/moe.py``'s).
 """
 
 from __future__ import annotations
@@ -59,6 +63,50 @@ from hetu_tpu.ops.moe_ops import held_expert_path
 
 # the names ``layer_types`` gives the two kinds of attention layer
 WINDOW, FULL = "sliding_attention", "full_attention"
+
+# what the cache entry points of a model with SPARSE layers count, summed
+# over those layers: the real queries that read their chosen blocks and
+# those that read every position (the layer's dense branch), the blocks the
+# former chose and could have (chosen / visible: how sparse the call was),
+# and on a decode round the pages the attention walked for them beside the
+# pages their sequences hold
+SPARSE_STATS = ("sparse_queries", "dense_queries", "blocks_chosen",
+                "blocks_visible", "sparse_pages_read", "pages_held")
+
+
+@dataclass(frozen=True)
+class ChosenBlocks:
+    """What a SPARSE attention layer chooses by (InfLLM-V2;
+    ``ops.select_blocks``): a compressed key every ``stride`` positions over
+    ``kernel`` of them, blocks of ``block`` positions (a serving cache's
+    page), the ``topk`` best a query a KV head with the first
+    ``init_blocks`` and those of the last ``local`` positions among them;
+    dense instead while the sequence is shorter than ``dense_len`` at the
+    call that computes the token (a prompt's token by the PROMPT's length, a
+    generated one by its own position + 1)."""
+
+    stride: int = 16
+    kernel: int = 32
+    block: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    local: int = 2048
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        if self.kernel % self.stride or self.block % self.stride:
+            raise ValueError(f"window {self.kernel} and block {self.block} "
+                             f"are multiples of the stride {self.stride}")
+        if self.init_blocks + -(-(self.local - 1) // self.block) + 1 \
+                > self.topk:
+            raise ValueError("the forced blocks alone exceed topk")
+
+    @property
+    def how(self) -> dict:
+        """``ops.select_blocks``' keywords."""
+        return {"stride": self.stride, "kernel": self.kernel,
+                "block": self.block, "topk": self.topk,
+                "init_blocks": self.init_blocks, "local": self.local}
 
 
 def draw_leaf(key, lead: tuple, shape: tuple, std, dtype):
@@ -126,6 +174,8 @@ class LayerCall:
     one_query: bool = False
     state: object = None
     last: object = None
+    prompt_len: object = None
+    counts: object = None
 
 
 class GroupedHeads:
@@ -136,7 +186,9 @@ class GroupedHeads:
     the leaves hold ``q_norm`` / ``k_norm`` (a model without them has no such
     norm), K times ``multipliers["key"]`` where the model states one, the
     half-rotation layout over the head's rotated dims, the out-projection.
-    Three things a model may STATE, each absent here: ``rotary_dim``, the
+    A ``g`` leaf [H, heads * D] is an output gate from a projection of its
+    own (the result times ``sigmoid(a W_g)``).  Three things a model may
+    STATE, each absent here: ``rotary_dim``, the
     leading dims of a head that are rotated (None: the whole head; the rest
     pass as they are); ``gated_query``, a ``q`` leaf of twice the width,
     ``[query | gate]`` a head, the attention's result times ``sigmoid(gate)``
@@ -184,6 +236,8 @@ class GroupedHeads:
         if self.gated_query:
             q, g = jnp.split(q, 2, axis=-1)
             gate = (g.reshape(b, s, -1),)
+        elif "g" in p:
+            gate = (ops.linear(a, p["g"][l].astype(dt)),)
         k = ops.linear(a, p["k"][l].astype(dt), trans_w=True).reshape(
             b, s, c.num_kv_heads, c.head_dim)
         v = ops.linear(a, p["v"][l].astype(dt)).reshape(
@@ -217,7 +271,13 @@ class BlockDecoder(GroupedHeads, Module):
     ``num_layers``); ``multipliers`` the constant factors the model scales
     by, each applied where it is named and nowhere when absent: ``embed`` the
     embedded rows, ``key`` attention's K, ``gate`` the feed-forward's gate
-    product inside its activation, ``down`` its result, ``head`` the logits.
+    product inside its activation, ``down`` its result, ``head`` the logits,
+    ``branch`` what either sub-layer adds to the stream.  ``sparse``
+    (:class:`ChosenBlocks`) with ``sparse_layers``: the layers whose cached
+    attention reads the blocks a query chose (their cache group keeps
+    compressed rows, ``serve.kv_cache.KVCacheSpec.comp_stride``, and its
+    pages are the blocks); the entry points of such a model return
+    ``SPARSE_STATS`` as their counts.
     The tables, each by layer index and holding
     the attention layers alone: ``attn_leaf`` the layer's index in the
     stacked attention leaves, ``cache_layer`` its (group, cache layer in the
@@ -237,8 +297,10 @@ class BlockDecoder(GroupedHeads, Module):
 
     def __init__(self, config, moe, *, attn_leaf, cache_layer, rotated,
                  window=None, multipliers=None, rotary_dim=None,
-                 gated_query: bool = False, unit_offset_norms: bool = False):
+                 gated_query: bool = False, unit_offset_norms: bool = False,
+                 sparse: Optional[ChosenBlocks] = None, sparse_layers=()):
         self.c = config
+        self.sparse, self.sparse_layers = sparse, frozenset(sparse_layers)
         self.moe = moe
         self.multipliers = dict(multipliers or {})
         self.rotary_dim, self.gated_query = rotary_dim, bool(gated_query)
@@ -255,7 +317,9 @@ class BlockDecoder(GroupedHeads, Module):
         of a stacked leaf at a static index is written into a buffer of
         its own in every call (``layers/base.py``
         ``Module.serving_params``).  ``p["q"][l]`` reads either form."""
-        attn = held_by_layer(params["layers"]["attn"], "q", "k", "v", "o")
+        attn = held_by_layer(params["layers"]["attn"], "q", "k", "v", "o",
+                             *(("g",) if "g" in params["layers"]["attn"]
+                               else ()))
         return dict(params, layers=dict(params["layers"], attn=attn))
 
     # ---- pieces of a layer ----
@@ -302,6 +366,12 @@ class BlockDecoder(GroupedHeads, Module):
                                    l in self.rotated)
         b, s = k.shape[:2]
         scope = "hetu.attn.window" if window else "hetu.attn.full"
+        if l in self.sparse_layers and not (
+                call.k is None and call.prompt_len is None
+                and s < self.sparse.dense_len):
+            # a sparse layer, unless the dense forward's own rows are a
+            # prompt under the dense length: then causal attention, below
+            return self._out(pa, al, self._chosen(q, k, v, l, call), *gate)
         if call.k is None:
             with jax.named_scope(scope):
                 # heads grouped by the KV head they read: [B, kv, rep, S, D]
@@ -332,14 +402,178 @@ class BlockDecoder(GroupedHeads, Module):
         call.v[g] = call.v[g].write(cl, v)
         return self._out(pa, al, o, *gate)
 
+    # ---- a sparse layer: the blocks a query chose ----
+    def _count(self, call: LayerCall, real, sparse, n, pos, *,
+               walked: bool = False):
+        """Add one sparse layer's ``SPARSE_STATS`` to the call's counts:
+        ``real`` [B, S] the queries that are tokens, ``sparse`` [B, S] those
+        that read their choice, ``n`` [B, S] how many blocks each chose a KV
+        head, ``pos`` their positions; ``walked``: a decode round, whose
+        attention walked the chosen pages and no others."""
+        if call.counts is None:
+            return
+        g, blk = self.c.num_kv_heads, self.sparse.block
+        chose = real & sparse
+        chosen = jnp.sum(jnp.where(chose, n, 0)) * g
+        visible = jnp.sum(jnp.where(chose, pos // blk + 1, 0)) * g
+        call.counts = call.counts + jnp.stack([
+            jnp.sum(chose), jnp.sum(real & ~sparse), chosen, visible,
+            chosen * walked, visible * walked]).astype(jnp.int32)
+
+    def _masked(self, q, comp, pos, k_rows, v_rows, every):
+        """Choose, then walk the rows under the choice's mask: q [B, heads,
+        S, D] at ``pos`` [B, S] over k_rows / v_rows [B, T, kv_heads, D]
+        with the compressed keys ``comp``; ``every`` (bool, broadcast
+        against [B, S, kv_heads, blocks]): the queries that read every
+        block all the same."""
+        sp = self.sparse
+        with jax.named_scope("hetu.sparse.select"):
+            idx, _ = ops.select_blocks(q, comp, pos, scale=self.scale,
+                                       **sp.how)
+            chosen = ops.chosen_mask(idx, k_rows.shape[1] // sp.block) | every
+        with jax.named_scope("hetu.sparse.attend"):
+            return ops.masked_block_attention(
+                q, k_rows, v_rows, pos, chosen, block=sp.block,
+                scale=self.scale)
+
+    def _chosen(self, q, k, v, l: int, call: LayerCall):
+        """The attention of sparse layer ``l`` (``self.sparse``), q [B,
+        heads, S, D], k / v [B, S, kv_heads, D] the call's new rows.  The
+        dense forward compresses and chooses over its own rows.  A cached
+        call keeps the layer's compressed keys beside its K rows
+        (``PagedLayers.read_comp`` / ``write_comp``): it writes the windows
+        its new rows COMPLETE (from the view, or the pool, where the rows
+        before them lie), scores the sequence's compressed keys, and a
+        chunk then walks its view under its queries' masks
+        (``ops.masked_block_attention``) while a decode round walks, a
+        (sequence, KV head), the pages that were chosen and no others
+        (``ops.chosen_pages_attention``).  Sequences under the dense length
+        read every position: a chunk whose prompt is that short runs the
+        call's own attention step (one ``lax.cond`` a layer), a round that
+        mixes both hands the short ones their whole (short) tables."""
+        c, sp = self.c, self.sparse
+        g, d = c.num_kv_heads, c.head_dim
+        b, s = k.shape[:2]
+        if call.k is None:                          # the dense forward
+            pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+            own = jnp.full((b, 1), s) if call.prompt_len is None \
+                else jnp.asarray(call.prompt_len).reshape(-1, 1)
+            sparse = jnp.where(pos < own, own >= sp.dense_len,
+                               pos + 1 >= sp.dense_len)
+            t = -(-s // sp.block) * sp.block
+            pad = ((0, 0), (0, t - s), (0, 0), (0, 0))
+            with jax.named_scope("hetu.sparse.compress"):
+                comp = ops.compress_keys(
+                    jnp.pad(k, ((0, 0), (0, t - s + sp.kernel - sp.stride),
+                                (0, 0), (0, 0))),
+                    stride=sp.stride, kernel=sp.kernel).astype(k.dtype)
+            return self._masked(q, comp, pos, jnp.pad(k, pad),
+                                jnp.pad(v, pad), ~sparse[:, :, None, None])
+        grp, cl = self.cache_layer[l]
+        kc, vc = call.k[grp], call.v[grp]
+        if kc.pool.shape[2] != sp.block:
+            raise ValueError(f"a sparse layer's blocks of {sp.block} are "
+                             f"its cache's pages, not {kc.pool.shape[2]}")
+        n_pg = kc.tables.shape[1]
+        real = jnp.ones((b, s), bool)
+        if call.state is not None and call.one_query:
+            real = call.state.real[:, None]
+        if call.one_query:
+            at = call.at
+            short = n_pg * sp.block < sp.dense_len  # every sequence is short
+            if short:
+                with jax.named_scope("hetu.attn.full"):
+                    o, kc, vc = ops.decode_layer_attention(
+                        q, k, v, kc, vc, cl, at, scale=self.scale)
+                sparse = jnp.zeros((b,), bool)
+                n = jnp.zeros((b,), jnp.int32)
+            else:
+                kc, vc = kc.write(cl, k), vc.write(cl, v)
+            with jax.named_scope("hetu.sparse.compress"):
+                # the one window the round's row may complete: its rows from
+                # the pool where they lie, the new one among them
+                rows = at[:, None] - sp.kernel + 1 + jnp.arange(sp.kernel)
+                pages = jnp.take_along_axis(
+                    kc.tables, jnp.clip(rows // sp.block, 0, n_pg - 1), 1)
+                new = ops.compress_keys(
+                    kc.pool[cl, pages, rows % sp.block], stride=sp.kernel,
+                    kernel=sp.kernel)
+                kc = kc.write_comp(
+                    cl, new, (rows[:, :1] // sp.stride),
+                    (rows[:, :1] >= 0) & (rows[:, :1] % sp.stride == 0))
+            if not short:
+                with jax.named_scope("hetu.sparse.select"):
+                    idx, n = ops.select_blocks(
+                        q, kc.read_comp(cl), at[:, None], scale=self.scale,
+                        **sp.how)
+                    n, sparse = n[:, 0], at + 1 >= sp.dense_len
+                with jax.named_scope("hetu.sparse.attend"):
+                    o = ops.chosen_pages_attention(
+                        q, kc, vc, cl, idx[:, 0], n, at, sparse,
+                        dense_blocks=-(-sp.dense_len // sp.block),
+                        scale=self.scale)
+            self._count(call, real, sparse[:, None], n[:, None],
+                        at[:, None], walked=True)
+            call.k[grp], call.v[grp] = kc, vc
+            return o
+        # a chunk: the view with the new rows in it, as a full layer's
+        pos = call.at[:, None] + jnp.arange(s)[None]
+        if call.last is not None:
+            real = jnp.arange(s)[None] <= call.last
+        k_view, v_view = kc.read(cl), vc.read(cl)
+        t = k_view.shape[1]
+        k_view, v_view = ops.cache_update(
+            k_view.reshape(b, t, -1), v_view.reshape(b, t, -1),
+            k.reshape(b, s, -1), v.reshape(b, s, -1), call.at)
+        with jax.named_scope("hetu.sparse.compress"):
+            # the windows that END in the chunk's rows: the first of them
+            # starts up to ``kernel - 1`` rows before the chunk
+            m = sp.kernel // sp.stride
+            first = jnp.maximum((call.at - sp.kernel) // sp.stride + 1, 0)
+            span = sp.stride * first[:, None] + jnp.arange(
+                sp.stride * (s // sp.stride + m))[None]
+            new = ops.compress_keys(
+                jnp.take_along_axis(
+                    k_view, jnp.minimum(span, t - 1)[..., None], 1),
+                stride=sp.stride, kernel=sp.kernel)
+            index = first[:, None] + jnp.arange(new.shape[1])[None]
+            end = call.at + (s - 1 if call.last is None else call.last)
+            kc = kc.write_comp(
+                cl, new, index,
+                sp.stride * index + sp.kernel - 1 <= end[:, None])
+            comp = kc.read_comp(cl)
+        own = jnp.asarray(call.prompt_len).reshape(-1)
+        heads = (g, d)
+
+        def dense(_):
+            with jax.named_scope("hetu.attn.full"):
+                return call.attention(q, k_view, v_view, None)
+
+        def choose(_):
+            return self._masked(
+                q, comp, pos, k_view.reshape((b, t) + heads),
+                v_view.reshape((b, t) + heads),
+                (own < sp.dense_len)[:, None, None, None])
+
+        o = jax.lax.cond(jnp.all(own < sp.dense_len), dense, choose, None)
+        self._count(call, real, jnp.broadcast_to(
+            (own >= sp.dense_len)[:, None], (b, s)),
+            jnp.minimum(sp.topk, pos // sp.block + 1), pos)
+        call.k[grp], call.v[grp] = kc.write(cl, k), vc.write(cl, v)
+        return o
+
     def _layer(self, p, l: int, h, call: LayerCall):
         """Layer ``l`` over ``h`` [B, S, H] in the call ``call``, ``p`` the
         stacked leaves of every layer.  Returns (out, the expert layer's
         counts [4] int32, zeros on a dense layer)."""
-        h = h + self._operator(p, l, self._norm(h, p["attn_norm"][l]), call)
+        by = self.multipliers.get("branch")
+        op = self._operator(p, l, self._norm(h, p["attn_norm"][l]), call)
+        h = h + (op if by is None else op * by)
         u = self._norm(h, p["ffn_norm"][l])
         if l < self.c.first_dense:
-            return h + self._ffn(p["ffn"], l, u), jnp.zeros((4,), jnp.int32)
+            f = self._ffn(p["ffn"], l, u)
+            return h + (f if by is None else f * by), \
+                jnp.zeros((4,), jnp.int32)
         moe, e = p["moe"], l - self.c.first_dense
         # the router's leaves are this layer's; a router with no correction
         # bias has no such leaf
@@ -364,19 +598,24 @@ class BlockDecoder(GroupedHeads, Module):
 
     # ---- dense forward ----
     def hidden_states(self, variables, input_ids, *, train: bool = False,
-                      rng=None):
+                      rng=None, prompt_len=None):
+        """``prompt_len`` [B] (a model with sparse layers): the rows'
+        first ``prompt_len`` tokens are a prompt, the rest were generated a
+        token a call; None: the rows are prompts whole."""
         p = variables["params"]
         c = self.c
         b, s = input_ids.shape
         h = self._embed(p, input_ids)
         pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-        call = LayerCall(*self.rope_at(pos))
+        call = LayerCall(*self.rope_at(pos), prompt_len=prompt_len)
         for l in range(c.num_layers):
             h, _ = self._layer(p["layers"], l, h, call)
         return h
 
-    def apply(self, variables, input_ids, *, train: bool = False, rng=None):
-        h = self.hidden_states(variables, input_ids, train=train, rng=rng)
+    def apply(self, variables, input_ids, *, train: bool = False, rng=None,
+              **how):
+        h = self.hidden_states(variables, input_ids, train=train, rng=rng,
+                               **how)
         return self._head(variables["params"], h), {}
 
     # ---- serving (hetu_tpu/serve): prefill in chunks / decode ----
@@ -389,7 +628,8 @@ class BlockDecoder(GroupedHeads, Module):
     # one states no ``step_stats`` and returns none.
 
     def _cached(self, p, input_ids, k_cache, v_cache, pos, attention,
-                one_query: bool = False, state=None, last=None):
+                one_query: bool = False, state=None, last=None,
+                prompt_len=None):
         """Both cache entry points: every layer in a :class:`LayerCall` over
         the cache layers (a group's pair, or one pair bare where the model's
         cache has one group) from each sequence's first position
@@ -401,7 +641,10 @@ class BlockDecoder(GroupedHeads, Module):
         call = LayerCall(
             *self.rope_at(pos), k=[k_cache] if bare else list(k_cache),
             v=[v_cache] if bare else list(v_cache), at=pos[:, 0],
-            attention=attention, one_query=one_query, state=state, last=last)
+            attention=attention, one_query=one_query, state=state, last=last,
+            prompt_len=prompt_len,
+            counts=jnp.zeros((len(SPARSE_STATS),), jnp.int32)
+            if self.sparse_layers else None)
         stats = jnp.zeros((4,), jnp.int32)
         for l in range(self.c.num_layers):
             h, n = self._layer(p["layers"], l, h, call)
@@ -411,6 +654,8 @@ class BlockDecoder(GroupedHeads, Module):
         out = (h, k_cache, v_cache)
         if self.moe is not None:
             out += (self._counts(stats),)
+        elif call.counts is not None:
+            out += (call.counts,)
         return out if state is None else out + (call.state,)
 
     def _counts(self, stats):
@@ -420,13 +665,14 @@ class BlockDecoder(GroupedHeads, Module):
 
     def prefill_chunk_with_cache(self, variables, input_ids, k_cache,
                                  v_cache, start, *, last_index=None,
-                                 state=None):
+                                 state=None, prompt_len=None):
         """input_ids [B, S_c] at absolute positions ``start..``; positions
         below ``start`` of the caches are written.  Returns (logits [B, V]
         at chunk-relative ``last_index``, new_k, new_v, counts where the
-        model has an expert layer), and with
+        model has an expert layer or sparse layers), and with
         ``state`` (a model with state layers) the state after
-        ``last_index`` behind them."""
+        ``last_index`` behind them.  ``prompt_len`` [B] (a model with sparse
+        layers): the length of the prompt the chunk is a part of."""
         p = variables["params"]
         b, s = input_ids.shape
         starts = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (b,))
@@ -441,7 +687,8 @@ class BlockDecoder(GroupedHeads, Module):
                 scale=self.scale, window=window)
 
         h, *rest = self._cached(p, input_ids, k_cache, v_cache, pos,
-                                attention, state=state, last=last_index)
+                                attention, state=state, last=last_index,
+                                prompt_len=prompt_len)
         idx = s - 1 if last_index is None else last_index
         h = jax.lax.dynamic_index_in_dim(h, idx, axis=1, keepdims=False)
         return (self._head(p, h), *rest)

@@ -63,10 +63,12 @@ from hetu_tpu.ops.moe_ops import (
     balance_assignment, make_slot_routing, gather_dispatch, gather_combine,
 )
 from hetu_tpu.ops.attention import (
-    attention, cache_update, causal_attention, chunk_attention,
-    chunk_kernel_why, chunk_plan, decode_attention, decode_layer_attention,
-    read_cache_layer, remat, ring_update, scan_cached_layers,
-    scan_layers_over_caches, write_cache_layer,
+    attention, cache_update, causal_attention, chosen_mask,
+    chosen_pages_attention, chunk_attention, chunk_kernel_why, chunk_plan,
+    compress_keys, decode_attention, decode_layer_attention,
+    masked_block_attention, read_cache_layer, remat, ring_update,
+    scan_cached_layers, scan_layers_over_caches, select_blocks,
+    write_cache_layer,
 )
 from hetu_tpu.ops.graph_ops import (
     coo_spmm, gcn_norm, gcn_conv,

@@ -263,14 +263,18 @@ def _attend_one_query(q, k_rows, v_rows, g: int, seen, scale):
     return out.reshape(b, nh, 1, dv)
 
 
-def _attend_blocks(q, k_cache, v_cache, pos, scale, block: int):
+def _attend_blocks(q, k_cache, v_cache, pos, scale, block: int, *,
+                   chosen=None, block_size: int = 0):
     """Causal attention of q [B, heads, S, D] at positions ``pos`` [B, S]
     over a time-major view [B, T, kv_heads, D] with ``T > block``: the keys
     are walked ``block`` at a time under a running maximum and sum, heads
     grouped, so neither the [heads, S, T] scores nor a repeated copy of the
     view is ever whole; and the walk ends at the last key any query can see,
     so a chunk's cost follows its history and not the width of the table it
-    was handed.  Returns [B, heads, S, D]."""
+    was handed.  ``chosen`` [B, S, kv_heads, T / block_size] bool: a query
+    reads a key only in a block of ``block_size`` positions it chose
+    (:func:`masked_block_attention`; ``block_size`` divides ``block`` and
+    ``T``).  Returns [B, heads, S, D]."""
     b, nh, s, d = q.shape
     t, n_kv = k_cache.shape[1], k_cache.shape[2]
     q5 = _grouped(q, n_kv)
@@ -287,6 +291,11 @@ def _attend_blocks(q, k_cache, v_cache, pos, scale, block: int):
         key = at + jnp.arange(block)
         seen = ((key[None, None, :] <= pos[:, :, None])
                 & (key >= j * block)[None, None, :])[:, None, None]
+        if chosen is not None:
+            mine = jax.lax.dynamic_slice_in_dim(
+                chosen, at // block_size, block // block_size, 3)
+            seen = seen & jnp.moveaxis(
+                jnp.repeat(mine, block_size, axis=-1), 2, 1)[:, :, None]
         scores = jnp.einsum("bgrsd,btgd->bgrst", q5, k_blk,
                             preferred_element_type=jnp.float32) * scale
         scores = jnp.where(seen, scores, -1e30)
@@ -498,3 +507,270 @@ def decode_layer_attention(q, k_new, v_new, k_cache, v_cache, layer,
     out = decode_attention(q, k_l, v_l, lengths, scale=scale, kv_heads=g)
     return (out, write_cache_layer(k_cache, layer, k_l, lengths, 1),
             write_cache_layer(v_cache, layer, v_l, lengths, 1))
+
+
+# ---------------------------------------------------------------------------
+# Block-sparse attention chosen by compressed keys (InfLLM-V2, arXiv
+# 2509.24663; the ``minicpm4`` layers of ``models/minicpm_sala.py``).  A KV
+# head keeps one COMPRESSED key every ``stride`` positions, the mean of the
+# ``kernel`` K rows from there on; a query scores them, the scores of a KV
+# head's query heads are summed, a BLOCK of ``block`` positions takes the
+# largest score of the windows that overlap it, and the query attends over
+# the ``topk`` best blocks only, the first ``init_blocks`` and those of its
+# last ``local`` positions always among them.  Plain ``jax.numpy``, float32
+# scores.
+# ---------------------------------------------------------------------------
+
+SELECT_WINDOWS = 512   # compressed keys a chunk's queries score at a time
+
+
+def compress_keys(rows, *, stride: int, kernel: int):
+    """The compressed keys of a span of K rows: rows [B, R, ...] (``R`` a
+    multiple of ``stride``, the span's first row at a multiple of ``stride``
+    in its sequence) -> float32 [B, R / stride - kernel / stride + 1, ...],
+    entry ``i`` the mean of rows ``stride * i .. stride * i + kernel - 1``:
+    every window that lies inside the span.  A caller that writes new rows
+    hands over the ``kernel - stride`` rows (or more) before them too, so
+    that the windows the new rows COMPLETE are among the result, whichever
+    page, chunk or round their first rows came in."""
+    b, r = rows.shape[:2]
+    m = kernel // stride
+    if kernel % stride or r % stride or r < kernel:
+        raise ValueError(f"a span of {r} rows holds no whole window of "
+                         f"{kernel} at stride {stride}")
+    parts = rows.astype(jnp.float32).reshape(
+        (b, r // stride, stride) + rows.shape[2:]).sum(2)
+    n = r // stride - m + 1
+    return sum(parts[:, j:j + n] for j in range(m)) / kernel
+
+
+def _block_scores(q, comp, pos, *, stride: int, kernel: int, block: int,
+                  scale: float):
+    """:func:`select_blocks`' ``score`` [B, kv_heads, S, blocks] float32
+    before any block is forced or hidden: the max, over the windows that
+    overlap a block, of the query heads' summed softmax probabilities.
+
+    One query a sequence (a decode round, whose compressed keys are as wide
+    as the round's page bucket) and a short sequence score every window at
+    once.  A chunk's queries WALK the windows ``SELECT_WINDOWS`` at a time,
+    twice (the softmax's maximum and sum, then the probabilities pooled into
+    their blocks), as far as the chunk's last position sees and no further:
+    the scores of 2,048 queries against a 66,624-position table's 4,164
+    windows are a gigabyte in float32, most of it behind the mask (the
+    whole-table form took 58% of the cell's busy time: ``PERF.md`` section
+    6, PR 56)."""
+    b, nh, s, d = q.shape
+    n_w, g = comp.shape[1:3]
+    r, m = block // stride, kernel // stride
+    q5 = _grouped(q, g)
+    comp = comp.astype(q.dtype)
+
+    def scored(keys, w):
+        """Scores [B, g, rep, S, W] of the windows of indices ``w`` [W] and
+        which of them each query sees [B, 1, 1, S, W]."""
+        sc = jnp.einsum("bgrqd,bwgd->bgrqw", q5, keys,
+                        preferred_element_type=jnp.float32) * scale
+        seen = ((w >= 0) & (w < n_w))[None, None] & (
+            (stride * w + kernel)[None, None] <= pos[:, :, None] + 1)
+        return sc, seen[:, None, None]
+
+    def pooled(p):
+        """Summed probabilities [B, g, S, W + m - 1] (from ``m - 1`` windows
+        before a block's first) -> the blocks' maxima [B, g, S, W / r]."""
+        return jax.lax.reduce_window(
+            p, -jnp.inf, jax.lax.max, (1, 1, 1, r + m - 1), (1, 1, 1, r),
+            "VALID")
+
+    wb = SELECT_WINDOWS // r * r
+    if s == 1 or n_w <= wb:
+        sc, seen = scored(comp, jnp.arange(n_w))
+        sc = jnp.where(seen, sc, -1e30)
+        p = jnp.where(seen, jnp.exp(sc - sc.max(-1, keepdims=True)), 0.0)
+        p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+        return pooled(jnp.pad(p.sum(2), ((0, 0),) * 3 + ((m - 1, 0),)))
+    n_wb = -(-n_w // wb)
+    held = jnp.pad(comp, ((0, 0), (m - 1, n_wb * wb - n_w), (0, 0), (0, 0)))
+    # window i is seen from position stride * i + kernel - 1 on
+    trips = jnp.minimum(jnp.max(pos) // (stride * wb) + 1, n_wb)
+    own = (jnp.arange(wb + m - 1) >= m - 1)[None, None, None, None]
+
+    def some(j):
+        return scored(jax.lax.dynamic_slice_in_dim(held, wb * j,
+                                                   wb + m - 1, 1),
+                      wb * j - (m - 1) + jnp.arange(wb + m - 1))
+
+    def sums(j, carry):
+        top, total = carry
+        sc, seen = some(j)
+        mine = seen & own          # the windows before are the last block's
+        sc = jnp.where(mine, sc, -1e30)
+        new = jnp.maximum(top, sc.max(-1))
+        total = total * jnp.exp(top - new) + jnp.where(
+            mine, jnp.exp(sc - new[..., None]), 0.0).sum(-1)
+        return new, total
+
+    rep = nh // g
+    top, total = jax.lax.fori_loop(0, trips, sums, (
+        jnp.full((b, g, rep, s), -1e30, jnp.float32),
+        jnp.zeros((b, g, rep, s), jnp.float32)))
+    total = jnp.maximum(total, 1e-30)
+
+    def pools(j, score):
+        sc, seen = some(j)
+        p = jnp.where(seen, jnp.exp(jnp.minimum(sc - top[..., None], 0.0)),
+                      0.0) / total[..., None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            score, pooled(p.sum(2)), (wb // r) * j, 3)
+
+    score = jax.lax.fori_loop(
+        0, trips, pools, jnp.zeros((b, g, s, n_wb * wb // r), jnp.float32))
+    return score[..., :n_w // r]
+
+
+def select_blocks(q, comp, pos, *, stride: int, kernel: int, block: int,
+                  topk: int, init_blocks: int, local: int, scale=None):
+    """The blocks each query reads.  q [B, heads, S, D]; comp [B, n_w,
+    kv_heads, D], a sequence's compressed keys in order (rows no window has
+    filled yet hold anything: a query sees window ``i`` only when ``stride *
+    i + kernel <= pos + 1``); pos [B, S] the queries' absolute positions.
+    Blocks are ``n_w * stride / block`` of ``block`` positions::
+
+        p_h    = softmax_i(q_h . c_i * scale) over the windows the query sees
+        P      = the sum of p_h over the query heads of a KV head
+        score_j = max of P over the windows that OVERLAP block j: those with
+                 a position in it, ``block/stride * j - (kernel/stride - 1)
+                 .. block/stride * j + block/stride - 1``
+        forced = the first ``init_blocks`` and the blocks that hold
+                 positions ``pos - local + 1 .. pos``
+        chosen = the ``topk`` best among the blocks with a position <= pos,
+                 the forced ones first
+
+    Returns (idx [B, S, kv_heads, min(topk, blocks)] int32, the chosen
+    blocks ASCENDING, behind them ``blocks`` where fewer exist; n [B, S] how
+    many are real: ``min(topk, pos // block + 1)``).  :func:`_block_scores`
+    says how the scores are walked."""
+    b, nh, s, d = q.shape
+    n_w = comp.shape[1]
+    r = block // stride
+    n_blocks = n_w // r
+    if block % stride or kernel % stride or n_w % r:
+        raise ValueError(f"{n_w} windows at stride {stride} are no whole "
+                         f"blocks of {block}")
+    k = min(int(topk), n_blocks)
+    score = _block_scores(q, comp, pos, stride=stride, kernel=kernel,
+                          block=block,
+                          scale=d ** -0.5 if scale is None else scale)
+    first = block * jnp.arange(n_blocks)
+    t = pos[:, None, :, None]
+    forced = (first < init_blocks * block) | (
+        (first + block > t - local + 1) & (first <= t))
+    score = jnp.where(forced, jnp.inf, score)
+    score = jnp.where(first <= t, score, -jnp.inf)
+    top, idx = jax.lax.top_k(score, k)
+    idx = jnp.sort(jnp.where(top > -jnp.inf, idx, n_blocks), -1)
+    return jnp.moveaxis(idx, 1, 2).astype(jnp.int32), \
+        jnp.minimum(k, pos // block + 1).astype(jnp.int32)
+
+
+def chosen_mask(idx, n_blocks: int):
+    """:func:`select_blocks`' idx [B, S, g, k] as a mask [B, S, g, n_blocks]:
+    True at the blocks a query chose."""
+    b, s, g, _ = idx.shape
+    at = jnp.ix_(jnp.arange(b), jnp.arange(s), jnp.arange(g))
+    return jnp.zeros((b, s, g, n_blocks + 1), bool).at[
+        at[0][..., None], at[1][..., None], at[2][..., None], idx].set(
+        True)[..., :n_blocks]
+
+
+def sparse_plan(form: str, q, rows: int, **ids):
+    """Which way a block-sparse layer's attention went is fixed when the
+    program is traced: one instant per attention built says so (``form``
+    ``masked``: a chunk's or the dense forward's queries walk the whole view
+    under their blocks' mask; ``paged``: a decode round's walk the pages
+    they chose, in the pool where they lie; ``gathered``: the same from a
+    view of those pages, off a TPU)."""
+    b, nh, s, d = q.shape
+    trace.instant("sparse.plan", {"form": form, "heads": nh, "d": d,
+                                  "queries": s, "batch": b,
+                                  "rows": int(rows), **ids})
+
+
+def masked_block_attention(q, k_cache, v_cache, pos, chosen, *, block: int,
+                           scale=None):
+    """Causal attention of q [B, heads, S, D] at positions ``pos`` [B, S]
+    over a time-major view [B, T, kv_heads, D], each query over the blocks
+    (of ``block`` positions) it CHOSE: ``chosen`` [B, S, kv_heads, T /
+    block] bool.  The masked form: the walk of a dense chunk
+    (:func:`_attend_blocks` over a long view) with the mask beside the
+    causal one, so it costs what dense attention costs and is exact."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    t = k_cache.shape[1]
+    sparse_plan("masked", q, t, block=block, kv_heads=k_cache.shape[2])
+    if t > KEY_BLOCK and KEY_BLOCK % block == 0 and t % block == 0:
+        return _attend_blocks(q, k_cache, v_cache, pos, scale, KEY_BLOCK,
+                              chosen=chosen, block_size=block)
+    b, nh, s, d = q.shape
+    seen = (jnp.arange(t)[None, None, :] <= pos[:, :, None])[:, None] \
+        & jnp.moveaxis(jnp.repeat(chosen, block, axis=-1), 2, 1)[..., :t]
+    scores = jnp.einsum("bgrsd,btgd->bgrst", _grouped(q, k_cache.shape[2]),
+                        k_cache, preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(seen[:, :, None], scores, jnp.finfo(scores.dtype).min)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
+    out = jnp.einsum("bgrst,btgd->bgrsd", probs, v_cache)
+    return out.reshape(b, nh, s, v_cache.shape[-1])
+
+
+def chosen_pages_attention(q, k_cache, v_cache, layer, idx, n, lengths,
+                           sparse, *, dense_blocks: int, scale=None):
+    """The one-query step of a decode round over cache layer ``layer`` of a
+    PAGED pool pair whose pages are the blocks (``page_size == block``),
+    each (sequence, KV head) over the pages it chose; the new rows are
+    already written.
+
+    q [B, heads, 1, D]; idx [B, kv_heads, k], n [B] :func:`select_blocks`'
+    choice for the one query; lengths [B] the newest token's position;
+    sparse [B] bool: the sequence reads its choice, else (a sequence still
+    under the model's dense length, at most ``dense_blocks`` pages long)
+    every page it holds.  A (sequence, KV head) is one walk of the paged
+    kernel over a SHORT table, ``max(k, dense_blocks)`` wide and no wider
+    than the round's: the chosen pages ascending, the newest token's page
+    last, so the walk's own causal mask (positions ``<= length`` ALONG THE
+    WALK, ``(n - 1) * page + lengths % page``) is the layer's.  The kernel
+    reads whole pages, every KV head's columns, and a walk's queries are
+    its own KV head's (the others' rows of the result are dropped), so a
+    chosen page is read once a KV head that chose it, never a page nobody
+    chose.  Off a TPU the same tables gather a view (:func:`decode_attention`
+    over it).  Returns [B, heads, 1, Dv]."""
+    b, nh, _, d = q.shape
+    g = idx.shape[1]
+    n_pg = k_cache.tables.shape[1]
+    ps = k_cache.pool.shape[2]
+    width = min(max(idx.shape[2], int(dense_blocks)), n_pg)
+    walk = jnp.arange(width)[None, None, :]
+    blocks = jnp.where(
+        sparse[:, None, None],
+        jnp.pad(idx, ((0, 0), (0, 0), (0, width - idx.shape[2])),
+                constant_values=n_pg), walk)
+    tables = jnp.take_along_axis(
+        k_cache.tables[:, None, :], jnp.clip(blocks, 0, n_pg - 1), 2)
+    along = jnp.where(sparse, (n - 1) * ps + lengths % ps, lengths)
+    tables = tables.reshape(b * g, width)
+    along = jnp.repeat(along, g)
+    q_each = jnp.repeat(q, g, axis=0)                 # [B * g, heads, 1, D]
+    kernel = not k_cache.sharded and _default_backend_is_tpu()
+    sparse_plan("paged" if kernel else "gathered", q, width * ps,
+                block=ps, kv_heads=g, pages=width)
+    if kernel:
+        o = k_cache.attend(v_cache, layer, q_each, along, scale=scale,
+                           tables=tables)
+    else:
+        o = decode_attention(
+            q_each, k_cache.read(layer, tables).reshape(b * g, width * ps, -1),
+            v_cache.read(layer, tables).reshape(b * g, width * ps, -1),
+            along, scale=scale, kv_heads=g)
+    # walk (b, j) holds KV head j's query heads' results
+    dv = o.shape[-1]
+    o = jnp.einsum("bjgrd,jg->bgrd", o.reshape(b, g, g, nh // g, dv),
+                   jnp.eye(g, dtype=o.dtype))
+    return o.reshape(b, nh, 1, dv)

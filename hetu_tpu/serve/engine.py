@@ -156,8 +156,8 @@ def _pools(layers):
     cache layers: one :class:`PagedLayers`' pool, or a tuple of them, one a
     group."""
     if isinstance(layers, PagedLayers):
-        return layers.pool
-    return tuple(g.pool for g in layers)
+        return layers.held()
+    return tuple(g.held() for g in layers)
 
 
 class _PrefillCursor:
@@ -309,6 +309,10 @@ class PagedServeEngine:
         # state layers (kv_cache.SlotStates): the cache built itself without
         # a prefix index; both programs carry ``cache.state``
         self._states = self.cache.state is not None
+        # a group whose layers choose what a query reads by the request's
+        # LENGTH (compressed rows, kv_cache.KVCacheSpec.comp_stride): the
+        # chunk programs take the prompt's length as one int more
+        self._chosen = spec.comp_stride is not None
         self._ring_chunk = tuple(g.spec.ring_pages(self.prefill_chunk, ps)
                                  for g in self._more)
         self._ring_decode = tuple(g.spec.ring_pages(1, ps)
@@ -345,10 +349,7 @@ class PagedServeEngine:
     def _pool_args(self):
         """The pools as the two programs take them: one pair, or with
         further groups a tuple of each."""
-        if not self._more:
-            return self.cache.k, self.cache.v
-        return (tuple(g.k for g in self.cache.groups),
-                tuple(g.v for g in self.cache.groups))
+        return self.cache.pool_args()
 
     def _count(self, stats):
         """The counts a step returned beside its tokens, added to the
@@ -471,6 +472,10 @@ class PagedServeEngine:
         mesh = None if self.mesh is None else self.mesh.abstract_mesh
         in_context = contextlib.nullcontext if mesh is None \
             else jax.sharding.use_abstract_mesh
+        # a local, as every other value the program closes over: a program
+        # that closed over the engine would keep it (and its pools) alive
+        # in a cycle until the collector ran
+        chosen = self._chosen
 
         def hetu_serve_prefill_chunk(params, k_pool, v_pool, aux, *state):
             # aux [3*sc + n_table + 2] int32 packs the chunk's host
@@ -478,10 +483,11 @@ class PagedServeEngine:
             # start | last) into one device_put, like the decode step; a
             # further group appends its own (write pages | ring table);
             # over state layers ``state`` is their array and one int more,
-            # the last, names the slot
+            # the last, names the slot; before it, where the model's layers
+            # choose by it, the prompt's length
             rings = sum(ring for _, ring in more)
-            sc = (aux.shape[0] - n_table - 2 - rings - len(state)) \
-                // (3 + len(more))
+            sc = (aux.shape[0] - n_table - 2 - rings - len(state)
+                  - chosen) // (3 + len(more))
             ids = aux[:sc][None]
             # per-token write map: real positions land in their pages,
             # pad positions in scratch 0
@@ -491,23 +497,27 @@ class PagedServeEngine:
             start = aux[3 * sc + n_table]
             last = aux[3 * sc + n_table + 1]
             # the pools: one pair, or with further groups a tuple of each
-            k = PagedLayers(k_pool[0] if more else k_pool, table, wpage,
-                            woff, k_row)
-            v = PagedLayers(v_pool[0] if more else v_pool, table, wpage,
-                            woff, v_row)
+            k = PagedLayers.over(k_pool[0] if more else k_pool, table, wpage,
+                                 woff, k_row)
+            v = PagedLayers.over(v_pool[0] if more else v_pool, table, wpage,
+                                 woff, v_row)
             if more:
                 k, v, at = [k], [v], 3 * sc + n_table + 2
                 for i, ((k_r, v_r), ring) in enumerate(more, 1):
                     wpage = aux[at:at + sc][None]
                     table = aux[at + sc:at + sc + ring][None]
-                    k.append(PagedLayers(k_pool[i], table, wpage, woff, k_r))
-                    v.append(PagedLayers(v_pool[i], table, wpage, woff, v_r))
+                    k.append(PagedLayers.over(k_pool[i], table, wpage, woff,
+                                              k_r))
+                    v.append(PagedLayers.over(v_pool[i], table, wpage, woff,
+                                              v_r))
                     at += sc + ring
                 k, v = tuple(k), tuple(v)
             # the slot's state, read as zeros by the chunk that starts the
             # sequence; the model hands it back last
             held = {"state": SlotStates(state[0], aux[-1:],
                                         (start == 0)[None])} if state else {}
+            if chosen:
+                held["prompt_len"] = aux[-1 - len(state)][None]
             # a model may return a fourth value, its per-call counts
             # (``model.step_stats`` names them); most return none.  The
             # views it gathers do not say that the pools are laid over a
@@ -559,19 +569,19 @@ class PagedServeEngine:
             # a pool laid over a mesh and a CPU backend gather that layer's
             # view): a decode step moves O(B) rows into the pool, never a
             # view of every layer and never the pool
-            k = PagedLayers(k_pool[0] if more else k_pool, tables, wpage,
-                            woff, k_row, sharded)
-            v = PagedLayers(v_pool[0] if more else v_pool, tables, wpage,
-                            woff, v_row, sharded)
+            k = PagedLayers.over(k_pool[0] if more else k_pool, tables,
+                                 wpage, woff, k_row, sharded)
+            v = PagedLayers.over(v_pool[0] if more else v_pool, tables,
+                                 wpage, woff, v_row, sharded)
             if more:
                 k, v, at = [k], [v], n_pg + 4
                 for i, ((k_r, v_r), ring) in enumerate(more, 1):
                     tables = aux[:, at:at + ring]
                     wpage = aux[:, at + ring:at + ring + 1]
-                    k.append(PagedLayers(k_pool[i], tables, wpage, woff, k_r,
-                                         sharded))
-                    v.append(PagedLayers(v_pool[i], tables, wpage, woff, v_r,
-                                         sharded))
+                    k.append(PagedLayers.over(k_pool[i], tables, wpage, woff,
+                                              k_r, sharded))
+                    v.append(PagedLayers.over(v_pool[i], tables, wpage, woff,
+                                              v_r, sharded))
                     at += ring + 1
                 k, v = tuple(k), tuple(v)
             held = {"state": SlotStates(state[0], aux[:, -1])} \
@@ -689,7 +699,7 @@ class PagedServeEngine:
         cur = self._cursors.get(slot)
         if cur is None:
             raise ValueError(f"slot {slot} has no prefill in progress")
-        if self._more or self._states:
+        if self._more or self._states or self._chosen:
             return self._prefill_step_grouped(slot, cur)
         # one span for the whole chunk, tiled by its four seams: prep is
         # the host's work before the program can be called (prefix match
@@ -809,8 +819,8 @@ class PagedServeEngine:
                 pages, wo = self.cache.prepare_write(slot, start, size)
                 wp, wo = self.cache.padded_write_map(pages[0], wo, s)
                 aux = np.zeros(3 * s + n_table + 2 + sum(
-                    s + ring for ring in self._ring_chunk) + self._states,
-                    np.int32)
+                    s + ring for ring in self._ring_chunk) + self._states
+                    + self._chosen, np.int32)
                 aux[:size] = cur.prompt[start:end]
                 aux[s:2 * s] = wp
                 aux[2 * s:3 * s] = wo
@@ -824,6 +834,8 @@ class PagedServeEngine:
                     aux[at:at + size] = wp
                     aux[at + s:at + s + ring] = g.device_table(slot, ring)
                     at += s + ring
+                if self._chosen:
+                    aux[-1 - self._states] = cur.n
                 if self._states:
                     aux[-1] = slot
                 k_pool, v_pool = self._pool_args()
@@ -886,7 +898,7 @@ class PagedServeEngine:
         act = np.nonzero(self.active)[0]
         if len(act) == 0:
             return {}
-        if self._more or self._states:
+        if self._more or self._states or self._chosen:
             return self._decode_grouped(act)
         # a cache of one group takes the round below, kept line for line as
         # it was before groups.  Folded into ONE round with the groups'

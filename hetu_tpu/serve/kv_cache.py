@@ -10,7 +10,12 @@ layer that remembers a sequence in a fixed-size array, not in rows a token:
 a short convolution's last inputs, a state-space recurrence's matrix;
 arrays ``[state layers, slots + 1, *shape]``, or ``[slots + 1, *shape]`` a
 layer for each part a layer keeps, rows that a slot owns whole, never paged, never shared, read as zeros by the
-chunk that starts a sequence: :class:`SlotStates`),
+chunk that starts a sequence: :class:`SlotStates`), and for a group whose
+layers CHOOSE the positions a query reads (block-sparse attention by
+compressed keys: ``KVCacheSpec.comp_stride``) a third pool of
+COMPRESSED-KEY rows, ``page_size // comp_stride`` rows a page a cache layer
+under the same page tables, so that allocation, release and copy-on-write
+are the pages' own,
 per-request page tables a group, refcounted PREFIX SHARING (hash-of-token-prefix
 → shared read-only pages, so identical system prompts across a pool's
 traffic dedup to one physical copy) with copy-on-write on the first
@@ -99,7 +104,17 @@ class KVCacheSpec:
     states ``state_shape``; where it states ``state_parts`` a tuple, in the
     parts' order, of tuples of the layers' arrays ``[slots + 1, *shape]``;
     only the spec a model hands over states them (a group under ``also``
-    has none of its own)."""
+    has none of its own).
+
+    **Compressed rows.**  ``comp_stride``: the group's layers keep, beside K
+    and V rows a token, one COMPRESSED-KEY row every ``comp_stride`` tokens
+    a cache layer (the mean of a window of K rows that starts there; what a
+    block-sparse layer scores its blocks by), of K's row shape and dtype.
+    They live in a third pool of ``page_size // comp_stride`` rows a page
+    under the group's own page tables (compressed row ``i`` of a sequence
+    is row ``i % rows`` of its page ``i // rows``), so a page's allocation,
+    release and copy carry them; ``page_size`` must be a multiple of the
+    stride.  None: no such rows."""
 
     num_layers: int
     num_kv_heads: int
@@ -112,6 +127,7 @@ class KVCacheSpec:
     state_shape: tuple = ()
     state_dtype: object = None
     state_parts: tuple = ()
+    comp_stride: Optional[int] = None
 
     @property
     def parts(self) -> tuple:
@@ -157,10 +173,13 @@ class KVCacheSpec:
 
     @property
     def bytes_per_token(self) -> int:
-        """Bytes one cached token takes over this group's cache layers."""
-        return (self.num_layers * self.num_kv_heads
-                * (self.head_dim + self.v_dim)
-                * np.dtype(self.dtype).itemsize)
+        """Bytes one cached token takes over this group's cache layers:
+        its K and V rows and its share of a compressed row."""
+        row = self.num_layers * self.num_kv_heads \
+            * np.dtype(self.dtype).itemsize
+        comp = row * self.head_dim // self.comp_stride \
+            if self.comp_stride else 0
+        return row * (self.head_dim + self.v_dim) + comp
 
     def ring_pages(self, rows: int, page_size: int) -> Optional[int]:
         """Pages a slot of a window group holds at most while a step writes
@@ -227,7 +246,7 @@ def pow2_ceil(n: int, cap: int) -> int:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=("pool", "tables", "wpage", "woff"),
+         data_fields=("pool", "tables", "wpage", "woff", "comp"),
          meta_fields=("row", "sharded"))
 @dataclass(frozen=True)
 class PagedLayers:
@@ -245,7 +264,11 @@ class PagedLayers:
     whether the engine laid the pool over a mesh (static: a traced pool does
     not say; the one-query step then keeps the view, which the partitioner
     splits by head, and does not hand a split pool to :meth:`attend`'s
-    kernel, which it cannot split).
+    kernel, which it cannot split); ``comp`` ``[L, num_pages, page_size //
+    stride, width]``, the K pool's COMPRESSED rows where the group keeps
+    them (``KVCacheSpec.comp_stride``; None elsewhere, and on the V pool),
+    read and written through the same tables (:meth:`read_comp`,
+    :meth:`write_comp`).
 
     The model carries the value through its layer scan
     (``ops.scan_cached_layers``, ``ops.scan_layers_over_caches``); a layer reads
@@ -260,14 +283,51 @@ class PagedLayers:
     woff: jax.Array
     row: tuple
     sharded: bool = False
+    comp: Optional[jax.Array] = None
 
-    def read(self, layer):
+    @classmethod
+    def over(cls, pool, tables, wpage, woff, row, sharded: bool = False):
+        """The value over ``pool`` as a program is handed it: the pool's
+        array, or the pair (pool, compressed rows) of a group that keeps
+        them (:func:`PagedKVCache.pool_args`)."""
+        pool, comp = pool if isinstance(pool, (tuple, list)) else (pool, None)
+        return cls(pool, tables, wpage, woff, row, sharded, comp)
+
+    def held(self):
+        """What a program hands back of this value: :meth:`over`'s
+        ``pool``."""
+        return self.pool if self.comp is None else (self.pool, self.comp)
+
+    def read(self, layer, tables=None):
         """Cache layer ``layer`` of every sequence, ``[B, n_pg * page_size,
-        *row]``: one gather of that layer's pages by the tables, on the
-        pool's two major axes."""
-        pages = self.pool[layer, self.tables]      # [B, n_pg, ps, width]
+        *row]``: one gather of that layer's pages by the tables (or by
+        ``tables`` ``[B', n]`` in their place: the pages a query chose), on
+        the pool's two major axes."""
+        tables = self.tables if tables is None else tables
+        pages = self.pool[layer, tables]           # [B, n_pg, ps, width]
         b, n_pg, ps = pages.shape[:3]
         return pages.reshape((b, n_pg * ps) + tuple(self.row))
+
+    def read_comp(self, layer):
+        """The compressed rows of cache layer ``layer`` of every sequence,
+        ``[B, n_pg * rows a page, *row]``, by the same tables."""
+        pages = self.comp[layer, self.tables]      # [B, n_pg, rpp, width]
+        b, n_pg, rpp = pages.shape[:3]
+        return pages.reshape((b, n_pg * rpp) + tuple(self.row))
+
+    def write_comp(self, layer, rows, index, valid):
+        """Compressed rows ``rows`` ``[B, n, *row]`` (or flat) of cache
+        layer ``layer``, row ``j`` of sequence ``b`` the sequence's
+        compressed row ``index[b, j]``: into row ``index % rows a page`` of
+        the table's page ``index // rows a page``; a row that is not
+        ``valid`` ``[B, n]`` (a window no real token has completed yet)
+        lands in the scratch page 0."""
+        rpp = self.comp.shape[2]
+        rows = rows.reshape(rows.shape[:2] + self.comp.shape[3:])
+        at = jnp.clip(index // rpp, 0, self.tables.shape[1] - 1)
+        page = jnp.where(valid, jnp.take_along_axis(self.tables, at, 1), 0)
+        return replace(self, comp=self.comp.at[layer, page, index % rpp].set(
+            rows.astype(self.comp.dtype)))
 
     def write(self, layer, rows):
         """The step's new rows ``[B, S, *row]`` (or flat, ``[B, S,
@@ -277,7 +337,7 @@ class PagedLayers:
         return replace(
             self, pool=self.pool.at[layer, self.wpage, self.woff].set(rows))
 
-    def attend(self, values, layer, q, lengths, *, scale=None):
+    def attend(self, values, layer, q, lengths, *, scale=None, tables=None):
         """The one-query step of a decode round over cache layer ``layer``,
         this pool the keys' and ``values`` the other pool of the pair (the
         same tables): q ``[B, heads, 1, D]`` attends over positions ``<=
@@ -285,9 +345,12 @@ class PagedLayers:
         (:meth:`write`).  ONE Pallas kernel walks the tables in the pools
         where they lie, a sequence's live pages and no more
         (``ops.pallas_kernels.paged_attention``): no view is gathered.
-        Returns ``[B, heads, 1, Dv]``."""
+        ``tables`` ``[B, n]`` in the tables' place: the pages each query
+        chose, in the order it walks them, ``lengths`` then counted along
+        THAT walk.  Returns ``[B, heads, 1, Dv]``."""
         return paged_decode_attention(
-            q, self.pool, values.pool, layer, self.tables, lengths,
+            q, self.pool, values.pool, layer,
+            self.tables if tables is None else tables, lengths,
             kv_heads=self.row[0], scale=scale)
 
 
@@ -332,6 +395,16 @@ class SlotStates:
     rows: object
     slots: jax.Array
     fresh: Optional[jax.Array] = None
+
+    @property
+    def real(self):
+        """[B] bool: the step's rows that are sequences; a bucket's padding
+        row names the scratch slot, the arrays' last."""
+        one = self.rows
+        while isinstance(one, tuple):
+            one = one[0]
+        slots = one.shape[0 if isinstance(self.rows, tuple) else 1]
+        return self.slots < slots - 1
 
     def _part(self, part):
         return self.rows if part is None else self.rows[part]
@@ -463,6 +536,15 @@ class _PageGroup:
         lead = (spec.num_layers, self.num_pages, page_size)
         self.k = jnp.zeros(lead + (int(np.prod(k_row)),), spec.dtype)
         self.v = jnp.zeros(lead + (int(np.prod(v_row)),), spec.dtype)
+        self.comp = None         # compressed-key rows under the same pages
+        if spec.comp_stride:
+            if sharding is not None or page_size % spec.comp_stride:
+                raise ValueError(
+                    f"compressed rows every {spec.comp_stride} tokens need "
+                    f"pages of a multiple of that ({page_size}) and no mesh")
+            self.comp = jnp.zeros(
+                lead[:2] + (page_size // spec.comp_stride,
+                            int(np.prod(k_row))), spec.dtype)
         if sharding is not None:
             self.k = jax.device_put(self.k, sharding)
             self.v = jax.device_put(self.v, sharding)
@@ -783,13 +865,26 @@ class PagedKVCache:
     def update(self, k, v, state=None) -> None:
         """Swap in the pool arrays a jitted step returned: one pair, or a
         sequence of each in the groups' order; with state layers, their
-        array too."""
-        if not isinstance(k, (tuple, list)):
+        array too.  A group that keeps compressed rows comes back as
+        :meth:`pool_args` handed it over: K the pair (pool, rows)."""
+        if not isinstance(k, (tuple, list)) or (
+                len(self.groups) == 1 and self.groups[0].comp is not None):
             k, v = (k,), (v,)
         for g, k_g, v_g in zip(self.groups, k, v):
+            if g.comp is not None:
+                k_g, g.comp = k_g
             g.k, g.v = k_g, v_g
         if state is not None:
             self.state = state
+
+    def pool_args(self) -> tuple:
+        """(K, V) as the engine's programs take them: a group's pool, the
+        pair (pool, compressed rows) for the K of a group that keeps them;
+        with several groups a tuple of each, in the groups' order."""
+        k = tuple(g.k if g.comp is None else (g.k, g.comp)
+                  for g in self.groups)
+        v = tuple(g.v for g in self.groups)
+        return (k[0], v[0]) if len(self.groups) == 1 else (k, v)
 
     # ---- page lifecycle (internal) ----
     def _evict_one_entry(self) -> bool:
@@ -831,17 +926,19 @@ class PagedKVCache:
         src = g.tables[slot][idx]
         dst = self._alloc_page(slot, group)
         if g.copy_fn is None:
-            def copy(k, v, src, dst):
-                k_page = jax.lax.dynamic_slice_in_dim(k, src, 1, axis=1)
-                v_page = jax.lax.dynamic_slice_in_dim(v, src, 1, axis=1)
-                k = jax.lax.dynamic_update_slice_in_dim(k, k_page, dst,
-                                                        axis=1)
-                v = jax.lax.dynamic_update_slice_in_dim(v, v_page, dst,
-                                                        axis=1)
-                return k, v
+            def copy(pools, src, dst):
+                # K, V and the page's compressed rows where the group
+                # keeps them
+                return tuple(jax.lax.dynamic_update_slice_in_dim(
+                    pool, jax.lax.dynamic_slice_in_dim(pool, src, 1, axis=1),
+                    dst, axis=1) for pool in pools)
 
-            g.copy_fn = jax.jit(copy, donate_argnums=(0, 1))
-        g.k, g.v = g.copy_fn(g.k, g.v, jnp.int32(src), jnp.int32(dst))
+            g.copy_fn = jax.jit(copy, donate_argnums=(0,))
+        g.k, g.v, *comp = g.copy_fn(
+            (g.k, g.v) if g.comp is None else (g.k, g.v, g.comp),
+            jnp.int32(src), jnp.int32(dst))
+        if comp:
+            g.comp, = comp
         g.tables[slot][idx] = dst
         g.unref_table(src)
         self.cow_copies += 1
@@ -1050,6 +1147,11 @@ class PagedKVCache:
                 f"groups: a window group holds a slot's last pages only, "
                 f"which the one-pair snapshot cannot carry; requeue the "
                 f"requests instead")
+        if self.groups[0].comp is not None:
+            raise GroupedCacheNotPortable(
+                f"cannot {verb} slots of a cache with compressed rows: the "
+                f"one-pair snapshot carries K and V rows alone; requeue "
+                f"the requests instead")
         if self.state is not None:
             raise GroupedCacheNotPortable(
                 f"cannot {verb} slots of a cache with "

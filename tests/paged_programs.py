@@ -200,7 +200,8 @@ def traced(engine, name: str, *, batch: int, chunk: int, params=None):
     """The engine's ``decode`` program at ``batch`` sequences or its
     ``chunk`` program at ``chunk`` tokens, traced (``.jaxpr``, ``.lower``)
     over the engine's own leaves or over ``params``: a cache of several
-    groups or with state layers too."""
+    groups, with state layers or with compressed rows (a chunk program
+    then takes its prompt's length as one int more) too."""
     k_pool, v_pool = engine._pool_args()
     n_pg = engine.cache.pages_per_slot
     state = () if engine.cache.state is None else (engine.cache.state,)
@@ -210,7 +211,7 @@ def traced(engine, name: str, *, batch: int, chunk: int, params=None):
                + sum(r + 1 for r in engine._ring_decode))
     else:
         fn = engine._build_chunk(n_pg)
-        aux = (3 * chunk + n_pg + 2 + len(state)
+        aux = (3 * chunk + n_pg + 2 + len(state) + engine._chosen
                + sum(chunk + r for r in engine._ring_chunk),)
     return fn.trace(engine.params if params is None else params, k_pool,
                     v_pool, jax.ShapeDtypeStruct(aux, np.int32), *state)

@@ -1255,3 +1255,87 @@ def test_a_model_without_state_layers_keeps_its_programs(kind, program, gpt,
         _programs_before_state_layers(engine)[program])(*args)
     assert len(now.jaxpr.invars) == len(before.jaxpr.invars)
     assert str(now) == str(before)
+
+
+# ------------------------------ compressed rows beside the pages (ISSUE 56)
+
+def _comp_spec(**kw):
+    return KVCacheSpec(num_layers=2, num_kv_heads=2, head_dim=8,
+                       dtype=jnp.float32, comp_stride=2, **kw)
+
+
+def test_compressed_rows_are_a_part_of_the_page_group():
+    """A spec with ``comp_stride`` counts a token's share of a compressed
+    row, and the cache holds a third pool of ``page_size // stride`` rows a
+    page under the group's own tables; the programs take K as the pair."""
+    spec = _comp_spec()
+    assert spec.bytes_per_token == 2 * 2 * (8 + 8) * 4 + 2 * 2 * 8 * 4 // 2
+    assert KVCacheSpec(num_layers=2, num_kv_heads=2, head_dim=8,
+                       dtype=jnp.float32).bytes_per_token == 2 * 2 * 16 * 4
+    cache = PagedKVCache(spec, 2, 32, page_size=8,
+                         max_prefix_entries=0)
+    group = cache.groups[0]
+    assert group.comp.shape == (2, cache.num_pages, 4, 16)
+    k, v = cache.pool_args()
+    assert k[0] is group.k and k[1] is group.comp and v is group.v
+    cache.update((k[0] + 1, k[1] + 2), v + 3)
+    assert float(cache.k[0, 0, 0, 0]) == 1 and float(group.comp[0, 0, 0, 0]) == 2
+    with pytest.raises(ValueError, match="multiple"):
+        PagedKVCache(spec, 2, 32, page_size=7, max_prefix_entries=0)
+
+
+@pytest.mark.parametrize("index,valid", [([0, 3, 4, 9], [1, 1, 1, 1]),
+                                         ([2, 5, 7, 11], [1, 0, 1, 0])])
+def test_a_compressed_row_lands_in_its_page_by_the_tables(index, valid):
+    """Row ``i`` of a sequence is row ``i % 4`` of its ``i // 4``-th page;
+    one no real token completed lands in the scratch page; what is read back
+    by the tables is the sequence's rows in order."""
+    tables = jnp.asarray([[5, 2, 7], [3, 6, 1]], jnp.int32)
+    z = jnp.zeros((2, 1), jnp.int32)
+    layers = PagedLayers(jnp.zeros((2, 8, 8, 16)), tables, z, z, (2, 8),
+                         comp=jnp.zeros((2, 8, 4, 16)))
+    rows = jnp.arange(2 * 4 * 16, dtype=jnp.float32).reshape(2, 4, 16) + 1
+    idx = jnp.asarray([index, index], jnp.int32)
+    ok = jnp.asarray([valid, valid], bool)
+    out = layers.write_comp(1, rows, idx, ok)
+    got = np.asarray(out.read_comp(1))                   # [2, 12, 2, 8]
+    assert got.shape == (2, 12, 2, 8)
+    for b in range(2):
+        for j, (i, real) in enumerate(zip(index, valid)):
+            want = np.asarray(rows[b, j]).reshape(2, 8) if real else 0.0
+            np.testing.assert_array_equal(got[b, i], want)
+    assert not np.asarray(out.comp[0]).any()             # layer 0 untouched
+    assert np.asarray(out.comp[1, 0]).any() == (not all(valid))
+    assert out.held()[1] is out.comp and layers.pool is out.pool
+
+
+def test_copy_on_write_carries_a_pages_compressed_rows():
+    cache = PagedKVCache(_comp_spec(), 2, 32, page_size=8)
+    g = cache.groups[0]
+    a = cache.alloc()
+    pages, _ = cache.prepare_write(a, 0, 8)
+    page = int(pages[0][0])
+    g.k = g.k.at[:, page].set(1.0)
+    g.comp = g.comp.at[:, page].set(2.0)
+    g.ref_index[page] += 1              # an index entry shares the page
+    new = cache._cow(a, 0)
+    assert new != page and cache.cow_copies == 1
+    assert float(g.k[1, new, 3, 0]) == 1.0
+    assert float(g.comp[1, new, 3, 0]) == 2.0
+
+
+def test_slots_with_compressed_rows_are_not_exported():
+    from hetu_tpu.serve.kv_cache import GroupedCacheNotPortable
+
+    cache = PagedKVCache(_comp_spec(), 2, 32, page_size=8,
+                         max_prefix_entries=0)
+    with pytest.raises(GroupedCacheNotPortable, match="compressed"):
+        cache.export_slots([cache.alloc()])
+
+
+def test_padding_rows_of_a_round_are_not_sequences():
+    rows = ((jnp.zeros((4, 2)), jnp.zeros((4, 2))),)
+    st = SlotStates(rows, jnp.asarray([0, 2, 3, 3], jnp.int32))
+    assert np.asarray(st.real).tolist() == [True, True, False, False]
+    one = SlotStates(jnp.zeros((2, 4, 2)), jnp.asarray([3, 1], jnp.int32))
+    assert np.asarray(one.real).tolist() == [False, True]

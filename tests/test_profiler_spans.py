@@ -800,6 +800,81 @@ def test_a_delta_rule_models_plan_scopes_and_state_gauges_by_part(tmp_path):
         assert other not in text, name
 
 
+def test_a_sparse_models_counts_plans_and_scopes(tmp_path):
+    """Block-sparse layers beside Lightning state layers (ISSUE 56): both
+    ``post`` spans carry the sparse layers' counts (``SPARSE_STATS``) beside
+    the state's, a decode round's ``sparse_pages_read`` never over ``topk``
+    pages a (sequence, KV head, layer) nor over ``pages_held``;
+    ``serve.cache_spec`` states a token's bytes WITH its share of a
+    compressed row; ONE ``lightning.plan`` instant a program traced says
+    which form it holds, ``sparse.plan`` says how a sparse layer attends
+    (``masked`` in a chunk, ``gathered`` in a round off a TPU); the programs
+    carry both mixers' scopes."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.block import SPARSE_STATS, ChosenBlocks
+    from hetu_tpu.models.minicpm_sala import (
+        LIGHTNING, SPARSE, MiniCPMSALAConfig, MiniCPMSALAModel,
+    )
+
+    model = MiniCPMSALAModel(MiniCPMSALAConfig(
+        vocab_size=97, hidden_size=32, num_layers=4,
+        mixer_types=(SPARSE, LIGHTNING, LIGHTNING, SPARSE), num_heads=4,
+        num_kv_heads=2, head_dim=8, lightning_heads=4, lightning_kv_heads=4,
+        lightning_head_dim=8, lightning_chunk=8, ffn_size=64,
+        sparse=ChosenBlocks(stride=2, kernel=4, block=4, topk=4,
+                            init_blocks=1, local=4, dense_len=16),
+        published_layers=8, first_layer=2, max_position=128,
+        dtype=jnp.float32, param_dtype=jnp.float32))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=128,
+                               page_size=4, prefill_chunk=8, min_bucket=4)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    # K and V rows of 2 layers x 2 heads x 8 float32, and half a compressed
+    # row a layer (one every 2 tokens)
+    assert spec[3]["bytes_per_token"] == 2 * 2 * 8 * 4 * 2 + 2 * 2 * 8 * 4 // 2
+    assert (spec[3]["state_layers"], spec[3]["cache_layers"]) == (2, 2)
+    rounds = _named(events, "serve.decode.post")
+    chunks = _named(events, "serve.prefill_chunk.post")
+    assert rounds and chunks
+    for e in rounds + chunks:
+        assert set(SPARSE_STATS) <= set(e[3])
+        assert e[3]["blocks_chosen"] <= e[3]["blocks_visible"]
+    for e in chunks:
+        assert e[3]["sparse_pages_read"] == 0
+    # prompts of 5, 20 and 33 over a dense length of 16: two choose
+    assert sum(e[3]["sparse_queries"] for e in chunks) == (20 + 33) * 2
+    assert sum(e[3]["dense_queries"] for e in chunks) == 5 * 2
+    for e in rounds:
+        ids = e[3]
+        assert ids["sparse_pages_read"] <= ids["pages_held"]
+        assert ids["sparse_pages_read"] <= ids["sparse_queries"] * 2 * 4
+        assert ids["sparse_queries"] + ids["dense_queries"] > 0
+    assert any(e[3]["sparse_pages_read"] < e[3]["pages_held"]
+               for e in rounds)
+    plans = [e[3] for e in _named(events, "lightning.plan")]
+    assert len(plans) == eng.compiled_executables()   # one a program traced
+    assert {p["form"] for p in plans} == {"chunk", "step"}
+    assert all(p["rule"] == "ops.ssm" and p["state_bytes_per_slot"]
+               == 4 * 8 * 8 * 4 for p in plans)
+    forms = {e[3]["form"] for e in _named(events, "sparse.plan")}
+    assert forms == {"masked", "gathered"}
+    from paged_programs import traced
+    shared = ("hetu.lightning.proj", "hetu.lightning.norm", "hetu.ffn.dense",
+              "hetu.sparse.compress", "hetu.sparse.select",
+              "hetu.sparse.attend", "hetu.attn.full")
+    for name, own, other in (
+            ("decode", "hetu.lightning.step", "hetu.lightning.rule"),
+            ("chunk", "hetu.lightning.rule", "hetu.lightning.step")):
+        text = traced(eng, name, batch=4, chunk=8).lower().as_text(
+            debug_info=True)
+        assert all(scope in text for scope in shared + (own,)), name
+        assert other not in text, name
+
+
 # ------------------------------------------ a trained expert model's counts
 
 def _expert_model():
