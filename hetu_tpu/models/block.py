@@ -68,10 +68,13 @@ WINDOW, FULL = "sliding_attention", "full_attention"
 # over those layers: the real queries that read their chosen blocks and
 # those that read every position (the layer's dense branch), the blocks the
 # former chose and could have (chosen / visible: how sparse the call was),
-# and on a decode round the pages the attention walked for them beside the
-# pages their sequences hold
+# on a decode round the pages the attention walked for them beside the pages
+# their sequences hold, and of the former those whose attention ran in the
+# flash forward kernel's sparse chunk call (a chunk's, where
+# ``ops.sparse_kernel_why`` is "": all of a chunk's or none)
 SPARSE_STATS = ("sparse_queries", "dense_queries", "blocks_chosen",
-                "blocks_visible", "sparse_pages_read", "pages_held")
+                "blocks_visible", "sparse_pages_read", "pages_held",
+                "sparse_kernel_queries")
 
 
 @dataclass(frozen=True)
@@ -404,21 +407,24 @@ class BlockDecoder(GroupedHeads, Module):
 
     # ---- a sparse layer: the blocks a query chose ----
     def _count(self, call: LayerCall, real, sparse, n, pos, *,
-               walked: bool = False):
+               walked: bool = False, kernel: bool = False):
         """Add one sparse layer's ``SPARSE_STATS`` to the call's counts:
         ``real`` [B, S] the queries that are tokens, ``sparse`` [B, S] those
         that read their choice, ``n`` [B, S] how many blocks each chose a KV
         head, ``pos`` their positions; ``walked``: a decode round, whose
-        attention walked the chosen pages and no others."""
+        attention walked the chosen pages and no others; ``kernel``: a chunk
+        whose masked attention is the flash kernel's sparse chunk call."""
         if call.counts is None:
             return
         g, blk = self.c.num_kv_heads, self.sparse.block
         chose = real & sparse
+        queries = jnp.sum(chose)
         chosen = jnp.sum(jnp.where(chose, n, 0)) * g
         visible = jnp.sum(jnp.where(chose, pos // blk + 1, 0)) * g
         call.counts = call.counts + jnp.stack([
-            jnp.sum(chose), jnp.sum(real & ~sparse), chosen, visible,
-            chosen * walked, visible * walked]).astype(jnp.int32)
+            queries, jnp.sum(real & ~sparse), chosen, visible,
+            chosen * walked, visible * walked,
+            queries * kernel]).astype(jnp.int32)
 
     def _masked(self, q, comp, pos, k_rows, v_rows, every):
         """Choose, then walk the rows under the choice's mask: q [B, heads,
@@ -558,7 +564,8 @@ class BlockDecoder(GroupedHeads, Module):
         o = jax.lax.cond(jnp.all(own < sp.dense_len), dense, choose, None)
         self._count(call, real, jnp.broadcast_to(
             (own >= sp.dense_len)[:, None], (b, s)),
-            jnp.minimum(sp.topk, pos // sp.block + 1), pos)
+            jnp.minimum(sp.topk, pos // sp.block + 1), pos,
+            kernel=not ops.sparse_kernel_why(s, t, sp.block))
         call.k[grp], call.v[grp] = kc.write(cl, k), vc.write(cl, v)
         return o
 

@@ -68,7 +68,7 @@ from hetu_tpu.ops.attention import (
     compress_keys, decode_attention, decode_layer_attention,
     masked_block_attention, read_cache_layer, remat, ring_update,
     scan_cached_layers, scan_layers_over_caches, select_blocks,
-    write_cache_layer,
+    sparse_kernel_why, write_cache_layer,
 )
 from hetu_tpu.ops.graph_ops import (
     coo_spmm, gcn_norm, gcn_conv,

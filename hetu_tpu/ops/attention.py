@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from hetu_tpu.ops.pallas_kernels import paged_attention
 from hetu_tpu.ops.pallas_kernels.flash_attention import (
     SAVED_LSE, SAVED_OUT, flash_chunk_attention,
+    flash_sparse_chunk_attention,
 )
 from hetu_tpu.parallel.mesh import AXIS_TP
 from hetu_tpu.telemetry import trace
@@ -685,8 +686,10 @@ def chosen_mask(idx, n_blocks: int):
 def sparse_plan(form: str, q, rows: int, **ids):
     """Which way a block-sparse layer's attention went is fixed when the
     program is traced: one instant per attention built says so (``form``
-    ``masked``: a chunk's or the dense forward's queries walk the whole view
-    under their blocks' mask; ``paged``: a decode round's walk the pages
+    ``kernel``: a chunk's or the dense forward's queries walk the whole view
+    under their blocks' mask in the flash forward kernel; ``masked``: the
+    same walk as XLA operations, and ``why``, as ``chunk_attn.plan`` says it
+    (:func:`sparse_kernel_why`); ``paged``: a decode round's walk the pages
     they chose, in the pool where they lie; ``gathered``: the same from a
     view of those pages, off a TPU)."""
     b, nh, s, d = q.shape
@@ -695,19 +698,48 @@ def sparse_plan(form: str, q, rows: int, **ids):
                                   "rows": int(rows), **ids})
 
 
+def sparse_kernel_why(queries: int, rows: int, block: int) -> str:
+    """Why a block-sparse layer's masked attention of ``queries`` queries a
+    sequence over a view of ``rows`` rows, chosen in blocks of ``block``
+    positions, can NOT run in the flash forward kernel where this is traced,
+    "" when it can: ``short``, a view of at most ``KEY_BLOCK`` rows (one
+    softmax); ``blocks``, a block that does not divide ``KEY_BLOCK`` or the
+    view; ``ragged``, queries no tile of the kernel divides (more than one
+    tile of them and no multiple of 8: a dense forward's odd length, never a
+    chunk bucket); else :func:`chunk_kernel_why`'s reasons, the rule of a
+    dense chunk."""
+    if rows <= KEY_BLOCK:
+        return "short"
+    if KEY_BLOCK % block or rows % block:
+        return "blocks"
+    if queries > 512 and queries % 8:
+        return "ragged"
+    return chunk_kernel_why()
+
+
 def masked_block_attention(q, k_cache, v_cache, pos, chosen, *, block: int,
                            scale=None):
     """Causal attention of q [B, heads, S, D] at positions ``pos`` [B, S]
-    over a time-major view [B, T, kv_heads, D], each query over the blocks
-    (of ``block`` positions) it CHOSE: ``chosen`` [B, S, kv_heads, T /
-    block] bool.  The masked form: the walk of a dense chunk
-    (:func:`_attend_blocks` over a long view) with the mask beside the
-    causal one, so it costs what dense attention costs and is exact."""
+    (a sequence's are consecutive: a chunk's, the dense forward's) over a
+    time-major view [B, T, kv_heads, D], each query over the blocks (of
+    ``block`` positions) it CHOSE: ``chosen`` [B, S, kv_heads, T / block]
+    bool.  The masked form: the walk of a dense chunk over a long view with
+    the mask beside the causal one, so it costs what dense attention costs
+    and is exact; and who walks is decided as a dense chunk's walker is
+    (:func:`sparse_kernel_why`): the flash forward kernel's SPARSE chunk
+    call, score and probability tiles in VMEM, or :func:`_attend_blocks`
+    under the mask, the portable path and the tests' oracle.  A
+    ``sparse.plan`` instant says which, and why."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     t = k_cache.shape[1]
-    sparse_plan("masked", q, t, block=block, kv_heads=k_cache.shape[2])
-    if t > KEY_BLOCK and KEY_BLOCK % block == 0 and t % block == 0:
+    why = sparse_kernel_why(q.shape[2], t, block)
+    sparse_plan("masked" if why else "kernel", q, t, block=block,
+                kv_heads=k_cache.shape[2], why=why)
+    if not why:
+        return flash_sparse_chunk_attention(
+            q, k_cache, v_cache, pos[:, 0], chosen, block=block, scale=scale)
+    if why not in ("short", "blocks"):
         return _attend_blocks(q, k_cache, v_cache, pos, scale, KEY_BLOCK,
                               chosen=chosen, block_size=block)
     b, nh, s, d = q.shape

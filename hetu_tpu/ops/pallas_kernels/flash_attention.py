@@ -112,6 +112,15 @@ occupant, padding) are masked by the diagonal.  No LSE leaves and there is
 no backward: what trains has static lengths and calls
 :func:`flash_attention`.
 
+**A sparse chunk call** (:func:`flash_sparse_chunk_attention`; a
+block-sparse layer's chunk, ``ops.masked_block_attention``): the chunk call
+with the queries' BLOCK CHOICE as one more operand, a word of bits a (K tile,
+query, KV head) laid as the statistics' rows are.  Same grid, walk and tile
+body; every tile is masked, by the choice and on the diagonal by both, and a
+masked probability is zeroed (a row may see nothing in a tile, the first
+included).  The dense calls do not know it exists: they lower to the text
+they lowered to.
+
 Interpret mode runs the same kernels on CPU for correctness tests.
 """
 
@@ -170,11 +179,13 @@ def _clip(x, lo, hi):
 class _Walk:
     """Static facts of one call that every kernel body shares: tile sizes,
     block counts, the causal offset, the window, how many query heads read
-    one KV head, and how ``scale`` is applied."""
+    one KV head, and how ``scale`` is applied; of a SPARSE chunk call also
+    ``block``, the positions a block of its queries' choice spans."""
 
     def __init__(self, *, s_q, s_k, block_q, block_k, scale, causal,
-                 window=None, group=1):
+                 window=None, group=1, block=None):
         self.bq, self.bk = block_q, block_k
+        self.block = block
         self.n_q, self.n_k = s_q // block_q, s_k // block_k
         self.causal = causal
         self.offset = s_k - s_q
@@ -190,14 +201,16 @@ class _Walk:
         # row may still see nothing in the FIRST tiles of its walk, the ones
         # the window's lower edge crosses; what the forward then sums is
         # wiped by the correction of the first tile that holds a real score
-        # (exp(-1e30 - m) is 0), and the diagonal tile always does.
-        self.guard = causal and self.offset < 0
+        # (exp(-1e30 - m) is 0), and the diagonal tile always does.  Under a
+        # CHOICE a row may see nothing in any tile, the first one included
+        # (no block is forced here), and nothing wipes what it summed.
+        self.guard = (causal and self.offset < 0) or block is not None
 
     def from_start(self, start):
         """This walk with the diagonal where a CHUNK's puts it: query row 0
         sits at key position ``start`` (traced: a sequence's entry of the
         prefetched ``starts``), not at ``s_k - s_q``.  ``start >= 0``, so
-        every row sees key 0 and nothing needs the guard."""
+        every row sees key 0 and the diagonal asks for no guard."""
         w = copy.copy(self)
         w.offset = start
         return w
@@ -225,6 +238,16 @@ class _Walk:
         if self.window is None:
             return rel >= edge
         return (rel >= edge) & (rel < edge + self.window)
+
+    def chose(self, words):
+        """[block_k, block_q]: query c chose the block of ``block`` positions
+        that key row r of this tile lies in: bit ``r // block`` of its word,
+        ``words`` [1, block_q] int32 (:func:`_choice_words`).  A block's rows
+        share a bit, so the tile is ``block_k / block`` rows of bits, each
+        spread down its block's sublanes: no per-element shift."""
+        bits = [jnp.broadcast_to((words >> r) & 1, (self.block, self.bq))
+                for r in range(self.bk // self.block)]
+        return jnp.concatenate(bits, axis=0) != 0
 
     # A walk is a few (lo, hi, masked) spans of block indices, in the order
     # they are visited; blocks outside every span are dead (above the
@@ -455,10 +478,13 @@ def _dkdv_resident_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ------------------------------------------------------- streamed kernels
 
 def _fwd_streamed_step(w, last, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
-                       l_ref, acc_ref):
+                       l_ref, acc_ref, words_ref=None):
     """Program (bh, qi, j): one tile, K block ``ki`` of the walk's step j;
     m/l [1, block_q] and acc^T [D, block_q] carry the online-softmax state
-    across the walk in scratch, and step ``last`` writes the results."""
+    across the walk in scratch, and step ``last`` writes the results.
+    ``words_ref`` [1, block_q] (a sparse chunk call): the queries' choice
+    among this tile's blocks; EVERY tile is then masked, by the choice and,
+    where the diagonal crosses it, by both."""
     qi, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -469,9 +495,13 @@ def _fwd_streamed_step(w, last, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     ki = w.at(spans, j)
 
     def tile(masked):
+        mask = w.mask(qi, ki) if masked else None
+        if words_ref is not None:
+            mine = w.chose(words_ref[:])
+            mask = mine if mask is None else mask & mine
         m_ref[:], l_ref[:], acc_ref[:] = _fwd_tile(
             w, w.q_block(q_ref[:]), k_ref[:], v_ref[:], m_ref[:], l_ref[:],
-            acc_ref[:], w.mask(qi, ki) if masked else None)
+            acc_ref[:], mask)
 
     _step(spans, ki, tile)
 
@@ -496,6 +526,17 @@ def _fwd_chunk_kernel(starts_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         w.from_start(starts_ref[pl.program_id(0) // heads]),
         pl.num_programs(2) - 1, q_ref, k_ref, v_ref, o_ref, None, m_ref,
         l_ref, acc_ref)
+
+
+def _fwd_sparse_chunk_kernel(starts_ref, q_ref, k_ref, v_ref, words_ref,
+                             o_ref, m_ref, l_ref, acc_ref, *, w, heads):
+    """:func:`_fwd_chunk_kernel` under the queries' block choice:
+    ``words_ref`` [1, block_q], this tile's bits of this Q block's queries,
+    the KV head's (its query heads share them)."""
+    _fwd_streamed_step(
+        w.from_start(starts_ref[pl.program_id(0) // heads]),
+        pl.num_programs(2) - 1, q_ref, k_ref, v_ref, o_ref, None, m_ref,
+        l_ref, acc_ref, words_ref)
 
 
 def _dq_streamed_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -733,15 +774,27 @@ def _flash_fwd(q, k, v, *, scale, causal, window, block_q, block_k,
     return out.reshape(b, h, s_q, d_v), lse
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "scale", "block_q", "block_k", "head_major", "interpret"))
-def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
-                 interpret):
+def _choice_words(chosen, n_k: int, per: int):
+    """A chunk's block choice as the sparse chunk kernel reads it: chosen
+    [B, S, g, blocks] bool -> int32 [B * g, n_k, 1, S], bit ``r`` of word
+    (K tile, query) saying whether the query chose block ``tile * per + r``
+    (``per`` blocks a K tile: 8 bits at 512 | 64); blocks past the view's
+    are unchosen.  Lane-dense as the m and l rows are: a tile's words are a
+    [1, block_q] row, one a (KV head, query)."""
+    b, s, g, n = chosen.shape
+    bits = jnp.pad(chosen, ((0, 0),) * 3 + ((0, n_k * per - n),)).reshape(
+        b, s, g, n_k, per).astype(jnp.int32)
+    words = jnp.sum(bits << jnp.arange(per, dtype=jnp.int32), axis=-1)
+    return jnp.moveaxis(words, 1, 3).reshape(b * g, n_k, 1, s)
+
+
+def _chunk_call(q, k, v, starts, chosen=None, *, scale, block_q, block_k,
+                head_major, interpret, block=None):
     """The forward of a chunk call, q [B, H, S_c, D_qk] against the
     TIME-major k [B, T, H / g, D_qk] and v [B, T, H / g, D_v] (``head_major``:
-    [B, H / g, T, .]).  Jitted: a program that attends in several places
-    (layers outside a scan, the branches of a switch) traces and lowers the
-    kernel once.
+    [B, H / g, T, .]); under ``chosen`` [B, S_c, H / g, T / block] each
+    query over the blocks of ``block`` positions it chose (every tile is
+    then masked: the other kernel body, the same walk).
 
     Where both widths of a time-major view are whole lane tiles (128, 256)
     it is fed AS IT LIES, its flat rows [B, T, (H / g) * D] with a KV head a
@@ -757,26 +810,33 @@ def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
     s_k = t + -t % min(block_k, t)
     w = _Walk(s_q=s_q, s_k=s_k, block_q=_fit_block(s_q, block_q),
               block_k=_fit_block(s_k, block_k), scale=scale, causal=True,
-              group=h // h_kv)
+              group=h // h_kv, block=block)
     # the K blocks up to the one the last chunk of the call ends in: the
     # walked axis of the grid, a traced bound
     steps = jnp.clip((jnp.max(starts) + s_q + w.bk - 1) // w.bk, 1, w.n_k)
     grid = (b * h, w.n_q, steps)
     as_rows = not head_major and d % 128 == 0 and d_v % 128 == 0
-    _plan("fwd_chunk", False, w, q,
+    _plan("fwd_chunk" if chosen is None else "fwd_sparse_chunk", False, w, q,
           jax.ShapeDtypeStruct((b, h_kv, s_k, d), k.dtype),
           jax.ShapeDtypeStruct((b, h_kv, s_k, d_v), v.dtype), None,
-          (b * h, w.n_q, w.n_k), as_rows=int(as_rows))
+          (b * h, w.n_q, w.n_k), as_rows=int(as_rows),
+          **({} if chosen is None else {"block": block}))
 
     def q_at(bh, qi, j, starts):
         return bh, qi, 0
 
-    def k_at(bh, qi, j, starts):
+    def k_block(bh, qi, j, starts):
         spans = w.from_start(starts[bh // h]).k_spans(qi)
-        at = _live(j, spans, w.n_k, ends="last")
+        return _live(j, spans, w.n_k, ends="last")
+
+    def k_at(bh, qi, j, starts):
+        at = k_block(bh, qi, j, starts)
         if as_rows:
             return bh // h, at, bh % h // w.g
         return bh // w.g, at, 0
+
+    def words_at(bh, qi, j, starts):
+        return bh // w.g, k_block(bh, qi, j, starts), 0, qi
 
     def fed(x):
         if as_rows:
@@ -786,13 +846,18 @@ def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
                 b * h_kv, t, -1)
         return x if t == s_k else jnp.pad(x, ((0, 0), (0, s_k - t), (0, 0)))
 
+    kernel, more_specs, more = _fwd_chunk_kernel, [], []
+    if chosen is not None:
+        kernel = _fwd_sparse_chunk_kernel
+        more_specs = [pl.BlockSpec((None, None, 1, w.bq), words_at)]
+        more = [_choice_words(chosen, w.n_k, w.bk // block)]
     out = pl.pallas_call(
-        functools.partial(_fwd_chunk_kernel, w=w, heads=h),
+        functools.partial(kernel, w=w, heads=h),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
             in_specs=[pl.BlockSpec((None, w.bq, d), q_at),
                       pl.BlockSpec((None, w.bk, d), k_at),
-                      pl.BlockSpec((None, w.bk, d_v), k_at)],
+                      pl.BlockSpec((None, w.bk, d_v), k_at), *more_specs],
             out_specs=pl.BlockSpec((None, w.bq, d_v), q_at),
             scratch_shapes=[pltpu.VMEM((1, w.bq), jnp.float32),
                             pltpu.VMEM((1, w.bq), jnp.float32),
@@ -800,8 +865,30 @@ def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
         out_shape=jax.ShapeDtypeStruct((b * h, s_q, d_v), q.dtype),
         compiler_params=_params(grid),
         interpret=interpret,
-    )(starts, q.reshape(b * h, s_q, d), fed(k), fed(v))
+    )(starts, q.reshape(b * h, s_q, d), fed(k), fed(v), *more)
     return out.reshape(b, h, s_q, d_v)
+
+
+# Jitted, each under a name of its own (the compiled call's name, which a
+# device trace is read by): a program that attends in several places (layers
+# outside a scan, the branches of a switch) traces and lowers a kernel once.
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "block_q", "block_k", "head_major", "interpret"))
+def _flash_chunk(q, k, v, starts, *, scale, block_q, block_k, head_major,
+                 interpret):
+    return _chunk_call(q, k, v, starts, scale=scale, block_q=block_q,
+                       block_k=block_k, head_major=head_major,
+                       interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block", "scale", "block_q", "block_k", "interpret"))
+def _flash_sparse_chunk(q, k, v, starts, chosen, *, block, scale, block_q,
+                        block_k, interpret):
+    return _chunk_call(q, k, v, starts, chosen, block=block, scale=scale,
+                       block_q=block_q, block_k=block_k, head_major=False,
+                       interpret=interpret)
 
 
 def _flash_bwd(q, k, v, out, lse, g, *, scale, causal, window, block_q,
@@ -920,6 +1007,13 @@ def _mesh_partition(batch: int, heads: int):
     return auto, P(axis(AXIS_DP, batch), axis(AXIS_TP, heads), None, None)
 
 
+def _check_heads(h: int, h_k: int, h_v: int) -> None:
+    if h_k != h_v or h % h_k:
+        raise ValueError(
+            f"{h} query heads over {h_k} key and {h_v} value heads: K and V "
+            "share a head count that divides the queries'")
+
+
 def flash_attention(q, k, v, *, causal: bool = False, window=None,
                     scale=None, block_q: int = 512, block_k: int = 512,
                     interpret=None):
@@ -956,11 +1050,7 @@ def flash_attention(q, k, v, *, causal: bool = False, window=None,
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    if k.shape[1] != v.shape[1] or q.shape[1] % k.shape[1]:
-        raise ValueError(
-            f"{q.shape[1]} query heads over {k.shape[1]} key and "
-            f"{v.shape[1]} value heads: K and V share a head count that "
-            "divides the queries'")
+    _check_heads(q.shape[1], k.shape[1], v.shape[1])
     if window is not None and (not causal or int(window) < 1):
         raise ValueError(f"window {window!r}: a positive number of keys, "
                          "and only with causal=True")
@@ -1046,12 +1136,44 @@ def flash_chunk_attention(q, k, v, starts, *, scale=None, block_q: int = 512,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     heads = 1 if head_major else 2
-    if k.shape[heads] != v.shape[heads] or q.shape[1] % k.shape[heads]:
-        raise ValueError(
-            f"{q.shape[1]} query heads over {k.shape[heads]} key and "
-            f"{v.shape[heads]} value heads: K and V share a head count that "
-            "divides the queries'")
+    _check_heads(q.shape[1], k.shape[heads], v.shape[heads])
     return _flash_chunk(q, k, v, starts.astype(jnp.int32),
                         scale=float(scale), block_q=int(block_q),
                         block_k=int(block_k), head_major=bool(head_major),
                         interpret=auto_interpret(interpret))
+
+
+def flash_sparse_chunk_attention(q, k, v, starts, chosen, *, block: int,
+                                 scale=None, block_q: int = 512,
+                                 block_k: int = 512, interpret=None):
+    """:func:`flash_chunk_attention` of a BLOCK-SPARSE layer: query ``i``
+    sees key ``t <= starts[b] + i`` only where it chose ``t``'s block of
+    ``block`` positions, ``chosen`` [B, S_c, H / g, T / block] bool (a
+    choice is a (token, KV head)'s: the ``g`` query heads of a KV head share
+    it; a query that reads every block has all of them set).  q [B, H, S_c,
+    D_qk]; k [B, T, H / g, D_qk] and v [B, T, H / g, D_v] time-major, ``T``
+    whole blocks.  The same walk, tiles and online softmax as the dense
+    call, every tile masked: by the choice (``_Walk.chose``, from words of
+    bits laid as the statistics' rows are, :func:`_choice_words`) and, where
+    the diagonal crosses it, by both.  Nothing is skipped for the choice's
+    sake: on a tile of 512 x 512 some query reads some block unless
+    neighbouring tokens choose alike.  A query that chose nothing it can see
+    reads 0.  ``block`` divides the K tile (``block_k`` rows, or one block
+    where a block is longer), at most 32 blocks a tile."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    _check_heads(q.shape[1], k.shape[2], v.shape[2])
+    t = k.shape[1]
+    block_k = max(int(block_k), int(block))     # a K tile holds whole blocks
+    tile = _fit_block(t + -t % min(block_k, t), block_k)
+    if t % block or tile % block or tile // block > 32 \
+            or chosen.shape != (q.shape[0], q.shape[2], k.shape[2],
+                                t // block):
+        raise ValueError(
+            f"a choice {chosen.shape} among blocks of {block} positions "
+            f"over {t} rows walked {tile} at a time: whole blocks, a K tile "
+            "whole blocks (32 at most), a flag a (query, KV head, block)")
+    return _flash_sparse_chunk(q, k, v, starts.astype(jnp.int32), chosen,
+                               block=int(block), scale=float(scale),
+                               block_q=int(block_q), block_k=block_k,
+                               interpret=auto_interpret(interpret))

@@ -27,7 +27,7 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
 )
 from hetu_tpu.ops.pallas_kernels import grouped_matmul  # noqa: E402,F401
 from hetu_tpu.ops.pallas_kernels.flash_attention import (  # noqa: E402
-    flash_chunk_attention, write_rows,
+    flash_chunk_attention, flash_sparse_chunk_attention, write_rows,
 )
 from hetu_tpu.ops.pallas_kernels.paged_attention import (  # noqa: E402
     paged_decode_attention,
@@ -82,6 +82,11 @@ BENCH_CHUNK_SHAPES = {
         (16, 2, 256, 256, 35968, (16, 2048)),
     "longcat-flash-omni.batch-long": (64, 64, 256, 128, 9216, (512,)),
     "lfm2-8b-a1b.batch-docs": (32, 8, 64, 64, 10240, (16, 2048))}
+# the SPARSE chunk call of the flash forward kernel in the cell whose chunks
+# attend under their queries' block choice (ISSUE 57): (query heads, KV
+# heads, head width, view rows, block, chunk buckets)
+BENCH_SPARSE_CHUNK_SHAPES = {
+    "minicpm-sala.batch-context": (32, 2, 128, 66624, 64, (16, 2048))}
 
 
 @pytest.fixture(autouse=True)
@@ -205,6 +210,16 @@ def _cases():
                    [((1, h, s_c, d), bf16), ((1, rows, h_kv, d), bf16),
                     ((1, rows, h_kv, d_v), bf16), ((1,), i32)], 1)
 
+
+    for cell, (h, h_kv, d, rows, block, buckets) in \
+            BENCH_SPARSE_CHUNK_SHAPES.items():
+        for s_c in buckets:
+            yield (f"flash sparse chunk call {cell} S_c={s_c}",
+                   functools.partial(flash_sparse_chunk_attention,
+                                     block=block),
+                   [((1, h, s_c, d), bf16), ((1, rows, h_kv, d), bf16),
+                    ((1, rows, h_kv, d), bf16), ((1,), i32),
+                    ((1, s_c, h_kv, rows // block), jnp.bool_)], 1)
 
     # LongCat's rebuild: a block of 1,024 keys of 64 heads put in place by
     # one DMA, keys at 256 lanes and values at 128

@@ -330,6 +330,46 @@ def test_a_chunk_counts_what_its_queries_chose(sala):
     assert got["sparse_pages_read"] == 0
 
 
+def test_a_chunks_sparse_layers_take_the_kernel_where_the_rule_says(
+        sala, monkeypatch):
+    """A chunk program's ``sparse.plan`` says ``kernel`` when the rule's
+    conditions hold (``ops.sparse_kernel_why``: a view longer than
+    ``KEY_BLOCK``, a TPU backend; forced here, the kernels in interpret
+    mode) and ``masked`` otherwise, and ``sparse_kernel_queries`` counts the
+    chunks' choosing queries then, none otherwise; the first tokens are the
+    same either way."""
+    model, variables = sala
+    att = sys.modules["hetu_tpu.ops.attention"]
+    plans = []
+    monkeypatch.setattr(att.trace, "instant",
+                        lambda name, attrs=None, cat="hetu":
+                        plans.append((name, attrs)))
+    first = {}
+    for forced in (False, True):
+        if forced:
+            monkeypatch.setattr(att, "KEY_BLOCK", 32)
+            monkeypatch.setattr(att, "_default_backend_is_tpu", lambda: True)
+        plans.clear()
+        engine = PagedServeEngine(model, variables, num_slots=2, max_len=160,
+                                  page_size=8, prefill_chunk=16)
+        slots = [engine.alloc_slot(), engine.alloc_slot()]
+        first[forced] = [engine.prefill(s, prompt_of(n, 3))
+                         for s, n in zip(slots, (70, 30))]
+        forms = {(a["form"], a["why"]) for n, a in plans
+                 if n == "sparse.plan" and a["queries"] > 1}
+        assert forms == ({("kernel", "")} if forced
+                         else {("masked", "short")})
+        got = engine.metrics.snapshot()
+        # the prompt of 70 chooses (dense under 48), two sparse layers
+        assert got["sparse_queries"] == 70 * 2
+        assert got["dense_queries"] == 30 * 2
+        assert got["sparse_kernel_queries"] == (70 * 2 if forced else 0)
+        engine.decode()                        # a round never takes it
+        assert engine.metrics.snapshot()["sparse_kernel_queries"] \
+            == got["sparse_kernel_queries"]
+    assert first[True] == first[False]
+
+
 # ---- (e) the state's precision ----
 
 def test_a_bfloat16_state_loses_a_long_decode():

@@ -1,13 +1,14 @@
 """Block-sparse attention chosen by compressed keys (``ops/attention.py``:
 ``compress_keys``, ``select_blocks``, ``masked_block_attention``,
-``chosen_pages_attention``) against the plain reference's statement
+``chosen_pages_attention``; the flash forward kernel's SPARSE CHUNK call,
+``flash_sparse_chunk_attention``) against the plain reference's statement
 (``benchmarks/reference/minicpm_sala.py``) and against each other: the
 compressed keys are the windows' means wherever a span starts; the chosen
 blocks are the reference's, forced blocks, visibility and the short
 sequences' "every block" included; the masked walk over a long view is the
-masked softmax; a decode round's walk over the chosen PAGES (the paged
-kernel in interpret mode, and the gathered view) reads what the mask
-reads."""
+masked softmax, and the kernel's sparse chunk call (interpret mode) is that
+walk; a decode round's walk over the chosen PAGES (the paged kernel in
+interpret mode, and the gathered view) reads what the mask reads."""
 
 import sys
 from pathlib import Path
@@ -133,6 +134,149 @@ def test_the_masked_walk_is_the_masked_softmax(monkeypatch, key_block):
     got = ops.masked_block_attention(q, k, v, pos, chosen, block=8)
     np.testing.assert_allclose(got, masked_softmax(q, k, v, pos, chosen),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---- the flash forward kernel's sparse chunk call (interpret mode) ----
+
+def _sparse_chunk_case(heads=8, kv_heads=2, d=16, s_c=32, rows=128,
+                       starts=(0, 40), block=8, tile_q=16, tile_k=32,
+                       dtype=jnp.float32, seed=0, empty=(), every=(),
+                       every_rows=0):
+    """q [B, heads, S_c, d], a time-major view of ``rows`` rows whose rows
+    past each sequence's chunk hold garbage, ``starts`` and a choice [B, S_c,
+    kv_heads, rows / block]: a third of the blocks, a query's own among
+    them; none of the K TILES ``empty`` (the own block apart); every block
+    for the sequences ``every`` and a sequence's first ``every_rows``
+    queries."""
+    b = len(starts)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(ks[0], (b, heads, s_c, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, rows, kv_heads, d)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, rows, kv_heads, d)).astype(dtype)
+    starts = jnp.asarray(starts, jnp.int32)
+    pos = starts[:, None] + jnp.arange(s_c)[None]
+    unseen = (jnp.arange(rows)[None] >= (starts + s_c)[:, None])[
+        :, :, None, None]
+    blocks = jnp.arange(rows // block)
+    chosen = jax.random.bernoulli(ks[3], 0.35,
+                                  (b, s_c, kv_heads, rows // block))
+    for tile in empty:
+        chosen &= (blocks // (tile_k // block) != tile)
+    chosen |= (pos // block)[:, :, None, None] == blocks
+    for i in every:
+        chosen = chosen.at[i].set(True)
+    chosen = chosen.at[:, :every_rows].set(True)
+    return (q, jnp.where(unseen, 3e3, k), jnp.where(unseen, -7e3, v),
+            starts, pos, chosen)
+
+
+SPARSE_CHUNK_CASES = {
+    # two KV heads x four query heads each, two sequences: one from position
+    # 0, one whose chunk starts inside a K tile of 32
+    "start0_and_inside_a_tile": dict(),
+    "past_several_tiles": dict(starts=(96, 70), rows=160),
+    "one_query_head_a_kv_head": dict(heads=2, starts=(13,)),
+    # 136 rows = 4.25 K tiles: the view is padded to 5, its choice with it
+    "view_no_whole_tiles": dict(rows=136, starts=(100, 5)),
+    # nobody chose a block of K tile 1 (tile 0, the forced block's, lives)
+    "a_tile_nobody_chose": dict(starts=(64, 90), empty=(1,)),
+    # init_blocks 0: the FIRST tile of every walk is empty, and m still
+    # holds its initial value when the first live score arrives
+    "the_first_tile_empty": dict(starts=(64, 40), empty=(0,)),
+    "the_first_two_tiles_empty": dict(starts=(96,), rows=160, empty=(0, 1)),
+    # a prompt under the dense length beside one over it, and queries that
+    # read everything beside queries that choose within one Q tile
+    "every_beside_choosing": dict(starts=(40, 8), every=(1,)),
+    "every_rows_in_a_tile": dict(starts=(50,), every_rows=5, empty=(0,)),
+    # the cell's pair: blocks of 64 under K tiles of 512 (8 bits a word)
+    "b64_under_512": dict(heads=4, s_c=64, rows=1600, starts=(700, 1500),
+                          block=64, tile_q=32, tile_k=512, empty=(1,)),
+    # one Q tile, 32 blocks a K tile: every bit of a word
+    "32_blocks_a_tile": dict(heads=4, s_c=16, rows=512, starts=(300,),
+                             block=8, tile_q=16, tile_k=256),
+    "bf16": dict(starts=(45, 64), dtype=jnp.bfloat16, empty=(1,)),
+}
+
+
+@pytest.mark.parametrize("case", list(SPARSE_CHUNK_CASES))
+def test_the_sparse_chunk_call_is_the_masked_walk(case):
+    """The kernel fed a chunk and its queries' choice against the XLA walk
+    under the same mask (``_attend_blocks(chosen=...)``, what runs off a
+    TPU)."""
+    from hetu_tpu.ops.attention import _attend_blocks
+    from hetu_tpu.ops.pallas_kernels.flash_attention import (
+        flash_sparse_chunk_attention,
+    )
+
+    kw = dict(SPARSE_CHUNK_CASES[case])
+    q, k, v, starts, pos, chosen = _sparse_chunk_case(**kw)
+    block = kw.get("block", 8)
+    got = flash_sparse_chunk_attention(
+        q, k, v, starts, chosen, block=block, block_q=kw.get("tile_q", 16),
+        block_k=kw.get("tile_k", 32))
+    assert got.shape == q.shape and got.dtype == q.dtype
+    want = _attend_blocks(q, k, v, pos, q.shape[-1] ** -0.5, 4 * block,
+                          chosen=chosen, block_size=block)
+    tol = dict(rtol=2e-2, atol=2e-2) if q.dtype == jnp.bfloat16 \
+        else dict(rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
+def test_a_query_that_chose_nothing_it_sees_reads_zero():
+    """No block is forced in the kernel: a query whose choice holds no
+    visible block reads 0, not an average of what its row's m and l held."""
+    from hetu_tpu.ops.pallas_kernels.flash_attention import (
+        flash_sparse_chunk_attention,
+    )
+
+    q, k, v, starts, pos, chosen = _sparse_chunk_case(starts=(40,))
+    chosen = chosen.at[0, 7].set(False).at[0, 7, :, -1].set(True)
+    got = flash_sparse_chunk_attention(q, k, v, starts, chosen, block=8,
+                                       block_q=16, block_k=32)
+    assert not np.asarray(got[0, :, 7]).any()
+    assert np.abs(np.asarray(got[0, :, 6])).max() > 0
+    with pytest.raises(ValueError):        # 12 positions divide no K tile
+        flash_sparse_chunk_attention(q, k[:, :120], v[:, :120], starts,
+                                     chosen[..., :10], block=12,
+                                     block_q=16, block_k=32)
+
+
+def test_who_walks_a_sparse_layers_view_is_decided_on_what_is_observed(
+        monkeypatch):
+    """``ops.sparse_kernel_why``: a long view whose blocks divide
+    ``KEY_BLOCK``, on a TPU backend with no mesh in context, takes the
+    kernel's sparse chunk call; ``sparse.plan`` says ``kernel`` or
+    ``masked`` and why; both give the masked softmax."""
+    att = sys.modules["hetu_tpu.ops.attention"]
+    monkeypatch.setattr(att, "KEY_BLOCK", 32)
+    seen = []
+    monkeypatch.setattr(att.trace, "instant",
+                        lambda name, attrs=None, cat="hetu":
+                        seen.append((name, attrs)))
+    q, k, v, _, pos, chosen = _sparse_chunk_case(
+        heads=HEADS, d=D, starts=(40, 96), rows=128)
+    assert ops.sparse_kernel_why(32, 128, 8) == "backend"
+    walked = ops.masked_block_attention(q, k, v, pos, chosen, block=8)
+    monkeypatch.setattr(att, "_default_backend_is_tpu", lambda: True)
+    assert ops.sparse_kernel_why(32, 128, 8) == ""
+    assert ops.sparse_kernel_why(32, 32, 8) == "short"
+    assert ops.sparse_kernel_why(32, 128, 12) == "blocks"
+    assert ops.sparse_kernel_why(32, 100, 8) == "blocks"
+    assert ops.sparse_kernel_why(1001, 2048, 8) == "ragged"
+    assert ops.sparse_kernel_why(100, 2048, 8) == ""
+    from hetu_tpu.parallel.mesh import make_mesh
+    with jax.set_mesh(make_mesh(tp=2)):
+        assert ops.sparse_kernel_why(32, 128, 8) == "sharded"
+    kernel = ops.masked_block_attention(q, k, v, pos, chosen, block=8)
+    np.testing.assert_allclose(kernel, walked, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(
+        kernel, masked_softmax(q, k, v, pos, chosen), rtol=2e-4, atol=2e-5)
+    plans = [a for n, a in seen if n == "sparse.plan"]
+    assert [(p["form"], p["why"]) for p in plans] == [
+        ("masked", "backend"), ("kernel", "")]
+    assert any(n == "flash.plan" and a["kernel"] == "fwd_sparse_chunk"
+               and a["block"] == 8 for n, a in seen)
 
 
 @pytest.mark.parametrize("kernel", [False, True])
