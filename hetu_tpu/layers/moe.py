@@ -352,8 +352,10 @@ class HeldExpertLayer:
     divided by their sum (the sum keeps ALL ``k`` chosen scores, the absent
     experts' too: the router is whole on every chip); ``shared`` whether the
     layer has a shared expert, one more dense SwiGLU that every chip
-    computes for its own tokens and that is no part of the routing; where
-    the leaves hold ``shared_gate_w`` [H] its result is scaled a token by
+    computes for its own tokens and that is no part of the routing;
+    ``swiglu_limit`` a clamp inside every SwiGLU of the layer, the shared
+    expert's too (``silu(min(gate, x)) * clip(up, -x, x)``; None: none);
+    where the leaves hold ``shared_gate_w`` [H] its result is scaled a token by
     ``sigmoid(w_sg . u)`` (absent: as it is), and where they hold no
     ``router_bias`` the choice is by the scores alone.  All
     three are the model's, set from its configuration, not options of a
@@ -391,7 +393,7 @@ class HeldExpertLayer:
     def __init__(self, *, n_routed: int, n_zero: int, k: int, scaling: float,
                  held: tuple, block_rows: int = 128, dtype=jnp.bfloat16,
                  scoring: str = "softmax", renormalise: bool = False,
-                 shared: bool = False):
+                 shared: bool = False, swiglu_limit=None):
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
         self.n_routed, self.n_zero, self.k = n_routed, n_zero, k
@@ -401,6 +403,10 @@ class HeldExpertLayer:
         self.dtype = dtype
         self.scoring, self.renormalise, self.shared = \
             scoring, bool(renormalise), bool(shared)
+        # the model's clamp inside every SwiGLU, the shared expert's too:
+        # silu(min(gate, x)) * clip(up, -x, x); None: none
+        self.swiglu_limit = None if swiglu_limit is None \
+            else float(swiglu_limit)
 
     def route(self, p, tokens):
         """tokens [T, H] -> (weights [T, k] float32, scaling included,
@@ -428,8 +434,13 @@ class HeldExpertLayer:
 
         with jax.named_scope("hetu.moe.shared"):
             x = tokens.astype(dt)
-            h = jax.nn.silu(jnp.dot(x, of("shared_gate"))) \
-                * jnp.dot(x, of("shared_up"))
+            # gate, its activation, then up: the order the programs of the
+            # models without a clamp were lowered in
+            limit = self.swiglu_limit
+            g = jnp.dot(x, of("shared_gate"))
+            a = jax.nn.silu(g if limit is None else jnp.minimum(g, limit))
+            u = jnp.dot(x, of("shared_up"))
+            h = a * (u if limit is None else jnp.clip(u, -limit, limit))
             out = jnp.dot(h, of("shared_down"),
                           preferred_element_type=jnp.float32)
             if "shared_gate_w" in p:
@@ -451,7 +462,8 @@ class HeldExpertLayer:
             routed, per_expert = held_expert_ffn(
                 tokens.astype(self.dtype), w, idx, p["gate"], p["up"],
                 p["down"], first=self.first, block_rows=self.block_rows,
-                layer=layer, routed=self.n_routed + self.n_zero)
+                layer=layer, routed=self.n_routed + self.n_zero,
+                limit=self.swiglu_limit)
         n_held = per_expert.sum()
         n_zero = zero.sum().astype(jnp.int32)
         stats = jnp.stack([n_held, n_zero, idx.size - n_held - n_zero,

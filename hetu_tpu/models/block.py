@@ -204,6 +204,9 @@ class GroupedHeads:
     rotary_dim = None
     gated_query = False
     unit_offset_norms = False
+    # a clamp inside every SwiGLU a model may state (``silu(min(gate, x)) *
+    # clip(up, -x, x)``); None: none, and nothing in the program
+    swiglu_limit = None
 
     def _norm(self, x, scale):
         if self.unit_offset_norms:
@@ -298,6 +301,14 @@ class BlockDecoder(GroupedHeads, Module):
     # what the fourth value of the two cache entry points counts, in order
     step_stats = MOE_STATS
 
+    @property
+    def call_stats(self) -> tuple:
+        """What the model's attention layers count into ``LayerCall.counts``
+        in a cached call (behind the expert layers' counts where the model
+        has both): ``SPARSE_STATS`` of a model with sparse layers; a model
+        that selects otherwise states its own."""
+        return SPARSE_STATS if self.sparse_layers else ()
+
     def __init__(self, config, moe, *, attn_leaf, cache_layer, rotated,
                  window=None, multipliers=None, rotary_dim=None,
                  gated_query: bool = False, unit_offset_norms: bool = False,
@@ -341,6 +352,9 @@ class BlockDecoder(GroupedHeads, Module):
             if "gate" in m:
                 g = g * m["gate"]
             u = ops.linear(x, p["up"][l].astype(dt))
+            if self.swiglu_limit is not None:
+                g = jnp.minimum(g, self.swiglu_limit)
+                u = jnp.clip(u, -self.swiglu_limit, self.swiglu_limit)
             out = ops.linear(ops.silu(g) * u, p["down"][l].astype(dt))
             return out * m["down"] if "down" in m else out
 
@@ -574,12 +588,14 @@ class BlockDecoder(GroupedHeads, Module):
         stacked leaves of every layer.  Returns (out, the expert layer's
         counts [4] int32, zeros on a dense layer)."""
         by = self.multipliers.get("branch")
-        op = self._operator(p, l, self._norm(h, p["attn_norm"][l]), call)
-        h = h + (op if by is None else op * by)
-        u = self._norm(h, p["ffn_norm"][l])
+        u, mix = self._read(p, l, 0, h)
+        op = self._operator(p, l, self._norm(u, p["attn_norm"][l]), call)
+        h = self._write(h, op if by is None else op * by, mix)
+        u, mix = self._read(p, l, 1, h)
+        u = self._norm(u, p["ffn_norm"][l])
         if l < self.c.first_dense:
             f = self._ffn(p["ffn"], l, u)
-            return h + (f if by is None else f * by), \
+            return self._write(h, f if by is None else f * by, mix), \
                 jnp.zeros((4,), jnp.int32)
         moe, e = p["moe"], l - self.c.first_dense
         # the router's leaves are this layer's; a router with no correction
@@ -587,7 +603,22 @@ class BlockDecoder(GroupedHeads, Module):
         own = {name: moe[name][e] for name in ("router", "router_bias")
                if name in moe}
         m, stats = self.moe.apply(dict(moe, **own), u, layer=e)
-        return h + m, stats
+        return self._write(h, m, mix), stats
+
+    # ---- the residual seam: what a sublayer reads of the stream, and how
+    # its result goes back.  Here the plain add, which puts nothing into a
+    # program; a model whose stream is several rows a token states its own
+    # pair (``models/glm5_next.py``: hyper-connections) ----
+    def _read(self, p, l: int, sub: int, h):
+        """What sublayer ``sub`` (0 the operator, 1 the feed-forward) of
+        layer ``l`` reads of the stream ``h`` BEFORE its norm, and what
+        :meth:`_write` needs of the read: the stream itself, and nothing."""
+        return h, None
+
+    def _write(self, h, y, mix):
+        """The stream after a sublayer added ``y`` (its result, the model's
+        ``branch`` factor already in it); ``mix`` is :meth:`_read`'s."""
+        return h + y
 
     def _embed(self, p, ids):
         h = ops.embedding_lookup(p["tok_emb"], ids).astype(self.c.dtype)
@@ -650,8 +681,8 @@ class BlockDecoder(GroupedHeads, Module):
             v=[v_cache] if bare else list(v_cache), at=pos[:, 0],
             attention=attention, one_query=one_query, state=state, last=last,
             prompt_len=prompt_len,
-            counts=jnp.zeros((len(SPARSE_STATS),), jnp.int32)
-            if self.sparse_layers else None)
+            counts=jnp.zeros((len(self.call_stats),), jnp.int32)
+            if self.call_stats else None)
         stats = jnp.zeros((4,), jnp.int32)
         for l in range(self.c.num_layers):
             h, n = self._layer(p["layers"], l, h, call)
@@ -660,7 +691,9 @@ class BlockDecoder(GroupedHeads, Module):
             else (tuple(call.k), tuple(call.v))
         out = (h, k_cache, v_cache)
         if self.moe is not None:
-            out += (self._counts(stats),)
+            counts = self._counts(stats)
+            out += (counts if call.counts is None
+                    else jnp.concatenate([counts, call.counts]),)
         elif call.counts is not None:
             out += (call.counts,)
         return out if state is None else out + (call.state,)

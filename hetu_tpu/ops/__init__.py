@@ -64,11 +64,12 @@ from hetu_tpu.ops.moe_ops import (
 )
 from hetu_tpu.ops.attention import (
     attention, cache_update, causal_attention, chosen_mask,
-    chosen_pages_attention, chunk_attention, chunk_kernel_why, chunk_plan,
-    compress_keys, decode_attention, decode_layer_attention,
-    masked_block_attention, read_cache_layer, remat, ring_update,
-    scan_cached_layers, scan_layers_over_caches, select_blocks,
-    sparse_kernel_why, write_cache_layer,
+    chosen_pages_attention, chosen_rows, chosen_rows_attention,
+    chunk_attention, chunk_kernel_why, chunk_plan, compress_keys,
+    decode_attention, decode_layer_attention, index_plan,
+    masked_block_attention, pool_index_keys, read_cache_layer, remat,
+    ring_update, scan_cached_layers, scan_layers_over_caches, select_blocks,
+    select_groups, sparse_kernel_why, write_cache_layer,
 )
 from hetu_tpu.ops.graph_ops import (
     coo_spmm, gcn_norm, gcn_conv,

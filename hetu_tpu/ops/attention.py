@@ -806,3 +806,134 @@ def chosen_pages_attention(q, k_cache, v_cache, layer, idx, n, lengths,
     o = jnp.einsum("bjgrd,jg->bgrd", o.reshape(b, g, g, nh // g, dv),
                    jnp.eye(g, dtype=o.dtype))
     return o.reshape(b, nh, 1, dv)
+
+
+# ---------------------------------------------------------------------------
+# selection by ROWS: a lightning indexer over a latent cache (DeepSeek
+# Sparse Attention with pooled indexer keys).  A second, small network scores
+# GROUPS of ``pool`` consecutive cached tokens by the MEAN of their indexer
+# keys; a query reads the rows of its ``topk`` best complete groups and of the
+# open group it stands in, and nothing else.  A group is neither a page nor a
+# flash block: its rows are GATHERED (a caller's, out of a view or out of the
+# page pool through a slot's table) and attended in the absorbed latent form.
+# Plain ``jax.numpy``, float32 scores, exact ``lax.top_k``.
+# ---------------------------------------------------------------------------
+
+INDEX_KEY_BLOCK = 2048   # pooled keys a call's queries score at a time
+
+
+def pool_index_keys(keys, open_sum, at, *, pool: int, last=None):
+    """The pooled indexer keys a call's new rows complete, and the open
+    group it leaves.  keys [B, S, D]: the call's new keys, row ``i`` of
+    sequence ``b`` at position ``at[b] + i``; open_sum [B, D] float32: the
+    sum of the ``at % pool`` keys of the group that was open when the call
+    began (not read where ``at % pool == 0``); last: the index of the last
+    real row (None: ``S - 1``).  Returns (means [B, n, D] float32, entry
+    ``j`` the mean of group ``at // pool + j``; done [B, n] bool, the group's
+    last position is a real row of this call; the new open sum [B, D]
+    float32, zeros where the last real row closes a group).  Whichever page,
+    chunk or round a group's first keys came in, its mean is the same sum."""
+    b, s, d = keys.shape
+    n = -(-s // pool) + 1
+    shift = (at % pool)[:, None]                            # [B, 1]
+    src = jnp.arange(n * pool)[None] - shift                # [B, n * pool]
+    upto = (s - 1 if last is None else last)
+    ok = (src >= 0) & (src <= upto)
+    rows = jnp.take_along_axis(
+        keys.astype(jnp.float32), jnp.clip(src, 0, s - 1)[..., None], 1)
+    sums = jnp.where(ok[..., None], rows, 0.0).reshape(b, n, pool, d).sum(2)
+    sums = sums.at[:, 0].add(jnp.where(shift > 0, open_sum, 0.0))
+    end = at + upto                                         # [B]
+    first = at // pool
+    done = pool * (first[:, None] + jnp.arange(n)[None]) + pool - 1 \
+        <= jnp.reshape(end, (-1, 1))
+    still = jnp.reshape((end + 1) % pool != 0, (-1, 1))
+    at_open = jnp.clip((end + 1) // pool - first, 0, n - 1)
+    new_open = jnp.where(still, jnp.take_along_axis(
+        sums, jnp.reshape(at_open, (-1, 1, 1)), 1)[:, 0], 0.0)
+    return sums / pool, done, new_open
+
+
+def select_groups(qi, w, kbar, pos, *, topk: int, pool: int):
+    """The groups each query reads.  qi [B, S, J, D] the indexer's queries,
+    w [B, S, J] float32 its head weights, kbar [B, G, D] the sequence's
+    pooled keys in order (rows past the complete groups: anything), pos [B,
+    S] the queries' positions.  ``I(t, g) = sum_j w_j relu(qi_j . kbar_g)``
+    in float32 over the ``(pos + 1) // pool`` COMPLETE groups; the ``topk``
+    largest, ties to the lower group (``lax.top_k``).  The scores are walked
+    ``INDEX_KEY_BLOCK`` groups at a time, so ``[J, S, G]`` is never whole.
+    Returns (idx [B, S, topk] int32 by falling score, n [B, S] int32: the
+    first ``n = min(topk, complete groups)`` of them are groups, the rest
+    padding)."""
+    b, s, j, d = qi.shape
+    g = kbar.shape[1]
+    kb = min(INDEX_KEY_BLOCK, g)
+    pad = -g % kb
+    if pad:
+        kbar = jnp.pad(kbar, ((0, 0), (0, pad), (0, 0)))
+    blocks = jnp.moveaxis(kbar.reshape(b, (g + pad) // kb, kb, d), 1, 0)
+
+    def one(block):
+        dots = jnp.einsum("bsjd,bgd->bsjg", qi, block,
+                          preferred_element_type=jnp.float32)
+        return jnp.sum(jnp.maximum(dots, 0.0) * w[..., None], axis=2)
+
+    scores = jnp.moveaxis(jax.lax.map(one, blocks), 0, 2).reshape(
+        b, s, g + pad)
+    complete = (pos + 1) // pool
+    scores = jnp.where(jnp.arange(g + pad)[None, None] < complete[..., None],
+                       scores, -jnp.inf)
+    if g + pad < topk:
+        scores = jnp.pad(scores, ((0, 0), (0, 0), (0, topk - g - pad)),
+                         constant_values=-jnp.inf)
+    _, idx = jax.lax.top_k(scores, topk)
+    return idx.astype(jnp.int32), jnp.minimum(complete, topk).astype(
+        jnp.int32)
+
+
+def chosen_rows(idx, n, pos, *, pool: int):
+    """The positions a query reads: idx [..., K], n [...] (:func:`select_groups`)
+    and pos [...] -> (rows [..., (K + 1) * pool] int32, valid bool the same
+    shape): the ``pool`` rows of each of the first ``n`` groups, then the
+    OPEN group's (``(pos + 1) // pool``) up to ``pos``: 0 to ``pool - 1`` of
+    them, the query's own among them unless it closes a group."""
+    k = idx.shape[-1]
+    groups = jnp.concatenate([idx, ((pos + 1) // pool)[..., None]], -1)
+    rows = groups[..., None] * pool + jnp.arange(pool)
+    valid = jnp.concatenate([
+        jnp.broadcast_to((jnp.arange(k) < n[..., None])[..., None],
+                         idx.shape + (pool,)),
+        rows[..., k:, :] <= pos[..., None, None]], -2)
+    flat = idx.shape[:-1] + ((k + 1) * pool,)
+    return rows.reshape(flat).astype(jnp.int32), valid.reshape(flat)
+
+
+def chosen_rows_attention(q, latents, valid, *, scale: float):
+    """Attention over GATHERED latent rows, the absorbed form: q [B, S,
+    heads, C] (each head's query already through its key up-projection),
+    latents [B, S, M, C] the rows query (b, s) reads (keys AND values: one
+    array), valid [B, S, M].  ``softmax(q . c * scale)`` over the valid rows
+    in float32; returns [B, S, heads, C] in ``latents``' dtype (a head's
+    value up-projection is the caller's).  A query with no valid row reads
+    zeros."""
+    scores = jnp.einsum("bshc,bsmc->bshm", q, latents,
+                        preferred_element_type=jnp.float32) * scale
+    scores = jnp.where(valid[:, :, None], scores, -jnp.inf)
+    top = jnp.max(scores, -1, keepdims=True)
+    e = jnp.exp(scores - jnp.where(jnp.isfinite(top), top, 0.0))
+    p = e / jnp.maximum(jnp.sum(e, -1, keepdims=True), 1e-30)
+    return jnp.einsum("bshm,bsmc->bshc", p.astype(latents.dtype), latents,
+                      preferred_element_type=jnp.float32).astype(
+                          latents.dtype)
+
+
+def index_plan(form: str, queries: int, batch: int, groups: int, **ids):
+    """Which way a row-selecting layer's attention went is fixed when the
+    program is traced: one instant per layer built says so (``form``
+    ``gathered``: the chosen groups' rows gathered and attended in the
+    absorbed form, a chunk's ``query_block`` queries at a time; ``why``: the
+    one form there is, ``rows`` for a group is neither a page the paged
+    kernel walks nor a block the flash kernel masks)."""
+    trace.instant("index.plan", {"form": form, "queries": int(queries),
+                                 "batch": int(batch), "groups": int(groups),
+                                 **ids})

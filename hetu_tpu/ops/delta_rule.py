@@ -47,6 +47,22 @@ float32:
   read (``S^T k``) BEFORE it is written.  A row of ``g`` = 0, ``beta`` = 0
   leaves its state bit for bit.
 
+**A decay a key channel** (Kimi Delta Attention, arXiv:2510.26692:
+:func:`kda_chunk_scan`, :func:`kda_step`): ``S <- Diag(exp(g_t)) S`` with
+``g_t`` [d_k], every row of the matrix forgetting at its own rate.  The
+chunk's ``L_ij = beta_i sum_c k_ic k_jc exp(Gamma_ic - Gamma_jc)`` no longer
+factors into ``(K K^T) * exp(gamma_i - gamma_j)``: the decays are folded into
+the operands, ``k_i * exp(Gamma_i - Gamma_ref)`` against ``k_j *
+exp(Gamma_ref - Gamma_j)``, and the second factor GROWS with ``j`` past the
+reference row.  So a chunk's rows are taken in SUB-BLOCKS of ``sub`` rows,
+each with its own reference, its first row: against the keys of earlier
+sub-blocks both exponents are <= 0, inside the sub-block the second is at
+most ``(sub - 1) |g|_max`` (a caller bounds ``g`` below: 15 x 5 = 75 at
+GLM-5.3's ``gate_lower_bound``, e^75 = 3.7e32, inside float32), and a pair
+the mask drops (a later sub-block's key) is never exponentiated.  The
+sub-blocks' ``L`` are ONE [C, C] system a chunk, solved and chained through
+the carried state as above; every other exponent taken is of a number <= 0.
+
 Plain ``jax.numpy`` / ``lax``.  The decays, their sums, ``L``, ``T``, ``U``,
 ``W`` and the state are float32 whatever the rows' dtype (the float32
 matmuls at the highest precision: on a TPU a float32 product is otherwise
@@ -186,6 +202,111 @@ def gated_delta_step(q, k, v, g, beta, state):
     qf = jnp.repeat(unit_rows(q) * dk ** -0.5, rep, axis=1)
     kf = jnp.repeat(unit_rows(k), rep, axis=1)
     state = state.astype(F32) * jnp.exp(g.astype(F32))[..., None, None]
+    held = jnp.sum(state * kf[..., None], axis=-2)         # S^T k
+    d = beta.astype(F32)[..., None] * (v.astype(F32) - held)
+    state = state + kf[..., None] * d[..., None, :]
+    return jnp.sum(state * qf[..., None], axis=-2).astype(v.dtype), state
+
+
+# ---------------------------------------------------------------------------
+# a decay a key channel (Kimi Delta Attention)
+# ---------------------------------------------------------------------------
+
+def kda_chunk_scan(q, k, v, g, beta, state, *, chunk: int = 64,
+                   sub: int = 16, last=None):
+    """The rule with a decay a key CHANNEL: ``q``, ``k`` [b, s, h, d_k] as
+    projected (the unit length and the query's ``1 / sqrt(d_k)`` are taken
+    here), ``v`` [b, s, h, d_v], ``g`` [b, s, h, d_k] (<= 0, bounded below by
+    the caller: ``(sub - 1) * max |g|`` is exponentiated), ``beta`` [b, s,
+    h]; ``state`` [b, h, d_k, d_v] or None (zeros); ``last`` the index of the
+    last real row (None: ``s - 1``).  ``chunk`` rows are solved together in
+    sub-blocks of ``sub`` (which divides it).  Returns (o [b, s, h, d_v] in
+    ``v``'s dtype, the state after row ``last`` float32).  Rows past
+    ``last`` and the padding of a ragged row count get ``g`` = 0 and
+    ``beta`` = 0 and leave the state bit for bit."""
+    b, s, h, dk = q.shape
+    dv, dt = v.shape[-1], v.dtype
+    if chunk % sub:
+        raise ValueError(f"sub-blocks of {sub} rows do not divide a chunk "
+                         f"of {chunk}")
+    g, beta = g.astype(F32), beta.astype(F32)
+    if last is not None:
+        real = jnp.arange(s)[None, :, None] <= last
+        g, beta = jnp.where(real[..., None], g, 0.0), \
+            jnp.where(real, beta, 0.0)
+    c = min(int(chunk), -(-s // sub) * sub)
+    pad = -s % c
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (q, k, v, g, beta))
+    n, a = (s + pad) // c, c // sub
+    # heads lead, a chunk's rows are the matrices' own: [b, n, h, c, width]
+    by_head = lambda x: jnp.moveaxis(x.reshape((b, n, c) + x.shape[2:]), 3, 2)
+    qc = by_head(unit_rows(q) * dk ** -0.5)
+    kc, vc = by_head(unit_rows(k)), by_head(v.astype(F32))
+    bc = by_head(beta)[..., None]                          # b n h c 1
+    # Gamma: the running sum of g down a chunk's rows (a product with a
+    # triangle of ones, as the scalar rule's: a windowed reduction otherwise)
+    cum = _f32_matmul("ij,bnhjd->bnhid", jnp.tril(jnp.ones((c, c), F32)),
+                      by_head(g))
+    # a sub-block's reference: Gamma at its first row
+    ref = cum.reshape(b, n, h, a, sub, dk)[:, :, :, :, 0]  # b n h a d
+    down = jnp.exp(cum.reshape(b, n, h, a, sub, dk) - ref[:, :, :, :, None])
+    # a key against sub-block ``a``'s reference: earlier rows decay further
+    # (<= 0), its own rows grow (at most (sub - 1) |g|), later rows are none
+    # of its business (masked BEFORE the exponent is taken)
+    upto = jnp.arange(c)[None, :] < (jnp.arange(a)[:, None] + 1) * sub
+    kx = kc[:, :, :, None] * jnp.exp(jnp.where(
+        upto[..., None], ref[:, :, :, :, None] - cum[:, :, :, None],
+        -jnp.inf))                                         # b n h a c d
+    pairs = lambda x: _f32_matmul(
+        "bnhaid,bnhajd->bnhaij", x.reshape(b, n, h, a, sub, dk) * down,
+        kx).reshape(b, n, h, c, c)
+    rows, cols = jnp.arange(c)[:, None], jnp.arange(c)[None, :]
+    low = jnp.where(rows > cols, pairs(kc), 0.0) * bc
+    reads = jnp.where(rows >= cols, pairs(qc), 0.0).astype(dt)
+    inv = unit_lower_inverse(low)
+    decay = jnp.exp(cum)                                   # exp(Gamma_i)
+    u = _f32_matmul("bnhij,bnhjd->bnhid", inv, vc * bc)
+    w = _f32_matmul("bnhij,bnhjd->bnhid", inv, kc * bc * decay).astype(dt)
+    end = cum[:, :, :, -1]                                 # b n h d_k
+    q_in = (qc * decay).astype(dt)
+    k_out = (kc * jnp.exp(end[:, :, :, None] - cum)).astype(dt)
+    if state is None:
+        state = jnp.zeros((b, h, dk, dv), F32)
+
+    def one_chunk(st, xs):
+        u_c, w_c, q_c, k_c, reads_c, left = xs
+        low_st = st.astype(dt)
+        d = (u_c - jnp.einsum("bhik,bhkd->bhid", w_c, low_st,
+                              preferred_element_type=F32)).astype(dt)
+        o = jnp.einsum("bhik,bhkd->bhid", q_c, low_st,
+                       preferred_element_type=F32) \
+            + jnp.einsum("bhij,bhjd->bhid", reads_c, d,
+                         preferred_element_type=F32)
+        st = st * left[..., None] \
+            + jnp.einsum("bhik,bhid->bhkd", k_c, d,
+                         preferred_element_type=F32)
+        return st, o.astype(dt)
+
+    state, o = jax.lax.scan(
+        one_chunk, state.astype(F32),
+        tuple(jnp.moveaxis(x, 1, 0)
+              for x in (u, w, q_in, k_out, reads, jnp.exp(end))))
+    # [n, b, h, c, d_v] -> [b, s, h, d_v]
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(b, n * c, h, dv)[:, :s]
+    return o, state
+
+
+def kda_step(q, k, v, g, beta, state):
+    """One row a sequence: ``q``, ``k`` [n, h, d_k] as projected, ``v`` [n,
+    h, d_v], ``g`` [n, h, d_k], ``beta`` [n, h], ``state`` [n, h, d_k, d_v].
+    Returns (o [n, h, d_v] in ``v``'s dtype, the new state float32).  A row
+    of ``g`` = 0, ``beta`` = 0 leaves its state bit for bit."""
+    dk = q.shape[-1]
+    qf, kf = unit_rows(q) * dk ** -0.5, unit_rows(k)
+    state = state.astype(F32) * jnp.exp(g.astype(F32))[..., None]
     held = jnp.sum(state * kf[..., None], axis=-2)         # S^T k
     d = beta.astype(F32)[..., None] * (v.astype(F32) - held)
     state = state + kf[..., None] * d[..., None, :]

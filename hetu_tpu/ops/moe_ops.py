@@ -230,7 +230,16 @@ def _expert_of(w, e, layer):
     return w[e] if layer is None else w[layer, e]
 
 
-def _held_forward(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
+def _clamped(g, u, limit):
+    """The model's clamp inside a SwiGLU: ``(min(g, limit), clip(u, -limit,
+    limit))``; ``limit`` None: as they are."""
+    if limit is None:
+        return g, u
+    return jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+
+
+def _held_forward(x, weights, idx, w_gate, w_up, w_down, layer, first, R,
+                  limit=None):
     k = idx.shape[1]
     E = w_gate.shape[0 if layer is None else 1]
     plan = _walk_plan(idx, first, E, R)
@@ -242,6 +251,7 @@ def _held_forward(x, weights, idx, w_gate, w_up, w_down, layer, first, R):
         rows = x[pairs // k]                              # [R, H]
         g = jnp.dot(rows, _expert_of(w_gate, e, layer).astype(dt))
         u = jnp.dot(rows, _expert_of(w_up, e, layer).astype(dt))
+        g, u = _clamped(g, u, limit)
         y = jnp.dot(jax.nn.silu(g) * u,
                     _expert_of(w_down, e, layer).astype(dt),
                     preferred_element_type=jnp.float32)
@@ -314,26 +324,37 @@ def _held_backward(x, weights, idx, w_gate, w_up, w_down, layer, plan, d_out,
         back(w_down, dwd), None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
-def _held(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
+def _held(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B,
+          limit=None):
     # evaluated, not differentiated (JAX runs the two rules below only under
     # autodiff): the grouped path's forward in trips of ``B`` rows, an
     # expert a fused call cut along F, and the loop reverse mode's alone;
     # without ``B`` (no tile of F fits) the loop's own forward
     if B is None:
         return _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
-                             first, R)[0]
+                             first, R, limit)[0]
     return _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer,
-                            first, B, True)[0]
+                            first, B, True, limit)[0]
 
 
-def _held_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B):
+def _served_alone(limit):
+    if limit is not None:
+        raise NotImplementedError(
+            "a SwiGLU clamped by the model's swiglu_limit is evaluated (a "
+            "served round or chunk, the dense forward); its reverse mode "
+            "is not written")
+
+
+def _held_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, R, B,
+                  limit):
+    _served_alone(limit)
     out, plan = _held_forward(x, weights, idx, w_gate, w_up, w_down, layer,
                               first, R)
     return out, (x, weights, idx, w_gate, w_up, w_down, layer, plan)
 
 
-def _held_vjp_bwd(first, R, B, res, g):
+def _held_vjp_bwd(first, R, B, limit, res, g):
     return _held_backward(*res, g[0], R)
 
 
@@ -462,11 +483,12 @@ def _trip(plan: _GroupedPlan, s, B: int, T: int, k: int,
                                  jnp.clip(end - lo, 0, B), B, tile_rows))
 
 
-@functools.partial(jax.jit, static_argnames=("first", "B", "fused"))
+@functools.partial(jax.jit, static_argnames=("first", "B", "fused", "limit"))
 def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
-                     B, fused=False):
+                     B, fused=False, limit=None):
     """``fused``: a trip's experts in ONE call (``gmm_ffn``) where the rule
-    says three; the row tile is then that call's (``gm.ffn_tiles``)."""
+    says three; the row tile is then that call's (``gm.ffn_tiles``).
+    ``limit``: the model's clamp inside the SwiGLU (:func:`_clamped`)."""
     (T, _), k, dt = x.shape, idx.shape[1], x.dtype
     plan = _grouped_plan(idx, first, w_gate.shape[-3], B)
     pair_w = weights.reshape(-1)
@@ -480,7 +502,7 @@ def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
         # sort put it: the inverse permutation)
         t = _trip(plan, 0, B, T, k, tile_rows)
         y = gm.gmm_ffn(x[t.tok], wg, wu, wd, pair_w[t.pairs], t.visits,
-                       layer=layer)
+                       layer=layer, limit=limit)
         at = jnp.argsort(plan.order[:T * k])
         y = jnp.where((at < plan.held)[:, None], y[at], 0.0)
         return (y.reshape(T, k, -1).sum(1), plan.counts), plan
@@ -490,10 +512,11 @@ def _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer, first,
         rows = x[t.tok]                                   # [B, H], once
         if fused:
             y = gm.gmm_ffn(rows, wg, wu, wd, pair_w[t.pairs], t.visits,
-                           layer=layer)
+                           layer=layer, limit=limit)
         else:
             g = gm.gmm(rows, wg, t.visits, layer=layer, out_dtype=dt)
             u = gm.gmm(rows, wu, t.visits, layer=layer, out_dtype=dt)
+            g, u = _clamped(g, u, limit)
             y = gm.gmm(jax.nn.silu(g) * u, wd, t.visits, layer=layer,
                        row_scale=pair_w[t.pairs])
         # rows past the held pairs were never written: dropped, not added
@@ -550,19 +573,22 @@ def _grouped_backward(x, weights, idx, w_gate, w_up, w_down, layer, plan,
         _leaf_grad(w_up, dwu, layer), _leaf_grad(w_down, dwd, layer), None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _grouped(x, weights, idx, w_gate, w_up, w_down, layer, first, B):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _grouped(x, weights, idx, w_gate, w_up, w_down, layer, first, B,
+             limit=None):
     return _grouped_forward(x, weights, idx, w_gate, w_up, w_down, layer,
-                            first, B)[0]
+                            first, B, False, limit)[0]
 
 
-def _grouped_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, B):
+def _grouped_vjp_fwd(x, weights, idx, w_gate, w_up, w_down, layer, first, B,
+                     limit):
+    _served_alone(limit)
     out, plan = _grouped_forward(x, weights, idx, w_gate, w_up, w_down,
                                  layer, first, B)
     return out, (x, weights, idx, w_gate, w_up, w_down, layer, plan)
 
 
-def _grouped_vjp_bwd(first, B, res, g):
+def _grouped_vjp_bwd(first, B, limit, res, g):
     return _grouped_backward(*res, g[0], B)
 
 
@@ -570,7 +596,8 @@ _grouped.defvjp(_grouped_vjp_fwd, _grouped_vjp_bwd)
 
 
 def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
-                    block_rows: int = 128, layer=None, routed=None):
+                    block_rows: int = 128, layer=None, routed=None,
+                    limit=None):
     """SwiGLU experts over the (token, choice) pairs that land on the
     experts held here; what absent experts would add is left out.
 
@@ -725,15 +752,23 @@ def held_expert_ffn(x, weights, idx, w_gate, w_up, w_down, *, first: int,
     between the two.  Gradients go to ``x``, to the pair ``weights`` (and
     through them to the router) and to the three weight leaves, accumulated
     in float32 and rounded once to the leaf's type; given ``layer``, a
-    leaf's gradient is zero outside that layer."""
+    leaf's gradient is zero outside that layer.
+
+    ``limit`` (None: none): the model's clamp inside the SwiGLU,
+    ``silu(min(gate, limit)) * clip(up, -limit, limit)``, on whichever path
+    the shapes take, the kernels' fused activation included; such a layer is
+    evaluated only (its reverse mode raises)."""
     T, k = idx.shape
+    limit = None if limit is None else float(limit)
     E, H, F = w_gate.shape[-3:]
     if layer is not None:               # an array: a static layer would
         layer = jnp.asarray(layer, jnp.int32)       # make a walk a layer
     path = held_expert_path(T, k, E, H, F, x.dtype.itemsize)
     B = grouped_row_budget(T, k, E, routed)
+    # a model without a clamp calls the walks as it always did
+    clamp = () if limit is None else (limit,)
     if path == "grouped":
         return _grouped(x, weights, idx, w_gate, w_up, w_down, layer,
-                        int(first), B)
+                        int(first), B, *clamp)
     return _held(x, weights, idx, w_gate, w_up, w_down, layer, int(first),
-                 int(block_rows), B if path == "cut" else None)
+                 int(block_rows), B if path == "cut" else None, *clamp)

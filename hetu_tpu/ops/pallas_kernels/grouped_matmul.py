@@ -242,35 +242,38 @@ def gmm(lhs, rhs, visits: Visits, *, layer=None, transpose_rhs: bool = False,
         interpret=interpret)
 
 
-def _swiglu_down(x, w_gate, w_up, w_down):
+def _swiglu_down(x, w_gate, w_up, w_down, limit=None):
     """``(silu(x w_gate) * (x w_up)) w_down`` in float32 over whole weights
     or over one F tile of each (the contraction over H is whole either way):
     gate and up rounded to the rows' type, as the three-call form hands
-    them on; SwiGLU in float32, its product rounded once."""
+    them on; SwiGLU in float32, its product rounded once.  ``limit``: the
+    model's clamp, ``silu(min(gate, limit)) * clip(up, -limit, limit)``."""
     f32 = jnp.float32
     g, u = (lax.dot_general(x, w[...], _NN, preferred_element_type=f32)
             .astype(x.dtype).astype(f32) for w in (w_gate, w_up))
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     a = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
     return lax.dot_general(a, w_down[...], _NN, preferred_element_type=f32)
 
 
 def _ffn_kernel(group, tile, starts, ends, layer, rows, w_gate, w_up, w_down,
-                scale, out, *, tm):
+                scale, out, *, tm, limit=None):
     del layer
     _, mask, fresh = _visit(group, tile, starts, ends, tm)
-    y = _swiglu_down(rows[...], w_gate, w_up, w_down)
+    y = _swiglu_down(rows[...], w_gate, w_up, w_down, limit)
     _store(out, y * scale[...], mask, fresh)
 
 
 def _ffn_cut_kernel(group, tile, starts, ends, layer, rows, w_gate, w_up,
-                    w_down, scale, out, acc, *, tm):
+                    w_down, scale, out, acc, *, tm, limit=None):
     """:func:`_ffn_kernel` with the grid's second axis over F tiles: a
     step's down product is added into ``acc`` (float32 [tm, H], VMEM) and
     the visit's last step stores the sum."""
     del layer
     f, last = pl.program_id(1), pl.num_programs(1) - 1
     _, mask, fresh = _visit(group, tile, starts, ends, tm)
-    y = _swiglu_down(rows[...], w_gate, w_up, w_down)
+    y = _swiglu_down(rows[...], w_gate, w_up, w_down, limit)
 
     @pl.when(f == 0)
     def _():
@@ -327,7 +330,7 @@ def ffn_tiles(H: int, F: int, itemsize: int = 2):
 
 
 def gmm_ffn(rows, w_gate, w_up, w_down, row_scale, visits: Visits, *,
-            layer=None, interpret=None):
+            layer=None, interpret=None, limit=None):
     """A SwiGLU expert a group over sorted rows, in ONE call: ``rows``
     [M, H], ``w_gate``/``w_up`` [G, H, F], ``w_down`` [G, F, H] (a leading
     layer axis with ``layer``), ``row_scale`` [M] float32 -> [M, H] float32:
@@ -341,7 +344,9 @@ def gmm_ffn(rows, w_gate, w_up, w_down, row_scale, visits: Visits, *,
     once a VISIT (``visits`` are then in tiles of ``ffn_tiles``' rows).
     float32 accumulation, gate and up rounded to ``rows``' type before
     SwiGLU, the down product and the scale in float32.  Rows as :func:`gmm`
-    leaves them."""
+    leaves them.  ``limit`` (static; None: none): a clamp inside the SwiGLU,
+    ``silu(min(gate, limit)) * clip(up, -limit, limit)``, an elementwise
+    pass over the same float32 tile."""
     H, F = w_gate.shape[-2:]
     tiles = ffn_tiles(H, F, rows.dtype.itemsize)
     if tiles is None:
@@ -353,8 +358,11 @@ def gmm_ffn(rows, w_gate, w_up, w_down, row_scale, visits: Visits, *,
              for w, axis in ((w_gate, 1), (w_up, 1), (w_down, 0))]
     more = dict(inner=(F // f_t,), scratch=[pltpu.VMEM(
         (visits.tile_rows, H), jnp.float32)]) if cut else {}
+    kernel = _ffn_cut_kernel if cut else _ffn_kernel
+    if limit is not None:
+        kernel = functools.partial(kernel, limit=float(limit))
     return _over_visits(
-        _ffn_cut_kernel if cut else _ffn_kernel, visits, specs[0][1],
+        kernel, visits, specs[0][1],
         [rows, w_gate, w_up, w_down, _column(row_scale)],
         [H] + [spec for spec, _ in specs] + [1], [(H, jnp.float32)],
         interpret=interpret, **more)

@@ -243,7 +243,9 @@ class PagedServeEngine:
     one executable per power-of-two PAGE-COUNT bucket — short sequences
     gather a fraction of ``max_len`` a layer instead of every slot's worst
     case, which is where paged decode's per-step byte traffic win comes
-    from.  Both are asserted via :meth:`compiled_executables`.
+    from (a model whose layers read a fixed number of CHOSEN rows has no such
+    win to make and states ``KVCacheSpec.whole_tables``: one page bucket, the
+    whole table).  Both are asserted via :meth:`compiled_executables`.
     """
 
     def __init__(self, model, variables, *, num_slots: int = 8,
@@ -313,6 +315,8 @@ class PagedServeEngine:
         # LENGTH (compressed rows, kv_cache.KVCacheSpec.comp_stride): the
         # chunk programs take the prompt's length as one int more
         self._chosen = spec.comp_stride is not None
+        # a round's page bucket is the whole table (KVCacheSpec.whole_tables)
+        self._whole_tables = spec.whole_tables
         self._ring_chunk = tuple(g.spec.ring_pages(self.prefill_chunk, ps)
                                  for g in self._more)
         self._ring_decode = tuple(g.spec.ring_pages(1, ps)
@@ -429,7 +433,7 @@ class PagedServeEngine:
         boundary) + one per (pow2 active-batch, pow2 page-count) decode
         bucket pair."""
         n_page_buckets = 1
-        b = 1
+        b = self.cache.pages_per_slot if self._whole_tables else 1
         while b < self.cache.pages_per_slot:
             b *= 2
             n_page_buckets += 1
@@ -1035,8 +1039,9 @@ class PagedServeEngine:
                 # remove
                 tables = [self.cache.tables[s] for s in act.tolist()]
                 widths = np.fromiter(map(len, tables), np.int64, n)
-                n_pg = pow2_ceil(int(widths.max()),
-                                 self.cache.pages_per_slot)
+                n_pg = self.cache.pages_per_slot if self._whole_tables \
+                    else pow2_ceil(int(widths.max()),
+                                   self.cache.pages_per_slot)
                 if (bb, n_pg) not in self._seen_page_buckets:
                     self._seen_page_buckets.add((bb, n_pg))
                     self.metrics.inc("decode_compiles")

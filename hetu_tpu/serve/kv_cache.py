@@ -98,7 +98,11 @@ class KVCacheSpec:
     (in ``state_dtype``; None: ``dtype``); one that keeps SEVERAL states
     ``state_parts``, each ``(name, shape, dtype)`` (a convolution's last
     rows in the compute type beside a recurrence's float32 matrix), and
-    every state layer keeps every part.  :attr:`parts` reads either form.
+    every state layer keeps every part, unless a part states a fourth
+    value, HOW MANY layers keep it (the open group of a row-selecting
+    layer's pooled keys: a state on the layers that hold paged rows, not on
+    the ``state_layers`` that hold none; :attr:`part_layers`).  :attr:`parts`
+    reads either form.
     The cache holds them as rows a slot owns whole (:class:`SlotStates`):
     one array ``[state layers, slots + 1, *state_shape]`` where the spec
     states ``state_shape``; where it states ``state_parts`` a tuple, in the
@@ -114,7 +118,19 @@ class KVCacheSpec:
     under the group's own page tables (compressed row ``i`` of a sequence
     is row ``i % rows`` of its page ``i // rows``), so a page's allocation,
     release and copy carry them; ``page_size`` must be a multiple of the
-    stride.  None: no such rows."""
+    stride.  None: no such rows.  ``comp_dim``: such a row's width where it
+    is not K's (a lightning indexer's pooled keys beside latent rows; the
+    rows are then read flat, ``[B, n, comp_dim]``).
+
+    ``v_head_dim`` 0: the layers keep ONE array a token (a latent that is
+    key and value both); the V pool is then of no width, and the programs
+    carry it empty.
+
+    ``whole_tables``: a decode round takes every slot's WHOLE page table,
+    whatever the histories.  For a model whose layers read a fixed number of
+    CHOSEN rows a round (and a few bytes a token of what they choose by), a
+    shorter table saves no read worth a program: the engine then compiles
+    one decode program a slot bucket, not one a (slot, page) bucket pair."""
 
     num_layers: int
     num_kv_heads: int
@@ -128,6 +144,23 @@ class KVCacheSpec:
     state_dtype: object = None
     state_parts: tuple = ()
     comp_stride: Optional[int] = None
+    comp_dim: Optional[int] = None
+    whole_tables: bool = False
+
+    @property
+    def comp_width(self) -> int:
+        """Width of a compressed row: ``comp_dim``, or K's."""
+        return self.num_kv_heads * self.head_dim if self.comp_dim is None \
+            else int(self.comp_dim)
+
+    @property
+    def part_layers(self) -> tuple:
+        """How many layers keep each of :attr:`parts`: ``state_layers``
+        unless the part states its own count."""
+        if self.state_layers and self.state_parts:
+            return tuple(int(p[3]) if len(p) > 3 else self.state_layers
+                         for p in self.state_parts)
+        return (self.state_layers,) * len(self.parts)
 
     @property
     def parts(self) -> tuple:
@@ -137,8 +170,8 @@ class KVCacheSpec:
         if not self.state_layers:
             return ()
         if self.state_parts:
-            return tuple((str(n), tuple(sh), np.dtype(dt))
-                         for n, sh, dt in self.state_parts)
+            return tuple((str(p[0]), tuple(p[1]), np.dtype(p[2]))
+                         for p in self.state_parts)
         return (("state", tuple(self.state_shape),
                  np.dtype(self.state_dtype or self.dtype)),)
 
@@ -146,8 +179,8 @@ class KVCacheSpec:
     def part_bytes_per_slot(self) -> dict:
         """By part's name, the bytes one slot's state takes in it over the
         state layers."""
-        return {n: self.state_layers * int(np.prod(sh, dtype=int))
-                * dt.itemsize for n, sh, dt in self.parts}
+        return {n: layers * int(np.prod(sh, dtype=int)) * dt.itemsize
+                for (n, sh, dt), layers in zip(self.parts, self.part_layers)}
 
     @property
     def groups(self) -> tuple:
@@ -177,7 +210,8 @@ class KVCacheSpec:
         its K and V rows and its share of a compressed row."""
         row = self.num_layers * self.num_kv_heads \
             * np.dtype(self.dtype).itemsize
-        comp = row * self.head_dim // self.comp_stride \
+        comp = self.num_layers * self.comp_width \
+            * np.dtype(self.dtype).itemsize // self.comp_stride \
             if self.comp_stride else 0
         return row * (self.head_dim + self.v_dim) + comp
 
@@ -308,12 +342,15 @@ class PagedLayers:
         b, n_pg, ps = pages.shape[:3]
         return pages.reshape((b, n_pg * ps) + tuple(self.row))
 
-    def read_comp(self, layer):
+    def read_comp(self, layer, row=None):
         """The compressed rows of cache layer ``layer`` of every sequence,
-        ``[B, n_pg * rows a page, *row]``, by the same tables."""
+        ``[B, n_pg * rows a page, *row]``, by the same tables; ``row``: the
+        shape the caller keeps such a row in where it is not K's
+        (``KVCacheSpec.comp_dim``)."""
         pages = self.comp[layer, self.tables]      # [B, n_pg, rpp, width]
         b, n_pg, rpp = pages.shape[:3]
-        return pages.reshape((b, n_pg * rpp) + tuple(self.row))
+        return pages.reshape((b, n_pg * rpp)
+                             + tuple(self.row if row is None else row))
 
     def write_comp(self, layer, rows, index, valid):
         """Compressed rows ``rows`` ``[B, n, *row]`` (or flat) of cache
@@ -543,8 +580,8 @@ class _PageGroup:
                     f"compressed rows every {spec.comp_stride} tokens need "
                     f"pages of a multiple of that ({page_size}) and no mesh")
             self.comp = jnp.zeros(
-                lead[:2] + (page_size // spec.comp_stride,
-                            int(np.prod(k_row))), spec.dtype)
+                lead[:2] + (page_size // spec.comp_stride, spec.comp_width),
+                spec.dtype)
         if sharding is not None:
             self.k = jax.device_put(self.k, sharding)
             self.v = jax.device_put(self.v, sharding)
@@ -742,8 +779,9 @@ class PagedKVCache:
             if spec.state_parts:    # a part a tuple of its layers' arrays
                 self.state = tuple(
                     tuple(jnp.zeros((rows,) + shape, dtype)
-                          for _ in range(spec.state_layers))
-                    for _, shape, dtype in spec.parts)
+                          for _ in range(layers))
+                    for (_, shape, dtype), layers
+                    in zip(spec.parts, spec.part_layers))
             else:
                 (_, shape, dtype), = spec.parts
                 self.state = jnp.zeros((spec.state_layers, rows) + shape,

@@ -992,3 +992,57 @@ def test_a_meshed_train_step_compiles_for_v5e_with_asynchronous_all_reduces(
     body = _backward_scan_body(text)
     assert len([ln for ln in body if plain.search(ln)]) == 2, body
     assert not re.search(r"(all-reduce|async-collective)-(start|done)", text)
+
+
+# ---- ISSUE 58: selection by rows and the per-channel rule at the shapes of
+# glm-5.3-flash.batch-context (plain XLA: what the chip's compiler accepts
+# and how much it keeps beside its arguments)
+
+def _glm_query_block(q, qi, w, kbar, view, pos):
+    """One query block of a chunk's DSA layer: 128 queries score 16,656
+    pooled keys, choose 512 groups, gather 2,052 rows of the slot's view and
+    attend in the absorbed form."""
+    from hetu_tpu import ops
+
+    idx, n = ops.select_groups(qi, w, kbar, pos, topk=512, pool=4)
+    rows, valid = ops.chosen_rows(idx, n, pos, pool=4)
+    latents = jax.vmap(lambda v, r: v[r])(view, jnp.clip(rows, 0, 66623))
+    return ops.chosen_rows_attention(q, latents, valid, scale=1.0 / 16)
+
+
+def _glm_rule(q, k, v, g, beta, state):
+    from hetu_tpu.ops import delta_rule
+
+    return delta_rule.kda_chunk_scan(q, k, v, g, beta, state, chunk=64,
+                                     sub=16, last=2000)
+
+
+GLM_CASES = (
+    ("a DSA query block", _glm_query_block,
+     (((1, 128, 64, 512), bf16), ((1, 128, 32, 128), bf16),
+      ((1, 128, 32), f32), ((1, 16656, 128), bf16), ((1, 66624, 512), bf16),
+      ((1, 128), i32)), 1 << 30),
+    ("the KDA rule over a chunk", _glm_rule,
+     (((1, 2048, 64, 128), bf16), ((1, 2048, 64, 128), bf16),
+      ((1, 2048, 64, 128), bf16), ((1, 2048, 64, 128), f32),
+      ((1, 2048, 64), f32), ((1, 64, 128, 128), f32)), 2 << 30))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("name,fn,args,most", GLM_CASES,
+                         ids=[c[0] for c in GLM_CASES])
+def test_row_selection_and_the_channel_rule_compile_for_v5e(name, fn, args,
+                                                            most):
+    """XLA:TPU accepts the gather, the exact top-k and the sub-blocked rule
+    at the published shapes, and keeps under ``most`` bytes of temporaries
+    (a block's gathered latents are 269 MB, the rule's sub-block factors
+    268 MB)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    sh = SingleDeviceSharding(topo.devices[0])
+    abstract = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in args]
+    compiled = jax.jit(fn).lower(*abstract).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < most

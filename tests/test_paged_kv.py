@@ -1339,3 +1339,62 @@ def test_padding_rows_of_a_round_are_not_sequences():
     assert np.asarray(st.real).tolist() == [True, True, False, False]
     one = SlotStates(jnp.zeros((2, 4, 2)), jnp.asarray([3, 1], jnp.int32))
     assert np.asarray(one.real).tolist() == [False, True]
+
+
+@pytest.mark.parametrize("comp_dim,v_head_dim", [(6, 0), (6, None),
+                                                  (None, 0)])
+def test_compressed_rows_of_their_own_width_and_a_pool_of_no_width(
+        comp_dim, v_head_dim):
+    """A latent layer keeps ONE array a token (``v_head_dim`` 0: the V pool
+    is of no width and the programs carry it empty) and compressed rows of
+    a width of their own (``comp_dim``: a lightning indexer's pooled keys),
+    read back flat; the bytes a token count both; a page's copy-on-write
+    carries them."""
+    spec = KVCacheSpec(num_layers=2, num_kv_heads=1, head_dim=16,
+                       v_head_dim=v_head_dim, dtype=jnp.float32,
+                       comp_stride=4, comp_dim=comp_dim)
+    wide = 16 if comp_dim is None else comp_dim
+    v_wide = 16 if v_head_dim is None else 0
+    assert (spec.comp_width, spec.v_dim) == (wide, v_wide)
+    assert spec.bytes_per_token == 2 * 4 * (16 + v_wide) + 2 * wide * 4 // 4
+    cache = PagedKVCache(spec, 2, 32, page_size=8, max_prefix_entries=0)
+    group = cache.groups[0]
+    assert group.comp.shape == (2, cache.num_pages, 2, wide)
+    assert group.v.shape == (2, cache.num_pages, 8, v_wide)
+    tables = jnp.asarray([[3, 1], [2, 4]], jnp.int32)
+    z = jnp.zeros((2, 1), jnp.int32)
+    layers = PagedLayers.over((group.k, group.comp), tables, z, z, (1, 16))
+    rows = jnp.arange(2 * 3 * wide, dtype=jnp.float32).reshape(2, 3, wide) + 1
+    layers = layers.write_comp(1, rows, jnp.asarray([[0, 1, 3]] * 2),
+                               jnp.ones((2, 3), bool))
+    back = layers.read_comp(1, row=None if comp_dim is None else (wide,))
+    assert back.shape == ((2, 4, 1, 16) if comp_dim is None else (2, 4, wide))
+    back = np.asarray(back).reshape(2, 4, wide)
+    np.testing.assert_array_equal(back[:, [0, 1, 3]], np.asarray(rows))
+    assert not back[:, 2].any()
+
+
+def test_a_state_part_may_state_how_many_layers_keep_it():
+    """``state_parts``' fourth value: the part is kept by that many layers,
+    not by every state layer (the open group of a row-selecting layer's
+    pooled keys lives on the layers that hold paged rows); the bytes a slot
+    and the cache's arrays follow."""
+    spec = KVCacheSpec(
+        num_layers=1, num_kv_heads=1, head_dim=8, dtype=jnp.float32,
+        state_layers=3, state_parts=(("conv", (6,), jnp.float32),
+                                     ("delta", (2, 4, 4), jnp.float32),
+                                     ("open", (5,), jnp.float32, 1)))
+    assert spec.part_layers == (3, 3, 1)
+    assert [n for n, _, _ in spec.parts] == ["conv", "delta", "open"]
+    assert spec.part_bytes_per_slot == {"conv": 3 * 6 * 4,
+                                        "delta": 3 * 32 * 4, "open": 5 * 4}
+    assert spec.bytes_per_slot == 72 + 384 + 20
+    cache = PagedKVCache(spec, 2, 16, page_size=8)
+    assert [len(part) for part in cache.state] == [3, 3, 1]
+    assert cache.state[2][0].shape == (3, 5)
+    assert cache.state_bytes == 3 * (72 + 384 + 20)
+    # three-value parts read as before
+    plain = KVCacheSpec(num_layers=1, num_kv_heads=1, head_dim=8,
+                        state_layers=2,
+                        state_parts=(("a", (3,), jnp.float32),))
+    assert plain.part_layers == (2,)

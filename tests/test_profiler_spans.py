@@ -875,6 +875,81 @@ def test_a_sparse_models_counts_plans_and_scopes(tmp_path):
         assert other not in text, name
 
 
+def test_a_row_selecting_models_counts_plans_and_scopes(tmp_path):
+    """A lightning indexer over a latent cache beside Kimi Delta Attention
+    state layers under hyper-connections (ISSUE 58): both ``post`` spans
+    carry the DSA layers' counts (``INDEX_STATS``) behind the expert
+    layers', ``groups_chosen`` never over ``groups_visible`` nor over
+    ``index_topk / index_kpool`` a query; ``serve.cache_spec`` states a
+    token's bytes as ONE latent and its share of a pooled key, a V pool of
+    no width and the three state parts; ONE ``kda.plan``, ``index.plan``
+    and ``mhc.plan`` instant a program traced; the programs carry the new
+    scopes."""
+    import jax.numpy as jnp
+
+    from hetu_tpu.models.glm5_next import (
+        DSA, INDEX_STATS, KDA, GLM5NextConfig, GLM5NextModel,
+    )
+
+    model = GLM5NextModel(GLM5NextConfig(
+        vocab_size=97, hidden_size=32, num_layers=3,
+        layer_types=(KDA, DSA, KDA),
+        mlp_layer_types=("dense", "sparse", "sparse"), num_heads=4,
+        head_dim=8, v_head_dim=8, q_lora_rank=16, kv_lora_rank=16,
+        index_n_heads=2, index_head_dim=8, index_topk=16, index_kpool=4,
+        index_rope_dim=4, index_query_block=4, kda_heads=4, kda_head_dim=8,
+        kda_gate_rank=4, kda_chunk=8, kda_sub=4, ffn_size=64,
+        expert_ffn_size=16, n_routed_experts=8, moe_topk=2, held=(0, 4),
+        max_position=128, dtype=jnp.float32, param_dtype=jnp.float32))
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0))
+    with profiled(tmp_path):
+        eng = PagedServeEngine(model, variables, num_slots=4, max_len=128,
+                               page_size=4, prefill_chunk=8, min_bucket=4)
+        _serve(ContinuousBatchingScheduler(eng))
+    events = hetu_threads(tmp_path)[0]
+    (spec,) = _named(events, "serve.cache_spec")
+    # one latent of 16 float32 a token and a quarter of a pooled key of 8
+    assert spec[3]["bytes_per_token"] == 16 * 4 + 8 * 4 // 4
+    assert (spec[3]["k_width"], spec[3]["v_width"]) == (16, 0)
+    assert (spec[3]["state_layers"], spec[3]["cache_layers"]) == (2, 1)
+    assert {"state_conv_bytes", "state_delta_bytes",
+            "state_open_bytes"} <= set(spec[3])
+    rounds = _named(events, "serve.decode.post")
+    chunks = _named(events, "serve.prefill_chunk.post")
+    assert rounds and chunks
+    for e in rounds + chunks:
+        ids = e[3]
+        assert set(INDEX_STATS) <= set(ids) and "moe_held" in ids
+        assert ids["groups_chosen"] <= ids["groups_visible"]
+        assert ids["groups_chosen"] <= 4 * (
+            ids["sparse_queries"] + ids["dense_queries"])
+    # prompts of 5, 20 and 33: positions from 19 on have over 4 groups
+    assert sum(e[3]["sparse_queries"] for e in chunks) == 1 + 14
+    assert sum(e[3]["dense_queries"] for e in chunks) == 5 + 19 + 19
+    assert any(e[3]["groups_chosen"] < e[3]["groups_visible"]
+               for e in rounds)
+    for name in ("kda.plan", "index.plan", "mhc.plan"):
+        plans = [e[3] for e in _named(events, name)]
+        assert len(plans) == eng.compiled_executables(), name
+    assert {p["form"] for p in (e[3] for e in _named(events, "kda.plan"))} \
+        == {"chunk", "step"}
+    for p in (e[3] for e in _named(events, "index.plan")):
+        assert (p["form"], p["why"], p["groups"], p["rows"]) \
+            == ("gathered", "rows", 4, 20)
+    assert all(e[3]["streams"] == 4 for e in _named(events, "mhc.plan"))
+    from paged_programs import traced
+    shared = ("hetu.mhc.mix", "hetu.kda.proj", "hetu.kda.conv",
+              "hetu.kda.norm", "hetu.dsa.proj", "hetu.index.proj",
+              "hetu.index.pool", "hetu.index.select", "hetu.index.attend",
+              "hetu.ffn.dense", "hetu.moe.experts")
+    for name, own, other in (("decode", "hetu.kda.step", "hetu.kda.rule"),
+                             ("chunk", "hetu.kda.rule", "hetu.kda.step")):
+        text = traced(eng, name, batch=4, chunk=8).lower().as_text(
+            debug_info=True)
+        assert all(scope in text for scope in shared + (own,)), name
+        assert other not in text, name
+
+
 # ------------------------------------------ a trained expert model's counts
 
 def _expert_model():
