@@ -48,14 +48,22 @@ tokens beside it under the same page tables (``comp_stride``, ``comp_dim``);
 and the running sum of the open group's keys, a state part of its own that
 the DSA layers keep (a group's keys arrive across chunk and round edges).
 
-**The attention is the absorbed form over GATHERED rows**: a query's scores
-are taken against the latents themselves (``q_h W_kb,h``, 512 wide) and its
-values are the same rows, up-projected after the softmax; a chunk's queries
-go ``index_query_block`` at a time (128 queries x 2,052 rows x 512 x 2 B =
-269 MB of gathered latents, 67 MB of float32 scores), a round gathers out of
-the page pool through each slot's table.  ``ops/attention.py``:
-``pool_index_keys``, ``select_groups``, ``chosen_rows``,
-``chosen_rows_attention``.
+**The attention is the absorbed form over the CHOSEN rows**: a query's
+scores are taken against the latents themselves (``q_h W_kb,h``, 512 wide)
+and its values are the same rows, up-projected after the softmax.  A chunk's
+queries go ``index_query_block`` at a time, and who fetches a block's rows
+is decided on what is observed (``ops.index_kernel_why``): on a TPU with no
+mesh in context the chosen groups are fetched INSIDE the call that attends
+them (``ops/pallas_kernels/chosen_groups.py``: the slot's view laid by group
+once a chunk, a group one 4 KB slab, one DMA a chosen group into VMEM;
+nothing gathered and no score goes through HBM); anywhere else, and in the
+dense forward (differentiated: the kernel has no backward), XLA gathers the
+rows (128 queries x 2,064 rows x 512 x 2 B = 271 MB of gathered latents, 68
+MB of float32 scores; a query's 2,052 rows brought up to whole 16-row tiles,
+or the gathered block is copied once more before it is read).  A round
+gathers out of the page pool through each slot's table.
+``ops/attention.py``: ``pool_index_keys``, ``select_groups``,
+``chosen_rows``, ``chosen_rows_attention``.
 
 **Shared**: the layer loop, the three calls, both cache entry points and the
 loss are ``models/block.py``'s ``BlockDecoder``, whose residual SEAM
@@ -85,6 +93,7 @@ from hetu_tpu.models.block import (
     BlockDecoder, LayerCall, counts_with_grouped, draw_leaf,
 )
 from hetu_tpu.ops import delta_rule, hyper
+from hetu_tpu.ops.pallas_kernels.chosen_groups import chosen_groups_attention
 from hetu_tpu.ops.ssm import causal_conv
 from hetu_tpu.telemetry import trace
 
@@ -94,9 +103,11 @@ DENSE, SPARSE = "dense", "sparse"
 # what the cache entry points count over the DSA layers, behind the expert
 # layers' counts: the real queries with more complete groups than the indexer
 # keeps (they read a choice) and those that read everything, the groups the
-# queries read and could have (chosen / visible: how sparse the call was)
+# queries read and could have (chosen / visible: how sparse the call was),
+# the real queries whose groups were fetched inside the call that attended
+# them (``ops.index_kernel_why``: all of a chunk's or none)
 INDEX_STATS = ("sparse_queries", "dense_queries", "groups_chosen",
-               "groups_visible")
+               "groups_visible", "index_kernel_queries")
 
 # the parts of the cache's state, in the order it holds them
 CONV, DELTA, OPEN = 0, 1, 2
@@ -537,10 +548,11 @@ class GLM5NextModel(BlockDecoder):
         with jax.named_scope("hetu.kda.proj"):
             return ops.linear(y.astype(dt_), p["o"][gl].astype(dt_))
 
-    def _count_index(self, call: LayerCall, real, n, pos):
+    def _count_index(self, call: LayerCall, real, n, pos, kernel: bool):
         """Add one DSA layer's ``INDEX_STATS`` to the call's counts: ``real``
         [B, S] the queries that are tokens, ``n`` [B, S] the complete groups
-        each read, ``pos`` their positions."""
+        each read, ``pos`` their positions; ``kernel``: their groups were
+        fetched inside the call that attended them."""
         if call.counts is None:
             return
         complete = (pos + 1) // self.c.index_kpool
@@ -548,28 +560,38 @@ class GLM5NextModel(BlockDecoder):
         call.counts = call.counts + jnp.stack([
             jnp.sum(real & sparse), jnp.sum(real & ~sparse),
             jnp.sum(jnp.where(real, n, 0)),
-            jnp.sum(jnp.where(real, complete, 0))]).astype(jnp.int32)
+            jnp.sum(jnp.where(real, complete, 0)),
+            jnp.sum(real) * kernel]).astype(jnp.int32)
 
-    def _read_chosen(self, q, qi, w, kbar, view, pos):
+    def _read_chosen(self, q, qi, w, kbar, view, pos, why: str):
         """A chunk's (or the dense forward's) queries over the view they
         chose from, ``index_query_block`` queries at a time: q [B, S, heads,
         C] absorbed, qi [B, S, J, d_I], w [B, S, J], kbar [B, G, d_I], view
-        [B, T, C], pos [B, S] -> (o [B, S, heads, C], n [B, S])."""
+        [B, T, C], pos [B, S] -> (o [B, S, heads, C], n [B, S]).  ``why``
+        (``ops.index_kernel_why``) "": a block's chosen groups are fetched
+        inside the call that attends them, out of the view laid BY GROUP
+        once a call; else XLA gathers their rows, a query's brought up to
+        whole row tiles."""
         c = self.c
         b, s = pos.shape
         qb = min(c.index_query_block, s)
         pad = -s % qb
-        t = view.shape[1]
+        t, P = view.shape[1], c.index_kpool
+        if not why:
+            view = jnp.pad(view, ((0, 0), (0, -t % P), (0, 0))).reshape(
+                b, -1, P, view.shape[-1])
 
         def block(xs):
             q_, qi_, w_, pos_ = xs
             with jax.named_scope("hetu.index.select"):
                 idx, n = ops.select_groups(
-                    qi_, w_, kbar, pos_, topk=c.index_groups,
-                    pool=c.index_kpool)
+                    qi_, w_, kbar, pos_, topk=c.index_groups, pool=P)
             with jax.named_scope("hetu.index.attend"):
-                rows, valid = ops.chosen_rows(idx, n, pos_,
-                                              pool=c.index_kpool)
+                if not why:
+                    return chosen_groups_attention(
+                        q_, view, idx, n, pos_, pool=P, scale=self.scale), n
+                rows, valid = ops.chosen_rows(idx, n, pos_, pool=P,
+                                              tile=ops.INDEX_ROW_TILE)
                 latents = jax.vmap(lambda v, r: v[r])(
                     view, jnp.clip(rows, 0, t - 1))
                 return ops.chosen_rows_attention(
@@ -624,16 +646,22 @@ class GLM5NextModel(BlockDecoder):
                 ki, open_sum, at, pool=P, last=call.last)
             if st is not None:
                 call.state = st.write(al, open_sum, OPEN)
+        # a round gathers out of the pool through the tables; the dense
+        # forward is differentiated (the model's loss), and the kernel has
+        # no backward; a chunk goes by what is observed
+        why = "round" if call.one_query else "forward" if call.k is None \
+            else ops.index_kernel_why(c.kv_lora_rank)
         if al == 0:
             ops.index_plan(
-                "gathered", s, b, c.index_groups, pool=P,
-                why="rows", rows=(c.index_groups + 1) * P,
+                "gathered" if why else "kernel", s, b, c.index_groups,
+                pool=P, why=why, rows=(c.index_groups + 1) * P,
                 query_block=1 if call.one_query
                 else min(c.index_query_block, s),
                 heads=nh, latent=c.kv_lora_rank, index_heads=J)
         real = jnp.ones((b, s), bool)
         if call.k is None:                          # the dense forward
-            o, n = self._read_chosen(q, qi, w, means.astype(dt_), lat, pos)
+            o, n = self._read_chosen(q, qi, w, means.astype(dt_), lat, pos,
+                                     why)
         else:
             grp, cl = self.cache_layer[l]
             kc = call.k[grp]
@@ -665,10 +693,10 @@ class GLM5NextModel(BlockDecoder):
                 view = jax.vmap(lambda v, rows_, i: jax.lax.
                                 dynamic_update_slice(v, rows_, (i, 0)))(
                     view.reshape(b, view.shape[1], -1), lat, at)
-                o, n = self._read_chosen(q, qi, w, kbar, view, pos)
+                o, n = self._read_chosen(q, qi, w, kbar, view, pos, why)
                 kc = kc.write(cl, lat)
             call.k[grp] = kc
-        self._count_index(call, real, n, pos)
+        self._count_index(call, real, n, pos, not why)
         with jax.named_scope("hetu.dsa.proj"):
             o = jnp.einsum("bshc,hcd->bshd", o, p["vb"][al].astype(dt_))
             return ops.linear(o.reshape(b, s, -1), p["o"][al].astype(dt_))

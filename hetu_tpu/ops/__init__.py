@@ -63,10 +63,11 @@ from hetu_tpu.ops.moe_ops import (
     balance_assignment, make_slot_routing, gather_dispatch, gather_combine,
 )
 from hetu_tpu.ops.attention import (
+    INDEX_ROW_TILE,
     attention, cache_update, causal_attention, chosen_mask,
     chosen_pages_attention, chosen_rows, chosen_rows_attention,
     chunk_attention, chunk_kernel_why, chunk_plan, compress_keys,
-    decode_attention, decode_layer_attention, index_plan,
+    decode_attention, decode_layer_attention, index_kernel_why, index_plan,
     masked_block_attention, pool_index_keys, read_cache_layer, remat,
     ring_update, scan_cached_layers, scan_layers_over_caches, select_blocks,
     select_groups, sparse_kernel_why, write_cache_layer,

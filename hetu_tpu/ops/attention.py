@@ -815,11 +815,17 @@ def chosen_pages_attention(q, k_cache, v_cache, layer, idx, n, lengths,
 # keys; a query reads the rows of its ``topk`` best complete groups and of the
 # open group it stands in, and nothing else.  A group is neither a page nor a
 # flash block: its rows are GATHERED (a caller's, out of a view or out of the
-# page pool through a slot's table) and attended in the absorbed latent form.
-# Plain ``jax.numpy``, float32 scores, exact ``lax.top_k``.
+# page pool through a slot's table) and attended in the absorbed latent form,
+# or, a chunk's on a TPU, FETCHED BY GROUP inside the call that attends them
+# (``pallas_kernels.chosen_groups``; :func:`index_kernel_why`).  The rest is
+# plain ``jax.numpy``, float32 scores, exact ``lax.top_k``.
 # ---------------------------------------------------------------------------
 
 INDEX_KEY_BLOCK = 2048   # pooled keys a call's queries score at a time
+INDEX_LANES = 128        # the lanes of a row tile: what a slab's rows fill
+# rows of a tile of the narrowest cached type (bfloat16: 16): a query's
+# gathered rows are brought up to a multiple, so a block's are whole tiles
+INDEX_ROW_TILE = 16
 
 
 def pool_index_keys(keys, open_sum, at, *, pool: int, last=None):
@@ -891,12 +897,18 @@ def select_groups(qi, w, kbar, pos, *, topk: int, pool: int):
         jnp.int32)
 
 
-def chosen_rows(idx, n, pos, *, pool: int):
+def chosen_rows(idx, n, pos, *, pool: int, tile: int = 1):
     """The positions a query reads: idx [..., K], n [...] (:func:`select_groups`)
-    and pos [...] -> (rows [..., (K + 1) * pool] int32, valid bool the same
-    shape): the ``pool`` rows of each of the first ``n`` groups, then the
-    OPEN group's (``(pos + 1) // pool``) up to ``pos``: 0 to ``pool - 1`` of
-    them, the query's own among them unless it closes a group."""
+    and pos [...] -> (rows [..., M] int32, valid bool the same shape), ``M``
+    = ``(K + 1) * pool``: the ``pool`` rows of each of the first ``n``
+    groups, then the OPEN group's (``(pos + 1) // pool``) up to ``pos``: 0 to
+    ``pool - 1`` of them, the query's own among them unless it closes a
+    group.  ``tile``: ``M`` is brought up to a multiple of it by rows that
+    are not valid, behind the others: what is gathered by a query's rows is
+    then a whole number of the memory's row tiles a query, and a block of
+    queries' rows ``[queries * M, C]`` read as ``[queries, M, C]`` is the
+    same bytes (2,052 rows of bfloat16, no multiple of 16, were a copy of
+    269 MB a block of 128 queries: PERF.md section 6, PR 59)."""
     k = idx.shape[-1]
     groups = jnp.concatenate([idx, ((pos + 1) // pool)[..., None]], -1)
     rows = groups[..., None] * pool + jnp.arange(pool)
@@ -905,7 +917,9 @@ def chosen_rows(idx, n, pos, *, pool: int):
                          idx.shape + (pool,)),
         rows[..., k:, :] <= pos[..., None, None]], -2)
     flat = idx.shape[:-1] + ((k + 1) * pool,)
-    return rows.reshape(flat).astype(jnp.int32), valid.reshape(flat)
+    rows, valid = rows.reshape(flat).astype(jnp.int32), valid.reshape(flat)
+    pad = ((0, 0),) * (rows.ndim - 1) + ((0, -flat[-1] % tile),)
+    return jnp.pad(rows, pad), jnp.pad(valid, pad)
 
 
 def chosen_rows_attention(q, latents, valid, *, scale: float):
@@ -927,13 +941,28 @@ def chosen_rows_attention(q, latents, valid, *, scale: float):
                           latents.dtype)
 
 
+def index_kernel_why(width: int) -> str:
+    """Why a chunk's row-selecting layer can NOT fetch its chosen groups
+    inside the call that attends them where this is traced
+    (``pallas_kernels.chosen_groups.chosen_groups_attention``), "" when it
+    can: ``width``, rows that are no whole number of ``INDEX_LANES`` lanes (a
+    group's slab is copied as it lies); else :func:`chunk_kernel_why`'s
+    reasons, the rule of a dense chunk."""
+    if width % INDEX_LANES:
+        return "width"
+    return chunk_kernel_why()
+
+
 def index_plan(form: str, queries: int, batch: int, groups: int, **ids):
     """Which way a row-selecting layer's attention went is fixed when the
     program is traced: one instant per layer built says so (``form``
-    ``gathered``: the chosen groups' rows gathered and attended in the
-    absorbed form, a chunk's ``query_block`` queries at a time; ``why``: the
-    one form there is, ``rows`` for a group is neither a page the paged
-    kernel walks nor a block the flash kernel masks)."""
+    ``kernel``: a chunk's or the dense forward's queries fetch the groups
+    they chose inside the call that attends them, ``query_block`` queries a
+    call; ``gathered``: the chosen groups' rows gathered by XLA and attended
+    in the absorbed form, and ``why``: :func:`index_kernel_why`'s reasons,
+    ``round`` for a decode round, which gathers out of the page pool through
+    the tables, ``forward`` for the dense forward, which is differentiated
+    where the kernel has no backward)."""
     trace.instant("index.plan", {"form": form, "queries": int(queries),
                                  "batch": int(batch), "groups": int(groups),
                                  **ids})

@@ -187,7 +187,9 @@ def test_the_comparison_sees(glm, monkeypatch, wrong):
 
         def headless(idx, n, pos, **how):
             r, valid = rows(idx, n, pos, **how)
-            return r, valid.at[..., -how["pool"]:].set(False) \
+            # the open group's rows stand behind the chosen groups'
+            tail = how["pool"] * idx.shape[-1]
+            return r, valid.at[..., tail:tail + how["pool"]].set(False) \
                 | (r == pos[..., None])
         monkeypatch.setattr(ops, "chosen_rows", headless)
     elif wrong == "no-clamp":
@@ -261,6 +263,46 @@ def test_two_requests_in_one_round_and_a_reused_slot(glm, served):
     ids = np.asarray([c + tc[:-1]])
     want = ref_logits(model, variables["params"], ids)[0, len(c) - 1:]
     assert rel_err(got_c, want) < F32_TOL
+
+
+def test_a_chunk_fetches_its_chosen_groups_in_the_kernel_where_the_rule_says(
+        glm, monkeypatch):
+    """A chunk program's ``index.plan`` says ``kernel`` when the rule's
+    conditions hold (``ops.index_kernel_why``: latents of whole lane tiles,
+    a TPU backend, no mesh; forced here, the kernel in interpret mode) and
+    ``index_kernel_queries`` counts the chunks' real queries, a padded last
+    chunk's rows left out; a round still gathers through the tables; and
+    the logits are the reference's within the float32 tolerance."""
+    from hetu_tpu.serve import ContinuousBatchingScheduler, Request
+
+    model, variables = glm
+    att = sys.modules["hetu_tpu.ops.attention"]
+    monkeypatch.setattr(att, "INDEX_LANES", 16)
+    monkeypatch.setattr(att, "_default_backend_is_tpu", lambda: True)
+    plans = []
+    monkeypatch.setattr(att.trace, "instant",
+                        lambda name, attrs=None, cat="hetu":
+                        plans.append((name, attrs)))
+    engine = PagedServeEngine(model, variables, num_slots=2, max_len=128,
+                              page_size=8, prefill_chunk=16, min_bucket=4)
+    sched = ContinuousBatchingScheduler(engine)
+    requests = [Request(prompt=prompt_of(n, n), max_tokens=3)
+                for n in (53, 21)]
+    sched.run(requests)
+    assert all(r.status == "ok" for r in requests)
+    forms = {(a["form"], a["why"], a["query_block"] > 1)
+             for name, a in plans if name == "index.plan"}
+    assert forms == {("kernel", "", True), ("gathered", "round", False)}
+    snap = engine.metrics.snapshot()
+    assert snap["index_kernel_queries"] == 53 + 21
+    # ... of the 53 + 21 prompt positions and two rounds' each
+    assert snap["sparse_queries"] + snap["dense_queries"] == 53 + 21 + 4
+    with jax.default_matmul_precision("highest"):
+        prompt = prompt_of(57, 9)
+        got, toks = served_logits(*engine_of(model, variables), prompt, 3)
+    ids = np.asarray([prompt + toks[:-1]])
+    want = ref_logits(model, variables["params"], ids)[0, len(prompt) - 1:]
+    assert rel_err(got, want) < F32_TOL
 
 
 def test_counters_and_the_cache_books(glm):
@@ -423,5 +465,5 @@ def test_a_bfloat16_state_fails_where_float32_holds(glm, served):
 
 def test_step_stats_name_the_index_counters(glm):
     model, _ = glm
-    assert model.step_stats[-4:] == INDEX_STATS
+    assert model.step_stats[-len(INDEX_STATS):] == INDEX_STATS
     assert model.call_stats == INDEX_STATS

@@ -2,7 +2,10 @@
 ``select_groups``, ``chosen_rows``, ``chosen_rows_attention``): pooled keys
 and the open group across call edges, the choice against a dense stable
 argsort with ties, and the gathered attention against masked dense
-attention."""
+attention; the rows brought up to whole tiles against the rows as they are;
+and the kernel that fetches the chosen groups itself
+(``ops/pallas_kernels/chosen_groups.py``, interpret mode) against the
+gathered form."""
 
 import sys
 
@@ -12,6 +15,7 @@ import numpy as np
 import pytest
 
 from hetu_tpu import ops
+from hetu_tpu.ops.pallas_kernels.chosen_groups import chosen_groups_attention
 
 POOL = 4
 
@@ -170,3 +174,121 @@ def test_chosen_rows_attention_is_masked_dense_attention():
             jnp.where(mask[:, None], scores, -jnp.inf), -1), v)
     # position 0..2 of a sequence read their open group alone (never empty)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ---- the chosen groups fetched inside the call that attends them ----
+
+def gathered(q, view, idx, n, pos, *, scale, tile=1):
+    """The oracle: XLA gathers the rows out of view [B, T, C]."""
+    rows, valid = ops.chosen_rows(idx, n, pos, pool=POOL, tile=tile)
+    latents = jax.vmap(lambda v, r: v[r])(
+        view, jnp.clip(rows, 0, view.shape[1] - 1))
+    return ops.chosen_rows_attention(q, latents, valid, scale=scale)
+
+
+def a_choice(key, pos, topk, groups):
+    """``select_groups``' result for queries at ``pos`` [B, S]: distinct
+    complete groups in no order, anything behind the first ``n``."""
+    b, s = pos.shape
+    order = jnp.argsort(jax.random.uniform(key, (b, s, groups)), -1)
+    complete = (pos + 1) // POOL
+    # a complete group first: the ranks of the groups under ``complete``
+    rank = jnp.argsort(jnp.argsort(
+        jnp.where(order < complete[..., None], 0, 1), -1, stable=True), -1)
+    idx = jnp.zeros_like(order).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        rank].set(order)[..., :topk]
+    return idx.astype(jnp.int32), jnp.minimum(complete, topk).astype(
+        jnp.int32)
+
+
+KERNEL_CASES = {
+    # positions of one sequence's queries; view rows; topk
+    "fewer complete groups than topk": ([5, 9, 14, 18], 64, 8),
+    "an open group of 0, 1 and pool - 1 rows": ([47, 48, 50, 43], 64, 8),
+    "a query that closes a group": ([39, 43, 63, 59], 64, 8),
+    "a query with no valid row": ([-1, 40, -1, 2], 64, 8),
+    "a view no multiple of pool": ([61, 44, 30, 57], 62, 8),
+    "more groups chosen than a 128-row run holds": ([200, 150, 255, 131],
+                                                    256, 40),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_the_kernel_reads_what_the_gather_reads(case, dtype):
+    """Two sequences a call (the second's positions the first's reversed),
+    the view by group padded to whole groups as the model lays it."""
+    at, t, topk = KERNEL_CASES[case]
+    key = jax.random.PRNGKey(len(case))
+    nh, c = 4, 32
+    pos = jnp.array([at, at[::-1]], jnp.int32)
+    b, s = pos.shape
+    q = jax.random.normal(key, (b, s, nh, c)).astype(dtype)
+    view = jax.random.normal(jax.random.fold_in(key, 1),
+                             (b, t, c)).astype(dtype)
+    idx, n = a_choice(jax.random.fold_in(key, 2), pos, topk, -(-t // POOL))
+    by_group = jnp.pad(view, ((0, 0), (0, -t % POOL), (0, 0))).reshape(
+        b, -1, POOL, c)
+    with jax.default_matmul_precision("highest"):
+        want = gathered(q, view, idx, n, pos, scale=c ** -0.5)
+        got = chosen_groups_attention(q, by_group, idx, n, pos, pool=POOL,
+                                      scale=c ** -0.5)
+    assert got.shape == want.shape and got.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=2e-5 if dtype == jnp.float32 else 2e-2)
+    if case == "a query with no valid row":
+        assert not np.asarray(got, np.float32)[0, 0].any()
+        assert not np.asarray(got, np.float32)[1, 3].any()
+
+
+def test_the_kernel_takes_an_odd_count_of_queries():
+    """A grid step is two queries: five take a sixth, which is dropped."""
+    key = jax.random.PRNGKey(5)
+    pos = jnp.array([[7, 20, 33, 46, 63]], jnp.int32)
+    q = jax.random.normal(key, (1, 5, 4, 32))
+    view = jax.random.normal(jax.random.fold_in(key, 1), (1, 64, 32))
+    idx, n = a_choice(jax.random.fold_in(key, 2), pos, 8, 16)
+    with jax.default_matmul_precision("highest"):
+        want = gathered(q, view, idx, n, pos, scale=0.2)
+        got = chosen_groups_attention(q, view.reshape(1, 16, POOL, 32), idx,
+                                      n, pos, pool=POOL, scale=0.2)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_the_kernel_refuses_a_view_by_other_groups():
+    with pytest.raises(ValueError, match="groups of 8"):
+        chosen_groups_attention(
+            jnp.zeros((1, 2, 4, 32)), jnp.zeros((1, 8, 8, 32)),
+            jnp.zeros((1, 2, 4), jnp.int32), jnp.zeros((1, 2), jnp.int32),
+            jnp.zeros((1, 2), jnp.int32), pool=POOL, scale=1.0)
+
+
+@pytest.mark.parametrize("topk", [3, 5, 8, 10])
+def test_rows_brought_up_to_whole_tiles_read_the_same(topk):
+    """``chosen_rows(tile=16)``: the rows and their validity are the
+    unpadded ones to the bit, those behind them are not valid, and the
+    attention over them is the unpadded one in float32 but for the order of
+    its sums (a zero probability a padded row: where the padding is none,
+    ``topk`` 3, to the bit; XLA's CPU sums 36 and 48 terms in different
+    runs of lanes, so 4e-7 of a value of order one elsewhere)."""
+    key = jax.random.PRNGKey(topk)
+    b, s, nh, c, t = 2, 24, 3, 16, 96
+    pos = jnp.broadcast_to(jnp.arange(t - s, t)[None], (b, s))
+    q = jax.random.normal(key, (b, s, nh, c))
+    view = jax.random.normal(jax.random.fold_in(key, 1), (b, t, c))
+    idx, n = a_choice(jax.random.fold_in(key, 2), pos, topk, t // POOL)
+    rows, valid = ops.chosen_rows(idx, n, pos, pool=POOL)
+    padded, padded_valid = ops.chosen_rows(idx, n, pos, pool=POOL, tile=16)
+    m = (topk + 1) * POOL
+    assert padded.shape[-1] == -(-m // 16) * 16
+    np.testing.assert_array_equal(padded[..., :m], rows)
+    np.testing.assert_array_equal(padded_valid[..., :m], valid)
+    assert not np.asarray(padded_valid[..., m:]).any()
+    got = gathered(q, view, idx, n, pos, scale=0.25, tile=16)
+    want = gathered(q, view, idx, n, pos, scale=0.25)
+    if m % 16 == 0:
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)

@@ -26,6 +26,9 @@ from hetu_tpu.ops.pallas_kernels import (  # noqa: E402
     topk_gating,
 )
 from hetu_tpu.ops.pallas_kernels import grouped_matmul  # noqa: E402,F401
+from hetu_tpu.ops.pallas_kernels.chosen_groups import (  # noqa: E402
+    chosen_groups_attention,
+)
 from hetu_tpu.ops.pallas_kernels.flash_attention import (  # noqa: E402
     flash_chunk_attention, flash_sparse_chunk_attention, write_rows,
 )
@@ -87,6 +90,11 @@ BENCH_CHUNK_SHAPES = {
 # heads, head width, view rows, block, chunk buckets)
 BENCH_SPARSE_CHUNK_SHAPES = {
     "minicpm-sala.batch-context": (32, 2, 128, 66624, 64, (16, 2048))}
+# the call that fetches the groups a chunk's queries chose and attends them
+# (ISSUE 59), a block of queries at a time: (queries a block, heads, latent
+# width, groups in the slot's view, rows a group, groups a query chooses)
+BENCH_CHOSEN_GROUPS_SHAPES = {
+    "glm-5.3-flash.batch-context": (128, 64, 512, 16656, 4, 512)}
 
 
 @pytest.fixture(autouse=True)
@@ -97,7 +105,7 @@ def _compiled_kernels(monkeypatch):
     are emptied on the way in and out, so that no test meets a walk another
     traced in the other mode."""
     for mod in ("embedding", "flash_attention", "paged_attention",
-                "grouped_matmul"):
+                "grouped_matmul", "chosen_groups"):
         m = sys.modules[f"hetu_tpu.ops.pallas_kernels.{mod}"]
         name = "_auto_interpret" if mod == "embedding" else "auto_interpret"
         monkeypatch.setattr(m, name, lambda interpret: False)
@@ -220,6 +228,14 @@ def _cases():
                    [((1, h, s_c, d), bf16), ((1, rows, h_kv, d), bf16),
                     ((1, rows, h_kv, d), bf16), ((1,), i32),
                     ((1, s_c, h_kv, rows // block), jnp.bool_)], 1)
+
+    for cell, (s_q, h, c, groups, pool, chosen) in \
+            BENCH_CHOSEN_GROUPS_SHAPES.items():
+        yield (f"chosen groups fetched and attended {cell}",
+               functools.partial(chosen_groups_attention, pool=pool,
+                                 scale=1.0 / 16),
+               [((1, s_q, h, c), bf16), ((1, groups, pool, c), bf16),
+                ((1, s_q, chosen), i32), ((1, s_q), i32), ((1, s_q), i32)], 1)
 
     # LongCat's rebuild: a block of 1,024 keys of 64 heads put in place by
     # one DMA, keys at 256 lanes and values at 128
@@ -999,13 +1015,15 @@ def test_a_meshed_train_step_compiles_for_v5e_with_asynchronous_all_reduces(
 # and how much it keeps beside its arguments)
 
 def _glm_query_block(q, qi, w, kbar, view, pos):
-    """One query block of a chunk's DSA layer: 128 queries score 16,656
-    pooled keys, choose 512 groups, gather 2,052 rows of the slot's view and
-    attend in the absorbed form."""
+    """One query block of a chunk's DSA layer off the kernel: 128 queries
+    score 16,656 pooled keys, choose 512 groups, gather 2,052 rows of the
+    slot's view, brought up to 2,064 (whole tiles of 16), and attend in the
+    absorbed form."""
     from hetu_tpu import ops
 
     idx, n = ops.select_groups(qi, w, kbar, pos, topk=512, pool=4)
-    rows, valid = ops.chosen_rows(idx, n, pos, pool=4)
+    rows, valid = ops.chosen_rows(idx, n, pos, pool=4,
+                                  tile=ops.INDEX_ROW_TILE)
     latents = jax.vmap(lambda v, r: v[r])(view, jnp.clip(rows, 0, 66623))
     return ops.chosen_rows_attention(q, latents, valid, scale=1.0 / 16)
 
@@ -1021,7 +1039,7 @@ GLM_CASES = (
     ("a DSA query block", _glm_query_block,
      (((1, 128, 64, 512), bf16), ((1, 128, 32, 128), bf16),
       ((1, 128, 32), f32), ((1, 16656, 128), bf16), ((1, 66624, 512), bf16),
-      ((1, 128), i32)), 1 << 30),
+      ((1, 128), i32)), 400 << 20),
     ("the KDA rule over a chunk", _glm_rule,
      (((1, 2048, 64, 128), bf16), ((1, 2048, 64, 128), bf16),
       ((1, 2048, 64, 128), bf16), ((1, 2048, 64, 128), f32),
@@ -1035,8 +1053,11 @@ def test_row_selection_and_the_channel_rule_compile_for_v5e(name, fn, args,
                                                             most):
     """XLA:TPU accepts the gather, the exact top-k and the sub-blocked rule
     at the published shapes, and keeps under ``most`` bytes of temporaries
-    (a block's gathered latents are 269 MB, the rule's sub-block factors
-    268 MB)."""
+    (a block's gathered latents are 271 MB, the rule's sub-block factors
+    268 MB).  The gathered rows are read where the gather left them: at
+    2,052 rows a query they were COPIED to ``[128, 2052, 512]`` (a
+    ``reshape`` lent scoped memory, 539 MB of temporaries: ISSUE 59); at
+    2,064 the reshape is a bitcast."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -1046,3 +1067,6 @@ def test_row_selection_and_the_channel_rule_compile_for_v5e(name, fn, args,
     abstract = [jax.ShapeDtypeStruct(s, d, sharding=sh) for s, d in args]
     compiled = jax.jit(fn).lower(*abstract).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < most
+    relays = [ln for ln in compiled.as_text().splitlines()
+              if re.search(r"= bf16\[\d+,\d+,512\]\S* reshape\(", ln)]
+    assert not relays, relays

@@ -880,7 +880,8 @@ def test_a_row_selecting_models_counts_plans_and_scopes(tmp_path):
     state layers under hyper-connections (ISSUE 58): both ``post`` spans
     carry the DSA layers' counts (``INDEX_STATS``) behind the expert
     layers', ``groups_chosen`` never over ``groups_visible`` nor over
-    ``index_topk / index_kpool`` a query; ``serve.cache_spec`` states a
+    ``index_topk / index_kpool`` a query, ``index_kernel_queries`` 0 off a
+    TPU; ``serve.cache_spec`` states a
     token's bytes as ONE latent and its share of a pooled key, a V pool of
     no width and the three state parts; ONE ``kda.plan``, ``index.plan``
     and ``mhc.plan`` instant a program traced; the programs carry the new
@@ -923,6 +924,7 @@ def test_a_row_selecting_models_counts_plans_and_scopes(tmp_path):
         assert ids["groups_chosen"] <= ids["groups_visible"]
         assert ids["groups_chosen"] <= 4 * (
             ids["sparse_queries"] + ids["dense_queries"])
+        assert ids["index_kernel_queries"] == 0
     # prompts of 5, 20 and 33: positions from 19 on have over 4 groups
     assert sum(e[3]["sparse_queries"] for e in chunks) == 1 + 14
     assert sum(e[3]["dense_queries"] for e in chunks) == 5 + 19 + 19
@@ -934,8 +936,10 @@ def test_a_row_selecting_models_counts_plans_and_scopes(tmp_path):
     assert {p["form"] for p in (e[3] for e in _named(events, "kda.plan"))} \
         == {"chunk", "step"}
     for p in (e[3] for e in _named(events, "index.plan")):
-        assert (p["form"], p["why"], p["groups"], p["rows"]) \
-            == ("gathered", "rows", 4, 20)
+        # a round gathers through the tables; a chunk by the rule
+        # (``ops.index_kernel_why``: latents of 16 are no whole lane tiles)
+        assert (p["form"], p["why"], p["groups"], p["rows"]) == (
+            "gathered", "round" if p["query_block"] == 1 else "width", 4, 20)
     assert all(e[3]["streams"] == 4 for e in _named(events, "mhc.plan"))
     from paged_programs import traced
     shared = ("hetu.mhc.mix", "hetu.kda.proj", "hetu.kda.conv",
